@@ -16,20 +16,16 @@ SUPPORTED = ("tpu", "cpu")
 
 
 def _detect_name() -> str:
-    override = os.environ.get("DS_ACCELERATOR")
-    if override:
-        if override not in SUPPORTED:
-            raise ValueError(f"DS_ACCELERATOR={override!r} not in {SUPPORTED}")
-        return override
-    try:
+    name, source = os.environ.get("DS_ACCELERATOR"), "DS_ACCELERATOR"
+    if not name:
         import jax
 
-        platforms = {d.platform for d in jax.local_devices()}
-    except Exception:
-        return "cpu"
-    if platforms - {"cpu"}:
-        return "tpu"  # any non-cpu XLA platform takes the TPU path
-    return "cpu"
+        # a backend that fails to start raises here — it is not reported
+        # as "cpu"
+        name, source = jax.local_devices()[0].platform, "JAX default platform"
+    if name not in SUPPORTED:
+        raise ValueError(f"{source} {name!r} not in {SUPPORTED}")
+    return name
 
 
 def get_accelerator() -> DeepSpeedAccelerator:

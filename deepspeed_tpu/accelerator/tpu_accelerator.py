@@ -21,9 +21,12 @@ class TPU_Accelerator(DeepSpeedAccelerator):
     def _platform_devices(self) -> List[Any]:
         import jax
 
-        devs = jax.local_devices()
-        tpu_like = [d for d in devs if d.platform not in ("cpu",)]
-        return tpu_like if tpu_like else devs
+        devs = [d for d in jax.local_devices() if d.platform == "tpu"]
+        if not devs:
+            raise RuntimeError(
+                "TPU accelerator selected but JAX reports no TPU device "
+                f"(local devices: {jax.local_devices()})")
+        return devs
 
     def device_name(self, device_index: Optional[int] = None) -> str:
         if device_index is None:
@@ -37,15 +40,8 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return len(self._platform_devices())
 
     def memory_stats(self, device_index: Optional[int] = None) -> Dict[str, int]:
-        devs = self._platform_devices()
-        if not devs:
-            return {}
-        dev = devs[device_index or 0]
-        try:
-            stats = dev.memory_stats() or {}
-        except Exception:
-            stats = {}
-        return {k: int(v) for k, v in stats.items()}
+        dev = self._platform_devices()[device_index or 0]
+        return {k: int(v) for k, v in (dev.memory_stats() or {}).items()}
 
     def is_bf16_supported(self) -> bool:
         return True

@@ -5,7 +5,7 @@ The zero-recompile contract (docs/SERVING.md, PAPER.md §L1's fused-
 kernel discipline, here "sharding is placement, never a program shape")
 rests on every ``jax.jit`` living at one of three kinds of seam:
 
-- **module-level process-global jits** — ``_COW_PROGS``-style caches
+- **module-level process-global jits** — ``_COW_PROG``-style jits
   that every engine incarnation shares (a warm restart must hit the jit
   cache, not recompile inside the recovery critical path — the exact
   bug PR 6's review caught by hand);

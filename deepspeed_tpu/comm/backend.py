@@ -95,10 +95,6 @@ class XLABackend(Backend):
         import jax.lax as lax
         import numpy as np
 
-        # lax.axis_size is newer-jax only; psum(1, axis) is static at
-        # trace time on every version the graft supports
-        size_of = getattr(lax, "axis_size", None) or (
-            lambda a: lax.psum(1, a))
         if isinstance(axis, (tuple, list)):
-            return int(np.prod([size_of(a) for a in axis]))
-        return size_of(axis)
+            return int(np.prod([lax.axis_size(a) for a in axis]))
+        return lax.axis_size(axis)

@@ -69,11 +69,8 @@ def run_op(op: str, global_bytes: int, trials: int = 20, warmups: int = 3,
     """Time one collective at one size; returns a result dict."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
 
     mesh, axis, n = _mesh_and_axis()
     dtype = dtype or jnp.float32
@@ -85,16 +82,9 @@ def run_op(op: str, global_bytes: int, trials: int = 20, warmups: int = 3,
     body = _programs(axis)[op]
     specs = dict(mesh=mesh, in_specs=P("x"),
                  out_specs=P("x") if op != "broadcast" else P())
-    if op == "broadcast":
-        # tiled all_gather output IS replicated, but shard_map's varying-axes
-        # check can't see through it; the flag is check_vma on jax>=0.8,
-        # check_rep before
-        try:
-            fn = jax.jit(shard_map(body, check_vma=False, **specs))
-        except TypeError:
-            fn = jax.jit(shard_map(body, check_rep=False, **specs))
-    else:
-        fn = jax.jit(shard_map(body, **specs))
+    # tiled all_gather output IS replicated, but shard_map's varying-axes
+    # check can't see through it
+    fn = jax.jit(shard_map(body, check_vma=op != "broadcast", **specs))
     x = jax.device_put(
         jnp.ones((n * per_dev,), dtype),
         NamedSharding(mesh, P("x")))

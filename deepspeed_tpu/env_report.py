@@ -55,22 +55,11 @@ def op_status():
     return rows
 
 
-def devices_report(timeout_s: float = 60.0):
-    """Device inventory; a report tool must DEGRADE, not hang, when the
-    device backend is unreachable (remote/tunneled backends can block
-    jax.devices() indefinitely), so the probe runs under a timeout."""
+def devices_report():
+    """Device inventory as JAX reports it."""
     import jax
 
-    from .utils.debug import probe_device_count
-
-    n, err = probe_device_count(timeout_s)
-    if n is None and err is None:
-        return [f"device probe timed out after {timeout_s:.0f}s — backend "
-                "unreachable (tunnel/libtpu down?); host report above is "
-                "still valid"]
-    if err is not None:
-        return [f"device probe failed: {err}"]
-    devs = jax.devices()   # backend proven responsive; returns immediately
+    devs = jax.devices()
     lines = []
     lines.append(f"platform ............. {devs[0].platform}")
     lines.append(f"local devices ........ {jax.local_device_count()}")

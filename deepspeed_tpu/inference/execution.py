@@ -58,40 +58,44 @@ from .sampling import position_keys, sample_tokens
 
 __all__ = ["MeshExecutor", "place_params", "pool_jit", "pool_bytes"]
 
-# process-global COW page-copy programs, keyed by donation (jax.jit caches
-# on argument avals INCLUDING shardings, so every engine with the same pool
+# process-global COW page-copy program (jax.jit caches on argument avals
+# INCLUDING shardings, so every engine with the same pool
 # shape/dtype/placement — notably a warm-restart replacement — shares ONE
 # compile per process, and meshed/unmeshed pools each get their own
-# specialization of the same jit).  The programs are generic over the
+# specialization of the same jit).  The program is generic over the
 # canonical pool TUPLE — a jit retraces per input pytree structure, so the
 # same cached jit serves full-precision (k, v) and quantized
-# (k, v, k_scale, v_scale) pools with one compile each.
-_COW_PROGS: Dict[bool, Any] = {}
+# (k, v, k_scale, v_scale) pools with one compile each.  No out_shardings:
+# the in-place page update propagates the input pools' sharding verbatim,
+# so one jit serves meshed and unmeshed pools alike.
+_COW_PROG = jax.jit(cow_copy_pool, donate_argnums=(0,))
 
 # process-global KV-tiering programs (docs/SERVING.md "KV-page tiering"),
-# shared across engines for the same reason as _COW_PROGS.  The extract
+# shared across engines for the same reason as _COW_PROG.  The extract
 # half NEVER donates (a demote reads the pool and must leave it alive);
 # the inject half donates the pool like the COW snapshot.
-_TIER_EXTRACT_PROG: Any = None
-_TIER_INJECT_PROGS: Dict[bool, Any] = {}
+_TIER_EXTRACT_PROG = jax.jit(extract_pool_page)
+_TIER_INJECT_PROG = jax.jit(inject_pool_page, donate_argnums=(0,))
 
 
-def pool_jit(fn, donate, mesh, pool_specs, n_leading: int):
-    """jit a pool-consuming program.  ``fn`` takes and returns the pool as
-    ONE canonical tuple argument/output (so ``donate_argnums`` donates
-    every pool leaf at once — payload AND scale planes on a quantized
-    pool).  On a mesh, pin the outputs: ``n_leading`` replicated leading
+def pool_jit(fn, mesh, pool_specs, n_leading: int):
+    """jit a pool-consuming program.  ``fn`` takes the pool as its second
+    argument and returns it last, as ONE canonical tuple (so donating that
+    one argument donates every pool leaf at once — payload AND scale planes
+    on a quantized pool).  Each tick consumes and reproduces the pool, so it
+    is donated on every backend: the pool exists once in device memory, not
+    twice.  On a mesh, pin the outputs: ``n_leading`` replicated leading
     outputs (tokens/counts) followed by the pool tuple on its canonical
     shardings (``pool_specs``: one PartitionSpec per pool array) — without
     ``out_shardings`` GSPMD is free to pick a different pool placement per
     program and the donated buffers would reshard every tick."""
     if mesh is None:
-        return jax.jit(fn, donate_argnums=donate)
+        return jax.jit(fn, donate_argnums=(1,))
     rep = NamedSharding(mesh, P())
     pools = tuple(NamedSharding(mesh, s) for s in pool_specs)
     if n_leading == 0:   # the program returns the bare pool tuple
-        return jax.jit(fn, donate_argnums=donate, out_shardings=pools)
-    return jax.jit(fn, donate_argnums=donate,
+        return jax.jit(fn, donate_argnums=(1,), out_shardings=pools)
+    return jax.jit(fn, donate_argnums=(1,),
                    out_shardings=tuple([rep] * n_leading) + (pools,))
 
 
@@ -231,14 +235,9 @@ class MeshExecutor:
             self.pools = tuple(
                 jax.device_put(cache[k], cache[k].sharding)
                 for k in self._pool_keys)
-        # donation: each tick consumes and reproduces the pool — donate the
-        # buffers so the pool exists once in HBM, not twice (CPU has no
-        # donation support and would warn every compile).  The pool tuple
-        # is ONE jit argument, so (1,) donates every leaf.
-        self._donate = (1,) if jax.default_backend() != "cpu" else ()
         self._decode_prog = self._build_decode()
         self._prefill_progs: Dict[int, Any] = {}
-        self._cow_prog = self._build_cow() if prefix_cache else None
+        self._cow_prog = _COW_PROG if prefix_cache else None
         if self._cow_prog is not None:
             # pre-warm the one COW program shape with a trash-page self-copy
             # so its single compile lands at init, never during admission —
@@ -256,7 +255,8 @@ class MeshExecutor:
         # shard receives exactly its head slice.
         self._extract_prog = self._inject_prog = None
         if host_tier:
-            self._extract_prog, self._inject_prog = self._build_tier()
+            self._extract_prog = _TIER_EXTRACT_PROG
+            self._inject_prog = _TIER_INJECT_PROG
             # prewarm through the entry points (trash-page round trip):
             # compiles land at init AND the catalog registers both movers
             self.inject(self.extract(0), 0)
@@ -308,8 +308,7 @@ class MeshExecutor:
                                     lambda: position_keys(seeds, lengths + 1))
                 return nxt, paged_pool_tuple(cache)
 
-            return pool_jit(prog, self._donate, self.mesh,
-                            self._pool_specs, 1)
+            return pool_jit(prog, self.mesh, self._pool_specs, 1)
 
         def prog(params, pools, page_table, lengths, last_tok, active,
                  temp, top_k, top_p, seeds):
@@ -327,7 +326,7 @@ class MeshExecutor:
                                 lambda: position_keys(seeds, lengths + 1))
             return nxt, paged_pool_tuple(cache)
 
-        return pool_jit(prog, self._donate, self.mesh, self._pool_specs, 1)
+        return pool_jit(prog, self.mesh, self._pool_specs, 1)
 
     def _build_prefill(self, s_pad: int):
         apply_paged = self.model.apply_paged
@@ -347,8 +346,7 @@ class MeshExecutor:
                     lambda: position_keys(seed, (start + n_real)[None]))[0]
                 return nxt, paged_pool_tuple(cache)
 
-            return pool_jit(prog, self._donate, self.mesh,
-                            self._pool_specs, 1)
+            return pool_jit(prog, self.mesh, self._pool_specs, 1)
 
         def prog(params, pools, pt_row, tokens, n_real, start,
                  temp, top_k, top_p, seed):
@@ -375,37 +373,7 @@ class MeshExecutor:
                 lambda: position_keys(seed, (start + n_real)[None]))[0]
             return nxt, paged_pool_tuple(cache)
 
-        return pool_jit(prog, self._donate, self.mesh, self._pool_specs, 1)
-
-    def _build_cow(self):
-        # process-global jit (see _COW_PROGS): a replacement engine's init
-        # prewarm then hits the jit cache on the same pool avals instead of
-        # recompiling a fresh closure inside the warm-restart critical
-        # path.  No out_shardings: the in-place page update propagates the
-        # input pools' sharding verbatim, so one jit serves meshed and
-        # unmeshed pools alike.
-        donate = jax.default_backend() != "cpu"
-        prog = _COW_PROGS.get(donate)
-        if prog is None:
-            prog = _COW_PROGS[donate] = jax.jit(
-                cow_copy_pool, donate_argnums=(0,) if donate else ())
-        return prog
-
-    def _build_tier(self):
-        # process-global jits (see _TIER_*): a warm-restart replacement's
-        # prewarm hits the jit cache on the same pool avals instead of
-        # recompiling.  No out_shardings on inject: the in-place page
-        # update propagates the input pools' sharding verbatim, exactly
-        # like COW.
-        global _TIER_EXTRACT_PROG
-        if _TIER_EXTRACT_PROG is None:
-            _TIER_EXTRACT_PROG = jax.jit(extract_pool_page)
-        donate = jax.default_backend() != "cpu"
-        inj = _TIER_INJECT_PROGS.get(donate)
-        if inj is None:
-            inj = _TIER_INJECT_PROGS[donate] = jax.jit(
-                inject_pool_page, donate_argnums=(0,) if donate else ())
-        return _TIER_EXTRACT_PROG, inj
+        return pool_jit(prog, self.mesh, self._pool_specs, 1)
 
     def _place_host_slabs(self, slabs):
         """Commit one host page's slab tuple to the pool's placement: on a

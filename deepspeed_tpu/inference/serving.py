@@ -167,7 +167,7 @@ class PoolConsumedError(RuntimeError):
 class SlotPrefillError(RuntimeError):
     """A prefill failed in a way attributable to one slot/request; the
     reservation was unwound and the request re-queued.  When the pool
-    survived (no donation, or the failure fired before the device call) the
+    survived (the failure fired before the device call) the
     engine keeps serving — no restart needed."""
 
     def __init__(self, msg: str, slot: int, rid: Any, quarantined: bool):
@@ -599,8 +599,7 @@ class ServingEngine:
             self._spec = SpeculativeDecoder(
                 speculative, model, self.num_pages, self.page_size,
                 self.b_slots, dtype=dtype, kv_dtype=kv_dtype, mesh=mesh,
-                donate=bool(self._donate), catalog=self._catalog,
-                adapters=adapters)
+                catalog=self._catalog, adapters=adapters)
             if self._cow_prog is not None:
                 # pre-warm the COW jit on the DRAFT pool aval too: a
                 # boundary COW at admission must never compile
@@ -613,8 +612,8 @@ class ServingEngine:
                if mesh is not None else ""), ranks=[0])
 
     # ---------------------------------------------- device-half delegation
-    # The executor owns the pool, the compiled programs and the donation
-    # policy (inference/execution.py).  These views exist for the
+    # The executor owns the pool and the compiled programs
+    # (inference/execution.py).  These views exist for the
     # supervisor's adoption checks, the probe/canary tests that swap a
     # bucket's program, and the speculative tick's pool handoff.
 
@@ -645,10 +644,6 @@ class ServingEngine:
     @property
     def _cow_prog(self):
         return self._exec._cow_prog
-
-    @property
-    def _donate(self):
-        return self._exec._donate
 
     def program_inventory(self) -> Dict[str, Any]:
         """The full set of program shapes this engine has built: one decode
@@ -1394,7 +1389,7 @@ class ServingEngine:
             # they are never quarantined, their references just drop.
             # If the slot did register (failure in the post-launch
             # bookkeeping), it owns the pages and the next run continues
-            # it.  NOTE: with donation enabled a failed DEVICE call also
+            # it.  NOTE: the pool is donated, so a failed DEVICE call also
             # consumes the pool — step() then refuses with
             # PoolConsumedError; the unwind still leaves the queue
             # replayable (ServingSupervisor rebuilds + replays).
@@ -1784,7 +1779,7 @@ class ServingEngine:
                 "serve: canary probe of quarantined slot %d failed "
                 "(%s: %s); slot stays fenced", slot, type(e).__name__, e)
             if not self.pool_alive():
-                # with donation enabled the failed probe ALSO consumed the
+                # the pool is donated: a failed probe device call ALSO consumed the
                 # pool: abort THIS tick — letting it continue into _admit
                 # would feed deleted arrays to a healthy slot's prefill and
                 # misattribute the failure to it.  The supervisor rebuilds,

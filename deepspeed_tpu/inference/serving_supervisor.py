@@ -707,11 +707,10 @@ class ServingSupervisor:
                 and new.num_pages == old.num_pages
                 and new.max_model_len == old.max_model_len
                 and new.kv_dtype == old.kv_dtype
-                and new._donate == old._donate
                 and new.mesh == old.mesh):
             new._exec.adopt_programs(old._exec)
             # _cow_prog needs no adoption: it is the process-global
-            # _COW_PROGS jit, already shared by both engines
+            # _COW_PROG jit, already shared by both engines
             if new._spec is not None and new._spec.compatible(old._spec):
                 # same draft model/k/pool geometry: the speculative
                 # programs are cache hits on the fresh draft pool's avals

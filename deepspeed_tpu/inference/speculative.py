@@ -175,8 +175,8 @@ class SpeculativeDecoder:
 
     def __init__(self, config: SpeculativeConfig, target_model,
                  num_pages: int, page_size: int, b_slots: int,
-                 dtype=None, kv_dtype=None, mesh=None, donate: bool = False,
-                 catalog=None, adapters=None):
+                 dtype=None, kv_dtype=None, mesh=None, catalog=None,
+                 adapters=None):
         from .execution import place_params, pool_bytes
 
         # multi-tenant adapter serving (docs/SERVING.md): the TARGET
@@ -197,7 +197,6 @@ class SpeculativeDecoder:
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.b_slots = int(b_slots)
-        self._donate = bool(donate)
         self._mesh = mesh
         tp = int(mesh.shape.get("model", 1)) if mesh is not None else 1
         if tp > 1 and self.draft_model.config.kv_heads % tp != 0:
@@ -247,9 +246,8 @@ class SpeculativeDecoder:
                 jax.device_put(cache[k], cache[k].sharding)
                 for k in self._pool_keys)
         self.pool_bytes = pool_bytes(*self.dpools)
-        dn = (1,) if donate else ()
-        self._draft_prog = self._build_draft(dn)
-        self._verify_prog = self._build_verify(target_model, dn)
+        self._draft_prog = self._build_draft()
+        self._verify_prog = self._build_verify(target_model)
         self._draft_prefill_progs: Dict[int, Any] = {}
         # rolling stats: mean accepted length = emitted / verify slot-ticks
         self.verify_slot_ticks = 0
@@ -258,7 +256,7 @@ class SpeculativeDecoder:
 
     # ----------------------------------------------------------- programs
 
-    def _build_draft(self, donate):
+    def _build_draft(self):
         draft_apply = self.draft_model.apply_paged
 
         def prog(dparams, dpools, page_table, pos, tok, active,
@@ -278,7 +276,7 @@ class SpeculativeDecoder:
 
         from .execution import pool_jit
 
-        return pool_jit(prog, donate, self._mesh, self._pool_specs, 2)
+        return pool_jit(prog, self._mesh, self._pool_specs, 2)
 
     def _build_draft_prefill(self, s_pad: int):
         draft_apply = self.draft_model.apply_paged
@@ -293,10 +291,9 @@ class SpeculativeDecoder:
 
         from .execution import pool_jit
 
-        return pool_jit(prog, (1,) if self._donate else (), self._mesh,
-                        self._pool_specs, 0)
+        return pool_jit(prog, self._mesh, self._pool_specs, 0)
 
-    def _build_verify(self, target_model, donate):
+    def _build_verify(self, target_model):
         target_apply = target_model.apply_paged
         k = self.k
         with_adapters = self.adapters is not None
@@ -376,8 +373,7 @@ class SpeculativeDecoder:
         # the verify pass consumes and reproduces the TARGET pool: its
         # output pools pin to the target's canonical shardings, same as
         # the plain decode tick's
-        return pool_jit(prog, donate, self._mesh, self._target_pool_specs,
-                        2)
+        return pool_jit(prog, self._mesh, self._target_pool_specs, 2)
 
     def program_inventory(self) -> Dict[str, Any]:
         return {"k": self.k, "draft_decode": 1, "verify": 1,
@@ -499,8 +495,7 @@ class SpeculativeDecoder:
                 and self.num_pages == other.num_pages
                 and self.page_size == other.page_size
                 and self.b_slots == other.b_slots
-                and self.kv_dtype == other.kv_dtype
-                and self._donate == other._donate)
+                and self.kv_dtype == other.kv_dtype)
 
     def adopt_programs(self, old: "SpeculativeDecoder") -> None:
         """Warm-restart path: carry the dead engine's compiled speculative

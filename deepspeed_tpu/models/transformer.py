@@ -662,7 +662,7 @@ def _sharded_flash(mesh, spec, sm_scale, q, k, v):
     from ..ops.pallas.flash_attention import flash_attention
     from ..parallel import mesh as mesh_mod
 
-    fa = mesh_mod.shard_map_compat(
+    fa = mesh_mod.shard_map_unchecked(
         functools.partial(flash_attention, causal=True, sm_scale=sm_scale),
         mesh, in_specs=(spec, spec, spec), out_specs=spec)
     # kernel may widen to f32; cast HERE so the tp and ulysses call sites
@@ -752,40 +752,52 @@ def _attention(cfg: TransformerConfig, q, k, v, positions, attn_impl: str = "xla
         v = constrain_spec(v, head_spec)
         out = _sharded_flash(m, head_spec, _sm_scale(cfg, hd), q, k, v)
         return constrain_spec(out, P(BATCH_AXES, "seq", "model", None))
-    if attn_impl == "auto":
-        # Measured on v5e (B=8,H=16,hd=64, bf16, fwd + fwd‖bwd):
+    if attn_impl in ("auto", "pallas"):
+        from ..parallel import mesh as mesh_mod
+
+        m = mesh_mod._GLOBAL_MESH
+        sharded = m is not None and any(s > 1 for s in m.shape.values())
+        # The flash kernel masks by row/col index, so it requires default
+        # positions (packed sequences carry custom ids); bias and windows
+        # are not fused.  pallas_call has no SPMD partitioning rule, so on a
+        # mesh it runs per-shard via shard_map — batch over DP axes, heads
+        # over 'model' — and needs the full sequence per shard (ring
+        # attention, above, covers the seq-sharded case).
+        checks = [("S % 128", S % 128 == 0),
+                  ("causal", bool(cfg.causal)),
+                  ("non-alibi", cfg.position != "alibi"),
+                  ("default positions", not custom_positions),
+                  ("no window", window is None)]
+        if sharded:
+            tp = m.shape["model"]
+            dp = mesh_mod.axis_size(m, BATCH_AXES)
+            checks += [("seq=1", m.shape["seq"] == 1),
+                       ("pipe=1", m.shape["pipe"] == 1),
+                       (f"Hq={Hq} % tp={tp}", Hq % tp == 0),
+                       (f"Hkv={Hkv} % tp={tp}", Hkv % tp == 0),
+                       (f"B={B} % dp={dp}", B % dp == 0)]
+        failed = [c for c, ok in checks if not ok]
+        if attn_impl == "pallas" and failed:
+            raise ValueError(
+                f"pallas attention requested but unsatisfiable: {failed} "
+                f"(S={S}, mesh={'none' if m is None else dict(m.shape)})")
+        # "auto": measured on v5e (B=8,H=16,hd=64, bf16, fwd + fwd‖bwd):
         #   S=1024: xla 13.9ms vs pallas 15.9ms  — xla wins
         #   S=2048: xla 32.0ms vs pallas 29.8ms  — pallas wins (B=4: +18%)
         #   S=4096: xla 50.4ms vs pallas 25.5ms  — pallas 2x
         # The flash kernel takes over once the materialized [S,S] scores
-        # dominate; below that XLA's fused einsum path is faster.
-        attn_impl = "pallas" if S >= 2048 else "xla"
-    # The flash kernel masks by row/col index, so it requires default
-    # positions; custom position ids (packed sequences) use the XLA path.
-    if attn_impl == "pallas" and cfg.position != "alibi" and cfg.causal \
-            and not custom_positions and window is None:
-        from ..ops.pallas.flash_attention import flash_attention
-        from ..parallel import mesh as mesh_mod
-
-        sm = _sm_scale(cfg, hd)
-        m = mesh_mod._GLOBAL_MESH
-        sharded = m is not None and any(s > 1 for s in m.shape.values())
-        if not sharded:
-            if S % 128 == 0:
-                # GQA handled in-kernel (KV-head index map), no repeat
-                return flash_attention(q, k, v, causal=True, sm_scale=sm)
-        else:
-            # pallas_call has no SPMD partitioning rule — run it per-shard
-            # via shard_map: batch over DP axes, heads over 'model'.  Dense
-            # flash needs the full sequence per shard (ring attention covers
-            # the seq-sharded case); 'seq'/'pipe' meshes fall back to XLA.
-            tp = m.shape["model"]
-            dp = mesh_mod.axis_size(m, BATCH_AXES)
-            ok = (S % 128 == 0 and m.shape["seq"] == 1 and m.shape["pipe"] == 1
-                  and Hq % tp == 0 and Hkv % tp == 0 and B % dp == 0)
-            if ok:
+        # dominate; below that XLA's fused einsum path is faster.  The
+        # choice reads the shape and the mesh only — never which device
+        # answered.
+        if attn_impl == "pallas" or (S >= 2048 and not failed):
+            sm = _sm_scale(cfg, hd)
+            if sharded:
                 return _sharded_flash(m, P(BATCH_AXES, None, "model", None),
                                       sm, q, k, v)
+            from ..ops.pallas.flash_attention import flash_attention
+
+            # GQA handled in-kernel (KV-head index map), no repeat
+            return flash_attention(q, k, v, causal=True, sm_scale=sm)
     if Hkv != Hq:  # GQA: repeat KV groups
         rep = Hq // Hkv
         k = jnp.repeat(k, rep, axis=2)

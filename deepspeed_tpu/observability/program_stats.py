@@ -128,8 +128,6 @@ class ProgramCatalog:
         flops = by = 0.0
         try:
             ca = jitted.lower(*args).cost_analysis()
-            if isinstance(ca, (list, tuple)):   # older jax returns [dict]
-                ca = ca[0] if ca else {}
             flops = float((ca or {}).get("flops", 0.0) or 0.0)
             by = float((ca or {}).get("bytes accessed", 0.0) or 0.0)
         except Exception as e:   # accounting never gates the program

@@ -7,8 +7,8 @@ conventions that DO repeat across kernels live here so they stay aligned:
 
   - ``NEG_INF`` — the masking constant (finite: ``-inf`` breaks the online
     softmax's ``exp(m_prev - m_new)`` rescale when a whole block is masked).
-  - ``interpret_default()`` — interpret mode on CPU hosts so the unit suite
-    runs kernels without hardware.
+  - ``resolve_interpret()`` — kernels are compiled unless interpret mode is
+    asked for by name; a host without a TPU is an error, not a reason.
   - ``pick_block()`` — largest power-of-two tile that divides the axis.
   - ``mask_to_i32()`` — masks cross the pallas_call boundary as int32 and
     are compared ``!= 0`` in-kernel: bool memref tiling is a Mosaic
@@ -18,16 +18,39 @@ conventions that DO repeat across kernels live here so they stay aligned:
 """
 from __future__ import annotations
 
+import os
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# Set to "1" by tests/conftest.py and by chip_smoke.py's CPU rehearsal (an
+# env var so the scripts those spawn inherit it).  Nothing else sets it.
+INTERPRET_ENV = "DS_TPU_PALLAS_INTERPRET"
 
-def interpret_default() -> bool:
-    """Kernels run in interpret mode when no TPU is attached."""
-    return jax.devices()[0].platform == "cpu"
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """Whether a kernel runs in Pallas interpret mode.
+
+    An explicit argument wins; otherwise interpret mode is on only when
+    ``DS_TPU_PALLAS_INTERPRET=1`` asks for it.  It is never inferred from the
+    devices present: with no TPU attached and no request, this raises rather
+    than hand back a slow imitation of the kernel.  (``interpret=False`` is
+    not checked, so a kernel can be AOT-compiled against a TPU topology from
+    a CPU host.)"""
+    if interpret is not None:
+        return interpret
+    if os.environ.get(INTERPRET_ENV) == "1":
+        return True
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "Pallas kernels compile for the TPU and none is attached "
+            f"(backend {jax.default_backend()!r}).  Tests and rehearsals ask "
+            f"for interpret mode by name: {INTERPRET_ENV}=1 or interpret=True.")
+    return False
 
 
 def pick_block(n: int, want: int, floor: int = 8) -> int:
@@ -57,9 +80,6 @@ def mask_to_i32(mask) -> jax.Array:
 def parallel_semantics(n_parallel: int, n_arbitrary: int = 1):
     """CompilerParams for an n-axis grid: leading axes independent, the
     trailing axes carrying accumulator state across iterations."""
-    # jax renamed TPUCompilerParams -> CompilerParams; support both
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams")
-    return params_cls(
+    return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * n_parallel
         + ("arbitrary",) * n_arbitrary)

@@ -58,8 +58,8 @@ DEFAULT_BLOCK_K = _env_block("DS_TPU_FLASH_BLOCK_K", 2048)
 # backward tiles: min(fwd tile, this) — the bwd kernels compile reliably at 1024
 DEFAULT_BWD_BLOCK = _env_block("DS_TPU_FLASH_BWD_BLOCK", 1024)
 
-from .common import (NEG_INF, interpret_default as _interpret_default,  # noqa: E402
-                     parallel_semantics, pick_block as _pick_block)
+from .common import (NEG_INF, parallel_semantics,  # noqa: E402
+                     pick_block as _pick_block, resolve_interpret)
 
 # The first three grid axes are independent in every kernel here; only the
 # INNERMOST axis carries accumulator state (the K sweep in _fwd/_bwd_dq, the
@@ -501,8 +501,7 @@ def flash_attention(q, k, v, causal: bool = True, sm_scale: Optional[float] = No
         bwd_block_k = _pick_block(S, bwd_block_k or min(block_k, DEFAULT_BWD_BLOCK))
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    if interpret is None:
-        interpret = _interpret_default()
+    interpret = resolve_interpret(interpret)
     # [B,S,H,hd] -> [B,H,S,hd]
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
     if return_lse:

@@ -141,10 +141,10 @@ def ring_attention_sharded(q, k, v, mesh, batch_axes, causal: bool = True,
     ``head_axis``."""
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map_compat
+    from ..parallel.mesh import shard_map_unchecked
 
     spec = P(batch_axes, seq_axis, head_axis, None)
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         functools.partial(ring_attention, axis_name=seq_axis, causal=causal,
                           sm_scale=sm_scale, impl=impl),
         mesh, in_specs=(spec, spec, spec), out_specs=spec)

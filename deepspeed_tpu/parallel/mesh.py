@@ -98,23 +98,21 @@ def build_mesh(layout: MeshLayout, devices: Optional[Sequence[jax.Device]] = Non
     """Construct the global Mesh for a layout.
 
     Uses ``jax.experimental.mesh_utils`` for ICI-topology-aware device
-    assignment on real TPU slices; falls back to row-major reshape on the host
-    platform (simulated meshes) where physical topology doesn't exist.
+    assignment on TPU slices — a layout the topology cannot carry is an
+    error there, not a silent row-major order.  The host platform (simulated
+    meshes) has no physical topology, so it reshapes row-major.
     """
     if devices is None:
         devices = jax.devices()
     shape = layout.axis_sizes()
     if layout.world_size != len(devices):
         raise ValueError(f"layout needs {layout.world_size} devices, got {len(devices)}")
-    try:
+    if devices[0].platform == "cpu":
+        dev_array = np.asarray(list(devices)).reshape(shape)
+    else:
         from jax.experimental import mesh_utils
 
-        if devices[0].platform not in ("cpu",):
-            dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-        else:
-            raise ValueError  # host platform: no physical topology to optimize
-    except Exception:
-        dev_array = np.asarray(list(devices)).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
     return Mesh(dev_array, MESH_AXES)
 
 
@@ -198,20 +196,11 @@ def named(mesh: Mesh, spec: P) -> NamedSharding:
     return NamedSharding(mesh, spec)
 
 
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """shard_map across jax versions (jax.shard_map vs experimental;
-    check_vma vs check_rep), with replication checking off — manual regions
+def shard_map_unchecked(fn, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the varying-axes check off — manual regions
     here wrap collectives/pallas calls the checker can't analyze."""
-    import inspect
-
-    try:
-        from jax import shard_map as _sm
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    kw = ("check_vma" if "check_vma" in inspect.signature(_sm).parameters
-          else "check_rep")
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               **{kw: False})
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 _IN_MANUAL_REGION = False

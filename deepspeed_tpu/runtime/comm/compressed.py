@@ -128,7 +128,7 @@ def make_compressed_grad_fn(accumulate, mesh, gas: int, freeze_step: int,
     -> (mean_grads, losses, new_error); ``batch_window`` is [gas, B_global,...].
     Requires a pure-DP mesh (engine validates).
     """
-    from ...parallel.mesh import manual_region, shard_map_compat
+    from ...parallel.mesh import manual_region, shard_map_unchecked
     from ...parallel.mesh import BATCH_AXES
 
     pads = jax.tree_util.tree_map(lambda x: _pad_len(x.size, block),
@@ -171,7 +171,7 @@ def make_compressed_grad_fn(accumulate, mesh, gas: int, freeze_step: int,
     err_specs = error_tree_specs(param_template)
     # window leaves are [gas, B_global, ...]: shard dim 1 over the DP axes
     # (prefix spec broadcasts over every batch leaf)
-    sm = shard_map_compat(
+    sm = shard_map_unchecked(
         region, mesh,
         in_specs=(rep, P(), P(None, BATCH_AXES), P(), err_specs, P()),
         out_specs=(rep, P(), err_specs))

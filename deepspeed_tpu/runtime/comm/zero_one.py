@@ -104,7 +104,7 @@ def make_zero_one_step(accumulate, mesh, gas: int, compute_dtype,
     ``accumulate`` is the shared microbatch scan (grads are
     loss_scale*gas-scaled sums; this path unscales in-region since it owns
     the whole update)."""
-    from ...parallel.mesh import BATCH_AXES, manual_region, shard_map_compat
+    from ...parallel.mesh import BATCH_AXES, manual_region, shard_map_unchecked
 
     b1, b2 = hyper.get("betas", (0.9, 0.999))
     eps = hyper.get("eps", 1e-8)
@@ -275,7 +275,7 @@ def make_zero_one_step(accumulate, mesh, gas: int, compute_dtype,
         exp_avg=perw, exp_avg_sq=repz, delta=perw, error=perw,
         lrs=P(), var_interval=P(), var_counter=P(),
         local_interval=P(), local_counter=P())
-    sm = shard_map_compat(
+    sm = shard_map_unchecked(
         region, mesh,
         in_specs=(rep, P(), P(None, BATCH_AXES), P(), zo_specs, P(), P()),
         out_specs=(rep, zo_specs, P(), P()))

@@ -106,9 +106,7 @@ class HostSteppedOffload:
 
     Step cost = one fp32-grad download + one bf16-param upload per step
     (params bytes x6 round trip) — ~0.4s/step for a 1B model over a TPU-VM's
-    local PCIe.  On remote/tunneled device backends that link can be orders
-    of magnitude slower; offload throughput follows the host link, by
-    construction.
+    local PCIe; offload throughput follows the host link, by construction.
     """
 
     def __init__(self, config, master, param_shardings, storage: str,

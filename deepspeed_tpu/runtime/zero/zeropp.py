@@ -102,7 +102,7 @@ def make_zeropp_cast(master_specs: Any, param_specs: Any, mesh, compute_dtype,
                          intra-then-inter quantized path
     The master spec (not the param spec) locates the sharded dim, so the
     hpZ case — where the compute view drops 'data_outer' — still finds it."""
-    from ...parallel.mesh import shard_map_compat
+    from ...parallel.mesh import shard_map_unchecked
 
     def leaf_fn(master_spec: P, param_spec: P):
         from ...parallel.mesh import BATCH_AXES
@@ -128,7 +128,7 @@ def make_zeropp_cast(master_specs: Any, param_specs: Any, mesh, compute_dtype,
             _quantized_gather_leaf, axis_names=axes, gather_dim=dim,
             compute_dtype=compute_dtype, weight_bits=weight_bits,
             grad_bits=grad_bits, block=block, grad_hierarchy=grad_hierarchy)
-        return shard_map_compat(region, mesh, in_specs=(master_spec,),
+        return shard_map_unchecked(region, mesh, in_specs=(master_spec,),
                                 out_specs=_strip_axes(master_spec, zero_axes))
 
     gathers = jax.tree_util.tree_map(

@@ -184,33 +184,3 @@ def assert_deterministic(fn: Callable, *args, what: str = "fn") -> Any:
             f"{what} is nondeterministic: {len(diff)} output leaves changed "
             f"between identical calls (first: {diff[:5]})")
     return out1
-
-
-def probe_device_count(timeout_s: float = 60.0):
-    """(device_count | None, error | None) without risking a hang.
-
-    A wedged remote/tunneled backend BLOCKS inside backend init rather than
-    raising, so the probe runs on a daemon thread (an executor's shutdown —
-    or interpreter exit with a non-daemon worker — would re-join the stuck
-    thread and reintroduce the hang).  None count = probe timed out; an
-    exception is returned, not collapsed.  Shared by ds_report's device
-    inventory and the driver dryrun's mesh-provisioning decision.
-    """
-    import threading
-
-    box = {}
-
-    def probe():
-        try:
-            import jax
-
-            box["n"] = jax.device_count()
-        except Exception as e:  # no backend / init raised
-            box["err"] = e
-
-    t = threading.Thread(target=probe, daemon=True)
-    t.start()
-    t.join(timeout_s)
-    if t.is_alive():
-        return None, None
-    return box.get("n"), box.get("err")

@@ -10,6 +10,7 @@ import numpy as np
 
 import deepspeed_tpu
 from deepspeed_tpu.models import CausalLM
+from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
 
 def main():
@@ -21,6 +22,7 @@ def main():
     ap.add_argument("--prompt_len", type=int, default=64)
     ap.add_argument("--new_tokens", type=int, default=64)
     args = ap.parse_args()
+    place_compile_cache()
 
     if args.hf:
         engine = deepspeed_tpu.init_inference(model=args.hf)

@@ -28,6 +28,7 @@ import deepspeed_tpu
 from deepspeed_tpu.models import CausalLM
 from deepspeed_tpu.runtime.hybrid_engine import DeepSpeedHybridEngine
 from deepspeed_tpu.runtime.lora import LoRAConfig, LoRAModel
+from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
 
 def main():
@@ -44,6 +45,7 @@ def main():
                          "(RolloutEngine, docs/HYBRID.md) instead of "
                          "sequential generate()")
     args = ap.parse_args()
+    place_compile_cache()
 
     base = CausalLM(args.model, max_seq_len=128)
     base_params = base.init_fn(jax.random.PRNGKey(0))

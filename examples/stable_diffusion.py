@@ -23,6 +23,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.diffusers import DSUNet, DSVAE
 from deepspeed_tpu.models.diffusion import TINY_UNET, TINY_VAE
+from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
 
 def main():
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--size", type=int, default=16, help="latent H=W")
     ap.add_argument("--batch", type=int, default=1)
     args = ap.parse_args()
+    place_compile_cache()
 
     unet = DSUNet(TINY_UNET, data_format="NHWC")
     vae = DSVAE(TINY_VAE, data_format="NHWC")

@@ -16,6 +16,7 @@ import numpy as np
 
 import deepspeed_tpu
 from deepspeed_tpu.models import CausalLM
+from deepspeed_tpu.utils.compile_cache import place_compile_cache
 
 
 def synthetic_batches(vocab, batch, seq, steps, seed=0):
@@ -32,6 +33,7 @@ def main():
     ap.add_argument("--ckpt_dir", default=None)
     ap = deepspeed_tpu.add_config_arguments(ap)
     args = ap.parse_args()
+    place_compile_cache()
 
     deepspeed_tpu.init_distributed()
     model = CausalLM(args.model, max_seq_len=args.seq_len)

@@ -1,0 +1,102 @@
+"""What can be known about the chip path without a chip — so a kernel the
+compiler would refuse, a fallback that would hide a missing TPU, or a smoke
+that passes on the CPU fails here before it costs chip time."""
+import os
+import subprocess
+import sys
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def test_flash_kernels_compile_for_v5e():
+    """AOT: the flash forward and backward, ``interpret=False``, compiled by
+    the installed libtpu for a compile-only v5e device.  One program — the
+    gradient's — holds all three kernels (forward, dq sweep, dkv sweep)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"TPU topology unavailable for AOT compile: {e}")
+    # llama-740m's head shape at the smoke's sequence length: the forward
+    # sweeps two K blocks and the backward four
+    spec = jax.ShapeDtypeStruct(
+        (1, 4096, 2, 128), jnp.bfloat16,
+        sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        spec, spec, spec)
+    assert lowered.as_text().count("tpu_custom_call") == 3
+    lowered.compile()           # Mosaic accepts the kernels, or this raises
+
+
+def test_interpret_mode_is_asked_for_never_inferred(monkeypatch):
+    from deepspeed_tpu.ops.pallas.common import (INTERPRET_ENV,
+                                                 resolve_interpret)
+
+    assert resolve_interpret() is True          # conftest asked, by name
+    assert resolve_interpret(False) is False    # explicit argument wins
+    monkeypatch.delenv(INTERPRET_ENV)
+    # a CPU host and no request: an error, not a slow imitation
+    with pytest.raises(RuntimeError, match="none is attached"):
+        resolve_interpret()
+    assert resolve_interpret(True) is True
+
+
+def test_explicit_pallas_raises_when_it_cannot_be_honoured():
+    from deepspeed_tpu.models import forward, get_config, init_params
+
+    cfg = get_config("tiny", dtype=jnp.float32)
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    ragged = jax.ShapeDtypeStruct((2, 100), jnp.int32)   # 100 % 128 != 0
+
+    def run(impl):
+        return jax.eval_shape(
+            lambda p, t: forward(cfg, p, t, attn_impl=impl), params, ragged)
+
+    with pytest.raises(ValueError, match="pallas attention requested"):
+        run("pallas")
+    assert run("auto").shape == (2, 100, cfg.vocab_size)   # auto still chooses
+
+
+def test_compile_cache_is_placed_from_outside_or_at_one_fixed_path(
+        monkeypatch, tmp_path):
+    from deepspeed_tpu.utils import compile_cache
+
+    written = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *kv: written.append(kv))
+    monkeypatch.setenv(compile_cache.CACHE_DIR_ENV, str(tmp_path))
+    assert compile_cache.place_compile_cache() == str(tmp_path)
+    assert written == []                    # env set: no config written
+
+    monkeypatch.delenv(compile_cache.CACHE_DIR_ENV)
+    fixed = os.path.join(REPO, ".jax_compile_cache")
+    assert compile_cache.place_compile_cache() == fixed
+    assert ("jax_compilation_cache_dir", fixed) in written
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_compile_cache/" in f.read().split()
+
+
+def test_chip_smoke_refuses_the_cpu():
+    """The plain invocation on a host without a TPU: non-zero, no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "chip_smoke.py"], cwd=REPO,
+                          env=env, capture_output=True, text=True,
+                          timeout=120)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr

@@ -7,11 +7,11 @@ from jax.sharding import PartitionSpec as P
 
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.parallel import initialize_mesh
-from deepspeed_tpu.parallel.mesh import shard_map_compat
+from deepspeed_tpu.parallel.mesh import shard_map_unchecked
 
 
 def _shmap(mesh, fn, in_specs, out_specs):
-    return jax.jit(shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
+    return jax.jit(shard_map_unchecked(fn, mesh=mesh, in_specs=in_specs,
                                     out_specs=out_specs))
 
 

@@ -209,19 +209,6 @@ def test_ds_report_runs():
     assert "Device inventory" in out.stdout
 
 
-def test_simulate_cmd_wraps_with_cpu_bootstrap():
-    from deepspeed_tpu.launcher.runner import _simulate_cmd, parse_args
-
-    args = parse_args(["--simulate", "2", "train.py", "--lr", "0.1"])
-    cmd = _simulate_cmd(args)
-    assert cmd[2] == "-c" and "jax_platforms" in cmd[3]
-    assert cmd[-3:] == ["train.py", "--lr", "0.1"]
-
-    margs = parse_args(["--simulate", "2", "--module", "pkg.train"])
-    mcmd = _simulate_cmd(margs)
-    assert "run_module" in mcmd[3] and mcmd[-1] == "pkg.train"
-
-
 def test_ds_ssh_local_fallback(tmp_path, capsys):
     from deepspeed_tpu.launcher.ds_ssh import main
 

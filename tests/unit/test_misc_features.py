@@ -165,7 +165,7 @@ def test_sparse_allreduce_over_data_axis():
         st = sparse_allreduce(SparseTensor(r, v, dense_rows=V), "data")
         return st.to_dense()
 
-    f = mesh_mod.shard_map_compat(
+    f = mesh_mod.shard_map_unchecked(
         region, mesh, in_specs=(P(("data_outer", "data", "expert")),
                                 P(("data_outer", "data", "expert"), None)),
         out_specs=P())

@@ -12,7 +12,7 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.ops.quantizer import (dequantize_blockwise, quantize_blockwise,
                                          quantized_all_gather,
                                          quantized_reduce_scatter)
-from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh, shard_map_compat
+from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh, shard_map_unchecked
 
 
 @pytest.mark.parametrize("bits", [8, 4])
@@ -52,7 +52,7 @@ def test_quantized_all_gather_matches_fp32_gather():
     rng = np.random.default_rng(2)
     x = rng.standard_normal((64, 16)).astype(np.float32)
 
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         functools.partial(quantized_all_gather, axis_name="data", block=64),
         mesh, in_specs=(P("data"),), out_specs=P())
     y = np.asarray(fn(jnp.asarray(x)))
@@ -74,7 +74,7 @@ def test_quantized_all_gather_gradient_is_reduce_scatter():
         return jax.grad(lambda s: jnp.sum(
             quantized_all_gather(s, "data", block=8) ** 2) / 2)(xs)
 
-    g = shard_map_compat(inner, mesh, in_specs=(P("data"),),
+    g = shard_map_unchecked(inner, mesh, in_specs=(P("data"),),
                          out_specs=P("data"))(jnp.asarray(x))
     scale_bound = np.abs(x).max() / 127
     assert np.abs(np.asarray(g) - 8 * x).max() <= 8 * scale_bound * 0.5 + 1e-6
@@ -91,7 +91,7 @@ def test_quantized_reduce_scatter_close_to_exact(bits):
     # exact answer is 8 * grad scattered.
     g = rng.standard_normal((64, 8)).astype(np.float32)
 
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         functools.partial(quantized_reduce_scatter, axis_name="data",
                           block=32, bits=bits),
         mesh, in_specs=(P(),), out_specs=P("data"))
@@ -117,14 +117,14 @@ def test_hierarchical_reduce_scatter_sum_and_landing():
     from jax.sharding import Mesh, PartitionSpec as P
 
     from deepspeed_tpu.ops.quantizer import hierarchical_quantized_reduce_scatter
-    from deepspeed_tpu.parallel.mesh import shard_map_compat
+    from deepspeed_tpu.parallel.mesh import shard_map_unchecked
 
     mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("do", "d"))
     rng = np.random.default_rng(0)
     L, K = 32, 3
     locals_ = rng.standard_normal((8, L, K)).astype(np.float32)
 
-    f = shard_map_compat(
+    f = shard_map_unchecked(
         lambda x: hierarchical_quantized_reduce_scatter(
             x, "d", "do", scatter_dim=0, block=16),
         mesh, in_specs=(P(("do", "d"), None),),

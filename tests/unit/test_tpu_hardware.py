@@ -15,13 +15,39 @@ import jax.numpy as jnp
 pytestmark = pytest.mark.tpu
 
 _ON_TPU = (os.environ.get("DS_TPU_REAL_TESTS") == "1"
-           and jax.devices()[0].platform not in ("cpu",))
+           and jax.devices()[0].platform == "tpu")
 
 
 @pytest.fixture(autouse=True)
 def _require_tpu():
     if not _ON_TPU:
         pytest.skip("needs DS_TPU_REAL_TESTS=1 and a real TPU device")
+
+
+def test_block_until_ready_waits():
+    """``block_until_ready`` joins the computation on the attached chip: a
+    ~27 TFLOP matmul chain timed to it takes as long as the same chain timed
+    to a scalar fetch (tools/chiptimer.py's join).  Timing tools may use
+    either."""
+    import time
+
+    a = jnp.full((4096, 4096), 1.0, jnp.bfloat16)
+
+    @jax.jit
+    def chain(a):
+        return jax.lax.fori_loop(
+            0, 200, lambda _, c: (c @ a) * (1.0 / 4096.0), a)
+
+    float(chain(a)[0, 0])                      # compile + first fetch
+    t0 = time.perf_counter()
+    jax.block_until_ready(chain(a))
+    t_bur = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    float(chain(a)[0, 0])
+    t_fetch = time.perf_counter() - t0
+    # 200 x 2*4096^3 FLOP is >= 0.13 s at the v5e's 197 TFLOP/s peak; an
+    # early return would read as microseconds
+    assert t_bur > 0.1 and t_bur > 0.5 * t_fetch, (t_bur, t_fetch)
 
 
 def test_flash_attention_mxu_parity():

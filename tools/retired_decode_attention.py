@@ -43,8 +43,7 @@ DEFAULT_BLOCK_T = 512
 import os as _os, sys as _sys  # noqa: E402
 _sys.path.insert(0, _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))))
 from deepspeed_tpu.ops.pallas.common import (  # noqa: E402
-    NEG_INF, interpret_default as _interpret_default, mask_to_i32,
-    parallel_semantics)
+    NEG_INF, mask_to_i32, parallel_semantics, pick_block, resolve_interpret)
 
 # B is independent; the T sweep carries the online-softmax state.
 _COMPILER_PARAMS = parallel_semantics(1, 1)
@@ -113,13 +112,11 @@ def flash_decode(q: jax.Array, ck: jax.Array, cv: jax.Array, mask: jax.Array,
         raise NotImplementedError(
             f"cache length {T} must be a multiple of 128 (lane-aligned "
             "blocks); use the XLA path")
-    from .common import pick_block
-
     bt = pick_block(T, block_t, floor=128)
     blocks_t = T // bt
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(hd)
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
 
     qg = q.reshape(B, Hkv, G, hd)
     out = pl.pallas_call(
