@@ -20,13 +20,17 @@ result line:
             (too big for one chip, so running at all proves the partitioning),
             and a tensor-sharded serving engine
 
-The last line of stdout is one JSON object.  It carries counts, losses, bytes
-and set-up (compile) seconds — no rate, utilisation or TFLOP/s: this script
-measures nothing, and ``"claim"`` is always null.
+Stdout ends with two lines of JSON (the library's log lines come before).
+The first is the summary: counts, losses, bytes
+and set-up (compile) seconds per leg — no rate, utilisation or TFLOP/s: this
+script measures nothing, and ``"claim"`` is always null.  The last is the
+verdict and holds nothing else:
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
 The rehearsal drives the same code at toy sizes on the CPU so chip time is not
-spent on typos.  Its result line has no ``"ok"`` key and says
-``"platform": "cpu"``: it proves nothing about the chip.
+spent on typos.  It prints its summary, which says ``"platform": "cpu"``, and
+no verdict: it proves nothing about the chip.
 """
 from __future__ import annotations
 
@@ -498,7 +502,7 @@ def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearse-cpu", action="store_true",
                     help="tiny sizes on the CPU with interpret-mode kernels; "
-                         "the result says platform cpu and has no 'ok'")
+                         "the summary says platform cpu; no verdict line")
     args = ap.parse_args()
     if args.rehearse_cpu:
         # before jax is imported; all three are asked for by name
@@ -567,13 +571,14 @@ def main():
           f"native ops on the main path: loaded {loaded}, built {built}")
     summary["native_ops_loaded"] = len(loaded)
     summary["wall_s"] = round(time.monotonic() - t_start, 1)   # set-up info
-    if on_tpu:
-        summary = {"ok": True, **summary}
-    else:
+    if not on_tpu:
         summary["rehearsal"] = ("CPU, toy sizes, interpret-mode kernels: "
                                 "control flow only")
     summary["claim"] = None
     print(json.dumps(summary))
+    if on_tpu:
+        # the verdict: exactly these keys, the device as JAX reports it
+        print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 
