@@ -99,6 +99,15 @@ def pool_jit(fn, mesh, pool_specs, n_leading: int):
                    out_shardings=tuple([rep] * n_leading) + (pools,))
 
 
+def _named_pool_jit(prog, name: str, mesh, pool_specs):
+    """``pool_jit`` for a serving program that returns (tokens, pools),
+    under a stable name: the XLA module is ``jit_<name>`` in a device
+    trace, a compile log and an HLO dump, whatever the Python closure that
+    built it is called (docs/OBSERVABILITY.md "Device-time correlation")."""
+    prog.__name__ = prog.__qualname__ = name
+    return pool_jit(prog, mesh, pool_specs, 1)
+
+
 def place_params(params, mesh):
     """Commit a param tree to its auto-TP shardings on ``mesh`` (reuses
     :func:`~.engine.auto_tp_specs` — the same Megatron-style split
@@ -304,11 +313,14 @@ class MeshExecutor:
                 logits, cache = apply_paged(
                     params, last_tok[:, None], cache, page_table, lengths,
                     active[:, None], adapters=adapters)
-                nxt = sample_tokens(logits[:, -1, :], temp, top_k, top_p,
-                                    lambda: position_keys(seeds, lengths + 1))
+                with jax.named_scope("sample"):
+                    nxt = sample_tokens(
+                        logits[:, -1, :], temp, top_k, top_p,
+                        lambda: position_keys(seeds, lengths + 1))
                 return nxt, paged_pool_tuple(cache)
 
-            return pool_jit(prog, self.mesh, self._pool_specs, 1)
+            return _named_pool_jit(prog, "serve_decode", self.mesh,
+                                   self._pool_specs)
 
         def prog(params, pools, page_table, lengths, last_tok, active,
                  temp, top_k, top_p, seeds):
@@ -322,11 +334,13 @@ class MeshExecutor:
             cache = paged_pool_cache(pools)
             logits, cache = apply_paged(params, last_tok[:, None], cache,
                                         page_table, lengths, active[:, None])
-            nxt = sample_tokens(logits[:, -1, :], temp, top_k, top_p,
-                                lambda: position_keys(seeds, lengths + 1))
+            with jax.named_scope("sample"):
+                nxt = sample_tokens(logits[:, -1, :], temp, top_k, top_p,
+                                    lambda: position_keys(seeds, lengths + 1))
             return nxt, paged_pool_tuple(cache)
 
-        return pool_jit(prog, self.mesh, self._pool_specs, 1)
+        return _named_pool_jit(prog, "serve_decode", self.mesh,
+                               self._pool_specs)
 
     def _build_prefill(self, s_pad: int):
         apply_paged = self.model.apply_paged
@@ -340,13 +354,16 @@ class MeshExecutor:
                 logits, cache = apply_paged(params, tokens, cache, pt_row,
                                             start[None], seq_mask,
                                             adapters=adapters)
-                lg = logits[0, n_real - 1, :][None]        # [1, V]
-                nxt = sample_tokens(
-                    lg, temp, top_k, top_p,
-                    lambda: position_keys(seed, (start + n_real)[None]))[0]
+                with jax.named_scope("sample"):
+                    lg = logits[0, n_real - 1, :][None]        # [1, V]
+                    nxt = sample_tokens(
+                        lg, temp, top_k, top_p,
+                        lambda: position_keys(seed,
+                                              (start + n_real)[None]))[0]
                 return nxt, paged_pool_tuple(cache)
 
-            return pool_jit(prog, self.mesh, self._pool_specs, 1)
+            return _named_pool_jit(prog, f"serve_prefill_{s_pad}", self.mesh,
+                                   self._pool_specs)
 
         def prog(params, pools, pt_row, tokens, n_real, start,
                  temp, top_k, top_p, seed):
@@ -364,16 +381,18 @@ class MeshExecutor:
             cache = paged_pool_cache(pools)
             logits, cache = apply_paged(params, tokens, cache, pt_row,
                                         start[None], seq_mask)
-            lg = logits[0, n_real - 1, :][None]        # [1, V]
             # the emitted token will sit at stream position S = start +
             # n_real — the counter-based key generate(sampling=...) and
             # every replay/failover resume re-derive for the same position
-            nxt = sample_tokens(
-                lg, temp, top_k, top_p,
-                lambda: position_keys(seed, (start + n_real)[None]))[0]
+            with jax.named_scope("sample"):
+                lg = logits[0, n_real - 1, :][None]        # [1, V]
+                nxt = sample_tokens(
+                    lg, temp, top_k, top_p,
+                    lambda: position_keys(seed, (start + n_real)[None]))[0]
             return nxt, paged_pool_tuple(cache)
 
-        return pool_jit(prog, self.mesh, self._pool_specs, 1)
+        return _named_pool_jit(prog, f"serve_prefill_{s_pad}", self.mesh,
+                               self._pool_specs)
 
     def _place_host_slabs(self, slabs):
         """Commit one host page's slab tuple to the pool's placement: on a

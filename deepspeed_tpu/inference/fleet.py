@@ -262,6 +262,8 @@ def result_to_doc(res: RequestResult) -> Dict[str, Any]:
         "trace_id": res.trace_id,
         "adapter_id": res.adapter_id,
         "lifecycle": [list(e) for e in res.lifecycle],
+        # NaN (a journal-resumed token has no stamp) is not JSON: null
+        "token_s": [None if t != t else float(t) for t in res.token_s],
     }
 
 
@@ -284,7 +286,9 @@ def result_from_doc(doc: Dict[str, Any]) -> RequestResult:
         resumed_tokens=int(doc.get("resumed_tokens") or 0),
         trace_id=doc.get("trace_id"),
         adapter_id=doc.get("adapter_id"),
-        lifecycle=[tuple(e) for e in doc.get("lifecycle") or []])
+        lifecycle=[tuple(e) for e in doc.get("lifecycle") or []],
+        token_s=np.asarray([np.nan if t is None else t
+                            for t in doc.get("token_s") or []], np.float64))
 
 
 class FleetMember:
@@ -2101,6 +2105,9 @@ class FleetRouter:
                                                   - len(resumed)]),
                     output_ids=np.concatenate(
                         [np.asarray(resumed, np.int32), res.output_ids]),
+                    # the journal carries tokens, not their stamps
+                    token_s=np.concatenate(
+                        [np.full(len(resumed), np.nan), res.token_s]),
                     resumed_tokens=len(resumed))
             if fo:
                 res = dataclasses.replace(res, failovers=fo)
@@ -2259,7 +2266,8 @@ class FleetRouter:
             first_token_s=t, finish_s=t,
             resumed_tokens=len(journaled),
             failovers=self._failed_over.pop(rid, 0),
-            trace_id=req.trace_id, lifecycle=lc)
+            trace_id=req.trace_id, lifecycle=lc,
+            token_s=np.full(len(journaled), np.nan))
         self._order.append(rid)
         self._requests.pop(rid, None)
         self._journal_delete(rid)

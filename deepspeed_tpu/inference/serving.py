@@ -279,6 +279,17 @@ class RequestResult:
     # record across incarnations and engines exactly like they stitch
     # tokens, so a failed-over request's record reads end to end.
     lifecycle: List = dataclasses.field(default_factory=list)
+    # one time.monotonic() stamp per output token (float64, same length as
+    # output_ids): the prefill's stamp for token 0, then one clock read per
+    # decode tick, taken right after the fetch — a speculative tick gives
+    # its 1..k tokens the same stamp.  Non-decreasing, token_s[0] ==
+    # first_token_s, token_s[-1] <= finish_s.  Tokens resumed from the
+    # fleet journal (which carries no stamps) read NaN; empty for shed and
+    # queue-expired results.  np.diff(token_s) is the inter-token latency
+    # a streaming client saw (docs/OBSERVABILITY.md "Per-request
+    # timelines").
+    token_s: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros((0,), np.float64))
 
     @property
     def ttft_s(self) -> float:
@@ -312,6 +323,8 @@ class _Slot:
     # lifecycle events recorded so far (moved from _lifecycle_pending at
     # admission; the finish event completes it into RequestResult)
     lifecycle: List = dataclasses.field(default_factory=list)
+    # emit stamp of every token in `tokens` (RequestResult.token_s)
+    token_s: List[float] = dataclasses.field(default_factory=list)
 
 
 class ServingEngine:
@@ -1485,7 +1498,7 @@ class ServingEngine:
             self._exec.invalidate_adapters()
             adapter_row = self._exec.adapter_row(self._adapter_stacks, slot)
         with trace_span("serve.prefill", rid=req.rid, slot=slot,
-                        bucket=s_pad, shared_tokens=n_shared):
+                        bucket=s_pad, tokens=S_tail, shared_tokens=n_shared):
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 if match.cow_src is not None:
@@ -1527,7 +1540,8 @@ class ServingEngine:
         self._slots[slot] = _Slot(
             request=req, pages=pages, tokens=[tok], bucket=s_pad,
             arrival_s=self._arrival_abs(req), admit_s=self._t0 + now,
-            first_token_s=t, shared_tokens=n_shared, lifecycle=lc)
+            first_token_s=t, shared_tokens=n_shared, lifecycle=lc,
+            token_s=[t])
         self._lengths[slot] = S
         self._last_tok[slot] = tok
         self._active[slot] = True
@@ -1555,13 +1569,16 @@ class ServingEngine:
             # publish this prompt's chunks (full pages + the partial
             # boundary) so later requests can share them; the index takes
             # one reference per new entry.  Shared chunks just LRU-touch
-            # their existing entries.
-            newly, released = self._prefix.publish(
-                req.input_ids, pages, salt=self._adapter_salt(req))
-            for p in newly:
-                self._share_page(p)
-            for p in released:
-                self._drop_page(p)
+            # their existing entries.  Host time AFTER the first token's
+            # stamp: the other slots' next token and the next admission
+            # wait for it.
+            with trace_span("serve.publish", rid=req.rid):
+                newly, released = self._prefix.publish(
+                    req.input_ids, pages, salt=self._adapter_salt(req))
+                for p in newly:
+                    self._share_page(p)
+                for p in released:
+                    self._drop_page(p)
         if self.monitor is not None:
             self.monitor.write_events([
                 ("serve/ttft_s", t - self._arrival_abs(req), self._tick)])
@@ -1602,6 +1619,7 @@ class ServingEngine:
             return
         lanes = self._lanes_jnp()
         with trace_span("serve.decode", tick=self._tick) as sp:
+            t_open = time.monotonic() if rid_map is not None else 0.0
             # tick-level slot→rid map (docs/OBSERVABILITY.md "Distributed
             # tracing"): a decode tick serves many requests at once, so
             # instead of one owning context the span is tagged with every
@@ -1615,25 +1633,38 @@ class ServingEngine:
                 nxt = self._exec.decode(self._page_table, self._lengths,
                                         self._last_tok, self._active, lanes,
                                         adapters=self._adapter_operand())
+                if rid_map is not None:
+                    # the launch has returned; what is left of the span is
+                    # the wait for the device in the fetch below.  Rows the
+                    # slots hold against rows the program's gather covers
+                    # (every slot's whole page-table row, live or not).
+                    sp.set(dispatch_ms=(time.monotonic() - t_open) * 1e3,
+                           live_rows=int(self._lengths[self._active].sum()),
+                           gathered_rows=(self._page_table.size
+                                          * self.page_size))
                 nxt = np.asarray(nxt)   # host fetch = device sync
+        t_tok = time.monotonic()   # every token of this tick: its emit stamp
         active_slots = np.flatnonzero(self._active)
         trace_count("serve.tokens", float(len(active_slots)))
-        for slot in active_slots:
-            st = self._slots[slot]
-            req = st.request
-            tok = int(nxt[slot])
-            st.tokens.append(tok)
-            st.decode_ticks += 1
-            self._lengths[slot] += 1
-            self._last_tok[slot] = tok
-            self._tokens_out += 1
-            if req.adapter_id is not None:
-                self._adapter_tokens_by_id[req.adapter_id] = (
-                    self._adapter_tokens_by_id.get(req.adapter_id, 0) + 1)
-            if req.eos_token_id is not None and tok == req.eos_token_id:
-                self._finish(slot, "eos")
-            elif len(st.tokens) >= req.max_new_tokens:
-                self._finish(slot, "length")
+        with trace_span("serve.emit", tick=self._tick) as sp:
+            for slot in active_slots:
+                st = self._slots[slot]
+                req = st.request
+                tok = int(nxt[slot])
+                st.tokens.append(tok)
+                st.token_s.append(t_tok)
+                st.decode_ticks += 1
+                self._lengths[slot] += 1
+                self._last_tok[slot] = tok
+                self._tokens_out += 1
+                if req.adapter_id is not None:
+                    self._adapter_tokens_by_id[req.adapter_id] = (
+                        self._adapter_tokens_by_id.get(req.adapter_id, 0) + 1)
+                if req.eos_token_id is not None and tok == req.eos_token_id:
+                    self._finish(slot, "eos")
+                elif len(st.tokens) >= req.max_new_tokens:
+                    self._finish(slot, "length")
+            sp.set(emitted=len(active_slots))
 
     def _spec_tick(self, rid_map: Optional[Dict[str, str]] = None) -> None:
         """Speculative decode tick: k draft proposals + one verify-k pass,
@@ -1653,35 +1684,40 @@ class ServingEngine:
                     self._page_table, self._lengths, self._last_tok,
                     self._active, *self._lanes_jnp(),
                     adapters=self._adapter_operand())
+        t_tok = time.monotonic()   # the tick's 1..k tokens share one stamp
         active_slots = np.flatnonzero(self._active)
         total = 0
-        for slot in active_slots:
-            st = self._slots[slot]
-            req = st.request
-            consumed = 0
-            finish = None
-            for j in range(int(n_emit[slot])):
-                tok = int(emitted[slot, j])
-                st.tokens.append(tok)
-                consumed += 1
-                self._tokens_out += 1
-                if req.eos_token_id is not None and tok == req.eos_token_id:
-                    finish = "eos"
-                    break
-                if len(st.tokens) >= req.max_new_tokens:
-                    finish = "length"
-                    break
-            st.decode_ticks += 1
-            total += consumed
-            if req.adapter_id is not None and consumed:
-                self._adapter_tokens_by_id[req.adapter_id] = (
-                    self._adapter_tokens_by_id.get(req.adapter_id, 0)
-                    + consumed)
-            self._spec.emitted_tokens += consumed
-            self._lengths[slot] += consumed
-            self._last_tok[slot] = st.tokens[-1]
-            if finish is not None:
-                self._finish(slot, finish)
+        with trace_span("serve.emit", tick=self._tick) as sp:
+            for slot in active_slots:
+                st = self._slots[slot]
+                req = st.request
+                consumed = 0
+                finish = None
+                for j in range(int(n_emit[slot])):
+                    tok = int(emitted[slot, j])
+                    st.tokens.append(tok)
+                    st.token_s.append(t_tok)
+                    consumed += 1
+                    self._tokens_out += 1
+                    if (req.eos_token_id is not None
+                            and tok == req.eos_token_id):
+                        finish = "eos"
+                        break
+                    if len(st.tokens) >= req.max_new_tokens:
+                        finish = "length"
+                        break
+                st.decode_ticks += 1
+                total += consumed
+                if req.adapter_id is not None and consumed:
+                    self._adapter_tokens_by_id[req.adapter_id] = (
+                        self._adapter_tokens_by_id.get(req.adapter_id, 0)
+                        + consumed)
+                self._spec.emitted_tokens += consumed
+                self._lengths[slot] += consumed
+                self._last_tok[slot] = st.tokens[-1]
+                if finish is not None:
+                    self._finish(slot, finish)
+            sp.set(emitted=total)
         trace_count("serve.tokens", float(total))
 
     def _finish(self, slot: int, reason: str) -> None:
@@ -1700,7 +1736,8 @@ class ServingEngine:
             decode_ticks=st.decode_ticks,
             shared_prefix_tokens=st.shared_tokens,
             trace_id=st.request.trace_id,
-            adapter_id=st.request.adapter_id, lifecycle=st.lifecycle)
+            adapter_id=st.request.adapter_id, lifecycle=st.lifecycle,
+            token_s=np.asarray(st.token_s, np.float64))
         if reason == "deadline":
             self.deadline_count += 1
         else:
@@ -1852,15 +1889,17 @@ class ServingEngine:
                 # scheduler round
                 if not self._draining:
                     self._admit(now)
-                # SLO evaluation per working tick (monitor-independent —
-                # alerts must fire even when no gauge backend is attached)
-                if self._slo is not None:
-                    self._slo.evaluate(monitor=self.monitor,
-                                       tracer=get_tracer())
-                # gauges only on working ticks: idle arrival-wait ticks
-                # would otherwise dilute occupancy stats and spam csv
-                # backends
-                self._write_gauges()
+                with trace_span("serve.gauges"):
+                    # SLO evaluation per working tick (monitor-independent
+                    # — alerts must fire even when no gauge backend is
+                    # attached)
+                    if self._slo is not None:
+                        self._slo.evaluate(monitor=self.monitor,
+                                           tracer=get_tracer())
+                    # gauges only on working ticks: idle arrival-wait ticks
+                    # would otherwise dilute occupancy stats and spam csv
+                    # backends
+                    self._write_gauges()
                 # windowed device capture (docs/OBSERVABILITY.md
                 # "Device-time correlation"): one WORKING tick = one
                 # capture unit — idle arrival-wait ticks must not burn the
@@ -1916,7 +1955,8 @@ class ServingEngine:
                     wait = (self._pending[0].arrival_time
                             - (time.monotonic() - self._t0))
                     if wait > 0:
-                        time.sleep(wait)
+                        with trace_span("serve.idle", wait_s=wait):
+                            time.sleep(wait)
                 elif self._queue:
                     if self._usable_slots() == 0:
                         # every slot fenced: nothing can ever be admitted

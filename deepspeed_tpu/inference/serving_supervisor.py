@@ -64,7 +64,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -141,6 +141,10 @@ class ServingSupervisor:
         # rid -> tokens decoded in previous engine incarnations; replay
         # outputs are prefixed with these when results are stitched
         self._prefix: Dict[Any, List[int]] = {}
+        # rid -> (first incarnation's admit_s, emit stamp of every carried
+        # token): the stitched result's timeline is the caller's, so its
+        # first token is the first one ANY incarnation emitted
+        self._prefix_s: Dict[Any, Tuple[float, List[float]]] = {}
         # rid -> lifecycle events from previous incarnations (each replay
         # appends a ("replay", t, new_incarnation) marker); stitched in
         # front of the finishing incarnation's record exactly like tokens
@@ -288,6 +292,7 @@ class ServingSupervisor:
             handed.extend(self._orig.pop(r.rid, r) for r in stash)
             for r in handed:
                 self._prefix.pop(r.rid, None)
+                self._prefix_s.pop(r.rid, None)
                 self._replay_count.pop(r.rid, None)
                 self._lifecycle.pop(r.rid, None)
             return handed
@@ -365,6 +370,7 @@ class ServingSupervisor:
 
     def _collect(self, res: RequestResult) -> None:
         prefix = self._prefix.pop(res.rid, None)
+        admit_s, prefix_s = self._prefix_s.pop(res.rid, (None, []))
         orig = self._orig.pop(res.rid, None)
         replays = self._replay_count.pop(res.rid, 0)
         lifecycle = self._lifecycle.pop(res.rid, None)
@@ -377,13 +383,19 @@ class ServingSupervisor:
             # rest via decode ticks — so a stitched result that kept
             # decoding keeps  decode_ticks == len(output_ids) - 1 - replays
             # (a replay terminated before its re-prefill contributes no new
-            # prefill token and sits one above that line).
+            # prefill token and sits one above that line).  The stamps are
+            # stitched like the tokens, and admit_s/first_token_s go back
+            # to the FIRST incarnation's: the caller saw its first token
+            # then, not at the re-prefill.
             res = dataclasses.replace(
                 res,
                 input_ids=orig.input_ids if orig is not None
                 else res.input_ids[:len(res.input_ids) - len(prefix)],
                 output_ids=np.concatenate(
                     [np.asarray(prefix, np.int32), res.output_ids]),
+                token_s=np.concatenate(
+                    [np.asarray(prefix_s, np.float64), res.token_s]),
+                admit_s=admit_s, first_token_s=prefix_s[0],
                 decode_ticks=res.decode_ticks + len(prefix) - replays,
                 replays=replays)
         elif replays:
@@ -515,7 +527,8 @@ class ServingSupervisor:
                                 generated=len(st.tokens)):
                     new.submit(replay)
                 replayed.append((req.rid, list(st.tokens),
-                                 list(st.lifecycle)))
+                                 list(st.lifecycle), st.admit_s,
+                                 list(st.token_s)))
             if drain:
                 # mid-drain recovery: never-served waiting requests are
                 # handed back, not re-served — stash them.  But a QUEUED
@@ -542,8 +555,10 @@ class ServingSupervisor:
         # (6) commit: prefixes only once every submission landed, so a
         # failed restart never double-counts replay tokens
         replay_t = time.monotonic()
-        for rid, tokens, lc in replayed:
+        for rid, tokens, lc, admit_s, stamps in replayed:
             self._prefix[rid] = self._prefix.get(rid, []) + tokens
+            first_admit_s, carried_s = self._prefix_s.get(rid, (admit_s, []))
+            self._prefix_s[rid] = (first_admit_s, carried_s + stamps)
             self._replay_count[rid] = self._replay_count.get(rid, 0) + 1
             # lifecycle carry: the dead incarnation's events plus a replay
             # marker stamped with the REPLACEMENT's incarnation (the

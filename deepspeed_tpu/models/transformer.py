@@ -603,16 +603,37 @@ def _param_specs_het(cfg: TransformerConfig) -> Dict[str, Any]:
 # Forward
 # ---------------------------------------------------------------------------
 
+# Layer names on the device (docs/OBSERVABILITY.md "Device-time
+# correlation"): every forward below runs its parts under jax.named_scope —
+# embed, norm, attn_qkv, attn, attn_out, mlp, lm_head, loss, and in the paged
+# block kv_write / kv_gather.  A scope is trace-time metadata on the ops it
+# encloses: it costs nothing at run time and leaves the compiled computation
+# as it was, and a device trace or an HLO dump then names a fusion by the
+# layer it came from instead of by a number the compiler renumbers.
+
 def _norm(cfg, x, scale, bias=None):
-    x32 = x.astype(jnp.float32)
-    if cfg.norm == "rmsnorm":
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        out = x32 * jax.lax.rsqrt(var + cfg.norm_eps) * scale
-    else:
-        mean = jnp.mean(x32, axis=-1, keepdims=True)
-        var = jnp.var(x32, axis=-1, keepdims=True)
-        out = (x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps) * scale + bias
-    return out.astype(x.dtype)
+    with jax.named_scope("norm"):
+        x32 = x.astype(jnp.float32)
+        if cfg.norm == "rmsnorm":
+            var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+            out = x32 * jax.lax.rsqrt(var + cfg.norm_eps) * scale
+        else:
+            mean = jnp.mean(x32, axis=-1, keepdims=True)
+            var = jnp.var(x32, axis=-1, keepdims=True)
+            out = ((x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps) * scale
+                   + bias)
+        return out.astype(x.dtype)
+
+
+def _lm_head(cfg, params, x):
+    """Final hidden states -> logits (tied or untied head, GPT-J's bias)."""
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return x @ params["embed"].astype(cfg.dtype).T
+        logits = x @ params["lm_head"].astype(cfg.dtype)
+        if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
+            logits = logits + params["lm_head_bias"].astype(cfg.dtype)
+        return logits
 
 
 def _rope(q, k, positions, theta, head_dim, rotary_dim=None,
@@ -870,31 +891,68 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic):
     """Post-norm MLP/MoE body shared by the training block and the KV-cached
     decode block: returns (output, moe_aux_loss).  MoE-ness is detected from
     the layer's params (PR-MoE pyramid layers differ per depth)."""
-    aux = jnp.float32(0.0)
-    if "router" in lp:
-        from ..moe.sharded_moe import MoEConfig, moe_ffn
+    with jax.named_scope("mlp"):
+        aux = jnp.float32(0.0)
+        if "router" in lp:
+            from ..moe.sharded_moe import MoEConfig, moe_ffn
 
-        m, aux = moe_ffn(
-            h, lp["router"], lp,
-            MoEConfig(num_experts=int(lp["router"].shape[-1]),
-                      top_k=cfg.moe_top_k,
-                      capacity_factor=cfg.capacity_factor,
-                      eval_capacity_factor=cfg.eval_capacity_factor,
-                      min_capacity=cfg.moe_min_capacity,
-                      noisy_gate_policy=cfg.noisy_gate_policy,
-                      drop_tokens=cfg.moe_drop_tokens),
-            activation=cfg.activation, deterministic=deterministic, rng=rng)
-        if "coefficient" in lp:
-            # residual MoE (reference moe/layer.py:16 use_residual): dense
-            # branch + learned softmax mixing coefficient
-            res = _dense_mlp(cfg, lp, h, prefix="res_")
-            coef = jax.nn.softmax(
-                (h @ lp["coefficient"]).astype(jnp.float32), axis=-1
-            ).astype(m.dtype)
-            m = m * coef[..., 0:1] + res * coef[..., 1:2]
-    else:
-        m = _dense_mlp(cfg, lp, h)
-    return m, aux
+            m, aux = moe_ffn(
+                h, lp["router"], lp,
+                MoEConfig(num_experts=int(lp["router"].shape[-1]),
+                          top_k=cfg.moe_top_k,
+                          capacity_factor=cfg.capacity_factor,
+                          eval_capacity_factor=cfg.eval_capacity_factor,
+                          min_capacity=cfg.moe_min_capacity,
+                          noisy_gate_policy=cfg.noisy_gate_policy,
+                          drop_tokens=cfg.moe_drop_tokens),
+                activation=cfg.activation, deterministic=deterministic, rng=rng)
+            if "coefficient" in lp:
+                # residual MoE (reference moe/layer.py:16 use_residual): dense
+                # branch + learned softmax mixing coefficient
+                res = _dense_mlp(cfg, lp, h, prefix="res_")
+                coef = jax.nn.softmax(
+                    (h @ lp["coefficient"]).astype(jnp.float32), axis=-1
+                ).astype(m.dtype)
+                m = m * coef[..., 0:1] + res * coef[..., 1:2]
+        else:
+            m = _dense_mlp(cfg, lp, h)
+        return m, aux
+
+
+def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
+    """Post-norm activations ``h [B,S,d]`` -> ``q [B,S,Hq,hd]``, ``k``,
+    ``v [B,S,Hkv,hd]``, biased and rotated — the pre-LN blocks' projection
+    (training, contiguous cache, paged pool).  ``proj(y, name, hin)``, when
+    given, adds the paged block's per-slot adapter delta."""
+    B, S, _ = h.shape
+    hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
+    with jax.named_scope("attn_qkv"):
+        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+        if proj is not None:
+            q, k, v = proj(q, "wq", h), proj(k, "wk", h), proj(v, "wv", h)
+        if cfg.attn_bias:
+            q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        q = q.reshape(B, S, nh, hd)
+        k = k.reshape(B, S, nkv, hd)
+        v = v.reshape(B, S, nkv, hd)
+        if cfg.position == "rope":
+            q, k = _rope(q, k, positions, cfg.rope_theta, hd,
+                         rotary_dim=cfg.rotary_dim,
+                         interleaved=cfg.rope_interleaved)
+    return q, k, v
+
+
+def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None):
+    """Attention output ``[B,S,Hq,hd]`` through the output projection."""
+    B, S = attn.shape[:2]
+    with jax.named_scope("attn_out"):
+        attn2d = attn.reshape(B, S, -1)
+        out = attn2d @ lp["wo"]
+        if proj is not None:
+            out = proj(out, "wo", attn2d)
+        if cfg.attn_bias:
+            out = out + lp["bo"]
+    return out
 
 
 def _block_postln(cfg: TransformerConfig, lp: Dict[str, Any], x, positions,
@@ -905,18 +963,18 @@ def _block_postln(cfg: TransformerConfig, lp: Dict[str, Any], x, positions,
     B, S, d = x.shape
     hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
     h = _maybe_act_quant(cfg, x)
-    q = (h @ lp["wq"]).reshape(B, S, nh, hd)
-    k = (h @ lp["wk"]).reshape(B, S, nkv, hd)
-    v = (h @ lp["wv"]).reshape(B, S, nkv, hd)
-    if cfg.attn_bias:
-        q = q + lp["bq"].reshape(nh, hd)
-        k = k + lp["bk"].reshape(nkv, hd)
-        v = v + lp["bv"].reshape(nkv, hd)
-    attn = _attention(cfg, q, k, v, positions, attn_impl, custom_positions,
-                      window=window)
-    attn = attn.reshape(B, S, nh * hd) @ lp["wo"]
-    if cfg.attn_bias:
-        attn = attn + lp["bo"]
+    with jax.named_scope("attn_qkv"):
+        q = (h @ lp["wq"]).reshape(B, S, nh, hd)
+        k = (h @ lp["wk"]).reshape(B, S, nkv, hd)
+        v = (h @ lp["wv"]).reshape(B, S, nkv, hd)
+        if cfg.attn_bias:
+            q = q + lp["bq"].reshape(nh, hd)
+            k = k + lp["bk"].reshape(nkv, hd)
+            v = v + lp["bv"].reshape(nkv, hd)
+    with jax.named_scope("attn"):
+        attn = _attention(cfg, q, k, v, positions, attn_impl,
+                          custom_positions, window=window)
+    attn = _attn_out(cfg, lp, attn)
     if cfg.dropout and not deterministic:
         rng, sub = jax.random.split(rng)
         attn = attn * jax.random.bernoulli(
@@ -938,38 +996,22 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     if cfg.post_layernorm:
         return _block_postln(cfg, lp, x, positions, rng, attn_impl,
                              deterministic, custom_positions, window=window)
-    B, S, d = x.shape
-    hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
-
     h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, h)
-    q = h @ lp["wq"]
-    k = h @ lp["wk"]
-    v = h @ lp["wv"]
-    if cfg.attn_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, S, nh, hd)
-    k = k.reshape(B, S, nkv, hd)
-    v = v.reshape(B, S, nkv, hd)
-    if cfg.position == "rope":
-        q, k = _rope(q, k, positions, cfg.rope_theta, hd,
-                     rotary_dim=cfg.rotary_dim,
-                     interleaved=cfg.rope_interleaved)
+    q, k, v = _qkv(cfg, lp, h, positions)
     # named so "save_matmuls" can pin the projection outputs (post-rope, so
     # the attention backward starts from exactly these tensors)
     q = checkpoint_name(q, "q_proj")
     k = checkpoint_name(k, "k_proj")
     v = checkpoint_name(v, "v_proj")
-    attn = _attention(cfg, q, k, v, positions, attn_impl, custom_positions,
-                      window=window)
+    with jax.named_scope("attn"):
+        attn = _attention(cfg, q, k, v, positions, attn_impl,
+                          custom_positions, window=window)
     # named checkpoint: the "save_attn" remat policy stashes this one tensor
     # per layer ([B,S,H*hd] bf16) so the backward skips recomputing the whole
     # attention (the costliest part of the recompute) while the rest of the
     # layer still rematerializes
-    attn = checkpoint_name(attn, "attn_out")
-    attn = attn.reshape(B, S, nh * hd) @ lp["wo"]
-    if cfg.attn_bias:
-        attn = attn + lp["bo"]
+    attn = _attn_out(cfg, lp, checkpoint_name(attn, "attn_out"))
     if cfg.dropout and not deterministic:
         rng, sub = jax.random.split(rng)
         attn = attn * jax.random.bernoulli(sub, 1 - cfg.dropout, attn.shape) / (1 - cfg.dropout)
@@ -1059,16 +1101,17 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
     if rng is None:
         rng = jax.random.PRNGKey(0)
 
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.position == "learned":
-        x = x + params["pos_embed"].astype(cfg.dtype)[positions]
-    if "type_embed" in params:   # BERT segment embeddings
-        tt = (token_type_ids if token_type_ids is not None
-              else jnp.zeros_like(tokens))
-        x = x + params["type_embed"].astype(cfg.dtype)[tt]
-    if cfg.embed_layernorm:      # Bloom / BERT embedding LayerNorm
-        x = _norm(cfg, x, params["embed_norm_scale"],
-                  params.get("embed_norm_bias"))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.position == "learned":
+            x = x + params["pos_embed"].astype(cfg.dtype)[positions]
+        if "type_embed" in params:   # BERT segment embeddings
+            tt = (token_type_ids if token_type_ids is not None
+                  else jnp.zeros_like(tokens))
+            x = x + params["type_embed"].astype(cfg.dtype)[tt]
+        if cfg.embed_layernorm:      # Bloom / BERT embedding LayerNorm
+            x = _norm(cfg, x, params["embed_norm_scale"],
+                      params.get("embed_norm_bias"))
     # activations: batch over DP axes, sequence over 'seq' axis
     act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
     x = constrain_spec(x, act_spec)
@@ -1174,12 +1217,7 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
     if cfg.final_norm:
         x = _norm(cfg, x, params["final_norm_scale"],
                   params.get("final_norm_bias"))
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].astype(cfg.dtype).T
-    else:
-        logits = x @ params["lm_head"].astype(cfg.dtype)
-        if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
-            logits = logits + params["lm_head_bias"].astype(cfg.dtype)
+    logits = _lm_head(cfg, params, x)
     if return_aux:
         return logits, {"moe_aux_loss": aux_total}
     return logits
@@ -1250,12 +1288,7 @@ def pipeline_1f1b_loss_and_grads(cfg: TransformerConfig, params: Dict[str, Any],
         if cfg.final_norm:
             y = _norm(cfg, y, hp["final_norm_scale"],
                       hp.get("final_norm_bias"))
-        if cfg.tie_embeddings:
-            logits = y @ hp["embed"].astype(cfg.dtype).T
-        else:
-            logits = y @ hp["lm_head"].astype(cfg.dtype)
-            if "lm_head_bias" in hp:
-                logits = logits + hp["lm_head_bias"].astype(cfg.dtype)
+        logits = _lm_head(cfg, hp, y)
         # scaled so the executor's vjp carries exactly the engine's gradient
         # (scale * mean-over-microbatches)
         return cross_entropy_loss(logits, lbl) * loss_scale / M
@@ -1368,32 +1401,20 @@ def _block_cached(cfg, lp, x, ck, cv, q_pos, q_slot, valid, kpos, next_slot,
                   rng, window=None):
     """One transformer block with cache read/write.  ck/cv are this layer's
     [B,T,Hkv,hd] buffers; returns (x, updated ck, cv)."""
-    B, S, _ = x.shape
-    hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
-
     h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, h)
-    q = h @ lp["wq"]
-    k = h @ lp["wk"]
-    v = h @ lp["wv"]
-    if cfg.attn_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, S, nh, hd)
-    k = k.reshape(B, S, nkv, hd)
-    v = v.reshape(B, S, nkv, hd)
-    if cfg.position == "rope":
-        q, k = _rope(q, k, q_pos, cfg.rope_theta, hd,
-                     rotary_dim=cfg.rotary_dim,
-                     interleaved=cfg.rope_interleaved)
-    ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), (0, next_slot, 0, 0))
-    cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), (0, next_slot, 0, 0))
-    ck = constrain_spec(ck, P(BATCH_AXES, None, "model", None))
-    cv = constrain_spec(cv, P(BATCH_AXES, None, "model", None))
-    attn = _attention_cached(cfg, q, ck, cv, q_pos, q_slot, valid, kpos,
-                             window=window)
-    attn = attn.reshape(B, S, nh * hd) @ lp["wo"]
-    if cfg.attn_bias:
-        attn = attn + lp["bo"]
+    q, k, v = _qkv(cfg, lp, h, q_pos)
+    with jax.named_scope("kv_write"):
+        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
+                                          (0, next_slot, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
+                                          (0, next_slot, 0, 0))
+        ck = constrain_spec(ck, P(BATCH_AXES, None, "model", None))
+        cv = constrain_spec(cv, P(BATCH_AXES, None, "model", None))
+    with jax.named_scope("attn"):
+        attn = _attention_cached(cfg, q, ck, cv, q_pos, q_slot, valid, kpos,
+                                 window=window)
+    attn = _attn_out(cfg, lp, attn)
 
     if cfg.parallel_residual:
         h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
@@ -1439,12 +1460,13 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
         raise NotImplementedError(
             "cached decode is a causal-LM operation; encoder models "
             "(causal=False) have no autoregressive cache")
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.position == "learned":
-        x = x + params["pos_embed"].astype(cfg.dtype)[positions]
-    if cfg.embed_layernorm:      # Bloom embedding LayerNorm
-        x = _norm(cfg, x, params["embed_norm_scale"],
-                  params.get("embed_norm_bias"))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.position == "learned":
+            x = x + params["pos_embed"].astype(cfg.dtype)[positions]
+        if cfg.embed_layernorm:      # Bloom embedding LayerNorm
+            x = _norm(cfg, x, params["embed_norm_scale"],
+                      params.get("embed_norm_bias"))
     x = constrain_spec(x, P(BATCH_AXES, None, None))
 
     rng = jax.random.PRNGKey(0)
@@ -1473,12 +1495,7 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
             body, x, (params["layers"], cache["k"], cache["v"], windows))
 
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].astype(cfg.dtype).T
-    else:
-        logits = x @ params["lm_head"].astype(cfg.dtype)
-        if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
-            logits = logits + params["lm_head_bias"].astype(cfg.dtype)
+    logits = _lm_head(cfg, params, x)
     new_cache = {"k": ck_all, "v": cv_all, "valid": valid, "pos": kpos,
                  "next_slot": next_slot + S}
     return logits, new_cache
@@ -1728,46 +1745,36 @@ def _block_paged(cfg, lp, x, ckf, cvf, positions, write_idx, gather_idx, rng,
 
     h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, h)
-    q = proj(h @ lp["wq"], "wq", h)
-    k = proj(h @ lp["wk"], "wk", h)
-    v = proj(h @ lp["wv"], "wv", h)
-    if cfg.attn_bias:
-        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-    q = q.reshape(B, S, nh, hd)
-    k = k.reshape(B, S, nkv, hd)
-    v = v.reshape(B, S, nkv, hd)
-    if cfg.position == "rope":
-        q, k = _rope(q, k, positions, cfg.rope_theta, hd,
-                     rotary_dim=cfg.rotary_dim,
-                     interleaved=cfg.rope_interleaved)
-    if cksf is not None:
-        # quantize on store: int8 rows + per-row scales through one scatter
-        kq, ks = kv_quantize_rows(k.reshape(B * S, nkv, hd))
-        vq, vs = kv_quantize_rows(v.reshape(B * S, nkv, hd))
-        ckf = ckf.at[write_idx].set(kq)
-        cvf = cvf.at[write_idx].set(vq)
-        cksf = cksf.at[write_idx].set(ks)
-        cvsf = cvsf.at[write_idx].set(vs)
+    q, k, v = _qkv(cfg, lp, h, positions, proj)
+    with jax.named_scope("kv_write"):
+        if cksf is not None:
+            # quantize on store: int8 rows + per-row scales through one
+            # scatter
+            kq, ks = kv_quantize_rows(k.reshape(B * S, nkv, hd))
+            vq, vs = kv_quantize_rows(v.reshape(B * S, nkv, hd))
+            ckf = ckf.at[write_idx].set(kq)
+            cvf = cvf.at[write_idx].set(vq)
+            cksf = cksf.at[write_idx].set(ks)
+            cvsf = cvsf.at[write_idx].set(vs)
+        else:
+            ckf = ckf.at[write_idx].set(
+                k.reshape(B * S, nkv, hd).astype(ckf.dtype))
+            cvf = cvf.at[write_idx].set(
+                v.reshape(B * S, nkv, hd).astype(cvf.dtype))
         ckf = constrain_spec(ckf, P(None, "model", None))
         cvf = constrain_spec(cvf, P(None, "model", None))
-        # dequantize inside the gather: the narrow representation is what
-        # crosses HBM; attention sees compute-dtype values
-        ck = kv_dequantize(ckf[gather_idx], cksf[gather_idx], cfg.dtype)
-        cv = kv_dequantize(cvf[gather_idx], cvsf[gather_idx], cfg.dtype)
-    else:
-        ckf = ckf.at[write_idx].set(
-            k.reshape(B * S, nkv, hd).astype(ckf.dtype))
-        cvf = cvf.at[write_idx].set(
-            v.reshape(B * S, nkv, hd).astype(cvf.dtype))
-        ckf = constrain_spec(ckf, P(None, "model", None))
-        cvf = constrain_spec(cvf, P(None, "model", None))
-        ck = ckf[gather_idx]   # [B, T, Hkv, hd] — each slot's pages
-        cv = cvf[gather_idx]
-    attn = _attention_paged(cfg, q, ck, cv, positions)
-    attn2d = attn.reshape(B, S, nh * hd)
-    attn = proj(attn2d @ lp["wo"], "wo", attn2d)
-    if cfg.attn_bias:
-        attn = attn + lp["bo"]
+    with jax.named_scope("kv_gather"):
+        if cksf is not None:
+            # dequantize inside the gather: the narrow representation is
+            # what crosses HBM; attention sees compute-dtype values
+            ck = kv_dequantize(ckf[gather_idx], cksf[gather_idx], cfg.dtype)
+            cv = kv_dequantize(cvf[gather_idx], cvsf[gather_idx], cfg.dtype)
+        else:
+            ck = ckf[gather_idx]   # [B, T, Hkv, hd] — each slot's pages
+            cv = cvf[gather_idx]
+    with jax.named_scope("attn"):
+        attn = _attention_paged(cfg, q, ck, cv, positions)
+    attn = _attn_out(cfg, lp, attn, proj)
 
     if cfg.parallel_residual:
         h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
@@ -1855,13 +1862,14 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                   + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
                   ).reshape(B, maxp * ps)
 
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    if cfg.position == "learned":
-        safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
-        x = x + params["pos_embed"].astype(cfg.dtype)[safe_pos]
-    if cfg.embed_layernorm:      # Bloom embedding LayerNorm
-        x = _norm(cfg, x, params["embed_norm_scale"],
-                  params.get("embed_norm_bias"))
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.position == "learned":
+            safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
+            x = x + params["pos_embed"].astype(cfg.dtype)[safe_pos]
+        if cfg.embed_layernorm:      # Bloom embedding LayerNorm
+            x = _norm(cfg, x, params["embed_norm_scale"],
+                      params.get("embed_norm_bias"))
     x = constrain_spec(x, P(BATCH_AXES, None, None))
 
     rng = jax.random.PRNGKey(0)
@@ -1905,12 +1913,7 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         x, (ck_all, cv_all) = jax.lax.scan(body, x, xs)
 
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    if cfg.tie_embeddings:
-        logits = x @ params["embed"].astype(cfg.dtype).T
-    else:
-        logits = x @ params["lm_head"].astype(cfg.dtype)
-        if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
-            logits = logits + params["lm_head_bias"].astype(cfg.dtype)
+    logits = _lm_head(cfg, params, x)
     new_cache = {"k": ck_all, "v": cv_all}
     if quantized:
         new_cache["k_scale"], new_cache["v_scale"] = cks_all, cvs_all
@@ -1920,10 +1923,11 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,
                        ignore_index: int = -100) -> jax.Array:
     """Mean next-token NLL; positions with ``labels == ignore_index`` masked."""
-    mask = (labels != ignore_index)
-    safe = jnp.where(mask, labels, 0)
-    logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
-    gold = jnp.take_along_axis(logits.astype(jnp.float32), safe[..., None],
-                               axis=-1)[..., 0]
-    nll = (logz - gold) * mask
-    return jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)
+    with jax.named_scope("loss"):
+        mask = (labels != ignore_index)
+        safe = jnp.where(mask, labels, 0)
+        logz = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+        gold = jnp.take_along_axis(logits.astype(jnp.float32),
+                                   safe[..., None], axis=-1)[..., 0]
+        nll = (logz - gold) * mask
+        return jnp.sum(nll) / jnp.maximum(jnp.sum(mask), 1)

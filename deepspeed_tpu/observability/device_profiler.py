@@ -57,6 +57,24 @@ __all__ = ["DeviceTraceCapture", "capture_device_trace",
            "DEVICE_TRACE_ENV", "DEVICE_TRACE_UNITS_ENV"]
 
 
+def capture_options():
+    """The ``ProfileOptions`` every capture runs with.  The Python tracer is
+    off: JAX's default hooks every Python call of the traced loop for the
+    length of the capture — events nobody reads, and a slower host in the
+    very window under study (a serving tick's host time read 2.9 ms with it
+    and 1.7 ms without, PERF.md §6 PR 24).  The host tracer is at 1, the
+    lowest level that still records ``TraceAnnotation``: the mirrored spans
+    are what the capture is for.  The device planes depend on neither
+    level, and they, not Python, are what ``stop_trace`` spends its seconds
+    on."""
+    import jax.profiler
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return opts
+
+
 class DeviceTraceCapture:
     """One windowed XLA-profiler capture.  Constructed armed-and-started;
     :meth:`unit` counts down the window (``n_units=None`` = until an
@@ -83,7 +101,8 @@ class DeviceTraceCapture:
             import jax.profiler
 
             os.makedirs(self.log_dir, exist_ok=True)
-            jax.profiler.start_trace(self.log_dir)
+            jax.profiler.start_trace(self.log_dir,
+                                     profiler_options=capture_options())
         except Exception as e:   # profiler unavailable / already tracing
             self.failed = f"{type(e).__name__}: {e}"
             logger.warning("device trace capture into %s failed to start "

@@ -736,7 +736,12 @@ class DeepSpeedEngine:
             return lambda masters, step=None: masters
 
         def tree_fn(masters, step=None):
-            work = constrain(_cast_tree(masters, compute_dtype), param_shardings)
+            # zero_params / zero_grads name the places where the ZeRO plan's
+            # sharding constraints apply; the gathers and reduce-scatters
+            # themselves are the partitioner's
+            with jax.named_scope("zero_params"):
+                work = constrain(_cast_tree(masters, compute_dtype),
+                                 param_shardings)
             if compress is not None and step is not None:
                 work = constrain(compress(work, step), param_shardings)
             return work
@@ -759,29 +764,38 @@ class DeepSpeedEngine:
         frozen_mask = self._frozen_mask
 
         def apply_update(state: TrainState, masters, opt_in, grads, eff_gas):
-            inv = 1.0 / (state.scaler.loss_scale * eff_gas)
-            if prescale:
-                inv = inv * predivide
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
-            if frozen_mask is not None:
-                # frozen params produce no gradient in the reference
-                # (requires_grad=False): zero theirs BEFORE the overflow
-                # check, grad norm and clipping so none of the three sees
-                # them (a frozen layer's inf would otherwise skip the step)
-                grads = jax.tree_util.tree_map(
-                    lambda m, g: jnp.zeros_like(g) if m else g,
-                    frozen_mask, grads)
-            finite = grads_finite(grads) if fp16 else jnp.bool_(True)
-            grad_norm = optax.global_norm(grads)
-            updates, new_opt = optimizer.update(grads, opt_in, masters)
-            new_masters = optax.apply_updates(masters, updates)
-            # overflow => skip (reference DynamicLossScaler step-skip semantics)
-            new_masters = _tree_select(finite, new_masters, masters)
-            new_opt = _tree_select(finite, new_opt, opt_in)
-            new_scaler = update_scale(state.scaler, finite)
+            # grad_clip: unscale, overflow check and the global norm the
+            # clip is computed from (the clip's own multiply is the first
+            # link of the optax chain, under `optimizer`)
+            with jax.named_scope("grad_clip"):
+                inv = 1.0 / (state.scaler.loss_scale * eff_gas)
+                if prescale:
+                    inv = inv * predivide
+                grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+                if frozen_mask is not None:
+                    # frozen params produce no gradient in the reference
+                    # (requires_grad=False): zero theirs BEFORE the overflow
+                    # check, grad norm and clipping so none of the three
+                    # sees them (a frozen layer's inf would otherwise skip
+                    # the step)
+                    grads = jax.tree_util.tree_map(
+                        lambda m, g: jnp.zeros_like(g) if m else g,
+                        frozen_mask, grads)
+                finite = grads_finite(grads) if fp16 else jnp.bool_(True)
+                grad_norm = optax.global_norm(grads)
+            with jax.named_scope("optimizer"):
+                updates, new_opt = optimizer.update(grads, opt_in, masters)
+                new_masters = optax.apply_updates(masters, updates)
+                # overflow => skip (reference DynamicLossScaler step-skip
+                # semantics)
+                new_masters = _tree_select(finite, new_masters, masters)
+                new_opt = _tree_select(finite, new_opt, opt_in)
+                new_scaler = update_scale(state.scaler, finite)
             if use_master:
-                new_params = constrain(_cast_tree(new_masters, compute_dtype),
-                                       param_shardings)
+                with jax.named_scope("zero_params"):
+                    new_params = constrain(
+                        _cast_tree(new_masters, compute_dtype),
+                        param_shardings)
                 new_master_out = new_masters
             else:
                 new_params = new_masters
@@ -1022,38 +1036,46 @@ class DeepSpeedEngine:
             masters, opt_in = stream_in(state)
             work = compute_tree(masters, state.step)  # bf16 cast hoisted out of the scan
 
-            if pipeline:
-                # pipeline engines consume the whole gas window in ONE call:
-                # the model splits it into microbatches internally and the
-                # SPMD pipeline overlaps them across stages (reference
-                # PipelineEngine.train_batch, pipe/engine.py:286)
-                flat = jax.tree_util.tree_map(
-                    lambda x: x.reshape((-1,) + x.shape[2:]), batch)
-                new_rng, sub = jax.random.split(state.rng)
-                if manual_pipe is not None:
-                    grads, losses = manual_pipe(work, state.scaler, flat, sub)
+            # grad_accum: forward and backward of the gas window (the
+            # model's own scopes nest under it)
+            with jax.named_scope("grad_accum"):
+                if pipeline:
+                    # pipeline engines consume the whole gas window in ONE
+                    # call: the model splits it into microbatches internally
+                    # and the SPMD pipeline overlaps them across stages
+                    # (reference PipelineEngine.train_batch,
+                    # pipe/engine.py:286)
+                    flat = jax.tree_util.tree_map(
+                        lambda x: x.reshape((-1,) + x.shape[2:]), batch)
+                    new_rng, sub = jax.random.split(state.rng)
+                    if manual_pipe is not None:
+                        grads, losses = manual_pipe(work, state.scaler, flat,
+                                                    sub)
+                    else:
+                        grads, losses = grad_of_batch(work, state.scaler,
+                                                      flat, sub)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(accum_dtype), grads)
+                    eff_gas = 1  # loss already averages over the gas window
+                elif gas == 1:
+                    # no accumulation window: skip the scan and the fp32
+                    # zero buffer init + add (saves ~12 bytes/param of HBM
+                    # traffic)
+                    new_rng, sub = jax.random.split(state.rng)
+                    grads, losses = grad_of_batch(
+                        work, state.scaler,
+                        jax.tree_util.tree_map(lambda x: x[0], batch), sub)
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(accum_dtype), grads)
+                    eff_gas = 1
                 else:
-                    grads, losses = grad_of_batch(work, state.scaler, flat, sub)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(accum_dtype), grads)
-                eff_gas = 1  # loss already averages over the gas window
-            elif gas == 1:
-                # no accumulation window: skip the scan and the fp32 zero
-                # buffer init + add (saves ~12 bytes/param of HBM traffic)
-                new_rng, sub = jax.random.split(state.rng)
-                grads, losses = grad_of_batch(
-                    work, state.scaler,
-                    jax.tree_util.tree_map(lambda x: x[0], batch), sub)
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(accum_dtype), grads)
-                eff_gas = 1
-            else:
-                grads, losses, new_rng = accumulate(work, state.scaler, batch,
-                                                    state.rng)
-                eff_gas = gas
+                    grads, losses, new_rng = accumulate(
+                        work, state.scaler, batch, state.rng)
+                    eff_gas = gas
             # ZeRO-2/3: land the accumulated grads sharded — XLA lowers the DP
             # reduction into reduce-scatter against this constraint
-            grads = constrain(grads, grad_specs)
+            with jax.named_scope("zero_grads"):
+                grads = constrain(grads, grad_specs)
             new_state, metrics = apply_update(state, masters, opt_in, grads, eff_gas)
             new_state = dataclasses.replace(new_state, rng=new_rng)
             metrics["loss"] = jnp.mean(losses)
@@ -1205,7 +1227,10 @@ class DeepSpeedEngine:
             self.state, metrics = self._compiled_train_step(self.state,
                                                             global_batch)
             _sp.sync(metrics["loss"])
-        self._account_step(global_batch)
+        # bookkeeping (train.monitor, here and below): untraced, this part
+        # overlaps the step the device is still running
+        with trace_span("train.monitor"):
+            self._account_step(global_batch)
         if profiling:
             from ..profiling.flops_profiler import cost_analysis_of
 
@@ -1220,15 +1245,21 @@ class DeepSpeedEngine:
                 output_file=fp.output_file)
         self.global_steps += 1
         self.micro_steps += self.gas
-        self._last_grad_norm = float(metrics["grad_norm"])
-        if self.fp16_enabled and not bool(metrics["step_applied"]):
-            self.skipped_steps += 1
-            log_dist(f"step {self.global_steps}: grad overflow, step skipped; "
-                     f"loss scale -> {float(self.state.scaler.loss_scale)}", ranks=[0])
-        self.tput_timer.stop(sync_tree=metrics["loss"])
-        self._emit_monitor_events(metrics)
-        if self.global_steps % self.config.steps_per_print == 0:
-            self._report_progress(metrics)
+        # the blocking reads after the step: untraced, the first of them is
+        # where the host waits for the device
+        with trace_span("train.fetch"):
+            self._last_grad_norm = float(metrics["grad_norm"])
+            if self.fp16_enabled and not bool(metrics["step_applied"]):
+                self.skipped_steps += 1
+                log_dist(f"step {self.global_steps}: grad overflow, step "
+                         "skipped; loss scale -> "
+                         f"{float(self.state.scaler.loss_scale)}", ranks=[0])
+            self.tput_timer.stop(sync_tree=metrics["loss"])
+        # serial with the device: the next step's launch waits for it
+        with trace_span("train.monitor"):
+            self._emit_monitor_events(metrics)
+            if self.global_steps % self.config.steps_per_print == 0:
+                self._report_progress(metrics)
         return metrics["loss"]
 
     def eval_batch(self, batch) -> jnp.ndarray:

@@ -7,8 +7,9 @@ short serving stream — then validates the Chrome/Perfetto export:
 
 - the artifact is valid JSON in trace-event format;
 - the expected span names from both paths are present (``train.batch``,
-  ``train.data``, ``train.step``, ``serve.tick``, ``serve.admit``,
-  ``serve.prefill``, ``serve.decode``);
+  ``train.data``, ``train.step``, ``train.fetch``, ``train.monitor``,
+  ``serve.tick``, ``serve.admit``, ``serve.prefill``, ``serve.publish``,
+  ``serve.decode``, ``serve.emit``, ``serve.gauges``);
 - nesting is sane: every recorded depth is non-negative, every duration is
   non-negative, and within each thread child spans lie inside their
   parents' intervals (events sorted by ts must nest like balanced
@@ -41,9 +42,10 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 os.pardir, "tests"))
 
-EXPECTED_SPANS = ("train.batch", "train.data", "train.step",
-                  "serve.tick", "serve.admit", "serve.prefill",
-                  "serve.decode")
+EXPECTED_SPANS = ("train.batch", "train.data", "train.step", "train.fetch",
+                  "train.monitor", "serve.tick", "serve.admit",
+                  "serve.prefill", "serve.publish", "serve.decode",
+                  "serve.emit", "serve.gauges")
 
 
 def measure_disabled_span_ns(iters: int = 200_000) -> float:
@@ -197,7 +199,11 @@ def run_smoke(trace_path: str = None, train_steps: int = 2,
         prom = prometheus_text(tracer=get_tracer())
         timeline_ok = all(
             r.queued_s >= 0 and r.ttft_s >= 0
-            and r.decode_ticks == len(r.output_ids) - 1 for r in results)
+            and r.decode_ticks == len(r.output_ids) - 1
+            and len(r.token_s) == len(r.output_ids)
+            and r.token_s[0] == r.first_token_s
+            and bool(np.all(np.diff(r.token_s) >= 0))
+            and r.token_s[-1] <= r.finish_s for r in results)
 
         # ---- histogram / SLO phase (ISSUE 12): the traced run above fed
         # per-span duration histograms; check serve.tick quantiles are
