@@ -1559,12 +1559,12 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     """Allocate the physical page pool: ``k``/``v`` are
     ``[L, num_pages, page_size, Hkv, hd]``.
 
-    Physical page 0 is RESERVED as the trash page: pad-token and
-    inactive-slot writes are redirected there (a masked write must still be
-    a static-shape scatter), and it is also the page-table value for
-    unallocated entries — its slot-indices always sit beyond every real
-    query position, so the causal mask keeps it out of attention.  The
-    serving engine hands out pages 1..num_pages-1.
+    Physical page 0 is RESERVED as the trash page: a page write none of
+    whose rows is real (pad tokens, an inactive slot) is redirected there
+    (a masked write must still be a static-shape scatter), and it is also
+    the page-table value for unallocated entries — its slot-indices always
+    sit beyond every real query position, so the causal mask keeps it out
+    of attention.  The serving engine hands out pages 1..num_pages-1.
 
     Sharing contract (cross-request KV reuse): pages are **immutable once
     full**.  A slot only ever writes at its own current position, which
@@ -1659,8 +1659,8 @@ def kv_quantize_rows(x: jax.Array):
 
 
 def kv_dequantize(q: jax.Array, scale: jax.Array, dtype):
-    """Invert :func:`kv_quantize_rows` on a gathered ``[B, T, Hkv, hd]``
-    block with its ``[B, T]`` scale rows; dequantizes in float32 before
+    """Invert :func:`kv_quantize_rows` on a gathered ``[..., Hkv, hd]``
+    block with its ``[...]`` scale rows; dequantizes in float32 before
     casting to the compute dtype so the scale multiply never loses the
     int8 mantissa."""
     return (q.astype(jnp.float32)
@@ -1668,7 +1668,8 @@ def kv_dequantize(q: jax.Array, scale: jax.Array, dtype):
 
 
 def _attention_paged(cfg, q, ck, cv, q_pos):
-    """q:[B,S,Hq,hd] against gathered per-slot pages ck/cv:[B,T,Hkv,hd].
+    """q:[B,S,Hq,hd] against each slot's gathered pages ck/cv:
+    [B,maxp,page,Hkv,hd], attended as ``T = maxp*page`` rows.
 
     Slot-local index == position, so the mask is purely causal
     (``t <= q_pos``): every slot-index at or before the query holds a real
@@ -1676,14 +1677,18 @@ def _attention_paged(cfg, q, ck, cv, q_pos):
     from unallocated page-table entries) is masked.  Same einsum structure
     as :func:`_attention_cached` — GQA contracts grouped heads against the
     Hkv cache directly, and decode stays on the XLA path (the Pallas decode
-    kernel was retired in round 5 on an honest A/B).
+    kernel was retired in round 5 on an honest A/B).  The products keep the
+    pages' own (page, row) axes and only the scores are flattened to ``T``:
+    merging the two axes of K/V would re-lay the gathered block out, which
+    the compiler then does to the whole pool (PERF.md, PR 25).
     """
     B, S, Hq, hd = q.shape
-    T, Hkv = ck.shape[1], ck.shape[2]
+    maxp, ps, Hkv = ck.shape[1], ck.shape[2], ck.shape[3]
+    T = maxp * ps
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
-    scores = jnp.einsum("bskgd,btkd->bkgst", qg, ck).astype(jnp.float32)
-    scores = scores * _sm_scale(cfg, hd)
+    scores = jnp.einsum("bskgd,bptkd->bkgspt", qg, ck).astype(jnp.float32)
+    scores = scores.reshape(B, Hkv, G, S, T) * _sm_scale(cfg, hd)
     t = jnp.arange(T, dtype=jnp.int32)
     if cfg.position == "alibi":
         slopes = jnp.asarray(_alibi_slopes(Hq)).reshape(Hkv, G)
@@ -1693,7 +1698,8 @@ def _attention_paged(cfg, q, ck, cv, q_pos):
     ok = t[None, None, :] <= q_pos[:, :, None]                  # [B,S,T]
     scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, cv)
+    out = jnp.einsum("bkgspt,bptkd->bskgd",
+                     probs.reshape(B, Hkv, G, S, maxp, ps), cv)
     return out.reshape(B, S, Hq, hd)
 
 
@@ -1713,20 +1719,32 @@ def _adapter_delta(h, ab, scale):
     return d * scale.astype(jnp.float32)[:, None, None]
 
 
-def _block_paged(cfg, lp, x, ckf, cvf, positions, write_idx, gather_idx, rng,
-                 cksf=None, cvsf=None, adapters=None, ad_scale=None):
-    """One transformer block against the paged pool.  ``ckf``/``cvf`` are
-    this layer's pool flattened to ``[P*page, Hkv, hd]``; ``write_idx``
-    [B*S] flat destinations (trash-redirected for masked tokens);
-    ``gather_idx`` [B, T] flat sources for each slot's pages.
+def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
+                 adapters=None, ad_scale=None):
+    """One transformer block against the paged pool, addressed a whole
+    page at a time.  ``pools`` maps each pool leaf (``k``/``v``, plus
+    ``k_scale``/``v_scale`` on a quantized pool) to its array with the
+    page axis leading: ``[N, page, Hkv, hd]`` (scales ``[N, page]``) — the
+    stacked pool with ``N = L*P`` from :func:`forward_paged`.  Returns
+    ``(x, pools)``.
 
-    ``cksf``/``cvsf`` (both or neither) are a quantized pool's scale
-    planes flattened to ``[P*page]``: the store quantizes each written row
-    (symmetric absmax, :func:`kv_quantize_rows`) and scatters its scale
-    through the SAME ``write_idx``, the gather dequantizes in-place before
-    attention — the scales ride as one extra traced operand, so the
-    program shapes (and the zero-recompile inventory built on them) are
-    unchanged.
+    Write: ``write = (src, keep, pages)`` is the block's page-merge plan
+    (:func:`forward_paged` builds it once for all layers): ``pages [B,
+    n_pg]`` the physical pages this block's positions fall in (the trash
+    page where none of a page's rows is written), ``src [B, n_pg*page]``
+    the token of the block each page row takes, ``keep [B, n_pg, page]``
+    whether it takes one.  The pages are gathered, merged and scattered
+    back whole.  Read: ``gather_pages [B, maxp]`` are each slot's pages,
+    gathered whole.  Every pool op thus slices all trailing axes, so it
+    runs in whatever layout the pool is stored in and the pool stays in
+    place; a row-granular scatter or gather makes the TPU compiler re-lay
+    the whole pool out around the layer scan (PERF.md, PR 25).
+
+    A quantized pool quantizes each written row on store (symmetric
+    absmax, :func:`kv_quantize_rows`), merges its scale through the SAME
+    plan, and dequantizes inside the gather — the scale planes are two
+    more leaves of ``pools``, so the program shapes (and the
+    zero-recompile inventory built on them) are unchanged.
 
     ``adapters``/``ad_scale`` (both or neither) are this layer's per-slot
     LoRA factor slices ``{target: {"A": [B,d_in,R], "B": [B,R,d_out]}}``
@@ -1747,31 +1765,40 @@ def _block_paged(cfg, lp, x, ckf, cvf, positions, write_idx, gather_idx, rng,
     h = _maybe_act_quant(cfg, h)
     q, k, v = _qkv(cfg, lp, h, positions, proj)
     with jax.named_scope("kv_write"):
-        if cksf is not None:
-            # quantize on store: int8 rows + per-row scales through one
-            # scatter
-            kq, ks = kv_quantize_rows(k.reshape(B * S, nkv, hd))
-            vq, vs = kv_quantize_rows(v.reshape(B * S, nkv, hd))
-            ckf = ckf.at[write_idx].set(kq)
-            cvf = cvf.at[write_idx].set(vq)
-            cksf = cksf.at[write_idx].set(ks)
-            cvsf = cvsf.at[write_idx].set(vs)
-        else:
-            ckf = ckf.at[write_idx].set(
-                k.reshape(B * S, nkv, hd).astype(ckf.dtype))
-            cvf = cvf.at[write_idx].set(
-                v.reshape(B * S, nkv, hd).astype(cvf.dtype))
-        ckf = constrain_spec(ckf, P(None, "model", None))
-        cvf = constrain_spec(cvf, P(None, "model", None))
+        src, keep, pages = write
+
+        def merge(pool, rows):
+            # rows [B,S,...] -> the rows of their pages [B,n_pg,page,...],
+            # laid over what those pages hold
+            w = (1,) * (rows.ndim - 2)
+            new = jnp.take_along_axis(rows, src.reshape(*src.shape, *w),
+                                      axis=1).astype(pool.dtype)
+            new = jnp.where(keep.reshape(*keep.shape, *w),
+                            new.reshape(*keep.shape, *rows.shape[2:]),
+                            pool[pages])
+            return pool.at[pages.reshape(-1)].set(
+                new.reshape(-1, *new.shape[2:]))
+
+        rows = {"k": k, "v": v}
+        if "k_scale" in pools:
+            # quantize on store: int8 rows + per-row scales, one plan
+            for name, r in (("k", k), ("v", v)):
+                q8, sc = kv_quantize_rows(r.reshape(B * S, nkv, hd))
+                rows[name] = q8.reshape(B, S, nkv, hd)
+                rows[name + "_scale"] = sc.reshape(B, S)
+        pools = {name: merge(pool, rows[name])
+                 for name, pool in pools.items()}
+        for name in ("k", "v"):
+            pools[name] = constrain_spec(pools[name],
+                                         P(None, None, "model", None))
     with jax.named_scope("kv_gather"):
-        if cksf is not None:
+        # each slot's pages [B, maxp, page, Hkv, hd]
+        ck, cv = pools["k"][gather_pages], pools["v"][gather_pages]
+        if "k_scale" in pools:
             # dequantize inside the gather: the narrow representation is
             # what crosses HBM; attention sees compute-dtype values
-            ck = kv_dequantize(ckf[gather_idx], cksf[gather_idx], cfg.dtype)
-            cv = kv_dequantize(cvf[gather_idx], cvsf[gather_idx], cfg.dtype)
-        else:
-            ck = ckf[gather_idx]   # [B, T, Hkv, hd] — each slot's pages
-            cv = cvf[gather_idx]
+            ck = kv_dequantize(ck, pools["k_scale"][gather_pages], cfg.dtype)
+            cv = kv_dequantize(cv, pools["v_scale"][gather_pages], cfg.dtype)
     with jax.named_scope("attn"):
         attn = _attention_paged(cfg, q, ck, cv, positions)
     attn = _attn_out(cfg, lp, attn, proj)
@@ -1780,13 +1807,44 @@ def _block_paged(cfg, lp, x, ckf, cvf, positions, write_idx, gather_idx, rng,
         h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
             cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
         m, _ = _mlp(cfg, lp, h2, rng, deterministic=True)
-        return x + attn + m, ckf, cvf, cksf, cvsf
+        return x + attn + m, pools
 
     x = x + attn
     h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
     h = _maybe_act_quant(cfg, h)
     m, _ = _mlp(cfg, lp, h, rng, deterministic=True)
-    return x + m, ckf, cvf, cksf, cvsf
+    return x + m, pools
+
+
+def _paged_write_plan(page_table, start, seq_mask, ps: int):
+    """How a block of tokens ``[B,S]`` at slot positions ``start + s`` lands
+    in whole pages: ``(src [B, n_pg*ps], keep [B, n_pg, ps], pages [B,
+    n_pg])``, one plan for all layers.
+
+    S consecutive positions fall in at most ``n_pg`` logical pages; row r
+    of the j-th of them holds position ``(start // ps + j) * ps + r``,
+    which is token ``src`` of the block if ``keep``.  Masked tokens and
+    positions past the page table are kept out of every page, so real
+    pages are never corrupted (a verify-k block past the table end must not
+    wrap into the clamped last page and overwrite confirmed K/V).  A page
+    none of whose rows is written is redirected to the trash page, so no
+    two slots ever write one real page and the scatter keeps its static
+    shape."""
+    B, S = seq_mask.shape
+    maxp = page_table.shape[1]
+    n_pg = (S + ps - 2) // ps + 1
+    lpage = (start // ps)[:, None] + jnp.arange(n_pg, dtype=jnp.int32)
+    src = (lpage[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)
+           - start[:, None, None])                         # [B,n_pg,ps]
+    in_block = (src >= 0) & (src < S) & (lpage < maxp)[:, :, None]
+    src = jnp.clip(src, 0, S - 1).reshape(B, n_pg * ps)
+    keep = in_block & jnp.take_along_axis(seq_mask, src,
+                                          axis=1).reshape(in_block.shape)
+    pages = jnp.where(
+        keep.any(-1),
+        jnp.take_along_axis(page_table, jnp.minimum(lpage, maxp - 1), axis=1),
+        0)
+    return src, keep, pages
 
 
 def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
@@ -1800,8 +1858,8 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     page (0 = the reserved trash page, also the unallocated filler).
     ``start [B]``: slot position of ``tokens[:, 0]`` (0 for prefill, the
     current length for decode).  ``seq_mask [B,S]``: True for real tokens —
-    False tokens' K/V are redirected to the trash page and their logits are
-    garbage (the caller reads logits only at real positions).
+    False tokens' K/V are written nowhere and their logits are garbage (the
+    caller reads logits only at real positions).
 
     One function, three static shapes at steady state — bucketed prefill
     ``[1, S_pad]``, fleet decode ``[B_slots, 1]``, and (with speculative
@@ -1810,16 +1868,23 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     distributions in one traversal (``inference/speculative.py``) — so
     admission into a running batch never recompiles.  Positions past the
     slot's page table (a verify block straddling the reserved region, or a
-    rejected-draft tail near ``max_model_len``) write to the trash page
-    rather than wrapping into the clamped last page, so multi-token decode
-    can never corrupt live K/V; their logits are garbage the caller never
-    reads.  Returns ``(logits [B,S,V], new_cache)``.
+    rejected-draft tail near ``max_model_len``) are dropped like masked
+    tokens rather than wrapping into the clamped last page, so multi-token
+    decode can never corrupt live K/V; their logits are garbage the caller
+    never reads.  Returns ``(logits [B,S,V], new_cache)``.
+
+    The pool stays in place for the whole program: its leaves ride the
+    layer scan as carry, stacked ``[L*P, page, ...]``, and each layer
+    gathers and scatters whole pages of the stack at ``l*P + page``
+    (:func:`_block_paged`) — no layer's slice of the pool is ever cut out,
+    re-laid out or written back, so the donated buffers are updated where
+    they lie.
 
     A quantized cache (``init_paged_cache(kv_dtype="int8")`` — extra
     ``k_scale``/``v_scale`` planes) runs the same three program shapes:
     writes quantize on store, the gather dequantizes, and the scale planes
-    scan through as two extra traced operands (docs/SERVING.md "Quantized
-    KV pages").
+    ride the carry as two more pool leaves (docs/SERVING.md "Quantized KV
+    pages").
 
     ``adapters`` (optional) is the per-slot LoRA operand pytree of
     multi-tenant adapter serving (docs/SERVING.md): ``{"scale": [B] f32,
@@ -1842,25 +1907,11 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         raise NotImplementedError(
             "paged decode does not support per-layer attention windows "
             "(attention_layers); use the contiguous cache path")
-    B, S = tokens.shape
-    num_pages, ps = cache["k"].shape[1], cache["k"].shape[2]
-    maxp = page_table.shape[1]
-
-    positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    raw_idx = positions // ps
-    page_idx = jnp.minimum(raw_idx, maxp - 1)
-    phys = jnp.take_along_axis(page_table, page_idx, axis=1)       # [B,S]
-    flat = phys * ps + positions % ps
-    # masked tokens AND positions past the page table write to the trash
-    # page (page 0, offset 0): the scatter keeps its static shape and real
-    # pages are never corrupted — without the in-table guard a verify-k
-    # block past the table end would silently wrap into the clamped last
-    # page and overwrite confirmed K/V
-    write_idx = jnp.where(seq_mask & (raw_idx < maxp),
-                          flat, 0).reshape(B * S)
-    gather_idx = (page_table[:, :, None] * ps
-                  + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
-                  ).reshape(B, maxp * ps)
+    num_layers, num_pages, ps = cache["k"].shape[:3]
+    positions = (start[:, None]
+                 + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :])
+    src, keep, write_pages = _paged_write_plan(page_table, start, seq_mask,
+                                               ps)
 
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
@@ -1873,51 +1924,40 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     x = constrain_spec(x, P(BATCH_AXES, None, None))
 
     rng = jax.random.PRNGKey(0)
-    quantized = "k_scale" in cache
     ad_scale = (adapters["scale"].astype(jnp.float32)
                 if adapters is not None else None)
 
-    def body(x, layer):
-        if adapters is not None:
-            layer, ad = layer[:-1], layer[-1]
-        else:
-            ad = None
-        if quantized:
-            lp, ck, cv, cks, cvs = layer
-            sks, svs = cks.reshape(num_pages * ps), cvs.reshape(num_pages * ps)
-        else:
-            lp, ck, cv = layer
-            sks = svs = None
-        x, ckf, cvf, cksf, cvsf = _block_paged(
-            cfg, lp, x,
-            ck.reshape(num_pages * ps, *ck.shape[2:]),
-            cv.reshape(num_pages * ps, *cv.shape[2:]),
-            positions, write_idx, gather_idx, rng, cksf=sks, cvsf=svs,
-            adapters=ad, ad_scale=ad_scale)
-        x = constrain_spec(x, P(BATCH_AXES, None, None))
-        out = (ckf.reshape(ck.shape), cvf.reshape(cv.shape))
-        if quantized:
-            out += (cksf.reshape(cks.shape), cvsf.reshape(cvs.shape))
-        return x, out
+    # The pool rides the layer scan as CARRY, stacked: [L*P, page, ...]
+    # merges its two major axes (free in any layout), and layer l's page p
+    # is page l*P + p.  Nothing may materialise one layer's slice: scanned
+    # as xs/ys, the pool was sliced, re-laid out and written back a layer
+    # at a time and copied whole around the loop, more than half of a
+    # decode tick (PERF.md, PR 25).
+    pools = {k: cache[k].reshape(-1, *cache[k].shape[2:])
+             for k in PAGED_POOL_KEYS if k in cache}
 
-    xs = (params["layers"], cache["k"], cache["v"])
-    if quantized:
-        xs += (cache["k_scale"], cache["v_scale"])
+    def body(carry, layer):
+        x, pools = carry
+        lp, first_page = layer[:2]
+        x, pools = _block_paged(
+            cfg, lp, x, pools, positions,
+            (src, keep, write_pages + first_page), page_table + first_page,
+            rng, adapters=layer[2] if adapters is not None else None,
+            ad_scale=ad_scale)
+        x = constrain_spec(x, P(BATCH_AXES, None, None))
+        return (x, pools), None
+
+    xs = (params["layers"],
+          jnp.arange(num_layers, dtype=jnp.int32) * num_pages)
     if adapters is not None:
         # per-slot factor stacks scan with the layers: each step's slice is
         # {target: {"A": [B,d_in,R], "B": [B,R,d_out]}} for THAT layer
         xs += (adapters["factors"],)
-    if quantized:
-        x, (ck_all, cv_all, cks_all, cvs_all) = jax.lax.scan(body, x, xs)
-    else:
-        x, (ck_all, cv_all) = jax.lax.scan(body, x, xs)
+    (x, pools), _ = jax.lax.scan(body, (x, pools), xs)
 
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
     logits = _lm_head(cfg, params, x)
-    new_cache = {"k": ck_all, "v": cv_all}
-    if quantized:
-        new_cache["k_scale"], new_cache["v_scale"] = cks_all, cvs_all
-    return logits, new_cache
+    return logits, {k: a.reshape(cache[k].shape) for k, a in pools.items()}
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,

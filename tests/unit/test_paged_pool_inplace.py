@@ -1,0 +1,289 @@
+"""The paged forward keeps the KV page pool in place across its layer scan
+(ISSUE 25).
+
+(a) Equivalence, twice.  Against a plain Python loop over layers that
+slices one layer's pool out of the stack, runs ``_block_paged`` on that
+private slice and stacks the slices back — what scanning the pool as
+``xs``/``ys`` computed — logits and every pool leaf must be ``array_equal``.
+Against a row-granular block written here (every token's row scattered at
+``page*page_size + offset`` with masked tokens sent to the trash row, every
+slot's rows gathered one by one, attention over ``T`` flat rows — the
+semantics the whole-page merge and gather replaced): every real page
+``array_equal``, logits to float32 rounding.  Shapes: the decode tick with
+an inactive slot, a padded tail prefill from an unaligned start, a verify-k
+block straddling the page table's end; pools: bf16 and int8 with scale
+planes; cases with adapters.
+
+(b) Structure: the layer scan's carry holds every pool leaf, nothing it
+scans over or stacks has the pool's page axis, and its body holds no
+``dynamic_slice``/``dynamic_update_slice`` of the pool and no gather or
+scatter that cuts a page — the ops that, one layer's slice or one row at a
+time, made the TPU compiler copy the pool (PERF.md, PR 25).
+"""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models import get_config, init_params
+from deepspeed_tpu.models.transformer import (_attn_out, _block_paged,
+                                              _lm_head, _mlp, _norm,
+                                              _paged_write_plan, _qkv,
+                                              _sm_scale, forward_paged,
+                                              init_paged_cache, kv_dequantize,
+                                              kv_quantize_rows)
+
+L, NUM_PAGES, PAGE, B = 3, 9, 8, 3   # 4 pages a slot: max_model_len 32
+CFG = get_config("tiny-gqa", num_layers=L, dtype=jnp.float32)
+# slot -> physical pages; 0 = unallocated (the trash page)
+PAGE_TABLE = np.array([[3, 1, 7, 5], [2, 8, 4, 0], [6, 0, 0, 0]], np.int32)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(CFG, jax.random.PRNGKey(11))
+
+
+def _filled_pool(kv_dtype, seed=5):
+    """A pool holding random rows everywhere, so a row written to or read
+    from the wrong layer or page changes the result."""
+    cache = init_paged_cache(CFG, NUM_PAGES, PAGE, dtype=jnp.bfloat16,
+                             kv_dtype=kv_dtype)
+    rng = np.random.default_rng(seed)
+    out = {}
+    for k, a in cache.items():
+        if a.dtype == jnp.int8:
+            out[k] = jnp.asarray(rng.integers(-127, 128, a.shape), jnp.int8)
+        elif k.endswith("_scale"):
+            out[k] = jnp.asarray(rng.uniform(0.001, 0.02, a.shape),
+                                 jnp.float32)
+        else:
+            out[k] = jnp.asarray(rng.normal(size=a.shape), a.dtype)
+    return out
+
+
+def _shape_case(name):
+    """tokens, page_table, start, seq_mask of one program shape."""
+    rng = np.random.default_rng(17)
+    if name == "decode":          # [B,1], slot 2 inactive
+        tokens = rng.integers(1, 250, (B, 1))
+        start = [5, 17, 0]
+        mask = np.array([[True], [True], [False]])
+        table = PAGE_TABLE
+    elif name == "prefill":       # [1,S_pad] tail prefill from inside a page
+        tokens = rng.integers(1, 250, (1, 16))
+        start = [11]
+        mask = (np.arange(16) < 9)[None, :]
+        table = PAGE_TABLE[:1]
+    else:                         # verify-k [B,k+1]; slot 0 runs past row 31
+        tokens = rng.integers(1, 250, (B, 4))
+        start = [30, 12, 3]
+        mask = np.ones((B, 4), bool)
+        table = PAGE_TABLE
+    return (jnp.asarray(tokens, jnp.int32), jnp.asarray(table),
+            jnp.asarray(start, jnp.int32), jnp.asarray(mask))
+
+
+def _adapters(seed=23, rank=4):
+    rng = np.random.default_rng(seed)
+    d, hq = CFG.hidden_size, CFG.num_heads * CFG.dims_per_head
+    dims = {"wq": (d, hq), "wo": (hq, d)}
+    return {"scale": jnp.asarray([0.5, 0.0, 2.0], jnp.float32),
+            "factors": {
+                t: {"A": jnp.asarray(rng.normal(size=(L, B, di, rank)) * 0.1,
+                                     jnp.float32),
+                    "B": jnp.asarray(rng.normal(size=(L, B, rank, do)) * 0.1,
+                                     jnp.float32)}
+                for t, (di, do) in dims.items()}}
+
+
+def _forward_layers(block, params, tokens, cache, adapters):
+    """embed -> ``block(lp, x, one layer's pool leaves, its factors)`` for
+    each layer in a Python loop -> head; the slices stacked back."""
+    x = params["embed"].astype(CFG.dtype)[tokens]
+    new = {k: [] for k in cache}
+    for layer in range(CFG.num_layers):
+        lp = jax.tree_util.tree_map(lambda a: a[layer], params["layers"])
+        ad = (jax.tree_util.tree_map(lambda a: a[layer], adapters["factors"])
+              if adapters is not None else None)
+        x, out = block(lp, x, {k: a[layer] for k, a in cache.items()}, ad)
+        for k in cache:
+            new[k].append(out[k])
+    x = _norm(CFG, x, params["final_norm_scale"],
+              params.get("final_norm_bias"))
+    return _lm_head(CFG, params, x), {k: jnp.stack(v) for k, v in new.items()}
+
+
+def _forward_sliced(params, tokens, cache, page_table, start, seq_mask,
+                    adapters=None):
+    """The pool handled one layer's slice at a time: what ``forward_paged``
+    computed with the pool as scan xs/ys."""
+    positions = start[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)
+    write = _paged_write_plan(page_table, start, seq_mask,
+                              cache["k"].shape[2])
+
+    def block(lp, x, pools, ad):
+        return _block_paged(
+            CFG, lp, x, pools, positions, write, page_table,
+            jax.random.PRNGKey(0), adapters=ad,
+            ad_scale=None if adapters is None else adapters["scale"])
+
+    return _forward_layers(block, params, tokens, cache, adapters)
+
+
+def _forward_rows(params, tokens, cache, page_table, start, seq_mask):
+    """Row-granular paging: one scatter index per token (masked tokens and
+    positions past the table go to row 0 of the trash page), one gather
+    index per row of each slot, attention over T flat rows."""
+    cfg = CFG
+    Bq, S = tokens.shape
+    ps, maxp = cache["k"].shape[2], page_table.shape[1]
+    hd, nkv, G = cfg.dims_per_head, cfg.kv_heads, cfg.num_heads // cfg.kv_heads
+    positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    raw = positions // ps
+    phys = jnp.take_along_axis(page_table, jnp.minimum(raw, maxp - 1), axis=1)
+    write_idx = jnp.where(seq_mask & (raw < maxp),
+                          phys * ps + positions % ps, 0).reshape(Bq * S)
+    gather_idx = (page_table[:, :, None] * ps
+                  + jnp.arange(ps, dtype=jnp.int32)).reshape(Bq, maxp * ps)
+    t = jnp.arange(maxp * ps, dtype=jnp.int32)
+
+    def block(lp, x, pools, _):
+        flat = {k: a.reshape(-1, *a.shape[2:]) for k, a in pools.items()}
+        h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
+        q, k, v = _qkv(cfg, lp, h, positions)
+        k, v = (a.reshape(Bq * S, nkv, hd) for a in (k, v))
+        if "k_scale" in flat:
+            (k, ks), (v, vs) = kv_quantize_rows(k), kv_quantize_rows(v)
+            flat["k_scale"] = flat["k_scale"].at[write_idx].set(ks)
+            flat["v_scale"] = flat["v_scale"].at[write_idx].set(vs)
+        flat["k"] = flat["k"].at[write_idx].set(k.astype(flat["k"].dtype))
+        flat["v"] = flat["v"].at[write_idx].set(v.astype(flat["v"].dtype))
+        ck, cv = flat["k"][gather_idx], flat["v"][gather_idx]
+        if "k_scale" in flat:
+            ck = kv_dequantize(ck, flat["k_scale"][gather_idx], cfg.dtype)
+            cv = kv_dequantize(cv, flat["v_scale"][gather_idx], cfg.dtype)
+        scores = jnp.einsum("bskgd,btkd->bkgst",
+                            q.reshape(Bq, S, nkv, G, hd), ck)
+        scores = scores.astype(jnp.float32) * _sm_scale(cfg, hd)
+        ok = t[None, None, :] <= positions[:, :, None]
+        scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+        attn = jnp.einsum("bkgst,btkd->bskgd", probs, cv)
+        x = x + _attn_out(cfg, lp, attn.reshape(Bq, S, -1, hd))
+        h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
+        m, _ = _mlp(cfg, lp, h, jax.random.PRNGKey(0), deterministic=True)
+        return x + m, {k: a.reshape(pools[k].shape) for k, a in flat.items()}
+
+    return _forward_layers(block, params, tokens, cache, None)
+
+
+CASES = [(shape, kv, False) for shape in ("decode", "prefill", "verify")
+         for kv in (None, "int8")] + [("decode", None, True),
+                                      ("verify", "int8", True)]
+
+
+@pytest.mark.parametrize(
+    "shape,kv_dtype,with_adapters", CASES,
+    ids=[f"{s}-{kv or 'bf16'}{'-adapters' if ad else ''}"
+         for s, kv, ad in CASES])
+def test_forward_paged_equals_per_layer_slices(params, shape, kv_dtype,
+                                               with_adapters):
+    tokens, table, start, mask = _shape_case(shape)
+    cache = _filled_pool(kv_dtype)
+    adapters = _adapters() if with_adapters else None
+    want_logits, want = jax.jit(_forward_sliced)(
+        params, tokens, cache, table, start, mask, adapters)
+    got_logits, got = jax.jit(functools.partial(forward_paged, CFG))(
+        params, tokens, cache, table, start, mask, adapters)
+    assert sorted(got) == sorted(cache)
+    for k in cache:
+        assert got[k].shape == cache[k].shape and got[k].dtype == cache[k].dtype
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]),
+                                      err_msg=f"pool leaf {k}")
+        # the forward did write: a pool handed back untouched proves nothing
+        assert not np.array_equal(np.asarray(got[k]), np.asarray(cache[k]))
+    real = np.asarray(mask)
+    np.testing.assert_array_equal(np.asarray(got_logits)[real],
+                                  np.asarray(want_logits)[real])
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+@pytest.mark.parametrize("shape", ["decode", "prefill", "verify"])
+def test_page_merge_equals_row_scatter_and_gather(params, shape, kv_dtype):
+    tokens, table, start, mask = _shape_case(shape)
+    cache = _filled_pool(kv_dtype)
+    want_logits, want = jax.jit(_forward_rows)(
+        params, tokens, cache, table, start, mask)
+    got_logits, got = jax.jit(functools.partial(forward_paged, CFG))(
+        params, tokens, cache, table, start, mask)
+    for k in cache:
+        # page 0 is the trash page: the row scatter dumps masked tokens
+        # there, the page merge writes them nowhere
+        np.testing.assert_array_equal(np.asarray(got[k])[:, 1:],
+                                      np.asarray(want[k])[:, 1:],
+                                      err_msg=f"pool leaf {k}")
+    real = np.asarray(mask)
+    np.testing.assert_allclose(np.asarray(got_logits)[real],
+                               np.asarray(want_logits)[real],
+                               rtol=1e-5, atol=1e-5)
+
+
+def _sub_jaxprs(jaxpr):
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for j in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    yield from _sub_jaxprs(inner)
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_layer_scan_carries_the_pool_in_place(params, kv_dtype):
+    tokens, table, start, mask = _shape_case("decode")
+    cache = _filled_pool(kv_dtype)
+    jaxpr = jax.make_jaxpr(functools.partial(forward_paged, CFG))(
+        params, tokens, cache, table, start, mask, _adapters()).jaxpr
+    scans = [e for j in _sub_jaxprs(jaxpr) for e in j.eqns
+             if e.primitive.name == "scan" and e.params["length"] == L]
+    assert len(scans) == 1, "one layer scan"
+    scan = scans[0]
+    n_const, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    carry = [v.aval for v in scan.invars[n_const:n_const + n_carry]]
+    scanned = [v.aval for v in scan.invars[n_const + n_carry:]]
+    assert not scan.outvars[n_carry:], "the scan stacks nothing"
+
+    stacked = {k: (L * NUM_PAGES,) + a.shape[2:] for k, a in cache.items()}
+    for k, a in cache.items():
+        assert any(c.shape == stacked[k] and c.dtype == a.dtype
+                   for c in carry), \
+            f"pool leaf {k} {stacked[k]} is not in the scan's carry"
+    for a in scanned:
+        assert a.shape[1:3] != (NUM_PAGES, PAGE), \
+            f"the scan slices something with the page axis: {a}"
+
+    # inside the body the pool is only ever gathered from and scattered
+    # into, whole pages at a time: no op cuts a layer or a row out of it
+    pool_shapes = set(stacked.values())
+    for a in cache.values():
+        pool_shapes |= {a.shape, a.shape[1:]}
+    seen = set()
+    for j in _sub_jaxprs(scan.params["jaxpr"].jaxpr):
+        for e in j.eqns:
+            shape = e.invars[0].aval.shape if e.invars else None
+            if shape not in pool_shapes:
+                continue
+            name = e.primitive.name
+            seen.add(name)
+            assert name not in ("dynamic_slice", "dynamic_update_slice"), \
+                f"{name} of the pool in the layer body: {e}"
+            if name == "gather":
+                assert tuple(e.params["slice_sizes"][1:]) == shape[1:], e
+            if name == "scatter":
+                dn = e.params["dimension_numbers"]
+                assert len(dn.update_window_dims) == len(shape) - 1, e
+    assert {"scatter", "gather"} <= seen
