@@ -118,11 +118,25 @@ class InferenceEngine:
                 shardings = jax.tree_util.tree_map(
                     lambda s: NamedSharding(mesh, s), specs,
                     is_leaf=lambda x: isinstance(x, P))
-                cast = lambda x: x.astype(dtype) if hasattr(x, "dtype") and jnp.issubdtype(  # noqa: E731
-                    x.dtype, jnp.floating) else x
-                # one-shot init-time cast+placement, discarded after load
-                self.params = jax.jit(lambda p: jax.tree_util.tree_map(cast, p),   # dslint: disable=recompile-hazard
-                                      out_shardings=shardings)(params)
+                # one-shot init-time cast+placement.  Only the leaves that
+                # are not yet in the engine's dtype go through the cast (one
+                # program for all of them); a leaf already in it is placed
+                # where it lies, so a tree born in the serving dtype on the
+                # device is never on it twice (a 10 GB model on a 16 GB chip)
+                leaves, treedef = jax.tree_util.tree_flatten(params)
+                placement = treedef.flatten_up_to(shardings)
+                todo = [i for i, x in enumerate(leaves)
+                        if hasattr(x, "dtype") and x.dtype != dtype
+                        and jnp.issubdtype(x.dtype, jnp.floating)]
+                if todo:
+                    done = jax.jit(   # dslint: disable=recompile-hazard
+                        lambda xs: [x.astype(dtype) for x in xs],
+                        out_shardings=[placement[i] for i in todo])(
+                            [leaves[i] for i in todo])
+                    for i, x in zip(todo, done):
+                        leaves[i] = x
+                self.params = treedef.unflatten(
+                    [jax.device_put(x, s) for x, s in zip(leaves, placement)])
         else:
             self.params = None
         if self._quant:

@@ -50,7 +50,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, cow_copy_pool,
-                                  paged_pool_cache, paged_pool_tuple)
+                                  expert_counts_shape, paged_pool_cache,
+                                  paged_pool_tuple)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .kv_tiering import extract_pool_page, inject_pool_page
@@ -186,6 +187,10 @@ class MeshExecutor:
         self.page_size = int(page_size)
         self.b_slots = int(b_slots)
         cfg = model.config
+        # (layers, experts) of a model whose expert layers are dropless:
+        # its decode and prefill programs append the rows each expert
+        # computed to the token output (split_counts); None for any other
+        self.moe_shape = expert_counts_shape(cfg)
         self.tp = 1
         if mesh is not None:
             if "model" not in mesh.axis_names:
@@ -303,21 +308,47 @@ class MeshExecutor:
 
     # ------------------------------------------------------------ programs
 
+    def _apply_paged(self, *args, **kw):
+        """``model.apply_paged`` -> ``(logits, cache, counts)``: the rows
+        each expert of each layer computed where the model hands them back
+        (``moe_shape``), else ``None``."""
+        if self.moe_shape is None:
+            return (*self.model.apply_paged(*args, **kw), None)
+        return self.model.apply_paged(*args, expert_counts=True, **kw)
+
+    @staticmethod
+    def _with_counts(nxt, counts):
+        """A program's one small output: the sampled token(s), followed by
+        the expert counts where the model has any, so the host's one fetch
+        of the tokens brings what the router did with it."""
+        if counts is None:
+            return nxt
+        return jnp.concatenate([nxt.reshape(-1).astype(jnp.int32),
+                                counts.reshape(-1)])
+
+    def split_counts(self, out: np.ndarray):
+        """The fetched output of :meth:`decode` or :meth:`prefill` ->
+        ``(tokens, counts [L, E] or None)``."""
+        if self.moe_shape is None:
+            return out, None
+        n = out.size - self.moe_shape[0] * self.moe_shape[1]
+        return out[:n], out[n:].reshape(self.moe_shape)
+
     def _build_decode(self):
-        apply_paged = self.model.apply_paged
+        apply_paged, with_counts = self._apply_paged, self._with_counts
 
         if self.adapters is not None:
             def prog(params, pools, page_table, lengths, last_tok, active,
                      temp, top_k, top_p, seeds, adapters):
                 cache = paged_pool_cache(pools)
-                logits, cache = apply_paged(
-                    params, last_tok[:, None], cache, page_table, lengths,
-                    active[:, None], adapters=adapters)
+                logits, cache, counts = apply_paged(
+                    params, last_tok[:lengths.shape[0], None], cache,
+                    page_table, lengths, active[:, None], adapters=adapters)
                 with jax.named_scope("sample"):
                     nxt = sample_tokens(
                         logits[:, -1, :], temp, top_k, top_p,
                         lambda: position_keys(seeds, lengths + 1))
-                return nxt, paged_pool_tuple(cache)
+                return with_counts(nxt, counts), paged_pool_tuple(cache)
 
             return _named_pool_jit(prog, "serve_decode", self.mesh,
                                    self._pool_specs)
@@ -331,19 +362,23 @@ class MeshExecutor:
             # generate(sampling=...) and a replay/failover re-prefill
             # derive, which is what keeps sampled streams engine-
             # independent and resume-exact (docs/SERVING.md "Sampling").
+            # `last_tok` has the shape of the program's own first output
+            # (tokens, then an MoE model's expert counts), so a tick can be
+            # fed the one before it without a fetch (ServingEngine lookahead)
             cache = paged_pool_cache(pools)
-            logits, cache = apply_paged(params, last_tok[:, None], cache,
-                                        page_table, lengths, active[:, None])
+            logits, cache, counts = apply_paged(
+                params, last_tok[:lengths.shape[0], None], cache, page_table,
+                lengths, active[:, None])
             with jax.named_scope("sample"):
                 nxt = sample_tokens(logits[:, -1, :], temp, top_k, top_p,
                                     lambda: position_keys(seeds, lengths + 1))
-            return nxt, paged_pool_tuple(cache)
+            return with_counts(nxt, counts), paged_pool_tuple(cache)
 
         return _named_pool_jit(prog, "serve_decode", self.mesh,
                                self._pool_specs)
 
     def _build_prefill(self, s_pad: int):
-        apply_paged = self.model.apply_paged
+        apply_paged, with_counts = self._apply_paged, self._with_counts
 
         if self.adapters is not None:
             def prog(params, pools, pt_row, tokens, n_real, start,
@@ -351,16 +386,16 @@ class MeshExecutor:
                 seq_mask = (jnp.arange(s_pad, dtype=jnp.int32)
                             < n_real)[None, :]
                 cache = paged_pool_cache(pools)
-                logits, cache = apply_paged(params, tokens, cache, pt_row,
-                                            start[None], seq_mask,
-                                            adapters=adapters)
+                logits, cache, counts = apply_paged(
+                    params, tokens, cache, pt_row, start[None], seq_mask,
+                    adapters=adapters)
                 with jax.named_scope("sample"):
                     lg = logits[0, n_real - 1, :][None]        # [1, V]
                     nxt = sample_tokens(
                         lg, temp, top_k, top_p,
                         lambda: position_keys(seed,
                                               (start + n_real)[None]))[0]
-                return nxt, paged_pool_tuple(cache)
+                return with_counts(nxt, counts), paged_pool_tuple(cache)
 
             return _named_pool_jit(prog, f"serve_prefill_{s_pad}", self.mesh,
                                    self._pool_specs)
@@ -379,8 +414,8 @@ class MeshExecutor:
             # A traced scalar: every start shares ONE program per bucket.
             seq_mask = (jnp.arange(s_pad, dtype=jnp.int32) < n_real)[None, :]
             cache = paged_pool_cache(pools)
-            logits, cache = apply_paged(params, tokens, cache, pt_row,
-                                        start[None], seq_mask)
+            logits, cache, counts = apply_paged(params, tokens, cache, pt_row,
+                                                start[None], seq_mask)
             # the emitted token will sit at stream position S = start +
             # n_real — the counter-based key generate(sampling=...) and
             # every replay/failover resume re-derive for the same position
@@ -389,7 +424,7 @@ class MeshExecutor:
                 nxt = sample_tokens(
                     lg, temp, top_k, top_p,
                     lambda: position_keys(seed, (start + n_real)[None]))[0]
-            return nxt, paged_pool_tuple(cache)
+            return with_counts(nxt, counts), paged_pool_tuple(cache)
 
         return _named_pool_jit(prog, f"serve_prefill_{s_pad}", self.mesh,
                                self._pool_specs)
@@ -414,17 +449,34 @@ class MeshExecutor:
     # first sight, count the dispatch, sample the synced wall time on the
     # picked invocations (docs/OBSERVABILITY.md "Per-program accounting").
 
+    def _token_sharding(self):
+        """Where a program's token output lands: replicated over the mesh
+        (``pool_jit``'s ``out_shardings``), else where the pool lies."""
+        if self.mesh is not None:
+            return NamedSharding(self.mesh, P())
+        return self.pools[0].sharding
+
     def decode(self, page_table, lengths, last_tok, active, lanes,
                adapters=None):
         """One fixed-shape decode step over all slots; returns the sampled
         [B_slots] token vector (device array — the caller fetches inside
-        its watchdog window) and updates the pools in place.  With an
+        its watchdog window; with expert counts behind it where the model
+        has them, :meth:`split_counts`) and updates the pools in place.
+        ``last_tok`` is the host's [B_slots] vector or the device array the
+        previous call returned, as it is.  With an
         adapter registry attached, ``adapters`` is the per-slot factor
         pytree (``adapter_stacks``); ``None`` rides the cached all-zero
         stacks (base-model traffic) — the program signature never changes."""
+        if isinstance(last_tok, np.ndarray):
+            if self.moe_shape is not None:
+                last_tok = np.concatenate([last_tok, np.zeros(
+                    self.moe_shape[0] * self.moe_shape[1], last_tok.dtype)])
+            # placed as the program's own output is, so that feeding that
+            # output back is the same program and not a second compile
+            last_tok = jax.device_put(last_tok, self._token_sharding())
         args = (self.params, self.pools,
                 jnp.asarray(page_table), jnp.asarray(lengths),
-                jnp.asarray(last_tok), jnp.asarray(active), *lanes)
+                last_tok, jnp.asarray(active), *lanes)
         if self.adapters is not None:
             args += (adapters if adapters is not None
                      else self._adapter_zero(),)
@@ -437,7 +489,8 @@ class MeshExecutor:
     def prefill(self, s_pad: int, pt_row, tokens, n_real, start,
                 lane_t, lane_k, lane_p, lane_s, adapter_row=None):
         """One bucketed prefill ([1, s_pad]); returns the first sampled
-        token (device scalar) and updates the pools.  Builds the bucket's
+        token (device scalar; a vector led by it where the model has expert
+        counts, :meth:`split_counts`) and updates the pools.  Builds the bucket's
         program on first use — the bucket set IS the program inventory.
         ``adapter_row`` is the admitted slot's one-slot factor slice
         (:meth:`adapter_row`) when a registry rides along."""

@@ -327,6 +327,30 @@ class _Slot:
     token_s: List[float] = dataclasses.field(default_factory=list)
 
 
+# decode ticks kept launched ahead of the one being fetched (docs/SERVING.md
+# "Decode lookahead"): what the device has queued when the host stands still.
+# One is enough to hide the host's own work of a tick; eight ride out a stall
+# of a few ticks' length (PERF.md §6, PR 26: ~115 ms, two to four a run).
+LOOKAHEAD_TICKS = 8
+
+
+@dataclasses.dataclass
+class _Ahead:
+    """A decode tick launched before the ticks ahead of it were fetched: its
+    device output, the output it was fed, and everything of the host's
+    state it was launched on.  It is used only if the state it finds when
+    its turn comes is that state (:meth:`ServingEngine._take_ahead`), so no
+    path that changes a slot between two ticks has to know that it exists."""
+    out: Any
+    fed: Any
+    page_table: np.ndarray
+    lengths: np.ndarray
+    active: np.ndarray
+    params: Any
+    lanes: Any
+    adapters: Any
+
+
 class ServingEngine:
     """Iteration-level scheduler over a fixed slot fleet + paged KV pool.
 
@@ -348,7 +372,8 @@ class ServingEngine:
                  speculative: Optional[SpeculativeConfig] = None,
                  program_stats_sample_every: int = 0,
                  slo_rules: Optional[List[SloRule]] = None,
-                 adapters: Optional[AdapterRegistry] = None):
+                 adapters: Optional[AdapterRegistry] = None,
+                 lookahead: bool = True):
         if not hasattr(model, "apply_paged"):
             raise ValueError(
                 "ServingEngine needs a model with the paged decode contract "
@@ -375,6 +400,15 @@ class ServingEngine:
                 f"+ the trash page)")
         self.monitor = monitor
         self.watchdog = watchdog
+        # decode lookahead (docs/SERVING.md "Decode lookahead"): launch tick
+        # t+1 on tick t's device-resident tokens before fetching them
+        self.lookahead = bool(lookahead)
+        self._ahead: Deque[_Ahead] = deque()
+        # the last tick emitted: (device output, its tokens on the host)
+        self._last_out: Optional[tuple] = None
+        self._in_run = False
+        self.lookahead_launched = 0
+        self.lookahead_dropped = 0
         # bounded admission: submissions past max_queue waiting requests are
         # shed with a typed result + retry-after hint (None = unbounded)
         self.max_queue = int(max_queue) if max_queue is not None else None
@@ -1498,7 +1532,8 @@ class ServingEngine:
             self._exec.invalidate_adapters()
             adapter_row = self._exec.adapter_row(self._adapter_stacks, slot)
         with trace_span("serve.prefill", rid=req.rid, slot=slot,
-                        bucket=s_pad, tokens=S_tail, shared_tokens=n_shared):
+                        bucket=s_pad, tokens=S_tail,
+                        shared_tokens=n_shared) as sp:
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 if match.cow_src is not None:
@@ -1518,10 +1553,14 @@ class ServingEngine:
                                        private[0])
                 pt_row = jnp.asarray(self._page_table[slot:slot + 1])
                 toks_j = jnp.asarray(toks)
-                tok = int(self._exec.prefill(
-                    s_pad, pt_row, toks_j, S_tail, n_shared,
-                    lane_t, lane_k, lane_p, lane_s, adapter_row))
+                out, counts = self._exec.split_counts(np.asarray(
+                    self._exec.prefill(
+                        s_pad, pt_row, toks_j, S_tail, n_shared,
+                        lane_t, lane_k, lane_p, lane_s, adapter_row)))
+                tok = int(out.flat[0])
                 # host fetch above lands inside the watchdog window
+                if counts is not None and get_tracer().enabled:
+                    self._set_moe_attrs(sp, counts, S_tail)
                 if self._spec is not None:
                     # draft-pool prefill of the same tail (same bucket,
                     # page-table row, start) — the draft emits nothing
@@ -1613,11 +1652,88 @@ class ServingEngine:
             return None
         return self._exec.adapter_stacks(self._adapter_stacks)
 
+    def _set_moe_attrs(self, sp, counts: np.ndarray, live_tokens: int
+                       ) -> None:
+        """What the router did in one program call of an MoE model, on the
+        call's span: from the rows each expert of each layer computed
+        (``counts [L, E]``, fetched with the tokens) and the real tokens
+        the call was given."""
+        sp.set(moe_live_rows=(live_tokens * self.model.config.moe_top_k
+                              * counts.shape[0]),
+               moe_rows=int(counts.sum()),
+               moe_experts_touched=int((counts > 0).sum()),
+               moe_max_load=int(counts.max()))
+
+    def _lookahead_depth(self) -> int:
+        """How many ticks after this one can be launched now, at most
+        ``LOOKAHEAD_TICKS``: those whose inputs are this tick's, so many
+        positions on.  Every live slot is still live then (none reaches its
+        length before, none can stop on a token the host has not seen yet),
+        and no arrival can be put behind them: every usable slot is busy,
+        or admission is closed, or this ``run()`` has nothing queued or
+        still to arrive.  A guess, not a guard: what was launched is
+        checked again when its turn comes."""
+        catalog = self._exec.catalog
+        if not self.lookahead or (catalog is not None
+                                  and catalog.sample_every):
+            return 0    # a sampled dispatch syncs on its own output
+        if not (int(self._active.sum()) >= self._usable_slots()
+                or self._draining
+                or (self._in_run and not self._queue and not self._pending)):
+            return 0
+        depth = LOOKAHEAD_TICKS
+        for slot in np.flatnonzero(self._active):
+            st = self._slots[slot]
+            if st.request.eos_token_id is not None:
+                return 0
+            depth = min(depth, st.request.max_new_tokens - len(st.tokens) - 1)
+        return max(depth, 0)
+
+    def _take_ahead(self, lanes, adapters):
+        """The device output of this tick if it was launched ahead on
+        exactly the state the host now holds, else ``None``, and then every
+        tick launched after it goes too (what they wrote is one K/V row a
+        slot each, past the slot's length, which the ticks launched in
+        their place write again)."""
+        if not self._ahead:
+            return None
+        ahead = self._ahead.popleft()
+        if (self._last_out is not None and ahead.fed is self._last_out[0]
+                and ahead.params is self._exec.params
+                and ahead.lanes is lanes and ahead.adapters is adapters
+                and np.array_equal(ahead.active, self._active)
+                and np.array_equal(ahead.lengths, self._lengths)
+                and np.array_equal(ahead.page_table, self._page_table)
+                and np.array_equal(self._last_out[1][ahead.active],
+                                   self._last_tok[self._active])):
+            return ahead.out
+        self.lookahead_dropped += 1 + len(self._ahead)
+        self._ahead.clear()
+        return None
+
+    def _launch_ahead(self, nxt, lanes, adapters) -> None:
+        """Top the queue of launched ticks up to :meth:`_lookahead_depth`:
+        each on the output of the one before it where it lies on the
+        device (``nxt``: this tick's), so the device goes from one program
+        into the next while the host fetches, emits and schedules."""
+        depth = self._lookahead_depth()
+        while len(self._ahead) < depth:
+            fed = self._ahead[-1].out if self._ahead else nxt
+            lengths = self._lengths + np.int32(len(self._ahead) + 1) * \
+                self._active.astype(np.int32)
+            self._ahead.append(_Ahead(
+                self._exec.decode(self._page_table, lengths, fed,
+                                  self._active, lanes, adapters=adapters),
+                fed, self._page_table.copy(), lengths, self._active.copy(),
+                self._exec.params, lanes, adapters))
+            self.lookahead_launched += 1
+
     def _decode_tick(self, rid_map: Optional[Dict[str, str]] = None) -> None:
         if self._spec is not None:
             self._spec_tick(rid_map)
             return
         lanes = self._lanes_jnp()
+        adapters = self._adapter_operand()
         with trace_span("serve.decode", tick=self._tick) as sp:
             t_open = time.monotonic() if rid_map is not None else 0.0
             # tick-level slot→rid map (docs/OBSERVABILITY.md "Distributed
@@ -1630,9 +1746,12 @@ class ServingEngine:
                 sp.set(slot_rids=rid_map)
             maybe_fire(SITE_SERVE_DECODE, tick=self._tick)
             with self._armed(f"serve.decode tick {self._tick}"):
-                nxt = self._exec.decode(self._page_table, self._lengths,
-                                        self._last_tok, self._active, lanes,
-                                        adapters=self._adapter_operand())
+                nxt = self._take_ahead(lanes, adapters)
+                if nxt is None:
+                    nxt = self._exec.decode(self._page_table, self._lengths,
+                                            self._last_tok, self._active,
+                                            lanes, adapters=adapters)
+                self._launch_ahead(nxt, lanes, adapters)
                 if rid_map is not None:
                     # the launch has returned; what is left of the span is
                     # the wait for the device in the fetch below.  Rows the
@@ -1642,7 +1761,14 @@ class ServingEngine:
                            live_rows=int(self._lengths[self._active].sum()),
                            gathered_rows=(self._page_table.size
                                           * self.page_size))
-                nxt = np.asarray(nxt)   # host fetch = device sync
+                # host fetch = device sync; an MoE model's expert counts
+                # come with the tokens
+                out = nxt
+                nxt, counts = self._exec.split_counts(np.asarray(out))
+                self._last_out = (out, nxt)
+                if counts is not None and rid_map is not None:
+                    self._set_moe_attrs(sp, counts,
+                                        int(self._active.sum()))
         t_tok = time.monotonic()   # every token of this tick: its emit stamp
         active_slots = np.flatnonzero(self._active)
         trace_count("serve.tokens", float(len(active_slots)))
@@ -1803,7 +1929,7 @@ class ServingEngine:
                 with self._armed(f"serve.probe slot={slot}"):
                     # greedy lane — the same program shape admissions use;
                     # the host fetch means the probe must really complete
-                    int(self._exec.prefill(
+                    np.asarray(self._exec.prefill(
                         s_pad, jnp.asarray(self._page_table[slot:slot + 1]),
                         jnp.asarray(toks), 1, 0, 0.0, 0, 1.0, 0))
         except BaseException as e:
@@ -1930,6 +2056,14 @@ class ServingEngine:
         start_tick = self._tick    # max_ticks bounds THIS run on a reused engine
         for req in requests or []:
             self.submit(req)
+        self._in_run = True    # nothing arrives that is not in _pending
+        try:
+            self._run_loop(start_tick, max_ticks)
+        finally:
+            self._in_run = False
+        return self.take_results()
+
+    def _run_loop(self, start_tick: int, max_ticks: Optional[int]) -> None:
         while True:
             pending = self.step()
             if pending == 0:
@@ -1980,7 +2114,6 @@ class ServingEngine:
                         f"{acct['free']} free ({acct['quarantined']} "
                         f"quarantined, {acct['referenced']} referenced) "
                         f"with no slot active")
-        return self.take_results()
 
     def take_results(self) -> List[RequestResult]:
         """Claim every finished result (completion order) and release their
@@ -2056,6 +2189,10 @@ class ServingEngine:
             "prefix_index_entries": (len(self._prefix)
                                      if self._prefix is not None else 0),
             "cow_copies_total": self.cow_copies,
+            # decode lookahead: ticks launched before the one ahead of them
+            # was fetched, and those of them found stale and not used
+            "lookahead_launched_total": self.lookahead_launched,
+            "lookahead_dropped_total": self.lookahead_dropped,
             # KV-page tiering (docs/SERVING.md "KV-page tiering"): the
             # demoted ledger and host-tier footprint, plus the cumulative
             # movement counters — what capacity planning reads to size the

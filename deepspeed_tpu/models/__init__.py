@@ -203,9 +203,10 @@ class CausalLM:
         return paged_cache_specs(self.config, kv_dtype=kv_dtype)
 
     def apply_paged(self, params, tokens, cache, page_table, start, seq_mask,
-                    adapters=None):
+                    adapters=None, expert_counts=False):
         return forward_paged(self.config, params, tokens, cache, page_table,
-                             start, seq_mask, adapters=adapters)
+                             start, seq_mask, adapters=adapters,
+                             expert_counts=expert_counts)
 
     @property
     def param_count(self) -> int:

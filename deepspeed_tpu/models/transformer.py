@@ -82,6 +82,10 @@ class TransformerConfig:
     # softmax scale override: GPT-Neo applies NO 1/sqrt(hd) scaling
     # (modeling_gpt_neo scales by 1.0); None = the standard 1/sqrt(hd)
     attn_softmax_scale: Optional[float] = None
+    # QK-norm (OLMoE): the norm of ``norm`` with a learned scale over the
+    # WHOLE q and k projections, before the head split and the rotary
+    # embedding; two more leaves a layer (q_norm_scale, k_norm_scale)
+    qk_norm: bool = False
     tie_embeddings: bool = False
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -98,6 +102,10 @@ class TransformerConfig:
     moe_min_capacity: int = 8
     moe_aux_loss_coef: float = 0.01
     moe_drop_tokens: bool = True              # False => ragged no-drop path
+    # top-k gates renormalised to sum to 1 (GShard top-2, Mixtral); False
+    # keeps the k largest softmax probabilities as they are (OLMoE
+    # ``norm_topk_prob=false``).  Top-1 never renormalises.
+    moe_norm_topk_prob: bool = True
     # residual MoE (PR-MoE, reference moe/layer.py use_residual): each MoE
     # layer also runs a dense MLP; outputs mix via a learned 2-way coefficient
     moe_use_residual: bool = False
@@ -157,6 +165,8 @@ class TransformerConfig:
         attn = d * hd * nh + 2 * d * hd * nkv + hd * nh * d
         if self.attn_bias:
             attn += nh * hd + 2 * nkv * hd + d
+        if self.qk_norm:
+            attn += nh * hd + nkv * hd
         mlp = 3 * d * f if self.activation == "swiglu" else 2 * d * f
         if self.mlp_bias:
             mlp += (2 * f if self.activation == "swiglu" else f) + d
@@ -231,6 +241,14 @@ CONFIGS: Dict[str, TransformerConfig] = {
     "llama-740m": TransformerConfig(
         vocab_size=32000, hidden_size=1792, intermediate_size=4864, num_layers=16,
         num_heads=14, max_seq_len=4096),
+    # allenai/OLMoE-1B-7B-0125-Instruct config.json: 64 SiLU-gated experts of
+    # width 1024 (``intermediate_size``), top-8 of the full softmax not
+    # renormalised, no token dropped, QK-norm, RMSNorm, full rotary, no bias
+    "olmoe-1b-7b": TransformerConfig(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+        num_layers=16, num_heads=16, max_seq_len=4096, norm_eps=1e-5,
+        rope_theta=10000.0, qk_norm=True, num_experts=64, moe_top_k=8,
+        moe_norm_topk_prob=False, moe_drop_tokens=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -276,6 +294,21 @@ def has_moe(cfg: TransformerConfig) -> bool:
     return max(moe_layer_experts(cfg)) > 1
 
 
+# The per-expert leaves of an MoE layer, ``[L, E, ...]`` in the layer stack:
+# the matmul weights and (gelu experts) their per-expert biases.
+_EXPERT_LEAVES = ("w_gate", "w_up", "w_in", "w_down", "b_in", "b_down")
+
+
+def expert_counts_shape(cfg) -> Optional[Tuple[int, int]]:
+    """``(layers, experts)`` of the counts ``forward_paged(expert_counts=
+    True)`` hands back: every layer a dropless expert layer.  None for any
+    other model (dense, capacity buffers, a per-layer pyramid)."""
+    experts = getattr(cfg, "num_experts", 1)
+    if isinstance(experts, int) and experts > 1 and not cfg.moe_drop_tokens:
+        return cfg.num_layers, experts
+    return None
+
+
 def layer_windows(cfg: TransformerConfig) -> Optional[jax.Array]:
     """[L] int32 of local-attention window sizes (0 = global) from
     cfg.attention_layers, or None when the config has no alternation."""
@@ -297,6 +330,13 @@ def _sm_scale(cfg: TransformerConfig, hd: int) -> float:
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
+
+def _check_qk_norm(cfg: TransformerConfig) -> None:
+    if cfg.norm != "rmsnorm" or cfg.post_layernorm:
+        raise NotImplementedError(
+            "qk_norm is the pre-LN RMSNorm blocks' (OLMoE): it carries a "
+            "scale and no offset, and the post-LN block projects on its own")
+
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     """Initialize fp32 params. Layer params are stacked on a leading [L] dim
@@ -321,6 +361,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # residual-path projections scaled down by sqrt(2L) (GPT-2 init)
         "wo": dense(keys[3], (L, nh * hd, d), std / math.sqrt(2 * L)),
     }
+    if cfg.qk_norm:
+        _check_qk_norm(cfg)
+        layers["q_norm_scale"] = jnp.ones((L, nh * hd))
+        layers["k_norm_scale"] = jnp.ones((L, nkv * hd))
     if not cfg.shared_layernorm:   # GPT-J shares the attention LN
         layers["mlp_norm_scale"] = jnp.ones((L, d))
     if cfg.norm == "layernorm":
@@ -428,6 +472,10 @@ def _init_params_het(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             "wv": dense(k[2], (d, nkv * hd)),
             "wo": dense(k[3], (nh * hd, d), std / math.sqrt(2 * L)),
         }
+        if cfg.qk_norm:
+            _check_qk_norm(cfg)
+            lp["q_norm_scale"] = jnp.ones((nh * hd,))
+            lp["k_norm_scale"] = jnp.ones((nkv * hd,))
         if not cfg.shared_layernorm:
             lp["mlp_norm_scale"] = jnp.ones((d,))
         if cfg.norm == "layernorm":
@@ -488,6 +536,9 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "attn_norm_scale": rep,
         "wq": col, "wk": col, "wv": col, "wo": row,
     }
+    if cfg.qk_norm:     # over the column-parallel projection, as bq / bk
+        layers.update(q_norm_scale=P(None, "model"),
+                      k_norm_scale=P(None, "model"))
     if not cfg.shared_layernorm:
         layers["mlp_norm_scale"] = rep
     if cfg.norm == "layernorm":
@@ -560,6 +611,8 @@ def _param_specs_het(cfg: TransformerConfig) -> Dict[str, Any]:
     for E in experts:
         lp: Dict[str, Any] = {"attn_norm_scale": rep,
                               "wq": col, "wk": col, "wv": col, "wo": row}
+        if cfg.qk_norm:
+            lp.update(q_norm_scale=P("model"), k_norm_scale=P("model"))
         if not cfg.shared_layernorm:
             lp["mlp_norm_scale"] = rep
         if cfg.norm == "layernorm":
@@ -887,16 +940,25 @@ def _dense_mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, prefix=""):
     return m
 
 
-def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic):
+def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
+         token_mask=None, expert_offset=None):
     """Post-norm MLP/MoE body shared by the training block and the KV-cached
-    decode block: returns (output, moe_aux_loss).  MoE-ness is detected from
-    the layer's params (PR-MoE pyramid layers differ per depth)."""
+    decode block: returns (output, moe_aux_loss, counts), ``counts`` the
+    rows each expert computed (``[E]`` int32; ``None`` unless the layer ran
+    the dropless dispatch).  MoE-ness is detected from the layer's params
+    (PR-MoE pyramid layers differ per depth).
+
+    ``token_mask [B,S]`` (serving: a prompt's padding, a tick's idle slots)
+    keeps masked tokens out of a dropless expert layer's groups; a dense MLP
+    computes them like any other.  ``expert_offset``: the expert leaves of
+    ``lp`` are the whole layer stack and this layer's experts start there
+    (``moe_ffn_nodrop``)."""
     with jax.named_scope("mlp"):
-        aux = jnp.float32(0.0)
+        aux, counts = jnp.float32(0.0), None
         if "router" in lp:
             from ..moe.sharded_moe import MoEConfig, moe_ffn
 
-            m, aux = moe_ffn(
+            m, aux, counts = moe_ffn(
                 h, lp["router"], lp,
                 MoEConfig(num_experts=int(lp["router"].shape[-1]),
                           top_k=cfg.moe_top_k,
@@ -904,8 +966,10 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic):
                           eval_capacity_factor=cfg.eval_capacity_factor,
                           min_capacity=cfg.moe_min_capacity,
                           noisy_gate_policy=cfg.noisy_gate_policy,
-                          drop_tokens=cfg.moe_drop_tokens),
-                activation=cfg.activation, deterministic=deterministic, rng=rng)
+                          drop_tokens=cfg.moe_drop_tokens,
+                          norm_topk_prob=cfg.moe_norm_topk_prob),
+                activation=cfg.activation, deterministic=deterministic, rng=rng,
+                token_mask=token_mask, expert_offset=expert_offset)
             if "coefficient" in lp:
                 # residual MoE (reference moe/layer.py:16 use_residual): dense
                 # branch + learned softmax mixing coefficient
@@ -916,7 +980,7 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic):
                 m = m * coef[..., 0:1] + res * coef[..., 1:2]
         else:
             m = _dense_mlp(cfg, lp, h)
-        return m, aux
+        return m, aux, counts
 
 
 def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
@@ -932,6 +996,9 @@ def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
             q, k, v = proj(q, "wq", h), proj(k, "wk", h), proj(v, "wv", h)
         if cfg.attn_bias:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+        if cfg.qk_norm:   # over the whole projection, before the head split
+            q = _norm(cfg, q, lp["q_norm_scale"])
+            k = _norm(cfg, k, lp["k_norm_scale"])
         q = q.reshape(B, S, nh, hd)
         k = k.reshape(B, S, nkv, hd)
         v = v.reshape(B, S, nkv, hd)
@@ -981,7 +1048,7 @@ def _block_postln(cfg: TransformerConfig, lp: Dict[str, Any], x, positions,
             sub, 1 - cfg.dropout, attn.shape) / (1 - cfg.dropout)
     x = _norm(cfg, x + attn, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
     rng, sub = jax.random.split(rng)
-    m, aux = _mlp(cfg, lp, _maybe_act_quant(cfg, x), sub, deterministic)
+    m, aux, _ = _mlp(cfg, lp, _maybe_act_quant(cfg, x), sub, deterministic)
     if cfg.dropout and not deterministic:
         rng, sub = jax.random.split(rng)
         m = m * jax.random.bernoulli(
@@ -1022,7 +1089,7 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
         h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
             cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
         rng, sub = jax.random.split(rng)
-        m, aux = _mlp(cfg, lp, h2, sub, deterministic)
+        m, aux, _ = _mlp(cfg, lp, h2, sub, deterministic)
         if cfg.dropout and not deterministic:
             rng, sub = jax.random.split(rng)
             m = m * jax.random.bernoulli(sub, 1 - cfg.dropout, m.shape) / (1 - cfg.dropout)
@@ -1032,7 +1099,7 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
     h = _maybe_act_quant(cfg, h)
     rng, sub = jax.random.split(rng)
-    m, aux = _mlp(cfg, lp, h, sub, deterministic)
+    m, aux, _ = _mlp(cfg, lp, h, sub, deterministic)
     if cfg.dropout and not deterministic:
         rng, sub = jax.random.split(rng)
         m = m * jax.random.bernoulli(sub, 1 - cfg.dropout, m.shape) / (1 - cfg.dropout)
@@ -1419,13 +1486,13 @@ def _block_cached(cfg, lp, x, ck, cv, q_pos, q_slot, valid, kpos, next_slot,
     if cfg.parallel_residual:
         h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
             cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
-        m, _ = _mlp(cfg, lp, h2, rng, deterministic=True)
+        m = _mlp(cfg, lp, h2, rng, deterministic=True)[0]
         return x + attn + m, ck, cv
 
     x = x + attn
     h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
     h = _maybe_act_quant(cfg, h)
-    m, _ = _mlp(cfg, lp, h, rng, deterministic=True)
+    m = _mlp(cfg, lp, h, rng, deterministic=True)[0]
     return x + m, ck, cv
 
 
@@ -1720,13 +1787,15 @@ def _adapter_delta(h, ab, scale):
 
 
 def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
-                 adapters=None, ad_scale=None):
+                 adapters=None, ad_scale=None, seq_mask=None,
+                 expert_offset=None):
     """One transformer block against the paged pool, addressed a whole
     page at a time.  ``pools`` maps each pool leaf (``k``/``v``, plus
     ``k_scale``/``v_scale`` on a quantized pool) to its array with the
     page axis leading: ``[N, page, Hkv, hd]`` (scales ``[N, page]``) — the
     stacked pool with ``N = L*P`` from :func:`forward_paged`.  Returns
-    ``(x, pools)``.
+    ``(x, pools, counts)``, ``counts`` a dropless expert layer's rows per
+    expert (``None`` for any other MLP).
 
     Write: ``write = (src, keep, pages)`` is the block's page-merge plan
     (:func:`forward_paged` builds it once for all layers): ``pages [B,
@@ -1751,7 +1820,12 @@ def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
     and the ``[B]`` per-slot scales (multi-tenant adapter serving,
     docs/SERVING.md): each projection named in the dict gains its slot's
     batched delta.  All-zero factors reproduce the base projection
-    exactly, so one traced program serves any tenant mix."""
+    exactly, so one traced program serves any tenant mix.
+
+    ``seq_mask [B,S]`` (True = a real token) keeps padding and idle slots
+    out of a dropless expert layer's groups; ``expert_offset`` says where
+    this layer's experts start in expert leaves that hold the whole stack
+    (:func:`forward_paged`)."""
     B, S, _ = x.shape
     hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
 
@@ -1803,17 +1877,16 @@ def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
         attn = _attention_paged(cfg, q, ck, cv, positions)
     attn = _attn_out(cfg, lp, attn, proj)
 
-    if cfg.parallel_residual:
-        h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
-            cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
-        m, _ = _mlp(cfg, lp, h2, rng, deterministic=True)
-        return x + attn + m, pools
-
-    x = x + attn
-    h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
-    h = _maybe_act_quant(cfg, h)
-    m, _ = _mlp(cfg, lp, h, rng, deterministic=True)
-    return x + m, pools
+    # GPT-J/NeoX: the MLP branches off x beside attention (GPT-J shares the
+    # attention LN); otherwise it follows the attention residual
+    res = x + attn
+    h2 = (h if cfg.parallel_residual and cfg.shared_layernorm else
+          _maybe_act_quant(cfg, _norm(
+              cfg, x if cfg.parallel_residual else res,
+              lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))))
+    m, _, counts = _mlp(cfg, lp, h2, rng, deterministic=True,
+                        token_mask=seq_mask, expert_offset=expert_offset)
+    return res + m, pools, counts
 
 
 def _paged_write_plan(page_table, start, seq_mask, ps: int):
@@ -1850,7 +1923,8 @@ def _paged_write_plan(page_table, start, seq_mask, ps: int):
 def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                   tokens: jax.Array, cache: Dict[str, Any],
                   page_table: jax.Array, start: jax.Array,
-                  seq_mask: jax.Array, adapters=None):
+                  seq_mask: jax.Array, adapters=None,
+                  expert_counts: bool = False):
     """Run ``tokens [B,S]`` against the paged pool, writing each real token's
     K/V at its slot position and attending each query to its own slot only.
 
@@ -1879,6 +1953,11 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     (:func:`_block_paged`) — no layer's slice of the pool is ever cut out,
     re-laid out or written back, so the donated buffers are updated where
     they lie.
+
+    ``expert_counts=True`` adds a third result: the rows each expert of
+    each layer computed, ``[L, E]`` int32, for a model whose expert layers
+    are dropless (masked tokens are in no group and in no count); ``None``
+    for every other model.
 
     A quantized cache (``init_paged_cache(kv_dtype="int8")`` — extra
     ``k_scale``/``v_scale`` planes) runs the same three program shapes:
@@ -1935,29 +2014,40 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     # decode tick (PERF.md, PR 25).
     pools = {k: cache[k].reshape(-1, *cache[k].shape[2:])
              for k in PAGED_POOL_KEYS if k in cache}
+    # So do the expert stacks of a dropless model, for the same reason
+    # (an 800 MB slice a layer, cut out and copied before the grouped
+    # matmuls read it): they stay whole, [L*E, ...], outside the scan's xs,
+    # and layer l's experts are the groups from l*E on (moe_ffn_nodrop).
+    layers, experts = params["layers"], {}
+    if expert_counts_shape(cfg):
+        experts = {k: v.reshape(-1, *v.shape[2:]) for k, v in layers.items()
+                   if k in _EXPERT_LEAVES}
+        layers = {k: v for k, v in layers.items() if k not in experts}
 
     def body(carry, layer):
         x, pools = carry
         lp, first_page = layer[:2]
-        x, pools = _block_paged(
-            cfg, lp, x, pools, positions,
+        x, pools, counts = _block_paged(
+            cfg, {**lp, **experts}, x, pools, positions,
             (src, keep, write_pages + first_page), page_table + first_page,
             rng, adapters=layer[2] if adapters is not None else None,
-            ad_scale=ad_scale)
+            ad_scale=ad_scale, seq_mask=seq_mask,
+            expert_offset=(first_page // num_pages * cfg.num_experts
+                           if experts else None))
         x = constrain_spec(x, P(BATCH_AXES, None, None))
-        return (x, pools), None
+        return (x, pools), counts
 
-    xs = (params["layers"],
-          jnp.arange(num_layers, dtype=jnp.int32) * num_pages)
+    xs = (layers, jnp.arange(num_layers, dtype=jnp.int32) * num_pages)
     if adapters is not None:
         # per-slot factor stacks scan with the layers: each step's slice is
         # {target: {"A": [B,d_in,R], "B": [B,R,d_out]}} for THAT layer
         xs += (adapters["factors"],)
-    (x, pools), _ = jax.lax.scan(body, (x, pools), xs)
+    (x, pools), counts = jax.lax.scan(body, (x, pools), xs)
 
     x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
     logits = _lm_head(cfg, params, x)
-    return logits, {k: a.reshape(cache[k].shape) for k, a in pools.items()}
+    cache = {k: a.reshape(cache[k].shape) for k, a in pools.items()}
+    return (logits, cache, counts) if expert_counts else (logits, cache)
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,

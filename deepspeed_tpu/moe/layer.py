@@ -77,9 +77,9 @@ class MoE:
     def apply(self, params: Dict[str, Any], x: jnp.ndarray,
               deterministic: bool = True,
               rng: Optional[jax.Array] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        out, aux = moe_ffn(x, params["router"], params, self.config,
-                           activation=self.activation,
-                           deterministic=deterministic, rng=rng)
+        out, aux, _ = moe_ffn(x, params["router"], params, self.config,
+                              activation=self.activation,
+                              deterministic=deterministic, rng=rng)
         if self.use_residual:
             if self.activation == "swiglu":
                 g = x @ params["res_w_gate"].astype(x.dtype)

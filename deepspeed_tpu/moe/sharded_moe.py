@@ -35,6 +35,10 @@ class MoEConfig:
     # The aux loss is returned UNscaled; the consumer applies its coefficient
     # (TransformerConfig.moe_aux_loss_coef in the model family).
     drop_tokens: bool = True
+    # top-k gates renormalised to sum to 1 (GShard, Mixtral); False keeps
+    # the k largest softmax probabilities as they are (OLMoE).  Top-1 never
+    # renormalises.
+    norm_topk_prob: bool = True
 
 
 def _capacity(tokens_per_group: int, cfg: MoEConfig, deterministic: bool) -> int:
@@ -90,7 +94,7 @@ def top_k_gating(logits: jnp.ndarray, cfg: MoEConfig, deterministic: bool):
         counts = counts + jnp.sum(mask * keep, axis=0)
         masked = masked * (1.0 - mask)  # exclude chosen expert for next k
 
-    if cfg.top_k > 1:
+    if cfg.top_k > 1 and cfg.norm_topk_prob:
         # renormalize combine weights over the kept top-k (reference top2
         # :297); top-1 keeps the raw gate probability (reference top1 :228) so
         # the router still gets gradient through the main loss
@@ -105,14 +109,20 @@ def _router_logits(x, router_w, cfg: MoEConfig, deterministic, rng):
         # sharded_moe.py:350 multiplicative_jitter, epsilon=1e-2)
         x_router = x_router * jax.random.uniform(
             rng, x_router.shape, jnp.float32, 1.0 - 1e-2, 1.0 + 1e-2)
-    return jnp.einsum("bsd,de->bse", x_router, router_w.astype(jnp.float32))
+    # float32 in full: a TPU's default float32 product rounds its operands
+    # to bfloat16, which is enough to swap the k-th and (k+1)-th expert
+    # where their probabilities are close; the product is [T, d] x [d, E]
+    return jnp.einsum("bsd,de->bse", x_router, router_w.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
                    expert_params: Dict[str, Any], cfg: MoEConfig,
                    activation: str = "swiglu", deterministic: bool = True,
-                   rng: Optional[jnp.ndarray] = None
-                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                   rng: Optional[jnp.ndarray] = None,
+                   token_mask: Optional[jnp.ndarray] = None,
+                   expert_offset: Optional[jnp.ndarray] = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """True no-token-dropping MoE via ``lax.ragged_dot`` — the TPU-native
     answer to the reference's dynamic-capacity exchange (sharded_moe.py:253
     allreduces the observed max load and reallocates; XLA needs static
@@ -121,6 +131,25 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     GEMMs).  Memory is O(T·top_k·D) regardless of expert count — the r2
     verdict's O(T·topk/E·cf) bar, beaten: no capacity factor at all, and no
     token is ever dropped.
+
+    ``token_mask [B, S]`` (optional, True = a real token): the assignments
+    of a masked token are sorted past the last expert's group, so they take
+    no row of any group, touch no expert and come back as zeros — a
+    serving prompt's padding and a decode tick's idle slots cost the
+    grouped matmuls nothing.  One formulation: without a mask every token
+    is real.
+
+    ``expert_offset`` (optional, a traced scalar): the expert leaves are
+    then a STACK of ``n`` layers' experts, ``[n*E, D, F]``, and this layer's
+    are the ``E`` from ``expert_offset`` on.  The group sizes are laid at
+    that offset into ``n*E`` groups, every other group empty, and the
+    grouped matmuls read this layer's experts where they lie.  A layer scan
+    that is handed the ``[L, E, D, F]`` stack as ``xs`` instead cuts each
+    layer's 800 MB slice out and copies it before the matmuls read it
+    (PERF.md, PR 26).
+
+    Returns ``(out [B,S,D], aux, counts [E] int32)``; ``counts`` are the
+    group sizes the matmuls ran with, the rows each expert computed.
 
     Best with ep=1 (dp/tp meshes): expert weights replicate and every shard
     routes its tokens locally.  With ep>1 GSPMD falls back to gathering the
@@ -131,42 +160,68 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.top_k
     T = B * S
-    logits = _router_logits(x, router_w, cfg, deterministic, rng)
-    gates = jax.nn.softmax(logits.reshape(T, E), axis=-1)        # [T, E]
-    vals, idx = jax.lax.top_k(gates, k)                          # [T, k]
-    # load-balancing aux loss over the top-1 assignment, per group (batch
-    # row) then averaged — same semantics as the capacity path
-    # (reference :179,277)
-    mask1 = jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32)
-    aux = jnp.mean(E * jnp.sum(
-        jnp.mean(gates.reshape(B, S, E), axis=1)
-        * jnp.mean(mask1.reshape(B, S, E), axis=1), axis=-1))
-    if k > 1:
-        vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_router"):
+        logits = _router_logits(x, router_w, cfg, deterministic, rng)
+        gates = jax.nn.softmax(logits.reshape(T, E), axis=-1)    # [T, E]
+        vals, idx = jax.lax.top_k(gates, k)    # [T, k]; ties: lower index
+        # load-balancing aux loss over the top-1 assignment, per group
+        # (batch row) then averaged — same semantics as the capacity path
+        # (reference :179,277)
+        mask1 = jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32)
+        aux = jnp.mean(E * jnp.sum(
+            jnp.mean(gates.reshape(B, S, E), axis=1)
+            * jnp.mean(mask1.reshape(B, S, E), axis=1), axis=-1))
+        if k > 1 and cfg.norm_topk_prob:
+            vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
 
-    flat_expert = idx.reshape(T * k)
-    order = jnp.argsort(flat_expert, stable=True)                # [T*k]
-    token_of = order // k
-    xs = x.reshape(T, D)[token_of]                               # [T*k, D]
-    group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+    with jax.named_scope("moe_dispatch"):
+        flat_expert = idx.reshape(T * k)
+        if token_mask is not None:
+            # expert id E: past every group, counted by none
+            flat_expert = jnp.where(
+                jnp.repeat(token_mask.reshape(T), k), flat_expert, E)
+        # rows in whole sublanes of 8, the spare ones in no group either:
+        # for any other row count the TPU compiler leaves its grouped-matmul
+        # kernel for a dense product over every group
+        flat_expert = jnp.pad(flat_expert, (0, -(T * k) % 8),
+                              constant_values=E)
+        order = jnp.argsort(flat_expert, stable=True)            # [rows]
+        xs = x.reshape(T, D)[jnp.minimum(order // k, T - 1)]     # [rows, D]
+        group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
+        row_expert = flat_expert[order]                          # [rows]
+        live = row_expert < E                    # in some expert's group
 
     w = lambda n: expert_params[n].astype(x.dtype)  # noqa: E731
-    row_expert = flat_expert[order]                              # [T*k]
-    if activation == "swiglu":
-        g = jax.lax.ragged_dot(xs, w("w_gate"), group_sizes)
-        u = jax.lax.ragged_dot(xs, w("w_up"), group_sizes)
-        h = jax.nn.silu(g) * u
-    else:
-        h = jax.lax.ragged_dot(xs, w("w_in"), group_sizes)
-        if "b_in" in expert_params:   # per-expert bias (Megatron-DS experts)
-            h = h + w("b_in")[row_expert]
-        h = jax.nn.gelu(h)
-    out = jax.lax.ragged_dot(h, w("w_down"), group_sizes)        # [T*k, D]
-    if "b_down" in expert_params and activation != "swiglu":
-        out = out + w("b_down")[row_expert]
-    out = out * vals.reshape(T * k)[order][:, None].astype(x.dtype)
-    y = jnp.zeros((T, D), out.dtype).at[token_of].add(out)
-    return y.reshape(B, S, D), aux.astype(jnp.float32)
+    counts = group_sizes
+    n_groups = expert_params["w_down"].shape[0]     # E, or a stack's n*E
+    if expert_offset is not None:
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_groups,), jnp.int32), group_sizes, (expert_offset,))
+        row_expert = row_expert + expert_offset
+    # a row in no group reads some expert's bias and is dropped below
+    row_expert = jnp.minimum(row_expert, n_groups - 1)
+    with jax.named_scope("moe_experts"):
+        if activation == "swiglu":
+            g = jax.lax.ragged_dot(xs, w("w_gate"), group_sizes)
+            u = jax.lax.ragged_dot(xs, w("w_up"), group_sizes)
+            h = jax.nn.silu(g) * u
+        else:
+            h = jax.lax.ragged_dot(xs, w("w_in"), group_sizes)
+            if "b_in" in expert_params:  # per-expert bias (Megatron-DS experts)
+                h = h + w("b_in")[row_expert]
+            h = jax.nn.gelu(h)
+        out = jax.lax.ragged_dot(h, w("w_down"), group_sizes)    # [T*k, D]
+        if "b_down" in expert_params and activation != "swiglu":
+            out = out + w("b_down")[row_expert]
+
+    with jax.named_scope("moe_combine"):
+        # back to token order: assignment j of token t sits in sorted row
+        # inv[t*k + j]; a row in no group (a masked token's) adds nothing
+        inv = jnp.argsort(order)[:T * k]
+        out = jnp.where(live[:, None], out, 0)[inv]
+        y = jnp.sum(out.reshape(T, k, D).astype(jnp.float32)
+                    * vals[:, :, None], axis=1).astype(x.dtype)
+    return y.reshape(B, S, D), aux.astype(jnp.float32), counts
 
 
 _NODROP_EP_WARNED = False
@@ -195,47 +250,62 @@ def _warn_nodrop_on_expert_mesh() -> None:
 
 def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any],
             cfg: MoEConfig, activation: str = "swiglu", deterministic: bool = True,
-            rng: Optional[jnp.ndarray] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x [B, S, D] -> (out [B, S, D], aux_loss).
+            rng: Optional[jnp.ndarray] = None,
+            token_mask: Optional[jnp.ndarray] = None,
+            expert_offset: Optional[jnp.ndarray] = None):
+    """x [B, S, D] -> (out [B, S, D], aux_loss, counts): ``counts`` are the
+    rows each expert computed, ``[E]`` int32, on the dropless path, and
+    ``None`` on the capacity path, whose buffers have one static size.
 
     Groups = batch rows; capacity is per group.  expert_params leaves are
     [E, D, F] / [E, F, D], sharded P('expert', None, 'model') by the model's
-    param_specs.
+    param_specs.  ``token_mask`` and ``expert_offset`` are the dropless
+    path's (see :func:`moe_ffn_nodrop`); a capacity buffer holds a masked
+    token like any other.
     """
     if not cfg.drop_tokens:
         _warn_nodrop_on_expert_mesh()
         return moe_ffn_nodrop(x, router_w, expert_params, cfg,
                               activation=activation,
-                              deterministic=deterministic, rng=rng)
+                              deterministic=deterministic, rng=rng,
+                              token_mask=token_mask,
+                              expert_offset=expert_offset)
+    assert expert_offset is None, "expert stacks are the dropless path's"
     B, S, D = x.shape
-    logits = _router_logits(x, router_w, cfg, deterministic, rng)
-    combine, dispatch, aux = jax.vmap(
-        lambda lg: top_k_gating(lg, cfg, deterministic))(logits)
-    aux = jnp.mean(aux)
+    with jax.named_scope("moe_router"):
+        logits = _router_logits(x, router_w, cfg, deterministic, rng)
+        combine, dispatch, aux = jax.vmap(
+            lambda lg: top_k_gating(lg, cfg, deterministic))(logits)
+        aux = jnp.mean(aux)
 
     # [G,S,E,C] x [G,S,D] -> [G,E,C,D]; G rides the data axis, E the expert
     # axis — this resharding IS the all-to-all
-    expert_in = jnp.einsum("gsec,gsd->gecd", dispatch.astype(x.dtype), x)
-    expert_in = constrain_spec(expert_in, P(DATA_AXES, "expert", None, None))
+    with jax.named_scope("moe_dispatch"):
+        expert_in = jnp.einsum("gsec,gsd->gecd", dispatch.astype(x.dtype), x)
+        expert_in = constrain_spec(expert_in,
+                                   P(DATA_AXES, "expert", None, None))
 
-    if activation == "swiglu":
-        g = jnp.einsum("gecd,edf->gecf", expert_in,
-                       expert_params["w_gate"].astype(x.dtype))
-        u = jnp.einsum("gecd,edf->gecf", expert_in,
-                       expert_params["w_up"].astype(x.dtype))
-        h = jax.nn.silu(g) * u
-    else:
-        h = jnp.einsum("gecd,edf->gecf", expert_in,
-                       expert_params["w_in"].astype(x.dtype))
-        if "b_in" in expert_params:   # per-expert bias [E, F]
-            h = h + expert_params["b_in"].astype(x.dtype)[None, :, None, :]
-        h = jax.nn.gelu(h)
-    expert_out = jnp.einsum("gecf,efd->gecd", h,
-                            expert_params["w_down"].astype(x.dtype))
-    if "b_down" in expert_params and activation != "swiglu":
-        expert_out = expert_out + \
-            expert_params["b_down"].astype(x.dtype)[None, :, None, :]
-    expert_out = constrain_spec(expert_out, P(DATA_AXES, "expert", None, None))
+    with jax.named_scope("moe_experts"):
+        if activation == "swiglu":
+            g = jnp.einsum("gecd,edf->gecf", expert_in,
+                           expert_params["w_gate"].astype(x.dtype))
+            u = jnp.einsum("gecd,edf->gecf", expert_in,
+                           expert_params["w_up"].astype(x.dtype))
+            h = jax.nn.silu(g) * u
+        else:
+            h = jnp.einsum("gecd,edf->gecf", expert_in,
+                           expert_params["w_in"].astype(x.dtype))
+            if "b_in" in expert_params:   # per-expert bias [E, F]
+                h = h + expert_params["b_in"].astype(x.dtype)[None, :, None, :]
+            h = jax.nn.gelu(h)
+        expert_out = jnp.einsum("gecf,efd->gecd", h,
+                                expert_params["w_down"].astype(x.dtype))
+        if "b_down" in expert_params and activation != "swiglu":
+            expert_out = expert_out + \
+                expert_params["b_down"].astype(x.dtype)[None, :, None, :]
+        expert_out = constrain_spec(expert_out,
+                                    P(DATA_AXES, "expert", None, None))
 
-    out = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), expert_out)
-    return out, aux.astype(jnp.float32)
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum("gsec,gecd->gsd", combine.astype(x.dtype), expert_out)
+    return out, aux.astype(jnp.float32), None

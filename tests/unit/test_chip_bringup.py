@@ -100,3 +100,50 @@ def test_chip_smoke_refuses_the_cpu():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "no TPU" in proc.stderr
+
+
+@pytest.mark.parametrize("top_k,slots", [(8, 16), (7, 1)],
+                         ids=["decode-16-slots", "rows-not-a-multiple-of-8"])
+def test_olmoe_paged_decode_compiles_for_v5e_with_its_experts_in_place(
+        top_k, slots):
+    """AOT: ``forward_paged`` of OLMoE at the published widths (depth 2),
+    one decode tick, compiled by the installed libtpu.  The expert matmuls
+    are the compiler's grouped-matmul kernel (``ragged-dot`` Mosaic calls),
+    also where ``tokens x k`` is no multiple of 8 (unpadded, that row count
+    left the kernel for a dense product the compiler then refused), and no
+    op of the program cuts out or copies a layer's expert stack."""
+    import re
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.models import CausalLM, get_config, init_params
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"TPU topology unavailable for AOT compile: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = get_config("olmoe-1b-7b", num_layers=2, moe_top_k=top_k)
+    model = CausalLM(cfg)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    pool = S((2, 1 + slots * 2, 128, 16, 128), jnp.bfloat16)   # far under a stack
+    text = jax.jit(model.apply_paged).lower(
+        params, S((slots, 1), jnp.int32), {"k": pool, "v": pool},
+        S((slots, 2), jnp.int32), S((slots,), jnp.int32),
+        S((slots, 1), jnp.bool_)).compile().as_text()
+    assert len(re.findall(r"%ragged-dot-none[.\d]* = ", text)) == 3
+    expert_stack = 64 * 2048 * 1024
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]+)\]", line)
+        if m and int(np.prod([int(d) for d in m.group(1).split(",")])) \
+                >= expert_stack:
+            assert re.search(r" (parameter|get-tuple-element|bitcast)\(",
+                             line), line[:200]

@@ -90,8 +90,8 @@ def test_no_drop_matches_uncapped_capacity_path():
     nd = MoEConfig(num_experts=E, top_k=2, drop_tokens=False)
     huge = MoEConfig(num_experts=E, top_k=2, drop_tokens=True,
                      capacity_factor=64.0, eval_capacity_factor=64.0)
-    y_nd, _ = jax.jit(lambda x: moe_ffn(x, router, p, nd))(x)
-    y_huge, _ = jax.jit(lambda x: moe_ffn(x, router, p, huge))(x)
+    y_nd = jax.jit(lambda x: moe_ffn(x, router, p, nd))(x)[0]
+    y_huge = jax.jit(lambda x: moe_ffn(x, router, p, huge))(x)[0]
     np.testing.assert_allclose(np.asarray(y_nd), np.asarray(y_huge),
                                rtol=5e-4, atol=5e-5)
 
@@ -108,8 +108,8 @@ def test_no_drop_survives_adversarial_routing():
     tight = MoEConfig(num_experts=E, top_k=1, drop_tokens=True,
                       capacity_factor=0.25, eval_capacity_factor=0.25,
                       min_capacity=8)
-    y_nd, _ = moe_ffn(x, router, p, nd)
-    y_tight, _ = moe_ffn(x, router, p, tight)
+    y_nd, *_ = moe_ffn(x, router, p, nd)
+    y_tight, *_ = moe_ffn(x, router, p, tight)
     nd_rows = np.abs(np.asarray(y_nd[0])).sum(-1)
     tight_rows = np.abs(np.asarray(y_tight[0])).sum(-1)
     assert (nd_rows > 0).all(), "no-drop dropped tokens"
@@ -237,11 +237,11 @@ def test_expert_biases_capacity_and_nodrop_agree():
     # no-drop precondition (capacity >= T=8 per group) holds by factor too
     cap = MoEConfig(num_experts=E, top_k=1, capacity_factor=8.0,
                     eval_capacity_factor=8.0, min_capacity=64)
-    y_cap, _ = moe_ffn(x, router, params, cap, activation="gelu",
-                       deterministic=True)
+    y_cap, *_ = moe_ffn(x, router, params, cap, activation="gelu",
+                        deterministic=True)
     nd = MoEConfig(num_experts=E, top_k=1, drop_tokens=False)
-    y_nd, _ = moe_ffn(x, router, params, nd, activation="gelu",
-                      deterministic=True)
+    y_nd, *_ = moe_ffn(x, router, params, nd, activation="gelu",
+                       deterministic=True)
     # tolerance matches test_no_drop_matches_uncapped_capacity_path: the
     # einsum vs ragged_dot accumulation differs under TPU matmul precision
     np.testing.assert_allclose(np.asarray(y_cap), np.asarray(y_nd),
@@ -249,6 +249,158 @@ def test_expert_biases_capacity_and_nodrop_agree():
     # biases actually matter: zeroing them changes the output
     zeroed = dict(params, b_in=jnp.zeros_like(params["b_in"]),
                   b_down=jnp.zeros_like(params["b_down"]))
-    y_zero, _ = moe_ffn(x, router, zeroed, nd, activation="gelu",
-                        deterministic=True)
+    y_zero, *_ = moe_ffn(x, router, zeroed, nd, activation="gelu",
+                         deterministic=True)
     assert not np.allclose(np.asarray(y_nd), np.asarray(y_zero))
+
+
+# ---- ISSUE 26: QK-norm, the unnormalised gate rule, OLMoE's sizes ----------
+
+def _old_qkv(cfg, lp, h, positions):
+    """``_qkv`` as it stood before the QK-norm field: project, bias, split,
+    rotate."""
+    from deepspeed_tpu.models.transformer import _rope
+
+    B, S, _ = h.shape
+    hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
+    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+    if cfg.attn_bias:
+        q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
+    q = q.reshape(B, S, nh, hd)
+    k = k.reshape(B, S, nkv, hd)
+    v = v.reshape(B, S, nkv, hd)
+    if cfg.position == "rope":
+        q, k = _rope(q, k, positions, cfg.rope_theta, hd,
+                     rotary_dim=cfg.rotary_dim,
+                     interleaved=cfg.rope_interleaved)
+    return q, k, v
+
+
+@pytest.mark.parametrize("name", ["opt-1.3b", "pythia-1.4b-d10"])
+def test_dense_configs_compile_what_they_compiled(name):
+    """The benchmark's dense configurations at the new fields' defaults: no
+    new leaf in the parameter tree, ``_qkv`` traces to the program it traced
+    to before QK-norm, ``_mlp`` to the plain MLP (no router, no sort, no
+    grouped matmul), with and without a token mask."""
+    from benchmark.lib import system
+    from deepspeed_tpu.models import init_params
+    from deepspeed_tpu.models.transformer import (_dense_mlp, _mlp, _qkv,
+                                                  expert_counts_shape)
+
+    cfg = system.transformer_config(
+        system.load_json("configs", name + ".json"), rehearse=True)
+    assert not cfg.qk_norm and cfg.moe_norm_topk_prob
+    assert expert_counts_shape(cfg) is None
+    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    assert set(params["layers"]) == {
+        "attn_norm_scale", "attn_norm_bias", "mlp_norm_scale", "mlp_norm_bias",
+        "wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo",
+        "w_in", "b_in", "w_down", "b_down"}
+    lp = {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype)
+          for k, v in params["layers"].items()}
+    h = jax.ShapeDtypeStruct((2, 8, cfg.hidden_size), jnp.float32)
+    pos = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    mask = jax.ShapeDtypeStruct((2, 8), jnp.bool_)
+    assert str(jax.make_jaxpr(lambda lp, h, p: _qkv(cfg, lp, h, p))(
+        lp, h, pos)) == str(jax.make_jaxpr(
+            lambda lp, h, p: _old_qkv(cfg, lp, h, p))(lp, h, pos))
+    plain = str(jax.make_jaxpr(lambda lp, h: _dense_mlp(cfg, lp, h))(lp, h))
+    assert str(jax.make_jaxpr(lambda lp, h: _mlp(
+        cfg, lp, h, None, True)[0])(lp, h)) == plain
+    masked = jax.make_jaxpr(lambda lp, h, m: _mlp(
+        cfg, lp, h, None, True, token_mask=m)[0])(lp, h, mask)
+    assert str(masked.jaxpr.eqns) == str(jax.make_jaxpr(
+        lambda lp, h, m: _dense_mlp(cfg, lp, h))(lp, h, mask).jaxpr.eqns)
+
+
+def test_olmoe_param_count_and_leaves():
+    from deepspeed_tpu.models import get_config, init_params, param_specs
+
+    cfg = get_config("olmoe-1b-7b")
+    assert cfg.param_count == 6_919_161_856
+    assert get_config("olmoe-1b-7b", num_layers=12).param_count == 5_240_883_200
+    # the formula against the tree, at a size that can be built
+    small = get_config("olmoe-1b-7b", num_layers=2, hidden_size=64,
+                       intermediate_size=32, num_heads=4, vocab_size=256,
+                       num_experts=8, moe_top_k=3)
+    params = init_params(small, jax.random.PRNGKey(0))
+    assert sum(x.size for x in jax.tree_util.tree_leaves(params)) \
+        == small.param_count
+    assert params["layers"]["q_norm_scale"].shape == (2, 64)
+    assert params["layers"]["k_norm_scale"].shape == (2, 64)
+    specs = param_specs(small)
+    assert jax.tree_util.tree_structure(
+        jax.tree_util.tree_map(lambda _: 0, params)) == \
+        jax.tree_util.tree_structure(jax.tree_util.tree_map(
+            lambda _: 0, specs,
+            is_leaf=lambda s: isinstance(s, jax.sharding.PartitionSpec)))
+
+
+@pytest.mark.parametrize("drop_tokens", [True, False])
+def test_gates_as_they_are_when_not_renormalised(drop_tokens):
+    """``norm_topk_prob=False``: the k largest softmax probabilities weigh
+    their experts as they are, on both paths (ample capacity on the first)."""
+    E, k, d = 8, 3, 16
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    x = jax.random.normal(keys[0], (2, 12, d))
+    router = jax.random.normal(keys[1], (d, E))
+    p = {"w_in": jax.random.normal(keys[2], (E, d, 24)) * 0.3,
+         "w_down": jax.random.normal(keys[3], (E, 24, d)) * 0.3}
+    cfg = MoEConfig(num_experts=E, top_k=k, capacity_factor=8.0,
+                    eval_capacity_factor=8.0, drop_tokens=drop_tokens,
+                    norm_topk_prob=False)
+    got, *_ = moe_ffn(x, router, p, cfg, activation="gelu")
+    gates = jax.nn.softmax(x.reshape(-1, d) @ router, axis=-1)
+    vals, idx = jax.lax.top_k(gates, k)
+    want = jnp.zeros((24, d))
+    for j in range(k):
+        h = jax.nn.gelu(jnp.einsum("td,tdf->tf", x.reshape(-1, d),
+                                   p["w_in"][idx[:, j]]))
+        want = want + vals[:, j:j + 1] * jnp.einsum(
+            "tf,tfd->td", h, p["w_down"][idx[:, j]])
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, d),
+                               np.asarray(want), rtol=2e-5, atol=2e-6)
+    renorm, *_ = moe_ffn(x, router, p, MoEConfig(
+        num_experts=E, top_k=k, capacity_factor=8.0, eval_capacity_factor=8.0,
+        drop_tokens=drop_tokens), activation="gelu")
+    assert np.abs(np.asarray(renorm) - np.asarray(got)).max() > 1e-2
+
+
+@pytest.mark.parametrize("top_k", [1, 2])
+def test_paged_forward_matches_forward_with_expert_biases(top_k):
+    """A dropless MoE with non-zero per-expert biases, three layers deep:
+    ``forward_paged`` (expert leaves stacked ``[L*E, ...]`` outside the layer
+    scan, biases included) computes what ``forward`` computes at every
+    layer, on a padded prompt with an idle slot."""
+    from deepspeed_tpu.models import (forward, forward_paged, init_paged_cache,
+                                      init_params)
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(
+        vocab_size=128, hidden_size=32, num_layers=3, num_heads=4,
+        intermediate_size=48, max_seq_len=64, activation="gelu",
+        mlp_bias=True, num_experts=4, moe_top_k=top_k, moe_drop_tokens=False,
+        dtype=jnp.float32)
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    keys = iter(jax.random.split(jax.random.PRNGKey(4), 8))
+    params["layers"] = {
+        k: 0.5 * jax.random.normal(next(keys), v.shape, v.dtype)
+        if k.startswith("b_") else v for k, v in params["layers"].items()}
+    assert params["layers"]["b_down"].shape == (3, 4, 32)
+
+    page, n, s_pad = 8, 11, 16
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, n), 0, 128)
+    with jax.default_matmul_precision("highest"):
+        want = forward(cfg, params, tokens, attn_impl="xla")
+        cache = init_paged_cache(cfg, 1 + 3 * 2, page, dtype=jnp.float32)
+        table = 1 + jnp.arange(6, dtype=jnp.int32).reshape(3, 2)
+        prompt = jnp.zeros((3, s_pad), jnp.int32).at[
+            jnp.asarray([0, 2]), :n].set(tokens)
+        mask = (jnp.arange(s_pad) < n)[None] & jnp.asarray(
+            [True, False, True])[:, None]
+        got, _, counts = jax.jit(lambda *a: forward_paged(
+            cfg, params, *a, expert_counts=True))(
+                prompt, cache, table, jnp.zeros((3,), jnp.int32), mask)
+    assert counts.shape == (3, 4) and int(counts.sum()) == 3 * 2 * n * top_k
+    np.testing.assert_allclose(np.asarray(got)[[0, 2], :n], np.asarray(want),
+                               rtol=2e-5, atol=2e-5)

@@ -129,7 +129,7 @@ def _forward_sliced(params, tokens, cache, page_table, start, seq_mask,
         return _block_paged(
             CFG, lp, x, pools, positions, write, page_table,
             jax.random.PRNGKey(0), adapters=ad,
-            ad_scale=None if adapters is None else adapters["scale"])
+            ad_scale=None if adapters is None else adapters["scale"])[:2]
 
     return _forward_layers(block, params, tokens, cache, adapters)
 
@@ -175,7 +175,7 @@ def _forward_rows(params, tokens, cache, page_table, start, seq_mask):
         attn = jnp.einsum("bkgst,btkd->bskgd", probs, cv)
         x = x + _attn_out(cfg, lp, attn.reshape(Bq, S, -1, hd))
         h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
-        m, _ = _mlp(cfg, lp, h, jax.random.PRNGKey(0), deterministic=True)
+        m = _mlp(cfg, lp, h, jax.random.PRNGKey(0), deterministic=True)[0]
         return x + m, {k: a.reshape(pools[k].shape) for k, a in flat.items()}
 
     return _forward_layers(block, params, tokens, cache, None)
