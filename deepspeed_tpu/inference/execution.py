@@ -51,6 +51,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, cow_copy_pool,
                                   expert_counts_shape, paged_pool_cache,
+                                  paged_pool_order,
                                   paged_pool_tuple)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
@@ -249,6 +250,10 @@ class MeshExecutor:
             self.pools = tuple(
                 jax.device_put(cache[k], cache[k].sharding)
                 for k in self._pool_keys)
+        # how the device stores a K/V leaf, where that is not row-major:
+        # the paged read hands its loop the pool in that order
+        # (models.transformer._pool_views)
+        self.pool_order = paged_pool_order(self.pools[0])
         self._decode_prog = self._build_decode()
         self._prefill_progs: Dict[int, Any] = {}
         self._cow_prog = _COW_PROG if prefix_cache else None
@@ -312,6 +317,7 @@ class MeshExecutor:
         """``model.apply_paged`` -> ``(logits, cache, counts)``: the rows
         each expert of each layer computed where the model hands them back
         (``moe_shape``), else ``None``."""
+        kw["pool_order"] = self.pool_order
         if self.moe_shape is None:
             return (*self.model.apply_paged(*args, **kw), None)
         return self.model.apply_paged(*args, expert_counts=True, **kw)

@@ -125,7 +125,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..models.transformer import PAGE_SIZE
+from ..models.transformer import PAGE_SIZE, paged_read_pages
 from ..observability.device_profiler import (device_trace_unit,
                                              maybe_capture_from_env)
 from ..observability.program_stats import ProgramCatalog
@@ -646,7 +646,8 @@ class ServingEngine:
             self._spec = SpeculativeDecoder(
                 speculative, model, self.num_pages, self.page_size,
                 self.b_slots, dtype=dtype, kv_dtype=kv_dtype, mesh=mesh,
-                catalog=self._catalog, adapters=adapters)
+                catalog=self._catalog, adapters=adapters,
+                target_pool_order=self._exec.pool_order)
             if self._cow_prog is not None:
                 # pre-warm the COW jit on the DRAFT pool aval too: a
                 # boundary COW at admission must never compile
@@ -1533,7 +1534,9 @@ class ServingEngine:
             adapter_row = self._exec.adapter_row(self._adapter_stacks, slot)
         with trace_span("serve.prefill", rid=req.rid, slot=slot,
                         bucket=s_pad, tokens=S_tail,
-                        shared_tokens=n_shared) as sp:
+                        shared_tokens=n_shared,
+                        gathered_rows=self._gathered_rows(
+                            n_shared + S_tail, 1)) as sp:
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 if match.cow_src is not None:
@@ -1664,6 +1667,15 @@ class ServingEngine:
                moe_experts_touched=int((counts > 0).sum()),
                moe_max_load=int(counts.max()))
 
+    def _gathered_rows(self, rows: int, slots: int) -> int:
+        """K/V rows a paged program reads a layer when the longest live
+        position of the call is ``rows - 1``: every one of its ``slots``
+        slots' pages up to that position's, in the read's whole steps
+        (``models.transformer.paged_read_pages``, the host's copy of the
+        bound the program computes from its own inputs)."""
+        return slots * self.page_size * paged_read_pages(
+            rows, self.page_size, self._page_table.shape[1])
+
     def _lookahead_depth(self) -> int:
         """How many ticks after this one can be launched now, at most
         ``LOOKAHEAD_TICKS``: those whose inputs are this tick's, so many
@@ -1755,12 +1767,13 @@ class ServingEngine:
                 if rid_map is not None:
                     # the launch has returned; what is left of the span is
                     # the wait for the device in the fetch below.  Rows the
-                    # slots hold against rows the program's gather covers
-                    # (every slot's whole page-table row, live or not).
+                    # slots hold against rows the program's read covers
+                    # (every slot's pages up to the longest live slot's).
+                    live = self._lengths[self._active]
                     sp.set(dispatch_ms=(time.monotonic() - t_open) * 1e3,
-                           live_rows=int(self._lengths[self._active].sum()),
-                           gathered_rows=(self._page_table.size
-                                          * self.page_size))
+                           live_rows=int(live.sum()),
+                           gathered_rows=self._gathered_rows(
+                               int(live.max(initial=-1)) + 1, self.b_slots))
                 # host fetch = device sync; an MoE model's expert counts
                 # come with the tokens
                 out = nxt

@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.transformer import (PAGED_POOL_KEYS, paged_pool_cache,
+                                  paged_pool_order,
                                   paged_pool_tuple)
 from ..observability.program_stats import account, finish_sample
 from .sampling import position_keys, sample_tokens, sampling_probs
@@ -176,7 +177,7 @@ class SpeculativeDecoder:
     def __init__(self, config: SpeculativeConfig, target_model,
                  num_pages: int, page_size: int, b_slots: int,
                  dtype=None, kv_dtype=None, mesh=None, catalog=None,
-                 adapters=None):
+                 adapters=None, target_pool_order=None):
         from .execution import place_params, pool_bytes
 
         # multi-tenant adapter serving (docs/SERVING.md): the TARGET
@@ -246,6 +247,10 @@ class SpeculativeDecoder:
                 jax.device_put(cache[k], cache[k].sharding)
                 for k in self._pool_keys)
         self.pool_bytes = pool_bytes(*self.dpools)
+        # each pool read in the order its device stores it
+        # (MeshExecutor.pool_order; the target's is the engine's)
+        self._dpool_order = paged_pool_order(self.dpools[0])
+        self._target_pool_order = target_pool_order
         self._draft_prog = self._build_draft()
         self._verify_prog = self._build_verify(target_model)
         self._draft_prefill_progs: Dict[int, Any] = {}
@@ -266,7 +271,8 @@ class SpeculativeDecoder:
             # slot's own sampling lane (salted position key)
             cache = paged_pool_cache(dpools)
             logits, cache = draft_apply(dparams, tok[:, None], cache,
-                                        page_table, pos, active[:, None])
+                                        page_table, pos, active[:, None],
+                                        pool_order=self._dpool_order)
             lg = logits[:, -1, :]
             d_tok = sample_tokens(
                 lg, temp, top_k, top_p,
@@ -286,7 +292,8 @@ class SpeculativeDecoder:
                         < n_real)[None, :]
             cache = paged_pool_cache(dpools)
             _, cache = draft_apply(dparams, tokens, cache, pt_row,
-                                   start[None], seq_mask)
+                                   start[None], seq_mask,
+                                   pool_order=self._dpool_order)
             return paged_pool_tuple(cache)
 
         from .execution import pool_jit
@@ -308,13 +315,10 @@ class SpeculativeDecoder:
             tokens = jnp.concatenate([last_tok[:, None], d_toks], axis=1)
             seq_mask = jnp.broadcast_to(active[:, None], (B, k + 1))
             cache = paged_pool_cache(pools)
-            if with_adapters:
-                logits, cache = target_apply(params, tokens, cache,
-                                             page_table, lengths, seq_mask,
-                                             adapters=adapters)
-            else:
-                logits, cache = target_apply(params, tokens, cache,
-                                             page_table, lengths, seq_mask)
+            logits, cache = target_apply(
+                params, tokens, cache, page_table, lengths, seq_mask,
+                adapters=adapters if with_adapters else None,
+                pool_order=self._target_pool_order)
             rep = lambda x: jnp.repeat(x, k + 1)                 # noqa: E731
             p = sampling_probs(logits.reshape(B * (k + 1), V), rep(temp),
                                rep(top_k), rep(top_p)).reshape(B, k + 1, V)

@@ -203,10 +203,11 @@ class CausalLM:
         return paged_cache_specs(self.config, kv_dtype=kv_dtype)
 
     def apply_paged(self, params, tokens, cache, page_table, start, seq_mask,
-                    adapters=None, expert_counts=False):
+                    adapters=None, expert_counts=False, pool_order=None):
         return forward_paged(self.config, params, tokens, cache, page_table,
                              start, seq_mask, adapters=adapters,
-                             expert_counts=expert_counts)
+                             expert_counts=expert_counts,
+                             pool_order=pool_order)
 
     @property
     def param_count(self) -> int:

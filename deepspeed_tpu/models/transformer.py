@@ -1727,47 +1727,157 @@ def kv_quantize_rows(x: jax.Array):
 
 def kv_dequantize(q: jax.Array, scale: jax.Array, dtype):
     """Invert :func:`kv_quantize_rows` on a gathered ``[..., Hkv, hd]``
-    block with its ``[...]`` scale rows; dequantizes in float32 before
-    casting to the compute dtype so the scale multiply never loses the
-    int8 mantissa."""
-    return (q.astype(jnp.float32)
-            * scale[..., None, None]).astype(dtype)
+    block with its ``[...]`` scale rows (or, for a block whose row axis
+    does not lead, scales already shaped to broadcast against it);
+    dequantizes in float32 before casting to the compute dtype so the
+    scale multiply never loses the int8 mantissa."""
+    if scale.ndim < q.ndim:
+        scale = scale[..., None, None]
+    return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-def _attention_paged(cfg, q, ck, cv, q_pos):
-    """q:[B,S,Hq,hd] against each slot's gathered pages ck/cv:
-    [B,maxp,page,Hkv,hd], attended as ``T = maxp*page`` rows.
+# Pages a step of the paged read covers: the read stops at the longest live
+# position of the call rounded up to this many pages (PERF.md, PR 27).
+PAGED_READ_GRANULE = 2
+
+
+def paged_read_pages(rows: int, page_size: int, max_pages: int) -> int:
+    """Pages of every slot that a paged forward reads when the longest live
+    position of the call is ``rows - 1``: ``rows`` rounded up to whole
+    steps of ``PAGED_READ_GRANULE`` pages, at least one step, at most the
+    page-table row.  The host's copy of what :func:`_paged_read_steps`
+    computes from ``start`` and ``seq_mask`` on the device (the serving
+    engine's ``gathered_rows`` span attr is this times ``page_size`` times
+    the slots of the call)."""
+    step = min(PAGED_READ_GRANULE, max_pages)
+    steps = max(-(-rows // (step * page_size)), 1)
+    return min(steps * step, max_pages)
+
+
+def _paged_read_steps(positions, seq_mask, ps: int, maxp: int):
+    """The read's traced trip count: steps of ``PAGED_READ_GRANULE`` pages
+    up to the longest live position of the call, ``max(positions) + 1``
+    rows over the real tokens (``positions``, ``seq_mask``: ``[B,S]``) — at
+    least one step (a call with no real token reads what it then throws
+    away), at most the table."""
+    rows = jnp.max(jnp.where(seq_mask, positions, -1)) + 1
+    step = min(PAGED_READ_GRANULE, maxp) * ps
+    return jnp.clip((rows + step - 1) // step, 1, -(-maxp * ps // step))
+
+
+def paged_pool_order(leaf: jax.Array) -> Optional[Tuple[int, ...]]:
+    """The order, major to minor, in which the device stores the five axes
+    of a K/V pool leaf (an array, not a tracer), for :func:`forward_paged`'s
+    ``pool_order``; ``None`` where that is the axes' own order (row-major)
+    or the backend does not say."""
+    layout = leaf.format.layout
+    order = None if layout is None else tuple(layout.major_to_minor)
+    return None if order == tuple(range(leaf.ndim)) else order
+
+
+def _pool_views(pools, pool_order):
+    """``(views, axes)``: each K/V leaf ``[N, page, Hkv, hd]`` with its
+    trailing axes in the order the device stores them (``pool_order``, of
+    the unstacked leaf), and that order as einsum letters (``t`` page row,
+    ``k`` head, ``d`` head dim).  A computation nested two deep (the read's
+    loop inside the layer scan) takes its operands row-major in their
+    logical shape, so the pool enters it as the view whose row-major order
+    is the bytes as they lie: a transpose that moves nothing.  Handed the
+    leaf in any other order the compiler re-lays the whole pool out in
+    front of every read (a 64-wide head puts the page rows minor-most on
+    the TPU, PERF.md PR 25 and PR 27).  The scale planes of a quantized
+    pool ``[N, page]`` go as they are."""
+    perm = (0, 1, 2, 3)
+    if pool_order is not None and tuple(pool_order[:2]) == (0, 1):
+        perm = (0,) + tuple(a - 1 for a in pool_order[2:])
+    views = {n: jnp.transpose(a, perm) if a.ndim == 4 else a
+             for n, a in pools.items()}
+    return views, "".join(" tkd"[a] for a in perm[1:])
+
+
+def _attention_paged(cfg, q, pools, gather_pages, q_pos, steps,
+                     pool_order=None):
+    """q:[B,S,Hq,hd] against the pages ``gather_pages [B, maxp]`` of each
+    slot, read ``PAGED_READ_GRANULE`` whole pages of every slot at a time
+    and only ``steps`` (traced) times: up to the longest live position of
+    the call (:func:`_paged_read_steps`), not the page-table row.
 
     Slot-local index == position, so the mask is purely causal
     (``t <= q_pos``): every slot-index at or before the query holds a real
     token of this request, everything after (including trash-page gathers
-    from unallocated page-table entries) is masked.  Same einsum structure
-    as :func:`_attention_cached` — GQA contracts grouped heads against the
-    Hkv cache directly, and decode stays on the XLA path (the Pallas decode
-    kernel was retired in round 5 on an honest A/B).  The products keep the
-    pages' own (page, row) axes and only the scores are flattened to ``T``:
-    merging the two axes of K/V would re-lay the gathered block out, which
-    the compiler then does to the whole pool (PERF.md, PR 25).
+    from unallocated page-table entries) is masked.  A row past the
+    longest live position fails ``t <= q_pos`` for every real query of the
+    call, so its probability is exactly 0 and leaving it unread is the same
+    mathematics: the softmax over the rows read is computed blockwise
+    (running max, sum and accumulator in float32, as a flash kernel does),
+    over exactly the rows that can pass the mask.  Masked queries (padding,
+    idle slots) may sit past the bound; their output is garbage either way.
+
+    Each step runs the same einsum structure as :func:`_attention_cached`
+    — GQA contracts grouped heads against the Hkv cache directly, and
+    decode stays on the XLA path (the Pallas decode kernel was retired in
+    round 5 on an honest A/B).  The products keep the pages' own axes as
+    the device stores them (:func:`_pool_views`) and only the scores are
+    flattened: merging or moving axes of K/V would re-lay the gathered
+    block out, which the compiler then does to the whole pool (PERF.md,
+    PR 25).  The pool is only ever gathered from, whole pages at a time, so
+    it stays where the layer scan carries it.
     """
     B, S, Hq, hd = q.shape
-    maxp, ps, Hkv = ck.shape[1], ck.shape[2], ck.shape[3]
-    T = maxp * ps
+    maxp = gather_pages.shape[1]
+    ps, Hkv = pools["k"].shape[1], pools["k"].shape[2]
+    C = min(PAGED_READ_GRANULE, maxp)
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
-    scores = jnp.einsum("bskgd,bptkd->bkgspt", qg, ck).astype(jnp.float32)
-    scores = scores.reshape(B, Hkv, G, S, T) * _sm_scale(cfg, hd)
-    t = jnp.arange(T, dtype=jnp.int32)
-    if cfg.position == "alibi":
-        slopes = jnp.asarray(_alibi_slopes(Hq)).reshape(Hkv, G)
-        rel = (q_pos[:, :, None] - t[None, None, :]).astype(jnp.float32)
-        scores = scores - (jnp.abs(rel)[:, None, None, :, :]
-                           * slopes[None, :, :, None, None])
-    ok = t[None, None, :] <= q_pos[:, :, None]                  # [B,S,T]
-    scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgspt,bptkd->bskgd",
-                     probs.reshape(B, Hkv, G, S, maxp, ps), cv)
-    return out.reshape(B, S, Hq, hd)
+    # a table that is no whole number of steps is filled up with the trash
+    # page: rows past the table are past every position
+    gather_pages = jnp.pad(gather_pages, ((0, 0), (0, -maxp % C)))
+    views, axes = _pool_views(pools, pool_order)
+    slopes = (jnp.asarray(_alibi_slopes(Hq)).reshape(Hkv, G)
+              if cfg.position == "alibi" else None)
+
+    def step(i, carry):
+        m, l, acc = carry
+        with jax.named_scope("kv_gather"):
+            # C whole pages of every slot: [B, C, *axes]
+            pages = jax.lax.dynamic_slice_in_dim(gather_pages, i * C, C, 1)
+            ck, cv = views["k"][pages], views["v"][pages]
+            if "k_scale" in views:
+                # dequantize inside the gather: the narrow representation
+                # is what crosses HBM; attention sees compute-dtype values
+                along = tuple(-1 if c == "t" else 1 for c in axes)
+                ck, cv = (
+                    kv_dequantize(c, views[n][pages].reshape(B, C, *along),
+                                  cfg.dtype)
+                    for c, n in ((ck, "k_scale"), (cv, "v_scale")))
+        scores = jnp.einsum(f"bskgd,bp{axes}->bkgspt", qg, ck)
+        scores = (scores.astype(jnp.float32).reshape(B, Hkv, G, S, C * ps)
+                  * _sm_scale(cfg, hd))
+        t = i * (C * ps) + jnp.arange(C * ps, dtype=jnp.int32)
+        if slopes is not None:
+            rel = (q_pos[:, :, None] - t[None, None, :]).astype(jnp.float32)
+            scores = scores - (jnp.abs(rel)[:, None, None, :, :]
+                               * slopes[None, :, :, None, None])
+        ok = t[None, None, :] <= q_pos[:, :, None]              # [B,S,C*ps]
+        scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
+        # row 0 passes every query's mask, so from the first step on m is a
+        # real score and a masked row's weight exp(-1e30 - m) is exactly 0
+        m_new = jnp.maximum(m, scores.max(-1))
+        p = jnp.exp(scores - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + p.sum(-1)
+        pv = jnp.einsum(f"bkgspt,bp{axes}->bskgd",
+                        p.astype(q.dtype).reshape(B, Hkv, G, S, C, ps), cv)
+        acc = (acc * jnp.moveaxis(alpha, 3, 1)[..., None]
+               + pv.astype(jnp.float32))
+        return m_new, l, acc
+
+    m0 = jnp.full((B, Hkv, G, S), -1e30, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, steps, step,
+        (m0, jnp.zeros_like(m0), jnp.zeros((B, S, Hkv, G, hd), jnp.float32)))
+    out = acc / jnp.moveaxis(l, 3, 1)[..., None]
+    return out.astype(q.dtype).reshape(B, S, Hq, hd)
 
 
 def _adapter_delta(h, ab, scale):
@@ -1786,9 +1896,9 @@ def _adapter_delta(h, ab, scale):
     return d * scale.astype(jnp.float32)[:, None, None]
 
 
-def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
-                 adapters=None, ad_scale=None, seq_mask=None,
-                 expert_offset=None):
+def _block_paged(cfg, lp, x, pools, positions, write, gather_pages,
+                 read_steps, rng, adapters=None, ad_scale=None, seq_mask=None,
+                 expert_offset=None, pool_order=None):
     """One transformer block against the paged pool, addressed a whole
     page at a time.  ``pools`` maps each pool leaf (``k``/``v``, plus
     ``k_scale``/``v_scale`` on a quantized pool) to its array with the
@@ -1803,11 +1913,15 @@ def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
     page where none of a page's rows is written), ``src [B, n_pg*page]``
     the token of the block each page row takes, ``keep [B, n_pg, page]``
     whether it takes one.  The pages are gathered, merged and scattered
-    back whole.  Read: ``gather_pages [B, maxp]`` are each slot's pages,
-    gathered whole.  Every pool op thus slices all trailing axes, so it
-    runs in whatever layout the pool is stored in and the pool stays in
-    place; a row-granular scatter or gather makes the TPU compiler re-lay
-    the whole pool out around the layer scan (PERF.md, PR 25).
+    back whole.  Read: ``gather_pages [B, maxp]`` are each slot's pages, of
+    which :func:`_attention_paged` gathers the first ``read_steps`` steps
+    of ``PAGED_READ_GRANULE`` whole pages: up to the longest live position
+    of the call (:func:`_paged_read_steps`), past which no row can pass any
+    real query's causal mask.  ``pool_order`` is how the device stores a
+    K/V leaf (:func:`_pool_views`).  Every pool op thus slices all trailing
+    axes, so it runs in whatever layout the pool is stored in and the pool
+    stays in place; a row-granular scatter or gather makes the TPU compiler
+    re-lay the whole pool out around the layer scan (PERF.md, PR 25).
 
     A quantized pool quantizes each written row on store (symmetric
     absmax, :func:`kv_quantize_rows`), merges its scale through the SAME
@@ -1865,16 +1979,9 @@ def _block_paged(cfg, lp, x, pools, positions, write, gather_pages, rng,
         for name in ("k", "v"):
             pools[name] = constrain_spec(pools[name],
                                          P(None, None, "model", None))
-    with jax.named_scope("kv_gather"):
-        # each slot's pages [B, maxp, page, Hkv, hd]
-        ck, cv = pools["k"][gather_pages], pools["v"][gather_pages]
-        if "k_scale" in pools:
-            # dequantize inside the gather: the narrow representation is
-            # what crosses HBM; attention sees compute-dtype values
-            ck = kv_dequantize(ck, pools["k_scale"][gather_pages], cfg.dtype)
-            cv = kv_dequantize(cv, pools["v_scale"][gather_pages], cfg.dtype)
     with jax.named_scope("attn"):
-        attn = _attention_paged(cfg, q, ck, cv, positions)
+        attn = _attention_paged(cfg, q, pools, gather_pages, positions,
+                                read_steps, pool_order)
     attn = _attn_out(cfg, lp, attn, proj)
 
     # GPT-J/NeoX: the MLP branches off x beside attention (GPT-J shares the
@@ -1924,7 +2031,8 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                   tokens: jax.Array, cache: Dict[str, Any],
                   page_table: jax.Array, start: jax.Array,
                   seq_mask: jax.Array, adapters=None,
-                  expert_counts: bool = False):
+                  expert_counts: bool = False,
+                  pool_order: Optional[Tuple[int, ...]] = None):
     """Run ``tokens [B,S]`` against the paged pool, writing each real token's
     K/V at its slot position and attending each query to its own slot only.
 
@@ -1953,6 +2061,22 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     (:func:`_block_paged`) — no layer's slice of the pool is ever cut out,
     re-laid out or written back, so the donated buffers are updated where
     they lie.
+
+    What is read: every slot's pages up to the longest live position of the
+    call, ``max(start + s) + 1`` rows over the real tokens (decode: the
+    longest active slot's length + 1; prefill: ``start + n_real``; verify-k:
+    the longest ``start + k + 1``), rounded up to ``PAGED_READ_GRANULE``
+    pages — not the ``maxp`` pages of the page-table row.  The bound is
+    computed here, on the device, from ``start`` and ``seq_mask``
+    (:func:`_paged_read_steps`), so one compiled program serves every
+    length and a tick launched ahead on ``lengths + k`` reads what it needs.
+    A row past the bound has ``t > q_pos`` for every real query, weight
+    exactly 0 in the softmax it was left out of.  ``pool_order``
+    (:func:`paged_pool_order` of the arrays the caller holds; optional, a
+    matter of speed only) is the order in which the device stores a K/V
+    leaf's axes, where that is not row-major: the read hands its loop the
+    pool in that order, or the TPU compiler re-lays the pool out before
+    every read (:func:`_pool_views`).
 
     ``expert_counts=True`` adds a third result: the rows each expert of
     each layer computed, ``[L, E]`` int32, for a model whose expert layers
@@ -1991,6 +2115,8 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                  + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :])
     src, keep, write_pages = _paged_write_plan(page_table, start, seq_mask,
                                                ps)
+    read_steps = _paged_read_steps(positions, seq_mask, ps,
+                                   page_table.shape[1])
 
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
@@ -2030,10 +2156,12 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         x, pools, counts = _block_paged(
             cfg, {**lp, **experts}, x, pools, positions,
             (src, keep, write_pages + first_page), page_table + first_page,
-            rng, adapters=layer[2] if adapters is not None else None,
+            read_steps, rng,
+            adapters=layer[2] if adapters is not None else None,
             ad_scale=ad_scale, seq_mask=seq_mask,
             expert_offset=(first_page // num_pages * cfg.num_experts
-                           if experts else None))
+                           if experts else None),
+            pool_order=pool_order)
         x = constrain_spec(x, P(BATCH_AXES, None, None))
         return (x, pools), counts
 

@@ -147,3 +147,79 @@ def test_olmoe_paged_decode_compiles_for_v5e_with_its_experts_in_place(
                 >= expert_stack:
             assert re.search(r" (parameter|get-tuple-element|bitcast)\(",
                              line), line[:200]
+
+
+@pytest.mark.parametrize(
+    "name,overrides,slots,pool_order",
+    [("opt-1.3b", {"activation": "relu"}, 8, (0, 1, 3, 4, 2)),
+     ("olmoe-1b-7b", {}, 16, None)],
+    ids=["opt-64-wide-heads-page-minor", "olmoe-128-wide-heads-row-major"])
+def test_paged_decode_reads_a_bounded_pool_in_place_on_v5e(
+        name, overrides, slots, pool_order):
+    """AOT: one decode tick of ``forward_paged`` at the published widths
+    (depth 2) and the benchmark's geometry (16 pages of 128 rows a slot),
+    compiled by the installed libtpu with the pool donated.  The read's loop
+    sits two computations deep, where the compiler takes an operand
+    row-major in its logical shape; handed the pool in the order the device
+    stores it (``pool_order``: the TPU puts the page rows of a 64-wide head
+    minor-most, keeps a 128-wide one row-major) nothing is re-laid out: no
+    op's result is the size of a pool leaf except the in-place page
+    scatters, no gather is a slot's whole page-table row wide, and the
+    program's temporaries are megabytes (PERF.md, PR 25 and PR 27)."""
+    import re
+
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models.transformer import (PAGED_READ_GRANULE,
+                                                  forward_paged)
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"TPU topology unavailable for AOT compile: {e}")
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    cfg = get_config(name, num_layers=2, **overrides)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    maxp, page = 16, 128
+    page_elems = page * cfg.kv_heads * cfg.dims_per_head
+    pool = S((2, 1 + slots * maxp, page, cfg.kv_heads, cfg.dims_per_head),
+             jnp.bfloat16)
+
+    def tick(params, k, v, tokens, table, start, mask):
+        logits, cache = forward_paged(cfg, params, tokens, {"k": k, "v": v},
+                                      table, start, mask,
+                                      pool_order=pool_order)
+        return jnp.argmax(logits[:, -1], -1), cache["k"], cache["v"]
+
+    compiled = jax.jit(tick, donate_argnums=(1, 2)).lower(
+        params, pool, pool, S((slots, 1), jnp.int32),
+        S((slots, maxp), jnp.int32), S((slots,), jnp.int32),
+        S((slots, 1), jnp.bool_)).compile()
+    row_wide = slots * maxp * page_elems
+    read = slots * PAGED_READ_GRANULE * page_elems
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 * read * 4
+    gathers = set()
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if sorted(dims[-3:]) != sorted([page, cfg.kv_heads,
+                                        cfg.dims_per_head]):
+            continue                     # not pages of K/V
+        n = int(np.prod(dims))
+        if " gather(" in line:
+            gathers.add(n)
+        if n >= row_wide:
+            assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
+                             r"\(| scatter\(|/scatter\"", line), line[:240]
+    assert read in gathers and max(gathers) == read

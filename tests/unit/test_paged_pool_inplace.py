@@ -19,6 +19,18 @@ scans over or stacks has the pool's page axis, and its body holds no
 ``dynamic_slice``/``dynamic_update_slice`` of the pool and no gather or
 scatter that cuts a page — the ops that, one layer's slice or one row at a
 time, made the TPU compiler copy the pool (PERF.md, PR 25).
+
+(c) The bounded read (ISSUE 27): the read stops at the longest live position
+of the call, two pages a step.  Against the same row-granular block, which
+attends over every row of every slot's page-table row: lengths one short
+of, at and one past a page's and a step's end, a slot at ``max_model_len -
+1`` beside short ones, an inactive slot, no active slot at all, a tail
+prefill over shared pages and a verify-k block across a step's end, on a
+table of three steps.  The softmax is computed blockwise, so what a layer
+writes after the first may differ from the reference in the last place;
+the first layer's pages are ``array_equal``.  No gather in the layer body
+is as wide as the page-table row, and the device's order of the pool's axes
+(``pool_order``) changes the view the read gathers from, never a number.
 """
 import functools
 
@@ -31,8 +43,11 @@ import jax.numpy as jnp
 from deepspeed_tpu.models import get_config, init_params
 from deepspeed_tpu.models.transformer import (_attn_out, _block_paged,
                                               _lm_head, _mlp, _norm,
+                                              PAGED_READ_GRANULE,
+                                              _paged_read_steps,
                                               _paged_write_plan, _qkv,
-                                              _sm_scale, forward_paged,
+                                              _adapter_delta, _sm_scale,
+                                              forward_paged,
                                               init_paged_cache, kv_dequantize,
                                               kv_quantize_rows)
 
@@ -40,6 +55,11 @@ L, NUM_PAGES, PAGE, B = 3, 9, 8, 3   # 4 pages a slot: max_model_len 32
 CFG = get_config("tiny-gqa", num_layers=L, dtype=jnp.float32)
 # slot -> physical pages; 0 = unallocated (the trash page)
 PAGE_TABLE = np.array([[3, 1, 7, 5], [2, 8, 4, 0], [6, 0, 0, 0]], np.int32)
+# the bounded read's table: 6 pages a slot (max_model_len 48) = three steps
+# of two pages, 15 physical pages
+WIDE_PAGES = 15
+WIDE_TABLE = np.array([[3, 1, 7, 5, 11, 13], [2, 8, 4, 0, 0, 0],
+                       [6, 9, 0, 0, 0, 0]], np.int32)
 
 
 @pytest.fixture(scope="module")
@@ -47,10 +67,10 @@ def params():
     return init_params(CFG, jax.random.PRNGKey(11))
 
 
-def _filled_pool(kv_dtype, seed=5):
+def _filled_pool(kv_dtype, seed=5, num_pages=NUM_PAGES):
     """A pool holding random rows everywhere, so a row written to or read
     from the wrong layer or page changes the result."""
-    cache = init_paged_cache(CFG, NUM_PAGES, PAGE, dtype=jnp.bfloat16,
+    cache = init_paged_cache(CFG, num_pages, PAGE, dtype=jnp.bfloat16,
                              kv_dtype=kv_dtype)
     rng = np.random.default_rng(seed)
     out = {}
@@ -78,25 +98,53 @@ def _shape_case(name):
         start = [11]
         mask = (np.arange(16) < 9)[None, :]
         table = PAGE_TABLE[:1]
-    else:                         # verify-k [B,k+1]; slot 0 runs past row 31
+    elif name == "verify":        # verify-k [B,k+1]; slot 0 runs past row 31
         tokens = rng.integers(1, 250, (B, 4))
         start = [30, 12, 3]
         mask = np.ones((B, 4), bool)
         table = PAGE_TABLE
+    elif name.startswith("len"):  # decode, slot 0 holds n rows; slot 2 idle
+        tokens = rng.integers(1, 250, (B, 1))
+        start = [int(name[3:]), 3, 0]
+        mask = np.array([[True], [True], [False]])
+        table = WIDE_TABLE
+    elif name == "long-beside-short":   # slot 0 writes the table's last row
+        tokens = rng.integers(1, 250, (B, 1))
+        start = [47, 2, 9]
+        mask = np.ones((B, 1), bool)
+        table = WIDE_TABLE
+    elif name == "all-inactive":
+        tokens = rng.integers(1, 250, (B, 1))
+        start = [21, 3, 0]
+        mask = np.zeros((B, 1), bool)
+        table = WIDE_TABLE
+    elif name == "tail-prefill":  # 9 real tokens behind 19 shared rows
+        tokens = rng.integers(1, 250, (1, 16))
+        start = [19]
+        mask = (np.arange(16) < 9)[None, :]
+        table = WIDE_TABLE[:1]
+    else:                         # verify-k across rows 15|16: a step's end
+        assert name == "verify-straddle", name
+        tokens = rng.integers(1, 250, (B, 4))
+        start = [14, 6, 3]
+        mask = np.ones((B, 4), bool)
+        table = WIDE_TABLE
     return (jnp.asarray(tokens, jnp.int32), jnp.asarray(table),
             jnp.asarray(start, jnp.int32), jnp.asarray(mask))
 
 
-def _adapters(seed=23, rank=4):
+def _adapters(seed=23, rank=4, slots=B):
     rng = np.random.default_rng(seed)
     d, hq = CFG.hidden_size, CFG.num_heads * CFG.dims_per_head
     dims = {"wq": (d, hq), "wo": (hq, d)}
-    return {"scale": jnp.asarray([0.5, 0.0, 2.0], jnp.float32),
+    return {"scale": jnp.asarray([0.5, 0.0, 2.0][:slots], jnp.float32),
             "factors": {
-                t: {"A": jnp.asarray(rng.normal(size=(L, B, di, rank)) * 0.1,
-                                     jnp.float32),
-                    "B": jnp.asarray(rng.normal(size=(L, B, rank, do)) * 0.1,
-                                     jnp.float32)}
+                t: {"A": jnp.asarray(
+                        rng.normal(size=(L, slots, di, rank)) * 0.1,
+                        jnp.float32),
+                    "B": jnp.asarray(
+                        rng.normal(size=(L, slots, rank, do)) * 0.1,
+                        jnp.float32)}
                 for t, (di, do) in dims.items()}}
 
 
@@ -125,19 +173,24 @@ def _forward_sliced(params, tokens, cache, page_table, start, seq_mask,
     write = _paged_write_plan(page_table, start, seq_mask,
                               cache["k"].shape[2])
 
+    steps = _paged_read_steps(positions, seq_mask, cache["k"].shape[2],
+                              page_table.shape[1])
+
     def block(lp, x, pools, ad):
         return _block_paged(
-            CFG, lp, x, pools, positions, write, page_table,
+            CFG, lp, x, pools, positions, write, page_table, steps,
             jax.random.PRNGKey(0), adapters=ad,
             ad_scale=None if adapters is None else adapters["scale"])[:2]
 
     return _forward_layers(block, params, tokens, cache, adapters)
 
 
-def _forward_rows(params, tokens, cache, page_table, start, seq_mask):
+def _forward_rows(params, tokens, cache, page_table, start, seq_mask,
+                  adapters=None):
     """Row-granular paging: one scatter index per token (masked tokens and
     positions past the table go to row 0 of the trash page), one gather
-    index per row of each slot, attention over T flat rows."""
+    index per row of each slot, attention over all T flat rows of the
+    page-table row."""
     cfg = CFG
     Bq, S = tokens.shape
     ps, maxp = cache["k"].shape[2], page_table.shape[1]
@@ -151,10 +204,16 @@ def _forward_rows(params, tokens, cache, page_table, start, seq_mask):
                   + jnp.arange(ps, dtype=jnp.int32)).reshape(Bq, maxp * ps)
     t = jnp.arange(maxp * ps, dtype=jnp.int32)
 
-    def block(lp, x, pools, _):
+    def block(lp, x, pools, ad):
+        def proj(y, name, hin):
+            if ad is not None and name in ad:
+                y = y + _adapter_delta(hin, ad[name],
+                                       adapters["scale"]).astype(y.dtype)
+            return y
+
         flat = {k: a.reshape(-1, *a.shape[2:]) for k, a in pools.items()}
         h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
-        q, k, v = _qkv(cfg, lp, h, positions)
+        q, k, v = _qkv(cfg, lp, h, positions, proj)
         k, v = (a.reshape(Bq * S, nkv, hd) for a in (k, v))
         if "k_scale" in flat:
             (k, ks), (v, vs) = kv_quantize_rows(k), kv_quantize_rows(v)
@@ -173,12 +232,12 @@ def _forward_rows(params, tokens, cache, page_table, start, seq_mask):
         scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         attn = jnp.einsum("bkgst,btkd->bskgd", probs, cv)
-        x = x + _attn_out(cfg, lp, attn.reshape(Bq, S, -1, hd))
+        x = x + _attn_out(cfg, lp, attn.reshape(Bq, S, -1, hd), proj)
         h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
         m = _mlp(cfg, lp, h, jax.random.PRNGKey(0), deterministic=True)[0]
         return x + m, {k: a.reshape(pools[k].shape) for k, a in flat.items()}
 
-    return _forward_layers(block, params, tokens, cache, None)
+    return _forward_layers(block, params, tokens, cache, adapters)
 
 
 CASES = [(shape, kv, False) for shape in ("decode", "prefill", "verify")
@@ -211,25 +270,91 @@ def test_forward_paged_equals_per_layer_slices(params, shape, kv_dtype,
                                   np.asarray(want_logits)[real])
 
 
-@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
-@pytest.mark.parametrize("shape", ["decode", "prefill", "verify"])
-def test_page_merge_equals_row_scatter_and_gather(params, shape, kv_dtype):
+_PAGED = jax.jit(functools.partial(forward_paged, CFG),
+                 static_argnames=("pool_order",))
+_ROWS = jax.jit(_forward_rows)
+
+# lengths one short of, at and one past the end of a page (8 rows) that is
+# no step's end, of the first step (16) and of the second (32)
+ROW_CASES = (
+    [(shape, kv, False) for shape in ("decode", "prefill", "verify")
+     for kv in (None, "int8")]
+    + [(f"len{n}", None, False) for n in (7, 8, 9, 15, 16, 17, 31, 32, 33)]
+    + [(f"len{n}", "int8", False) for n in (15, 16, 17)]
+    + [("len16", None, True), ("len17", "int8", True)]
+    + [(shape, kv, ad)
+       for shape in ("long-beside-short", "all-inactive", "tail-prefill",
+                     "verify-straddle")
+       for kv, ad in ((None, False), ("int8", True))])
+
+
+def _assert_pools_match(got, want, layers, exact):
+    """Every real page (page 0 is the trash page: the row scatter dumps
+    masked tokens there, the page merge writes them nowhere) of ``layers``:
+    bit for bit, or to the last place of what a leaf stores."""
+    for k in got:
+        g = np.asarray(got[k])[layers, 1:]
+        w = np.asarray(want[k])[layers, 1:]
+        if exact:
+            np.testing.assert_array_equal(g, w, err_msg=f"pool leaf {k}")
+        elif g.dtype == np.int8:
+            assert np.abs(g.astype(np.int32) - w).max() <= 1, k
+            assert (g != w).mean() < 1e-3, k
+        else:
+            np.testing.assert_allclose(
+                g.astype(np.float32), w.astype(np.float32),
+                rtol=2.0 ** -7 if g.dtype == jnp.bfloat16 else 1e-5,
+                atol=1e-6, err_msg=f"pool leaf {k}")
+
+
+@pytest.mark.parametrize(
+    "shape,kv_dtype,with_adapters", ROW_CASES,
+    ids=[f"{s}-{kv or 'bf16'}{'-adapters' if ad else ''}"
+         for s, kv, ad in ROW_CASES])
+def test_page_merge_equals_row_scatter_and_gather(params, shape, kv_dtype,
+                                                  with_adapters):
     tokens, table, start, mask = _shape_case(shape)
-    cache = _filled_pool(kv_dtype)
-    want_logits, want = jax.jit(_forward_rows)(
-        params, tokens, cache, table, start, mask)
-    got_logits, got = jax.jit(functools.partial(forward_paged, CFG))(
-        params, tokens, cache, table, start, mask)
-    for k in cache:
-        # page 0 is the trash page: the row scatter dumps masked tokens
-        # there, the page merge writes them nowhere
-        np.testing.assert_array_equal(np.asarray(got[k])[:, 1:],
-                                      np.asarray(want[k])[:, 1:],
-                                      err_msg=f"pool leaf {k}")
+    wide = table.shape[1] == WIDE_TABLE.shape[1]
+    cache = _filled_pool(kv_dtype,
+                         num_pages=WIDE_PAGES if wide else NUM_PAGES)
+    adapters = (_adapters(slots=tokens.shape[0]) if with_adapters
+                else None)
+    want_logits, want = _ROWS(params, tokens, cache, table, start, mask,
+                              adapters)
+    got_logits, got = _PAGED(params, tokens, cache, table, start, mask,
+                             adapters)
     real = np.asarray(mask)
+    if real.any():
+        assert not np.array_equal(np.asarray(got["k"]),
+                                  np.asarray(cache["k"]))
+    else:   # nothing real: every real page is as it was, nothing is NaN
+        _assert_pools_match(got, cache, slice(None), exact=True)
+        assert np.isfinite(np.asarray(got_logits)).all()
+    # the first layer writes before any attention has run
+    _assert_pools_match(got, want, slice(0, 1), exact=True)
+    _assert_pools_match(got, want, slice(None), exact=False)
     np.testing.assert_allclose(np.asarray(got_logits)[real],
                                np.asarray(want_logits)[real],
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("order", [(0, 1, 3, 4, 2), (0, 1, 4, 2, 3),
+                                   (1, 0, 2, 3, 4)],
+                         ids=["page-minor", "heads-minor", "not-stackable"])
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["bf16", "int8"])
+def test_pool_order_changes_the_view_not_the_numbers(params, kv_dtype, order):
+    """``pool_order`` says how the device stores a leaf; the read gathers
+    from the view in that order (or, where the page axes do not lead, from
+    the leaf as it is) and computes what it computes without it."""
+    tokens, table, start, mask = _shape_case("verify-straddle")
+    cache = _filled_pool(kv_dtype, num_pages=WIDE_PAGES)
+    want_logits, want = _PAGED(params, tokens, cache, table, start, mask)
+    got_logits, got = _PAGED(params, tokens, cache, table, start, mask,
+                             pool_order=order)
+    _assert_pools_match(got, want, slice(0, 1), exact=True)
+    _assert_pools_match(got, want, slice(None), exact=False)
+    np.testing.assert_allclose(np.asarray(got_logits),
+                               np.asarray(want_logits), rtol=1e-5, atol=1e-5)
 
 
 def _sub_jaxprs(jaxpr):
@@ -283,6 +408,10 @@ def test_layer_scan_carries_the_pool_in_place(params, kv_dtype):
                 f"{name} of the pool in the layer body: {e}"
             if name == "gather":
                 assert tuple(e.params["slice_sizes"][1:]) == shape[1:], e
+                # ... and never a slot's whole page-table row: the read
+                # takes PAGED_READ_GRANULE pages of every slot a step
+                n_pages = int(np.prod(e.invars[1].aval.shape[:-1]))
+                assert n_pages <= B * PAGED_READ_GRANULE < table.size, e
             if name == "scatter":
                 dn = e.params["dimension_numbers"]
                 assert len(dn.update_window_dims) == len(shape) - 1, e

@@ -135,9 +135,14 @@ def test_decode_and_prefill_attrs_match_the_requests(tiny_serve, traced):
     # (0-based) finds every slot holding its prompt plus j tokens' rows
     assert [s.attrs["live_rows"] for s in ticks] == [
         sum(lengths) + len(lengths) * j for j in range(n_new - 1)]
-    pages_per_slot = -(-GEO["max_model_len"] // GEO["page_size"])
-    assert {s.attrs["gathered_rows"] for s in ticks} == {
-        GEO["b_slots"] * pages_per_slot * GEO["page_size"]}
+    # the read covers every slot's pages up to the longest slot's newest
+    # row (its prompt, j tokens, the one being written), two pages a step
+    step = 2 * GEO["page_size"]
+    assert [s.attrs["gathered_rows"] for s in ticks] == [
+        GEO["b_slots"] * step * -(-(max(lengths) + j + 1) // step)
+        for j in range(n_new - 1)]
+    for i, n in enumerate(lengths):
+        assert fills[f"q{i}"]["gathered_rows"] == step * -(-n // step)
     for s in ticks:
         assert 0 < s.attrs["dispatch_ms"] <= s.dur_s * 1e3
 
