@@ -150,10 +150,11 @@ def release():
 
 
 def train_config(micro_batch, zero_stage):
-    # the memory-lean Adam the repo's one measured training cell used
-    # (bench.py defaults): lr 1e-4, bf16 first moment, bf16 gradient
-    # accumulation.  No warm-up in so few steps, so the rate stays low: at
-    # 3e-4 opt-1.3b's loss fell for three steps and jumped on the fourth.
+    # the memory-lean Adam of the benchmark's training cells
+    # (benchmark/traffic/train-*.json): lr 1e-4, bf16 first moment, bf16
+    # gradient accumulation.  No warm-up in so few steps, so the rate stays
+    # low: at 3e-4 opt-1.3b's loss fell for three steps and jumped on the
+    # fourth.
     return {
         "train_micro_batch_size_per_gpu": micro_batch,
         "gradient_accumulation_steps": 1,

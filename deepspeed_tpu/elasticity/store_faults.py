@@ -7,8 +7,7 @@ the store itself perfectly healthy and instant.  This module closes that
 gap with a :class:`FaultyStore` proxy that wraps ANY store with seeded,
 per-op-class fault programs:
 
-- **latency** — a real ``time.sleep`` before the op (the serve_bench
-  store-latency sweep drives this);
+- **latency** — a real ``time.sleep`` before the op;
 - **error** — raise :class:`InjectedStoreFault` (an ``OSError``:
   transient, retryable — exactly what
   :class:`~.coordination.StoreRetryPolicy` absorbs);
@@ -212,8 +211,7 @@ class FaultyStore(CoordinationStore):
     backend surface like ``cas_contended_total``, ``corrupt_docs_total``
     or ``_path`` stays reachable through the proxy.  Per-op wall
     latencies are recorded in bounded windows
-    (:meth:`op_latency_percentiles`) — the measurement surface of
-    ``serve_bench --store_latency_ms``."""
+    (:meth:`op_latency_percentiles`)."""
 
     def __init__(self, inner: CoordinationStore, client: str = "client",
                  rules: Optional[List[StoreFaultRule]] = None,

@@ -46,11 +46,6 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     max_tokens: int = 1024
     enable_cuda_graph: bool = False  # accepted for parity; jit IS the graph
     replace_method: str = "auto"
-    # RETIRED knob, accepted for config compat and ignored (with a
-    # warning): the Pallas decode kernel lost 21/22 cells of the honest
-    # per-(B, T, head-mix) A/B (tools/artifacts/decode_r5.json) and was
-    # deleted in round 5 — decode always rides the XLA einsum path
-    use_flash_decode: Optional[bool] = None
     zero: Dict[str, Any] = Field(default_factory=dict)
     triangular_masking: bool = True
     return_tuple: bool = True

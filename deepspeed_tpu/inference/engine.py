@@ -60,13 +60,6 @@ class InferenceEngine:
         self._config = config or DeepSpeedInferenceConfig()
         self._model = model if hasattr(model, "apply_cached") else None
         self._gen_cache: OrderedDict = OrderedDict()
-        if self._config.use_flash_decode:
-            logger.warning(
-                "use_flash_decode: the Pallas decode kernel was RETIRED in "
-                "round 5 — it lost 21/22 cells of the honest per-(B, T, "
-                "head-mix) A/B (tools/artifacts/decode_r5.json); decode "
-                "always uses the XLA einsum path now.  The knob is accepted "
-                "for config compatibility and ignored.")
         if model is not None:
             apply_fn = apply_fn or getattr(model, "apply_fn", None) or getattr(
                 model, "apply", None)

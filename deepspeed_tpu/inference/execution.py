@@ -343,24 +343,8 @@ class MeshExecutor:
     def _build_decode(self):
         apply_paged, with_counts = self._apply_paged, self._with_counts
 
-        if self.adapters is not None:
-            def prog(params, pools, page_table, lengths, last_tok, active,
-                     temp, top_k, top_p, seeds, adapters):
-                cache = paged_pool_cache(pools)
-                logits, cache, counts = apply_paged(
-                    params, last_tok[:lengths.shape[0], None], cache,
-                    page_table, lengths, active[:, None], adapters=adapters)
-                with jax.named_scope("sample"):
-                    nxt = sample_tokens(
-                        logits[:, -1, :], temp, top_k, top_p,
-                        lambda: position_keys(seeds, lengths + 1))
-                return with_counts(nxt, counts), paged_pool_tuple(cache)
-
-            return _named_pool_jit(prog, "serve_decode", self.mesh,
-                                   self._pool_specs)
-
         def prog(params, pools, page_table, lengths, last_tok, active,
-                 temp, top_k, top_p, seeds):
+                 temp, top_k, top_p, seeds, *adapters):
             # write each slot's last token at position `lengths`, read the
             # next-token logits; inactive slots write to the trash page.
             # The sampled token will sit at stream position `lengths + 1`,
@@ -370,11 +354,14 @@ class MeshExecutor:
             # independent and resume-exact (docs/SERVING.md "Sampling").
             # `last_tok` has the shape of the program's own first output
             # (tokens, then an MoE model's expert counts), so a tick can be
-            # fed the one before it without a fetch (ServingEngine lookahead)
+            # fed the one before it without a fetch (ServingEngine
+            # lookahead).  `adapters`: the per-slot factor pytree, one
+            # trailing operand where a registry rides along, else none.
             cache = paged_pool_cache(pools)
             logits, cache, counts = apply_paged(
                 params, last_tok[:lengths.shape[0], None], cache, page_table,
-                lengths, active[:, None])
+                lengths, active[:, None],
+                adapters=adapters[0] if adapters else None)
             with jax.named_scope("sample"):
                 nxt = sample_tokens(logits[:, -1, :], temp, top_k, top_p,
                                     lambda: position_keys(seeds, lengths + 1))
@@ -386,28 +373,8 @@ class MeshExecutor:
     def _build_prefill(self, s_pad: int):
         apply_paged, with_counts = self._apply_paged, self._with_counts
 
-        if self.adapters is not None:
-            def prog(params, pools, pt_row, tokens, n_real, start,
-                     temp, top_k, top_p, seed, adapters):
-                seq_mask = (jnp.arange(s_pad, dtype=jnp.int32)
-                            < n_real)[None, :]
-                cache = paged_pool_cache(pools)
-                logits, cache, counts = apply_paged(
-                    params, tokens, cache, pt_row, start[None], seq_mask,
-                    adapters=adapters)
-                with jax.named_scope("sample"):
-                    lg = logits[0, n_real - 1, :][None]        # [1, V]
-                    nxt = sample_tokens(
-                        lg, temp, top_k, top_p,
-                        lambda: position_keys(seed,
-                                              (start + n_real)[None]))[0]
-                return with_counts(nxt, counts), paged_pool_tuple(cache)
-
-            return _named_pool_jit(prog, f"serve_prefill_{s_pad}", self.mesh,
-                                   self._pool_specs)
-
         def prog(params, pools, pt_row, tokens, n_real, start,
-                 temp, top_k, top_p, seed):
+                 temp, top_k, top_p, seed, *adapters):
             # tokens [1, s_pad] right-padded; only the first n_real K/V are
             # written (pads go to the trash page); the first generated token
             # samples the last REAL position's logits under the request's
@@ -415,13 +382,15 @@ class MeshExecutor:
             # in-graph, so the historical greedy contract is bit-identical).
             # `start` is the slot position of tokens[:, 0] — 0 for a cold
             # prefill, the shared-prefix length for a tail prefill (the
-            # gather still covers the whole page-table row, so queries
-            # attend to the shared pages through the ordinary causal mask).
+            # read starts at the slot's first page, so queries attend to
+            # the shared pages through the ordinary causal mask).
             # A traced scalar: every start shares ONE program per bucket.
+            # `adapters`: the admitted slot's factor slice, as in decode.
             seq_mask = (jnp.arange(s_pad, dtype=jnp.int32) < n_real)[None, :]
             cache = paged_pool_cache(pools)
-            logits, cache, counts = apply_paged(params, tokens, cache, pt_row,
-                                                start[None], seq_mask)
+            logits, cache, counts = apply_paged(
+                params, tokens, cache, pt_row, start[None], seq_mask,
+                adapters=adapters[0] if adapters else None)
             # the emitted token will sit at stream position S = start +
             # n_real — the counter-based key generate(sampling=...) and
             # every replay/failover resume re-derive for the same position

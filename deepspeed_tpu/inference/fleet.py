@@ -72,8 +72,8 @@ training pods — by leaning on the :class:`~..elasticity.coordination
   :meth:`~.serving_supervisor.ServingSupervisor.recycle` a fresh engine
   without spending the fault-restart budget.
 
-The in-process harness (tests, ``tools/chaos_soak.py --mode fleet``,
-``tools/serve_bench.py --mode fleet``) drives members cooperatively — one
+The in-process harness (tests, ``tools/chaos_soak.py --mode fleet``)
+drives members cooperatively — one
 ``pump()`` per router round — so chaos schedules stay deterministic; the
 production shape is one member per process with the router polling the same
 store keys.  Fleet rollup gauges (``fleet/engines_live``,
@@ -664,9 +664,9 @@ class FleetRouter:
         # time-based flush alternative (PR 8 carry-over): flush whenever
         # journal_flush_ms of STORE-clock time passed since the last flush
         # — the cadence an operator tunes against the store's real write
-        # latency (serve_bench --mode fleet reports per-flush CAS p50/p99
-        # for exactly that).  Composes with journal_every_k: either trigger
-        # flushes; None+None disables mid-stream appends entirely.
+        # latency (journal_cas_latencies).  Composes with journal_every_k:
+        # either trigger flushes; None+None disables mid-stream appends
+        # entirely.
         self.journal_flush_ms = (float(journal_flush_ms)
                                  if journal_flush_ms is not None else None)
         if self.journal_flush_ms is not None and self.journal_flush_ms <= 0:
@@ -1712,8 +1712,7 @@ class FleetRouter:
     def journal_cas_latencies(self) -> List[float]:
         """Recent per-append journal CAS wall times in seconds (bounded
         window) — what ``journal_every_k`` / ``journal_flush_ms`` should
-        be tuned against on a real store (serve_bench --mode fleet reports
-        the p50/p99)."""
+        be tuned against on a real store."""
         return list(self._journal_cas_lat_s)
 
     def _journaled_tokens(self, rid: Any) -> List[int]:
@@ -1781,7 +1780,7 @@ class FleetRouter:
                 t0 = time.perf_counter()
                 won = self.store.compare_and_swap(key, cur, new)
                 # per-append CAS wall time: the number journal_flush_ms is
-                # tuned against (serve_bench --mode fleet reports p50/p99)
+                # tuned against
                 self._journal_cas_lat_s.append(time.perf_counter() - t0)
                 if won:
                     self._journal_docs[rid] = new

@@ -296,8 +296,7 @@ class FleetMemberDaemon:
         outbox flush, progress and beats happen at most once per
         ``min_store_poll_s`` while pump runs every round — the bound that
         keeps store-op volume per wall second independent of the tick
-        rate (and of per-op store latency; see serve_bench
-        --store_latency_ms)."""
+        rate (and of per-op store latency)."""
         if self.min_store_poll_s <= 0:
             return True
         now = time.monotonic()

@@ -50,7 +50,7 @@ pages are REFCOUNTED and immutable-once-full, and a prefix index
 physical page) lets a request whose prompt prefix is already resident map
 the shared pages into its page table and prefill only the unshared tail —
 copy-on-write applies to the one partial boundary page (a fixed-shape
-snapshot program; see ``models.transformer.cow_copy_page``).  Admission
+snapshot program; see ``models.transformer.cow_copy_pool``).  Admission
 reserves only unshared pages; retirement, expiry and quarantine DROP
 refcounts instead of freeing, and the index holds one refcount per cached
 page so hot prefixes survive their donors.  The pool invariant becomes

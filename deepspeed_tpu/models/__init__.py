@@ -13,7 +13,7 @@ import os
 import jax.numpy as jnp
 
 from .transformer import (CONFIGS, KV_QUANT_DTYPES, PAGE_SIZE,
-                          TransformerConfig, cache_specs, cow_copy_page,
+                          TransformerConfig, cache_specs,
                           cow_copy_pool, cross_entropy_loss, forward,
                           forward_cached, forward_paged, get_config, has_moe,
                           init_cache, init_paged_cache, init_params,
@@ -23,7 +23,7 @@ from .transformer import (CONFIGS, KV_QUANT_DTYPES, PAGE_SIZE,
 __all__ = ["CausalLM", "TransformerConfig", "CONFIGS", "get_config", "forward",
            "forward_cached", "forward_paged", "init_cache", "init_paged_cache",
            "cache_specs", "paged_cache_specs", "init_params", "param_specs",
-           "cross_entropy_loss", "PAGE_SIZE", "cow_copy_page", "cow_copy_pool",
+           "cross_entropy_loss", "PAGE_SIZE", "cow_copy_pool",
            "paged_pool_tuple", "paged_pool_cache", "KV_QUANT_DTYPES"]
 
 

@@ -132,11 +132,6 @@ class TransformerConfig:
     act_quant_bits: int = 0
     act_quant_symmetric: bool = False
     scan_layers: bool = True
-    # RETIRED knob, accepted for config compat: the Pallas flash-decode
-    # kernel was removed in round 5 after losing 21/22 cells of an honest
-    # per-(B, T, head-mix) A/B (tools/artifacts/decode_r5.json); decode
-    # always rides the XLA einsum now (see _attention_cached)
-    flash_decode: Optional[bool] = None
     dtype: Any = jnp.bfloat16                 # compute dtype hint (engine casts)
     initializer_range: float = 0.02
     # frozen parameters (reference requires_grad=False; engine contract
@@ -198,7 +193,7 @@ class TransformerConfig:
                 + final_norm)
 
 
-# -- named configs (sizes from the public model cards; used by bench + tests) --
+# -- named configs (sizes from the public model cards) --
 CONFIGS: Dict[str, TransformerConfig] = {
     "gpt2-125m": TransformerConfig(
         vocab_size=50257, hidden_size=768, intermediate_size=3072, num_layers=12,
@@ -226,18 +221,11 @@ CONFIGS: Dict[str, TransformerConfig] = {
         vocab_size=50272, hidden_size=2048, intermediate_size=8192, num_layers=24,
         num_heads=32, max_seq_len=2048, norm="layernorm", activation="gelu",
         position="learned", attn_bias=True, mlp_bias=True, tie_embeddings=True),
-    # single-v5e-chip bench models (llama architecture, fit bf16+fp32 Adam)
+    # llama-architecture sizes that train on one v5e chip (bf16 + fp32
+    # Adam); ~740M is the largest whose fused-Adam peak fits without offload
     "llama-374m": TransformerConfig(
         vocab_size=32000, hidden_size=1024, intermediate_size=2816, num_layers=24,
         num_heads=16, max_seq_len=2048),
-    # ~950M: matmul-dominated config (needs host offload or >1 chip: the
-    # fused update's transient peak is ~18 bytes/param on one 16G chip)
-    "llama-1b": TransformerConfig(
-        vocab_size=32000, hidden_size=2048, intermediate_size=5632, num_layers=16,
-        num_heads=16, max_seq_len=2048),
-    # ~740M: the largest llama config whose fused-Adam peak fits a single
-    # v5e chip without offload (VERDICT r1 weak #2: at 374M vocab/embedding
-    # matmuls and remat dominate the measurement)
     "llama-740m": TransformerConfig(
         vocab_size=32000, hidden_size=1792, intermediate_size=4864, num_layers=16,
         num_heads=14, max_seq_len=4096),
@@ -332,10 +320,9 @@ def _sm_scale(cfg: TransformerConfig, hd: int) -> float:
 # ---------------------------------------------------------------------------
 
 def _check_qk_norm(cfg: TransformerConfig) -> None:
-    if cfg.norm != "rmsnorm" or cfg.post_layernorm:
+    if cfg.norm != "rmsnorm":
         raise NotImplementedError(
-            "qk_norm is the pre-LN RMSNorm blocks' (OLMoE): it carries a "
-            "scale and no offset, and the post-LN block projects on its own")
+            "qk_norm is an RMSNorm (OLMoE): it carries a scale and no offset")
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
@@ -678,6 +665,25 @@ def _norm(cfg, x, scale, bias=None):
         return out.astype(x.dtype)
 
 
+def _embed(cfg, params, tokens, positions, token_type_ids=None):
+    """tokens ``[B,S]`` -> hidden states: token embedding, learned positions
+    (``positions`` index the table as they are; a caller whose positions can
+    pass its end clamps them), BERT's segment embedding, and the embedding
+    LayerNorm of Bloom / BERT."""
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens]
+        if cfg.position == "learned":
+            x = x + params["pos_embed"].astype(cfg.dtype)[positions]
+        if "type_embed" in params:
+            tt = (token_type_ids if token_type_ids is not None
+                  else jnp.zeros_like(tokens))
+            x = x + params["type_embed"].astype(cfg.dtype)[tt]
+        if cfg.embed_layernorm:
+            x = _norm(cfg, x, params["embed_norm_scale"],
+                      params.get("embed_norm_bias"))
+    return x
+
+
 def _lm_head(cfg, params, x):
     """Final hidden states -> logits (tied or untied head, GPT-J's bias)."""
     with jax.named_scope("lm_head"):
@@ -687,6 +693,15 @@ def _lm_head(cfg, params, x):
         if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
             logits = logits + params["lm_head_bias"].astype(cfg.dtype)
         return logits
+
+
+def _head(cfg, params, x):
+    """The last layer's output -> logits: the final norm (post-LN blocks end
+    normalised and have none), then :func:`_lm_head`."""
+    if cfg.final_norm:
+        x = _norm(cfg, x, params["final_norm_scale"],
+                  params.get("final_norm_bias"))
+    return _lm_head(cfg, params, x)
 
 
 def _rope(q, k, positions, theta, head_dim, rotary_dim=None,
@@ -901,8 +916,8 @@ def _alibi_bias(cfg, positions, num_heads, S, dtype):
 
 
 def _maybe_act_quant(cfg: TransformerConfig, h):
-    """Activation fake-quant at the post-norm matmul inputs (one shared site
-    for all four block variants — keep behavior in sync here)."""
+    """Activation fake-quant at the inputs of the attention and MLP
+    matmuls."""
     if not cfg.act_quant_bits:
         return h
     from ..compression.quantize import activation_fake_quant
@@ -942,10 +957,9 @@ def _dense_mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, prefix=""):
 
 def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
          token_mask=None, expert_offset=None):
-    """Post-norm MLP/MoE body shared by the training block and the KV-cached
-    decode block: returns (output, moe_aux_loss, counts), ``counts`` the
-    rows each expert computed (``[E]`` int32; ``None`` unless the layer ran
-    the dropless dispatch).  MoE-ness is detected from the layer's params
+    """The MLP or expert layer of :func:`_block`: returns (output,
+    moe_aux_loss, counts), ``counts`` the rows each expert computed (``[E]``
+    int32; ``None`` unless the layer ran the dropless dispatch).  MoE-ness is detected from the layer's params
     (PR-MoE pyramid layers differ per depth).
 
     ``token_mask [B,S]`` (serving: a prompt's padding, a tick's idle slots)
@@ -985,9 +999,9 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
 
 def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
     """Post-norm activations ``h [B,S,d]`` -> ``q [B,S,Hq,hd]``, ``k``,
-    ``v [B,S,Hkv,hd]``, biased and rotated — the pre-LN blocks' projection
-    (training, contiguous cache, paged pool).  ``proj(y, name, hin)``, when
-    given, adds the paged block's per-slot adapter delta."""
+    ``v [B,S,Hkv,hd]``, biased, QK-normed and rotated.  ``proj(y, name,
+    hin)``, when given, adds the serving path's per-slot adapter delta
+    (:func:`_adapter_proj`)."""
     B, S, _ = h.shape
     hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
     with jax.named_scope("attn_qkv"):
@@ -1022,88 +1036,80 @@ def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None):
     return out
 
 
-def _block_postln(cfg: TransformerConfig, lp: Dict[str, Any], x, positions,
-                  rng, attn_impl: str, deterministic: bool,
-                  custom_positions: bool = False, window=None):
-    """Post-layernorm encoder block (BERT):  x = LN(x + attn(x));
-    x = LN(x + mlp(x)).  The norm params are the POST-sublayer LayerNorms."""
-    B, S, d = x.shape
-    hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
-    h = _maybe_act_quant(cfg, x)
-    with jax.named_scope("attn_qkv"):
-        q = (h @ lp["wq"]).reshape(B, S, nh, hd)
-        k = (h @ lp["wk"]).reshape(B, S, nkv, hd)
-        v = (h @ lp["wv"]).reshape(B, S, nkv, hd)
-        if cfg.attn_bias:
-            q = q + lp["bq"].reshape(nh, hd)
-            k = k + lp["bk"].reshape(nkv, hd)
-            v = v + lp["bv"].reshape(nkv, hd)
-    with jax.named_scope("attn"):
-        attn = _attention(cfg, q, k, v, positions, attn_impl,
-                          custom_positions, window=window)
-    attn = _attn_out(cfg, lp, attn)
-    if cfg.dropout and not deterministic:
-        rng, sub = jax.random.split(rng)
-        attn = attn * jax.random.bernoulli(
-            sub, 1 - cfg.dropout, attn.shape) / (1 - cfg.dropout)
-    x = _norm(cfg, x + attn, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
+def _dropout(cfg: TransformerConfig, y, rng, deterministic: bool):
+    """``(y, rng)``: residual dropout on a sublayer's output, and the key
+    chain moved on by the split it took."""
+    if not cfg.dropout or deterministic:
+        return y, rng
     rng, sub = jax.random.split(rng)
-    m, aux, _ = _mlp(cfg, lp, _maybe_act_quant(cfg, x), sub, deterministic)
-    if cfg.dropout and not deterministic:
-        rng, sub = jax.random.split(rng)
-        m = m * jax.random.bernoulli(
-            sub, 1 - cfg.dropout, m.shape) / (1 - cfg.dropout)
-    return _norm(cfg, x + m, lp["mlp_norm_scale"],
-                 lp.get("mlp_norm_bias")), aux
+    keep = jax.random.bernoulli(sub, 1 - cfg.dropout, y.shape)
+    return y * keep / (1 - cfg.dropout), rng
 
 
 def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
-           attn_impl: str, deterministic: bool, custom_positions: bool = False,
-           window=None):
-    if cfg.post_layernorm:
-        return _block_postln(cfg, lp, x, positions, rng, attn_impl,
-                             deterministic, custom_positions, window=window)
-    h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
+           attend, deterministic: bool = True, proj=None, token_mask=None,
+           expert_offset=None):
+    """One transformer layer, every residual wiring the family has:
+
+      pre-LN (GPT-2, OPT, Llama)   x += attn(LN(x));  x += mlp(LN'(x))
+      parallel (NeoX; GPT-J)       x += attn(LN(x)) + mlp(LN'(x))  (GPT-J:
+                                   one LN, the MLP reads attention's input)
+      post-LN (BERT)               x = LN(x + attn(x));  x = LN'(x + mlp(x))
+
+    What attention reads, and where K/V go, is the caller's:
+    ``attend(q, k, v) -> (out [B,S,Hq,hd], state)`` is handed the layer's
+    projections and returns whatever it keeps (:func:`_attend_full`: nothing;
+    :func:`_attend_cached`: the layer's cache buffers; :func:`_attend_paged`:
+    the page pool).  ``proj``, ``token_mask`` and ``expert_offset`` are the
+    serving path's (:func:`_qkv`, :func:`_attn_out`, :func:`_mlp`).
+
+    Returns ``(x, moe_aux_loss, expert_counts, state)``."""
+    post = cfg.post_layernorm
+    h = x if post else _norm(cfg, x, lp["attn_norm_scale"],
+                             lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, h)
-    q, k, v = _qkv(cfg, lp, h, positions)
+    q, k, v = _qkv(cfg, lp, h, positions, proj)
     # named so "save_matmuls" can pin the projection outputs (post-rope, so
     # the attention backward starts from exactly these tensors)
     q = checkpoint_name(q, "q_proj")
     k = checkpoint_name(k, "k_proj")
     v = checkpoint_name(v, "v_proj")
-    with jax.named_scope("attn"):
-        attn = _attention(cfg, q, k, v, positions, attn_impl,
-                          custom_positions, window=window)
+    attn, state = attend(q, k, v)
     # named checkpoint: the "save_attn" remat policy stashes this one tensor
     # per layer ([B,S,H*hd] bf16) so the backward skips recomputing the whole
     # attention (the costliest part of the recompute) while the rest of the
     # layer still rematerializes
-    attn = _attn_out(cfg, lp, checkpoint_name(attn, "attn_out"))
-    if cfg.dropout and not deterministic:
-        rng, sub = jax.random.split(rng)
-        attn = attn * jax.random.bernoulli(sub, 1 - cfg.dropout, attn.shape) / (1 - cfg.dropout)
-
-    if cfg.parallel_residual:
-        # GPT-J/NeoX: attention and MLP both branch off x; one shared LN
-        # (GPT-J) or a second LN of the ORIGINAL x (NeoX)
-        h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
-            cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
-        rng, sub = jax.random.split(rng)
-        m, aux, _ = _mlp(cfg, lp, h2, sub, deterministic)
-        if cfg.dropout and not deterministic:
-            rng, sub = jax.random.split(rng)
-            m = m * jax.random.bernoulli(sub, 1 - cfg.dropout, m.shape) / (1 - cfg.dropout)
-        return x + attn + m, aux
-
-    x = x + attn
-    h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
-    h = _maybe_act_quant(cfg, h)
+    attn = _attn_out(cfg, lp, checkpoint_name(attn, "attn_out"), proj)
+    attn, rng = _dropout(cfg, attn, rng, deterministic)
+    res = x + attn
+    if post:
+        res = _norm(cfg, res, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
+        h2 = _maybe_act_quant(cfg, res)
+    elif cfg.parallel_residual and cfg.shared_layernorm:
+        h2 = h
+    else:
+        h2 = _maybe_act_quant(cfg, _norm(
+            cfg, x if cfg.parallel_residual else res,
+            lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
     rng, sub = jax.random.split(rng)
-    m, aux, _ = _mlp(cfg, lp, h, sub, deterministic)
-    if cfg.dropout and not deterministic:
-        rng, sub = jax.random.split(rng)
-        m = m * jax.random.bernoulli(sub, 1 - cfg.dropout, m.shape) / (1 - cfg.dropout)
-    return x + m, aux
+    m, aux, counts = _mlp(cfg, lp, h2, sub, deterministic,
+                          token_mask=token_mask, expert_offset=expert_offset)
+    m, rng = _dropout(cfg, m, rng, deterministic)
+    x = res + m
+    if post:
+        x = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
+    return x, aux, counts, state
+
+
+def _attend_full(cfg: TransformerConfig, positions, attn_impl: str = "xla",
+                 custom_positions: bool = False, window=None):
+    """:func:`_block`'s ``attend`` over the block's own tokens (training,
+    the uncached forward): nothing is kept."""
+    def attend(q, k, v):
+        with jax.named_scope("attn"):
+            return _attention(cfg, q, k, v, positions, attn_impl,
+                              custom_positions, window=window), None
+    return attend
 
 
 def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
@@ -1112,8 +1118,9 @@ def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
     policy and random-LTD wrapping applied — shared by forward() and the
     1F1B pipeline executor."""
     block = lambda lp, x, sub, pos, window=None: _block(  # noqa: E731
-        cfg, lp, x, pos, sub, attn_impl, deterministic, custom_positions,
-        window=window)
+        cfg, lp, x, pos, sub,
+        _attend_full(cfg, pos, attn_impl, custom_positions, window),
+        deterministic)[:2]
     if cfg.remat:
         if cfg.remat_policy == "save_attn":
             # keep each layer's attention output ([B,S,D] bf16 — ~2*B*S*D
@@ -1148,10 +1155,24 @@ def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
                 "random-LTD with per-layer attention types is not supported "
                 "(the token-subset wrapper does not thread the window)")
         inner_block = block
-        block = lambda lp, x, sub, pos: random_ltd_block(  # noqa: E731
-            inner_block, cfg, lp, x, pos, sub, cfg.random_ltd_keep,
-            deterministic)
+
+        def block(lp, x, sub, pos, window=None):
+            return random_ltd_block(inner_block, cfg, lp, x, pos, sub,
+                                    cfg.random_ltd_keep, deterministic)
     return block
+
+
+def _layer_step(block, positions, carry, xs):
+    """One layer of a training forward, in the form ``lax.scan`` takes:
+    ``carry = (x, rng, aux_sum)``, ``xs = (lp, keep, window)`` with ``keep``
+    (progressive layer drop: False skips the layer) and ``window`` (a local
+    attention layer's span) None where the call has none."""
+    (x, rng, aux_sum), (lp, keep, window) = carry, xs
+    rng, sub = jax.random.split(rng)
+    y, aux = block(lp, x, sub, positions, window)
+    if keep is not None:
+        y, aux = jnp.where(keep, y, x), jnp.where(keep, aux, 0.0)
+    return (y, rng, aux_sum + aux), None
 
 
 def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
@@ -1168,24 +1189,14 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
     if rng is None:
         rng = jax.random.PRNGKey(0)
 
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
-        if cfg.position == "learned":
-            x = x + params["pos_embed"].astype(cfg.dtype)[positions]
-        if "type_embed" in params:   # BERT segment embeddings
-            tt = (token_type_ids if token_type_ids is not None
-                  else jnp.zeros_like(tokens))
-            x = x + params["type_embed"].astype(cfg.dtype)[tt]
-        if cfg.embed_layernorm:      # Bloom / BERT embedding LayerNorm
-            x = _norm(cfg, x, params["embed_norm_scale"],
-                      params.get("embed_norm_bias"))
+    x = _embed(cfg, params, tokens, positions, token_type_ids)
     # activations: batch over DP axes, sequence over 'seq' axis
     act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
     x = constrain_spec(x, act_spec)
 
     block = _build_block(cfg, attn_impl, deterministic, custom_positions)
+    step = functools.partial(_layer_step, block)
 
-    aux_total = jnp.float32(0.0)
     het = isinstance(params["layers"], (list, tuple))  # PR-MoE pyramid
     windows = layer_windows(cfg)
     if pld_theta is not None and (cfg.pipeline_stages > 1
@@ -1208,14 +1219,9 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
         xm = x.reshape((M, mb) + x.shape[1:])
 
         def stage_fn(lp_stage, xs, srng):
-            def body(carry, lp):
-                xc, r, aux = carry
-                r, sub = jax.random.split(r)
-                xc, a = block(lp, xc, sub, pos_mb)
-                return (xc, r, aux + a), None
-
             (xs, _, aux), _ = jax.lax.scan(
-                body, (xs, srng, jnp.float32(0.0)), lp_stage)
+                lambda c, lp: step(pos_mb, c, (lp, None, None)),
+                (xs, srng, jnp.float32(0.0)), lp_stage)
             return xs, aux
 
         y, aux_sum = pipeline_apply(stage_fn, params["layers"], xm, rng)
@@ -1223,68 +1229,35 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
         x = constrain_spec(x, act_spec)
         aux_total = aux_sum / M      # mean over microbatches, sum over layers
     elif cfg.scan_layers and not het:
+        keep = None
         if pld_theta is not None:
-            # progressive layer drop (runtime/progressive_layer_drop.py):
-            # per-layer keep decisions ride the scan as a second xs — a
+            # progressive layer drop (runtime/progressive_layer_drop.py): a
             # dropped layer is the residual identity and contributes no aux
             from ..runtime.progressive_layer_drop import pld_keep_mask
 
             rng, sub = jax.random.split(rng)
             keep = pld_keep_mask(sub, cfg.num_layers, pld_theta)
 
-            if windows is not None:
-                raise NotImplementedError(
-                    "progressive layer drop with per-layer attention types "
-                    "is not supported")
+        # the per-layer keep decisions and local-attention windows ride the
+        # scan beside the layer stack where the call has them (None: an
+        # empty pytree), so layers stay uniform under one body
+        def body(carry, xs):
+            (x, r, aux), _ = step(positions, carry, xs)
+            return (constrain_spec(x, act_spec), r, aux), None
 
-            def body(carry, xs):
-                lp, keep_i = xs
-                x, r, aux_sum = carry
-                r, sub = jax.random.split(r)
-                x_new, aux = block(lp, x, sub, positions)
-                x = jnp.where(keep_i, x_new, x)
-                aux = jnp.where(keep_i, aux, 0.0)
-                x = constrain_spec(x, act_spec)
-                return (x, r, aux_sum + aux), None
-
-            (x, _, aux_total), _ = jax.lax.scan(
-                body, (x, rng, aux_total), (params["layers"], keep))
-        elif windows is not None:
-            # per-layer window rides the scan as a second xs — layers stay
-            # uniform (window==0 reduces to the plain causal mask)
-            def body(carry, xs):
-                lp, w = xs
-                x, r, aux_sum = carry
-                r, sub = jax.random.split(r)
-                x, aux = block(lp, x, sub, positions, w)
-                x = constrain_spec(x, act_spec)
-                return (x, r, aux_sum + aux), None
-
-            (x, _, aux_total), _ = jax.lax.scan(body, (x, rng, aux_total),
-                                                (params["layers"], windows))
-        else:
-            def body(carry, lp):
-                x, r, aux_sum = carry
-                r, sub = jax.random.split(r)
-                x, aux = block(lp, x, sub, positions)
-                x = constrain_spec(x, act_spec)
-                return (x, r, aux_sum + aux), None
-
-            (x, _, aux_total), _ = jax.lax.scan(body, (x, rng, aux_total),
-                                                params["layers"])
+        (x, _, aux_total), _ = jax.lax.scan(
+            body, (x, rng, jnp.float32(0.0)),
+            (params["layers"], keep, windows))
     else:
+        carry = (x, rng, jnp.float32(0.0))
         for i in range(cfg.num_layers):
             lp = (params["layers"][i] if het else
                   jax.tree_util.tree_map(lambda a: a[i], params["layers"]))
-            rng, sub = jax.random.split(rng)
-            x, aux = block(lp, x, sub, positions,
-                           None if windows is None else windows[i])
-            aux_total = aux_total + aux
+            carry, _ = step(positions, carry, (
+                lp, None, None if windows is None else windows[i]))
+        x, _, aux_total = carry
 
-    if cfg.final_norm:
-        x = _norm(cfg, x, params["final_norm_scale"],
-                  params.get("final_norm_bias"))
-    logits = _lm_head(cfg, params, x)
+    logits = _head(cfg, params, x)
     if return_aux:
         return logits, {"moe_aux_loss": aux_total}
     return logits
@@ -1322,13 +1295,9 @@ def pipeline_1f1b_loss_and_grads(cfg: TransformerConfig, params: Dict[str, Any],
                          custom_positions=False)
 
     def stage_fn(lp_stage, xs, srng):
-        def body(carry, lp):
-            xc, r = carry
-            r, sub = jax.random.split(r)
-            xc, _aux = block(lp, xc, sub, positions)
-            return (xc, r), None
-
-        (xs, _), _ = jax.lax.scan(body, (xs, srng), lp_stage)
+        (xs, _, _), _ = jax.lax.scan(
+            lambda c, lp: _layer_step(block, positions, c, (lp, None, None)),
+            (xs, srng, jnp.float32(0.0)), lp_stage)
         return xs
 
     stem_keys = [k for k in ("embed", "pos_embed", "embed_norm_scale",
@@ -1341,24 +1310,16 @@ def pipeline_1f1b_loss_and_grads(cfg: TransformerConfig, params: Dict[str, Any],
         head["embed"] = params["embed"]  # grads from the head sum with stem's
 
     def embed_fn(stem_p):
-        x = stem_p["embed"].astype(cfg.dtype)[tokens]
-        if "pos_embed" in stem_p:
-            x = x + stem_p["pos_embed"].astype(cfg.dtype)[
-                jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))]
-        if "embed_norm_scale" in stem_p:
-            x = _norm(cfg, x, stem_p["embed_norm_scale"],
-                      stem_p.get("embed_norm_bias"))
-        x = constrain_spec(x, P(BATCH_AXES, "seq", None))
+        x = _embed(cfg, stem_p, tokens, jnp.broadcast_to(
+            jnp.arange(S, dtype=jnp.int32)[None], (B, S)))
+        x = constrain_spec(x, act_spec)
         return x.reshape((M, mb) + x.shape[1:])
 
     def head_fn(hp, y, lbl):
-        if cfg.final_norm:
-            y = _norm(cfg, y, hp["final_norm_scale"],
-                      hp.get("final_norm_bias"))
-        logits = _lm_head(cfg, hp, y)
         # scaled so the executor's vjp carries exactly the engine's gradient
         # (scale * mean-over-microbatches)
-        return cross_entropy_loss(logits, lbl) * loss_scale / M
+        return (cross_entropy_loss(_head(cfg, hp, y), lbl)
+                * loss_scale / M)
 
     from ..runtime.pipe.spmd import pipeline_1f1b
 
@@ -1421,6 +1382,19 @@ def cache_specs(cfg: TransformerConfig) -> Dict[str, P]:
             "pos": P(BATCH_AXES, None), "next_slot": P()}
 
 
+def _check_decodable(cfg, params, what: str) -> None:
+    """What neither cached forward (contiguous, paged) can serve."""
+    assert cfg.pipeline_stages == 1, f"{what} requires pipeline_stages=1"
+    if not cfg.causal:
+        raise NotImplementedError(
+            f"{what} is a causal-LM operation; encoder models "
+            "(causal=False) have no autoregressive cache")
+    if isinstance(params["layers"], (list, tuple)):
+        raise NotImplementedError(
+            f"{what} with a PR-MoE pyramid (per-layer num_experts) is not "
+            "supported: the layer scan needs uniform stacks")
+
+
 def _attention_cached(cfg, q, ck, cv, q_pos, q_slot, valid, kpos, window=None):
     """q:[B,S,Hq,hd] against the full cache ck/cv:[B,T,Hkv,hd].
 
@@ -1432,17 +1406,9 @@ def _attention_cached(cfg, q, ck, cv, q_pos, q_slot, valid, kpos, window=None):
     B, S, Hq, hd = q.shape
     T, Hkv = ck.shape[1], ck.shape[2]
     G = Hq // Hkv
-    # There is deliberately NO custom decode kernel here.  A Pallas
-    # flash-decode shipped in rounds 2-4 and was REMOVED in round 5 after
-    # an honest per-cell A/B (tools/decode_bench.py ->
-    # tools/artifacts/decode_r5.json): the XLA einsum below won 21/22
-    # (B, T, head-mix) cells (its one loss is a jitter outlier: an
-    # anomalous 2x-slow XLA sample at a shape XLA wins at the next size
-    # up) — decode attention is HBM-bound, XLA
-    # saturates the bandwidth, and at small GQA caches it additionally
-    # keeps the cache VMEM-resident across the generate scan, which a
-    # per-call kernel cannot.  The einsum also GSPMD-partitions for every
-    # sharded layout a kernel would need bespoke rules for.
+    # No custom kernel: decode attention is bandwidth-bound and XLA's einsum
+    # reaches the roof (tools/artifacts/decode_r5.json: 21 of 22 cells), and
+    # it partitions under GSPMD for every sharded layout.
     qg = q.reshape(B, S, Hkv, G, hd)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, ck).astype(jnp.float32)
     scores = scores * _sm_scale(cfg, hd)
@@ -1464,36 +1430,21 @@ def _attention_cached(cfg, q, ck, cv, q_pos, q_slot, valid, kpos, window=None):
     return out.reshape(B, S, Hq, hd)
 
 
-def _block_cached(cfg, lp, x, ck, cv, q_pos, q_slot, valid, kpos, next_slot,
-                  rng, window=None):
-    """One transformer block with cache read/write.  ck/cv are this layer's
-    [B,T,Hkv,hd] buffers; returns (x, updated ck, cv)."""
-    h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
-    h = _maybe_act_quant(cfg, h)
-    q, k, v = _qkv(cfg, lp, h, q_pos)
-    with jax.named_scope("kv_write"):
-        ck = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype),
-                                          (0, next_slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype),
-                                          (0, next_slot, 0, 0))
-        ck = constrain_spec(ck, P(BATCH_AXES, None, "model", None))
-        cv = constrain_spec(cv, P(BATCH_AXES, None, "model", None))
-    with jax.named_scope("attn"):
-        attn = _attention_cached(cfg, q, ck, cv, q_pos, q_slot, valid, kpos,
-                                 window=window)
-    attn = _attn_out(cfg, lp, attn)
-
-    if cfg.parallel_residual:
-        h2 = h if cfg.shared_layernorm else _maybe_act_quant(cfg, _norm(
-            cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
-        m = _mlp(cfg, lp, h2, rng, deterministic=True)[0]
-        return x + attn + m, ck, cv
-
-    x = x + attn
-    h = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
-    h = _maybe_act_quant(cfg, h)
-    m = _mlp(cfg, lp, h, rng, deterministic=True)[0]
-    return x + m, ck, cv
+def _attend_cached(cfg, ck, cv, q_pos, q_slot, valid, kpos, next_slot,
+                   window=None):
+    """:func:`_block`'s ``attend`` against a contiguous cache: the layer's
+    K/V land in its ``[B,T,Hkv,hd]`` buffers ``ck``/``cv`` at ``next_slot``,
+    the queries read the whole buffers, and the buffers are what is kept."""
+    def attend(q, k, v):
+        spec, at = P(BATCH_AXES, None, "model", None), (0, next_slot, 0, 0)
+        with jax.named_scope("kv_write"):
+            nk = jax.lax.dynamic_update_slice(ck, k.astype(ck.dtype), at)
+            nv = jax.lax.dynamic_update_slice(cv, v.astype(cv.dtype), at)
+            nk, nv = constrain_spec(nk, spec), constrain_spec(nv, spec)
+        with jax.named_scope("attn"):
+            return _attention_cached(cfg, q, nk, nv, q_pos, q_slot, valid,
+                                     kpos, window=window), (nk, nv)
+    return attend
 
 
 def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
@@ -1510,11 +1461,7 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
     ONE function under two static shapes, so a whole generation run compiles
     exactly twice.
     """
-    assert cfg.pipeline_stages == 1, "cached decode requires pipeline_stages=1"
-    if isinstance(params["layers"], (list, tuple)):
-        raise NotImplementedError(
-            "cached decode with a PR-MoE pyramid (per-layer num_experts) is "
-            "not supported: the KV cache scan needs uniform layer stacks")
+    _check_decodable(cfg, params, "cached decode")
     B, S = tokens.shape
     next_slot = cache["next_slot"]
 
@@ -1523,46 +1470,22 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
                                         (0, next_slot))
     q_slot = next_slot + jnp.arange(S, dtype=jnp.int32)
 
-    if not cfg.causal:
-        raise NotImplementedError(
-            "cached decode is a causal-LM operation; encoder models "
-            "(causal=False) have no autoregressive cache")
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
-        if cfg.position == "learned":
-            x = x + params["pos_embed"].astype(cfg.dtype)[positions]
-        if cfg.embed_layernorm:      # Bloom embedding LayerNorm
-            x = _norm(cfg, x, params["embed_norm_scale"],
-                      params.get("embed_norm_bias"))
-    x = constrain_spec(x, P(BATCH_AXES, None, None))
-
+    x = constrain_spec(_embed(cfg, params, tokens, positions),
+                       P(BATCH_AXES, None, None))
     rng = jax.random.PRNGKey(0)
 
-    windows = layer_windows(cfg)
-    if windows is None:
-        def body(x, layer):
-            lp, ck, cv = layer
-            x, ck, cv = _block_cached(cfg, lp, x, ck, cv, positions, q_slot,
-                                      valid, kpos, next_slot, rng)
-            x = constrain_spec(x, P(BATCH_AXES, None, None))
-            return x, (ck, cv)
+    # the cache rides the scan as xs/ys beside the layer stack, and so does
+    # a per-layer local window where the model has them (GPT-Neo)
+    def body(x, layer):
+        lp, ck, cv, w = layer
+        x, _, _, kv = _block(cfg, lp, x, positions, rng, _attend_cached(
+            cfg, ck, cv, positions, q_slot, valid, kpos, next_slot, w))
+        return constrain_spec(x, P(BATCH_AXES, None, None)), kv
 
-        x, (ck_all, cv_all) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
-    else:
-        # per-layer local window rides the scan (GPT-Neo alternation)
-        def body(x, layer):
-            lp, ck, cv, w = layer
-            x, ck, cv = _block_cached(cfg, lp, x, ck, cv, positions, q_slot,
-                                      valid, kpos, next_slot, rng, window=w)
-            x = constrain_spec(x, P(BATCH_AXES, None, None))
-            return x, (ck, cv)
-
-        x, (ck_all, cv_all) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"], windows))
-
-    x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    logits = _lm_head(cfg, params, x)
+    x, (ck_all, cv_all) = jax.lax.scan(
+        body, x, (params["layers"], cache["k"], cache["v"],
+                  layer_windows(cfg)))
+    logits = _head(cfg, params, x)
     new_cache = {"k": ck_all, "v": cv_all, "valid": valid, "pos": kpos,
                  "next_slot": next_slot + S}
     return logits, new_cache
@@ -1642,7 +1565,7 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     any other slot whose prompt starts with the same tokens.  Sharing is
     pure page-table indirection: no program here changes shape for it.  The
     one mutable case — a *partial* boundary page the owner is still
-    appending to — is shared by value instead: :func:`cow_copy_page`
+    appending to — is shared by value instead: :func:`cow_copy_pool`
     snapshots it into the reader's own page (copy-on-write).
 
     ``kv_dtype="int8"`` allocates the pools in int8 plus per-page scale
@@ -1676,10 +1599,12 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
     return {"k": kv, "v": kv, "k_scale": sc, "v_scale": sc}
 
 
-def cow_copy_page(k: jax.Array, v: jax.Array, src: jax.Array,
-                  dst: jax.Array):
+def cow_copy_pool(pools, src: jax.Array, dst: jax.Array):
     """Copy-on-write primitive: snapshot physical page ``src`` onto ``dst``
-    across every layer of the ``[L, P, page, Hkv, hd]`` pools.
+    across every layer of every array of the canonical pool tuple (k/v
+    ``[L, P, page, Hkv, hd]``, plus the ``[L, P, page]`` scale planes of a
+    quantized pool) — raw bytes, so an int8 page's snapshot never
+    round-trips through float.
 
     Used when a new request's prompt extends partway into a donor's
     *partial* boundary page: the donor keeps appending to its own page, so
@@ -1687,21 +1612,9 @@ def cow_copy_page(k: jax.Array, v: jax.Array, src: jax.Array,
     every row past the matched prefix itself before its query positions can
     reach them (slot-index == position, so a row is causally invisible
     until the sharer has written it).  ``src``/``dst`` are traced int32
-    scalars — ONE fixed program shape regardless of which pages move, so
-    the zero-recompile serving contract is untouched.  ``dst == src`` (or
-    the trash page 0 onto itself, used to pre-warm the compile) is a
-    harmless self-copy.
-    """
-    return k.at[:, dst].set(k[:, src]), v.at[:, dst].set(v[:, src])
-
-
-def cow_copy_pool(pools, src: jax.Array, dst: jax.Array):
-    """:func:`cow_copy_page` generalized over the canonical pool tuple
-    (k/v, plus the ``[L, P, page]`` scale planes of a quantized pool):
-    every array copies its page-axis slice ``src`` onto ``dst`` — raw
-    bytes, so an int8 page's COW snapshot never round-trips through
-    float (the sharer's copy dequantizes bit-identically to the donor's).
-    """
+    scalars — ONE fixed program shape regardless of which pages move.
+    ``dst == src`` (the trash page 0 onto itself pre-warms the compile) is
+    a harmless self-copy."""
     return tuple(a.at[:, dst].set(a[:, src]) for a in pools)
 
 
@@ -1814,9 +1727,8 @@ def _attention_paged(cfg, q, pools, gather_pages, q_pos, steps,
     idle slots) may sit past the bound; their output is garbage either way.
 
     Each step runs the same einsum structure as :func:`_attention_cached`
-    — GQA contracts grouped heads against the Hkv cache directly, and
-    decode stays on the XLA path (the Pallas decode kernel was retired in
-    round 5 on an honest A/B).  The products keep the pages' own axes as
+    (GQA contracts grouped heads against the Hkv cache directly, no
+    kernel).  The products keep the pages' own axes as
     the device stores them (:func:`_pool_views`) and only the scores are
     flattened: merging or moving axes of K/V would re-lay the gathered
     block out, which the compiler then does to the whole pool (PERF.md,
@@ -1896,104 +1808,81 @@ def _adapter_delta(h, ab, scale):
     return d * scale.astype(jnp.float32)[:, None, None]
 
 
-def _block_paged(cfg, lp, x, pools, positions, write, gather_pages,
-                 read_steps, rng, adapters=None, ad_scale=None, seq_mask=None,
-                 expert_offset=None, pool_order=None):
-    """One transformer block against the paged pool, addressed a whole
-    page at a time.  ``pools`` maps each pool leaf (``k``/``v``, plus
-    ``k_scale``/``v_scale`` on a quantized pool) to its array with the
-    page axis leading: ``[N, page, Hkv, hd]`` (scales ``[N, page]``) — the
-    stacked pool with ``N = L*P`` from :func:`forward_paged`.  Returns
-    ``(x, pools, counts)``, ``counts`` a dropless expert layer's rows per
-    expert (``None`` for any other MLP).
-
-    Write: ``write = (src, keep, pages)`` is the block's page-merge plan
-    (:func:`forward_paged` builds it once for all layers): ``pages [B,
-    n_pg]`` the physical pages this block's positions fall in (the trash
-    page where none of a page's rows is written), ``src [B, n_pg*page]``
-    the token of the block each page row takes, ``keep [B, n_pg, page]``
-    whether it takes one.  The pages are gathered, merged and scattered
-    back whole.  Read: ``gather_pages [B, maxp]`` are each slot's pages, of
-    which :func:`_attention_paged` gathers the first ``read_steps`` steps
-    of ``PAGED_READ_GRANULE`` whole pages: up to the longest live position
-    of the call (:func:`_paged_read_steps`), past which no row can pass any
-    real query's causal mask.  ``pool_order`` is how the device stores a
-    K/V leaf (:func:`_pool_views`).  Every pool op thus slices all trailing
-    axes, so it runs in whatever layout the pool is stored in and the pool
-    stays in place; a row-granular scatter or gather makes the TPU compiler
-    re-lay the whole pool out around the layer scan (PERF.md, PR 25).
-
-    A quantized pool quantizes each written row on store (symmetric
-    absmax, :func:`kv_quantize_rows`), merges its scale through the SAME
-    plan, and dequantizes inside the gather — the scale planes are two
-    more leaves of ``pools``, so the program shapes (and the
-    zero-recompile inventory built on them) are unchanged.
-
-    ``adapters``/``ad_scale`` (both or neither) are this layer's per-slot
-    LoRA factor slices ``{target: {"A": [B,d_in,R], "B": [B,R,d_out]}}``
-    and the ``[B]`` per-slot scales (multi-tenant adapter serving,
-    docs/SERVING.md): each projection named in the dict gains its slot's
-    batched delta.  All-zero factors reproduce the base projection
-    exactly, so one traced program serves any tenant mix.
-
-    ``seq_mask [B,S]`` (True = a real token) keeps padding and idle slots
-    out of a dropless expert layer's groups; ``expert_offset`` says where
-    this layer's experts start in expert leaves that hold the whole stack
-    (:func:`forward_paged`)."""
-    B, S, _ = x.shape
-    hd, nh, nkv = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads
+def _adapter_proj(adapters, ad_scale):
+    """:func:`_block`'s ``proj`` for multi-tenant adapter serving
+    (docs/SERVING.md): ``adapters`` are one layer's per-slot LoRA factor
+    slices ``{target: {"A": [B,d_in,R], "B": [B,R,d_out]}}`` and ``ad_scale``
+    the ``[B]`` per-slot scales; each projection named in the dict gains its
+    slot's batched delta.  All-zero factors reproduce the base projection
+    exactly, so one traced program serves any tenant mix.  None without
+    adapters."""
+    if adapters is None:
+        return None
 
     def proj(y, name, hin):
-        if adapters is not None and name in adapters:
+        if name in adapters:
             y = y + _adapter_delta(hin, adapters[name],
                                    ad_scale).astype(y.dtype)
         return y
+    return proj
 
-    h = _norm(cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
-    h = _maybe_act_quant(cfg, h)
-    q, k, v = _qkv(cfg, lp, h, positions, proj)
-    with jax.named_scope("kv_write"):
-        src, keep, pages = write
 
-        def merge(pool, rows):
-            # rows [B,S,...] -> the rows of their pages [B,n_pg,page,...],
-            # laid over what those pages hold
-            w = (1,) * (rows.ndim - 2)
-            new = jnp.take_along_axis(rows, src.reshape(*src.shape, *w),
-                                      axis=1).astype(pool.dtype)
-            new = jnp.where(keep.reshape(*keep.shape, *w),
-                            new.reshape(*keep.shape, *rows.shape[2:]),
-                            pool[pages])
-            return pool.at[pages.reshape(-1)].set(
-                new.reshape(-1, *new.shape[2:]))
+def _attend_paged(cfg, pools, write, gather_pages, q_pos, read_steps,
+                  pool_order=None):
+    """:func:`_block`'s ``attend`` against the paged pool, addressed a whole
+    page at a time; the pool is what is kept.  ``pools`` maps each pool leaf
+    (``k``/``v``, plus ``k_scale``/``v_scale`` on a quantized pool) to its
+    array with the page axis leading: ``[N, page, Hkv, hd]`` (scales ``[N,
+    page]``) — the stacked pool with ``N = L*P`` from :func:`forward_paged`.
 
-        rows = {"k": k, "v": v}
-        if "k_scale" in pools:
-            # quantize on store: int8 rows + per-row scales, one plan
-            for name, r in (("k", k), ("v", v)):
-                q8, sc = kv_quantize_rows(r.reshape(B * S, nkv, hd))
-                rows[name] = q8.reshape(B, S, nkv, hd)
-                rows[name + "_scale"] = sc.reshape(B, S)
-        pools = {name: merge(pool, rows[name])
-                 for name, pool in pools.items()}
-        for name in ("k", "v"):
-            pools[name] = constrain_spec(pools[name],
-                                         P(None, None, "model", None))
-    with jax.named_scope("attn"):
-        attn = _attention_paged(cfg, q, pools, gather_pages, positions,
-                                read_steps, pool_order)
-    attn = _attn_out(cfg, lp, attn, proj)
+    Write: ``write = (src, keep, pages)`` is the block's page-merge plan
+    (:func:`_paged_write_plan`, one for all layers, ``pages`` moved to this
+    layer's): the pages are gathered, merged and scattered back whole.
+    Read: ``gather_pages [B, maxp]`` are each slot's pages, of which
+    :func:`_attention_paged` gathers the first ``read_steps`` steps of
+    ``PAGED_READ_GRANULE`` whole pages.  Every pool op thus slices all
+    trailing axes, so it runs in whatever layout the pool is stored in
+    (``pool_order``, :func:`_pool_views`) and the pool stays in place; a
+    row-granular scatter or gather makes the TPU compiler re-lay the whole
+    pool out around the layer scan (PERF.md, PR 25).
 
-    # GPT-J/NeoX: the MLP branches off x beside attention (GPT-J shares the
-    # attention LN); otherwise it follows the attention residual
-    res = x + attn
-    h2 = (h if cfg.parallel_residual and cfg.shared_layernorm else
-          _maybe_act_quant(cfg, _norm(
-              cfg, x if cfg.parallel_residual else res,
-              lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))))
-    m, _, counts = _mlp(cfg, lp, h2, rng, deterministic=True,
-                        token_mask=seq_mask, expert_offset=expert_offset)
-    return res + m, pools, counts
+    A quantized pool quantizes each written row on store (symmetric absmax,
+    :func:`kv_quantize_rows`), merges its scale through the SAME plan, and
+    dequantizes inside the gather — the scale planes are two more leaves of
+    ``pools``, so the program shapes (and the zero-recompile inventory built
+    on them) are unchanged."""
+    src, keep, pages = write
+
+    def merge(pool, rows):
+        # rows [B,S,...] -> the rows of their pages [B,n_pg,page,...], laid
+        # over what those pages hold
+        w = (1,) * (rows.ndim - 2)
+        new = jnp.take_along_axis(rows, src.reshape(*src.shape, *w),
+                                  axis=1).astype(pool.dtype)
+        new = jnp.where(keep.reshape(*keep.shape, *w),
+                        new.reshape(*keep.shape, *rows.shape[2:]),
+                        pool[pages])
+        return pool.at[pages.reshape(-1)].set(new.reshape(-1, *new.shape[2:]))
+
+    def attend(q, k, v):
+        B, S, nkv, hd = k.shape
+        with jax.named_scope("kv_write"):
+            rows = {"k": k, "v": v}
+            if "k_scale" in pools:
+                # quantize on store: int8 rows + per-row scales, one plan
+                for name, r in (("k", k), ("v", v)):
+                    q8, sc = kv_quantize_rows(r.reshape(B * S, nkv, hd))
+                    rows[name] = q8.reshape(B, S, nkv, hd)
+                    rows[name + "_scale"] = sc.reshape(B, S)
+            new = {name: merge(pool, rows[name])
+                   for name, pool in pools.items()}
+            for name in ("k", "v"):
+                new[name] = constrain_spec(new[name],
+                                           P(None, None, "model", None))
+        with jax.named_scope("attn"):
+            return _attention_paged(cfg, q, new, gather_pages, q_pos,
+                                    read_steps, pool_order), new
+    return attend
 
 
 def _paged_write_plan(page_table, start, seq_mask, ps: int):
@@ -2058,54 +1947,34 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     The pool stays in place for the whole program: its leaves ride the
     layer scan as carry, stacked ``[L*P, page, ...]``, and each layer
     gathers and scatters whole pages of the stack at ``l*P + page``
-    (:func:`_block_paged`) — no layer's slice of the pool is ever cut out,
-    re-laid out or written back, so the donated buffers are updated where
-    they lie.
+    (:func:`_attend_paged`), so the donated buffers are updated where they
+    lie.  A quantized cache (``init_paged_cache(kv_dtype="int8")``) is two
+    more leaves of that carry and the same program shapes.
 
     What is read: every slot's pages up to the longest live position of the
-    call, ``max(start + s) + 1`` rows over the real tokens (decode: the
-    longest active slot's length + 1; prefill: ``start + n_real``; verify-k:
-    the longest ``start + k + 1``), rounded up to ``PAGED_READ_GRANULE``
-    pages — not the ``maxp`` pages of the page-table row.  The bound is
-    computed here, on the device, from ``start`` and ``seq_mask``
-    (:func:`_paged_read_steps`), so one compiled program serves every
-    length and a tick launched ahead on ``lengths + k`` reads what it needs.
-    A row past the bound has ``t > q_pos`` for every real query, weight
-    exactly 0 in the softmax it was left out of.  ``pool_order``
+    call (decode: the longest active slot's length + 1; prefill: ``start +
+    n_real``; verify-k: the longest ``start + k + 1``), not the ``maxp``
+    pages of the page-table row.  The bound is computed here, on the device,
+    from ``start`` and ``seq_mask`` (:func:`_paged_read_steps`), so one
+    compiled program serves every length and a tick launched ahead on
+    ``lengths + k`` reads what it needs.  ``pool_order``
     (:func:`paged_pool_order` of the arrays the caller holds; optional, a
     matter of speed only) is the order in which the device stores a K/V
-    leaf's axes, where that is not row-major: the read hands its loop the
-    pool in that order, or the TPU compiler re-lays the pool out before
-    every read (:func:`_pool_views`).
+    leaf's axes, where that is not row-major (:func:`_pool_views`).
 
     ``expert_counts=True`` adds a third result: the rows each expert of
     each layer computed, ``[L, E]`` int32, for a model whose expert layers
     are dropless (masked tokens are in no group and in no count); ``None``
     for every other model.
 
-    A quantized cache (``init_paged_cache(kv_dtype="int8")`` — extra
-    ``k_scale``/``v_scale`` planes) runs the same three program shapes:
-    writes quantize on store, the gather dequantizes, and the scale planes
-    ride the carry as two more pool leaves (docs/SERVING.md "Quantized KV
-    pages").
-
     ``adapters`` (optional) is the per-slot LoRA operand pytree of
     multi-tenant adapter serving (docs/SERVING.md): ``{"scale": [B] f32,
     "factors": {target: {"A": [L,B,d_in,R], "B": [L,B,R,d_out]}}}``.  The
-    factor stacks ride the layer scan as one extra xs element, so the
-    program count is unchanged and all-zero factors reproduce the
-    adapter-free forward exactly.  ``None`` keeps today's trace
-    byte-identical (no adapter operands at all).
+    factor stacks ride the layer scan beside the layers, so the program
+    count is unchanged (:func:`_adapter_proj`); ``None`` traces no adapter
+    operand at all.
     """
-    assert cfg.pipeline_stages == 1, "paged decode requires pipeline_stages=1"
-    if not cfg.causal:
-        raise NotImplementedError(
-            "paged decode is a causal-LM operation; encoder models "
-            "(causal=False) have no autoregressive cache")
-    if isinstance(params["layers"], (list, tuple)):
-        raise NotImplementedError(
-            "paged decode with a PR-MoE pyramid (per-layer num_experts) is "
-            "not supported: the layer scan needs uniform stacks")
+    _check_decodable(cfg, params, "paged decode")
     if cfg.attention_layers is not None:
         raise NotImplementedError(
             "paged decode does not support per-layer attention windows "
@@ -2118,14 +1987,9 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     read_steps = _paged_read_steps(positions, seq_mask, ps,
                                    page_table.shape[1])
 
-    with jax.named_scope("embed"):
-        x = params["embed"].astype(cfg.dtype)[tokens]
-        if cfg.position == "learned":
-            safe_pos = jnp.minimum(positions, cfg.max_seq_len - 1)
-            x = x + params["pos_embed"].astype(cfg.dtype)[safe_pos]
-        if cfg.embed_layernorm:      # Bloom embedding LayerNorm
-            x = _norm(cfg, x, params["embed_norm_scale"],
-                      params.get("embed_norm_bias"))
+    # a slot may run to positions past the learned table's end
+    x = _embed(cfg, params, tokens,
+               jnp.minimum(positions, cfg.max_seq_len - 1))
     x = constrain_spec(x, P(BATCH_AXES, None, None))
 
     rng = jax.random.PRNGKey(0)
@@ -2134,10 +1998,9 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
 
     # The pool rides the layer scan as CARRY, stacked: [L*P, page, ...]
     # merges its two major axes (free in any layout), and layer l's page p
-    # is page l*P + p.  Nothing may materialise one layer's slice: scanned
-    # as xs/ys, the pool was sliced, re-laid out and written back a layer
-    # at a time and copied whole around the loop, more than half of a
-    # decode tick (PERF.md, PR 25).
+    # is page l*P + p.  Nothing may materialise one layer's slice: as the
+    # scan's xs/ys the pool is sliced, re-laid out, written back and copied
+    # whole around the loop, more than half of a decode tick (PERF.md, PR 25)
     pools = {k: cache[k].reshape(-1, *cache[k].shape[2:])
              for k in PAGED_POOL_KEYS if k in cache}
     # So do the expert stacks of a dropless model, for the same reason
@@ -2152,28 +2015,24 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
 
     def body(carry, layer):
         x, pools = carry
-        lp, first_page = layer[:2]
-        x, pools, counts = _block_paged(
-            cfg, {**lp, **experts}, x, pools, positions,
-            (src, keep, write_pages + first_page), page_table + first_page,
-            read_steps, rng,
-            adapters=layer[2] if adapters is not None else None,
-            ad_scale=ad_scale, seq_mask=seq_mask,
+        lp, first_page, factors = layer
+        x, _, counts, pools = _block(
+            cfg, {**lp, **experts}, x, positions, rng,
+            _attend_paged(cfg, pools, (src, keep, write_pages + first_page),
+                          page_table + first_page, positions, read_steps,
+                          pool_order),
+            proj=_adapter_proj(factors, ad_scale), token_mask=seq_mask,
             expert_offset=(first_page // num_pages * cfg.num_experts
-                           if experts else None),
-            pool_order=pool_order)
+                           if experts else None))
         x = constrain_spec(x, P(BATCH_AXES, None, None))
         return (x, pools), counts
 
-    xs = (layers, jnp.arange(num_layers, dtype=jnp.int32) * num_pages)
-    if adapters is not None:
-        # per-slot factor stacks scan with the layers: each step's slice is
-        # {target: {"A": [B,d_in,R], "B": [B,R,d_out]}} for THAT layer
-        xs += (adapters["factors"],)
-    (x, pools), counts = jax.lax.scan(body, (x, pools), xs)
-
-    x = _norm(cfg, x, params["final_norm_scale"], params.get("final_norm_bias"))
-    logits = _lm_head(cfg, params, x)
+    # the per-slot factor stacks scan beside the layers where the call has
+    # them: each step's slice is THAT layer's
+    (x, pools), counts = jax.lax.scan(body, (x, pools), (
+        layers, jnp.arange(num_layers, dtype=jnp.int32) * num_pages,
+        None if adapters is None else adapters["factors"]))
+    logits = _head(cfg, params, x)
     cache = {k: a.reshape(cache[k].shape) for k, a in pools.items()}
     return (logits, cache, counts) if expert_counts else (logits, cache)
 

@@ -30,10 +30,9 @@ Opt-in surfaces:
   default 16): the first train/serving engine init arms one capture of N
   units into ``<dir>`` — zero code changes to profile a production run's
   first N steps/ticks.
-- ``capture_device_trace(log_dir, n_units=...)`` — the API
-  ``serve_bench --device_trace`` / ``bench.py --device_trace`` use to
-  window a capture around an extra measured pass (the reported pass stays
-  untraced, same discipline as ``--trace``).
+- ``capture_device_trace(log_dir, n_units=...)`` — the API that windows a
+  capture around an extra measured pass (``benchmark/run.py --trace 1``:
+  the reported pass stays untraced).
 
 View with TensorBoard: ``tensorboard --logdir <dir>`` → Profile tab
 (docs/OBSERVABILITY.md "Device-time correlation").  Every failure path

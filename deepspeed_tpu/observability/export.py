@@ -6,8 +6,8 @@ Two render targets for the same recorded data:
   Format (``chrome://tracing`` / https://ui.perfetto.dev): each completed
   span becomes one ``"ph": "X"`` complete event (µs timestamps on the
   process monotonic clock), each counter a ``"ph": "C"`` event, plus ``M``
-  metadata naming threads.  ``tools/serve_bench.py --trace out.json`` and
-  ``tools/chaos_soak.py --trace out.json`` emit this for a measured run.
+  metadata naming threads.  ``tools/chaos_soak.py --trace out.json`` emits
+  this for a measured run.
 - :func:`prometheus_text` — the Prometheus exposition format: the latest
   value of every monitor gauge (anything with an ``events`` stream of
   ``(name, value, step)``, e.g. :class:`~..monitor.InMemoryMonitor`) plus

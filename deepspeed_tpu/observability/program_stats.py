@@ -51,10 +51,9 @@ PEAK_TFLOPS_ENV = "DS_TPU_PEAK_TFLOPS"
 
 def peak_flops_per_sec() -> Optional[float]:
     """The chip's peak flops/s for MFU denominators, or ``None`` when
-    unknown.  Honest by construction: there is no baked-in spec-sheet
-    table (bench.py measures the real matmul roof and found the v5e spec
-    number unachievable) — the operator states the roof they trust via
-    ``DS_TPU_PEAK_TFLOPS`` (e.g. the bench's measured value)."""
+    unknown.  There is no baked-in spec-sheet table here (the benchmark
+    keeps the published peaks, ``benchmark/lib/peaks.json``): the operator
+    states the roof they trust via ``DS_TPU_PEAK_TFLOPS``."""
     raw = os.environ.get(PEAK_TFLOPS_ENV, "").strip()
     if not raw:
         return None

@@ -106,8 +106,7 @@ class TraceSegmentPublisher:
         self.published_total = 0
         self.dropped_total = 0
         self.publishes_total = 0
-        # per-publish store CAS wall time (bounded window): what
-        # serve_bench --collect_traces reports p50/p99 over
+        # per-publish store CAS wall time (bounded window)
         self._cas_lat_s: deque = deque(maxlen=2048)
 
     def pending(self, tracer: Optional[Tracer] = None) -> List[Span]:

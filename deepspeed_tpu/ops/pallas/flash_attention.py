@@ -36,10 +36,10 @@ from jax.experimental.pallas import tpu as pltpu
 import os as _os
 
 def _env_block(name: str, default: int) -> int:
-    """Tile override via env (read at import — trace-time semantics like
-    DS_TPU_FLASH_DECODE): lets tools/tune_flash.py A/B tile choices in the
-    FULL remat train step via subprocess env, the only measurement that
-    predicts end-to-end cost (see note above: isolated sweeps mislead)."""
+    """Tile override via env (read at import, so it binds when a program
+    traces): lets a run A/B tile choices in the FULL remat train step, the
+    only measurement that predicts end-to-end cost (see note above:
+    isolated sweeps mislead)."""
     v = _os.environ.get(name, "").strip()
     if not v:
         return default

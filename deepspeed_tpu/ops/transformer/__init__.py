@@ -22,7 +22,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ...models.transformer import (TransformerConfig, _block)
+from ...models.transformer import (TransformerConfig, _attend_full,
+                                   _block)
 
 __all__ = ["DeepSpeedTransformerConfig", "DeepSpeedTransformerLayer"]
 
@@ -153,9 +154,9 @@ class DeepSpeedTransformerLayer:
             jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
         det = (not self.config.training if deterministic is None
                else deterministic)
-        out, _aux = _block(
+        out = _block(
             self.native, params, hidden_states.astype(self.native.dtype),
             positions, rng if rng is not None else jax.random.PRNGKey(
                 max(self.config.seed, 0)),
-            attn_impl="auto", deterministic=det)
+            _attend_full(self.native, positions, "auto"), det)[0]
         return (out,) if self.config.return_tuple else out

@@ -266,7 +266,8 @@ def get_detailed_profile(model, batch_size: int = 1, seq_len: int = 128,
     mlp_f, mlp_b = _flops_bytes(
         lambda lp, h: T._mlp(cfg, lp, h, rng, True)[0], lp0, x)
     blk_f, blk_b = _flops_bytes(
-        lambda lp, h: T._block(cfg, lp, h, positions, rng, "xla", True)[0],
+        lambda lp, h: T._block(cfg, lp, h, positions, rng,
+                               T._attend_full(cfg, positions))[0],
         lp0, x)
     proj_f = max(blk_f - attn_f - mlp_f, 0.0)
     proj_b = max(blk_b - attn_b - mlp_b, 0.0)

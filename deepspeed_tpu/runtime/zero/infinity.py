@@ -353,7 +353,8 @@ class InfinityParamEngine:
     # shape-identical across layers so XLA reuses one executable).
     # ------------------------------------------------------------------
     def _build_programs(self):
-        from ...models.transformer import (_block, _norm, cross_entropy_loss)
+        from ...models.transformer import (_attend_full, _block, _embed,
+                                           _head, cross_entropy_loss)
 
         cfg = self.cfg
         attn_impl = self.attn_impl
@@ -370,14 +371,8 @@ class InfinityParamEngine:
                 jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
 
         def stem_body(stem, tokens):
-            x = stem["embed"].astype(cfg.dtype)[tokens]
-            if "pos_embed" in stem:
-                x = x + stem["pos_embed"].astype(cfg.dtype)[
-                    positions_of(tokens)]
-            if "embed_norm_scale" in stem:   # Bloom embedding LayerNorm
-                x = _norm(cfg, x, stem["embed_norm_scale"],
-                          stem.get("embed_norm_bias"))
-            return constrain_spec(x, act_spec)
+            return constrain_spec(
+                _embed(cfg, stem, tokens, positions_of(tokens)), act_spec)
 
         moe = self._moe
         # single source: the SAME value is jit-baked into layer_bwd's aux
@@ -389,26 +384,18 @@ class InfinityParamEngine:
             B, S, _ = x.shape
             pos = jnp.broadcast_to(
                 jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
-            y, aux = _block(cfg, lp, x, pos, rng, attn_impl,
-                            deterministic=deterministic)
+            y, aux = _block(cfg, lp, x, pos, rng,
+                            _attend_full(cfg, pos, attn_impl),
+                            deterministic)[:2]
             y = constrain_spec(y, act_spec)
             # MoE: the load-balancing aux is part of the loss, so it must be
             # a layer OUTPUT for the vjp to route router gradients
             return (y, aux) if moe else y
 
         def head_body(head, stem, x, labels):
-            if "final_norm_scale" in head:
-                xn = _norm(cfg, x, head["final_norm_scale"],
-                           head.get("final_norm_bias"))
-            else:                            # final_norm=False configs
-                xn = x
-            if tied:
-                logits = xn @ stem["embed"].astype(cfg.dtype).T
-            else:
-                logits = xn @ head["lm_head"].astype(cfg.dtype)
-                if "lm_head_bias" in head:
-                    logits = logits + head["lm_head_bias"].astype(cfg.dtype)
-            return cross_entropy_loss(logits, labels)
+            # a tied head reads the stem's embedding: its gradient lands there
+            hp = {**head, "embed": stem["embed"]} if tied else head
+            return cross_entropy_loss(_head(cfg, hp, x), labels)
 
         self._stem_fwd = jax.jit(stem_body)
         self._layer_fwd = jax.jit(layer_body)

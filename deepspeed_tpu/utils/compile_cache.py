@@ -2,7 +2,8 @@
 
 A program that compiles for minutes should pay that once per machine, not
 once per process.  The programs call :func:`place_compile_cache` before
-their first compile (``chip_smoke.py``, ``bench.py``, ``examples/*.py``);
+their first compile (``chip_smoke.py``, ``benchmark/run.py``,
+``examples/*.py``);
 nothing calls it at import, and tests never do.
 
 The directory is part of the cache key's world: a cache that moves never

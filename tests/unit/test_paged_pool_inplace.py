@@ -2,7 +2,7 @@
 (ISSUE 25).
 
 (a) Equivalence, twice.  Against a plain Python loop over layers that
-slices one layer's pool out of the stack, runs ``_block_paged`` on that
+slices one layer's pool out of the stack, runs the paged block on that
 private slice and stacks the slices back — what scanning the pool as
 ``xs``/``ys`` computed — logits and every pool leaf must be ``array_equal``.
 Against a row-granular block written here (every token's row scattered at
@@ -41,7 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models import get_config, init_params
-from deepspeed_tpu.models.transformer import (_attn_out, _block_paged,
+from deepspeed_tpu.models.transformer import (_adapter_proj, _attend_paged,
+                                              _attn_out, _block,
                                               _lm_head, _mlp, _norm,
                                               PAGED_READ_GRANULE,
                                               _paged_read_steps,
@@ -177,10 +178,12 @@ def _forward_sliced(params, tokens, cache, page_table, start, seq_mask,
                               page_table.shape[1])
 
     def block(lp, x, pools, ad):
-        return _block_paged(
-            CFG, lp, x, pools, positions, write, page_table, steps,
-            jax.random.PRNGKey(0), adapters=ad,
-            ad_scale=None if adapters is None else adapters["scale"])[:2]
+        out = _block(
+            CFG, lp, x, positions, jax.random.PRNGKey(0),
+            _attend_paged(CFG, pools, write, page_table, positions, steps),
+            proj=_adapter_proj(
+                ad, None if adapters is None else adapters["scale"]))
+        return out[0], out[3]
 
     return _forward_layers(block, params, tokens, cache, adapters)
 

@@ -30,15 +30,16 @@ def test_layer_forward_shapes(pre_ln):
 def test_layer_matches_bert_block():
     """post-LN mode must be exactly the native encoder block the BERT
     injection path trains (one implementation, two surfaces)."""
-    from deepspeed_tpu.models.transformer import _block
+    from deepspeed_tpu.models.transformer import _attend_full, _block
 
     layer = DeepSpeedTransformerLayer(_cfg(pre_layer_norm=False))
     params = layer.init(jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 32))
     out = layer.apply(params, x)
     pos = jnp.broadcast_to(jnp.arange(8, dtype=jnp.int32)[None], (2, 8))
-    ref, _ = _block(layer.native, params, x.astype(layer.native.dtype), pos,
-                    jax.random.PRNGKey(0), "auto", deterministic=True)
+    ref = _block(layer.native, params, x.astype(layer.native.dtype), pos,
+                 jax.random.PRNGKey(0),
+                 _attend_full(layer.native, pos, "auto"))[0]
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
 

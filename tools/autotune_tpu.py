@@ -1,13 +1,11 @@
 """Real-chip autotuner session: the model-based/grid tuner against hardware.
 
-VERDICT r3 weak #6 noted the tuner had only ever seen synthetic grids and
-the virtual CPU mesh.  This driver runs a small but real space on the
-actual chip — llama-374m, ZeRO-1, micro-batch ladder x remat policy — and
-commits the records + best config as artifacts, exactly the files the
-reference's ``autotuning_results/`` layout produces (reference
+Runs a small but real space on the chip — llama-374m, ZeRO-1, micro-batch
+ladder x remat policy — and writes the records + best config, exactly the
+files the reference's ``autotuning_results/`` layout produces (reference
 ``autotuning/autotuner.py:404 tune()``).
 
-    python tools/autotune_tpu.py [--results_dir tools/artifacts/autotune_r4_tpu]
+    python tools/autotune_tpu.py [--results_dir autotuning_results]
 """
 from __future__ import annotations
 
@@ -27,8 +25,7 @@ def main():
     ap.add_argument("--model", default="llama-374m")
     ap.add_argument("--seq_len", type=int, default=2048)
     ap.add_argument("--results_dir",
-                    default=os.path.join(REPO, "tools", "artifacts",
-                                         "autotune_r4_tpu"))
+                    default="autotuning_results")
     ap.add_argument("--tuner_type", default="gridsearch",
                     choices=["gridsearch", "random", "model_based"])
     args = ap.parse_args()

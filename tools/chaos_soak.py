@@ -1335,8 +1335,7 @@ def run_pod_recover_compare(seed: int, root: str, total_steps: int = 12,
     checkpoint baseline).  ``run_pod_soak``'s schedule normalization is
     deliberately independent of ``replica_every_k``, so both legs kill
     the same victim at the same step; the adoption leg must roll back
-    STRICTLY fewer steps.  Returns the comparison dict shipped as
-    ``tools/artifacts/pod_recover_r22.json``."""
+    STRICTLY fewer steps.  Returns the comparison dict (``--json``)."""
     adopt = run_pod_soak(seed, total_steps=total_steps,
                          ckpt_every=ckpt_every,
                          ckpt_dir=os.path.join(root, "adopt", "ckpt"),
@@ -2500,8 +2499,7 @@ def main(argv=None) -> int:
                          "the SAME seeded kill schedule — replica "
                          "adoption vs checkpoint restart — and assert "
                          "adoption rolls back strictly fewer steps "
-                         "(stats dict -> tools/artifacts/"
-                         "pod_recover_r22.json via --json)")
+                         "(stats dict via --json)")
     ap.add_argument("--members", type=int, default=2,
                     help="fleet_procs mode: member daemon subprocesses "
                          "per soak")

@@ -9,9 +9,8 @@ Usage::
     python tools/dslint.py deepspeed_tpu/ --no-baseline   # full inventory
 
 Exit status: 0 when every finding is suppressed or baselined, 1 when
-NEW findings exist, 2 on usage errors.  The JSON artifact carries
-per-rule counts (``tools/artifacts/dslint_r*.json`` tracks the baseline
-burn-down trajectory across PRs).
+NEW findings exist, 2 on usage errors.  ``--json`` carries per-rule
+counts.
 
 Pure stdlib + AST — no jax import, so it runs anywhere the repo checks
 out (pre-push hooks, doc builds, CI shards without accelerators).
