@@ -125,7 +125,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from ..models.transformer import PAGE_SIZE, paged_read_pages
+from ..models.transformer import PAGE_SIZE, paged_read_rows
 from ..observability.device_profiler import (device_trace_unit,
                                              maybe_capture_from_env)
 from ..observability.program_stats import ProgramCatalog
@@ -1536,7 +1536,7 @@ class ServingEngine:
                         bucket=s_pad, tokens=S_tail,
                         shared_tokens=n_shared,
                         gathered_rows=self._gathered_rows(
-                            n_shared + S_tail, 1)) as sp:
+                            [n_shared + S_tail], 1)) as sp:
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 if match.cow_src is not None:
@@ -1667,14 +1667,15 @@ class ServingEngine:
                moe_experts_touched=int((counts > 0).sum()),
                moe_max_load=int(counts.max()))
 
-    def _gathered_rows(self, rows: int, slots: int) -> int:
-        """K/V rows a paged program reads a layer when the longest live
-        position of the call is ``rows - 1``: every one of its ``slots``
-        slots' pages up to that position's, in the read's whole steps
-        (``models.transformer.paged_read_pages``, the host's copy of the
-        bound the program computes from its own inputs)."""
-        return slots * self.page_size * paged_read_pages(
-            rows, self.page_size, self._page_table.shape[1])
+    def _gathered_rows(self, lengths, slots: int) -> int:
+        """K/V rows a paged program of ``slots`` slots reads a layer when
+        its live slots hold ``lengths`` rows, the rows being written
+        counted in: each slot's own pages, and none of an idle slot, in the
+        read's whole steps (``models.transformer.paged_read_rows``, the
+        host's copy of the list the program computes from its own
+        inputs)."""
+        return paged_read_rows(lengths, self.page_size,
+                               self._page_table.shape[1], slots)
 
     def _lookahead_depth(self) -> int:
         """How many ticks after this one can be launched now, at most
@@ -1768,12 +1769,12 @@ class ServingEngine:
                     # the launch has returned; what is left of the span is
                     # the wait for the device in the fetch below.  Rows the
                     # slots hold against rows the program's read covers
-                    # (every slot's pages up to the longest live slot's).
+                    # (each live slot's own pages, the row it writes too).
                     live = self._lengths[self._active]
                     sp.set(dispatch_ms=(time.monotonic() - t_open) * 1e3,
                            live_rows=int(live.sum()),
                            gathered_rows=self._gathered_rows(
-                               int(live.max(initial=-1)) + 1, self.b_slots))
+                               live + 1, self.b_slots))
                 # host fetch = device sync; an MoE model's expert counts
                 # come with the tokens
                 out = nxt

@@ -1649,33 +1649,68 @@ def kv_dequantize(q: jax.Array, scale: jax.Array, dtype):
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-# Pages a step of the paged read covers: the read stops at the longest live
-# position of the call rounded up to this many pages (PERF.md, PR 27).
+# Pairs a step of the paged read covers, for each slot of the program's
+# shape: a call of B slots reads B * PAGED_READ_GRANULE (slot, page) pairs of
+# its live pages a step, whichever slots they belong to (PERF.md, PR 29).
 PAGED_READ_GRANULE = 2
 
 
-def paged_read_pages(rows: int, page_size: int, max_pages: int) -> int:
-    """Pages of every slot that a paged forward reads when the longest live
-    position of the call is ``rows - 1``: ``rows`` rounded up to whole
-    steps of ``PAGED_READ_GRANULE`` pages, at least one step, at most the
-    page-table row.  The host's copy of what :func:`_paged_read_steps`
-    computes from ``start`` and ``seq_mask`` on the device (the serving
-    engine's ``gathered_rows`` span attr is this times ``page_size`` times
-    the slots of the call)."""
-    step = min(PAGED_READ_GRANULE, max_pages)
-    steps = max(-(-rows // (step * page_size)), 1)
-    return min(steps * step, max_pages)
+def paged_read_pairs(slots: int, max_pages: int) -> int:
+    """(slot, page) pairs one step of the paged read gathers in a program
+    of ``slots`` slots whose page-table rows hold ``max_pages`` pages:
+    static, a function of the program's shape alone."""
+    return slots * min(PAGED_READ_GRANULE, max_pages)
 
 
-def _paged_read_steps(positions, seq_mask, ps: int, maxp: int):
-    """The read's traced trip count: steps of ``PAGED_READ_GRANULE`` pages
-    up to the longest live position of the call, ``max(positions) + 1``
-    rows over the real tokens (``positions``, ``seq_mask``: ``[B,S]``) — at
-    least one step (a call with no real token reads what it then throws
-    away), at most the table."""
-    rows = jnp.max(jnp.where(seq_mask, positions, -1)) + 1
-    step = min(PAGED_READ_GRANULE, maxp) * ps
-    return jnp.clip((rows + step - 1) // step, 1, -(-maxp * ps // step))
+def paged_read_rows(lengths, page_size: int, max_pages: int,
+                    slots: int) -> int:
+    """K/V rows a layer of a paged forward of ``slots`` slots reads when its
+    live slots hold ``lengths`` rows each (the rows being written counted
+    in; a slot with no real token is left out or 0): the slots' own whole
+    pages, at most the page-table row each, summed and rounded up to whole
+    steps of :func:`paged_read_pairs` pairs.  The host's copy of what
+    :func:`_paged_read_plan` computes from ``start``, ``seq_mask`` and the
+    table's shape on the device (the serving engine's ``gathered_rows`` span
+    attr)."""
+    pairs = paged_read_pairs(slots, max_pages)
+    live = int(np.minimum(-(-np.asarray(lengths, np.int64) // page_size),
+                          max_pages).sum())
+    return -(-live // pairs) * pairs * page_size
+
+
+def _paged_read_plan(page_table, start, seq_mask, ps: int):
+    """What a paged forward reads, as one flat slot-major list of the
+    call's live (slot, logical page) pairs, cut into steps of N =
+    :func:`paged_read_pairs` pairs: ``(steps, slot [T, N], pages [T, N],
+    limit [T, N, S])``, one plan for all layers.
+
+    Slot b holds ``ceil((its longest real position + 1) / ps)`` live pages,
+    at most its page-table row and **none if it has no real token**; pair i
+    of the list is page ``j`` of the slot whose pages span i (offsets by
+    cumulative sum).  ``pages`` is its physical page and ``limit[i, s]`` the
+    last row of that page query ``s`` of its slot may see (``q_pos - j*ps``;
+    rows ``r <= limit`` pass, so a page wholly behind the query passes whole
+    and one ahead of it not at all).  Pairs past the total have ``slot ==
+    B`` (no slot's), the trash page and ``limit == -1``: they contribute
+    exactly nothing.  ``steps`` (traced) is the total in whole steps, the
+    read's trip count; ``T`` is the whole table's."""
+    B, maxp = page_table.shape
+    S = seq_mask.shape[1]
+    pairs = paged_read_pairs(B, maxp)
+    positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    rows = jnp.max(jnp.where(seq_mask, positions, -1), axis=1) + 1
+    n_pages = jnp.minimum((rows + ps - 1) // ps, maxp)            # [B]
+    ends = jnp.cumsum(n_pages)
+    i = jnp.arange(-(-B * maxp // pairs) * pairs, dtype=jnp.int32)
+    # the slot whose pages span i: the offsets at or under it, counted
+    slot = jnp.sum(i[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    valid = slot < B
+    at = jnp.minimum(slot, B - 1)
+    j = i - (ends - n_pages)[at]
+    pages = jnp.where(valid, page_table[at, jnp.clip(j, 0, maxp - 1)], 0)
+    limit = jnp.where(valid[:, None], positions[at] - j[:, None] * ps, -1)
+    return ((ends[-1] + pairs - 1) // pairs, slot.reshape(-1, pairs),
+            pages.reshape(-1, pairs), limit.reshape(-1, pairs, S))
 
 
 def paged_pool_order(leaf: jax.Array) -> Optional[Tuple[int, ...]]:
@@ -1708,78 +1743,108 @@ def _pool_views(pools, pool_order):
     return views, "".join(" tkd"[a] for a in perm[1:])
 
 
-def _attention_paged(cfg, q, pools, gather_pages, q_pos, steps,
-                     pool_order=None):
-    """q:[B,S,Hq,hd] against the pages ``gather_pages [B, maxp]`` of each
-    slot, read ``PAGED_READ_GRANULE`` whole pages of every slot at a time
-    and only ``steps`` (traced) times: up to the longest live position of
-    the call (:func:`_paged_read_steps`), not the page-table row.
+def _attention_paged(cfg, q, pools, read, pool_order=None):
+    """q:[B,S,Hq,hd] against the call's live pages, ``read`` =
+    :func:`_paged_read_plan`'s flat list of (slot, page) pairs with the
+    pages moved to this layer's: a step of the loop gathers the whole pages
+    of N pairs, whichever slots they belong to, and the loop runs ``steps``
+    (traced) times.  Every slot is read to its own length, and a slot with
+    no real token not at all.
 
     Slot-local index == position, so the mask is purely causal
-    (``t <= q_pos``): every slot-index at or before the query holds a real
-    token of this request, everything after (including trash-page gathers
-    from unallocated page-table entries) is masked.  A row past the
-    longest live position fails ``t <= q_pos`` for every real query of the
-    call, so its probability is exactly 0 and leaving it unread is the same
-    mathematics: the softmax over the rows read is computed blockwise
-    (running max, sum and accumulator in float32, as a flash kernel does),
-    over exactly the rows that can pass the mask.  Masked queries (padding,
-    idle slots) may sit past the bound; their output is garbage either way.
+    (``t <= q_pos``, as ``r <= limit`` within a page): every slot-index at
+    or before the query holds a real token of this request, everything
+    after is masked.  A row past a slot's longest real position fails the
+    mask for every real query of the slot, so its probability is exactly 0
+    and leaving it unread is the same mathematics: the softmax is computed
+    blockwise (running max, sum and accumulator in float32 **per slot**, as
+    a flash kernel keeps them per query block), over exactly the rows that
+    can pass the mask.  A step's pairs are folded into their slots' state
+    by a ``[B, N]`` one-hot: the max by a masked reduction, the
+    probabilities by placing each pair's block in its slot's row of the
+    product with V (scores are laid out ``[Hkv, G, S, N, page]``, pair and
+    row last as the contraction wants them; with the pair leading the
+    compiler recomputes a prefill's exponentials for the product).  A
+    slot's first page leads its pairs and its row 0 passes every query's
+    mask, so from a slot's first pair on its ``m`` is a real score and a
+    masked row's weight ``exp(-1e30 - m)`` is exactly 0; a pair past the
+    total is in no slot's row.  Masked queries (padding, idle slots) may
+    sit past what is read; their output is garbage either way (0 for a
+    slot that is not read at all).
 
-    Each step runs the same einsum structure as :func:`_attention_cached`
-    (GQA contracts grouped heads against the Hkv cache directly, no
-    kernel).  The products keep the pages' own axes as
-    the device stores them (:func:`_pool_views`) and only the scores are
-    flattened: merging or moving axes of K/V would re-lay the gathered
-    block out, which the compiler then does to the whole pool (PERF.md,
-    PR 25).  The pool is only ever gathered from, whole pages at a time, so
-    it stays where the layer scan carries it.
+    GQA contracts grouped heads against the Hkv pages directly, no kernel.
+    The products keep the pages' own axes as the device stores them
+    (:func:`_pool_views`) and only the scores are flattened: merging or
+    moving axes of K/V would re-lay the gathered block out, which the
+    compiler then does to the whole pool (PERF.md, PR 25).  The pool is
+    only ever gathered from, whole pages at a time, so it stays where the
+    layer scan carries it.
     """
+    steps, slot, pages, limit = read
     B, S, Hq, hd = q.shape
-    maxp = gather_pages.shape[1]
     ps, Hkv = pools["k"].shape[1], pools["k"].shape[2]
-    C = min(PAGED_READ_GRANULE, maxp)
+    N = slot.shape[1]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
-    # a table that is no whole number of steps is filled up with the trash
-    # page: rows past the table are past every position
-    gather_pages = jnp.pad(gather_pages, ((0, 0), (0, -maxp % C)))
     views, axes = _pool_views(pools, pool_order)
     slopes = (jnp.asarray(_alibi_slopes(Hq)).reshape(Hkv, G)
               if cfg.position == "alibi" else None)
+    r = jnp.arange(ps, dtype=jnp.int32)
 
     def step(i, carry):
         m, l, acc = carry
+        at, pg, lim = slot[i], pages[i], limit[i]        # [N] [N] [N,S]
+        if B > 1:
+            # mine[b, n]: pair n is a page of slot b
+            mine = (at[None, :] == jnp.arange(B, dtype=jnp.int32)[:, None])
+            mine = mine[:, None, None, None, :]          # [B,1,1,1,N]
+
+            def in_slot(x, fill):    # [Hkv,G,S,N,..] -> [B,Hkv,G,S,N,..]
+                own = mine.reshape(mine.shape + (1,) * (x.ndim - 4))
+                return jnp.where(own, x[None], fill)
+
+            def of_slot(x):          # [B,Hkv,G,S] -> [Hkv,G,S,N]
+                return jnp.where(mine, x[..., None], 0.0).sum(0)
+        else:
+            # one slot (every prefill program): every pair is its (one past
+            # the total is masked whole and weighs 0) and the fold is the
+            # plain reduction.  Written out, because through the one-hot the
+            # compiler computes the 2048-token bucket's exponentials twice
+            # (prefill +4.8%, PERF.md PR 29)
+            def in_slot(x, fill):
+                return x[None]
+
+            def of_slot(x):
+                return jnp.broadcast_to(x[0, ..., None], x.shape[1:] + (N,))
         with jax.named_scope("kv_gather"):
-            # C whole pages of every slot: [B, C, *axes]
-            pages = jax.lax.dynamic_slice_in_dim(gather_pages, i * C, C, 1)
-            ck, cv = views["k"][pages], views["v"][pages]
+            # N whole pages: [N, *axes]
+            ck, cv = views["k"][pg], views["v"][pg]
             if "k_scale" in views:
                 # dequantize inside the gather: the narrow representation
                 # is what crosses HBM; attention sees compute-dtype values
                 along = tuple(-1 if c == "t" else 1 for c in axes)
                 ck, cv = (
-                    kv_dequantize(c, views[n][pages].reshape(B, C, *along),
+                    kv_dequantize(c, views[n][pg].reshape(N, *along),
                                   cfg.dtype)
                     for c, n in ((ck, "k_scale"), (cv, "v_scale")))
-        scores = jnp.einsum(f"bskgd,bp{axes}->bkgspt", qg, ck)
-        scores = (scores.astype(jnp.float32).reshape(B, Hkv, G, S, C * ps)
-                  * _sm_scale(cfg, hd))
-        t = i * (C * ps) + jnp.arange(C * ps, dtype=jnp.int32)
+        # each pair's own slot's queries; one slot's are every pair's
+        qn = (qg[jnp.minimum(at, B - 1)] if B > 1
+              else jnp.broadcast_to(qg, (N,) + qg.shape[1:]))
+        scores = jnp.einsum(f"nskgd,n{axes}->kgsnt", qn, ck)
+        scores = scores.astype(jnp.float32) * _sm_scale(cfg, hd)
+        lim_sn = lim.T                                          # [S,N]
         if slopes is not None:
-            rel = (q_pos[:, :, None] - t[None, None, :]).astype(jnp.float32)
-            scores = scores - (jnp.abs(rel)[:, None, None, :, :]
-                               * slopes[None, :, :, None, None])
-        ok = t[None, None, :] <= q_pos[:, :, None]              # [B,S,C*ps]
-        scores = jnp.where(ok[:, None, None, :, :], scores, -1e30)
-        # row 0 passes every query's mask, so from the first step on m is a
-        # real score and a masked row's weight exp(-1e30 - m) is exactly 0
-        m_new = jnp.maximum(m, scores.max(-1))
-        p = jnp.exp(scores - m_new[..., None])
+            rel = (lim_sn[:, :, None] - r[None, None, :]).astype(jnp.float32)
+            scores = scores - (jnp.abs(rel)[None, None]
+                               * slopes[:, :, None, None, None])
+        ok = r[None, None, :] <= lim_sn[:, :, None]              # [S,N,ps]
+        scores = jnp.where(ok[None, None], scores, -1e30)
+        m_new = jnp.maximum(m, in_slot(scores.max(-1), -1e30).max(-1))
+        p = jnp.exp(scores - of_slot(m_new)[..., None])
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + p.sum(-1)
-        pv = jnp.einsum(f"bkgspt,bp{axes}->bskgd",
-                        p.astype(q.dtype).reshape(B, Hkv, G, S, C, ps), cv)
+        l = l * alpha + in_slot(p.sum(-1), 0.0).sum(-1)
+        pv = jnp.einsum(f"bkgsnt,n{axes}->bskgd",
+                        in_slot(p.astype(q.dtype), 0), cv)
         acc = (acc * jnp.moveaxis(alpha, 3, 1)[..., None]
                + pv.astype(jnp.float32))
         return m_new, l, acc
@@ -1788,7 +1853,8 @@ def _attention_paged(cfg, q, pools, gather_pages, q_pos, steps,
     _, l, acc = jax.lax.fori_loop(
         0, steps, step,
         (m0, jnp.zeros_like(m0), jnp.zeros((B, S, Hkv, G, hd), jnp.float32)))
-    out = acc / jnp.moveaxis(l, 3, 1)[..., None]
+    # a slot that was not read has l == 0: its output is 0, not NaN
+    out = acc / jnp.moveaxis(jnp.where(l > 0, l, 1.0), 3, 1)[..., None]
     return out.astype(q.dtype).reshape(B, S, Hq, hd)
 
 
@@ -1827,8 +1893,7 @@ def _adapter_proj(adapters, ad_scale):
     return proj
 
 
-def _attend_paged(cfg, pools, write, gather_pages, q_pos, read_steps,
-                  pool_order=None):
+def _attend_paged(cfg, pools, write, read, pool_order=None):
     """:func:`_block`'s ``attend`` against the paged pool, addressed a whole
     page at a time; the pool is what is kept.  ``pools`` maps each pool leaf
     (``k``/``v``, plus ``k_scale``/``v_scale`` on a quantized pool) to its
@@ -1838,9 +1903,10 @@ def _attend_paged(cfg, pools, write, gather_pages, q_pos, read_steps,
     Write: ``write = (src, keep, pages)`` is the block's page-merge plan
     (:func:`_paged_write_plan`, one for all layers, ``pages`` moved to this
     layer's): the pages are gathered, merged and scattered back whole.
-    Read: ``gather_pages [B, maxp]`` are each slot's pages, of which
-    :func:`_attention_paged` gathers the first ``read_steps`` steps of
-    ``PAGED_READ_GRANULE`` whole pages.  Every pool op thus slices all
+    Read: ``read`` is the call's list of live (slot, page) pairs
+    (:func:`_paged_read_plan`, one for all layers, its pages moved to this
+    layer's), which :func:`_attention_paged` gathers a step's worth of
+    whole pages at a time.  Every pool op thus slices all
     trailing axes, so it runs in whatever layout the pool is stored in
     (``pool_order``, :func:`_pool_views`) and the pool stays in place; a
     row-granular scatter or gather makes the TPU compiler re-lay the whole
@@ -1880,8 +1946,7 @@ def _attend_paged(cfg, pools, write, gather_pages, q_pos, read_steps,
                 new[name] = constrain_spec(new[name],
                                            P(None, None, "model", None))
         with jax.named_scope("attn"):
-            return _attention_paged(cfg, q, new, gather_pages, q_pos,
-                                    read_steps, pool_order), new
+            return _attention_paged(cfg, q, new, read, pool_order), new
     return attend
 
 
@@ -1951,13 +2016,15 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     lie.  A quantized cache (``init_paged_cache(kv_dtype="int8")``) is two
     more leaves of that carry and the same program shapes.
 
-    What is read: every slot's pages up to the longest live position of the
-    call (decode: the longest active slot's length + 1; prefill: ``start +
-    n_real``; verify-k: the longest ``start + k + 1``), not the ``maxp``
-    pages of the page-table row.  The bound is computed here, on the device,
-    from ``start`` and ``seq_mask`` (:func:`_paged_read_steps`), so one
-    compiled program serves every length and a tick launched ahead on
-    ``lengths + k`` reads what it needs.  ``pool_order``
+    What is read: every slot's pages up to its own longest real position
+    (decode: an active slot's length + 1; prefill: ``start + n_real``;
+    verify-k: ``start + k + 1``) and nothing of a slot with no real token,
+    as one flat list of (slot, page) pairs walked a fixed number of pairs a
+    step — not the ``maxp`` pages of every page-table row, nor every slot to
+    the longest slot's length.  The list is computed here, on the device,
+    from ``start``, ``seq_mask`` and the table (:func:`_paged_read_plan`),
+    so one compiled program serves every length and a tick launched ahead
+    on ``lengths + k`` reads what it needs.  ``pool_order``
     (:func:`paged_pool_order` of the arrays the caller holds; optional, a
     matter of speed only) is the order in which the device stores a K/V
     leaf's axes, where that is not row-major (:func:`_pool_views`).
@@ -1984,8 +2051,8 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                  + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :])
     src, keep, write_pages = _paged_write_plan(page_table, start, seq_mask,
                                                ps)
-    read_steps = _paged_read_steps(positions, seq_mask, ps,
-                                   page_table.shape[1])
+    steps, read_slot, read_pages, limit = _paged_read_plan(
+        page_table, start, seq_mask, ps)
 
     # a slot may run to positions past the learned table's end
     x = _embed(cfg, params, tokens,
@@ -2019,7 +2086,7 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         x, _, counts, pools = _block(
             cfg, {**lp, **experts}, x, positions, rng,
             _attend_paged(cfg, pools, (src, keep, write_pages + first_page),
-                          page_table + first_page, positions, read_steps,
+                          (steps, read_slot, read_pages + first_page, limit),
                           pool_order),
             proj=_adapter_proj(factors, ad_scale), token_mask=seq_mask,
             expert_offset=(first_page // num_pages * cfg.num_experts

@@ -20,17 +20,26 @@ scans over or stacks has the pool's page axis, and its body holds no
 scatter that cuts a page — the ops that, one layer's slice or one row at a
 time, made the TPU compiler copy the pool (PERF.md, PR 25).
 
-(c) The bounded read (ISSUE 27): the read stops at the longest live position
-of the call, two pages a step.  Against the same row-granular block, which
-attends over every row of every slot's page-table row: lengths one short
-of, at and one past a page's and a step's end, a slot at ``max_model_len -
-1`` beside short ones, an inactive slot, no active slot at all, a tail
-prefill over shared pages and a verify-k block across a step's end, on a
-table of three steps.  The softmax is computed blockwise, so what a layer
-writes after the first may differ from the reference in the last place;
-the first layer's pages are ``array_equal``.  No gather in the layer body
-is as wide as the page-table row, and the device's order of the pool's axes
-(``pool_order``) changes the view the read gathers from, never a number.
+(c) The read of live pages only (ISSUE 27, ISSUE 29): the read walks a flat
+list of the call's live (slot, page) pairs, every slot to its own length,
+two pairs a slot of the program a step.  Against the same row-granular
+block, which attends over every row of every slot's page-table row: lengths
+one short of, at and one past a page's and a pair of pages' end, a slot at
+``max_model_len - 1`` beside short ones, an inactive slot (last, and between
+two live ones), no active slot at all, a tail prefill over shared pages and
+a verify-k block across a page pair's end, on a table of six pages.  The
+softmax is computed blockwise, so what a layer writes after the first may
+differ from the reference in the last place; the first layer's pages are
+``array_equal``.  No gather in the layer body is as wide as the page-table
+row, and the device's order of the pool's axes (``pool_order``) changes the
+view the read gathers from, never a number.
+
+(d) Against the contiguous cache (ISSUE 29): slots of very unequal length in
+one decode call — one at the table's last row, one exactly at a page's edge,
+one of three rows, an idle one between two live ones — give the logits and
+the K/V rows that ``forward_cached`` gives each request alone, and the
+logits of the plain ``forward``; with grouped heads, alibi, learned
+positions and an int8 pool.
 """
 import functools
 
@@ -44,13 +53,13 @@ from deepspeed_tpu.models import get_config, init_params
 from deepspeed_tpu.models.transformer import (_adapter_proj, _attend_paged,
                                               _attn_out, _block,
                                               _lm_head, _mlp, _norm,
-                                              PAGED_READ_GRANULE,
-                                              _paged_read_steps,
+                                              _paged_read_plan,
                                               _paged_write_plan, _qkv,
                                               _adapter_delta, _sm_scale,
                                               forward_paged,
                                               init_paged_cache, kv_dequantize,
-                                              kv_quantize_rows)
+                                              kv_quantize_rows,
+                                              paged_read_pairs)
 
 L, NUM_PAGES, PAGE, B = 3, 9, 8, 3   # 4 pages a slot: max_model_len 32
 CFG = get_config("tiny-gqa", num_layers=L, dtype=jnp.float32)
@@ -114,6 +123,11 @@ def _shape_case(name):
         start = [47, 2, 9]
         mask = np.ones((B, 1), bool)
         table = WIDE_TABLE
+    elif name == "idle-between":        # slot 1 idle between two live ones
+        tokens = rng.integers(1, 250, (B, 1))
+        start = [37, 5, 12]
+        mask = np.array([[True], [False], [True]])
+        table = WIDE_TABLE
     elif name == "all-inactive":
         tokens = rng.integers(1, 250, (B, 1))
         start = [21, 3, 0]
@@ -173,14 +187,13 @@ def _forward_sliced(params, tokens, cache, page_table, start, seq_mask,
     positions = start[:, None] + jnp.arange(tokens.shape[1], dtype=jnp.int32)
     write = _paged_write_plan(page_table, start, seq_mask,
                               cache["k"].shape[2])
-
-    steps = _paged_read_steps(positions, seq_mask, cache["k"].shape[2],
-                              page_table.shape[1])
+    read = _paged_read_plan(page_table, start, seq_mask,
+                            cache["k"].shape[2])
 
     def block(lp, x, pools, ad):
         out = _block(
             CFG, lp, x, positions, jax.random.PRNGKey(0),
-            _attend_paged(CFG, pools, write, page_table, positions, steps),
+            _attend_paged(CFG, pools, write, read),
             proj=_adapter_proj(
                 ad, None if adapters is None else adapters["scale"]))
         return out[0], out[3]
@@ -286,8 +299,8 @@ ROW_CASES = (
     + [(f"len{n}", "int8", False) for n in (15, 16, 17)]
     + [("len16", None, True), ("len17", "int8", True)]
     + [(shape, kv, ad)
-       for shape in ("long-beside-short", "all-inactive", "tail-prefill",
-                     "verify-straddle")
+       for shape in ("long-beside-short", "idle-between", "all-inactive",
+                     "tail-prefill", "verify-straddle")
        for kv, ad in ((None, False), ("int8", True))])
 
 
@@ -360,6 +373,80 @@ def test_pool_order_changes_the_view_not_the_numbers(params, kv_dtype, order):
                                np.asarray(want_logits), rtol=1e-5, atol=1e-5)
 
 
+UNEQUAL = {   # overrides of "tiny" (rotary, RMSNorm, 4 heads), pool dtype
+    "gqa": (dict(num_heads=8, num_kv_heads=2), None),
+    "alibi": (dict(position="alibi", norm="layernorm", activation="gelu"),
+              None),
+    "learned-int8": (dict(position="learned", norm="layernorm",
+                          attn_bias=True, mlp_bias=True), "int8"),
+}
+
+
+@pytest.mark.parametrize("topology", list(UNEQUAL))
+def test_unequal_slots_equal_the_contiguous_cache(topology):
+    """One decode call over five slots of 32, 0 (idle), 8, 30 and 3 rows
+    after it (page 8, four pages a slot: the table's last row, a page's
+    edge, an idle slot between live ones), each prefilled through the paged
+    forward alone.  Every live slot's logits and every K/V row it holds are
+    what ``forward_cached`` gives that request alone, and its logits what
+    the plain forward gives."""
+    from deepspeed_tpu.models import forward, forward_cached, init_cache
+
+    overrides, kv_dtype = UNEQUAL[topology]
+    cfg = get_config("tiny", dtype=jnp.float32, **overrides)
+    params = init_params(cfg, jax.random.PRNGKey(7))
+    held = [32, 0, 8, 30, 3]          # rows after the decode call
+    Bq, maxp = len(held), 4
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, 250, (n,)).astype(np.int32) for n in held]
+    table = np.zeros((Bq, maxp), np.int32)
+    table[[0, 2, 3, 4]] = 1 + rng.permutation(4 * maxp).reshape(4, maxp)
+    cache = init_paged_cache(cfg, 1 + 4 * maxp, PAGE, dtype=jnp.float32,
+                             kv_dtype=kv_dtype)
+    paged = jax.jit(functools.partial(forward_paged, cfg))
+    for b, toks in enumerate(prompts):
+        if len(toks) > 1:             # all but the last token, bucket 32
+            pad = np.zeros((1, 32), np.int32)
+            pad[0, :len(toks) - 1] = toks[:-1]
+            _, cache = paged(params, jnp.asarray(pad), cache,
+                             jnp.asarray(table[b:b + 1]),
+                             jnp.zeros((1,), jnp.int32),
+                             jnp.asarray(np.arange(32) < len(toks) - 1)[None])
+    live = np.array([n > 0 for n in held])
+    last = np.array([[t[-1] if len(t) else 0] for t in prompts], np.int32)
+    logits, cache = paged(
+        params, jnp.asarray(last), cache, jnp.asarray(table),
+        jnp.asarray([max(n - 1, 0) for n in held], jnp.int32),
+        jnp.asarray(live[:, None]))
+    assert np.isfinite(np.asarray(logits)).all()
+
+    tol = dict(rtol=2e-2, atol=2e-2) if kv_dtype else dict(rtol=2e-5,
+                                                            atol=2e-5)
+    for b, toks in enumerate(prompts):
+        if not len(toks):
+            continue
+        n = len(toks)
+        pos = jnp.arange(n, dtype=jnp.int32)[None]
+        want = forward(cfg, params, jnp.asarray(toks)[None], attn_impl="xla")
+        np.testing.assert_allclose(np.asarray(logits[b, 0]),
+                                   np.asarray(want[0, -1]), **tol)
+        cached, cc = forward_cached(
+            cfg, params, jnp.asarray(toks)[None],
+            init_cache(cfg, 1, n, dtype=jnp.float32), pos,
+            jnp.ones((1, n), bool))
+        np.testing.assert_allclose(np.asarray(logits[b, 0]),
+                                   np.asarray(cached[0, -1]), **tol)
+        # the rows the slot holds, through its page-table row
+        t = np.arange(n)
+        for name in ("k", "v"):
+            got = np.asarray(cache[name])[:, table[b, t // PAGE], t % PAGE]
+            if kv_dtype:
+                got = got * np.asarray(cache[name + "_scale"])[
+                    :, table[b, t // PAGE], t % PAGE][..., None, None]
+            np.testing.assert_allclose(got, np.asarray(cc[name])[:, 0],
+                                       **tol, err_msg=f"slot {b} {name}")
+
+
 def _sub_jaxprs(jaxpr):
     yield jaxpr
     for eqn in jaxpr.eqns:
@@ -412,10 +499,22 @@ def test_layer_scan_carries_the_pool_in_place(params, kv_dtype):
             if name == "gather":
                 assert tuple(e.params["slice_sizes"][1:]) == shape[1:], e
                 # ... and never a slot's whole page-table row: the read
-                # takes PAGED_READ_GRANULE pages of every slot a step
+                # takes a step's pairs, the merge the pages it writes
                 n_pages = int(np.prod(e.invars[1].aval.shape[:-1]))
-                assert n_pages <= B * PAGED_READ_GRANULE < table.size, e
+                assert n_pages <= paged_read_pairs(*table.shape), e
+                assert n_pages < table.size, e
             if name == "scatter":
                 dn = e.params["dimension_numbers"]
                 assert len(dn.update_window_dims) == len(shape) - 1, e
     assert {"scatter", "gather"} <= seen
+
+    # the read's loop: one gather a pool leaf a step, of a step's pairs
+    loops = [e for j in _sub_jaxprs(scan.params["jaxpr"].jaxpr)
+             for e in j.eqns if e.primitive.name == "while"]
+    assert len(loops) == 1, "one read loop in the layer body"
+    reads = [e for j in _sub_jaxprs(loops[0].params["body_jaxpr"].jaxpr)
+             for e in j.eqns if e.primitive.name == "gather"
+             and e.invars[0].aval.shape in pool_shapes]
+    assert len(reads) == len(cache)
+    for e in reads:
+        assert e.invars[1].aval.shape[0] == paged_read_pairs(*table.shape)
