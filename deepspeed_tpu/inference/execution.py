@@ -50,9 +50,9 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, cow_copy_pool,
-                                  expert_counts_shape, paged_pool_cache,
-                                  paged_pool_order,
-                                  paged_pool_tuple)
+                                  expert_counts_shape, is_hybrid,
+                                  paged_pool_cache, paged_pool_order,
+                                  paged_pool_tuple, window_ring_pages)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .kv_tiering import extract_pool_page, inject_pool_page
@@ -209,6 +209,26 @@ class MeshExecutor:
                     "dividing kv_heads or replicate with tp=1")
         # params ride the same auto-TP shardings generate() uses; already-
         # committed trees (InferenceEngine.serving()) pass through
+        # a model with window layers keeps a second pool, its slots' rings
+        # (docs/SERVING.md "Two kinds of layer"): what moves or shares
+        # pages of ONE pool says so instead of serving a wrong answer
+        self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
+                           if is_hybrid(cfg) else 0)
+        # the window pool: a ring a slot, and its own trash page
+        self.window_pages = (1 + self.b_slots * self.ring_pages
+                             if self.ring_pages else 0)
+        if self.ring_pages:
+            for on, what in ((self.tp > 1, "tensor-sharded heads (tp > 1)"),
+                             (prefix_cache, "copy-on-write page snapshots "
+                              "(prefix_cache=True)"),
+                             (host_tier, "KV-page tiering"),
+                             (kv_dtype is not None, "the int8 pool"),
+                             (adapters is not None, "multi-tenant adapters")):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} does not support a model with window "
+                        "layers (layer_pattern): its window layers keep a "
+                        "ring of pages a slot in a pool of their own")
         self.params = place_params(params, mesh)
         # capture the placed tree's shape so LIVE weight updates
         # (update_params — hybrid rollout, docs/HYBRID.md) can be pinned to
@@ -224,8 +244,13 @@ class MeshExecutor:
             jax.tree_util.tree_map(lambda x: x.sharding, self.params)
             if leaves and all(hasattr(x, "sharding") for x in leaves)
             else None)
-        cache = model.init_paged_cache(self.num_pages, self.page_size,
-                                       dtype=dtype, kv_dtype=kv_dtype)
+        if self.ring_pages:
+            cache = model.init_paged_cache(
+                self.num_pages, self.page_size, dtype=dtype,
+                window_pages=self.window_pages)
+        else:
+            cache = model.init_paged_cache(self.num_pages, self.page_size,
+                                           dtype=dtype, kv_dtype=kv_dtype)
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
         # (k, v) full precision, (k, v, k_scale, v_scale) quantized — every
@@ -252,8 +277,12 @@ class MeshExecutor:
                 for k in self._pool_keys)
         # how the device stores a K/V leaf, where that is not row-major:
         # the paged read hands its loop the pool in that order
-        # (models.transformer._pool_views)
-        self.pool_order = paged_pool_order(self.pools[0])
+        # (models.transformer._pool_views); leaves of different widths may
+        # be stored differently, so a pool a kind gives the order a leaf
+        self.pool_order = (
+            {k: paged_pool_order(a)
+             for k, a in zip(self._pool_keys, self.pools)}
+            if self.ring_pages else paged_pool_order(self.pools[0]))
         self._decode_prog = self._build_decode()
         self._prefill_progs: Dict[int, Any] = {}
         self._cow_prog = _COW_PROG if prefix_cache else None
@@ -342,6 +371,7 @@ class MeshExecutor:
 
     def _build_decode(self):
         apply_paged, with_counts = self._apply_paged, self._with_counts
+        keys = self._pool_keys
 
         def prog(params, pools, page_table, lengths, last_tok, active,
                  temp, top_k, top_p, seeds, *adapters):
@@ -357,7 +387,7 @@ class MeshExecutor:
             # fed the one before it without a fetch (ServingEngine
             # lookahead).  `adapters`: the per-slot factor pytree, one
             # trailing operand where a registry rides along, else none.
-            cache = paged_pool_cache(pools)
+            cache = paged_pool_cache(pools, keys)
             logits, cache, counts = apply_paged(
                 params, last_tok[:lengths.shape[0], None], cache, page_table,
                 lengths, active[:, None],
@@ -372,6 +402,7 @@ class MeshExecutor:
 
     def _build_prefill(self, s_pad: int):
         apply_paged, with_counts = self._apply_paged, self._with_counts
+        keys = self._pool_keys
 
         def prog(params, pools, pt_row, tokens, n_real, start,
                  temp, top_k, top_p, seed, *adapters):
@@ -387,7 +418,7 @@ class MeshExecutor:
             # A traced scalar: every start shares ONE program per bucket.
             # `adapters`: the admitted slot's factor slice, as in decode.
             seq_mask = (jnp.arange(s_pad, dtype=jnp.int32) < n_real)[None, :]
-            cache = paged_pool_cache(pools)
+            cache = paged_pool_cache(pools, keys)
             logits, cache, counts = apply_paged(
                 params, tokens, cache, pt_row, start[None], seq_mask,
                 adapters=adapters[0] if adapters else None)
@@ -449,9 +480,11 @@ class MeshExecutor:
             # placed as the program's own output is, so that feeding that
             # output back is the same program and not a second compile
             last_tok = jax.device_put(last_tok, self._token_sharding())
+        # ``page_table``: the slots' table, or (full, ring) tables of a
+        # model with window layers
         args = (self.params, self.pools,
-                jnp.asarray(page_table), jnp.asarray(lengths),
-                last_tok, jnp.asarray(active), *lanes)
+                jax.tree_util.tree_map(jnp.asarray, page_table),
+                jnp.asarray(lengths), last_tok, jnp.asarray(active), *lanes)
         if self.adapters is not None:
             args += (adapters if adapters is not None
                      else self._adapter_zero(),)

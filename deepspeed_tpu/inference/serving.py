@@ -123,9 +123,12 @@ from typing import Any, Deque, Dict, List, Optional
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
-from ..models.transformer import PAGE_SIZE, paged_read_rows
+from ..models.transformer import (PAGE_SIZE, block_read_rows, is_hybrid,
+                                  kind_layers, paged_read_rows,
+                                  window_read_rows, window_ring_pages)
 from ..observability.device_profiler import (device_trace_unit,
                                              maybe_capture_from_env)
 from ..observability.program_stats import ProgramCatalog
@@ -366,7 +369,7 @@ class ServingEngine:
                  watchdog=None, dtype=None, kv_dtype=None, mesh=None,
                  max_queue: Optional[int] = None, quarantine_limit: int = 2,
                  probe_after_ticks: Optional[int] = None,
-                 prefix_cache: bool = True,
+                 prefix_cache: Optional[bool] = None,
                  prefix_index_entries: int = 4096,
                  host_tier_pages: Optional[int] = None,
                  speculative: Optional[SpeculativeConfig] = None,
@@ -398,6 +401,26 @@ class ServingEngine:
                 f"num_pages={self.num_pages} cannot hold one full slot "
                 f"({self.pages_per_slot} pages of {self.page_size} tokens "
                 f"+ the trash page)")
+        # layers of two kinds (docs/SERVING.md "Two kinds of layer"): a
+        # window layer keeps a ring of ``ring`` pages a slot in a pool of
+        # its own.  What shares, moves or re-reads pages of one pool is
+        # refused by name; prefix sharing is on by default everywhere else
+        cfg = model.config
+        self._ring = (window_ring_pages(cfg.window_size, self.page_size)
+                      if is_hybrid(cfg) else 0)
+        if self._ring:
+            for on, what in (
+                    (prefix_cache, "prefix sharing (prefix_cache=True)"),
+                    (host_tier_pages is not None, "KV-page tiering"),
+                    (speculative is not None, "speculative decoding")):
+                if on:
+                    raise NotImplementedError(
+                        f"{what} does not support a model with window "
+                        "layers (layer_pattern): a window layer's ring "
+                        "holds its slot's last positions only, so there is "
+                        "no page of it to share, park or verify against")
+        if prefix_cache is None:
+            prefix_cache = not self._ring
         self.monitor = monitor
         self.watchdog = watchdog
         # decode lookahead (docs/SERVING.md "Decode lookahead"): launch tick
@@ -539,6 +562,18 @@ class ServingEngine:
         self._pages_hwm = 0            # high-water mark of occupied pages
         self._page_table = np.zeros((self.b_slots, self.pages_per_slot),
                                     np.int32)
+        # the window pool's allocator: page 0 its trash page, a slot's ring
+        # taken whole at admission and given back when the request ends (the
+        # ring is reused in place as positions fall out of the window)
+        self._free_ring: List[int] = list(range(
+            self._exec.window_pages - 1, 0, -1))
+        self._ring_table = np.zeros((self.b_slots, self._ring), np.int32)
+        self._quarantined_ring: List[int] = []
+        if self._ring:
+            # K/V head rows a token row of each kind stands for, over the
+            # kind's layers (the kv_rows_* span attrs)
+            self._kind_heads = {kind: g.kv_heads * n
+                                for kind, (g, n) in kind_layers(cfg).items()}
         self._lengths = np.zeros((self.b_slots,), np.int32)
         self._last_tok = np.zeros((self.b_slots,), np.int32)
         self._active = np.zeros((self.b_slots,), bool)
@@ -779,6 +814,27 @@ class ServingEngine:
             self._refcount[p] = 0
         self._quarantined_pages.extend(pages)
 
+    def _tables(self, slot: Optional[int] = None):
+        """What a paged program takes as its page table: the slots' table
+        (one slot's row), beside the rings' where the model has window
+        layers."""
+        rows = slice(None) if slot is None else slice(slot, slot + 1)
+        if not self._ring:
+            return self._page_table[rows]
+        return self._page_table[rows], self._ring_table[rows]
+
+    def _take_ring(self, slot: int) -> None:
+        self._ring_table[slot] = [self._free_ring.pop()
+                                  for _ in range(self._ring)]
+
+    def _give_ring(self, slot: int, leak: bool = False) -> None:
+        """A slot's ring back to the window pool's free list, or (``leak``:
+        the slot is being fenced) into its quarantine account."""
+        pages = [int(p) for p in self._ring_table[slot] if p]
+        (self._quarantined_ring if leak else self._free_ring).extend(pages)
+        if not leak:
+            self._ring_table[slot] = 0
+
     def page_accounting(self) -> Dict[str, Any]:
         """The refcount pool invariant, one call: every page (minus the
         trash page) is exactly one of free, quarantined, or referenced
@@ -794,7 +850,21 @@ class ServingEngine:
         free = len(self._free_pages)
         quarantined = len(self._quarantined_pages)
         demoted = self._prefix.demoted if self._prefix is not None else 0
+        # the window pool: every ring page is free, quarantined or in the
+        # ring of exactly one slot that holds a request or is fenced
+        held = self._ring_table[self._ring_table > 0]
+        window = {"free": len(self._free_ring),
+                  "quarantined": len(self._quarantined_ring),
+                  "referenced": int(held.size) - len(self._quarantined_ring),
+                  "total": self.b_slots * self._ring}
+        window["balanced"] = not self._ring or (
+            len(set(held.tolist())) == held.size
+            and window["free"] + held.size == window["total"]
+            and all(self._ring_table[s].all() == bool(
+                self._active[s] or self._quarantined[s])
+                for s in range(self.b_slots)))
         return {
+            "window": window,
             "free": free,
             "quarantined": quarantined,
             "referenced": referenced,
@@ -813,7 +883,8 @@ class ServingEngine:
             "balanced": free + quarantined + referenced
             == self.num_pages - 1
             and demoted == (len(self._tier) if self._tier is not None
-                            else 0),
+                            else 0)
+            and window["balanced"],
         }
 
     def _adapter_salt(self, req: Request) -> int:
@@ -1422,6 +1493,8 @@ class ServingEngine:
         for p in shared:
             self._share_page(p)
         pages = self._alloc_pages(need)
+        if self._ring:
+            self._take_ring(slot)
         try:
             self._prefill(slot, req, shared, pages, match, now)
         except BaseException as e:
@@ -1453,11 +1526,14 @@ class ServingEngine:
                     # the slot: plain unwind, no quarantine accounting
                     for p in pages:
                         self._drop_page(p)
+                    self._give_ring(slot)
                     raise
                 self._slot_failures[slot] += 1
                 self._last_failure_tick = self._tick
                 fails = int(self._slot_failures[slot])
                 fenced = fails >= self.quarantine_limit
+                # the slot's ring goes the way of its private pages
+                self._give_ring(slot, leak=fenced)
                 if fenced:
                     self._quarantined[slot] = True
                     self._leak_pages(pages)
@@ -1535,8 +1611,12 @@ class ServingEngine:
         with trace_span("serve.prefill", rid=req.rid, slot=slot,
                         bucket=s_pad, tokens=S_tail,
                         shared_tokens=n_shared,
-                        gathered_rows=self._gathered_rows(
-                            [n_shared + S_tail], 1)) as sp:
+                        # a model with window layers gathers nothing back:
+                        # its prompt attends within itself (kv_rows_*)
+                        gathered_rows=0 if self._ring else
+                        self._gathered_rows([n_shared + S_tail], 1)) as sp:
+            if self._ring and get_tracer().enabled:
+                self._set_kv_row_attrs(sp, [S_tail], 1, block=s_pad)
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 if match.cow_src is not None:
@@ -1554,7 +1634,8 @@ class ServingEngine:
                         # donor prefix its target-side boundary does
                         self._spec.cow(self._cow_prog, match.cow_src,
                                        private[0])
-                pt_row = jnp.asarray(self._page_table[slot:slot + 1])
+                pt_row = jax.tree_util.tree_map(jnp.asarray,
+                                                self._tables(slot))
                 toks_j = jnp.asarray(toks)
                 out, counts = self._exec.split_counts(np.asarray(
                     self._exec.prefill(
@@ -1661,11 +1742,45 @@ class ServingEngine:
         call's span: from the rows each expert of each layer computed
         (``counts [L, E]``, fetched with the tokens) and the real tokens
         the call was given."""
-        sp.set(moe_live_rows=(live_tokens * self.model.config.moe_top_k
-                              * counts.shape[0]),
+        pairs = live_tokens * self.model.config.moe_top_k * counts.shape[0]
+        sp.set(moe_live_rows=pairs,
                moe_rows=int(counts.sum()),
                moe_experts_touched=int((counts > 0).sum()),
-               moe_max_load=int(counts.max()))
+               moe_max_load=int(counts.max()),
+               # the (token, expert) pairs the routers chose, those whose
+               # expert is held here (all of them unless the model holds a
+               # share, ``moe_experts_held``), and the experts held, a layer
+               moe_pairs=pairs, moe_local_pairs=int(counts.sum()),
+               moe_experts_held=int(counts.size))
+
+    def _set_kv_row_attrs(self, sp, lengths, slots: int, block: int = 0
+                          ) -> None:
+        """On a ``serve.decode`` / ``serve.prefill`` span, the read of a
+        model with two kinds of layer, a kind: K/V head
+        rows (token rows x the kind's KV heads x its layers) the program
+        reads and those of them that pass the mask, when its live slots hold
+        ``lengths`` rows, the rows being written counted in.  A decode tick
+        reads by its plans: a full layer each slot's own pages, a window
+        layer the ring pages under the last ``window`` positions.  A
+        ``block`` of tokens (a prompt's bucket) reads itself: a full layer
+        each chunk of queries the chunks of keys at or before it, a window
+        layer each chunk two chunks of keys (a short block: all of itself,
+        once)."""
+        lengths = np.asarray(lengths, np.int64)
+        W = self.model.config.window_size
+        full, window = self._kind_heads["full"], self._kind_heads["window"]
+        if block:
+            rows = block_read_rows(block)
+            ring_rows = block_read_rows(block, W)
+            ring_live = int(lengths.sum())
+        else:
+            rows = self._gathered_rows(lengths, slots)
+            ring_rows = window_read_rows(lengths, self.page_size, W, slots)
+            ring_live = int(np.minimum(lengths, W).sum())
+        sp.set(kv_rows_full=rows * full,
+               kv_live_rows_full=int(lengths.sum()) * full,
+               kv_rows_window=ring_rows * window,
+               kv_live_rows_window=ring_live * window)
 
     def _gathered_rows(self, lengths, slots: int) -> int:
         """K/V rows a paged program of ``slots`` slots reads a layer when
@@ -1735,7 +1850,7 @@ class ServingEngine:
             lengths = self._lengths + np.int32(len(self._ahead) + 1) * \
                 self._active.astype(np.int32)
             self._ahead.append(_Ahead(
-                self._exec.decode(self._page_table, lengths, fed,
+                self._exec.decode(self._tables(), lengths, fed,
                                   self._active, lanes, adapters=adapters),
                 fed, self._page_table.copy(), lengths, self._active.copy(),
                 self._exec.params, lanes, adapters))
@@ -1761,7 +1876,7 @@ class ServingEngine:
             with self._armed(f"serve.decode tick {self._tick}"):
                 nxt = self._take_ahead(lanes, adapters)
                 if nxt is None:
-                    nxt = self._exec.decode(self._page_table, self._lengths,
+                    nxt = self._exec.decode(self._tables(), self._lengths,
                                             self._last_tok, self._active,
                                             lanes, adapters=adapters)
                 self._launch_ahead(nxt, lanes, adapters)
@@ -1775,6 +1890,8 @@ class ServingEngine:
                            live_rows=int(live.sum()),
                            gathered_rows=self._gathered_rows(
                                live + 1, self.b_slots))
+                    if self._ring:
+                        self._set_kv_row_attrs(sp, live + 1, self.b_slots)
                 # host fetch = device sync; an MoE model's expert counts
                 # come with the tokens
                 out = nxt
@@ -1893,6 +2010,7 @@ class ServingEngine:
         # last reference this was return to the free list
         for p in st.pages:
             self._drop_page(p)
+        self._give_ring(slot)
         self._slots[slot] = None
         self._active[slot] = False
         self._lengths[slot] = 0
@@ -1944,7 +2062,8 @@ class ServingEngine:
                     # greedy lane — the same program shape admissions use;
                     # the host fetch means the probe must really complete
                     np.asarray(self._exec.prefill(
-                        s_pad, jnp.asarray(self._page_table[slot:slot + 1]),
+                        s_pad, jax.tree_util.tree_map(jnp.asarray,
+                                                      self._tables(slot)),
                         jnp.asarray(toks), 1, 0, 0.0, 0, 1.0, 0))
         except BaseException as e:
             self._page_table[slot, :] = 0
@@ -1975,6 +2094,11 @@ class ServingEngine:
         for p in pages:
             self._quarantined_pages.remove(p)
         self._free_pages.extend(pages)
+        for p in self._ring_table[slot]:
+            if p:     # the fenced slot's ring comes back with its pages
+                self._quarantined_ring.remove(int(p))
+                self._free_ring.append(int(p))
+        self._ring_table[slot] = 0
         self.unfence_count += 1
         logger.info(
             "serve: slot %d passed its canary probe after quarantine; "
@@ -2023,6 +2147,11 @@ class ServingEngine:
                 if rid_map is not None:
                     # tick span carries the slot→rid map it decoded under
                     sp.set(slot_rids=rid_map)
+                    if self._ring:
+                        # pages of each kind that hold a request's K/V
+                        sp.set(pages_full=int((self._refcount[1:] > 0).sum()),
+                               pages_window=int(
+                                   (self._ring_table[self._active] > 0).sum()))
                 self._decode_tick(rid_map)
                 # refill slots the decode just retired — the queue head
                 # starts its prefill this tick instead of idling one

@@ -195,9 +195,9 @@ class CausalLM:
     #    kv_dtype="int8" narrows the pool's at-rest representation (per-page
     #    scale planes ride in the cache dict); None = compute dtype --
     def init_paged_cache(self, num_pages, page_size=PAGE_SIZE, dtype=None,
-                         kv_dtype=None):
+                         kv_dtype=None, window_pages=None):
         return init_paged_cache(self.config, num_pages, page_size, dtype,
-                                kv_dtype=kv_dtype)
+                                kv_dtype=kv_dtype, window_pages=window_pages)
 
     def paged_cache_specs(self, kv_dtype=None):
         return paged_cache_specs(self.config, kv_dtype=kv_dtype)

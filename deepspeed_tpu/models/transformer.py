@@ -86,6 +86,25 @@ class TransformerConfig:
     # WHOLE q and k projections, before the head split and the rotary
     # embedding; two more leaves a layer (q_norm_scale, k_norm_scale)
     qk_norm: bool = False
+    # Two kinds of attention layer in one model (MiMo-V2): a tuple of
+    # "full"/"window" per layer (the first ``num_layers`` entries are used:
+    # a cut in depth keeps the published pattern).  A window layer sees the last
+    # ``window_size`` positions and may have its own KV head count, its own
+    # rotary theta and a learned sink (one logit a query head that takes
+    # probability in the softmax and gives no value).  The layers are then
+    # stacked by kind (:func:`layer_groups`) and run in the published order;
+    # the paged cache holds a pool per kind, a window layer's a ring of
+    # :func:`window_ring_pages` pages a slot.
+    layer_pattern: Optional[tuple] = None
+    window_kv_heads: Optional[int] = None     # None => num_kv_heads
+    window_rope_theta: Optional[float] = None  # None => rope_theta
+    window_attn_sink: bool = False
+    # values narrower (or wider) than keys, and scaled after the projection
+    v_head_dim: Optional[int] = None          # None => head_dim
+    attn_value_scale: float = 1.0
+    # the first ``dense_layers`` layers keep a dense MLP (of width
+    # ``intermediate_size``) in a model whose other layers are expert layers
+    dense_layers: int = 0
     tie_embeddings: bool = False
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -106,6 +125,22 @@ class TransformerConfig:
     # keeps the k largest softmax probabilities as they are (OLMoE
     # ``norm_topk_prob=false``).  Top-1 never renormalises.
     moe_norm_topk_prob: bool = True
+    # an expert's width where it is not the dense MLP's (None =>
+    # intermediate_size)
+    moe_intermediate_size: Optional[int] = None
+    # the router's scores: "softmax" over all experts, or "sigmoid" of each
+    # logit; ``moe_select_bias`` adds a learned per-expert bias
+    # (``router_bias``) to the scores for the CHOICE of the k experts and
+    # not to the gates
+    moe_score_func: str = "softmax"
+    moe_select_bias: bool = False
+    # one chip's share of the experts: the router keeps ``num_experts``
+    # outputs and ``moe_top_k`` a token, the expert stacks hold
+    # ``moe_experts_held`` experts from ``moe_expert_first`` on, and a pair
+    # whose expert is elsewhere is computed by no one here (the dropless
+    # path only).  None: every expert is here.
+    moe_experts_held: Optional[int] = None
+    moe_expert_first: int = 0
     # residual MoE (PR-MoE, reference moe/layer.py use_residual): each MoE
     # layer also runs a dense MLP; outputs mix via a learned 2-way coefficient
     moe_use_residual: bool = False
@@ -154,14 +189,30 @@ class TransformerConfig:
         return self.head_dim or self.hidden_size // self.num_heads
 
     @property
+    def v_dims_per_head(self) -> int:
+        return self.v_head_dim or self.dims_per_head
+
+    @property
     def param_count(self) -> int:
+        if self.layer_pattern is not None:
+            # the parts outside the layers once, each group's layers beside
+            outside = dataclasses.replace(
+                self, layer_pattern=None, dense_layers=0,
+                num_layers=0).param_count
+            return outside + sum(
+                g.param_count - outside for g, _ in layer_groups(self).values())
         d, f, v, L = self.hidden_size, self.intermediate_size, self.vocab_size, self.num_layers
         hd, nh, nkv = self.dims_per_head, self.num_heads, self.kv_heads
-        attn = d * hd * nh + 2 * d * hd * nkv + hd * nh * d
+        vd = self.v_dims_per_head
+        attn = d * hd * nh + d * hd * nkv + d * vd * nkv + vd * nh * d
         if self.attn_bias:
-            attn += nh * hd + 2 * nkv * hd + d
+            attn += nh * hd + nkv * hd + nkv * vd + d
         if self.qk_norm:
             attn += nh * hd + nkv * hd
+        if self.window_attn_sink:
+            attn += nh
+        if self.moe_intermediate_size and self.num_experts != 1:
+            f = self.moe_intermediate_size
         mlp = 3 * d * f if self.activation == "swiglu" else 2 * d * f
         if self.mlp_bias:
             mlp += (2 * f if self.activation == "swiglu" else f) + d
@@ -172,7 +223,10 @@ class TransformerConfig:
         for E in experts:
             m = mlp
             if E > 1:
-                m = mlp * E + d * E  # experts + router
+                # the experts held here + the router at its full width
+                m = mlp * (self.moe_experts_held or E) + d * E
+                if self.moe_select_bias:
+                    m += E
                 if self.moe_use_residual:
                     m += mlp + 2 * d  # dense residual branch + coefficient
             total_mlp += m
@@ -237,6 +291,26 @@ CONFIGS: Dict[str, TransformerConfig] = {
         num_layers=16, num_heads=16, max_seq_len=4096, norm_eps=1e-5,
         rope_theta=10000.0, qk_norm=True, num_experts=64, moe_top_k=8,
         moe_norm_topk_prob=False, moe_drop_tokens=False),
+    # XiaomiMiMo/MiMo-V2.5 config.json (``mimo_v2``, the language model):
+    # 48 layers, 9 of full attention (64 heads over 4 KV heads, theta 1e7)
+    # and 39 of a 128-wide sliding window (64 over 8, theta 1e4, a learned
+    # sink), keys 192 wide with rotary on the leading 64, values 128 wide
+    # and scaled by 0.707; layer 0 a dense SwiGLU of width 16,384, the rest
+    # 256 experts of width 2,048, sigmoid scores, 8 a token chosen on score
+    # + bias, gates renormalised over the chosen
+    "mimo-v2.5": TransformerConfig(
+        vocab_size=152576, hidden_size=4096, intermediate_size=16384,
+        moe_intermediate_size=2048,
+        num_layers=48, num_heads=64, num_kv_heads=4, head_dim=192,
+        v_head_dim=128, max_seq_len=1048576, norm_eps=1e-5,
+        rope_theta=1e7, rotary_dim=64, attn_value_scale=0.707,
+        layer_pattern=tuple("full" if i in (0, 5, 11, 17, 23, 29, 35, 41, 47)
+                            else "window" for i in range(48)),
+        window_size=128, window_kv_heads=8, window_rope_theta=1e4,
+        window_attn_sink=True, dense_layers=1,
+        num_experts=256, moe_top_k=8, moe_score_func="sigmoid",
+        moe_select_bias=True, moe_norm_topk_prob=True, moe_drop_tokens=False,
+        remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -263,6 +337,9 @@ CONFIGS: Dict[str, TransformerConfig] = {
 
 def get_config(name_or_cfg, **overrides) -> TransformerConfig:
     cfg = CONFIGS[name_or_cfg] if isinstance(name_or_cfg, str) else name_or_cfg
+    # a list (a JSON file's) is the tuple the frozen config holds
+    overrides = {k: tuple(v) if isinstance(v, list) else v
+                 for k, v in overrides.items()}
     return dataclasses.replace(cfg, **overrides) if overrides else cfg
 
 
@@ -289,12 +366,78 @@ _EXPERT_LEAVES = ("w_gate", "w_up", "w_in", "w_down", "b_in", "b_down")
 
 def expert_counts_shape(cfg) -> Optional[Tuple[int, int]]:
     """``(layers, experts)`` of the counts ``forward_paged(expert_counts=
-    True)`` hands back: every layer a dropless expert layer.  None for any
-    other model (dense, capacity buffers, a per-layer pyramid)."""
+    True)`` hands back: the dropless expert layers (every layer but the
+    leading dense ones) and the experts held here.  None for any other
+    model (dense, capacity buffers, a per-layer pyramid)."""
     experts = getattr(cfg, "num_experts", 1)
     if isinstance(experts, int) and experts > 1 and not cfg.moe_drop_tokens:
-        return cfg.num_layers, experts
+        return (cfg.num_layers - cfg.dense_layers,
+                cfg.moe_experts_held or experts)
     return None
+
+
+def is_hybrid(cfg: TransformerConfig) -> bool:
+    """Layers of two kinds (``layer_pattern``): stacks grouped by kind."""
+    return cfg.layer_pattern is not None
+
+
+def window_ring_pages(window: int, page_size: int) -> int:
+    """Pages of a window layer's ring, a slot: what ``window`` consecutive
+    positions can span, and one being written.  Position ``p`` lives in
+    ring page ``(p // page_size) % ring``."""
+    return -(-window // page_size) + 1
+
+
+def pool_leaf_head_major(kv_heads: int, width: int) -> bool:
+    """Whether a leaf of a per-kind pool is kept ``[L, P, Hkv, page,
+    width]`` and not ``[L, P, page, Hkv, width]``.  The TPU tiles an array's
+    two minor-most axes 8 x 128: under fewer than 8 KV heads of whole-lane
+    width the heads are a partial tile, and the compiler copies such a pool
+    into the head-major order before every page scatter and back after it
+    (2 x 1 GB a tick for MiMo's 4 x 128 values; PERF.md, PR 30).  Kept
+    head-major from the start it is updated where it lies."""
+    return kv_heads < 8 and width % 128 == 0
+
+
+def layer_plan(cfg: TransformerConfig):
+    """A hybrid model's layers in the published order: ``(group, index in
+    the group, kind, dense)`` each, ``group`` = ``<kind>_<dense|moe>``."""
+    if len(cfg.layer_pattern) < cfg.num_layers:
+        raise ValueError(
+            f"layer_pattern has {len(cfg.layer_pattern)} entries for "
+            f"{cfg.num_layers} layers")
+    plan, seen = [], {}
+    # a model cut in depth runs the first layers of the published pattern
+    for i, kind in enumerate(cfg.layer_pattern[:cfg.num_layers]):
+        if kind not in ("full", "window"):
+            raise ValueError(f"layer_pattern[{i}] = {kind!r}: full | window")
+        dense = i < cfg.dense_layers or not has_moe(cfg)
+        group = f"{kind}_{'dense' if dense else 'moe'}"
+        plan.append((group, seen.get(group, 0), kind, dense))
+        seen[group] = seen.get(group, 0) + 1
+    return plan
+
+
+def layer_groups(cfg: TransformerConfig):
+    """``{group: (the uniform config of its layers, how many)}`` of a hybrid
+    model, in order of first appearance: each group is a plain stack that
+    :func:`init_params`, :func:`param_specs` and :func:`_block` take as they
+    take any model's, with the kind's KV heads, theta, sink and MLP."""
+    groups: Dict[str, Any] = {}
+    for group, index, kind, dense in layer_plan(cfg):
+        window = kind == "window"
+        groups[group] = (dataclasses.replace(
+            cfg, layer_pattern=None, dense_layers=0, num_layers=index + 1,
+            num_kv_heads=(cfg.window_kv_heads if window
+                          and cfg.window_kv_heads else cfg.num_kv_heads),
+            rope_theta=(cfg.window_rope_theta if window
+                        and cfg.window_rope_theta else cfg.rope_theta),
+            window_attn_sink=window and cfg.window_attn_sink,
+            moe_intermediate_size=None if dense else cfg.moe_intermediate_size,
+            num_experts=1 if dense else cfg.num_experts,
+            moe_experts_held=None if dense else cfg.moe_experts_held),
+            index + 1)
+    return groups
 
 
 def layer_windows(cfg: TransformerConfig) -> Optional[jax.Array]:
@@ -319,6 +462,17 @@ def _sm_scale(cfg: TransformerConfig, hd: int) -> float:
 # Parameters
 # ---------------------------------------------------------------------------
 
+def kind_layers(cfg: TransformerConfig):
+    """``{kind: (a group config of the kind, its layers in the model)}`` of a
+    hybrid model: what a kind's K/V pool is shaped by (KV heads, widths,
+    which every group of one kind shares) and how many layers it holds."""
+    kinds: Dict[str, Any] = {}
+    for name, (g, n) in layer_groups(cfg).items():
+        kind = name.split("_")[0]
+        kinds[kind] = (g, kinds.get(kind, (g, 0))[1] + n)
+    return kinds
+
+
 def _check_qk_norm(cfg: TransformerConfig) -> None:
     if cfg.norm != "rmsnorm":
         raise NotImplementedError(
@@ -332,10 +486,21 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     differ per layer, so there is nothing to scan."""
     if isinstance(cfg.num_experts, (tuple, list)):
         return _init_params_het(cfg, rng)
+    if is_hybrid(cfg):
+        # the parts outside the layers from a one-layer model of the first
+        # group, then each group's own stack: ``params["layers"][group]``
+        groups = layer_groups(cfg)
+        first = next(iter(groups.values()))[0]
+        params = init_params(dataclasses.replace(first, num_layers=1), rng)
+        params["layers"] = {
+            name: init_params(g, jax.random.fold_in(rng, i + 1))["layers"]
+            for i, (name, (g, _)) in enumerate(groups.items())}
+        return params
     d, f = cfg.hidden_size, cfg.intermediate_size
     hd, nh, nkv, L = cfg.dims_per_head, cfg.num_heads, cfg.kv_heads, cfg.num_layers
+    vd = cfg.v_dims_per_head
     std = cfg.initializer_range
-    keys = jax.random.split(rng, 16)
+    keys = jax.random.split(rng, 18)
 
     def dense(key, shape, scale=std):
         return (jax.random.normal(key, shape, jnp.float32) * scale)
@@ -344,14 +509,19 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         "attn_norm_scale": jnp.ones((L, d)),
         "wq": dense(keys[0], (L, d, nh * hd)),
         "wk": dense(keys[1], (L, d, nkv * hd)),
-        "wv": dense(keys[2], (L, d, nkv * hd)),
+        "wv": dense(keys[2], (L, d, nkv * vd)),
         # residual-path projections scaled down by sqrt(2L) (GPT-2 init)
-        "wo": dense(keys[3], (L, nh * hd, d), std / math.sqrt(2 * L)),
+        "wo": dense(keys[3], (L, nh * vd, d), std / math.sqrt(2 * L)),
     }
     if cfg.qk_norm:
         _check_qk_norm(cfg)
         layers["q_norm_scale"] = jnp.ones((L, nh * hd))
         layers["k_norm_scale"] = jnp.ones((L, nkv * hd))
+    if cfg.window_attn_sink:
+        # one logit a query head, of the order of a score between two
+        # tokens at these weights (std^2 * d), so that it takes a real
+        # share of a row's probability: a checkpoint learns it
+        layers["attn_sink"] = dense(keys[16], (L, nh), std * std * d)
     if not cfg.shared_layernorm:   # GPT-J shares the attention LN
         layers["mlp_norm_scale"] = jnp.ones((L, d))
     if cfg.norm == "layernorm":
@@ -359,13 +529,36 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         if not cfg.shared_layernorm:
             layers["mlp_norm_bias"] = jnp.zeros((L, d))
     E = cfg.num_experts
-    mlp_shape = (lambda *s: (L, E) + s) if E > 1 else (lambda *s: (L,) + s)
+    if E > 1 and cfg.moe_intermediate_size:
+        f = cfg.moe_intermediate_size
+    held = cfg.moe_experts_held or E        # the expert stacks' own count
+    mlp_shape = (lambda *s: (L, held) + s) if E > 1 else (lambda *s: (L,) + s)
     if E > 1:
         # per-expert biases supported on the gelu/relu path (Megatron-DS MoE
         # experts are biased Linears); swiglu experts stay bias-free
         assert not (cfg.mlp_bias and cfg.activation == "swiglu"), \
             "swiglu MoE experts do not support mlp_bias"
         layers["router"] = dense(keys[10], (L, d, E))
+        if cfg.moe_select_bias:
+            # about a fifth of the spread of the scores across experts at
+            # these weights (router logits have std * sqrt(d)): it moves
+            # the choice for some tokens and fixes it for none.  Near the
+            # top-k threshold an expert's load goes as exp(~17 x its bias
+            # / the scores' spread x 0.27), a factor of two a standard
+            # deviation, so independent draws would give a share of 16
+            # experts a quarter more or less load from one seed to the
+            # next (PERF.md, PR 30).  The values are therefore the
+            # quantiles of that normal over one share's experts
+            # (``moe_experts_held``, else all), laid over every share in an
+            # order of its own from the seed: every share of the experts,
+            # under every seed, holds the same biases.
+            n = held if E % held == 0 else E
+            quantiles = jax.scipy.special.ndtri(
+                (jnp.arange(n, dtype=jnp.float32) + 0.5) / n)
+            order = jax.vmap(lambda key: jax.random.permutation(key, n))(
+                jax.random.split(keys[17], L * (E // n)))
+            layers["router_bias"] = (quantiles[order].reshape(L, E)
+                                     * (0.04 * std * math.sqrt(d)))
     if cfg.activation == "swiglu":
         layers["w_gate"] = dense(keys[4], mlp_shape(d, f))
         layers["w_up"] = dense(keys[5], mlp_shape(d, f))
@@ -516,6 +709,12 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     The ZeRO planner composes ('data','expert') on top of these."""
     if isinstance(cfg.num_experts, (tuple, list)):
         return _param_specs_het(cfg)
+    if is_hybrid(cfg):
+        groups = layer_groups(cfg)
+        specs = param_specs(next(iter(groups.values()))[0])
+        specs["layers"] = {name: param_specs(g)["layers"]
+                           for name, (g, _) in groups.items()}
+        return specs
     col = P(None, None, "model")     # [L, d, f_shard]
     row = P(None, "model", None)     # [L, f_shard, d]
     rep = P(None, None)
@@ -526,6 +725,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     if cfg.qk_norm:     # over the column-parallel projection, as bq / bk
         layers.update(q_norm_scale=P(None, "model"),
                       k_norm_scale=P(None, "model"))
+    if cfg.window_attn_sink:
+        layers["attn_sink"] = P(None, "model")
     if not cfg.shared_layernorm:
         layers["mlp_norm_scale"] = rep
     if cfg.norm == "layernorm":
@@ -538,6 +739,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         mcol = P(None, "expert", None, "model")   # [L, E, d, f_shard]
         mrow = P(None, "expert", "model", None)   # [L, E, f_shard, d]
         layers["router"] = P(None, None, None)
+        if cfg.moe_select_bias:
+            layers["router_bias"] = P(None, None)
     else:
         mcol, mrow = col, row
     if cfg.activation == "swiglu":
@@ -760,11 +963,13 @@ def _sharded_flash(mesh, spec, sm_scale, q, k, v):
 
 
 def _attention(cfg: TransformerConfig, q, k, v, positions, attn_impl: str = "xla",
-               custom_positions: bool = False, window=None):
+               custom_positions: bool = False, window=None, sink=None):
     """q:[B,S,Hq,hd] k,v:[B,S,Hkv,hd] -> [B,S,Hq,hd], causal.
 
     ``window``: traced per-layer scalar (0 = global) — local layers mask
-    keys older than ``window`` positions; rides the masked XLA path only."""
+    keys older than ``window`` positions; rides the masked XLA path only.
+    ``sink [Hq]``: a learned logit a head that joins each row's softmax as
+    one more column and gives no value (the masked XLA path only)."""
     B, S, Hq, hd = q.shape
     Hkv = k.shape[2]
     # Sequence-parallel mesh: ring attention keeps queries resident and
@@ -905,8 +1110,21 @@ def _attention(cfg: TransformerConfig, q, k, v, positions, attn_impl: str = "xla
         rel = positions[:, None, :, None] - positions[:, None, None, :]
         local_ok = (window <= 0) | (rel < window)
         scores = jnp.where(local_ok, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    probs = _softmax_with_sink(
+        scores, None if sink is None
+        else sink.astype(jnp.float32)[None, :, None, None]).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _softmax_with_sink(scores, sink):
+    """Softmax over the last axis of float32 ``scores``; ``sink`` (shaped to
+    broadcast against ``scores[..., :1]``, or None) is one more logit a row
+    that takes its share of the probability and has no column of its own."""
+    if sink is None:
+        return jax.nn.softmax(scores, axis=-1)
+    m = jnp.maximum(scores.max(-1, keepdims=True), sink)
+    p = jnp.exp(scores - m)
+    return p / (p.sum(-1, keepdims=True) + jnp.exp(sink - m))
 
 
 def _alibi_bias(cfg, positions, num_heads, S, dtype):
@@ -955,6 +1173,10 @@ def _dense_mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, prefix=""):
     return m
 
 
+# Tokens of one prompt a dropless expert layer takes at a time
+MOE_CHUNK_TOKENS = 2048
+
+
 def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
          token_mask=None, expert_offset=None):
     """The MLP or expert layer of :func:`_block`: returns (output,
@@ -972,18 +1194,41 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
         if "router" in lp:
             from ..moe.sharded_moe import MoEConfig, moe_ffn
 
-            m, aux, counts = moe_ffn(
-                h, lp["router"], lp,
-                MoEConfig(num_experts=int(lp["router"].shape[-1]),
-                          top_k=cfg.moe_top_k,
-                          capacity_factor=cfg.capacity_factor,
-                          eval_capacity_factor=cfg.eval_capacity_factor,
-                          min_capacity=cfg.moe_min_capacity,
-                          noisy_gate_policy=cfg.noisy_gate_policy,
-                          drop_tokens=cfg.moe_drop_tokens,
-                          norm_topk_prob=cfg.moe_norm_topk_prob),
-                activation=cfg.activation, deterministic=deterministic, rng=rng,
-                token_mask=token_mask, expert_offset=expert_offset)
+            moe = MoEConfig(num_experts=int(lp["router"].shape[-1]),
+                            top_k=cfg.moe_top_k,
+                            capacity_factor=cfg.capacity_factor,
+                            eval_capacity_factor=cfg.eval_capacity_factor,
+                            min_capacity=cfg.moe_min_capacity,
+                            noisy_gate_policy=cfg.noisy_gate_policy,
+                            drop_tokens=cfg.moe_drop_tokens,
+                            norm_topk_prob=cfg.moe_norm_topk_prob,
+                            score_func=cfg.moe_score_func,
+                            held=((cfg.moe_expert_first, cfg.moe_experts_held)
+                                  if cfg.moe_experts_held else None))
+
+            def experts(hc, mask):
+                return moe_ffn(
+                    hc, lp["router"], lp, moe, activation=cfg.activation,
+                    deterministic=deterministic, rng=rng, token_mask=mask,
+                    expert_offset=expert_offset,
+                    select_bias=lp.get("router_bias"))
+
+            B, S, D = h.shape
+            n = S // MOE_CHUNK_TOKENS
+            if (B == 1 and n > 1 and S % MOE_CHUNK_TOKENS == 0
+                    and not cfg.moe_drop_tokens):
+                # a long prompt a chunk at a time: the sorted rows, their
+                # products and the way back are top_k x the chunk, not
+                # top_k x the prompt (8 x 16,384 rows of 4,096 are 1 GB
+                # each time they are written)
+                mask = (token_mask if token_mask is not None
+                        else jnp.ones((B, S), bool))
+                m, aux, counts = jax.lax.map(
+                    lambda a: experts(a[0][None], a[1][None]),
+                    (h.reshape(n, -1, D), mask.reshape(n, -1)))
+                m, aux, counts = m.reshape(B, S, D), aux.mean(), counts.sum(0)
+            else:
+                m, aux, counts = experts(h, token_mask)
             if "coefficient" in lp:
                 # residual MoE (reference moe/layer.py:16 use_residual): dense
                 # branch + learned softmax mixing coefficient
@@ -998,8 +1243,9 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
 
 
 def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
-    """Post-norm activations ``h [B,S,d]`` -> ``q [B,S,Hq,hd]``, ``k``,
-    ``v [B,S,Hkv,hd]``, biased, QK-normed and rotated.  ``proj(y, name,
+    """Post-norm activations ``h [B,S,d]`` -> ``q [B,S,Hq,hd]``, ``k
+    [B,S,Hkv,hd]``, ``v [B,S,Hkv,vd]``, biased, QK-normed, rotated, the
+    values scaled.  ``proj(y, name,
     hin)``, when given, adds the serving path's per-slot adapter delta
     (:func:`_adapter_proj`)."""
     B, S, _ = h.shape
@@ -1015,7 +1261,9 @@ def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
             k = _norm(cfg, k, lp["k_norm_scale"])
         q = q.reshape(B, S, nh, hd)
         k = k.reshape(B, S, nkv, hd)
-        v = v.reshape(B, S, nkv, hd)
+        v = v.reshape(B, S, nkv, cfg.v_dims_per_head)
+        if cfg.attn_value_scale != 1.0:
+            v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
         if cfg.position == "rope":
             q, k = _rope(q, k, positions, cfg.rope_theta, hd,
                          rotary_dim=cfg.rotary_dim,
@@ -1102,13 +1350,14 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
 
 
 def _attend_full(cfg: TransformerConfig, positions, attn_impl: str = "xla",
-                 custom_positions: bool = False, window=None):
+                 custom_positions: bool = False, window=None, sink=None):
     """:func:`_block`'s ``attend`` over the block's own tokens (training,
     the uncached forward): nothing is kept."""
     def attend(q, k, v):
         with jax.named_scope("attn"):
             return _attention(cfg, q, k, v, positions, attn_impl,
-                              custom_positions, window=window), None
+                              custom_positions, window=window,
+                              sink=sink), None
     return attend
 
 
@@ -1193,6 +1442,28 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
     # activations: batch over DP axes, sequence over 'seq' axis
     act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
     x = constrain_spec(x, act_spec)
+
+    if is_hybrid(cfg):
+        # layers of two kinds, inference only: each layer its group's
+        # uniform config and its own slice of the group's stack, through the
+        # masked product (window, sink, values of their own width)
+        if not deterministic or pld_theta is not None:
+            _hybrid_refuse("training (dropout, layer drop, a backward pass)")
+        if attn_impl not in ("xla", "auto"):
+            _hybrid_refuse(f"the flash kernel (attn_impl={attn_impl!r}: keys "
+                           "and values of one width, no sink)")
+        groups = layer_groups(cfg)
+        for group, index, kind, _ in layer_plan(cfg):
+            lp = jax.tree_util.tree_map(lambda a: a[index],
+                                        params["layers"][group])
+            x = _block(groups[group][0], lp, x, positions, rng, _attend_full(
+                cfg, positions, "xla", custom_positions,
+                window=cfg.window_size if kind == "window" else None,
+                sink=lp.get("attn_sink")))[0]
+            x = constrain_spec(x, act_spec)
+        logits = _head(cfg, params, x)
+        return (logits, {"moe_aux_loss": jnp.float32(0.0)}) if return_aux \
+            else logits
 
     block = _build_block(cfg, attn_impl, deterministic, custom_positions)
     step = functools.partial(_layer_step, block)
@@ -1462,6 +1733,8 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
     exactly twice.
     """
     _check_decodable(cfg, params, "cached decode")
+    if is_hybrid(cfg):
+        _hybrid_refuse("the contiguous cache (forward_cached, generate())")
     B, S = tokens.shape
     next_slot = cache["next_slot"]
 
@@ -1517,7 +1790,10 @@ KV_QUANT_DTYPES = ("int8",)
 # threads through every program (k/v always; the scale planes only when the
 # pool is quantized).  Keeping the order fixed is what lets one generic
 # program body serve both pool layouts with a stable donation index.
-PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale")
+# A model with window layers (``layer_pattern``) keeps a pool per kind of
+# layer: ``k``/``v`` are its full layers', ``k_window``/``v_window`` its
+# window layers' rings, each leaf with its kind's KV heads and its own width.
+PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window")
 
 
 def paged_pool_tuple(cache: Dict[str, Any]) -> tuple:
@@ -1526,9 +1802,10 @@ def paged_pool_tuple(cache: Dict[str, Any]) -> tuple:
     return tuple(cache[k] for k in PAGED_POOL_KEYS if k in cache)
 
 
-def paged_pool_cache(pools) -> Dict[str, Any]:
-    """Inverse of :func:`paged_pool_tuple`."""
-    return dict(zip(PAGED_POOL_KEYS, pools))
+def paged_pool_cache(pools, keys=PAGED_POOL_KEYS) -> Dict[str, Any]:
+    """Inverse of :func:`paged_pool_tuple`; ``keys``: the leaves the tuple
+    was made of, where they are not the leading ones of the order."""
+    return dict(zip(keys, pools))
 
 
 def _normalize_kv_dtype(kv_dtype):
@@ -1545,7 +1822,8 @@ def _normalize_kv_dtype(kv_dtype):
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      page_size: int = PAGE_SIZE, dtype=None,
-                     kv_dtype=None) -> Dict[str, Any]:
+                     kv_dtype=None, window_pages: Optional[int] = None
+                     ) -> Dict[str, Any]:
     """Allocate the physical page pool: ``k``/``v`` are
     ``[L, num_pages, page_size, Hkv, hd]``.
 
@@ -1577,10 +1855,36 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     page; only its at-rest representation narrows.
     """
     dtype = dtype or cfg.dtype
-    kv = (cfg.num_layers, num_pages, page_size, cfg.kv_heads,
-          cfg.dims_per_head)
+    if is_hybrid(cfg):
+        # a pool per kind of layer: the full layers' ``k``/``v`` over
+        # ``num_pages`` pages, the window layers' ``k_window``/``v_window``
+        # over ``window_pages`` (each slot a ring of
+        # :func:`window_ring_pages`; page 0 the trash page of its own pool)
+        if _normalize_kv_dtype(kv_dtype) is not None:
+            raise NotImplementedError(
+                "the int8 pool does not support a model with window layers "
+                "(layer_pattern): its scale planes are one pool's")
+        kinds = kind_layers(cfg)
+        cache = {}
+        for kind, suffix, pages in (
+                ("full", "", num_pages),
+                ("window", "_window",
+                 num_pages if window_pages is None else window_pages)):
+            if kind in kinds:
+                g, layers = kinds[kind]
+                for n, w in (("k", g.dims_per_head),
+                             ("v", g.v_dims_per_head)):
+                    rows = ((g.kv_heads, page_size)
+                            if pool_leaf_head_major(g.kv_heads, w)
+                            else (page_size, g.kv_heads))
+                    cache[n + suffix] = jnp.zeros(
+                        (layers, pages) + rows + (w,), dtype)
+        return cache
+    lead = (cfg.num_layers, num_pages, page_size, cfg.kv_heads)
+    kv = lead + (cfg.dims_per_head,)
     if _normalize_kv_dtype(kv_dtype) is None:
-        return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
+        return {"k": jnp.zeros(kv, dtype),
+                "v": jnp.zeros(lead + (cfg.v_dims_per_head,), dtype)}
     sc = (cfg.num_layers, num_pages, page_size)
     return {"k": jnp.zeros(kv, jnp.int8), "v": jnp.zeros(kv, jnp.int8),
             "k_scale": jnp.zeros(sc, jnp.float32),
@@ -1593,6 +1897,8 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
     scale planes ``[L, P, page]`` have no head dim, so they ride replicated
     alongside their (page-replicated) int8 payload."""
     kv = P(None, None, None, "model", None)
+    if is_hybrid(cfg):
+        return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
     if _normalize_kv_dtype(kv_dtype) is None:
         return {"k": kv, "v": kv}
     sc = P(None, None, None)
@@ -1726,8 +2032,8 @@ def paged_pool_order(leaf: jax.Array) -> Optional[Tuple[int, ...]]:
 def _pool_views(pools, pool_order):
     """``(views, axes)``: each K/V leaf ``[N, page, Hkv, hd]`` with its
     trailing axes in the order the device stores them (``pool_order``, of
-    the unstacked leaf), and that order as einsum letters (``t`` page row,
-    ``k`` head, ``d`` head dim).  A computation nested two deep (the read's
+    the unstacked leaf: one order, or ``{leaf: order}``), and each leaf's
+    order as einsum letters (``t`` page row, ``k`` head, ``d`` head dim).  A computation nested two deep (the read's
     loop inside the layer scan) takes its operands row-major in their
     logical shape, so the pool enters it as the view whose row-major order
     is the bytes as they lie: a transpose that moves nothing.  Handed the
@@ -1735,15 +2041,22 @@ def _pool_views(pools, pool_order):
     front of every read (a 64-wide head puts the page rows minor-most on
     the TPU, PERF.md PR 25 and PR 27).  The scale planes of a quantized
     pool ``[N, page]`` go as they are."""
-    perm = (0, 1, 2, 3)
-    if pool_order is not None and tuple(pool_order[:2]) == (0, 1):
-        perm = (0,) + tuple(a - 1 for a in pool_order[2:])
-    views = {n: jnp.transpose(a, perm) if a.ndim == 4 else a
-             for n, a in pools.items()}
-    return views, "".join(" tkd"[a] for a in perm[1:])
+    views, axes = {}, {}
+    for n, a in pools.items():
+        # one observed order for every leaf, or one a leaf (K and V of
+        # different widths may be stored differently)
+        order = pool_order.get(n) if isinstance(pool_order, dict) \
+            else pool_order
+        perm = (0, 1, 2, 3)
+        if order is not None and tuple(order[:2]) == (0, 1):
+            perm = (0,) + tuple(i - 1 for i in order[2:])
+        views[n] = jnp.transpose(a, perm) if a.ndim == 4 else a
+        if a.ndim == 4:
+            axes[n] = "".join(" tkd"[i] for i in perm[1:])
+    return views, axes
 
 
-def _attention_paged(cfg, q, pools, read, pool_order=None):
+def _attention_paged(cfg, q, pools, read, pool_order=None, sink=None):
     """q:[B,S,Hq,hd] against the call's live pages, ``read`` =
     :func:`_paged_read_plan`'s flat list of (slot, page) pairs with the
     pages moved to this layer's: a step of the loop gathers the whole pages
@@ -1779,10 +2092,18 @@ def _attention_paged(cfg, q, pools, read, pool_order=None):
     compiler then does to the whole pool (PERF.md, PR 25).  The pool is
     only ever gathered from, whole pages at a time, so it stays where the
     layer scan carries it.
+
+    A window layer's ``read`` (:func:`_ring_read_plan`) carries a fifth
+    member, ``low``: the first row of each pair's page its queries may see
+    (rows ``low <= r <= limit`` pass: the window).  ``sink [Hq]`` is a
+    learned logit a head that joins each row's softmax at the end of the
+    walk, one more term of the sum that adds nothing to the accumulator.
     """
-    steps, slot, pages, limit = read
+    steps, slot, pages, limit = read[:4]
+    low = read[4] if len(read) > 4 else None
     B, S, Hq, hd = q.shape
     ps, Hkv = pools["k"].shape[1], pools["k"].shape[2]
+    vd = pools["v"].shape[3]
     N = slot.shape[1]
     G = Hq // Hkv
     qg = q.reshape(B, S, Hkv, G, hd)
@@ -1822,7 +2143,7 @@ def _attention_paged(cfg, q, pools, read, pool_order=None):
             if "k_scale" in views:
                 # dequantize inside the gather: the narrow representation
                 # is what crosses HBM; attention sees compute-dtype values
-                along = tuple(-1 if c == "t" else 1 for c in axes)
+                along = tuple(-1 if c == "t" else 1 for c in axes["k"])
                 ck, cv = (
                     kv_dequantize(c, views[n][pg].reshape(N, *along),
                                   cfg.dtype)
@@ -1830,7 +2151,7 @@ def _attention_paged(cfg, q, pools, read, pool_order=None):
         # each pair's own slot's queries; one slot's are every pair's
         qn = (qg[jnp.minimum(at, B - 1)] if B > 1
               else jnp.broadcast_to(qg, (N,) + qg.shape[1:]))
-        scores = jnp.einsum(f"nskgd,n{axes}->kgsnt", qn, ck)
+        scores = jnp.einsum(f"nskgd,n{axes['k']}->kgsnt", qn, ck)
         scores = scores.astype(jnp.float32) * _sm_scale(cfg, hd)
         lim_sn = lim.T                                          # [S,N]
         if slopes is not None:
@@ -1838,24 +2159,33 @@ def _attention_paged(cfg, q, pools, read, pool_order=None):
             scores = scores - (jnp.abs(rel)[None, None]
                                * slopes[:, :, None, None, None])
         ok = r[None, None, :] <= lim_sn[:, :, None]              # [S,N,ps]
+        if low is not None:
+            ok = ok & (r[None, None, :] >= low[i].T[:, :, None])
         scores = jnp.where(ok[None, None], scores, -1e30)
         m_new = jnp.maximum(m, in_slot(scores.max(-1), -1e30).max(-1))
         p = jnp.exp(scores - of_slot(m_new)[..., None])
         alpha = jnp.exp(m - m_new)
         l = l * alpha + in_slot(p.sum(-1), 0.0).sum(-1)
-        pv = jnp.einsum(f"bkgsnt,n{axes}->bskgd",
+        pv = jnp.einsum(f"bkgsnt,n{axes['v']}->bskgd",
                         in_slot(p.astype(q.dtype), 0), cv)
         acc = (acc * jnp.moveaxis(alpha, 3, 1)[..., None]
                + pv.astype(jnp.float32))
         return m_new, l, acc
 
     m0 = jnp.full((B, Hkv, G, S), -1e30, jnp.float32)
-    _, l, acc = jax.lax.fori_loop(
+    m, l, acc = jax.lax.fori_loop(
         0, steps, step,
-        (m0, jnp.zeros_like(m0), jnp.zeros((B, S, Hkv, G, hd), jnp.float32)))
+        (m0, jnp.zeros_like(m0), jnp.zeros((B, S, Hkv, G, vd), jnp.float32)))
+    if sink is not None:
+        # the sink's term joins the sum under the larger of the two maxima
+        b = sink.astype(jnp.float32).reshape(1, Hkv, G, 1)
+        m_new = jnp.maximum(m, b)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.exp(b - m_new)
+        acc = acc * jnp.moveaxis(alpha, 3, 1)[..., None]
     # a slot that was not read has l == 0: its output is 0, not NaN
     out = acc / jnp.moveaxis(jnp.where(l > 0, l, 1.0), 3, 1)[..., None]
-    return out.astype(q.dtype).reshape(B, S, Hq, hd)
+    return out.astype(q.dtype).reshape(B, S, Hq, vd)
 
 
 def _adapter_delta(h, ab, scale):
@@ -1893,7 +2223,8 @@ def _adapter_proj(adapters, ad_scale):
     return proj
 
 
-def _attend_paged(cfg, pools, write, read, pool_order=None):
+def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
+                  within=None):
     """:func:`_block`'s ``attend`` against the paged pool, addressed a whole
     page at a time; the pool is what is kept.  ``pools`` maps each pool leaf
     (``k``/``v``, plus ``k_scale``/``v_scale`` on a quantized pool) to its
@@ -1916,7 +2247,16 @@ def _attend_paged(cfg, pools, write, read, pool_order=None):
     :func:`kv_quantize_rows`), merges its scale through the SAME plan, and
     dequantizes inside the gather — the scale planes are two more leaves of
     ``pools``, so the program shapes (and the zero-recompile inventory built
-    on them) are unchanged."""
+    on them) are unchanged.
+
+    A window layer: ``write`` is :func:`_ring_write_plan`'s (the block's
+    last pages into the slot's ring), ``sink`` the layer's learned sink
+    logits, and a block of more than one token attends ``within`` itself
+    (``(positions, window)``, :func:`_attention_window_block`) instead of
+    reading the ring, which cannot hold a prompt longer than the window.
+    ``within = (positions, None)``: a full layer of such a model, whose
+    block starts its slot too, attends within itself causally
+    (:func:`_attention_causal_block`) and reads nothing back."""
     src, keep, pages = write
 
     def merge(pool, rows):
@@ -1946,8 +2286,138 @@ def _attend_paged(cfg, pools, write, read, pool_order=None):
                 new[name] = constrain_spec(new[name],
                                            P(None, None, "model", None))
         with jax.named_scope("attn"):
-            return _attention_paged(cfg, q, new, read, pool_order), new
+            if within is not None:
+                positions, window = within
+                if window is None:
+                    return _attention_causal_block(cfg, q, k, v,
+                                                   positions), new
+                return _attention_window_block(cfg, q, k, v, positions,
+                                               window, sink), new
+            return _attention_paged(cfg, q, new, read, pool_order, sink), new
     return attend
+
+
+# Chunks of ``window`` queries a long block's window attention takes at a time
+WINDOW_BLOCK_CHUNKS = 16
+# Queries, and keys, a step of a long block's causal attention takes
+CAUSAL_BLOCK_CHUNK = 512
+
+
+def block_read_rows(block: int, window: Optional[int] = None) -> int:
+    """K/V rows a block of ``block`` tokens that starts its slot reads of
+    itself, a layer: through a full layer each chunk of queries the chunks
+    of keys at or before it (:func:`_attention_causal_block`), through a
+    window layer each chunk of ``window`` queries two chunks of keys
+    (:func:`_attention_window_block`); a short block all of itself, once.
+    The host's copy of those functions' shapes (the ``kv_rows_*`` span
+    attrs of a prompt)."""
+    if window is not None:
+        return 2 * block if block % window == 0 and block >= 2 * window \
+            else block
+    n = block // CAUSAL_BLOCK_CHUNK
+    if block % CAUSAL_BLOCK_CHUNK or n < 2:
+        return block
+    return CAUSAL_BLOCK_CHUNK * n * (n + 1) // 2
+
+
+def _attention_causal_block(cfg, q, k, v, positions):
+    """A block of tokens ``[B,S,...]`` that starts its slot, through a full
+    layer: plain causal attention over the block's own keys, values of
+    their own width, nothing read from the pool.  Where ``S`` is whole
+    chunks of ``CAUSAL_BLOCK_CHUNK``, a chunk of queries walks the chunks of
+    keys at or before it with a running maximum, sum and accumulator of its
+    own size (float32), the diagonal chunk masked: the work is the causal
+    half, and no array is ``[S, S]`` or rescaled ``S``-wide a step (the
+    paged read's per-slot state is, which at 16,384 queries rewrote 0.5 GB
+    every two pages: PERF.md, PR 30).  A shorter block takes the masked
+    product."""
+    B, S, Hq, hd = q.shape
+    Hkv, vd, C = k.shape[2], v.shape[-1], CAUSAL_BLOCK_CHUNK
+    if S % C or S < 2 * C:
+        return _attention(cfg, q, k, v, positions, "xla",
+                          custom_positions=True)
+    n, G = S // C, Hq // Hkv
+    qc = jnp.moveaxis(q.reshape(B, n, C, Hkv, G, hd), 1, 0)
+    diagonal = (jnp.arange(C, dtype=jnp.int32)[None, :]
+                <= jnp.arange(C, dtype=jnp.int32)[:, None])       # [Cq, Ck]
+
+    def chunk(args):
+        i, qi = args                                  # qi [B,C,Hkv,G,hd]
+
+        def step(j, carry):
+            m, l, acc = carry
+            kj = jax.lax.dynamic_slice_in_dim(k, j * C, C, axis=1)
+            vj = jax.lax.dynamic_slice_in_dim(v, j * C, C, axis=1)
+            s = jnp.einsum("bckgd,bjkd->bkgcj", qi, kj).astype(jnp.float32)
+            s = jnp.where(diagonal | (j < i), s * _sm_scale(cfg, hd), -1e30)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            pv = jnp.einsum("bkgcj,bjkd->bkgcd", p.astype(q.dtype), vj)
+            return (m_new, l * alpha + p.sum(-1),
+                    acc * alpha[..., None] + pv.astype(jnp.float32))
+
+        m0 = jnp.full((B, Hkv, G, C), -1e30, jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, i + 1, step, (
+            m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, C, vd),
+                                              jnp.float32)))
+        return (acc / l[..., None]).astype(q.dtype)   # [B,Hkv,G,C,vd]
+
+    out = jax.lax.map(chunk, (jnp.arange(n, dtype=jnp.int32), qc))
+    # [n,B,Hkv,G,C,vd] -> [B, n*C, Hkv*G, vd]
+    return jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(B, S, Hq, vd)
+
+
+def _attention_window_block(cfg, q, k, v, positions, window: int, sink=None):
+    """A block of tokens ``[B,S,...]`` that starts its slot, through a
+    window layer: every query sees the block's own keys at most ``window -
+    1`` positions back.  Where ``S`` is whole chunks of ``window`` tokens, a
+    chunk of queries meets its own chunk of keys and the one before it
+    (scores ``[.., S/window, window, 2*window]``, never ``[S, S]``); a
+    shorter or ragged block takes the masked product."""
+    B, S, Hq, hd = q.shape
+    Hkv, C = k.shape[2], window
+    if S % C or S < 2 * C:
+        return _attention(cfg, q, k, v, positions, "xla",
+                          custom_positions=True, window=window, sink=sink)
+    n, G = S // C, Hq // Hkv
+    qc = q.reshape(B, n, C, Hkv, G, hd)
+
+    def with_previous(x):     # [B,S,Hkv,w] -> [B,n,2C,Hkv,w]
+        xc = x.reshape(B, n, C, Hkv, x.shape[-1])
+        before = jnp.concatenate([jnp.zeros_like(xc[:, :1]), xc[:, :-1]], 1)
+        return jnp.concatenate([before, xc], axis=2)
+
+    c = jnp.arange(C, dtype=jnp.int32)[:, None]
+    j = jnp.arange(2 * C, dtype=jnp.int32)[None, :] - C    # key - chunk start
+    ok = (j <= c) & (c - j < window)                       # [C, 2C]
+    # the first chunk has no chunk before it
+    ok = ok[None] & ~((jnp.arange(n) == 0)[:, None, None] & (j < 0)[None])
+    b = (None if sink is None
+         else sink.astype(jnp.float32).reshape(1, 1, Hkv, G, 1, 1))
+
+    def chunks(qc, kk, vv, ok):     # [B,m,...] for m of the n chunks
+        scores = jnp.einsum("bnckgd,bnjkd->bnkgcj", qc, kk)
+        scores = scores.astype(jnp.float32) * _sm_scale(cfg, hd)
+        scores = jnp.where(ok[None, :, None, None], scores, -1e30)
+        probs = _softmax_with_sink(scores, b).astype(q.dtype)
+        return jnp.einsum("bnkgcj,bnjkd->bnckgd", probs, vv)
+
+    kk, vv = with_previous(k), with_previous(v)
+    m = WINDOW_BLOCK_CHUNKS
+    if n > m and n % m == 0:
+        # a long prompt some chunks at a time: the float32 scores of all
+        # 128 chunks of a 16,384-token bucket are 1 GB, and there are
+        # several arrays of their size
+        def group(x):
+            return jnp.moveaxis(x.reshape(x.shape[0], n // m, m,
+                                          *x.shape[2:]), 1, 0)
+        out = jax.lax.map(lambda a: chunks(*a), (
+            group(qc), group(kk), group(vv), ok.reshape(n // m, m, C, 2 * C)))
+        out = jnp.moveaxis(out, 0, 1)
+    else:
+        out = chunks(qc, kk, vv, ok)
+    return out.reshape(B, S, Hq, v.shape[-1])
 
 
 def _paged_write_plan(page_table, start, seq_mask, ps: int):
@@ -1979,6 +2449,181 @@ def _paged_write_plan(page_table, start, seq_mask, ps: int):
         jnp.take_along_axis(page_table, jnp.minimum(lpage, maxp - 1), axis=1),
         0)
     return src, keep, pages
+
+
+def _ring_write_plan(ring_table, start, seq_mask, ps: int):
+    """:func:`_paged_write_plan` for a window layer, whose slot keeps a ring
+    of ``R = ring_table.shape[1]`` pages (logical page ``j`` in ring page
+    ``j % R``): ``(src, keep, pages)`` over the LAST pages the block's real
+    tokens reach, at most ``R`` of them.  A decode token lands in its page
+    (what the page held of logical page ``j - R`` behind it is past every
+    query's mask); of a prompt longer than the ring only the last rows are
+    kept, the rest is never read again (a window layer's queries attend
+    inside the block, :func:`_attention_window_block`)."""
+    B, S = seq_mask.shape
+    R = ring_table.shape[1]
+    n_pg = min(R, (S + ps - 2) // ps + 1)
+    positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    last = jnp.max(jnp.where(seq_mask, positions, -1), axis=1)       # [B]
+    lpage = ((jnp.maximum(last, 0) // ps)[:, None] - (n_pg - 1)
+             + jnp.arange(n_pg, dtype=jnp.int32))                    # [B,n_pg]
+    src = (lpage[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)
+           - start[:, None, None])
+    in_block = (src >= 0) & (src < S) & (lpage >= 0)[:, :, None]
+    src = jnp.clip(src, 0, S - 1).reshape(B, n_pg * ps)
+    keep = in_block & jnp.take_along_axis(seq_mask, src,
+                                          axis=1).reshape(in_block.shape)
+    pages = jnp.where(keep.any(-1),
+                      jnp.take_along_axis(ring_table, lpage % R, axis=1), 0)
+    return src, keep, pages
+
+
+def window_read_rows(lengths, page_size: int, window: int,
+                     slots: int) -> int:
+    """:func:`paged_read_rows` for a window layer's ring: a live slot of
+    ``lengths`` rows (the row being written counted in) is read over the
+    pages that hold its last ``window`` positions, the list rounded up to
+    whole steps.  The host's copy of :func:`_ring_read_plan`."""
+    pairs = paged_read_pairs(slots, window_ring_pages(window, page_size))
+    at = np.asarray(lengths, np.int64) - 1
+    at = at[at >= 0]
+    live = int((at // page_size
+                - np.maximum(at - window + 1, 0) // page_size + 1).sum())
+    return -(-live // pairs) * pairs * page_size
+
+
+def _ring_read_plan(ring_table, start, seq_mask, ps: int, window: int):
+    """:func:`_paged_read_plan` for a window layer's decode step (one token
+    a slot): the pairs are the ring pages that hold positions ``pos -
+    window + 1 .. pos`` of each live slot, ``limit`` as there and ``low``
+    the first row of each page inside the window.  ``(steps, slot, pages,
+    limit, low)``."""
+    B, R = ring_table.shape
+    S = seq_mask.shape[1]
+    pairs = paged_read_pairs(B, R)
+    positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    last = jnp.max(jnp.where(seq_mask, positions, -1), axis=1)       # [B]
+    first_page = jnp.maximum(last - window + 1, 0) // ps
+    n_pages = jnp.where(last >= 0, last // ps - first_page + 1, 0)
+    ends = jnp.cumsum(n_pages)
+    i = jnp.arange(-(-B * R // pairs) * pairs, dtype=jnp.int32)
+    slot = jnp.sum(i[:, None] >= ends[None, :], axis=1, dtype=jnp.int32)
+    valid = slot < B
+    at = jnp.minimum(slot, B - 1)
+    j = first_page[at] + i - (ends - n_pages)[at]
+    pages = jnp.where(valid, ring_table[at, j % R], 0)
+    limit = jnp.where(valid[:, None], positions[at] - j[:, None] * ps, -1)
+    low = limit - (window - 1)
+    return ((ends[-1] + pairs - 1) // pairs, slot.reshape(-1, pairs),
+            pages.reshape(-1, pairs), limit.reshape(-1, pairs, S),
+            low.reshape(-1, pairs, S))
+
+
+def _hybrid_refuse(what: str):
+    raise NotImplementedError(
+        f"{what} does not support a model with window and full attention "
+        "layers (layer_pattern): it runs through forward() and the paged "
+        "serving path (forward_paged)")
+
+
+def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
+                          seq_mask, expert_counts, pool_order):
+    """:func:`forward_paged` for a model with layers of two kinds: a pool
+    and a plan per kind, the layers in their published order (a Python
+    loop: the kinds' stacks differ in shape, so there is nothing to scan).
+
+    ``page_table`` is ``(full [B, maxp], ring [B, R])``; a lone table's first
+    ``R`` columns serve as the ring (one sequence over pools of equal page
+    counts).  A full layer is :func:`forward_paged`'s own.  A window layer
+    writes into its slot's ring (:func:`_ring_write_plan`); one token a
+    slot reads the ring through the window (:func:`_ring_read_plan`).  A
+    longer block must start its slot (the engine refuses what would start
+    one elsewhere: prefix sharing, speculation) and attends inside itself in
+    both kinds of layer, writing its K/V for the tokens to come."""
+    full_table, ring_table = (page_table if isinstance(page_table,
+                                                       (tuple, list))
+                              else (page_table, None))
+    groups = layer_groups(cfg)
+    suffix = {"full": "", "window": "_window"}
+    kind_cfg = {kind: g for kind, (g, _) in kind_layers(cfg).items()}
+    # each kind's pool stacked [L_kind * P_kind, page, Hkv, w], a leaf kept
+    # head-major (pool_leaf_head_major) seen through the transpose that
+    # moves nothing, its layers' pages at l * P_kind
+    head_major = {
+        kind: {"k": pool_leaf_head_major(g.kv_heads, g.dims_per_head),
+               "v": pool_leaf_head_major(g.kv_heads, g.v_dims_per_head)}
+        for kind, g in kind_cfg.items()}
+
+    def stacked(kind, n):
+        a = cache[n + suffix[kind]]
+        a = a.reshape(-1, *a.shape[2:])
+        return jnp.transpose(a, (0, 2, 1, 3)) if head_major[kind][n] else a
+
+    pools = {kind: {n: stacked(kind, n) for n in ("k", "v")}
+             for kind in kind_cfg}
+    ps = next(iter(pools.values()))["k"].shape[1]
+    W = cfg.window_size
+    R = window_ring_pages(W, ps)
+    if ring_table is None:
+        ring_table = full_table[:, :R]
+    S = tokens.shape[1]
+    positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
+    # one token a slot reads each kind's pool by its plan; a longer block
+    # starts its slot and attends within itself, in both kinds
+    plans = {
+        "full": (_paged_write_plan(full_table, start, seq_mask, ps),
+                 _paged_read_plan(full_table, start, seq_mask, ps)
+                 if S == 1 else None),
+        "window": (_ring_write_plan(ring_table, start, seq_mask, ps),
+                   _ring_read_plan(ring_table, start, seq_mask, ps, W)
+                   if S == 1 else None)}
+    x = _embed(cfg, params, tokens,
+               jnp.minimum(positions, cfg.max_seq_len - 1))
+    x = constrain_spec(x, P(BATCH_AXES, None, None))
+    rng = jax.random.PRNGKey(0)
+    n_pages = {kind: cache["k" + suffix[kind]].shape[1] for kind in pools}
+    orders = {kind: {n: ((0, 1, 3, 2, 4) if head_major[kind][n]
+                         else pool_order.get(n + suffix[kind])
+                         if isinstance(pool_order, dict) else pool_order)
+                     for n in ("k", "v")} for kind in pools}
+    # each group's expert stacks whole [n * E, ...] with a layer's experts
+    # at l * E: nothing of a layer's size is cut out
+    experts = {name: {k: v.reshape(-1, *v.shape[2:]) for k, v in lp.items()
+                      if k in _EXPERT_LEAVES and "router" in lp}
+               for name, lp in params["layers"].items()}
+    seen = {kind: 0 for kind in pools}
+    counts = []
+    for group, index, kind, _ in layer_plan(cfg):
+        g = groups[group][0]
+        lp = {k: v[index] for k, v in params["layers"][group].items()
+              if k not in experts[group]}
+        first_page = seen[kind] * n_pages[kind]
+        seen[kind] += 1
+        (src, keep, wpages), read = plans[kind]
+        if read is not None:
+            read = (read[0], read[1], read[2] + first_page) + read[3:]
+        x, _, c, pools[kind] = _block(
+            g, {**lp, **experts[group]}, x, positions, rng,
+            _attend_paged(g, pools[kind], (src, keep, wpages + first_page),
+                          read, orders[kind], sink=lp.get("attn_sink"),
+                          within=(None if S == 1 else
+                                  (positions, W if kind == "window"
+                                   else None))),
+            token_mask=seq_mask,
+            expert_offset=(jnp.int32(index * (g.moe_experts_held
+                                              or g.num_experts))
+                           if experts[group] else None))
+        x = constrain_spec(x, P(BATCH_AXES, None, None))
+        if c is not None:
+            counts.append(c)
+    logits = _head(cfg, params, x)
+    out = {n + suffix[kind]: (jnp.transpose(a, (0, 2, 1, 3))
+                              if head_major[kind][n] else a
+                              ).reshape(cache[n + suffix[kind]].shape)
+           for kind, leaves in pools.items() for n, a in leaves.items()}
+    if not expert_counts:
+        return logits, out
+    return logits, out, (jnp.stack(counts) if counts else None)
 
 
 def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
@@ -2046,6 +2691,13 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         raise NotImplementedError(
             "paged decode does not support per-layer attention windows "
             "(attention_layers); use the contiguous cache path")
+    if is_hybrid(cfg):
+        if adapters is not None:
+            _hybrid_refuse("multi-tenant adapter serving (per-slot LoRA "
+                           "factors)")
+        return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
+                                     start, seq_mask, expert_counts,
+                                     pool_order)
     num_layers, num_pages, ps = cache["k"].shape[:3]
     positions = (start[:, None]
                  + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :])

@@ -39,6 +39,14 @@ class MoEConfig:
     # the k largest softmax probabilities as they are (OLMoE).  Top-1 never
     # renormalises.
     norm_topk_prob: bool = True
+    # the scores the router's logits become: "softmax" over all experts, or
+    # "sigmoid" of each logit on its own (the dropless path only)
+    score_func: str = "softmax"
+    # ``(first, count)``: one chip's share of the experts.  ``num_experts``
+    # stays the router's width and ``top_k`` the experts a token chooses
+    # among all of them; the expert leaves hold ``count`` experts from
+    # ``first`` on (the dropless path only).  None: every expert is here.
+    held: Optional[Tuple[int, int]] = None
 
 
 def _capacity(tokens_per_group: int, cfg: MoEConfig, deterministic: bool) -> int:
@@ -121,7 +129,8 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
                    activation: str = "swiglu", deterministic: bool = True,
                    rng: Optional[jnp.ndarray] = None,
                    token_mask: Optional[jnp.ndarray] = None,
-                   expert_offset: Optional[jnp.ndarray] = None
+                   expert_offset: Optional[jnp.ndarray] = None,
+                   select_bias: Optional[jnp.ndarray] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """True no-token-dropping MoE via ``lax.ragged_dot`` — the TPU-native
     answer to the reference's dynamic-capacity exchange (sharded_moe.py:253
@@ -148,8 +157,22 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     layer's 800 MB slice out and copies it before the matmuls read it
     (PERF.md, PR 26).
 
-    Returns ``(out [B,S,D], aux, counts [E] int32)``; ``counts`` are the
-    group sizes the matmuls ran with, the rows each expert computed.
+    The scores are ``cfg.score_func`` of the router's logits; ``select_bias
+    [E]`` (optional) is added to them for the CHOICE of the k experts and
+    not to the gates, which are the chosen experts' own scores
+    (renormalised over the chosen if ``cfg.norm_topk_prob``).
+
+    ``cfg.held = (first, count)``: the expert leaves hold ``count`` of the
+    ``E`` experts, from ``first`` on (one chip's share under expert
+    parallelism).  Routing is over all ``E`` as published; a (token, expert)
+    pair whose expert is elsewhere is in no group and in no count, as a
+    masked token's are, and what that expert would add is left out: the
+    result is this share's part of the layer's sum, the gates those of the
+    whole choice.
+
+    Returns ``(out [B,S,D], aux, counts [E or count] int32)``; ``counts``
+    are the group sizes the matmuls ran with, the rows each expert here
+    computed.
 
     Best with ep=1 (dp/tp meshes): expert weights replicate and every shard
     routes its tokens locally.  With ep>1 GSPMD falls back to gathering the
@@ -162,8 +185,19 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     T = B * S
     with jax.named_scope("moe_router"):
         logits = _router_logits(x, router_w, cfg, deterministic, rng)
-        gates = jax.nn.softmax(logits.reshape(T, E), axis=-1)    # [T, E]
-        vals, idx = jax.lax.top_k(gates, k)    # [T, k]; ties: lower index
+        if cfg.score_func == "sigmoid":
+            gates = jax.nn.sigmoid(logits.reshape(T, E))
+        elif cfg.score_func == "softmax":
+            gates = jax.nn.softmax(logits.reshape(T, E), axis=-1)  # [T, E]
+        else:
+            raise ValueError(f"score_func={cfg.score_func!r}: "
+                             "softmax | sigmoid")
+        if select_bias is None:
+            vals, idx = jax.lax.top_k(gates, k)  # [T, k]; ties: lower index
+        else:
+            _, idx = jax.lax.top_k(
+                gates + select_bias.astype(jnp.float32)[None, :], k)
+            vals = jnp.take_along_axis(gates, idx, axis=1)
         # load-balancing aux loss over the top-1 assignment, per group
         # (batch row) then averaged — same semantics as the capacity path
         # (reference :179,277)
@@ -176,6 +210,13 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
 
     with jax.named_scope("moe_dispatch"):
         flat_expert = idx.reshape(T * k)
+        if cfg.held is not None:
+            # the groups are the experts held: a pair whose expert is
+            # elsewhere goes where a masked token's go, past the last
+            first, E = cfg.held
+            flat_expert = jnp.where(
+                (flat_expert >= first) & (flat_expert < first + E),
+                flat_expert - first, E)
         if token_mask is not None:
             # expert id E: past every group, counted by none
             flat_expert = jnp.where(
@@ -252,7 +293,8 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
             cfg: MoEConfig, activation: str = "swiglu", deterministic: bool = True,
             rng: Optional[jnp.ndarray] = None,
             token_mask: Optional[jnp.ndarray] = None,
-            expert_offset: Optional[jnp.ndarray] = None):
+            expert_offset: Optional[jnp.ndarray] = None,
+            select_bias: Optional[jnp.ndarray] = None):
     """x [B, S, D] -> (out [B, S, D], aux_loss, counts): ``counts`` are the
     rows each expert computed, ``[E]`` int32, on the dropless path, and
     ``None`` on the capacity path, whose buffers have one static size.
@@ -269,8 +311,16 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
                               activation=activation,
                               deterministic=deterministic, rng=rng,
                               token_mask=token_mask,
-                              expert_offset=expert_offset)
+                              expert_offset=expert_offset,
+                              select_bias=select_bias)
     assert expert_offset is None, "expert stacks are the dropless path's"
+    if (cfg.score_func != "softmax" or cfg.held is not None
+            or select_bias is not None):
+        raise NotImplementedError(
+            "sigmoid scores, a selection bias and a held share of the "
+            "experts are the dropless path's (drop_tokens=False); the "
+            "capacity buffers route by softmax over experts that are all "
+            "here")
     B, S, D = x.shape
     with jax.named_scope("moe_router"):
         logits = _router_logits(x, router_w, cfg, deterministic, rng)

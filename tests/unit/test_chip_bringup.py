@@ -227,3 +227,162 @@ def test_paged_decode_reads_a_bounded_pool_in_place_on_v5e(
             assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
                              r"\(| scatter\(|/scatter\"", line), line[:240]
     assert read in gathers and max(gathers) == read
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
+        pytest.skip(f"TPU topology unavailable for AOT compile: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def test_hybrid_paged_decode_keeps_both_pools_in_place_on_v5e(one_v5e_chip):
+    """AOT: ``jit_serve_decode``'s body for MiMo-V2.5 at the benchmark's cut
+    (published widths, 7 layers, 16 experts held, 32 slots of 128 pages),
+    compiled by the installed libtpu with the pool of each kind donated and
+    handed over in the order the chip stores it (observed on the v5e, PR 30:
+    the 192-wide keys page-rows minor-most, the 128-wide values row-major, the
+    full layers' 4 x 128 values kept head-major).  No op's result is the size
+    of a leaf of either pool but the in-place page scatters, the window
+    layers' gathers are a step's (slot, page) pairs of a two-page ring, and
+    the program's temporaries are a fraction of one pool leaf (with the
+    values of the full layers page-major they were 2 x 1 GB of copies)."""
+    import re
+
+    import numpy as np
+
+    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models.transformer import (forward_paged,
+                                                  paged_read_pairs)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    cfg = get_config("mimo-v2.5", num_layers=7, moe_experts_held=16,
+                     vocab_size=19072)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    slots, maxp, page = 32, 128, 128
+    pages, ring = 1 + slots * maxp, 1 + slots * 2
+    cache = {"k": S((2, pages, page, 4, 192), jnp.bfloat16),
+             "v": S((2, pages, 4, page, 128), jnp.bfloat16),
+             "k_window": S((5, ring, page, 8, 192), jnp.bfloat16),
+             "v_window": S((5, ring, page, 8, 128), jnp.bfloat16)}
+    page_minor = (0, 1, 3, 4, 2)
+    order = {"k": page_minor, "v": None, "k_window": page_minor,
+             "v_window": None}
+
+    def tick(params, cache, tokens, tables, start, mask):
+        logits, cache, counts = forward_paged(
+            cfg, params, tokens, cache, tables, start, mask,
+            expert_counts=True, pool_order=order)
+        return jnp.argmax(logits[:, -1], -1), counts, cache
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, cache, S((slots, 1), jnp.int32),
+        (S((slots, maxp), jnp.int32), S((slots, 2), jnp.int32)),
+        S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
+    # 0.64 GB, most of it weights the compiler re-lays out for its products
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    leaves = {(page, 4, 192): 2 * pages, (page, 4, 128): 2 * pages,
+              (page, 8, 192): 5 * ring, (page, 8, 128): 5 * ring}
+    gathers = {}
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        kind = next((k for k in leaves if sorted(dims[-3:]) == sorted(k)),
+                    None)
+        if kind is None or len(dims) < 4:
+            continue                     # not pages of K/V
+        n = int(np.prod(dims))
+        if " gather(" in line:
+            gathers.setdefault(kind, set()).add(n)
+        if n >= leaves[kind] * int(np.prod(kind)):
+            assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
+                             r"\(| scatter\(|/scatter\"", line), line[:240]
+    for kind, sizes in gathers.items():
+        pairs = paged_read_pairs(slots, maxp if kind[1] == 4 else 2)
+        assert max(sizes) <= pairs * int(np.prod(kind)), kind
+
+
+# The optimized HLO of the programs the benchmark's other configurations run
+# (depth 2, metadata stripped), as the parent of PR 30 compiled them for the
+# v5e: a change to the code they share with a new configuration either
+# leaves these programs as they are, or says which instruction moved and why.
+PROGRAMS_AT_PR_29 = {
+    "opt-1.3b_decode": "af3bcf9b66555cdf",
+    "olmoe-1b-7b_decode": "79db2ac2ddb9f704",
+    "olmoe-1b-7b_prefill_256": "9b758bab88e95216",
+    "pythia_step": "28efbc2bb5a12dc6",
+}
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS_AT_PR_29))
+def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
+    import hashlib
+    import json
+    import re
+
+    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models.transformer import (cross_entropy_loss, forward,
+                                                  forward_paged)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    def shapes(cfg):
+        return jax.tree_util.tree_map(
+            lambda a: S(a.shape, jnp.bfloat16),
+            jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+
+    if program == "pythia_step":
+        import dataclasses
+
+        from benchmark.lib import system
+
+        with open(os.path.join(REPO, "benchmark", "configs",
+                               "pythia-1.4b-d10.json")) as f:
+            cfg = dataclasses.replace(
+                system.transformer_config(json.load(f), False), num_layers=2)
+
+        def loss(p, toks):
+            logits = forward(cfg, p, toks[:, :-1], attn_impl="xla",
+                             deterministic=False, rng=jax.random.PRNGKey(0))
+            return cross_entropy_loss(logits, toks[:, 1:])
+
+        compiled = jax.jit(jax.value_and_grad(loss)).lower(
+            shapes(cfg), S((4, 1025), jnp.int32)).compile()
+    else:
+        name, what = program.split("_", 1)
+        over, slots, order = {
+            "opt-1.3b": ({"activation": "relu"}, 8, (0, 1, 3, 4, 2)),
+            "olmoe-1b-7b": ({}, 16, None)}[name]
+        cfg = get_config(name, num_layers=2, **over)
+        moe = cfg.num_experts != 1
+        pool = S((2, 1 + slots * 16, 128, cfg.kv_heads, cfg.dims_per_head),
+                 jnp.bfloat16)
+
+        def tick(params, k, v, tokens, table, start, mask):
+            r = forward_paged(cfg, params, tokens, {"k": k, "v": v}, table,
+                              start, mask, pool_order=order,
+                              expert_counts=moe)
+            return (jnp.argmax(r[0][:, -1], -1), r[1]["k"], r[1]["v"]) + (
+                (r[2],) if moe else ())
+
+        b, s = (slots, 1) if what == "decode" else (1, int(what[8:]))
+        compiled = jax.jit(tick, donate_argnums=(1, 2)).lower(
+            shapes(cfg), pool, pool, S((b, s), jnp.int32),
+            S((b, 16), jnp.int32), S((b,), jnp.int32),
+            S((b, s), jnp.bool_)).compile()
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", compiled.as_text())
+    text = re.sub(r"stack_frame_id=\d+", "", text).split("\nFileNames")[0]
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        PROGRAMS_AT_PR_29[program]
