@@ -332,8 +332,10 @@ class _Slot:
 
 # decode ticks kept launched ahead of the one being fetched (docs/SERVING.md
 # "Decode lookahead"): what the device has queued when the host stands still.
-# One is enough to hide the host's own work of a tick; eight ride out a stall
-# of a few ticks' length (PERF.md §6, PR 26: ~115 ms, two to four a run).
+# One is enough to hide the host's own work of a tick, and is all an arrival
+# may find ahead of its prefill (a free slot, admission open); eight ride out
+# a stall of a few ticks' length where no arrival can be placed anyway
+# (PERF.md §6, PR 26: ~115 ms, two to four a run).
 LOOKAHEAD_TICKS = 8
 
 
@@ -1792,24 +1794,42 @@ class ServingEngine:
         return paged_read_rows(lengths, self.page_size,
                                self._page_table.shape[1], slots)
 
+    def _arrival_waiting(self, now: float) -> bool:
+        """A request is due and a usable slot is free: the next admission
+        call would try to place it."""
+        return ((bool(self._queue) or (bool(self._pending)
+                 and self._pending[0].arrival_time <= now))
+                and int(self._active.sum()) < self._usable_slots())
+
     def _lookahead_depth(self) -> int:
-        """How many ticks after this one can be launched now, at most
-        ``LOOKAHEAD_TICKS``: those whose inputs are this tick's, so many
-        positions on.  Every live slot is still live then (none reaches its
-        length before, none can stop on a token the host has not seen yet),
-        and no arrival can be put behind them: every usable slot is busy,
-        or admission is closed, or this ``run()`` has nothing queued or
-        still to arrive.  A guess, not a guard: what was launched is
+        """How many ticks after this one can be launched now: those whose
+        inputs are this tick's, so many positions on.  Every live slot is
+        still live then (none reaches its length before, none can stop on a
+        token the host has not seen yet).  How many, by what an arrival
+        would find on the device:
+
+        - no arrival can be placed (every usable slot is busy, or admission
+          is closed, or this ``run()`` has nothing queued or still to
+          arrive): up to ``LOOKAHEAD_TICKS``;
+        - a slot is free and admission is open, and nothing waits for it
+          (``_decode_tick`` follows ``step()``'s admission call, so a
+          request still queued is one the pool cannot hold yet): one, the
+          most an arrival's prefill is ever launched behind.
+
+        Nothing is launched over a request that can be placed: ``step()``
+        puts its admission after the tick in flight and ``_decode_tick``
+        then skips the launch.  A guess, not a guard: what was launched is
         checked again when its turn comes."""
         catalog = self._exec.catalog
         if not self.lookahead or (catalog is not None
                                   and catalog.sample_every):
             return 0    # a sampled dispatch syncs on its own output
-        if not (int(self._active.sum()) >= self._usable_slots()
+        if (int(self._active.sum()) >= self._usable_slots()
                 or self._draining
                 or (self._in_run and not self._queue and not self._pending)):
-            return 0
-        depth = LOOKAHEAD_TICKS
+            depth = LOOKAHEAD_TICKS
+        else:
+            depth = 1
         for slot in np.flatnonzero(self._active):
             st = self._slots[slot]
             if st.request.eos_token_id is not None:
@@ -1817,25 +1837,39 @@ class ServingEngine:
             depth = min(depth, st.request.max_new_tokens - len(st.tokens) - 1)
         return max(depth, 0)
 
-    def _take_ahead(self, lanes, adapters):
-        """The device output of this tick if it was launched ahead on
-        exactly the state the host now holds, else ``None``, and then every
-        tick launched after it goes too (what they wrote is one K/V row a
-        slot each, past the slot's length, which the ticks launched in
-        their place write again)."""
-        if not self._ahead:
-            return None
-        ahead = self._ahead.popleft()
-        if (self._last_out is not None and ahead.fed is self._last_out[0]
+    def _ahead_current(self, lanes, adapters) -> bool:
+        """The tick launched first was launched on exactly the state the
+        host now holds: fed the output of the tick emitted last, with the
+        tables, lengths, slots, last tokens, parameters, lanes and adapter
+        operand of this moment."""
+        ahead = self._ahead[0]
+        return (self._last_out is not None
+                and ahead.fed is self._last_out[0]
                 and ahead.params is self._exec.params
                 and ahead.lanes is lanes and ahead.adapters is adapters
                 and np.array_equal(ahead.active, self._active)
                 and np.array_equal(ahead.lengths, self._lengths)
                 and np.array_equal(ahead.page_table, self._page_table)
                 and np.array_equal(self._last_out[1][ahead.active],
-                                   self._last_tok[self._active])):
-            return ahead.out
-        self.lookahead_dropped += 1 + len(self._ahead)
+                                   self._last_tok[self._active]))
+
+    def _take_ahead(self, lanes, adapters):
+        """The device output of this tick if it was launched ahead on
+        exactly the state the host now holds, else ``None``, and then every
+        tick launched after it goes too.  What they wrote is one K/V row a
+        slot each, past the slot's length, which the ticks launched in
+        their place write again; and where a slot ended under them (a
+        deadline) and its pages went to a request admitted since, the order
+        of the programs on the device keeps the pages right: the stale
+        ticks' rows land first, the new request's prefill, launched after
+        them, over them, and a row past its prompt is one no read reaches
+        before the slot's own decode writes it."""
+        if not self._ahead:
+            return None
+        if self._ahead_current(lanes, adapters):
+            return self._ahead.popleft().out
+        trace_count("serve.lookahead_dropped", float(len(self._ahead)))
+        self.lookahead_dropped += len(self._ahead)
         self._ahead.clear()
         return None
 
@@ -1845,6 +1879,9 @@ class ServingEngine:
         device (``nxt``: this tick's), so the device goes from one program
         into the next while the host fetches, emits and schedules."""
         depth = self._lookahead_depth()
+        launched = depth - len(self._ahead)
+        if launched > 0:
+            trace_count("serve.lookahead_launched", float(launched))
         while len(self._ahead) < depth:
             fed = self._ahead[-1].out if self._ahead else nxt
             lengths = self._lengths + np.int32(len(self._ahead) + 1) * \
@@ -1856,7 +1893,11 @@ class ServingEngine:
                 self._exec.params, lanes, adapters))
             self.lookahead_launched += 1
 
-    def _decode_tick(self, rid_map: Optional[Dict[str, str]] = None) -> None:
+    def _decode_tick(self, rid_map: Optional[Dict[str, str]] = None,
+                     held: bool = False) -> None:
+        """One decode step over the live slots: take the tick launched
+        ahead or launch it, launch ahead of it (not where ``step()`` holds
+        an admission back behind this tick, ``held``), fetch, emit."""
         if self._spec is not None:
             self._spec_tick(rid_map)
             return
@@ -1871,7 +1912,9 @@ class ServingEngine:
             # streams it was serving.  Built once per tick by step()
             # (None while tracing is off).
             if rid_map is not None:
-                sp.set(slot_rids=rid_map)
+                # ``ahead``: ticks in flight as the span opens (0: this
+                # tick's program is launched inside the span)
+                sp.set(slot_rids=rid_map, ahead=len(self._ahead))
             maybe_fire(SITE_SERVE_DECODE, tick=self._tick)
             with self._armed(f"serve.decode tick {self._tick}"):
                 nxt = self._take_ahead(lanes, adapters)
@@ -1879,7 +1922,8 @@ class ServingEngine:
                     nxt = self._exec.decode(self._tables(), self._lengths,
                                             self._last_tok, self._active,
                                             lanes, adapters=adapters)
-                self._launch_ahead(nxt, lanes, adapters)
+                if not held:
+                    self._launch_ahead(nxt, lanes, adapters)
                 if rid_map is not None:
                     # the launch has returned; what is left of the span is
                     # the wait for the device in the fetch below.  Rows the
@@ -2139,7 +2183,16 @@ class ServingEngine:
             if (self.probe_after_ticks is not None and not self._draining
                     and self._quarantined.any()):
                 self._probe_quarantined()
-            if not self._draining:
+            # an arrival that finds a tick in flight (launched on the state
+            # the host still holds) is placed by the admission call that
+            # follows that tick, and nothing is launched over it: its
+            # prefill waits for one decode program at most, and no device
+            # work of the live streams is thrown away
+            held = (not self._draining and bool(self._ahead)
+                    and self._arrival_waiting(now)
+                    and self._ahead_current(self._lanes_jnp(),
+                                            self._adapter_operand()))
+            if not self._draining and not held:
                 self._admit(now)
             if self._active.any():
                 rid_map = (self._slot_rid_map() if get_tracer().enabled
@@ -2152,10 +2205,11 @@ class ServingEngine:
                         sp.set(pages_full=int((self._refcount[1:] > 0).sum()),
                                pages_window=int(
                                    (self._ring_table[self._active] > 0).sum()))
-                self._decode_tick(rid_map)
+                self._decode_tick(rid_map, held)
                 # refill slots the decode just retired — the queue head
                 # starts its prefill this tick instead of idling one
-                # scheduler round
+                # scheduler round — and place what was held back behind
+                # the tick in flight
                 if not self._draining:
                     self._admit(now)
                 with trace_span("serve.gauges"):
