@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, cow_copy_pool,
-                                  expert_counts_shape, is_hybrid,
+                                  expert_counts_shape, is_hybrid, is_latent,
                                   paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, window_ring_pages)
 from ..observability.program_stats import (ProgramCatalog, account,
@@ -201,7 +201,8 @@ class MeshExecutor:
                     "initialize_serving_mesh), got axes "
                     f"{tuple(mesh.axis_names)}")
             self.tp = int(mesh.shape["model"])
-            if self.tp > 1 and cfg.kv_heads % self.tp != 0:
+            if self.tp > 1 and not is_latent(cfg) \
+                    and cfg.kv_heads % self.tp != 0:
                 raise ValueError(
                     f"kv_heads={cfg.kv_heads} not divisible by the mesh's "
                     f"model axis ({self.tp}): the paged KV pool shards its "
@@ -217,7 +218,20 @@ class MeshExecutor:
         # the window pool: a ring a slot, and its own trash page
         self.window_pages = (1 + self.b_slots * self.ring_pages
                              if self.ring_pages else 0)
+        # what moves, shares or shards pages as K and V of some heads in
+        # ONE pool says so instead of serving a wrong answer
+        unlike = None
         if self.ring_pages:
+            unlike = ("window layers (layer_pattern): its window layers "
+                      "keep a ring of pages a slot in a pool of their own")
+        elif is_latent(cfg):
+            # one leaf of latent rows with no head axis, read back only by
+            # one token a slot (docs/SERVING.md "A latent cache")
+            unlike = ("latent attention (kv_lora_rank): its cache rows have "
+                      "no head axis to shard or scale, and a block of more "
+                      "than one token attends within itself, so it has to "
+                      "start its slot")
+        if unlike:
             for on, what in ((self.tp > 1, "tensor-sharded heads (tp > 1)"),
                              (prefix_cache, "copy-on-write page snapshots "
                               "(prefix_cache=True)"),
@@ -226,9 +240,7 @@ class MeshExecutor:
                              (adapters is not None, "multi-tenant adapters")):
                 if on:
                     raise NotImplementedError(
-                        f"{what} does not support a model with window "
-                        "layers (layer_pattern): its window layers keep a "
-                        "ring of pages a slot in a pool of their own")
+                        f"{what} does not support a model with {unlike}")
         self.params = place_params(params, mesh)
         # capture the placed tree's shape so LIVE weight updates
         # (update_params — hybrid rollout, docs/HYBRID.md) can be pinned to
@@ -260,7 +272,7 @@ class MeshExecutor:
         self.quantized = "k_scale" in cache
         self._pool_keys = tuple(k for k in PAGED_POOL_KEYS if k in cache)
         self._pool_specs = tuple(specs[k] for k in self._pool_keys)
-        self._kv_spec = specs["k"]
+        self._kv_spec = specs[self._pool_keys[0]]
         # commit the fresh pool to its placement: a jit caches on the arg's
         # committed-ness, so an UNcommitted initial pool would cost each
         # program one extra compile when the second call arrives holding

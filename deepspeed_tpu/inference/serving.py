@@ -127,6 +127,7 @@ import jax
 import jax.numpy as jnp
 
 from ..models.transformer import (PAGE_SIZE, block_read_rows, is_hybrid,
+                                  is_latent,
                                   kind_layers, paged_read_rows,
                                   window_read_rows, window_ring_pages)
 from ..observability.device_profiler import (device_trace_unit,
@@ -410,19 +411,30 @@ class ServingEngine:
         cfg = model.config
         self._ring = (window_ring_pages(cfg.window_size, self.page_size)
                       if is_hybrid(cfg) else 0)
+        # a latent cache (docs/SERVING.md "A latent cache"): a block of more
+        # than one token attends within itself over its expanded keys and
+        # values, so it has to start its slot
+        self._latent = is_latent(cfg)
+        unlike = None
         if self._ring:
+            unlike = ("window layers (layer_pattern): a window layer's ring "
+                      "holds its slot's last positions only, so there is no "
+                      "page of it to share, park or verify against")
+        elif self._latent:
+            unlike = ("latent attention (kv_lora_rank): only one token a "
+                      "slot reads its latent rows back, so a prompt's tail "
+                      "behind shared pages, or a block of draft tokens, has "
+                      "nothing to attend through")
+        if unlike:
             for on, what in (
                     (prefix_cache, "prefix sharing (prefix_cache=True)"),
                     (host_tier_pages is not None, "KV-page tiering"),
                     (speculative is not None, "speculative decoding")):
                 if on:
                     raise NotImplementedError(
-                        f"{what} does not support a model with window "
-                        "layers (layer_pattern): a window layer's ring "
-                        "holds its slot's last positions only, so there is "
-                        "no page of it to share, park or verify against")
+                        f"{what} does not support a model with {unlike}")
         if prefix_cache is None:
-            prefix_cache = not self._ring
+            prefix_cache = not (self._ring or self._latent)
         self.monitor = monitor
         self.watchdog = watchdog
         # decode lookahead (docs/SERVING.md "Decode lookahead"): launch tick
@@ -1613,9 +1625,10 @@ class ServingEngine:
         with trace_span("serve.prefill", rid=req.rid, slot=slot,
                         bucket=s_pad, tokens=S_tail,
                         shared_tokens=n_shared,
-                        # a model with window layers gathers nothing back:
-                        # its prompt attends within itself (kv_rows_*)
-                        gathered_rows=0 if self._ring else
+                        # a model with window layers (kv_rows_*) or a
+                        # latent cache gathers nothing back: its prompt
+                        # attends within itself
+                        gathered_rows=0 if self._ring or self._latent else
                         self._gathered_rows([n_shared + S_tail], 1)) as sp:
             if self._ring and get_tracer().enabled:
                 self._set_kv_row_attrs(sp, [S_tail], 1, block=s_pad)

@@ -103,8 +103,20 @@ class TransformerConfig:
     v_head_dim: Optional[int] = None          # None => head_dim
     attn_value_scale: float = 1.0
     # the first ``dense_layers`` layers keep a dense MLP (of width
-    # ``intermediate_size``) in a model whose other layers are expert layers
+    # ``intermediate_size``) in a model whose other layers are expert layers:
+    # the layers are then stacked by group (:func:`layer_groups`)
     dense_layers: int = 0
+    # Latent attention (MLA, ``deepseek_v3``): a token's keys and values are
+    # up-projections (``wkv_b``) of one ``kv_lora_rank``-wide latent, RMS-
+    # normed, beside one rotated key row of ``rotary_dim`` dims that every
+    # head shares.  ``head_dim`` is a query's and key's whole width, its
+    # LAST ``rotary_dim`` dims the rotated ones; ``v_head_dim`` the values'.
+    # The paged cache holds one leaf ``latent [L, P, page, kv_lora_rank +
+    # rotary_dim]`` with no head axis; a prompt attends within itself over
+    # the expanded keys and values, a decode tick reads the latent rows
+    # themselves with the up-projections absorbed into the query and the
+    # output (:func:`_attend_latent_paged`).  None: K and V heads.
+    kv_lora_rank: Optional[int] = None
     tie_embeddings: bool = False
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -141,6 +153,12 @@ class TransformerConfig:
     # path only).  None: every expert is here.
     moe_experts_held: Optional[int] = None
     moe_expert_first: int = 0
+    # ``moe_shared_experts`` experts every token goes through beside its
+    # routed ones, built as ONE gated MLP of that many expert widths
+    # (``shared_w_*``, every chip's alike under expert parallelism); the
+    # routed experts' gates are multiplied by ``moe_routed_scale``
+    moe_shared_experts: int = 0
+    moe_routed_scale: float = 1.0
     # residual MoE (PR-MoE, reference moe/layer.py use_residual): each MoE
     # layer also runs a dense MLP; outputs mix via a learned 2-way coefficient
     moe_use_residual: bool = False
@@ -194,7 +212,7 @@ class TransformerConfig:
 
     @property
     def param_count(self) -> int:
-        if self.layer_pattern is not None:
+        if is_grouped(self):
             # the parts outside the layers once, each group's layers beside
             outside = dataclasses.replace(
                 self, layer_pattern=None, dense_layers=0,
@@ -205,6 +223,10 @@ class TransformerConfig:
         hd, nh, nkv = self.dims_per_head, self.num_heads, self.kv_heads
         vd = self.v_dims_per_head
         attn = d * hd * nh + d * hd * nkv + d * vd * nkv + vd * nh * d
+        if self.kv_lora_rank:
+            r, rd = self.kv_lora_rank, self.rotary_dim
+            attn = (d * hd * nh + d * (r + rd) + r
+                    + r * nh * (hd - rd + vd) + vd * nh * d)
         if self.attn_bias:
             attn += nh * hd + nkv * hd + nkv * vd + d
         if self.qk_norm:
@@ -227,6 +249,7 @@ class TransformerConfig:
                 m = mlp * (self.moe_experts_held or E) + d * E
                 if self.moe_select_bias:
                     m += E
+                m += mlp * self.moe_shared_experts
                 if self.moe_use_residual:
                     m += mlp + 2 * d  # dense residual branch + coefficient
             total_mlp += m
@@ -311,6 +334,22 @@ CONFIGS: Dict[str, TransformerConfig] = {
         num_experts=256, moe_top_k=8, moe_score_func="sigmoid",
         moe_select_bias=True, moe_norm_topk_prob=True, moe_drop_tokens=False,
         remat=False),
+    # kakaocorp/kanana-2-30b-a3b-instruct-2601 config.json (``deepseek_v3``):
+    # 48 layers, 32 heads of latent attention (a 512-wide normed latent and
+    # one shared 64-wide rotated key row a token; keys 128 + 64, values 128,
+    # no q_lora), rotary on adjacent pairs at theta 1e6; layer 0 a dense
+    # SwiGLU of 6,144, the rest 128 routed experts of 768 beside 2 shared
+    # (one MLP of 1,536), sigmoid scores, 6 a token chosen on score + bias,
+    # gates renormalised and scaled by 2.448
+    "kanana-2-30b-a3b": TransformerConfig(
+        vocab_size=128256, hidden_size=2048, intermediate_size=6144,
+        moe_intermediate_size=768, num_layers=48, num_heads=32, head_dim=192,
+        v_head_dim=128, kv_lora_rank=512, rotary_dim=64,
+        rope_interleaved=True, rope_theta=1e6, max_seq_len=32768,
+        norm_eps=1e-6, dense_layers=1, num_experts=128, moe_top_k=6,
+        moe_score_func="sigmoid", moe_select_bias=True,
+        moe_norm_topk_prob=True, moe_routed_scale=2.448,
+        moe_shared_experts=2, moe_drop_tokens=False, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -377,8 +416,22 @@ def expert_counts_shape(cfg) -> Optional[Tuple[int, int]]:
 
 
 def is_hybrid(cfg: TransformerConfig) -> bool:
-    """Layers of two kinds (``layer_pattern``): stacks grouped by kind."""
+    """Attention layers of two kinds (``layer_pattern``): a K/V pool a
+    kind."""
     return cfg.layer_pattern is not None
+
+
+def is_grouped(cfg: TransformerConfig) -> bool:
+    """Layers that are not one uniform stack (two kinds of attention, or
+    leading dense layers before expert layers): ``params["layers"]`` is
+    ``{group: stack}`` (:func:`layer_groups`)."""
+    return cfg.layer_pattern is not None or cfg.dense_layers > 0
+
+
+def is_latent(cfg: TransformerConfig) -> bool:
+    """Latent attention (``kv_lora_rank``): one cache leaf with no head
+    axis, and two attention paths over it."""
+    return bool(cfg.kv_lora_rank)
 
 
 def window_ring_pages(window: int, page_size: int) -> int:
@@ -400,15 +453,17 @@ def pool_leaf_head_major(kv_heads: int, width: int) -> bool:
 
 
 def layer_plan(cfg: TransformerConfig):
-    """A hybrid model's layers in the published order: ``(group, index in
-    the group, kind, dense)`` each, ``group`` = ``<kind>_<dense|moe>``."""
-    if len(cfg.layer_pattern) < cfg.num_layers:
+    """A grouped model's layers in the published order: ``(group, index in
+    the group, kind, dense)`` each, ``group`` = ``<kind>_<dense|moe>``
+    (every layer ``full`` without a ``layer_pattern``)."""
+    pattern = cfg.layer_pattern or ("full",) * cfg.num_layers
+    if len(pattern) < cfg.num_layers:
         raise ValueError(
-            f"layer_pattern has {len(cfg.layer_pattern)} entries for "
+            f"layer_pattern has {len(pattern)} entries for "
             f"{cfg.num_layers} layers")
     plan, seen = [], {}
     # a model cut in depth runs the first layers of the published pattern
-    for i, kind in enumerate(cfg.layer_pattern[:cfg.num_layers]):
+    for i, kind in enumerate(pattern[:cfg.num_layers]):
         if kind not in ("full", "window"):
             raise ValueError(f"layer_pattern[{i}] = {kind!r}: full | window")
         dense = i < cfg.dense_layers or not has_moe(cfg)
@@ -419,8 +474,8 @@ def layer_plan(cfg: TransformerConfig):
 
 
 def layer_groups(cfg: TransformerConfig):
-    """``{group: (the uniform config of its layers, how many)}`` of a hybrid
-    model, in order of first appearance: each group is a plain stack that
+    """``{group: (the uniform config of its layers, how many)}`` of a
+    grouped model, in order of first appearance: each group is a plain stack that
     :func:`init_params`, :func:`param_specs` and :func:`_block` take as they
     take any model's, with the kind's KV heads, theta, sink and MLP."""
     groups: Dict[str, Any] = {}
@@ -473,6 +528,28 @@ def kind_layers(cfg: TransformerConfig):
     return kinds
 
 
+def _check_latent(cfg: TransformerConfig) -> None:
+    """What a latent-attention layer is built from, and what it leaves
+    out."""
+    if not (cfg.rotary_dim and cfg.position == "rope"
+            and cfg.norm == "rmsnorm"
+            and cfg.dims_per_head > cfg.rotary_dim):
+        raise NotImplementedError(
+            "latent attention (kv_lora_rank) takes RMSNorm, rotary "
+            "positions and head_dim = the unrotated width + rotary_dim")
+    for on, what in ((cfg.attn_bias, "attn_bias"), (cfg.qk_norm, "qk_norm"),
+                     (cfg.num_kv_heads not in (None, cfg.num_heads),
+                      "grouped KV heads"),
+                     (cfg.attn_value_scale != 1.0, "attn_value_scale"),
+                     (cfg.layer_pattern is not None, "layer_pattern"),
+                     (cfg.attention_layers is not None, "attention_layers")):
+        if on:
+            raise NotImplementedError(
+                f"latent attention (kv_lora_rank) does not take {what}")
+    if cfg.moe_shared_experts and cfg.activation != "swiglu":
+        raise NotImplementedError("shared experts are gated (swiglu) MLPs")
+
+
 def _check_qk_norm(cfg: TransformerConfig) -> None:
     if cfg.norm != "rmsnorm":
         raise NotImplementedError(
@@ -486,7 +563,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     differ per layer, so there is nothing to scan."""
     if isinstance(cfg.num_experts, (tuple, list)):
         return _init_params_het(cfg, rng)
-    if is_hybrid(cfg):
+    if is_grouped(cfg):
         # the parts outside the layers from a one-layer model of the first
         # group, then each group's own stack: ``params["layers"][group]``
         groups = layer_groups(cfg)
@@ -513,6 +590,16 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # residual-path projections scaled down by sqrt(2L) (GPT-2 init)
         "wo": dense(keys[3], (L, nh * vd, d), std / math.sqrt(2 * L)),
     }
+    if is_latent(cfg):
+        # no K and V projections: the latent and the shared key row from
+        # ``wkv_a``, the latent's own norm, and the up-projection to every
+        # head's keys (their unrotated part) and values
+        _check_latent(cfg)
+        r, rd = cfg.kv_lora_rank, cfg.rotary_dim
+        del layers["wk"], layers["wv"]
+        layers["wkv_a"] = dense(keys[1], (L, d, r + rd))
+        layers["kv_a_norm_scale"] = jnp.ones((L, r))
+        layers["wkv_b"] = dense(keys[2], (L, r, nh * (hd - rd + vd)))
     if cfg.qk_norm:
         _check_qk_norm(cfg)
         layers["q_norm_scale"] = jnp.ones((L, nh * hd))
@@ -566,6 +653,14 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     else:
         layers["w_in"] = dense(keys[4], mlp_shape(d, f))
         layers["w_down"] = dense(keys[6], mlp_shape(f, d), std / math.sqrt(2 * L))
+    if E > 1 and cfg.moe_shared_experts:
+        # the shared experts as one gated MLP of their widths together
+        fs = cfg.moe_shared_experts * f
+        sk = jax.random.split(jax.random.fold_in(rng, 18), 3)
+        layers["shared_w_gate"] = dense(sk[0], (L, d, fs))
+        layers["shared_w_up"] = dense(sk[1], (L, d, fs))
+        layers["shared_w_down"] = dense(sk[2], (L, fs, d),
+                                        std / math.sqrt(2 * L))
     if E > 1 and cfg.moe_use_residual:
         # residual MoE (PR-MoE, reference moe/layer.py use_residual): a dense
         # MLP branch + learned 2-way mixing coefficient per layer
@@ -709,7 +804,7 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     The ZeRO planner composes ('data','expert') on top of these."""
     if isinstance(cfg.num_experts, (tuple, list)):
         return _param_specs_het(cfg)
-    if is_hybrid(cfg):
+    if is_grouped(cfg):
         groups = layer_groups(cfg)
         specs = param_specs(next(iter(groups.values()))[0])
         specs["layers"] = {name: param_specs(g)["layers"]
@@ -722,6 +817,12 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         "attn_norm_scale": rep,
         "wq": col, "wk": col, "wv": col, "wo": row,
     }
+    if is_latent(cfg):
+        # the latent is every head's: its projection and norm replicated,
+        # the up-projection by head like any column-parallel one
+        del layers["wk"], layers["wv"]
+        layers.update(wkv_a=P(None, None, None), kv_a_norm_scale=rep,
+                      wkv_b=col)
     if cfg.qk_norm:     # over the column-parallel projection, as bq / bk
         layers.update(q_norm_scale=P(None, "model"),
                       k_norm_scale=P(None, "model"))
@@ -747,6 +848,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         layers.update(w_gate=mcol, w_up=mcol, w_down=mrow)
     else:
         layers.update(w_in=mcol, w_down=mrow)
+    if cfg.num_experts > 1 and cfg.moe_shared_experts:
+        layers.update(shared_w_gate=col, shared_w_up=col, shared_w_down=row)
     if cfg.num_experts > 1 and cfg.moe_use_residual:
         if cfg.activation == "swiglu":
             layers.update(res_w_gate=col, res_w_up=col, res_w_down=row)
@@ -1204,7 +1307,8 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
                             norm_topk_prob=cfg.moe_norm_topk_prob,
                             score_func=cfg.moe_score_func,
                             held=((cfg.moe_expert_first, cfg.moe_experts_held)
-                                  if cfg.moe_experts_held else None))
+                                  if cfg.moe_experts_held else None),
+                            routed_scale=cfg.moe_routed_scale)
 
             def experts(hc, mask):
                 return moe_ffn(
@@ -1229,6 +1333,12 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
                 m, aux, counts = m.reshape(B, S, D), aux.mean(), counts.sum(0)
             else:
                 m, aux, counts = experts(h, token_mask)
+            if "shared_w_gate" in lp:
+                # the shared experts: every token's, beside the routed sum
+                # (under a held share of the routed experts every chip
+                # computes them alike)
+                with jax.named_scope("mlp_shared"):
+                    m = m + _dense_mlp(cfg, lp, h, prefix="shared_")
             if "coefficient" in lp:
                 # residual MoE (reference moe/layer.py:16 use_residual): dense
                 # branch + learned softmax mixing coefficient
@@ -1271,6 +1381,53 @@ def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
     return q, k, v
 
 
+def _qkv_latent(cfg: TransformerConfig, lp: Dict[str, Any], h, positions):
+    """Post-norm activations ``h [B,S,d]`` of a latent-attention layer ->
+    ``q [B,S,Hq,hd]`` and the token's cache row ``latent [B,S,r + rd]``:
+    the ``r``-wide latent RMS-normed (``kv_a_norm_scale``), then the one key
+    row every head shares, rotated.  The rotary embedding takes the last
+    ``rd`` dims of each query head and that row, adjacent pairs ``(2i, 2i +
+    1)`` under ``rope_interleaved`` (a checkpoint loader that de-interleaves
+    both, as ``deepseek_v3`` does, permutes q and k alike and leaves every
+    score as it is)."""
+    B, S, _ = h.shape
+    hd, nh = cfg.dims_per_head, cfg.num_heads
+    r, rd = cfg.kv_lora_rank, cfg.rotary_dim
+    with jax.named_scope("attn_qkv"):
+        q = (h @ lp["wq"]).reshape(B, S, nh, hd)
+        kv = h @ lp["wkv_a"]
+        c = _norm(cfg, kv[..., :r], lp["kv_a_norm_scale"])
+        q_pe, k_pe = _rope(q[..., hd - rd:], kv[..., None, r:], positions,
+                           cfg.rope_theta, rd,
+                           interleaved=cfg.rope_interleaved)
+        q = jnp.concatenate([q[..., :hd - rd], q_pe], axis=-1)
+        latent = jnp.concatenate([c, k_pe[:, :, 0]], axis=-1)
+    return q, latent
+
+
+def _latent_up(cfg: TransformerConfig, wkv_b):
+    """``wkv_b [r, Hq * (nope + vd)]`` as its two views ``(W_UK [r, Hq,
+    nope], W_UV [r, Hq, vd])``: a head's keys (their unrotated part) and
+    values from the latent."""
+    nope = cfg.dims_per_head - cfg.rotary_dim
+    w = wkv_b.reshape(cfg.kv_lora_rank, cfg.num_heads, -1)
+    return w[..., :nope], w[..., nope:]
+
+
+def _latent_expand(cfg: TransformerConfig, latent, wkv_b):
+    """Cache rows ``latent [B,S,r + rd]`` -> every head's ``k [B,S,Hq,hd]``
+    (its own unrotated part, then the shared rotated row) and ``v
+    [B,S,Hq,vd]``: the expanded path, what a prompt attends over."""
+    B, S, _ = latent.shape
+    r, nh = cfg.kv_lora_rank, cfg.num_heads
+    w_uk, w_uv = _latent_up(cfg, wkv_b)
+    c = latent[..., :r]
+    k_pe = jnp.broadcast_to(latent[:, :, None, r:],
+                            (B, S, nh, cfg.rotary_dim))
+    k = jnp.concatenate([jnp.einsum("bsr,rhn->bshn", c, w_uk), k_pe], -1)
+    return k, jnp.einsum("bsr,rhv->bshv", c, w_uv)
+
+
 def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None):
     """Attention output ``[B,S,Hq,hd]`` through the output projection."""
     B, S = attn.shape[:2]
@@ -1306,7 +1463,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
 
     What attention reads, and where K/V go, is the caller's:
     ``attend(q, k, v) -> (out [B,S,Hq,hd], state)`` is handed the layer's
-    projections and returns whatever it keeps (:func:`_attend_full`: nothing;
+    projections (a latent-attention layer's: its queries, the tokens' cache
+    rows and its up-projection) and returns whatever it keeps (:func:`_attend_full`: nothing;
     :func:`_attend_cached`: the layer's cache buffers; :func:`_attend_paged`:
     the page pool).  ``proj``, ``token_mask`` and ``expert_offset`` are the
     serving path's (:func:`_qkv`, :func:`_attn_out`, :func:`_mlp`).
@@ -1316,12 +1474,18 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     h = x if post else _norm(cfg, x, lp["attn_norm_scale"],
                              lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, h)
-    q, k, v = _qkv(cfg, lp, h, positions, proj)
-    # named so "save_matmuls" can pin the projection outputs (post-rope, so
-    # the attention backward starts from exactly these tensors)
-    q = checkpoint_name(q, "q_proj")
-    k = checkpoint_name(k, "k_proj")
-    v = checkpoint_name(v, "v_proj")
+    if is_latent(cfg):
+        # latent attention: ``attend`` is handed the token's cache row in
+        # place of k, and the layer's up-projection in place of v
+        q, k = _qkv_latent(cfg, lp, h, positions)
+        v = lp["wkv_b"]
+    else:
+        q, k, v = _qkv(cfg, lp, h, positions, proj)
+        # named so "save_matmuls" can pin the projection outputs (post-rope,
+        # so the attention backward starts from exactly these tensors)
+        q = checkpoint_name(q, "q_proj")
+        k = checkpoint_name(k, "k_proj")
+        v = checkpoint_name(v, "v_proj")
     attn, state = attend(q, k, v)
     # named checkpoint: the "save_attn" remat policy stashes this one tensor
     # per layer ([B,S,H*hd] bf16) so the backward skips recomputing the whole
@@ -1355,6 +1519,8 @@ def _attend_full(cfg: TransformerConfig, positions, attn_impl: str = "xla",
     the uncached forward): nothing is kept."""
     def attend(q, k, v):
         with jax.named_scope("attn"):
+            if is_latent(cfg):     # the expanded path
+                k, v = _latent_expand(cfg, k, v)
             return _attention(cfg, q, k, v, positions, attn_impl,
                               custom_positions, window=window,
                               sink=sink), None
@@ -1443,15 +1609,17 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
     act_spec = P(BATCH_AXES, "seq" if seq_sharded else None, None)
     x = constrain_spec(x, act_spec)
 
-    if is_hybrid(cfg):
-        # layers of two kinds, inference only: each layer its group's
-        # uniform config and its own slice of the group's stack, through the
-        # masked product (window, sink, values of their own width)
+    if is_grouped(cfg):
+        # layers in groups, inference only: each layer its group's uniform
+        # config and its own slice of the group's stack, through the masked
+        # product (window, sink, values of their own width; a latent
+        # layer's expanded keys and values)
         if not deterministic or pld_theta is not None:
-            _hybrid_refuse("training (dropout, layer drop, a backward pass)")
+            _hybrid_refuse("training (dropout, layer drop, a backward pass)",
+                           cfg)
         if attn_impl not in ("xla", "auto"):
             _hybrid_refuse(f"the flash kernel (attn_impl={attn_impl!r}: keys "
-                           "and values of one width, no sink)")
+                           "and values of one width, no sink)", cfg)
         groups = layer_groups(cfg)
         for group, index, kind, _ in layer_plan(cfg):
             lp = jax.tree_util.tree_map(lambda a: a[index],
@@ -1733,8 +1901,9 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
     exactly twice.
     """
     _check_decodable(cfg, params, "cached decode")
-    if is_hybrid(cfg):
-        _hybrid_refuse("the contiguous cache (forward_cached, generate())")
+    if is_grouped(cfg) or is_latent(cfg):
+        _hybrid_refuse("the contiguous cache (forward_cached, generate())",
+                       cfg)
     B, S = tokens.shape
     next_slot = cache["next_slot"]
 
@@ -1793,7 +1962,10 @@ KV_QUANT_DTYPES = ("int8",)
 # A model with window layers (``layer_pattern``) keeps a pool per kind of
 # layer: ``k``/``v`` are its full layers', ``k_window``/``v_window`` its
 # window layers' rings, each leaf with its kind's KV heads and its own width.
-PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window")
+# A latent-attention model (``kv_lora_rank``) keeps ONE leaf, ``latent``:
+# a token's normed latent and its shared rotated key row, no head axis.
+PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window",
+                   "latent")
 
 
 def paged_pool_tuple(cache: Dict[str, Any]) -> tuple:
@@ -1880,6 +2052,15 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                     cache[n + suffix] = jnp.zeros(
                         (layers, pages) + rows + (w,), dtype)
         return cache
+    if is_latent(cfg):
+        if _normalize_kv_dtype(kv_dtype) is not None:
+            raise NotImplementedError(
+                "the int8 pool does not support a model with latent "
+                "attention (kv_lora_rank): its row scales are over K and V "
+                "heads, and a latent row is both at once")
+        return {"latent": jnp.zeros(
+            (cfg.num_layers, num_pages, page_size,
+             cfg.kv_lora_rank + cfg.rotary_dim), dtype)}
     lead = (cfg.num_layers, num_pages, page_size, cfg.kv_heads)
     kv = lead + (cfg.dims_per_head,)
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -1897,6 +2078,8 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
     scale planes ``[L, P, page]`` have no head dim, so they ride replicated
     alongside their (page-replicated) int8 payload."""
     kv = P(None, None, None, "model", None)
+    if is_latent(cfg):      # no head axis: every chip holds whole rows
+        return {"latent": P(None, None, None, None)}
     if is_hybrid(cfg):
         return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -2223,6 +2406,127 @@ def _adapter_proj(adapters, ad_scale):
     return proj
 
 
+def _merge_pages(pool, rows, write):
+    """``rows [B,S,...]`` of a block of tokens laid into ``pool [N, page,
+    ...]`` by the block's page-merge plan ``write = (src, keep, pages)``
+    (:func:`_paged_write_plan`): the rows of their pages ``[B,n_pg,page,
+    ...]`` over what those pages hold, gathered and scattered back whole."""
+    src, keep, pages = write
+    w = (1,) * (rows.ndim - 2)
+    new = jnp.take_along_axis(rows, src.reshape(*src.shape, *w),
+                              axis=1).astype(pool.dtype)
+    new = jnp.where(keep.reshape(*keep.shape, *w),
+                    new.reshape(*keep.shape, *rows.shape[2:]),
+                    pool[pages])
+    return pool.at[pages.reshape(-1)].set(new.reshape(-1, *new.shape[2:]))
+
+
+def _attention_latent_paged(cfg, q, wkv_b, pool, read):
+    """The absorbed path: ``q [B,S,Hq,hd]`` against the call's live pages of
+    the latent leaf ``pool [N, page, r + rd]``, ``read`` =
+    :func:`_paged_read_plan`'s list of (slot, page) pairs moved to this
+    layer's pages.  The same numbers as attention over the expanded keys
+    and values, in another order: with ``(W_UK, W_UV)`` =
+    :func:`_latent_up`, a query's unrotated part goes through ``W_UK`` once
+    (``r`` wide) and then meets each cached row ``[c ; k_pe]`` as it lies,
+    one ``r + rd``-wide key and one ``r``-wide value (``c`` itself) for
+    every head; ``W_UV`` is applied to the ``r``-wide sum at the end.
+    Nothing ``Hq`` times a row's width is ever made of the cache.
+
+    A step gathers the whole pages of N pairs and computes each PAIR's own
+    partial softmax (its maximum, sum and ``r``-wide weighted sum: two
+    batched products over the pair axis, ``Hq`` rows against a page); the
+    pairs are then folded into their slots' running state by a ``[B, N]``
+    one-hot product, each weighed by ``exp(its maximum - the slot's)``.  A
+    pair past the total is in no slot's row; a page ahead of a query has
+    every row masked, its maximum ``-1e30`` and its weight exactly 0 from
+    the slot's first page on (whose row 0 passes every query's mask).
+
+    The products take the gathered pages as ``[N, r + rd, page]``, the page
+    rows minor-most, whatever order the caller observed: that is how the
+    v5e stores this leaf (``major_to_minor`` (0, 1, 3, 2): ``r + rd`` = 576
+    is no whole number of 128 lanes), so there the transpose moves nothing
+    (:func:`_pool_views`' rule, for a leaf with no head axis) and the pool
+    stays where the layer scan carries it; on a backend that stores it
+    row-major it is the same arithmetic, and the CPU tests run the code
+    the chip times."""
+    steps, slot, pages, limit = read
+    B, S, Hq, hd = q.shape
+    r, rd = cfg.kv_lora_rank, cfg.rotary_dim
+    ps = pool.shape[1]
+    N = slot.shape[1]
+    w_uk, w_uv = _latent_up(cfg, wkv_b)
+    with jax.named_scope("absorb_q"):
+        qa = jnp.concatenate(
+            [jnp.einsum("bshn,rhn->bshr", q[..., :hd - rd], w_uk),
+             q[..., hd - rd:]], axis=-1)                    # [B,S,Hq,r+rd]
+    view = jnp.transpose(pool, (0, 2, 1))             # [N, r+rd, page]
+    row = jnp.arange(ps, dtype=jnp.int32)
+    slots = jnp.arange(B, dtype=jnp.int32)
+    scale = _sm_scale(cfg, hd)
+
+    def step(i, carry):
+        m, l, acc = carry                                # [B,S,Hq] x2, +[r]
+        at, pg, lim = slot[i], pages[i], limit[i]        # [N] [N] [N,S]
+        with jax.named_scope("kv_gather"):
+            c = view[pg]                                 # [N,r+rd,page]
+        own = jnp.minimum(at, B - 1)
+        qn = qa[own]                                     # [N,S,Hq,r+rd]
+        s = jnp.einsum("nshd,ndt->nsht", qn, c)
+        ok = row[None, None, :] <= lim[:, :, None]                # [N,S,ps]
+        s = jnp.where(ok[:, :, None, :], s.astype(jnp.float32) * scale,
+                      -1e30)
+        m_n = s.max(-1)                                           # [N,S,Hq]
+        p = jnp.exp(s - m_n[..., None])
+        u = jnp.einsum("nsht,nrt->nshr", p.astype(q.dtype), c[:, :r])
+        mine = at[None, :] == slots[:, None]                      # [B,N]
+        m_new = jnp.maximum(m, jnp.max(
+            jnp.where(mine[:, :, None, None], m_n[None], -1e30), axis=1))
+        alpha = jnp.exp(m - m_new)
+        w = jnp.exp(m_n - m_new[own])                             # [N,S,Hq]
+        fold = functools.partial(jnp.einsum, precision=(
+            jax.lax.Precision.HIGH), preferred_element_type=jnp.float32)
+        mine = mine.astype(jnp.float32)
+        l = l * alpha + fold("bn,nsh->bsh", mine, p.sum(-1) * w)
+        acc = (acc * alpha[..., None]
+               + fold("bn,nshr->bshr", mine,
+                      u.astype(jnp.float32) * w[..., None]))
+        return m_new, l, acc
+
+    m0 = jnp.full((B, S, Hq), -1e30, jnp.float32)
+    _, l, acc = jax.lax.fori_loop(
+        0, steps, step,
+        (m0, jnp.zeros_like(m0), jnp.zeros((B, S, Hq, r), jnp.float32)))
+    # a slot that was not read has l == 0: its output is 0, not NaN
+    u = (acc / jnp.where(l > 0, l, 1.0)[..., None]).astype(q.dtype)
+    with jax.named_scope("absorb_out"):
+        return jnp.einsum("bshr,rhv->bshv", u, w_uv)
+
+
+def _attend_latent_paged(cfg, pools, write, read, within=None):
+    """:func:`_block`'s ``attend`` of a latent-attention layer against the
+    paged pool, whose one leaf ``latent [N, page, r + rd]`` holds a token's
+    normed latent and its shared rotated key row.  The block's rows are
+    written by the same whole-page merge as K and V (``write``,
+    :func:`_paged_write_plan`).  Which path reads is decided by what the
+    call is: a block of more than one token starts its slot and attends
+    ``within`` itself (its positions) over the expanded keys and values,
+    reading nothing back (:func:`_attention_causal_block`, the expanded
+    path); one token a slot reads the live pairs of ``read`` through the
+    absorbed path (:func:`_attention_latent_paged`)."""
+    def attend(q, latent, wkv_b):
+        with jax.named_scope("kv_write"):
+            new = {"latent": _merge_pages(pools["latent"], latent, write)}
+        with jax.named_scope("attn"):
+            if within is not None:
+                k, v = _latent_expand(cfg, latent, wkv_b)
+                return _attention_causal_block(cfg, q, k, v, within), new
+            with jax.named_scope("attn_latent"):
+                return _attention_latent_paged(
+                    cfg, q, wkv_b, new["latent"], read), new
+    return attend
+
+
 def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
                   within=None):
     """:func:`_block`'s ``attend`` against the paged pool, addressed a whole
@@ -2257,18 +2561,8 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
     ``within = (positions, None)``: a full layer of such a model, whose
     block starts its slot too, attends within itself causally
     (:func:`_attention_causal_block`) and reads nothing back."""
-    src, keep, pages = write
-
     def merge(pool, rows):
-        # rows [B,S,...] -> the rows of their pages [B,n_pg,page,...], laid
-        # over what those pages hold
-        w = (1,) * (rows.ndim - 2)
-        new = jnp.take_along_axis(rows, src.reshape(*src.shape, *w),
-                                  axis=1).astype(pool.dtype)
-        new = jnp.where(keep.reshape(*keep.shape, *w),
-                        new.reshape(*keep.shape, *rows.shape[2:]),
-                        pool[pages])
-        return pool.at[pages.reshape(-1)].set(new.reshape(-1, *new.shape[2:]))
+        return _merge_pages(pool, rows, write)
 
     def attend(q, k, v):
         B, S, nkv, hd = k.shape
@@ -2519,11 +2813,15 @@ def _ring_read_plan(ring_table, start, seq_mask, ps: int, window: int):
             low.reshape(-1, pairs, S))
 
 
-def _hybrid_refuse(what: str):
+def _hybrid_refuse(what: str, cfg: Optional[TransformerConfig] = None):
+    """What only the uniform K/V models go through, refused by name."""
+    model = ("window and full attention layers (layer_pattern)"
+             if cfg is None or is_hybrid(cfg)
+             else "latent attention (kv_lora_rank)" if is_latent(cfg)
+             else "leading dense layers (dense_layers)")
     raise NotImplementedError(
-        f"{what} does not support a model with window and full attention "
-        "layers (layer_pattern): it runs through forward() and the paged "
-        "serving path (forward_paged)")
+        f"{what} does not support a model with {model}: it runs through "
+        "forward() and the paged serving path (forward_paged)")
 
 
 def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
@@ -2698,22 +2996,24 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
                                      start, seq_mask, expert_counts,
                                      pool_order)
-    num_layers, num_pages, ps = cache["k"].shape[:3]
+    if is_grouped(cfg) and adapters is not None:
+        _hybrid_refuse("multi-tenant adapter serving (per-slot LoRA "
+                       "factors)", cfg)
+    num_pages, ps = cache["latent" if is_latent(cfg) else "k"].shape[1:3]
     positions = (start[:, None]
                  + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :])
-    src, keep, write_pages = _paged_write_plan(page_table, start, seq_mask,
-                                               ps)
-    steps, read_slot, read_pages, limit = _paged_read_plan(
-        page_table, start, seq_mask, ps)
+    write = _paged_write_plan(page_table, start, seq_mask, ps)
+    # a latent model's block of more than one token starts its slot (the
+    # engine refuses what would start one elsewhere) and attends within
+    # itself; only one token a slot reads the pool
+    within = positions if is_latent(cfg) and tokens.shape[1] > 1 else None
+    read = (None if within is not None
+            else _paged_read_plan(page_table, start, seq_mask, ps))
 
     # a slot may run to positions past the learned table's end
     x = _embed(cfg, params, tokens,
                jnp.minimum(positions, cfg.max_seq_len - 1))
     x = constrain_spec(x, P(BATCH_AXES, None, None))
-
-    rng = jax.random.PRNGKey(0)
-    ad_scale = (adapters["scale"].astype(jnp.float32)
-                if adapters is not None else None)
 
     # The pool rides the layer scan as CARRY, stacked: [L*P, page, ...]
     # merges its two major axes (free in any layout), and layer l's page p
@@ -2722,38 +3022,70 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     # whole around the loop, more than half of a decode tick (PERF.md, PR 25)
     pools = {k: cache[k].reshape(-1, *cache[k].shape[2:])
              for k in PAGED_POOL_KEYS if k in cache}
-    # So do the expert stacks of a dropless model, for the same reason
-    # (an 800 MB slice a layer, cut out and copied before the grouped
-    # matmuls read it): they stay whole, [L*E, ...], outside the scan's xs,
-    # and layer l's experts are the groups from l*E on (moe_ffn_nodrop).
-    layers, experts = params["layers"], {}
+    # one scan a group of equal layers, in the published order: the whole
+    # model, or a model's leading dense layers and then its expert layers
+    groups = ({name: (g, n, params["layers"][name])
+               for name, (g, n) in layer_groups(cfg).items()}
+              if is_grouped(cfg)
+              else {None: (cfg, cfg.num_layers, params["layers"])})
+    first, counts = 0, None
+    for g, n, layers in groups.values():
+        x, pools, c = _paged_layers(
+            g, layers, x, pools, first, n, num_pages, positions, seq_mask,
+            write, read, within, pool_order, adapters)
+        first, counts = first + n, (counts if c is None else c)
+    logits = _head(cfg, params, x)
+    cache = {k: a.reshape(cache[k].shape) for k, a in pools.items()}
+    return (logits, cache, counts) if expert_counts else (logits, cache)
+
+
+def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
+                  positions, seq_mask, write, read, within, pool_order,
+                  adapters):
+    """``n`` equal layers of :func:`forward_paged` as one scan, the model's
+    layers ``first .. first + n - 1``: ``(x, pools, counts)`` with the pool
+    as carry, layer ``l``'s pages at ``l * num_pages`` of the stacked
+    leaves.  ``cfg`` is the layers' uniform config and ``layers`` their
+    stack (the whole model's, or one group's of :func:`layer_groups`)."""
+    rng = jax.random.PRNGKey(0)
+    ad_scale = (adapters["scale"].astype(jnp.float32)
+                if adapters is not None else None)
+    src, keep, write_pages = write
+    # The expert stacks of a dropless model stay out of the scan's xs, for
+    # the pool's reason (an 800 MB slice a layer, cut out and copied before
+    # the grouped matmuls read it): whole, [n*E, ...], and layer l's experts
+    # are the groups from l*E on (moe_ffn_nodrop).
+    experts = {}
     if expert_counts_shape(cfg):
         experts = {k: v.reshape(-1, *v.shape[2:]) for k, v in layers.items()
                    if k in _EXPERT_LEAVES}
         layers = {k: v for k, v in layers.items() if k not in experts}
+    held = cfg.moe_experts_held or cfg.num_experts
 
     def body(carry, layer):
         x, pools = carry
         lp, first_page, factors = layer
+        wplan = (src, keep, write_pages + first_page)
+        rplan = (None if read is None else
+                 (read[0], read[1], read[2] + first_page, read[3]))
+        attend = (_attend_latent_paged(cfg, pools, wplan, rplan, within)
+                  if is_latent(cfg) else
+                  _attend_paged(cfg, pools, wplan, rplan, pool_order))
+        layer = first_page // num_pages
         x, _, counts, pools = _block(
-            cfg, {**lp, **experts}, x, positions, rng,
-            _attend_paged(cfg, pools, (src, keep, write_pages + first_page),
-                          (steps, read_slot, read_pages + first_page, limit),
-                          pool_order),
+            cfg, {**lp, **experts}, x, positions, rng, attend,
             proj=_adapter_proj(factors, ad_scale), token_mask=seq_mask,
-            expert_offset=(first_page // num_pages * cfg.num_experts
-                           if experts else None))
+            expert_offset=(layer - first) * held if experts else None)
         x = constrain_spec(x, P(BATCH_AXES, None, None))
         return (x, pools), counts
 
     # the per-slot factor stacks scan beside the layers where the call has
     # them: each step's slice is THAT layer's
+    index = jnp.arange(n, dtype=jnp.int32)
     (x, pools), counts = jax.lax.scan(body, (x, pools), (
-        layers, jnp.arange(num_layers, dtype=jnp.int32) * num_pages,
+        layers, (index + first) * num_pages,
         None if adapters is None else adapters["factors"]))
-    logits = _head(cfg, params, x)
-    cache = {k: a.reshape(cache[k].shape) for k, a in pools.items()}
-    return (logits, cache, counts) if expert_counts else (logits, cache)
+    return x, pools, counts
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array,

@@ -47,6 +47,9 @@ class MoEConfig:
     # among all of them; the expert leaves hold ``count`` experts from
     # ``first`` on (the dropless path only).  None: every expert is here.
     held: Optional[Tuple[int, int]] = None
+    # what the chosen experts' gates are multiplied by, after any
+    # renormalisation (``routed_scaling_factor``; the dropless path only)
+    routed_scale: float = 1.0
 
 
 def _capacity(tokens_per_group: int, cfg: MoEConfig, deterministic: bool) -> int:
@@ -207,6 +210,8 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
             * jnp.mean(mask1.reshape(B, S, E), axis=1), axis=-1))
         if k > 1 and cfg.norm_topk_prob:
             vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
+        if cfg.routed_scale != 1.0:
+            vals = vals * cfg.routed_scale
 
     with jax.named_scope("moe_dispatch"):
         flat_expert = idx.reshape(T * k)
@@ -315,10 +320,10 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
                               select_bias=select_bias)
     assert expert_offset is None, "expert stacks are the dropless path's"
     if (cfg.score_func != "softmax" or cfg.held is not None
-            or select_bias is not None):
+            or select_bias is not None or cfg.routed_scale != 1.0):
         raise NotImplementedError(
-            "sigmoid scores, a selection bias and a held share of the "
-            "experts are the dropless path's (drop_tokens=False); the "
+            "sigmoid scores, a selection bias, a scale on the gates and a "
+            "held share of the experts are the dropless path's (drop_tokens=False); the "
             "capacity buffers route by softmax over experts that are all "
             "here")
     B, S, D = x.shape

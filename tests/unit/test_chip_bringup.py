@@ -313,15 +313,89 @@ def test_hybrid_paged_decode_keeps_both_pools_in_place_on_v5e(one_v5e_chip):
         assert max(sizes) <= pairs * int(np.prod(kind)), kind
 
 
+def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
+    """AOT: ``jit_serve_decode``'s body for Kanana-2 at the benchmark's
+    widths and geometry (depth 3: the dense layer and two expert layers, 16
+    experts held, 32 slots of 64 pages), compiled by the installed libtpu
+    with the latent leaf donated.  The v5e stores ``[L, P, 128, 576]`` with
+    the page rows minor-most (``major_to_minor`` (0, 1, 3, 2): 576 is no
+    whole number of 128 lanes), and the absorbed read takes the gathered
+    pages in that order whatever the caller observed: no op's result is the
+    size of the leaf but the in-place page scatters, a gather is one step's
+    (slot, page) pairs, and the program's temporaries are megabytes.  With
+    the products written over ``[N, page, 576]`` the compiler copied the
+    whole leaf in front of every layer's read (0.68 GB of temp at depth 2:
+    PERF.md, PR 32)."""
+    import re
+
+    import numpy as np
+
+    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models.transformer import (forward_paged,
+                                                  paged_read_pairs)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    depth = 3
+    cfg = get_config("kanana-2-30b-a3b", num_layers=depth,
+                     moe_experts_held=16, vocab_size=16032)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    slots, maxp, page, width = 32, 64, 128, 576
+    pages = 1 + slots * maxp
+    cache = {"latent": S((depth, pages, page, width), jnp.bfloat16)}
+
+    def tick(params, cache, tokens, table, start, mask):
+        logits, cache, counts = forward_paged(
+            cfg, params, tokens, cache, table, start, mask,
+            expert_counts=True)
+        return jnp.argmax(logits[:, -1], -1), counts, cache
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, cache, S((slots, 1), jnp.int32), S((slots, maxp), jnp.int32),
+        S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
+    layout = compiled.input_formats[0][1]["latent"].layout
+    assert tuple(layout.major_to_minor) == (0, 1, 3, 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e6
+    text = compiled.as_text()
+    # the scores and the sum over values reach the MXU as products
+    assert "attn_latent" in text
+    leaf, read = depth * pages * page * width, paged_read_pairs(slots, maxp)
+    gathers = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if sorted(dims[-2:]) != sorted([page, width]):
+            continue                     # not pages of latent rows
+        n = int(np.prod(dims))
+        if " gather(" in line:
+            gathers.add(n)
+        if n >= leaf:
+            assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
+                             r"\(| scatter\(|/scatter\"", line), line[:240]
+    assert gathers and max(gathers) == read * page * width
+
+
 # The optimized HLO of the programs the benchmark's other configurations run
-# (depth 2, metadata stripped), as the parent of PR 30 compiled them for the
-# v5e: a change to the code they share with a new configuration either
-# leaves these programs as they are, or says which instruction moved and why.
+# (depth 2, MiMo at its cut's 7; metadata and the four source tables
+# stripped), as the parent of PR 32 compiled them for the v5e, which are the
+# parent of PR 30's for the first four: a change to the code they share with
+# a new configuration either leaves these programs as they are, or says
+# which instruction moved and why.  The hash is over every computation of
+# the module: the installed XLA prints its four source tables (``FileNames``
+# ... ``StackFrames``) AHEAD of the computations, so they are cut out of the
+# text, which is not cut at them.
 PROGRAMS_AT_PR_29 = {
-    "opt-1.3b_decode": "af3bcf9b66555cdf",
-    "olmoe-1b-7b_decode": "79db2ac2ddb9f704",
-    "olmoe-1b-7b_prefill_256": "9b758bab88e95216",
-    "pythia_step": "28efbc2bb5a12dc6",
+    "opt-1.3b_decode": "65db27332cd211e1",
+    "olmoe-1b-7b_decode": "99fe542b1b7b4288",
+    "olmoe-1b-7b_prefill_256": "ba49df7d1cba2c84",
+    "pythia_step": "08b45152c86e09db",
+    "mimo-v2.5_decode": "e90810420bd2a420",
+    "mimo-v2.5_prefill_256": "7636fa83161144ea",
 }
 
 
@@ -360,6 +434,29 @@ def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
 
         compiled = jax.jit(jax.value_and_grad(loss)).lower(
             shapes(cfg), S((4, 1025), jnp.int32)).compile()
+    elif program.startswith("mimo"):
+        from benchmark.lib import system
+        from deepspeed_tpu.models import CausalLM
+
+        with open(os.path.join(REPO, "benchmark", "configs",
+                               "mimo-v2.5-ep16-d7.json")) as f:
+            cfg = system.transformer_config(json.load(f), False)
+        slots = 8
+        cache = jax.tree_util.tree_map(
+            lambda a: S(a.shape, a.dtype), jax.eval_shape(
+                lambda: CausalLM(cfg).init_paged_cache(
+                    1 + slots * 16, 128, dtype=jnp.bfloat16)))
+
+        def tick(params, cache, tokens, table, start, mask):
+            logits, cache, counts = forward_paged(
+                cfg, params, tokens, cache, table, start, mask,
+                expert_counts=True)
+            return jnp.argmax(logits[:, -1], -1), cache, counts
+
+        b, s = (slots, 1) if program.endswith("decode") else (1, 256)
+        compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+            shapes(cfg), cache, S((b, s), jnp.int32), S((b, 16), jnp.int32),
+            S((b,), jnp.int32), S((b, s), jnp.bool_)).compile()
     else:
         name, what = program.split("_", 1)
         over, slots, order = {
@@ -383,6 +480,9 @@ def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
             S((b, 16), jnp.int32), S((b,), jnp.int32),
             S((b, s), jnp.bool_)).compile()
     text = re.sub(r",? ?metadata=\{[^}]*\}", "", compiled.as_text())
-    text = re.sub(r"stack_frame_id=\d+", "", text).split("\nFileNames")[0]
+    text = re.sub(r"stack_frame_id=\d+", "", text)
+    text = re.sub(r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:\d+ .*\n)*", "\n", text)
+    assert text.count("\n") > 1000          # the computations, not a header
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PROGRAMS_AT_PR_29[program]

@@ -235,22 +235,25 @@ def test_beat_once_concurrent_callers_lose_no_beats(tmp_path):
 
 
 def test_watchdog_quiet_while_peers_renew(tmp_path):
+    # six leases long.  A lease is 0.2 s: beside five other workers that
+    # compile, the renewing thread was starved for two leases of 0.05 s and
+    # the watchdog, rightly, declared it dead (the driver's run of PR 32)
     s = _store(tmp_path)
     stop = threading.Event()
 
     def renew():
         while not stop.is_set():
-            beat(s, "h1", generation=1, lease_s=0.05)
-            time.sleep(0.01)
+            beat(s, "h1", generation=1, lease_s=0.2)
+            time.sleep(0.04)
 
     t = threading.Thread(target=renew, daemon=True)
     t.start()
     wd = HeartbeatWatchdog(s, "h0", generation=1, peers=["h1"],
-                           lease_s=0.05, miss_limit=2, renew_s=0.01,
+                           lease_s=0.2, miss_limit=2, renew_s=0.04,
                            on_peer_dead=lambda h: None)
     wd.start()
     try:
-        time.sleep(0.3)
+        time.sleep(1.2)
         assert wd.dead == []
     finally:
         wd.stop()
