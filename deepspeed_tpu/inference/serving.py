@@ -126,8 +126,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.transformer import (PAGE_SIZE, block_read_rows, is_hybrid,
-                                  is_latent,
+from ..models.transformer import (PAGE_SIZE, block_read_rows,
+                                  causal_walk_steps, is_hybrid, is_latent,
                                   kind_layers, paged_read_rows,
                                   window_read_rows, window_ring_pages)
 from ..observability.device_profiler import (device_trace_unit,
@@ -1630,8 +1630,13 @@ class ServingEngine:
                         # attends within itself
                         gathered_rows=0 if self._ring or self._latent else
                         self._gathered_rows([n_shared + S_tail], 1)) as sp:
-            if self._ring and get_tracer().enabled:
-                self._set_kv_row_attrs(sp, [S_tail], 1, block=s_pad)
+            if (self._ring or self._latent) and get_tracer().enabled:
+                # the chunk steps its full or latent layers run, bounded by
+                # the prompt's own length, beside the whole bucket's
+                sp.set(walk_steps=causal_walk_steps(s_pad, S_tail),
+                       walk_steps_bucket=causal_walk_steps(s_pad))
+                if self._ring:
+                    self._set_kv_row_attrs(sp, [S_tail], 1, block=s_pad)
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 if match.cow_src is not None:
@@ -1778,15 +1783,17 @@ class ServingEngine:
         reads by its plans: a full layer each slot's own pages, a window
         layer the ring pages under the last ``window`` positions.  A
         ``block`` of tokens (a prompt's bucket) reads itself: a full layer
-        each chunk of queries the chunks of keys at or before it, a window
-        layer each chunk two chunks of keys (a short block: all of itself,
-        once)."""
+        each chunk of queries that holds a real token the chunks of keys at
+        or before it, a window layer each chunk two chunks of keys, of a
+        long block the groups of chunks that hold a real token (a short
+        block: all of itself, once)."""
         lengths = np.asarray(lengths, np.int64)
         W = self.model.config.window_size
         full, window = self._kind_heads["full"], self._kind_heads["window"]
         if block:
-            rows = block_read_rows(block)
-            ring_rows = block_read_rows(block, W)
+            tokens = int(lengths.max())
+            rows = block_read_rows(block, tokens=tokens)
+            ring_rows = block_read_rows(block, W, tokens=tokens)
             ring_live = int(lengths.sum())
         else:
             rows = self._gathered_rows(lengths, slots)

@@ -2510,17 +2510,20 @@ def _attend_latent_paged(cfg, pools, write, read, within=None):
     written by the same whole-page merge as K and V (``write``,
     :func:`_paged_write_plan`).  Which path reads is decided by what the
     call is: a block of more than one token starts its slot and attends
-    ``within`` itself (its positions) over the expanded keys and values,
-    reading nothing back (:func:`_attention_causal_block`, the expanded
-    path); one token a slot reads the live pairs of ``read`` through the
-    absorbed path (:func:`_attention_latent_paged`)."""
+    ``within`` itself (``(positions, None, reach)``, as
+    :func:`_attend_paged`'s) over the expanded keys and values, reading
+    nothing back (:func:`_attention_causal_block`, the expanded path); one
+    token a slot reads the live pairs of ``read`` through the absorbed path
+    (:func:`_attention_latent_paged`)."""
     def attend(q, latent, wkv_b):
         with jax.named_scope("kv_write"):
             new = {"latent": _merge_pages(pools["latent"], latent, write)}
         with jax.named_scope("attn"):
             if within is not None:
+                positions, _, reach = within
                 k, v = _latent_expand(cfg, latent, wkv_b)
-                return _attention_causal_block(cfg, q, k, v, within), new
+                return _attention_causal_block(cfg, q, k, v, positions,
+                                               reach), new
             with jax.named_scope("attn_latent"):
                 return _attention_latent_paged(
                     cfg, q, wkv_b, new["latent"], read), new
@@ -2556,11 +2559,13 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
     A window layer: ``write`` is :func:`_ring_write_plan`'s (the block's
     last pages into the slot's ring), ``sink`` the layer's learned sink
     logits, and a block of more than one token attends ``within`` itself
-    (``(positions, window)``, :func:`_attention_window_block`) instead of
-    reading the ring, which cannot hold a prompt longer than the window.
-    ``within = (positions, None)``: a full layer of such a model, whose
-    block starts its slot too, attends within itself causally
-    (:func:`_attention_causal_block`) and reads nothing back."""
+    (``(positions, window, reach)``, :func:`_attention_window_block`)
+    instead of reading the ring, which cannot hold a prompt longer than the
+    window.  ``within = (positions, None, reach)``: a full layer of such a
+    model, whose block starts its slot too, attends within itself causally
+    (:func:`_attention_causal_block`) and reads nothing back; ``reach``
+    (:func:`_block_reach`) is how far into the block real tokens reach,
+    where both stop."""
     def merge(pool, rows):
         return _merge_pages(pool, rows, write)
 
@@ -2581,12 +2586,12 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
                                            P(None, None, "model", None))
         with jax.named_scope("attn"):
             if within is not None:
-                positions, window = within
+                positions, window, reach = within
                 if window is None:
-                    return _attention_causal_block(cfg, q, k, v,
-                                                   positions), new
+                    return _attention_causal_block(cfg, q, k, v, positions,
+                                                   reach), new
                 return _attention_window_block(cfg, q, k, v, positions,
-                                               window, sink), new
+                                               window, sink, reach), new
             return _attention_paged(cfg, q, new, read, pool_order, sink), new
     return attend
 
@@ -2597,24 +2602,60 @@ WINDOW_BLOCK_CHUNKS = 16
 CAUSAL_BLOCK_CHUNK = 512
 
 
-def block_read_rows(block: int, window: Optional[int] = None) -> int:
+def causal_walk_steps(block: int, tokens: Optional[int] = None) -> int:
+    """Chunk steps a full or latent layer runs for a block of ``block``
+    tokens that starts its slot and holds ``tokens`` real ones from its
+    start (all of them if ``None``): chunk ``i`` of the ``r`` chunks that
+    real tokens reach walks ``i + 1`` chunks of keys and a chunk past them
+    none, ``r (r + 1) / 2``; a short block is one masked product.  The
+    host's copy of :func:`_attention_causal_block`'s trip counts (the
+    ``walk_steps`` span attrs of a prompt)."""
+    C = CAUSAL_BLOCK_CHUNK
+    if block % C or block < 2 * C:
+        return 1
+    r = -(-min(block if tokens is None else tokens, block) // C)
+    return r * (r + 1) // 2
+
+
+def block_read_rows(block: int, window: Optional[int] = None,
+                    tokens: Optional[int] = None) -> int:
     """K/V rows a block of ``block`` tokens that starts its slot reads of
-    itself, a layer: through a full layer each chunk of queries the chunks
-    of keys at or before it (:func:`_attention_causal_block`), through a
-    window layer each chunk of ``window`` queries two chunks of keys
+    itself, a layer, when ``tokens`` of them from its start are real (all
+    if ``None``): through a full layer each chunk of queries that holds a
+    real token the chunks of keys at or before it
+    (:func:`causal_walk_steps`), through a window layer each chunk of
+    ``window`` queries two chunks of keys, of a long block the groups of
+    ``WINDOW_BLOCK_CHUNKS`` chunks that hold a real token
     (:func:`_attention_window_block`); a short block all of itself, once.
     The host's copy of those functions' shapes (the ``kv_rows_*`` span
     attrs of a prompt)."""
+    tokens = block if tokens is None else min(tokens, block)
     if window is not None:
-        return 2 * block if block % window == 0 and block >= 2 * window \
-            else block
-    n = block // CAUSAL_BLOCK_CHUNK
-    if block % CAUSAL_BLOCK_CHUNK or n < 2:
+        if block % window or block < 2 * window:
+            return block
+        group = WINDOW_BLOCK_CHUNKS * window
+        if block > group and block % group == 0:
+            block = -(-tokens // group) * group
+        return 2 * block
+    C = CAUSAL_BLOCK_CHUNK
+    if block % C or block < 2 * C:
         return block
-    return CAUSAL_BLOCK_CHUNK * n * (n + 1) // 2
+    return C * causal_walk_steps(block, tokens)
 
 
-def _attention_causal_block(cfg, q, k, v, positions):
+def _block_reach(seq_mask):
+    """How far into a block ``[B,S]`` real tokens reach: the tokens from
+    its start to the last real one of any row (a traced scalar: one program
+    a bucket).  A prompt is right-padded to its bucket, so what lies past
+    holds padding alone, and a block that attends within itself
+    (:func:`_attention_causal_block`, :func:`_attention_window_block`) runs
+    nothing for the chunks there."""
+    S = seq_mask.shape[1]
+    return jnp.max(jnp.where(seq_mask, jnp.arange(1, S + 1, dtype=jnp.int32),
+                             0))
+
+
+def _attention_causal_block(cfg, q, k, v, positions, reach=None):
     """A block of tokens ``[B,S,...]`` that starts its slot, through a full
     layer: plain causal attention over the block's own keys, values of
     their own width, nothing read from the pool.  Where ``S`` is whole
@@ -2624,7 +2665,15 @@ def _attention_causal_block(cfg, q, k, v, positions):
     half, and no array is ``[S, S]`` or rescaled ``S``-wide a step (the
     paged read's per-slot state is, which at 16,384 queries rewrote 0.5 GB
     every two pages: PERF.md, PR 30).  A shorter block takes the masked
-    product."""
+    product.
+
+    ``reach`` (:func:`_block_reach`; ``None``: every row is real) bounds
+    the walk by what the block holds: the query chunks past the
+    ``ceil(reach / CAUSAL_BLOCK_CHUNK)`` that real tokens reach hold a
+    bucket's padding alone, the last and longest walks, and run no step.
+    Their rows come out 0 (a ``0 / 0`` there would reach real rows of the
+    next layer through ``0 * NaN``); every key chunk at or before a real
+    query chunk is walked as before."""
     B, S, Hq, hd = q.shape
     Hkv, vd, C = k.shape[2], v.shape[-1], CAUSAL_BLOCK_CHUNK
     if S % C or S < 2 * C:
@@ -2651,24 +2700,30 @@ def _attention_causal_block(cfg, q, k, v, positions):
             return (m_new, l * alpha + p.sum(-1),
                     acc * alpha[..., None] + pv.astype(jnp.float32))
 
+        steps = i + 1 if reach is None else jnp.where(i * C < reach, i + 1, 0)
         m0 = jnp.full((B, Hkv, G, C), -1e30, jnp.float32)
-        _, l, acc = jax.lax.fori_loop(0, i + 1, step, (
+        _, l, acc = jax.lax.fori_loop(0, steps, step, (
             m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, C, vd),
                                               jnp.float32)))
-        return (acc / l[..., None]).astype(q.dtype)   # [B,Hkv,G,C,vd]
+        # a chunk that ran no step has l == 0: its output is 0, not NaN
+        return (acc / jnp.where(l > 0, l, 1.0)[..., None]).astype(q.dtype)
 
     out = jax.lax.map(chunk, (jnp.arange(n, dtype=jnp.int32), qc))
     # [n,B,Hkv,G,C,vd] -> [B, n*C, Hkv*G, vd]
     return jnp.transpose(out, (1, 0, 4, 2, 3, 5)).reshape(B, S, Hq, vd)
 
 
-def _attention_window_block(cfg, q, k, v, positions, window: int, sink=None):
+def _attention_window_block(cfg, q, k, v, positions, window: int, sink=None,
+                            reach=None):
     """A block of tokens ``[B,S,...]`` that starts its slot, through a
     window layer: every query sees the block's own keys at most ``window -
     1`` positions back.  Where ``S`` is whole chunks of ``window`` tokens, a
     chunk of queries meets its own chunk of keys and the one before it
     (scores ``[.., S/window, window, 2*window]``, never ``[S, S]``); a
-    shorter or ragged block takes the masked product."""
+    shorter or ragged block takes the masked product.  A long block takes
+    ``WINDOW_BLOCK_CHUNKS`` chunks at a time, and of those groups the ones
+    that real tokens ``reach`` (:func:`_block_reach`; ``None``: all): a
+    group of a bucket's padding alone is not computed and comes out 0."""
     B, S, Hq, hd = q.shape
     Hkv, C = k.shape[2], window
     if S % C or S < 2 * C:
@@ -2703,12 +2758,18 @@ def _attention_window_block(cfg, q, k, v, positions, window: int, sink=None):
         # a long prompt some chunks at a time: the float32 scores of all
         # 128 chunks of a 16,384-token bucket are 1 GB, and there are
         # several arrays of their size
-        def group(x):
-            return jnp.moveaxis(x.reshape(x.shape[0], n // m, m,
-                                          *x.shape[2:]), 1, 0)
-        out = jax.lax.map(lambda a: chunks(*a), (
-            group(qc), group(kk), group(vv), ok.reshape(n // m, m, C, 2 * C)))
-        out = jnp.moveaxis(out, 0, 1)
+        def group(i, out):
+            def take(x, axis=1):
+                return jax.lax.dynamic_slice_in_dim(x, i * m, m, axis)
+
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, chunks(take(qc), take(kk), take(vv), take(ok, 0)),
+                i * m, axis=1)
+
+        groups = (n // m if reach is None
+                  else jnp.minimum(-(-reach // (m * C)), n // m))
+        out = jax.lax.fori_loop(0, groups, group, jnp.zeros(
+            (B, n, C, Hkv, G, v.shape[-1]), jnp.result_type(q.dtype, v.dtype)))
     else:
         out = chunks(qc, kk, vv, ok)
     return out.reshape(B, S, Hq, v.shape[-1])
@@ -2875,6 +2936,7 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
         "window": (_ring_write_plan(ring_table, start, seq_mask, ps),
                    _ring_read_plan(ring_table, start, seq_mask, ps, W)
                    if S == 1 else None)}
+    reach = None if S == 1 else _block_reach(seq_mask)
     x = _embed(cfg, params, tokens,
                jnp.minimum(positions, cfg.max_seq_len - 1))
     x = constrain_spec(x, P(BATCH_AXES, None, None))
@@ -2906,7 +2968,7 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                           read, orders[kind], sink=lp.get("attn_sink"),
                           within=(None if S == 1 else
                                   (positions, W if kind == "window"
-                                   else None))),
+                                   else None, reach))),
             token_mask=seq_mask,
             expert_offset=(jnp.int32(index * (g.moe_experts_held
                                               or g.num_experts))
@@ -3005,8 +3067,10 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     write = _paged_write_plan(page_table, start, seq_mask, ps)
     # a latent model's block of more than one token starts its slot (the
     # engine refuses what would start one elsewhere) and attends within
-    # itself; only one token a slot reads the pool
-    within = positions if is_latent(cfg) and tokens.shape[1] > 1 else None
+    # itself, as far as its real tokens reach; only one token a slot reads
+    # the pool
+    within = ((positions, None, _block_reach(seq_mask))
+              if is_latent(cfg) and tokens.shape[1] > 1 else None)
     read = (None if within is not None
             else _paged_read_plan(page_table, start, seq_mask, ps))
 

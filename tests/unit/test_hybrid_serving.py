@@ -85,17 +85,35 @@ def test_params_and_pools_are_grouped_by_kind():
     assert sum(k == "full" for _, _, k, _ in T.layer_plan(full)) == 9
 
 
-def test_engine_serves_both_pools_and_gives_the_rings_back(engine):
+def _sparse_prompts():
+    """Prompts that leave more than half of their bucket's chunks of 4
+    empty (3 of 16 tokens: one chunk of four) and about half (17 of 32; 33
+    of 64, which leaves a window layer's last chunk of 16 empty too)."""
+    rng = np.random.default_rng(11)
+    return [Request(rid=f"sparse{n}", arrival_time=0.0, max_new_tokens=24,
+                    input_ids=rng.integers(0, 256, (n,)).astype(np.int32))
+            for n in (3, 17, 33)]
+
+
+@pytest.mark.parametrize("chunk", [512, 4])
+def test_engine_serves_both_pools_and_gives_the_rings_back(engine,
+                                                           monkeypatch, chunk):
+    """``chunk`` 4: every prompt's full layers walk their chunks, as far as
+    the prompt's own tokens reach (512, as shipped: the masked product)."""
     from deepspeed_tpu.models.transformer import forward
 
+    monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", chunk)
+    if chunk == 4:      # and its window layers their chunks of 16 one by one
+        monkeypatch.setattr(T, "WINDOW_BLOCK_CHUNKS", 1)
     sv = engine.serving(**SERVE_KW)
     assert sv._ring == 3 and sv._exec.window_pages == 1 + 3 * 3
     assert sv._prefix is None                       # off for this model
-    reqs = _requests(7)
+    reqs = _requests(7) + _sparse_prompts()
     results = sv.run(reqs, max_ticks=4000)
-    assert len(results) == 7
+    assert len(results) == 10
     cfg, params = engine.model.config, engine.params
-    for r in results[:3]:           # greedy, token for token, past the ring
+    # greedy, token for token, past the ring
+    for r in results[:3] + results[-3:]:
         ids = np.concatenate([r.input_ids, r.output_ids])
         assert len(ids) > 16 + 8
         greedy = np.asarray(jnp.argmax(jax.jit(
@@ -199,9 +217,12 @@ def test_ring_read_plan_is_the_hosts_formula(page, window):
         assert not pages[slot >= B].any()
 
 
-def test_spans_carry_the_rows_of_each_kind_and_the_held_pairs(engine):
+def test_spans_carry_the_rows_of_each_kind_and_the_held_pairs(engine,
+                                                              monkeypatch):
     from deepspeed_tpu.observability import Span, configure_tracer, get_tracer
 
+    monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", 4)
+    monkeypatch.setattr(T, "WINDOW_BLOCK_CHUNKS", 1)
     sv = engine.serving(**SERVE_KW)
     sv.run(_requests(2, seed=1), max_ticks=2000)        # warm
     configure_tracer(enabled=True)
@@ -228,6 +249,20 @@ def test_spans_carry_the_rows_of_each_kind_and_the_held_pairs(engine):
         assert a["kv_rows_full"] == a["gathered_rows"] * 4
         assert a["kv_rows_window"] % 20 == 0
         assert a["kv_live_rows_window"] <= 3 * 16 * 20
+    # a prompt's full layers walk chunks of 4 as far as its own tokens
+    # reach into the bucket; its window layers two chunks of 16 a chunk that
+    # holds a token (taken one at a time here)
+    for a in prefill:
+        r, n = -(-a["tokens"] // 4), a["bucket"] // 4
+        assert a["walk_steps"] == r * (r + 1) // 2
+        assert a["walk_steps_bucket"] == n * (n + 1) // 2
+        assert a["kv_rows_full"] == 4 * a["walk_steps"] * 4
+        assert a["kv_rows_full"] == 4 * T.block_read_rows(
+            a["bucket"], tokens=a["tokens"])
+        assert a["kv_rows_window"] == 20 * T.block_read_rows(
+            a["bucket"], 16, tokens=a["tokens"])
+    assert (sum(a["walk_steps"] for a in prefill)
+            < sum(a["walk_steps_bucket"] for a in prefill))
     # 4 of 16 experts held: about a quarter of the pairs land here
     share = (sum(a["moe_local_pairs"] for a in prefill)
              / sum(a["moe_pairs"] for a in prefill))

@@ -117,17 +117,31 @@ def test_absorbed_is_expanded_to_rounding():
     assert np.allclose(np.asarray(pool[1:]).reshape(-1, 40)[:n], latent[0])
 
 
-def test_engine_serves_the_latent_pool_token_for_token(engine):
+def _sparse_prompts():
+    """Prompts that leave more than half of their bucket's chunks of 4
+    empty (3 of 16 tokens: one chunk of four) and about half (17 of 32)."""
+    rng = np.random.default_rng(11)
+    return [Request(rid=f"sparse{n}", arrival_time=0.0, max_new_tokens=9,
+                    input_ids=rng.integers(0, 256, (n,)).astype(np.int32))
+            for n in (3, 17)]
+
+
+@pytest.mark.parametrize("chunk", [512, 4])
+def test_engine_serves_the_latent_pool_token_for_token(engine, monkeypatch,
+                                                       chunk):
+    """``chunk`` 4: every prompt here walks its chunks, as far as its own
+    tokens reach (512, as shipped: they take the masked product)."""
     from deepspeed_tpu.models.transformer import forward
 
+    monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", chunk)
     sv = engine.serving(**SERVE_KW)
     assert sv._prefix is None and sv._latent        # sharing off for it
     assert sv._exec._pool_keys == ("latent",)
     assert sv._exec.moe_shape == (3, 4)
-    results = sv.run(_requests(7), max_ticks=4000)
-    assert len(results) == 7
+    results = sv.run(_requests(7) + _sparse_prompts(), max_ticks=4000)
+    assert len(results) == 9
     cfg, params = engine.model.config, engine.params
-    for r in results[:3]:
+    for r in results[:3] + results[-2:]:
         ids = np.concatenate([r.input_ids, r.output_ids])
         greedy = np.asarray(jnp.argmax(jax.jit(
             lambda p, t: forward(cfg, p, t))(params, jnp.asarray(ids)[None]),
@@ -140,7 +154,7 @@ def test_engine_serves_the_latent_pool_token_for_token(engine):
     assert sv.health()["lookahead_dropped_total"] == 0
     # the plain loop emits the same tokens
     plain = engine.serving(lookahead=False, **SERVE_KW).run(
-        _requests(7), max_ticks=4000)
+        _requests(7) + _sparse_prompts(), max_ticks=4000)
     assert all((a.output_ids == b.output_ids).all()
                for a, b in zip(results, plain))
 
@@ -166,9 +180,31 @@ def test_latent_rows_read_are_the_devices_trip_count(page):
         assert live == int(((lengths + 1) * active).sum()) <= rows
 
 
-def test_spans_carry_the_latent_rows_and_the_held_pairs(engine):
+@pytest.mark.parametrize("block,chunk", [(64, 16), (32, 4), (16, 4)])
+def test_a_prompts_rows_read_are_the_walks_trip_count(monkeypatch, block,
+                                                      chunk):
+    """``block_read_rows(block, tokens=n)`` (and the ``walk_steps`` span
+    attr) is what the walk runs for the mask the prefill program builds:
+    query chunk ``i`` of the ``r`` that real tokens reach runs ``i + 1``
+    steps of ``chunk`` keys, a chunk past them none."""
+    monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", chunk)
+    for n in range(1, block + 1):
+        seq_mask = (jnp.arange(block, dtype=jnp.int32) < n)[None, :]
+        assert int(T._block_reach(seq_mask)) == n
+        r = -(-n // chunk)          # query chunk i runs where i * chunk < n
+        steps = r * (r + 1) // 2
+        assert T.causal_walk_steps(block, n) == steps
+        assert T.block_read_rows(block, tokens=n) == chunk * steps
+    assert T.block_read_rows(block) == T.block_read_rows(block, tokens=block)
+    # a block under two chunks is one masked product over all of itself
+    assert T.causal_walk_steps(chunk, 1) == 1
+    assert T.block_read_rows(chunk, tokens=1) == chunk
+
+
+def test_spans_carry_the_latent_rows_and_the_held_pairs(engine, monkeypatch):
     from deepspeed_tpu.observability import Span, configure_tracer, get_tracer
 
+    monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", 4)
     sv = engine.serving(**SERVE_KW)
     sv.run(_requests(2, seed=1), max_ticks=2000)        # warm
     configure_tracer(enabled=True)
@@ -187,6 +223,12 @@ def test_spans_carry_the_latent_rows_and_the_held_pairs(engine):
         assert a["gathered_rows"] % (T.paged_read_pairs(3, 12) * 8) == 0
     for a in prefill:       # a prompt attends within itself: nothing read
         assert a["gathered_rows"] == 0
+        # ... in chunks of 4, as far as its own tokens reach into the bucket
+        r, n = -(-a["tokens"] // 4), a["bucket"] // 4
+        assert a["walk_steps"] == r * (r + 1) // 2
+        assert a["walk_steps_bucket"] == n * (n + 1) // 2
+    assert (sum(a["walk_steps"] for a in prefill)
+            < sum(a["walk_steps_bucket"] for a in prefill))
     for a in decode + prefill:
         assert a["moe_experts_held"] == 3 * 4
         assert a["moe_local_pairs"] == a["moe_rows"] <= a["moe_pairs"]
