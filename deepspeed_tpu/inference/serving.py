@@ -355,6 +355,7 @@ class _Ahead:
     params: Any
     lanes: Any
     adapters: Any
+    seq: int    # its ``serve.launch``'s, for the ``serve.fetch`` that reads it
 
 
 class ServingEngine:
@@ -446,6 +447,10 @@ class ServingEngine:
         self._in_run = False
         self.lookahead_launched = 0
         self.lookahead_dropped = 0
+        # decode and prefill programs launched so far: the k-th launch is
+        # the k-th such program the device runs (``seq`` of the
+        # ``serve.launch`` / ``serve.fetch`` spans)
+        self._launch_seq = 0
         # bounded admission: submissions past max_queue waiting requests are
         # shed with a typed result + retry-after hint (None = unbounded)
         self.max_queue = int(max_queue) if max_queue is not None else None
@@ -1654,13 +1659,11 @@ class ServingEngine:
                         # donor prefix its target-side boundary does
                         self._spec.cow(self._cow_prog, match.cow_src,
                                        private[0])
-                pt_row = jax.tree_util.tree_map(jnp.asarray,
-                                                self._tables(slot))
-                toks_j = jnp.asarray(toks)
-                out, counts = self._exec.split_counts(np.asarray(
-                    self._exec.prefill(
-                        s_pad, pt_row, toks_j, S_tail, n_shared,
-                        lane_t, lane_k, lane_p, lane_s, adapter_row)))
+                out, seq, pt_row, toks_j = self._launch_prefill(
+                    s_pad, slot, toks, S_tail, n_shared,
+                    lane_t, lane_k, lane_p, lane_s, adapter_row)
+                out, counts = self._exec.split_counts(
+                    self._fetch(out, f"prefill_{s_pad}", seq))
                 tok = int(out.flat[0])
                 # host fetch above lands inside the watchdog window
                 if counts is not None and get_tracer().enabled:
@@ -1744,6 +1747,41 @@ class ServingEngine:
         import contextlib
 
         return contextlib.nullcontext()
+
+    def _launch_decode(self, lengths, fed, lanes, adapters, ahead: int):
+        """Enqueue one decode program under a ``serve.launch`` span:
+        ``(its device output, its seq)``.  ``ahead``: ticks in flight
+        behind the one being fetched once this launch has returned."""
+        with trace_span("serve.launch", program="decode",
+                        seq=self._launch_seq + 1, ahead=ahead):
+            out = self._exec.decode(self._tables(), lengths, fed,
+                                    self._active, lanes, adapters=adapters)
+        self._launch_seq += 1
+        return out, self._launch_seq
+
+    def _launch_prefill(self, s_pad: int, slot: int, toks: np.ndarray,
+                        n_real: int, start: int, *lane_and_adapter):
+        """Upload ``slot``'s page-table row and the padded prompt and
+        enqueue the bucket's prefill program, all under one
+        ``serve.launch`` span (the uploads are part of what a launch
+        costs): ``(its device output, its seq, the row and the tokens as
+        uploaded)``."""
+        with trace_span("serve.launch", program=f"prefill_{s_pad}",
+                        seq=self._launch_seq + 1, ahead=0):
+            pt_row = jax.tree_util.tree_map(jnp.asarray, self._tables(slot))
+            toks_j = jnp.asarray(toks)
+            out = self._exec.prefill(s_pad, pt_row, toks_j, n_real, start,
+                                     *lane_and_adapter)
+        self._launch_seq += 1
+        return out, self._launch_seq, pt_row, toks_j
+
+    @staticmethod
+    def _fetch(out, program: str, seq: int) -> np.ndarray:
+        """The blocking read of a program's output (host fetch = device
+        sync) under a ``serve.fetch`` span that names the launch whose
+        output it reads."""
+        with trace_span("serve.fetch", program=program, seq=seq):
+            return np.asarray(out)
 
     def _lanes_jnp(self):
         return self._exec.lanes(self._lane_temp, self._lane_top_k,
@@ -1874,9 +1912,10 @@ class ServingEngine:
                                    self._last_tok[self._active]))
 
     def _take_ahead(self, lanes, adapters):
-        """The device output of this tick if it was launched ahead on
-        exactly the state the host now holds, else ``None``, and then every
-        tick launched after it goes too.  What they wrote is one K/V row a
+        """The device output of this tick and its launch's ``seq`` if it
+        was launched ahead on exactly the state the host now holds, else
+        ``None``, and then every tick launched after it goes too.  What
+        they wrote is one K/V row a
         slot each, past the slot's length, which the ticks launched in
         their place write again; and where a slot ended under them (a
         deadline) and its pages went to a request admitted since, the order
@@ -1887,8 +1926,8 @@ class ServingEngine:
         if not self._ahead:
             return None
         if self._ahead_current(lanes, adapters):
-            return self._ahead.popleft().out
-        trace_count("serve.lookahead_dropped", float(len(self._ahead)))
+            ahead = self._ahead.popleft()
+            return ahead.out, ahead.seq
         self.lookahead_dropped += len(self._ahead)
         self._ahead.clear()
         return None
@@ -1899,18 +1938,16 @@ class ServingEngine:
         device (``nxt``: this tick's), so the device goes from one program
         into the next while the host fetches, emits and schedules."""
         depth = self._lookahead_depth()
-        launched = depth - len(self._ahead)
-        if launched > 0:
-            trace_count("serve.lookahead_launched", float(launched))
         while len(self._ahead) < depth:
             fed = self._ahead[-1].out if self._ahead else nxt
             lengths = self._lengths + np.int32(len(self._ahead) + 1) * \
                 self._active.astype(np.int32)
+            out, seq = self._launch_decode(lengths, fed, lanes, adapters,
+                                           ahead=len(self._ahead) + 1)
             self._ahead.append(_Ahead(
-                self._exec.decode(self._tables(), lengths, fed,
-                                  self._active, lanes, adapters=adapters),
-                fed, self._page_table.copy(), lengths, self._active.copy(),
-                self._exec.params, lanes, adapters))
+                out, fed, self._page_table.copy(), lengths,
+                self._active.copy(), self._exec.params, lanes, adapters,
+                seq))
             self.lookahead_launched += 1
 
     def _decode_tick(self, rid_map: Optional[Dict[str, str]] = None,
@@ -1937,11 +1974,10 @@ class ServingEngine:
                 sp.set(slot_rids=rid_map, ahead=len(self._ahead))
             maybe_fire(SITE_SERVE_DECODE, tick=self._tick)
             with self._armed(f"serve.decode tick {self._tick}"):
-                nxt = self._take_ahead(lanes, adapters)
-                if nxt is None:
-                    nxt = self._exec.decode(self._tables(), self._lengths,
-                                            self._last_tok, self._active,
-                                            lanes, adapters=adapters)
+                nxt, seq = (self._take_ahead(lanes, adapters)
+                            or self._launch_decode(self._lengths,
+                                                   self._last_tok, lanes,
+                                                   adapters, ahead=0))
                 if not held:
                     self._launch_ahead(nxt, lanes, adapters)
                 if rid_map is not None:
@@ -1959,7 +1995,8 @@ class ServingEngine:
                 # host fetch = device sync; an MoE model's expert counts
                 # come with the tokens
                 out = nxt
-                nxt, counts = self._exec.split_counts(np.asarray(out))
+                nxt, counts = self._exec.split_counts(
+                    self._fetch(out, "decode", seq))
                 self._last_out = (out, nxt)
                 if counts is not None and rid_map is not None:
                     self._set_moe_attrs(sp, counts,
@@ -2125,10 +2162,9 @@ class ServingEngine:
                 with self._armed(f"serve.probe slot={slot}"):
                     # greedy lane — the same program shape admissions use;
                     # the host fetch means the probe must really complete
-                    np.asarray(self._exec.prefill(
-                        s_pad, jax.tree_util.tree_map(jnp.asarray,
-                                                      self._tables(slot)),
-                        jnp.asarray(toks), 1, 0, 0.0, 0, 1.0, 0))
+                    out, seq, *_ = self._launch_prefill(
+                        s_pad, slot, toks, 1, 0, 0.0, 0, 1.0, 0)
+                    self._fetch(out, f"prefill_{s_pad}", seq)
         except BaseException as e:
             self._page_table[slot, :] = 0
             self._fence_tick[slot] = self._tick

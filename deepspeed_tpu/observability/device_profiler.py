@@ -150,7 +150,11 @@ class DeviceTraceCapture:
         try:
             import jax.profiler
 
-            jax.profiler.stop_trace()
+            # the seconds the stop blocks its caller (the serving loop's
+            # tick, a train step), as a host span of their own: a reader of
+            # the whole window takes them out of the host's work
+            with trace_mod.trace_span("profile.stop"):
+                jax.profiler.stop_trace()
         except Exception as e:   # pragma: no cover - backend hiccup
             logger.warning("device trace stop failed (%s); trace under %s "
                            "may be incomplete", e, self.log_dir)

@@ -313,6 +313,10 @@ def test_trace_smoke_tool(tmp_path):
     # the overhead guarantee docs/OBSERVABILITY.md quotes: a disabled
     # instrumentation site costs well under a microsecond
     assert out["disabled_span_ns"] < 5000
+    # and the two per-program sites of the serving loop (ISSUE 34)
+    assert set(out["disabled_site_ns"]) == {"serve.launch", "serve.fetch"}
+    assert max(out["disabled_site_ns"].values()) < 5000
+    assert {"serve.launch", "serve.fetch"} <= set(out["span_names"])
     # the global tracer was restored to disabled
     assert not get_tracer().enabled
 

@@ -47,6 +47,7 @@ def tracer():
     tracer.reset()
     yield tracer
     configure_tracer(enabled=False)
+    tracer.reset()      # the ring is the process's: leave the next file none
 
 
 def _serving(engine, lookahead, **kw):
@@ -325,11 +326,10 @@ def test_lookahead_keeps_span_attrs_per_tick(engine, tracer):
 def test_the_tracer_sees_what_was_in_flight_launched_and_dropped(engine,
                                                                  tracer):
     """Each ``serve.decode`` span says how many ticks were in flight when it
-    opened (``ahead``), and the ticks launched ahead and dropped reach the
-    tracer as counts: a traced run gives the share of its ticks that were
-    taken from a launch ahead."""
-    from deepspeed_tpu.observability.trace import CounterEvent
-
+    opened (``ahead``), and the ticks launched ahead and dropped are
+    ``health()``'s totals and can be read off the ``serve.launch`` spans: a
+    launch with ``ahead`` >= 1 was launched ahead, one no ``serve.fetch``
+    names was dropped."""
     sv = _serving(engine, True)
     reqs = _requests(3)
     reqs[0].deadline_s = 100.0
@@ -345,10 +345,14 @@ def test_the_tracer_sees_what_was_in_flight_launched_and_dropped(engine,
     # in flight as a tick's span opens = in flight as the step before ended
     assert [s.attrs["ahead"] for s in spans] == [0] + seen[:-1]
     assert seen == [LOOKAHEAD_TICKS] * 5 + [1] * 3
-    counts = {name: sum(e.value for e in events
-                        if isinstance(e, CounterEvent) and e.name == name)
-              for name in ("serve.lookahead_launched",
-                           "serve.lookahead_dropped")}
-    assert counts == {"serve.lookahead_launched": sv.lookahead_launched,
-                      "serve.lookahead_dropped": sv.lookahead_dropped}
+    launches = [s.attrs for s in events if s.name == "serve.launch"
+                and s.attrs["program"] == "decode"]
+    fetched = {s.attrs["seq"] for s in events if s.name == "serve.fetch"}
+    in_flight = {a.seq for a in sv._ahead}
+    health = sv.health()
+    assert {"serve.lookahead_launched": health["lookahead_launched_total"],
+            "serve.lookahead_dropped": health["lookahead_dropped_total"]} == {
+        "serve.lookahead_launched": sum(a["ahead"] >= 1 for a in launches),
+        "serve.lookahead_dropped": sum(
+            a["seq"] not in fetched | in_flight for a in launches)}
     assert sv.lookahead_dropped == LOOKAHEAD_TICKS

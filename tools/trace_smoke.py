@@ -9,7 +9,8 @@ short serving stream — then validates the Chrome/Perfetto export:
 - the expected span names from both paths are present (``train.batch``,
   ``train.data``, ``train.step``, ``train.fetch``, ``train.monitor``,
   ``serve.tick``, ``serve.admit``, ``serve.prefill``, ``serve.publish``,
-  ``serve.decode``, ``serve.emit``, ``serve.gauges``);
+  ``serve.decode``, ``serve.launch``, ``serve.fetch``, ``serve.emit``,
+  ``serve.gauges``);
 - nesting is sane: every recorded depth is non-negative, every duration is
   non-negative, and within each thread child spans lie inside their
   parents' intervals (events sorted by ts must nest like balanced
@@ -17,7 +18,9 @@ short serving stream — then validates the Chrome/Perfetto export:
 
 It also MEASURES the disabled-tracer cost — the exact call instrumentation
 sites make (``trace_span(...)`` enter/exit) timed over many iterations with
-tracing off — and reports it as ``disabled_span_ns``.  That number is the
+tracing off — and reports it as ``disabled_span_ns``, and the same for the
+calls the two per-program sites make (``serve.launch``, ``serve.fetch``:
+``disabled_site_ns``).  That number is the
 overhead guarantee docs/OBSERVABILITY.md quotes: the serving tick loop runs
 3-4 such calls per tick against a device call measured in milliseconds.
 
@@ -45,18 +48,31 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
 EXPECTED_SPANS = ("train.batch", "train.data", "train.step", "train.fetch",
                   "train.monitor", "serve.tick", "serve.admit",
                   "serve.prefill", "serve.publish", "serve.decode",
-                  "serve.emit", "serve.gauges")
+                  "serve.launch", "serve.fetch", "serve.emit",
+                  "serve.gauges")
 
 
-def measure_disabled_span_ns(iters: int = 200_000) -> float:
+# the per-program sites of the serving loop, with the attrs they pass
+# (inference/serving.py ``_launch_decode`` / ``_fetch``): a tick pays each once
+DISABLED_SITES = {
+    "overhead.probe": lambda i: {"tick": i},
+    "serve.launch": lambda i: {"program": "decode", "seq": i, "ahead": 1},
+    "serve.fetch": lambda i: {"program": "decode", "seq": i},
+}
+
+
+def measure_disabled_span_ns(iters: int = 200_000,
+                             site: str = "overhead.probe") -> float:
     """ns per disabled ``with trace_span(...)`` — the instrumentation-site
-    cost when tracing is off (must be noise against a device call)."""
+    cost when tracing is off (must be noise against a device call), for
+    the call one of ``DISABLED_SITES`` makes."""
     from deepspeed_tpu.observability import configure_tracer, trace_span
 
     configure_tracer(enabled=False)
+    attrs = DISABLED_SITES[site]
     t0 = time.perf_counter()
     for i in range(iters):
-        with trace_span("overhead.probe", tick=i):
+        with trace_span(site, **attrs(i)):
             pass
     dt = time.perf_counter() - t0
     return dt / iters * 1e9
@@ -224,10 +240,13 @@ def run_smoke(trace_path: str = None, train_steps: int = 2,
         problems.append("RequestResult timeline fields inconsistent")
     if "dstpu_span_count" not in prom:
         problems.append("prometheus exposition missing span aggregates")
-    disabled_ns = measure_disabled_span_ns()
-    if disabled_ns > 5000:   # 5µs/callsite would no longer be "noise"
-        problems.append(f"disabled span cost {disabled_ns:.0f}ns "
-                        "is not negligible")
+    site_ns = {site: measure_disabled_span_ns(site=site)
+               for site in DISABLED_SITES}
+    for site, ns in site_ns.items():
+        if ns > 5000:   # 5µs/callsite would no longer be "noise"
+            problems.append(f"disabled span cost {ns:.0f}ns at {site} "
+                            "is not negligible")
+    disabled_ns = site_ns.pop("overhead.probe")
     return {
         "metric": "trace-smoke",
         "trace_path": trace_path,
@@ -236,6 +255,7 @@ def run_smoke(trace_path: str = None, train_steps: int = 2,
                               if e.get("ph") == "X"}),
         "requests_served": len(results),
         "disabled_span_ns": round(disabled_ns, 1),
+        "disabled_site_ns": {k: round(v, 1) for k, v in site_ns.items()},
         "histogram_slo_ok": not hist_slo_problems,
         "problems": problems,
         "ok": not problems,
