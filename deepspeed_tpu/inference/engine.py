@@ -14,6 +14,7 @@ captured graph.
 """
 from __future__ import annotations
 
+import threading
 import weakref
 from collections import OrderedDict
 from typing import Any, Callable, Optional
@@ -60,6 +61,9 @@ class InferenceEngine:
         self._config = config or DeepSpeedInferenceConfig()
         self._model = model if hasattr(model, "apply_cached") else None
         self._gen_cache: OrderedDict = OrderedDict()
+        # serving() hands self.params over and takes the placed tree back:
+        # one builder at a time (a fleet's members restart on own threads)
+        self._handover = threading.Lock()
         if model is not None:
             apply_fn = apply_fn or getattr(model, "apply_fn", None) or getattr(
                 model, "apply", None)
@@ -202,7 +206,23 @@ class InferenceEngine:
             # top of this pin — the scale rows dequantize back into the
             # pinned compute dtype inside the gather
             kwargs["dtype"] = self._config.compute_jnp_dtype
-        return ServingEngine(self._model, self.params, **kwargs)
+        # The tree is handed over, not shared: the executor holds the
+        # weights as its decode program reads them (a leaf a layer where
+        # the forward walks the layers in Python, each leaf in the layout
+        # the compiled tick asks for: docs/SERVING.md "Weight placement"),
+        # what it replaces is freed as it is replaced, and the placed tree,
+        # which every forward reads, is this engine's from here on.
+        def hand_over():
+            tree, self.params = self.params, None
+            return tree
+
+        # (the executor refuses what it cannot serve before it takes the
+        # tree; a failure after that, out of memory under the pool, leaves
+        # this engine without weights as it leaves the process without room)
+        with self._handover:
+            sv = ServingEngine(self._model, hand_over, **kwargs)
+            self.params = sv.params
+        return sv
 
     def supervised_serving(self, max_restarts: int = 5, **kwargs):
         """A :class:`~.serving_supervisor.ServingSupervisor` whose engine

@@ -47,12 +47,14 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, cow_copy_pool,
                                   expert_counts_shape, is_hybrid, is_latent,
                                   paged_pool_cache, paged_pool_order,
-                                  paged_pool_tuple, window_ring_pages)
+                                  paged_pool_tuple, per_layer_leaves,
+                                  window_ring_pages)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .kv_tiering import extract_pool_page, inject_pool_page
@@ -80,7 +82,26 @@ _TIER_EXTRACT_PROG = jax.jit(extract_pool_page)
 _TIER_INJECT_PROG = jax.jit(inject_pool_page, donate_argnums=(0,))
 
 
-def pool_jit(fn, mesh, pool_specs, n_leading: int):
+# What an executor compiles ahead of time to learn how the device wants its
+# state, by everything the compiled program is made from: the program that
+# makes the pool, and the layouts a decode program asked for its weights
+# (MeshExecutor._tick_formats).  An engine built again over the same model,
+# geometry and placement (a warm restart, a fleet's members) reads them here
+# and compiles nothing to find them.
+_AOT: Dict[Any, Any] = {}
+_AOT_MAX = 128
+
+
+def _aot(key, build):
+    """``build()`` once a process a ``key``."""
+    if key not in _AOT:
+        if len(_AOT) >= _AOT_MAX:
+            _AOT.clear()
+        _AOT[key] = build()
+    return _AOT[key]
+
+
+def pool_jit(fn, mesh, pool_specs, n_leading: int, in_shardings=None):
     """jit a pool-consuming program.  ``fn`` takes the pool as its second
     argument and returns it last, as ONE canonical tuple (so donating that
     one argument donates every pool leaf at once — payload AND scale planes
@@ -90,24 +111,28 @@ def pool_jit(fn, mesh, pool_specs, n_leading: int):
     outputs (tokens/counts) followed by the pool tuple on its canonical
     shardings (``pool_specs``: one PartitionSpec per pool array) — without
     ``out_shardings`` GSPMD is free to pick a different pool placement per
-    program and the donated buffers would reshard every tick."""
+    program and the donated buffers would reshard every tick.
+    ``in_shardings`` (one entry an argument, ``None``: as the argument lies)
+    is for the one ahead-of-time compile that asks the compiler which
+    layouts it wants (``MeshExecutor._tick_formats``)."""
+    kw = {} if in_shardings is None else {"in_shardings": in_shardings}
     if mesh is None:
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(fn, donate_argnums=(1,), **kw)
     rep = NamedSharding(mesh, P())
     pools = tuple(NamedSharding(mesh, s) for s in pool_specs)
     if n_leading == 0:   # the program returns the bare pool tuple
-        return jax.jit(fn, donate_argnums=(1,), out_shardings=pools)
+        return jax.jit(fn, donate_argnums=(1,), out_shardings=pools, **kw)
     return jax.jit(fn, donate_argnums=(1,),
-                   out_shardings=tuple([rep] * n_leading) + (pools,))
+                   out_shardings=tuple([rep] * n_leading) + (pools,), **kw)
 
 
-def _named_pool_jit(prog, name: str, mesh, pool_specs):
+def _named_pool_jit(prog, name: str, mesh, pool_specs, in_shardings=None):
     """``pool_jit`` for a serving program that returns (tokens, pools),
     under a stable name: the XLA module is ``jit_<name>`` in a device
     trace, a compile log and an HLO dump, whatever the Python closure that
     built it is called (docs/OBSERVABILITY.md "Device-time correlation")."""
     prog.__name__ = prog.__qualname__ = name
-    return pool_jit(prog, mesh, pool_specs, 1)
+    return pool_jit(prog, mesh, pool_specs, 1, in_shardings)
 
 
 def place_params(params, mesh):
@@ -131,6 +156,24 @@ def place_params(params, mesh):
         lambda s: NamedSharding(mesh, s), specs,
         is_leaf=lambda x: isinstance(x, P))
     return jax.device_put(params, shardings)
+
+
+def _as_given(xs):
+    """What a re-laying program computes: its ``out_shardings`` are the
+    work.  One function a process, so that the jit finds by its arguments
+    what an earlier engine compiled."""
+    return xs
+
+
+def _lies_as(leaf, fmt) -> bool:
+    """Whether the device array ``leaf`` lies as ``fmt`` (a ``Format``)
+    says: on that sharding, its axes in that order, tiled so.  Read field by
+    field: a layout that a compiled program reports and the one an array
+    reports for the same bytes need not compare equal whole."""
+    have, want = leaf.format.layout, fmt.layout
+    return (leaf.sharding == fmt.sharding
+            and tuple(have.major_to_minor) == tuple(want.major_to_minor)
+            and tuple(have.tiling or ()) == tuple(want.tiling or ()))
 
 
 def pool_bytes(*pools) -> Dict[str, int]:
@@ -168,7 +211,14 @@ class MeshExecutor:
     def __init__(self, model, params, num_pages: int, page_size: int,
                  b_slots: int, dtype=None, kv_dtype=None, mesh=None,
                  prefix_cache: bool = True, host_tier: bool = False,
-                 catalog: Optional[ProgramCatalog] = None, adapters=None):
+                 catalog: Optional[ProgramCatalog] = None, adapters=None,
+                 pages_per_slot: Optional[int] = None):
+        """``params``: the model's tree (a stack a group), or a call that
+        returns it and keeps no hold on it (``InferenceEngine.serving()``
+        hands its tree over so: what the placement below replaces is then
+        freed before the pool is made, and nothing is held twice).
+        ``pages_per_slot``: the width of the decode program's page table
+        (default: the pool's pages over the slots)."""
         self.model = model
         self.mesh = mesh
         # multi-tenant adapter serving (docs/SERVING.md): when an
@@ -208,8 +258,6 @@ class MeshExecutor:
                     f"model axis ({self.tp}): the paged KV pool shards its "
                     "head dim over 'model' (paged_cache_specs) — pick tp "
                     "dividing kv_heads or replicate with tp=1")
-        # params ride the same auto-TP shardings generate() uses; already-
-        # committed trees (InferenceEngine.serving()) pass through
         # a model with window layers keeps a second pool, its slots' rings
         # (docs/SERVING.md "Two kinds of layer"): what moves or shares
         # pages of ONE pool says so instead of serving a wrong answer
@@ -241,61 +289,90 @@ class MeshExecutor:
                 if on:
                     raise NotImplementedError(
                         f"{what} does not support a model with {unlike}")
-        self.params = place_params(params, mesh)
-        # capture the placed tree's shape so LIVE weight updates
-        # (update_params — hybrid rollout, docs/HYBRID.md) can be pinned to
-        # the exact avals + shardings every program compiled against: a jit
-        # caches on both, so an update committed to the captured placement
-        # is a guaranteed cache hit, never a recompile
-        leaves = jax.tree_util.tree_leaves(self.params)
-        self._param_treedef = jax.tree_util.tree_structure(self.params)
-        self._param_avals = [(tuple(getattr(x, "shape", ())),
-                              str(getattr(x, "dtype", type(x).__name__)))
-                             for x in leaves]
-        self._param_shardings = (
-            jax.tree_util.tree_map(lambda x: x.sharding, self.params)
-            if leaves and all(hasattr(x, "sharding") for x in leaves)
-            else None)
         if self.ring_pages:
-            cache = model.init_paged_cache(
-                self.num_pages, self.page_size, dtype=dtype,
-                window_pages=self.window_pages)
+            pool_kw = {"dtype": dtype, "window_pages": self.window_pages}
         else:
-            cache = model.init_paged_cache(self.num_pages, self.page_size,
-                                           dtype=dtype, kv_dtype=kv_dtype)
+            pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
         # (k, v) full precision, (k, v, k_scale, v_scale) quantized — every
         # program, COW/tier mover and byte gauge runs off this one tuple,
         # so the int8 layout is the SAME code path, not a parallel one
         self.kv_dtype = kv_dtype if kv_dtype is None else str(kv_dtype)
-        self.quantized = "k_scale" in cache
-        self._pool_keys = tuple(k for k in PAGED_POOL_KEYS if k in cache)
+
+        def fresh_cache():
+            return model.init_paged_cache(self.num_pages, self.page_size,
+                                          **pool_kw)
+
+        shapes = jax.eval_shape(fresh_cache)
+        self._pool_keys = tuple(k for k in PAGED_POOL_KEYS if k in shapes)
+        self.quantized = "k_scale" in shapes
         self._pool_specs = tuple(specs[k] for k in self._pool_keys)
         self._kv_spec = specs[self._pool_keys[0]]
-        # commit the fresh pool to its placement: a jit caches on the arg's
-        # committed-ness, so an UNcommitted initial pool would cost each
-        # program one extra compile when the second call arrives holding
-        # committed program outputs.  On a mesh the pool must live on the
-        # same device set as the (sharded) params — KV heads over 'model'
-        # (scale planes carry no head dim and ride replicated).
-        if mesh is not None:
-            self.pools = tuple(
-                jax.device_put(cache[k], NamedSharding(mesh, specs[k]))
-                for k in self._pool_keys)
-        else:
-            self.pools = tuple(
-                jax.device_put(cache[k], cache[k].sharding)
-                for k in self._pool_keys)
+        # The program that makes the pool, compiled before it runs: what it
+        # reports of its results is how the device will store each leaf, so
+        # the weights can be placed against the decode program while no pool
+        # is in memory yet.  On a mesh the pool lives on the same device set
+        # as the (sharded) params — KV heads over 'model' (scale planes
+        # carry no head dim and ride replicated).
+        make_pool = _aot(
+            ("pool", type(model), cfg, mesh, self.num_pages, self.page_size,
+             tuple(sorted((k, str(v)) for k, v in pool_kw.items()))),
+            lambda: jax.jit(
+                lambda: paged_pool_tuple(fresh_cache()), out_shardings=(
+                    None if mesh is None else tuple(
+                        NamedSharding(mesh, s) for s in self._pool_specs))
+            ).lower().compile())
+        self._pool_avals = tuple(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=f.sharding)
+            for a, f in zip(paged_pool_tuple(shapes),
+                            make_pool.output_formats))
         # how the device stores a K/V leaf, where that is not row-major:
         # the paged read hands its loop the pool in that order
         # (models.transformer._pool_views); leaves of different widths may
         # be stored differently, so a pool a kind gives the order a leaf
-        self.pool_order = (
-            {k: paged_pool_order(a)
-             for k, a in zip(self._pool_keys, self.pools)}
-            if self.ring_pages else paged_pool_order(self.pools[0]))
+        orders = [paged_pool_order(f) for f in make_pool.output_formats]
+        self.pool_order = (dict(zip(self._pool_keys, orders))
+                           if self.ring_pages else orders[0])
         self._decode_prog = self._build_decode()
+        # The weights, placed ONCE in the form the decode program consumes
+        # (docs/SERVING.md "Weight placement"), as the pool is stored the
+        # way that program reads it: a leaf a layer where the forward walks
+        # its layers in Python, each leaf in the layout the compiled tick
+        # asks for.  Every program of the engine is compiled against this
+        # placement.  ``tree`` is rebound at each step, so a tree that was
+        # handed over is freed as it is replaced, before the pool exists.
+        self.pages_per_slot = int(pages_per_slot or max(
+            1, (self.num_pages - 1) // self.b_slots))
+        # (first onto the auto-TP shardings generate() uses; a tree already
+        # committed to this mesh, InferenceEngine.serving()'s, passes through)
+        tree = place_params(params() if callable(params) else params, mesh)
+        tree, cut = self._per_layer(tree)
+        # capture the placed tree's shape so LIVE weight updates
+        # (update_params — hybrid rollout, docs/HYBRID.md) can be pinned to
+        # the exact avals + shardings + layouts every program compiled
+        # against: a jit caches on all three, so an update committed to the
+        # captured placement is a guaranteed cache hit, never a recompile
+        self._param_formats = self._tick_formats(tree)
+        self.params, relaid, nbytes = self._in_formats(tree)
+        del tree
+        # the programs that cut and copied run behind this thread and hold
+        # what they read until they end: the pool is made after them, or it
+        # would be allocated beside the leaves they replace
+        jax.block_until_ready(self.params)
+        self.weight_placement = {"weight_leaves_split": cut,
+                                 "weight_leaves_relaid": relaid,
+                                 "weight_bytes_relaid": nbytes}
+        leaves, self._param_treedef = jax.tree_util.tree_flatten(self.params)
+        self._param_avals = [(tuple(getattr(x, "shape", ())),
+                              str(getattr(x, "dtype", type(x).__name__)))
+                             for x in leaves]
+        # the fresh pool, committed to its placement: a jit caches on the
+        # arg's committed-ness, so an UNcommitted initial pool would cost
+        # each program one extra compile when the second call arrives
+        # holding committed program outputs
+        self.pools = tuple(jax.device_put(a, a.sharding)
+                           for a in make_pool())
         self._prefill_progs: Dict[int, Any] = {}
         self._cow_prog = _COW_PROG if prefix_cache else None
         if self._cow_prog is not None:
@@ -409,8 +486,113 @@ class MeshExecutor:
                                     lambda: position_keys(seeds, lengths + 1))
             return with_counts(nxt, counts), paged_pool_tuple(cache)
 
+        # kept for the jit that asks the compiler for the weights' layouts
+        # (_compile_tick_formats): one function, so that jit finds it traced
+        self._decode_fn = prog
         return _named_pool_jit(prog, "serve_decode", self.mesh,
                                self._pool_specs)
+
+    def _tick_formats(self, params):
+        """The ``Format`` (layout and sharding) the decode program wants
+        each leaf of ``params`` in: the tick compiled ahead of time with
+        ``Layout.AUTO`` on the parameter tree and every other argument as
+        :meth:`decode` passes it, read off ``compiled.input_formats``.  The
+        tick decides because it is bound by memory and runs thousands of
+        times a window; a prefill re-lays a leaf out inside a program a
+        hundred times longer if it must.  The CPU backend answers with the
+        layout a leaf has.  ``None`` for a tree with a leaf that is no
+        device array (a host tree rides each call's own upload, as it did).
+        Found once a process for what the program is made from
+        (``_aot``)."""
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        if not leaves or not all(isinstance(x, jax.Array) for x in leaves):
+            return None
+        # a leaf nobody committed goes where the programs' small operands
+        # go (replicated over the mesh, else where the pool lies), once and
+        # not with every call
+        avals = treedef.unflatten([
+            jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=(
+                x.sharding if x.committed else self._token_sharding()))
+            for x in leaves])
+        adapters = (() if self.adapters is None else (jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+            self._adapter_zero()),))
+        order = (tuple(sorted(self.pool_order.items()))
+                 if isinstance(self.pool_order, dict) else self.pool_order)
+        key = (getattr(self.model.apply_paged, "__func__",
+                       self.model.apply_paged), self.model.config, self.mesh,
+               self.b_slots, self.page_size, self.pages_per_slot, order,
+               tuple((a.shape, str(a.dtype), a.sharding)
+                     for a in self._pool_avals),
+               treedef, tuple((a.shape, str(a.dtype), a.sharding)
+                              for a in jax.tree_util.tree_leaves(avals)),
+               str(jax.tree_util.tree_structure(adapters)),
+               tuple((a.shape, str(a.dtype))
+                     for a in jax.tree_util.tree_leaves(adapters)))
+        return _aot(key, lambda: self._compile_tick_formats(avals, adapters))
+
+    def _compile_tick_formats(self, params, adapters):
+        B = self.b_slots
+
+        def vec(dtype, n=B):
+            return jax.ShapeDtypeStruct((n,), dtype)
+
+        table = jax.ShapeDtypeStruct((B, self.pages_per_slot), jnp.int32)
+        if self.ring_pages:
+            table = (table, jax.ShapeDtypeStruct((B, self.ring_pages),
+                                                 jnp.int32))
+        counts = self.moe_shape[0] * self.moe_shape[1] if self.moe_shape else 0
+        args = (params, self._pool_avals, table, vec(jnp.int32),
+                jax.ShapeDtypeStruct((B + counts,), jnp.int32,
+                                     sharding=self._token_sharding()),
+                vec(jnp.bool_), vec(jnp.float32), vec(jnp.int32),
+                vec(jnp.float32), vec(jnp.uint32)) + adapters
+        auto = jax.tree_util.tree_map(
+            lambda x: Format(Layout.AUTO, x.sharding), params)
+        formats = _named_pool_jit(
+            self._decode_fn, "serve_decode", self.mesh, self._pool_specs,
+            (auto,) + (None,) * (len(args) - 1)).lower(*args).compile(
+            ).input_formats[0][0]
+        # each leaf's layout, on the sharding the leaf has
+        return jax.tree_util.tree_map(
+            lambda x, f: Format(f.layout, x.sharding), params, formats)
+
+    def _per_layer(self, tree, sharding=None):
+        """``per_layer_leaves`` of a tree of device arrays, each first
+        committed to ``sharding`` (default: where it lies): a jit caches on
+        that, so the programs that cut the tree the engine was built on are
+        the ones that cut the next (:meth:`update_params` compiles
+        nothing)."""
+        leaves = jax.tree_util.tree_leaves(tree)
+        if is_hybrid(self.model.config) and leaves and all(
+                isinstance(x, jax.Array) for x in leaves):
+            tree = jax.device_put(tree, sharding or jax.tree_util.tree_map(
+                lambda x: x.sharding, tree))
+        return per_layer_leaves(self.model.config, tree)
+
+    def _in_formats(self, tree):
+        """``(tree as the decode program reads it, leaves copied, their
+        bytes)``: a leaf that lies so already where it lies (not copied, not
+        even committed anew), any other committed to its sharding and, if
+        its layout is another, copied into the layout, all of those by one
+        program.  The same steps for the tree the engine is built on and for
+        every later one, so the second finds that program compiled."""
+        if self._param_formats is None:
+            return tree, 0, 0
+        leaves, treedef = jax.tree_util.tree_flatten(tree)
+        formats = jax.tree_util.tree_leaves(self._param_formats)
+        for i, f in enumerate(formats):
+            if not _lies_as(leaves[i], f):
+                leaves[i] = jax.device_put(leaves[i], f.sharding)
+        moved = [i for i, f in enumerate(formats)
+                 if not _lies_as(leaves[i], f)]
+        nbytes = sum(int(leaves[i].nbytes) for i in moved)
+        if moved:
+            for i, x in zip(moved, jax.jit(_as_given, out_shardings=tuple(
+                    formats[i] for i in moved))(
+                        tuple(leaves[i] for i in moved))):
+                leaves[i] = x
+        return treedef.unflatten(leaves), len(moved), nbytes
 
     def _build_prefill(self, s_pad: int):
         apply_paged, with_counts = self._apply_paged, self._with_counts
@@ -472,7 +654,7 @@ class MeshExecutor:
         (``pool_jit``'s ``out_shardings``), else where the pool lies."""
         if self.mesh is not None:
             return NamedSharding(self.mesh, P())
-        return self.pools[0].sharding
+        return self._pool_avals[0].sharding
 
     def decode(self, page_table, lengths, last_tok, active, lanes,
                adapters=None):
@@ -579,12 +761,21 @@ class MeshExecutor:
         zero-recompile: the incoming tree (typically the training engine's
         live compute view) is resharded through the same
         ``place_params``/``auto_tp_specs`` path the original placement
-        used, then committed to the EXACT shardings captured at build time,
-        so the jitted programs see identical avals + shardings and hit
-        their caches.  A tree whose structure or leaf shapes/dtypes differ
-        from the compiled ones is rejected loudly — it would silently
-        recompile every program in the inventory."""
+        used, held a leaf a layer where the first one is, then committed to
+        the EXACT shardings and layouts captured at build time, so the
+        jitted programs see identical avals + shardings + layouts and hit
+        their caches.  What callers hand in is the model's tree, a stack a
+        group, or the placed tree itself.  A tree whose structure or leaf
+        shapes/dtypes differ from the compiled ones is rejected loudly — it
+        would silently recompile every program in the inventory."""
         placed = place_params(params, self.mesh)
+        if (is_hybrid(self.model.config) and isinstance(placed, dict)
+                and jax.tree_util.tree_structure(placed)
+                != self._param_treedef):
+            # a stack a group, as callers hold the model: cut where the
+            # placed tree lies (such a model is on one device)
+            placed, _ = self._per_layer(placed, jax.tree_util.tree_leaves(
+                self.params)[0].sharding)
         treedef = jax.tree_util.tree_structure(placed)
         if treedef != self._param_treedef:
             raise ValueError(
@@ -600,9 +791,7 @@ class MeshExecutor:
                     f"update_params: leaf {i} has aval {aval}, compiled "
                     f"programs expect {self._param_avals[i]} — the swap "
                     "must be shape/dtype-identical (zero-recompile)")
-        if self._param_shardings is not None:
-            placed = jax.device_put(placed, self._param_shardings)
-        self.params = placed
+        self.params = self._in_formats(placed)[0]
 
     def lanes(self, temp, top_k, top_p, seeds):
         """Cached device copy of the per-slot lane vectors; the engine
@@ -661,14 +850,19 @@ class MeshExecutor:
         return not (dead and self.kpool.is_deleted())
 
     def mesh_info(self) -> Dict[str, Any]:
-        """Static mesh facts for health()/gauges: device count and the
-        non-trivial axis sizes (``{}`` / 1 device when unmeshed)."""
-        if self.mesh is None:
-            return {"mesh_devices": 1, "mesh_axes": {}}
-        return {"mesh_devices": int(self.mesh.size),
-                "mesh_axes": {a: int(self.mesh.shape[a])
-                              for a in self.mesh.axis_names
-                              if int(self.mesh.shape[a]) > 1}}
+        """Static facts for health()/gauges: device count and the
+        non-trivial axis sizes (``{}`` / 1 device when unmeshed), and what
+        the weights' placement did to the tree it was given:
+        ``weight_leaves_split`` stacks cut into a leaf a layer,
+        ``weight_leaves_relaid`` leaves (``weight_bytes_relaid`` bytes)
+        copied into the layout the decode program asked for; all 0 for a
+        tree that already lay so (a warm restart's)."""
+        mesh = self.mesh
+        return {"mesh_devices": 1 if mesh is None else int(mesh.size),
+                "mesh_axes": {} if mesh is None else {
+                    a: int(mesh.shape[a]) for a in mesh.axis_names
+                    if int(mesh.shape[a]) > 1},
+                **self.weight_placement}
 
     # ----------------------------------------------------------- adoption
 

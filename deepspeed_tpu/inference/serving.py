@@ -362,9 +362,12 @@ class ServingEngine:
     """Iteration-level scheduler over a fixed slot fleet + paged KV pool.
 
     ``model`` must expose the paged decode contract (``init_paged_cache`` /
-    ``apply_paged`` — see ``models.CausalLM``); ``params`` are used as given
-    (share ``InferenceEngine.params`` via :meth:`InferenceEngine.serving` to
-    keep serving numerics identical to ``generate()``).
+    ``apply_paged`` — see ``models.CausalLM``); ``params`` is the model's
+    tree (share ``InferenceEngine.params`` via :meth:`InferenceEngine.serving`
+    to keep serving numerics identical to ``generate()``), or a call that
+    hands it over.  The engine's own ``params`` is that tree as the executor
+    placed it (:class:`~.execution.MeshExecutor`: the same values, held the
+    way the decode program reads them).
     """
 
     def __init__(self, model, params, b_slots: int = 4,
@@ -501,8 +504,10 @@ class ServingEngine:
                                   kv_dtype=kv_dtype, mesh=mesh,
                                   prefix_cache=prefix_cache,
                                   host_tier=host_tier_pages is not None,
-                                  catalog=self._catalog, adapters=adapters)
-        self.params = self._exec.params   # auto-TP-sharded on a mesh
+                                  catalog=self._catalog, adapters=adapters,
+                                  pages_per_slot=self.pages_per_slot)
+        # auto-TP-sharded on a mesh, and held as the decode program reads it
+        self.params = self._exec.params
         # ---- multi-tenant adapter serving (docs/SERVING.md "Multi-tenant
         # adapter serving"): with a registry attached, every decode/prefill
         # /verify program takes the per-slot LoRA factor stacks as ONE
@@ -711,7 +716,11 @@ class ServingEngine:
             f"pages={self.num_pages}x{self.page_size} "
             f"(max_model_len={self.max_model_len})"
             + (f" mesh={info['mesh_devices']}dev {info['mesh_axes']}"
-               if mesh is not None else ""), ranks=[0])
+               if mesh is not None else "")
+            + f" weights: {info['weight_leaves_split']} stack(s) held a "
+            f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
+            f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out",
+            ranks=[0])
 
     # ---------------------------------------------- device-half delegation
     # The executor owns the pool and the compiled programs
@@ -2404,9 +2413,10 @@ class ServingEngine:
             # multi-chip serving (docs/SERVING.md): the mesh this engine's
             # programs span, and the per-device KV-pool footprint — on a
             # tp-sharded mesh bytes_per_device is ~total/tp (heads over
-            # 'model'), the number HBM capacity planning reads
-            "mesh_devices": info["mesh_devices"],
-            "mesh_axes": info["mesh_axes"],
+            # 'model'), the number HBM capacity planning reads; and what
+            # the executor's placement did to the weights it was given
+            # (docs/SERVING.md "Weight placement")
+            **info,
             "kv_pool_bytes_total": pb["total"],
             "kv_pool_bytes_per_device": pb["per_device"],
             # at-rest pool storage dtype (docs/SERVING.md "Quantized KV

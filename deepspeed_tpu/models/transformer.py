@@ -495,6 +495,44 @@ def layer_groups(cfg: TransformerConfig):
     return groups
 
 
+def _whole_in_group(lp: Dict[str, Any], leaf: str) -> bool:
+    """Whether ``leaf`` of a group's stack ``lp`` is read whole by the paged
+    forward over two kinds of layer: an expert layer's per-expert stacks,
+    ``[n * E, ...]`` with a layer's experts at ``l * E``."""
+    return leaf in _EXPERT_LEAVES and "router" in lp
+
+
+# one program a tree of stacks, shared by every engine of the process: each
+# stack ``[n, ...]`` as its n layers, each an array of its own
+_unstack = jax.jit(lambda stacks: jax.tree_util.tree_map(
+    lambda v: tuple(v[i] for i in range(v.shape[0])), stacks))
+
+
+def per_layer_leaves(cfg: TransformerConfig, params: Dict[str, Any]):
+    """``(params, leaves cut)`` with a leaf a layer where the paged forward
+    walks its layers in Python (:func:`is_hybrid`: a ``layer_pattern``, whose
+    kinds' stacks differ in shape, so there is nothing to scan): every leaf
+    of a group that is not read whole (:func:`_whole_in_group`) as a tuple
+    of its layers' arrays, so that ``v[index]`` is a Python index and the
+    compiler is handed no ``slice`` of a stack (a static slice that feeds a
+    copy is materialised: 1 GB of ``wq`` written back to memory every tick,
+    PERF.md PR 35).  The expert stacks stay whole; a scanned model's tree
+    comes back as it is (there the scan's dynamic slice is the fetch), and
+    so does a leaf that is already held a layer at a time or is no array.
+    The containers are new, the tree handed in is not touched."""
+    if not is_hybrid(cfg):
+        return params, 0
+    stacks = {name: {k: v for k, v in lp.items() if isinstance(v, jax.Array)
+                     and not _whole_in_group(lp, k)}
+              for name, lp in params["layers"].items()}
+    if not any(stacks.values()):
+        return params, 0
+    cut = _unstack(stacks)
+    return ({**params, "layers": {name: {**lp, **cut[name]} for name, lp
+                                  in params["layers"].items()}},
+            sum(len(lp) for lp in stacks.values()))
+
+
 def layer_windows(cfg: TransformerConfig) -> Optional[jax.Array]:
     """[L] int32 of local-attention window sizes (0 = global) from
     cfg.attention_layers, or None when the config has no alternation."""
@@ -1622,8 +1660,9 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
                            "and values of one width, no sink)", cfg)
         groups = layer_groups(cfg)
         for group, index, kind, _ in layer_plan(cfg):
-            lp = jax.tree_util.tree_map(lambda a: a[index],
-                                        params["layers"][group])
+            # a stack, or a tuple of the layers' own arrays where the
+            # serving executor holds the tree (per_layer_leaves)
+            lp = {k: v[index] for k, v in params["layers"][group].items()}
             x = _block(groups[group][0], lp, x, positions, rng, _attend_full(
                 cfg, positions, "xla", custom_positions,
                 window=cfg.window_size if kind == "window" else None,
@@ -2202,14 +2241,15 @@ def _paged_read_plan(page_table, start, seq_mask, ps: int):
             pages.reshape(-1, pairs), limit.reshape(-1, pairs, S))
 
 
-def paged_pool_order(leaf: jax.Array) -> Optional[Tuple[int, ...]]:
+def paged_pool_order(leaf) -> Optional[Tuple[int, ...]]:
     """The order, major to minor, in which the device stores the five axes
-    of a K/V pool leaf (an array, not a tracer), for :func:`forward_paged`'s
-    ``pool_order``; ``None`` where that is the axes' own order (row-major)
-    or the backend does not say."""
-    layout = leaf.format.layout
+    of a K/V pool leaf (an array, not a tracer, or the ``Format`` a compiled
+    program reports for one), for :func:`forward_paged`'s ``pool_order``;
+    ``None`` where that is the axes' own order (row-major) or the backend
+    does not say."""
+    layout = getattr(leaf, "format", leaf).layout
     order = None if layout is None else tuple(layout.major_to_minor)
-    return None if order == tuple(range(leaf.ndim)) else order
+    return None if order == tuple(range(len(order or ()))) else order
 
 
 def _pool_views(pools, pool_order):
@@ -2949,12 +2989,15 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     # each group's expert stacks whole [n * E, ...] with a layer's experts
     # at l * E: nothing of a layer's size is cut out
     experts = {name: {k: v.reshape(-1, *v.shape[2:]) for k, v in lp.items()
-                      if k in _EXPERT_LEAVES and "router" in lp}
+                      if _whole_in_group(lp, k)}
                for name, lp in params["layers"].items()}
     seen = {kind: 0 for kind in pools}
     counts = []
     for group, index, kind, _ in layer_plan(cfg):
         g = groups[group][0]
+        # ``v`` is the group's stack or, as the serving executor holds it, a
+        # tuple of its layers' arrays (per_layer_leaves): then nothing is
+        # cut out of a stack here
         lp = {k: v[index] for k, v in params["layers"][group].items()
               if k not in experts[group]}
         first_page = seen[kind] * n_pages[kind]

@@ -241,22 +241,69 @@ def one_v5e_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _placed_as_the_executor_places(cfg, chip):
+    """``(params, in_shardings entry)`` for an AOT compile of a tick: the
+    shapes of ``cfg``'s bfloat16 tree held as ``MeshExecutor`` holds it (a
+    leaf a layer where the forward walks the layers in Python,
+    ``per_layer_leaves``), and ``Layout.AUTO`` on every leaf, which is how
+    the executor asks the compiler for the layouts it then places the tree
+    in (``MeshExecutor._tick_formats``): the program compiled so is the
+    tick at the executor's placement."""
+    from jax.experimental.layout import Format, Layout
+
+    from deepspeed_tpu.models import init_params
+    from deepspeed_tpu.models.transformer import per_layer_leaves
+
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16, sharding=chip),
+        jax.eval_shape(lambda: per_layer_leaves(
+            cfg, init_params(cfg, jax.random.PRNGKey(0)))[0]))
+    return params, jax.tree_util.tree_map(
+        lambda a: Format(Layout.AUTO, chip), params)
+
+
+def _materialised(text):
+    """``(name, opcode, [(dims, layout), ...])`` of every bfloat16 result
+    of every instruction of a compiled module that is not inside a fusion's
+    body: what the program writes somewhere, a tuple's parts each."""
+    import re
+
+    bodies = set(re.findall(r" fusion\([^\n]*calls=%([\w.\-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\(", line)
+        if m and inside not in bodies:
+            yield m.group(1), m.group(3), [
+                ([int(d) for d in dims.split(",")], layout)
+                for dims, layout in re.findall(
+                    r"bf16\[([\d,]+)\]\{([^}]*)\}", m.group(2))]
+
+
 def test_hybrid_paged_decode_keeps_both_pools_in_place_on_v5e(one_v5e_chip):
     """AOT: ``jit_serve_decode``'s body for MiMo-V2.5 at the benchmark's cut
     (published widths, 7 layers, 16 experts held, 32 slots of 128 pages),
     compiled by the installed libtpu with the pool of each kind donated and
     handed over in the order the chip stores it (observed on the v5e, PR 30:
     the 192-wide keys page-rows minor-most, the 128-wide values row-major, the
-    full layers' 4 x 128 values kept head-major).  No op's result is the size
+    full layers' 4 x 128 values kept head-major), and the weights as the
+    executor places them (PR 35).  No op's result is the size
     of a leaf of either pool but the in-place page scatters, the window
     layers' gathers are a step's (slot, page) pairs of a two-page ring, and
-    the program's temporaries are a fraction of one pool leaf (with the
-    values of the full layers page-major they were 2 x 1 GB of copies)."""
+    the program's temporaries are a twentieth of one pool leaf (with the
+    values of the full layers page-major they were 2 x 1 GB of copies; with
+    a stack a group, 0.64 GB: one fusion cut the five window layers' ``wq``
+    out of their stack and wrote them back every tick).  Nothing the
+    program writes outside on-chip memory is of a ``wq`` leaf's size: every
+    projection is read once, by its product."""
     import re
 
     import numpy as np
 
-    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models import get_config
     from deepspeed_tpu.models.transformer import (forward_paged,
                                                   paged_read_pairs)
 
@@ -265,9 +312,9 @@ def test_hybrid_paged_decode_keeps_both_pools_in_place_on_v5e(one_v5e_chip):
 
     cfg = get_config("mimo-v2.5", num_layers=7, moe_experts_held=16,
                      vocab_size=19072)
-    params = jax.tree_util.tree_map(
-        lambda a: S(a.shape, jnp.bfloat16),
-        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    params, auto = _placed_as_the_executor_places(cfg, one_v5e_chip)
+    assert isinstance(params["layers"]["window_moe"]["wq"], tuple)
+    assert not isinstance(params["layers"]["window_moe"]["w_up"], tuple)
     slots, maxp, page = 32, 128, 128
     pages, ring = 1 + slots * maxp, 1 + slots * 2
     cache = {"k": S((2, pages, page, 4, 192), jnp.bfloat16),
@@ -284,16 +331,24 @@ def test_hybrid_paged_decode_keeps_both_pools_in_place_on_v5e(one_v5e_chip):
             expert_counts=True, pool_order=order)
         return jnp.argmax(logits[:, -1], -1), counts, cache
 
-    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+    compiled = jax.jit(tick, donate_argnums=(1,),
+                       in_shardings=(auto,) + (None,) * 5).lower(
         params, cache, S((slots, 1), jnp.int32),
         (S((slots, maxp), jnp.int32), S((slots, 2), jnp.int32)),
         S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
-    # 0.64 GB, most of it weights the compiler re-lays out for its products
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    # 0.045 GB (0.117 with a leaf a layer in the default layouts, 0.644 with
+    # a stack a group: PERF.md, PR 35)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+    text = compiled.as_text()
+    wq = int(np.prod(params["layers"]["window_moe"]["wq"][0].shape))
+    for name, opcode, results in _materialised(text):
+        for dims, layout in results:
+            if opcode in ("fusion", "copy") and "S(1)" not in layout:
+                assert int(np.prod(dims)) != wq, (name, dims, layout)
     leaves = {(page, 4, 192): 2 * pages, (page, 4, 128): 2 * pages,
               (page, 8, 192): 5 * ring, (page, 8, 128): 5 * ring}
     gathers = {}
-    for line in compiled.as_text().splitlines():
+    for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
         if not m:
             continue
@@ -378,6 +433,63 @@ def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
             assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
                              r"\(| scatter\(|/scatter\"", line), line[:240]
     assert gathers and max(gathers) == read * page * width
+
+
+@pytest.mark.parametrize(
+    "name,overrides,slots,pool_order",
+    [("kanana-2-30b-a3b", {"num_layers": 3, "moe_experts_held": 16,
+                           "vocab_size": 16032}, 32, None),
+     ("opt-1.3b", {"num_layers": 2, "activation": "relu"}, 8,
+      (0, 1, 3, 4, 2)),
+     ("olmoe-1b-7b", {"num_layers": 2}, 16, None)],
+    ids=["kanana-2", "opt-1.3b", "olmoe-1b-7b"])
+def test_scanned_tick_at_the_executors_layouts_copies_no_sliced_weight(
+        one_v5e_chip, name, overrides, slots, pool_order):
+    """AOT: a scanned model's decode tick at the published widths, compiled
+    by the installed libtpu with its weights as the executor places them: a
+    stack a group (the scan's dynamic slice IS the fetch into on-chip
+    memory) in the layouts the compiler asks for (PR 35).  In the default
+    layouts a ``copy`` re-laid the slice of ``wq`` (Kanana's ``wkv_a`` and
+    ``wkv_b``, OPT's ``wk`` and ``wv`` too) out before its product, every
+    layer of every tick; at the executor's there is no ``copy`` of a
+    projection's size left in the program."""
+    import numpy as np
+
+    from deepspeed_tpu.models import CausalLM, get_config
+    from deepspeed_tpu.models.transformer import forward_paged
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    cfg = get_config(name, **overrides)
+    params, auto = _placed_as_the_executor_places(cfg, one_v5e_chip)
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * 16, 128, dtype=jnp.bfloat16)))
+    moe = cfg.num_experts != 1
+
+    def tick(params, cache, tokens, table, start, mask):
+        r = forward_paged(cfg, params, tokens, cache, table, start, mask,
+                          pool_order=pool_order, expert_counts=moe)
+        return (jnp.argmax(r[0][:, -1], -1),) + tuple(r[1:])
+
+    compiled = jax.jit(tick, donate_argnums=(1,),
+                       in_shardings=(auto,) + (None,) * 5).lower(
+        params, cache, S((slots, 1), jnp.int32), S((slots, 16), jnp.int32),
+        S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
+    stacks = [v for v in jax.tree_util.tree_leaves(params["layers"])
+              if v.ndim == 3]
+    projections = {int(np.prod(v.shape[1:])) for v in stacks}
+    assert any(tuple(f.layout.major_to_minor) != (0, 1, 2)
+               for f in jax.tree_util.tree_leaves(
+                   compiled.input_formats[0][0]["layers"])
+               if len(f.layout.major_to_minor) == 3)
+    for op, opcode, results in _materialised(compiled.as_text()):
+        for dims, layout in results:
+            if opcode == "copy":
+                assert int(np.prod(dims)) not in projections, (op, dims,
+                                                               layout)
 
 
 # The optimized HLO of the programs the benchmark's other configurations run
