@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..utils.logging import log_dist, logger
+from ..utils.memory import is_out_of_memory, program_bytes
 
 DEFAULT_HBM_BYTES = 16 * 1024 ** 3       # v5e chip
 MEMORY_SAFETY_MARGIN = 0.92              # leave headroom for runtime buffers
@@ -44,21 +45,19 @@ class TrialRecord:
         return dataclasses.asdict(self)
 
 
-
-def _memory_bytes(mem) -> int:
-    """Compiled-program HBM estimate — ONE formula for prune and measure."""
-    return int(getattr(mem, "temp_size_in_bytes", 0)
-               + getattr(mem, "argument_size_in_bytes", 0)
-               + getattr(mem, "output_size_in_bytes", 0)
-               - getattr(mem, "alias_size_in_bytes", 0))
+def _budget_bytes(hbm_bytes: int) -> int:
+    """What a candidate's fused step may plan — ONE budget for prune, for
+    measure, and for the engine where it resolves a candidate's checkpoint
+    policy (``compile_train_step(batch, budget_bytes=...)``)."""
+    return int(hbm_bytes * MEMORY_SAFETY_MARGIN)
 
 
 def _apply_budget(rec: TrialRecord, mem, hbm_bytes: int) -> bool:
     """Record the estimate; True if the config fits the budget."""
     if mem is None:
         return True
-    rec.memory_bytes = _memory_bytes(mem)
-    if rec.memory_bytes > hbm_bytes * MEMORY_SAFETY_MARGIN:
+    rec.memory_bytes = program_bytes(mem)
+    if rec.memory_bytes > _budget_bytes(hbm_bytes):
         rec.status = "compile_oom"
         rec.error = (f"predicted {rec.memory_bytes / 1e9:.2f} GB > "
                      f"budget {hbm_bytes / 1e9:.2f} GB")
@@ -254,6 +253,9 @@ class Autotuner:
                 try:
                     engine = self.make_engine(dict(ov))
                     batch = self.make_batch(engine)
+                    # (where the engine resolves the checkpoint policy,
+                    # its leanest program: in budget exactly where the one
+                    # _measure compiles within the same budget is)
                     low = engine.lower_train_step(batch)
                     lowered.append((rec, low))
                 except Exception as e:  # noqa: BLE001
@@ -270,8 +272,7 @@ class Autotuner:
                     _apply_budget(rec, compiled.memory_analysis(),
                                   self.config.hbm_bytes)
                 except Exception as e:  # noqa: BLE001
-                    rec.status = ("compile_oom"
-                                  if "resource_exhausted" in str(e).lower()
+                    rec.status = ("compile_oom" if is_out_of_memory(e)
                                   else "compile_error")
                     rec.error = str(e)[:300]
                 return rec
@@ -415,7 +416,11 @@ class Autotuner:
             engine = self.make_engine(dict(overrides))
             batch = self.make_batch(engine)
             t0 = time.perf_counter()
-            step = engine.compile_train_step(batch)
+            # an engine that resolves the candidate's checkpoint policy
+            # itself (no remat_policy named) resolves it within the budget
+            # the program is then held to
+            step = engine.compile_train_step(
+                batch, budget_bytes=_budget_bytes(self.config.hbm_bytes))
             rec.compile_sec = time.perf_counter() - t0
             mem = step.memory_analysis() if hasattr(step, "memory_analysis") else None
             if not _apply_budget(rec, mem, self.config.hbm_bytes):
@@ -435,11 +440,8 @@ class Autotuner:
             rec.metric_val = (samples / dt if self.config.metric == "throughput"
                               else -dt)
         except Exception as e:  # noqa: BLE001 — a failed trial is a record
-            msg = str(e)
-            low = msg.lower()
-            rec.status = ("compile_oom" if "resource_exhausted" in low
-                          or "out of memory" in low else "run_error")
-            rec.error = msg[:300]
+            rec.status = "compile_oom" if is_out_of_memory(e) else "run_error"
+            rec.error = str(e)[:300]
         return rec
 
     def tune(self) -> Tuple[Optional[Dict[str, Any]], List[TrialRecord]]:

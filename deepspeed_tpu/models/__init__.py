@@ -85,6 +85,13 @@ class CausalLM:
         model.param_specs = param_specs(cfg)
         return model, params
 
+    def variant(self, **fields):
+        """This model with config fields replaced (same attention
+        implementation): how the engine builds the model behind a compiled
+        step that differs in a static field — a random-LTD keep count, the
+        checkpoint policy it resolved."""
+        return type(self)(self.config, attn_impl=self.attn_impl, **fields)
+
     def init_fn(self, rng):
         from ..utils.init_on_device import on_device_init
 

@@ -173,7 +173,9 @@ class TransformerConfig:
     # independent of M, the reference TrainSchedule's memory contract.
     pipeline_schedule: str = "gpipe"
     remat: bool = True                        # activation checkpointing
-    remat_policy: str = "nothing_saveable"    # nothing_saveable | dots_saveable
+    # which residuals a checkpointed layer keeps for its backward: a rung of
+    # REMAT_LADDER, any jax.checkpoint_policies name, or REMAT_AUTO (below)
+    remat_policy: str = "auto"
     # random-LTD (data efficiency): non-deterministic passes run each layer on
     # a random `random_ltd_keep`-token subset; dropped tokens ride the
     # residual stream (runtime/data_pipeline/data_routing/random_ltd.py)
@@ -1134,9 +1136,9 @@ def _attention(cfg: TransformerConfig, q, k, v, positions, attn_impl: str = "xla
             if not failed:
                 from ..ops.ring_attention import ring_attention_sharded
 
-                return ring_attention_sharded(
+                return checkpoint_name(ring_attention_sharded(
                     q, k, v, m, BATCH_AXES, causal=True,
-                    sm_scale=_sm_scale(cfg, hd))
+                    sm_scale=_sm_scale(cfg, hd)), "attn_out")
             if attn_impl == "ring":
                 raise ValueError(
                     f"ring attention requested but unsatisfiable: {failed}")
@@ -1254,7 +1256,8 @@ def _attention(cfg: TransformerConfig, q, k, v, positions, attn_impl: str = "xla
     probs = _softmax_with_sink(
         scores, None if sink is None
         else sink.astype(jnp.float32)[None, :, None, None]).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return checkpoint_name(jnp.einsum("bhqk,bkhd->bqhd", probs, v),
+                           "attn_out")
 
 
 def _softmax_with_sink(scores, sink):
@@ -1524,12 +1527,11 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
         q = checkpoint_name(q, "q_proj")
         k = checkpoint_name(k, "k_proj")
         v = checkpoint_name(v, "v_proj")
+    # the attention's output is named "attn_out" where it is made
+    # (:func:`_attention`; the flash kernel names its own output inside its
+    # vjp, in the layout its backward reads), so a remat policy can keep it
     attn, state = attend(q, k, v)
-    # named checkpoint: the "save_attn" remat policy stashes this one tensor
-    # per layer ([B,S,H*hd] bf16) so the backward skips recomputing the whole
-    # attention (the costliest part of the recompute) while the rest of the
-    # layer still rematerializes
-    attn = _attn_out(cfg, lp, checkpoint_name(attn, "attn_out"), proj)
+    attn = _attn_out(cfg, lp, attn, proj)
     attn, rng = _dropout(cfg, attn, rng, deterministic)
     res = x + attn
     if post:
@@ -1565,6 +1567,32 @@ def _attend_full(cfg: TransformerConfig, positions, attn_impl: str = "xla",
     return attend
 
 
+# ``remat_policy`` left at REMAT_AUTO names no policy: the training engine
+# resolves one for each compiled fused step from the memory the step has
+# (``DeepSpeedEngine.resolve_remat``: the richest rung of REMAT_LADDER whose
+# program fits).  Where no resolver runs (``forward`` under a caller's own
+# jit, the 1F1B executor, the layer-streamed offload path, evaluation) it
+# means "nothing_saveable".
+REMAT_AUTO = "auto"
+# The residuals a rung keeps, by their ``checkpoint_name`` (:func:`_block`,
+# :func:`_attention`, :func:`_mlp`, the flash kernel's vjp); everything else of the layer is run
+# again inside its backward.  Kept bytes a layer in bf16, T tokens, d hidden,
+# f the MLP's width, H heads: save_attn the attention's output and the flash
+# kernel's row statistics (T*d + 4*T*H), so the backward runs the projections
+# again but not the flash forward; save_qkv adds q, k, v (T*(d + 2*kv));
+# save_matmuls adds the up (and gate) projection (T*f each), so what is run
+# again is norms and elementwise.
+# ("dots_saveable" would also pin the [S,S] scores of the XLA attention.)
+REMAT_SAVED_NAMES = {
+    "save_attn": ("attn_out", "attn_lse"),
+    "save_qkv": ("attn_out", "attn_lse", "q_proj", "k_proj", "v_proj"),
+    "save_matmuls": ("attn_out", "attn_lse", "q_proj", "k_proj", "v_proj",
+                     "mlp_gate", "mlp_up"),
+}
+# richest first: each rung keeps a superset of the next one's residuals
+REMAT_LADDER = ("save_matmuls", "save_qkv", "save_attn", "nothing_saveable")
+
+
 def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
                  custom_positions: bool):
     """One layer's apply fn ``block(lp, x, rng, positions)`` with the remat
@@ -1575,27 +1603,13 @@ def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
         _attend_full(cfg, pos, attn_impl, custom_positions, window),
         deterministic)[:2]
     if cfg.remat:
-        if cfg.remat_policy == "save_attn":
-            # keep each layer's attention output ([B,S,D] bf16 — ~2*B*S*D
-            # bytes/layer) and rematerialize everything else: the backward
-            # re-runs the cheap matmul/norm chain but not attention
-            policy = jax.checkpoint_policies.save_only_these_names("attn_out")
-        elif cfg.remat_policy == "save_qkv":
-            # attention fully pinned (projections + residuals): backward
-            # never re-runs the S² kernel; only the MLP rematerializes
+        name = ("nothing_saveable" if cfg.remat_policy == REMAT_AUTO
+                else cfg.remat_policy)
+        if name in REMAT_SAVED_NAMES:
             policy = jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_lse", "q_proj", "k_proj", "v_proj")
-        elif cfg.remat_policy == "save_matmuls":
-            # pin every big projection output (q/k/v post-rope, gate/up, attn)
-            # so the backward recompute is norms/elementwise only — recompute
-            # cost drops from +2N to ~0 at ~6 saved [B,S,·] tensors per layer
-            # (vs dots_saveable, which would also pin the [S,S] score matrices
-            # and OOM)
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "attn_out", "attn_lse", "q_proj", "k_proj", "v_proj",
-                "mlp_gate", "mlp_up")
+                *REMAT_SAVED_NAMES[name])
         else:
-            policy = getattr(jax.checkpoint_policies, cfg.remat_policy, None)
+            policy = getattr(jax.checkpoint_policies, name, None)
         block = jax.checkpoint(block, policy=policy)
     if cfg.random_ltd and cfg.random_ltd_keep > 0:
         # token drop wraps OUTSIDE remat so only the kept-subset compute is

@@ -68,7 +68,7 @@ def peak_flops_per_sec() -> Optional[float]:
 
 class _Stat:
     __slots__ = ("flops", "bytes", "invocations", "synced_samples",
-                 "synced_seconds", "registered")
+                 "synced_seconds", "registered", "notes")
 
     def __init__(self):
         self.flops = 0.0          # per invocation, from cost_analysis
@@ -77,6 +77,7 @@ class _Stat:
         self.synced_samples = 0
         self.synced_seconds = 0.0
         self.registered = False
+        self.notes: Dict[str, Any] = {}   # how the program came to be
 
 
 class ProgramCatalog:
@@ -135,6 +136,13 @@ class ProgramCatalog:
                            type(e).__name__, e)
         self.register(name, flops=flops, bytes=by)
 
+    def annotate(self, name: str, **notes: Any) -> None:
+        """Facts about how ``name`` was built that its numbers do not show
+        (the checkpoint policy the train engine resolved for its step):
+        carried on the program's row of :meth:`table`."""
+        with self._lock:
+            self._stats.setdefault(name, _Stat()).notes.update(notes)
+
     # ------------------------------------------------------------ accounting
 
     def invoke(self, name: str, n: int = 1) -> Optional[float]:
@@ -173,9 +181,11 @@ class ProgramCatalog:
         out: Dict[str, Dict[str, Any]] = {}
         with self._lock:
             items = [(k, (v.flops, v.bytes, v.invocations, v.synced_samples,
-                          v.synced_seconds)) for k, v in self._stats.items()]
-        for name, (flops, by, inv, ns, secs) in sorted(items):
+                          v.synced_seconds, dict(v.notes)))
+                     for k, v in self._stats.items()]
+        for name, (flops, by, inv, ns, secs, notes) in sorted(items):
             row: Dict[str, Any] = {
+                **notes,
                 "flops": flops,
                 "bytes": by,
                 "invocations": inv,

@@ -383,11 +383,14 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
 # ---------------------------------------------------------------------------
 
 # The custom VJP is defined on a function whose PRIMAL OUTPUTS are (out, lse)
-# — exactly the non-input residuals the backward needs.  The model names both
-# with checkpoint_name, so a remat policy that pins q/k/v + attn_out +
-# attn_lse lets the backward run WITHOUT re-executing the forward kernel
-# (with out/lse hidden inside the vjp, remat must re-run the S² forward to
-# regenerate residuals no matter what the policy saves).
+# — exactly the non-input residuals the backward needs.  Both are named with
+# checkpoint_name INSIDE the vjp-fwd, as the residuals themselves, so a remat
+# policy that pins q/k/v + attn_out + attn_lse lets the backward run WITHOUT
+# re-executing the forward kernel.  A name on a value derived from ``out``
+# does not do: the residual is ``out`` as the kernel wrote it, [B,H,S,hd],
+# and remat cannot get that back from the model's [B,S,H*hd] view of it —
+# on the v5e all four kernels of a layer still ran under "save_matmuls"
+# while only the view was named (PERF.md §6, PR 38).
 # Forward and backward take SEPARATE tile sizes: the fwd prefers a full-row K
 # block (no online-softmax carry — measured ~25% faster at S=2048), while the
 # bwd kernels are fastest (and compile reliably) at 1024.
@@ -405,7 +408,8 @@ def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
     out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                     block_mask)
     # names INSIDE the vjp-fwd so remat policies can pin the residuals
-    # themselves ("attn_lse" + the model-level "attn_out"/q/k/v names)
+    # themselves (with the model-level q/k/v names)
+    out = checkpoint_name(out, "attn_out")
     lse = checkpoint_name(lse, "attn_lse")
     return (out, lse), (q, k, v, out, lse)
 
@@ -436,9 +440,11 @@ def _flash_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
 
     out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
                     None)
-    # same residual tagging as _flash_fwd: a remat policy pinning
-    # 'attn_lse' must cover the ring path too, or every ring step's
-    # backward re-runs the forward kernel
+    # the ring's per-step output is NOT named as _flash_fwd's is: a policy
+    # that keeps "attn_out" would then hold one partial output a ring step
+    # a layer, n times the merged one the model names (unmeasured: PERF.md
+    # §7).  So under every policy the ring's backward runs each step's
+    # forward kernel again, and the program is what it was before PR 38
     lse = checkpoint_name(lse, "attn_lse")
     return (out, lse), (q, k, v, out, lse)
 

@@ -45,10 +45,11 @@ from .offload import (resolve_offload_mode, apply_streamed_placement,
                       HostSteppedOffload)
 from .features import (wire_compression, wire_progressive_layer_drop,
                        wire_curriculum, wire_random_ltd, wire_flops_profiler)
-from ..observability.trace import trace_span
+from ..observability.trace import get_tracer, trace_span
 from ..parallel.mesh import (dp_world_size, resolve_engine_mesh,
                              BATCH_AXES, ZERO_AXES)
 from ..utils.logging import logger, log_dist
+from ..utils.memory import is_out_of_memory, program_bytes
 from ..utils.timer import SynchronizedWallClockTimer, ThroughputTimer
 from .. import comm as dist
 
@@ -130,6 +131,26 @@ def _cast_tree(tree, dtype):
 
 def _tree_select(pred, a, b):
     return jax.tree_util.tree_map(lambda x, y: jnp.where(pred, x, y), a, b)
+
+
+# The share of a device's memory (``memory_stats()["bytes_limit"]``) that a
+# fused step may plan for before the engine takes the next leaner checkpoint
+# policy (:meth:`DeepSpeedEngine.resolve_remat`).  Chosen on a v5e (PERF.md
+# §6, PR 38), not a knob.  Its evidence is one cell, Pythia-1.4b at depth 10
+# and 8,192 tokens a step: the top rung's program, 97.1% of the limit, ran
+# every step of every run and beat the next rung (81.2%) by 5.4%, so the
+# constant sits just above the fullest program seen to run.  The compiler's
+# plan is exact for the program; the 2% left is for what the plan cannot
+# see (the allocator's fragmentation, the small arrays a loop keeps between
+# steps).  Tooling with a budget of its own hands that in instead
+# (``compile_train_step(batch, budget_bytes=...)``: the autotuner).
+REMAT_HEADROOM = 0.98
+
+
+def _shape_signature(tree) -> tuple:
+    """What a jitted step is compiled for: the shape and dtype of each leaf."""
+    return tuple((np.shape(x), str(getattr(x, "dtype", type(x).__name__)))
+                 for x in jax.tree_util.tree_leaves(tree))
 
 
 class DeepSpeedEngine:
@@ -359,6 +380,11 @@ class DeepSpeedEngine:
         self.tput_timer = ThroughputTimer(batch_size=self.train_batch_size,
                                           steps_per_output=self.config.steps_per_print)
         self._compiled_train_step = None
+        self.remat_resolution: Optional[Dict[str, Any]] = None
+        self._remat_span_attrs: Optional[Dict[str, Any]] = None
+        # batch shapes -> (the fused step resolved for them, its record)
+        self._remat_steps: Dict[tuple, Tuple[Any, Optional[Dict]]] = {}
+        self._remat_signature: Optional[tuple] = None
         self._compiled_grad_step = None
         self._compiled_eval_step = None
         self._compiled_micro_grad = None
@@ -375,6 +401,28 @@ class DeepSpeedEngine:
         wire_curriculum(self)
         wire_random_ltd(self, self.model)
         wire_flops_profiler(self)
+        # the checkpoint policy is the engine's to resolve where the model's
+        # config names none, the loss is the model's own (the resolver builds
+        # each rung's step from a model variant's ``loss_fn``: a loss the
+        # caller handed in is not its to swap) and the step is the fused one
+        # at a fixed token count: a step that grows (random-LTD's keep count,
+        # a sequence-length curriculum), the 1F1B executor's stash and the
+        # offload executors keep "nothing_saveable"
+        mcfg = getattr(self.model, "config", None)
+        self._remat_auto = False
+        if (getattr(mcfg, "remat", False) and hasattr(self.model, "variant")
+                and getattr(self.loss_fn, "__self__", None) is self.model
+                and self._random_ltd is None and not self._curriculum_seqlen
+                and self._offload is None
+                and self._param_offload is None
+                and getattr(mcfg, "pipeline_schedule", "gpipe") != "1f1b"):
+            from ..models.transformer import REMAT_AUTO
+
+            self._remat_auto = mcfg.remat_policy == REMAT_AUTO
+        if self._remat_auto:
+            log_dist("activation checkpointing: the model names no "
+                     "remat_policy; the engine resolves one from each "
+                     "compiled step's memory", ranks=[0])
         # per-program device-time accounting (docs/OBSERVABILITY.md
         # "Per-program accounting"): the fused train step registers its
         # lowered FLOPs on first run; every step counts an invocation and
@@ -674,9 +722,11 @@ class DeepSpeedEngine:
     # ------------------------------------------------------------------
     # The jitted step
     # ------------------------------------------------------------------
-    def _make_scaled_grad(self):
+    def _make_scaled_grad(self, loss_fn=None):
         """grad_fn(tree, scaler, batch, sub) -> (scaled grads, loss) —
         shared by the fused train_step scan and the per-microbatch loop.
+        ``loss_fn`` defaults to the engine's own; the fused step hands in the
+        variant whose checkpoint policy it resolved.
 
         ``tree`` is what :meth:`_compute_tree` returned: normally the
         compute-precision (bf16) params — differentiating w.r.t. the bf16
@@ -687,7 +737,7 @@ class DeepSpeedEngine:
         identical; accumulation still happens in fp32.  With ZeRO++ the
         quantized-gather cast must stay inside the grad (its custom VJP is
         the gradient reduce-scatter), so ``tree`` is the fp32 masters."""
-        loss_fn = self.loss_fn
+        loss_fn = loss_fn or self.loss_fn
         prescale = self.config.prescale_gradients
         predivide = self.config.gradient_predivide_factor
         cast_inside = self._compute_cast if self.use_master_weights else None
@@ -826,8 +876,7 @@ class DeepSpeedEngine:
         and swap in (or rebuild) the matching compiled step."""
         self._ltd_keep = keep
         active = keep < self.model.config.max_seq_len
-        variant = type(self.model)(
-            self.model.config, attn_impl=getattr(self.model, "attn_impl", "auto"),
+        variant = self.model.variant(
             random_ltd=active, random_ltd_keep=int(keep) if active else 0)
         self.loss_fn = variant.loss_fn
         self._compiled_train_step = self._ltd_cache.get(keep)
@@ -912,11 +961,11 @@ class DeepSpeedEngine:
             self._report_progress(metrics)
         return loss
 
-    def _make_train_step(self):
+    def _make_train_step(self, loss_fn=None):
         gas = self.gas
         grad_specs = self._grad_shardings
         pipeline = self.mesh.shape.get("pipe", 1) > 1
-        grad_of_batch = self._make_scaled_grad()
+        grad_of_batch = self._make_scaled_grad(loss_fn)
         compute_tree = self._make_compute_tree()
         apply_update = self._make_update_body()
         stream_in = self._stream_in
@@ -1086,6 +1135,105 @@ class DeepSpeedEngine:
                              out_shardings=self._train_out_shardings)
         return _jit_step(train_step, donate_argnums=(0,))
 
+    def _select_train_step(self, global_batch,
+                           budget_bytes: Optional[int] = None) -> None:
+        """Point ``_compiled_train_step`` at the fused step to run on
+        ``global_batch``.  Where the checkpoint policy is the engine's to
+        resolve that is one step a batch shape, each resolved from its own
+        program's memory (a longer batch than the first is held to the same
+        budget, not retraced under what the first one chose); else the one
+        step of the model as configured."""
+        if self._remat_auto:
+            signature = _shape_signature(global_batch)
+            if signature != self._remat_signature:
+                self._remat_signature = signature
+                self._compiled_train_step, self.remat_resolution = \
+                    self._remat_steps.get(signature, (None, None))
+        if self._compiled_train_step is not None:
+            return
+        if (not self._remat_auto
+                or self.resolve_remat(global_batch, budget_bytes) is None):
+            self._compiled_train_step = self._make_train_step()
+        if self._remat_auto:
+            self._remat_steps[signature] = (self._compiled_train_step,
+                                            self.remat_resolution)
+
+    def resolve_remat(self, global_batch, budget_bytes: Optional[int] = None):
+        """Pick what the backward keeps from how much memory the step has.
+
+        Walks ``REMAT_LADDER`` from the richest policy down.  Each rung is
+        the model variant with that policy behind a fused step, compiled
+        ahead of time for ``global_batch``'s shapes; the first whose program
+        fits is kept as the step to run: ``memory_analysis()``'s arguments +
+        temporaries + outputs - aliased (:func:`program_bytes`), within
+        ``budget_bytes`` (default: ``REMAT_HEADROOM`` of the device's
+        ``bytes_limit``).  A compile the compiler refuses as out of memory
+        does not fit; the last rung is taken whatever it needs.  The common
+        case compiles one program, the one ``train_batch`` then runs (the
+        jit cache is shared).
+
+        The rule reads the program's bytes and the device's limit, which
+        every process of a job sees alike, so all pick the same rung.
+        Returns the record also kept as ``remat_resolution``, or None where
+        the policy is not the engine's to resolve, or the backend reports no
+        memory limit to decide from (the CPU) and no budget was given."""
+        from ..models.transformer import REMAT_LADDER
+
+        if not self._remat_auto:
+            return None
+        if budget_bytes is None:
+            limit = self._device_bytes_limit()
+            if not limit:
+                return None
+            budget_bytes = int(REMAT_HEADROOM * limit)
+        tried = []
+        for rung in REMAT_LADDER:
+            last = rung == REMAT_LADDER[-1]
+            try:
+                step, compiled = self._compile_remat_rung(rung, global_batch)
+            except Exception as err:
+                if last or not is_out_of_memory(err):
+                    raise
+                tried.append({"policy": rung, "bytes": None})
+                continue
+            mem = compiled.memory_analysis()
+            tried.append({"policy": rung, "bytes": program_bytes(mem)})
+            if tried[-1]["bytes"] <= budget_bytes or last:
+                break
+        self._compiled_train_step = step
+        self.remat_resolution = {
+            "policy": rung, "tried": tried, "budget_bytes": int(budget_bytes),
+            "argument_bytes": mem.argument_size_in_bytes,
+            "temp_bytes": mem.temp_size_in_bytes,
+            "output_bytes": mem.output_size_in_bytes,
+            "alias_bytes": mem.alias_size_in_bytes}
+        rungs = ", ".join(
+            f"{t['policy']} " + ("does not compile in memory"
+                                 if t["bytes"] is None
+                                 else f"{t['bytes'] / 2**30:.2f} GiB")
+            for t in tried)
+        self._remat_span_attrs = {
+            "remat_policy": rung, "remat_tried": rungs,
+            "remat_argument_bytes": mem.argument_size_in_bytes,
+            "remat_temp_bytes": mem.temp_size_in_bytes,
+            "remat_budget_bytes": int(budget_bytes)}
+        self.program_catalog.annotate("train_step", **self._remat_span_attrs)
+        log_dist(f"activation checkpointing: remat_policy={rung} resolved "
+                 f"from the compiled step ({rungs}; may plan "
+                 f"{budget_bytes / 2**30:.2f} GiB)", ranks=[0])
+        return self.remat_resolution
+
+    def _device_bytes_limit(self) -> Optional[int]:
+        stats = self.mesh.local_devices[0].memory_stats() or {}
+        return stats.get("bytes_limit")
+
+    def _compile_remat_rung(self, rung: str, global_batch):
+        """``(step, compiled)``: the fused step of the model variant that
+        keeps ``rung``'s residuals, and its program for ``global_batch``."""
+        step = self._make_train_step(
+            self.model.variant(remat_policy=rung).loss_fn)
+        return step, step.lower(self.state, global_batch).compile()
+
     def _make_eval_step(self):
         eval_fn = self._eval_fn
         compress = self._compression_transform
@@ -1209,8 +1357,8 @@ class DeepSpeedEngine:
             return self._train_batch_param_offload(global_batch)
         if self._nvme_swapper is not None:
             return self._train_batch_nvme(global_batch)
-        if self._compiled_train_step is None:
-            self._compiled_train_step = self._make_train_step()
+        if self._remat_auto or self._compiled_train_step is None:
+            self._select_train_step(global_batch)
             if self._random_ltd is not None:
                 self._ltd_cache[self._ltd_keep] = self._compiled_train_step
         profiling = (self.flops_profiler is not None
@@ -1227,6 +1375,10 @@ class DeepSpeedEngine:
             self.state, metrics = self._compiled_train_step(self.state,
                                                             global_batch)
             _sp.sync(metrics["loss"])
+            if self._remat_span_attrs and get_tracer().enabled:
+                # once, on the first step a tracer sees
+                _sp.set(**self._remat_span_attrs)
+                self._remat_span_attrs = None
         # bookkeeping (train.monitor, here and below): untraced, this part
         # overlaps the step the device is still running
         with trace_span("train.monitor"):
@@ -1335,24 +1487,40 @@ class DeepSpeedEngine:
             raise NotImplementedError(
                 "lower_train_step does not cover the NVMe grad-only / "
                 "layer-streamed offload paths")
-        if self._compiled_train_step is None:
-            self._compiled_train_step = self._make_train_step()
-        return self._compiled_train_step.lower(self.state, global_batch)
+        if self._remat_auto:
+            # lowering alone cannot resolve a checkpoint policy (that takes
+            # the compiled program's memory): the step these shapes resolved
+            # to where they have, else the "nothing_saveable" one, which is
+            # not kept, so the first train_batch still resolves.  That is the
+            # leanest program the resolver can fall to: it fits a budget
+            # exactly where the resolved step will
+            step = (self._remat_steps.get(_shape_signature(global_batch),
+                                          (None, None))[0]
+                    or self._make_train_step())
+        else:
+            if self._compiled_train_step is None:
+                self._compiled_train_step = self._make_train_step()
+            step = self._compiled_train_step
+        return step.lower(self.state, global_batch)
 
-    def compile_train_step(self, batch):
+    def compile_train_step(self, batch, budget_bytes: Optional[int] = None):
         """AOT-compile the fused train step for ``batch``'s shapes and return
         the ``jax.stages.Compiled`` — its ``memory_analysis()`` /
         ``cost_analysis()`` let tooling (autotuner, flops profiler) judge a
         config without executing a step.  The jit cache is shared, so the
-        subsequent ``train_batch`` call does not recompile."""
+        subsequent ``train_batch`` call does not recompile.  Where the
+        model's checkpoint policy is the engine's to resolve, this is where
+        it is resolved for these shapes (:meth:`resolve_remat`), within
+        ``budget_bytes`` where the caller holds the program to a budget of
+        its own (the autotuner), so both judge by one."""
         global_batch = self._collect_global_batch(batch)
         global_batch = self._inject_pld_theta(global_batch, shape=(self.gas,))
         if self._nvme_swapper is not None or self._param_offload is not None:
             raise NotImplementedError(
                 "compile_train_step does not cover the NVMe grad-only / "
                 "layer-streamed offload paths")
-        if self._compiled_train_step is None:
-            self._compiled_train_step = self._make_train_step()
+        if self._remat_auto or self._compiled_train_step is None:
+            self._select_train_step(global_batch, budget_bytes)
         return self._compiled_train_step.lower(self.state,
                                                global_batch).compile()
 

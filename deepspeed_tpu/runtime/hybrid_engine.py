@@ -40,6 +40,12 @@ class DeepSpeedHybridEngine:
         from ..inference.config import DeepSpeedInferenceConfig
 
         self.engine = engine
+        # generation state (KV caches, a rollout engine's page pool) shares
+        # the device with the trainer and is in no fused step's program, so
+        # the step's own bytes do not say what fits: the engine resolves no
+        # checkpoint policy here ("nothing_saveable" unless the config names
+        # one; docs/TUNING.md "Remat")
+        engine._remat_auto = False
         model = model or engine.model
         if model is None or not hasattr(model, "apply_cached"):
             raise ValueError(

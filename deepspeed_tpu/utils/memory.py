@@ -16,6 +16,25 @@ from typing import Dict, Optional
 from .logging import logger
 
 
+def program_bytes(mem) -> int:
+    """What a compiled program plans to hold on a device, from its
+    ``memory_analysis()``: arguments + temporaries + outputs - aliased.  The
+    ONE formula wherever a program is held against a memory budget (the
+    engine's checkpoint-policy resolver, the autotuner's prune and measure)."""
+    return int(getattr(mem, "temp_size_in_bytes", 0)
+               + getattr(mem, "argument_size_in_bytes", 0)
+               + getattr(mem, "output_size_in_bytes", 0)
+               - getattr(mem, "alias_size_in_bytes", 0))
+
+
+def is_out_of_memory(err: BaseException) -> bool:
+    """The compiler's (or the runtime's) "this does not fit the device": XLA
+    raises it as RESOURCE_EXHAUSTED, "Ran out of memory in memory space
+    hbm"."""
+    text = f"{type(err).__name__}: {err}".lower()
+    return "resource_exhausted" in text or "out of memory" in text
+
+
 def _host_peak_rss_gb() -> float:
     rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
     # linux reports KB; darwin reports bytes

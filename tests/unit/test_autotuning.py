@@ -121,7 +121,7 @@ class _FakeEngine:
         a = 0.04 if self.stage == 2 else 0.08
         self._t = a + 1e-3 * self.mb + 2e-4 * self.mb ** 2
 
-    def compile_train_step(self, batch):
+    def compile_train_step(self, batch, budget_bytes=None):
         class _C:
             def memory_analysis(self_inner):
                 return None
@@ -237,7 +237,7 @@ class _FakeEngineDeep:
                    + self.mb * (0.8e-3 * S + 0.9e-3 * S * S)
                    + 2.5e-4 * self.mb ** 2)
 
-    def compile_train_step(self, batch):
+    def compile_train_step(self, batch, budget_bytes=None):
         class _C:
             def memory_analysis(self_inner):
                 return None

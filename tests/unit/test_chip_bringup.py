@@ -598,3 +598,98 @@ def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
     assert text.count("\n") > 1000          # the computations, not a header
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
         PROGRAMS_AT_PR_29[program]
+
+
+# What the v5e reports as ``memory_stats()["bytes_limit"]`` (my chip run,
+# PR 38), and the rung its policy table names for the one-chip training cell.
+V5E_BYTES_LIMIT = 16909336576
+PYTHIA_CELL_RUNG = "save_matmuls"
+
+
+def test_pythia_cell_resolves_the_chip_tables_rung_on_v5e(one_v5e_chip,
+                                                          monkeypatch):
+    """AOT: ``pythia-1.4b-d10`` at S=2,048 x mb 4 through the engine's
+    resolver.  The engine cannot be built on a described device (it places
+    its own state), so a small CPU engine runs the resolver and is handed,
+    in place of its own rungs, the cell's step compiled by the installed
+    libtpu for the v5e: the cell's model and loss under the rung's policy,
+    bf16 gradients into AdamW with a bf16 first moment on float32 masters,
+    the state donated.  It must stop at the rung the chip's table names,
+    with the program's bytes inside the headroom, and that program holds 3
+    flash custom calls a layer (forward, dq, dkv; the layers are one scan
+    forward and one backward) where ``nothing_saveable`` holds 4."""
+    import json
+
+    import numpy as np
+    import optax
+
+    import deepspeed_tpu
+    from benchmark.lib import system
+    from deepspeed_tpu.models import CausalLM, init_params
+    from deepspeed_tpu.models.transformer import REMAT_LADDER
+    from deepspeed_tpu.ops.pallas import flash_attention as flash
+    from deepspeed_tpu.parallel import mesh as mesh_mod
+    from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh
+    from deepspeed_tpu.runtime.engine import REMAT_HEADROOM
+
+    # compile the kernel itself: this host's interpret mode is the CPU's
+    monkeypatch.setattr(flash, "resolve_interpret", lambda interpret=None:
+                        False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "pythia-1.4b-d10.json")) as f:
+        cfg = system.transformer_config(json.load(f), False)
+
+    def placed(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                           sharding=one_v5e_chip), tree)
+
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
+    adam = optax.adamw(1e-4, mu_dtype=jnp.bfloat16)
+    state = (placed(shapes, jnp.bfloat16), placed(shapes, jnp.float32),
+             placed(jax.eval_shape(adam.init, placed(shapes, jnp.float32))))
+    tokens = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_v5e_chip)
+    texts = {}
+
+    def compile_rung(rung, _batch):
+        model = CausalLM(cfg, attn_impl="pallas", remat_policy=rung)
+
+        def step(state, toks):
+            params, master, moments = state
+            loss, grads = jax.value_and_grad(model.loss_fn)(
+                params, {"input_ids": toks}, jax.random.PRNGKey(0))
+            updates, moments = adam.update(grads, moments, master)
+            master = optax.apply_updates(master, updates)
+            params = jax.tree_util.tree_map(
+                lambda m: m.astype(jnp.bfloat16), master)
+            return (params, master, moments), loss
+
+        jitted = jax.jit(step, donate_argnums=(0,))
+        compiled = jitted.lower(state, tokens).compile()
+        texts[rung] = compiled.as_text()
+        return jitted, compiled
+
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=CausalLM("tiny", remat=True),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "adamw", "params": {"lr": 1e-4}},
+                "bf16": {"enabled": True}, "steps_per_print": 10 ** 9},
+        mesh=initialize_mesh(MeshLayout(), devices=jax.devices()[:1]))
+    assert engine._remat_auto
+    # the forward constrains activations on the global mesh: the CPU's
+    mesh_mod.reset_mesh()
+    monkeypatch.setattr(engine, "_compile_remat_rung", compile_rung)
+    monkeypatch.setattr(engine, "_device_bytes_limit",
+                        lambda: V5E_BYTES_LIMIT)
+    got = engine.resolve_remat({"input_ids": np.zeros((1, 1, 8), np.int32)})
+    assert got["policy"] == PYTHIA_CELL_RUNG
+    assert [t["policy"] for t in got["tried"]] == list(
+        REMAT_LADDER[:REMAT_LADDER.index(PYTHIA_CELL_RUNG) + 1])
+    need = got["tried"][-1]["bytes"]
+    assert need <= got["budget_bytes"] == int(
+        REMAT_HEADROOM * V5E_BYTES_LIMIT), need
+    # richer than the parent's program, which is why it is faster
+    compile_rung("nothing_saveable", None)
+    calls = {r: t.count('custom_call_target="tpu_custom_call"')
+             for r, t in texts.items()}
+    assert calls[PYTHIA_CELL_RUNG] == 3 and calls["nothing_saveable"] == 4

@@ -225,6 +225,7 @@ def test_spans_carry_the_rows_of_each_kind_and_the_held_pairs(engine,
     monkeypatch.setattr(T, "WINDOW_BLOCK_CHUNKS", 1)
     sv = engine.serving(**SERVE_KW)
     sv.run(_requests(2, seed=1), max_ticks=2000)        # warm
+    get_tracer().reset()     # spans an earlier test of this process left
     configure_tracer(enabled=True)
     try:
         sv.run(_requests(5, seed=2), max_ticks=4000)
