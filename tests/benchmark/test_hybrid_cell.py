@@ -62,11 +62,34 @@ def test_rehearse_the_cell(capsys):
 
 
 def test_the_hybrid_metrics_list_the_new_cell_alone(manifest):
-    """No cell of a one-pool model is asked for them."""
-    for m in manifest["per_layer"]:
-        if m["name"] in HYBRID | {"hybrid_decode_roofline"}:
-            assert m["workloads"] == [CELL]
-            assert m["moves"] == "serve_tokens_per_s"
+    """No cell of a one-pool model is asked for them, as a rule read from
+    the configurations and not a list of one: the two held-share readers
+    are reported by exactly the serving cells whose configuration holds a
+    share of its experts (``n_routed_experts`` under ``reduced``), the two
+    K/V readers and the roofline by this cell and by none whose model has
+    one kind of attention layer."""
+    from benchmark.lib import system
+    from deepspeed_tpu.models.transformer import is_hybrid
+
+    configs = {c["name"]: c for c in manifest["configs"]}
+    config_of = {c["name"]: configs[c["config"]]
+                 for c in manifest["workloads"]}
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    (serving,) = [set(m["workloads"]) for m in manifest["end_to_end"]
+                  if m["name"] == "serve_tokens_per_s"]
+    held = {cell for cell in serving
+            if "n_routed_experts" in config_of[cell]["reduced"]}
+    for name in ("moe_local_pair_share", "moe_held_touched_share"):
+        assert set(by_name[name]["workloads"]) == held, name
+    for name in ("kv_window_rows_share", "kv_full_read_useful_share",
+                 "hybrid_decode_roofline"):
+        assert CELL in by_name[name]["workloads"]
+        for cell in by_name[name]["workloads"]:
+            with open(os.path.join(ROOT, config_of[cell]["file"])) as f:
+                cfg = system.transformer_config(json.load(f), rehearse=False)
+            assert is_hybrid(cfg), (name, cell)
+    for name in HYBRID | {"hybrid_decode_roofline"}:
+        assert by_name[name]["moves"] == "serve_tokens_per_s"
 
 
 def test_a_cut_configuration_states_what_it_cut_and_names_its_reference(manifest):

@@ -13,8 +13,8 @@ from benchmark import run as bench_run
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 CELL = "kanana-2-30b-a3b-ep8-d24.longctx-backlog"
-NEW = {"kv_latent_traffic_share", "moe_local_pair_share.shared_experts",
-       "moe_held_touched_share.shared_experts"}
+NEW = {"kv_latent_traffic_share", "moe_local_pair_share",
+       "moe_held_touched_share"}
 
 
 @pytest.fixture(scope="module")
@@ -229,11 +229,11 @@ def test_latent_readers_on_a_hand_built_record():
     got = _read("mla_decode_roofline", rec)
     assert got == pytest.approx(100 * need / 27.0e-3)
     assert 0 < got < 100
-    # the expert layer's two readers under the names this cell lists them by
-    assert _read("moe_local_pair_share.shared_experts", rec) == pytest.approx(
+    # the expert layer's two readers, which this cell shares with MiMo's
+    assert _read("moe_local_pair_share", rec) == pytest.approx(
         100 * (540 + 564 + 69_000) / (4416 * 2 + 552_000))
-    assert _read("moe_held_touched_share.shared_experts", rec) == \
-        _read("moe_held_touched_share", rec)
+    assert _read("moe_held_touched_share", rec) == pytest.approx(
+        100 * (280 + 300) / (368 * 2))
     assert _read("kv_gather_useful_share.capacity", rec) == pytest.approx(
         100 * (300_000 + 8) / (155_648 + 172_032 + 8192))
 
