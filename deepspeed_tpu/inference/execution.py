@@ -50,8 +50,9 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import (PAGED_POOL_KEYS, cow_copy_pool,
-                                  expert_counts_shape, is_hybrid, is_latent,
+from ..models.transformer import (PAGED_POOL_KEYS, SSM_POOL_KEYS,
+                                  cow_copy_pool, expert_counts_shape,
+                                  is_hybrid, is_latent, is_ssm,
                                   paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, per_layer_leaves,
                                   window_ring_pages)
@@ -279,6 +280,13 @@ class MeshExecutor:
                       "no head axis to shard or scale, and a block of more "
                       "than one token attends within itself, so it has to "
                       "start its slot")
+        elif is_ssm(cfg):
+            # two leaves a row a slot beside the pages (docs/SERVING.md "A
+            # state a slot"): a page's contents say nothing of the state
+            # that went with them
+            unlike = ("state-space layers (a state a slot): a slot's state "
+                      "is one tensor that no page holds, so a page copied, "
+                      "parked, rescaled or split by head leaves it behind")
         if unlike:
             for on, what in ((self.tp > 1, "tensor-sharded heads (tp > 1)"),
                              (prefix_cache, "copy-on-write page snapshots "
@@ -289,8 +297,13 @@ class MeshExecutor:
                 if on:
                     raise NotImplementedError(
                         f"{what} does not support a model with {unlike}")
+        # the prefill program of a model with a state a slot is told which
+        # slot's row it resets and writes
+        self.stateful = is_ssm(cfg)
         if self.ring_pages:
             pool_kw = {"dtype": dtype, "window_pages": self.window_pages}
+        elif self.stateful:
+            pool_kw = {"dtype": dtype, "slots": self.b_slots}
         else:
             pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
@@ -400,6 +413,10 @@ class MeshExecutor:
         # constant for the engine's lifetime (the pool never reallocates):
         # health()/gauges read these per tick, so compute them once
         self.pool_bytes = pool_bytes(*self.pools)
+        # of which the leaves indexed by slot (a state-space model's)
+        self.state_bytes = sum(
+            int(a.nbytes) for k, a in zip(self._pool_keys, self.pools)
+            if k in SSM_POOL_KEYS)
         # device copy of the lane vectors, rebuilt only when a lane
         # changes (admission / retirement) — unlike lengths/last_tok the
         # lanes are constant across a request's whole decode, so the
@@ -598,29 +615,38 @@ class MeshExecutor:
         apply_paged, with_counts = self._apply_paged, self._with_counts
         keys = self._pool_keys
 
+        stateful = self.stateful
+
         def prog(params, pools, pt_row, tokens, n_real, start,
-                 temp, top_k, top_p, seed, *adapters):
+                 temp, top_k, top_p, seed, *rest):
             # tokens [1, s_pad] right-padded; only the first n_real K/V are
             # written (pads go to the trash page); the first generated token
             # samples the last REAL position's logits under the request's
             # lane ([1]-shaped traced params — greedy folds to argmax
             # in-graph, so the historical greedy contract is bit-identical).
+            # The head runs over that one position (``logits_at``), not over
+            # the bucket.
             # `start` is the slot position of tokens[:, 0] — 0 for a cold
             # prefill, the shared-prefix length for a tail prefill (the
             # read starts at the slot's first page, so queries attend to
             # the shared pages through the ordinary causal mask).
             # A traced scalar: every start shares ONE program per bucket.
-            # `adapters`: the admitted slot's factor slice, as in decode.
+            # `rest`: the admitted slot's factor slice, as in decode; or,
+            # for a model with a state a slot, the slot (a traced scalar:
+            # one program a bucket serves every slot), whose state row the
+            # call resets (start 0) and leaves at the last real token.
             seq_mask = (jnp.arange(s_pad, dtype=jnp.int32) < n_real)[None, :]
             cache = paged_pool_cache(pools, keys)
+            kw = ({"state_slot": rest[0][None]} if stateful else
+                  {"adapters": rest[0] if rest else None})
             logits, cache, counts = apply_paged(
                 params, tokens, cache, pt_row, start[None], seq_mask,
-                adapters=adapters[0] if adapters else None)
+                logits_at=(n_real - 1)[None], **kw)
             # the emitted token will sit at stream position S = start +
             # n_real — the counter-based key generate(sampling=...) and
             # every replay/failover resume re-derive for the same position
             with jax.named_scope("sample"):
-                lg = logits[0, n_real - 1, :][None]        # [1, V]
+                lg = logits[0]                             # [1, V]
                 nxt = sample_tokens(
                     lg, temp, top_k, top_p,
                     lambda: position_keys(seed, (start + n_real)[None]))[0]
@@ -689,13 +715,14 @@ class MeshExecutor:
         return nxt
 
     def prefill(self, s_pad: int, pt_row, tokens, n_real, start,
-                lane_t, lane_k, lane_p, lane_s, adapter_row=None):
+                lane_t, lane_k, lane_p, lane_s, adapter_row=None, slot=0):
         """One bucketed prefill ([1, s_pad]); returns the first sampled
         token (device scalar; a vector led by it where the model has expert
         counts, :meth:`split_counts`) and updates the pools.  Builds the bucket's
         program on first use — the bucket set IS the program inventory.
         ``adapter_row`` is the admitted slot's one-slot factor slice
-        (:meth:`adapter_row`) when a registry rides along."""
+        (:meth:`adapter_row`) when a registry rides along; ``slot`` the slot
+        admitted, which a model with a state a slot needs to know."""
         prog = self._prefill_progs.get(s_pad)
         if prog is None:
             prog = self._prefill_progs[s_pad] = self._build_prefill(s_pad)
@@ -711,6 +738,8 @@ class MeshExecutor:
         if self.adapters is not None:
             args += (adapter_row if adapter_row is not None
                      else self._adapter_zero_row(),)
+        elif self.stateful:
+            args += (jnp.int32(slot),)
         t0 = account(self.catalog, f"prefill_{s_pad}", prog, args)
         nxt, self.pools = prog(*args)
         if t0 is not None:

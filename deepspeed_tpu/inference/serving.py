@@ -128,8 +128,9 @@ import jax.numpy as jnp
 
 from ..models.transformer import (PAGE_SIZE, block_read_rows,
                                   causal_walk_steps, is_hybrid, is_latent,
-                                  kind_layers, paged_read_rows,
-                                  window_read_rows, window_ring_pages)
+                                  is_ssm, kind_layers, paged_read_rows,
+                                  ssm_scan_chunks, window_read_rows,
+                                  window_ring_pages)
 from ..observability.device_profiler import (device_trace_unit,
                                              maybe_capture_from_env)
 from ..observability.program_stats import ProgramCatalog
@@ -356,6 +357,7 @@ class _Ahead:
     lanes: Any
     adapters: Any
     seq: int    # its ``serve.launch``'s, for the ``serve.fetch`` that reads it
+    gen: np.ndarray     # each slot's admission count at the launch
 
 
 class ServingEngine:
@@ -419,6 +421,11 @@ class ServingEngine:
         # than one token attends within itself over its expanded keys and
         # values, so it has to start its slot
         self._latent = is_latent(cfg)
+        # a state a slot (docs/SERVING.md "A state a slot"): every tick
+        # advances each live slot's recurrent state whole, admission resets
+        # it through the prefill at position 0, and a tick that was launched
+        # is never un-launched for a slot that goes on (_take_ahead)
+        self._stateful = is_ssm(cfg)
         unlike = None
         if self._ring:
             unlike = ("window layers (layer_pattern): a window layer's ring "
@@ -429,6 +436,11 @@ class ServingEngine:
                       "slot reads its latent rows back, so a prompt's tail "
                       "behind shared pages, or a block of draft tokens, has "
                       "nothing to attend through")
+        elif self._stateful:
+            unlike = ("state-space layers (a state a slot): a slot's state "
+                      "after a prefix, a parked page or a rejected draft "
+                      "token is held nowhere, so there is nothing to start "
+                      "a tail from or to go back to")
         if unlike:
             for on, what in (
                     (prefix_cache, "prefix sharing (prefix_cache=True)"),
@@ -438,7 +450,8 @@ class ServingEngine:
                     raise NotImplementedError(
                         f"{what} does not support a model with {unlike}")
         if prefix_cache is None:
-            prefix_cache = not (self._ring or self._latent)
+            prefix_cache = not (self._ring or self._latent
+                                or self._stateful)
         self.monitor = monitor
         self.watchdog = watchdog
         # decode lookahead (docs/SERVING.md "Decode lookahead"): launch tick
@@ -450,6 +463,12 @@ class ServingEngine:
         self._in_run = False
         self.lookahead_launched = 0
         self.lookahead_dropped = 0
+        # stale ticks of a model with a state a slot whose tokens were taken
+        # for the slots that went on (never dropped: _take_ahead)
+        self.lookahead_stale_taken = 0
+        # admissions a slot has seen: a tick launched ahead names the
+        # request it advanced by (slot, count)
+        self._slot_gen = np.zeros((self.b_slots,), np.int64)
         # decode and prefill programs launched so far: the k-th launch is
         # the k-th such program the device runs (``seq`` of the
         # ``serve.launch`` / ``serve.fetch`` spans)
@@ -680,7 +699,8 @@ class ServingEngine:
             # kv_pool_bytes_total spent on per-page scale rows (0 on a
             # full-precision pool), page_bytes the all-in per-page cost —
             # the honest denominator of the 2× capacity claim
-            scale_bytes = sum(int(a.nbytes) for a in self._exec.pools[2:])
+            scale_bytes = (sum(int(a.nbytes) for a in self._exec.pools[2:])
+                           if self._exec.quantized else 0)
             self.monitor.write_events(
                 [("serve/mesh_devices", float(info["mesh_devices"]), 0),
                  ("serve/kv_pool_bytes_total", float(pb["total"]), 0),
@@ -690,7 +710,9 @@ class ServingEngine:
                   1.0 if self._exec.quantized else 0.0, 0),
                  ("serve/kvq_scale_bytes_total", float(scale_bytes), 0),
                  ("serve/kvq_page_bytes",
-                  float(pb["total"] // self.num_pages), 0)]
+                  float(pb["total"] // self.num_pages), 0),
+                 ("serve/state_pool_bytes",
+                  float(self._exec.state_bytes), 0)]
                 + [(f"serve/mesh_axis_{a}", float(s), 0)
                    for a, s in info["mesh_axes"].items()])
 
@@ -1139,6 +1161,9 @@ class ServingEngine:
                 "in flight: a live stream's K/V would straddle two weight "
                 "epochs — drain or finish the tick loop first "
                 "(RolloutEngine sequences rounds so this cannot happen)")
+        # ticks still in flight were launched on the old weights: what they
+        # computed is taken under the old epoch before the tree is swapped
+        self._settle_ahead()
         t0 = time.monotonic()
         with trace_span("serve.weight_update", epoch=self._weight_epoch + 1):
             # swaps first (each validates BEFORE mutating), flush last, and
@@ -1644,6 +1669,13 @@ class ServingEngine:
                         # attends within itself
                         gathered_rows=0 if self._ring or self._latent else
                         self._gathered_rows([n_shared + S_tail], 1)) as sp:
+            if self._stateful and get_tracer().enabled:
+                # chunks of the scan that hold a real token beside the
+                # bucket's, and that the call resets its slot's state
+                cfg = self.model.config
+                sp.set(scan_chunks=ssm_scan_chunks(cfg, s_pad, S_tail),
+                       scan_chunks_bucket=ssm_scan_chunks(cfg, s_pad),
+                       state_reset=int(n_shared == 0))
             if (self._ring or self._latent) and get_tracer().enabled:
                 # the chunk steps its full or latent layers run, bounded by
                 # the prompt's own length, beside the whole bucket's
@@ -1700,6 +1732,7 @@ class ServingEngine:
         self._lengths[slot] = S
         self._last_tok[slot] = tok
         self._active[slot] = True
+        self._slot_gen[slot] += 1
         self._lane_temp[slot] = lane_t
         self._lane_top_k[slot] = lane_k
         self._lane_top_p[slot] = lane_p
@@ -1780,7 +1813,7 @@ class ServingEngine:
             pt_row = jax.tree_util.tree_map(jnp.asarray, self._tables(slot))
             toks_j = jnp.asarray(toks)
             out = self._exec.prefill(s_pad, pt_row, toks_j, n_real, start,
-                                     *lane_and_adapter)
+                                     *lane_and_adapter, slot=slot)
         self._launch_seq += 1
         return out, self._launch_seq, pt_row, toks_j
 
@@ -1921,25 +1954,75 @@ class ServingEngine:
                                    self._last_tok[self._active]))
 
     def _take_ahead(self, lanes, adapters):
-        """The device output of this tick and its launch's ``seq`` if it
-        was launched ahead on exactly the state the host now holds, else
-        ``None``, and then every tick launched after it goes too.  What
-        they wrote is one K/V row a
-        slot each, past the slot's length, which the ticks launched in
-        their place write again; and where a slot ended under them (a
-        deadline) and its pages went to a request admitted since, the order
-        of the programs on the device keeps the pages right: the stale
-        ticks' rows land first, the new request's prefill, launched after
-        them, over them, and a row past its prompt is one no read reaches
-        before the slot's own decode writes it."""
-        if not self._ahead:
-            return None
-        if self._ahead_current(lanes, adapters):
+        """``(the device output of this tick, its launch's seq, the slots it
+        is emitted for)`` if a tick launched ahead stands for this one, else
+        ``None``.
+
+        **Models whose cache is K/V rows alone.**  The tick is used only if
+        it was launched on exactly the state the host now holds (every live
+        slot: ``None`` for the slots); else it and every tick launched after
+        it are dropped.  What they wrote is one K/V row a slot each, past
+        the slot's length, which the ticks launched in their place write
+        again; and where a slot ended under them (a deadline) and its pages
+        went to a request admitted since, the order of the programs on the
+        device keeps the pages right: the stale ticks' rows land first, the
+        new request's prefill, launched after them, over them, and a row
+        past its prompt is one no read reaches before the slot's own decode
+        writes it.
+
+        **A model with a state a slot.**  That argument is about rows and is
+        false for a state: a dropped tick has also advanced every live
+        slot's recurrent state by one token, and the tick launched in its
+        place would advance it again.  So a launched tick is never
+        un-launched for a slot that goes on: a stale tick is fetched and its
+        token emitted for every slot whose OWN inputs it was launched on
+        (the same admission of the slot, its length, its last token, its
+        page row: a mask of slots), since no slot's arithmetic depends on
+        another's.  A slot that ended under it is reset by its next
+        admission's prefill, which the device runs after the stale ticks
+        because it was launched after them; a slot admitted since waits
+        until the stale ticks are through (nothing is launched ahead over
+        them).  A stale tick under which no slot goes on is fetched and
+        read by no one."""
+        while self._ahead:
+            if self._ahead_current(lanes, adapters):
+                ahead = self._ahead.popleft()
+                return ahead.out, ahead.seq, None
+            if not self._stateful:
+                self.lookahead_dropped += len(self._ahead)
+                self._ahead.clear()
+                return None
             ahead = self._ahead.popleft()
-            return ahead.out, ahead.seq
-        self.lookahead_dropped += len(self._ahead)
-        self._ahead.clear()
+            self.lookahead_stale_taken += 1
+            own = self._ahead_slots(ahead)
+            if own.any():
+                return ahead.out, ahead.seq, own
+            self._fetch(ahead.out, "decode", ahead.seq)
         return None
+
+    def _ahead_slots(self, ahead: _Ahead) -> np.ndarray:
+        """The slots ``[b_slots]`` (bool) for which the stale tick ``ahead``
+        computed the token the host would ask for now: fed the tick emitted
+        last under the weights of now, and the slot live then and now under
+        the same admission, at the same length, last token and page row."""
+        if (self._last_out is None or ahead.fed is not self._last_out[0]
+                or ahead.params is not self._exec.params):
+            return np.zeros((self.b_slots,), bool)
+        return (ahead.active & self._active
+                & (ahead.gen == self._slot_gen)
+                & (ahead.lengths == self._lengths)
+                & (self._last_out[1][:self.b_slots] == self._last_tok)
+                & (ahead.page_table == self._page_table).all(axis=1))
+
+    def _settle_ahead(self) -> None:
+        """Before the weights change under a model with a state a slot: the
+        ticks in flight are fetched (no slot is live, so their tokens are no
+        one's) and counted as taken, not dropped.  Any other model's are
+        found stale by their parameters when their turn comes."""
+        while self._stateful and self._ahead:
+            ahead = self._ahead.popleft()
+            self.lookahead_stale_taken += 1
+            self._fetch(ahead.out, "decode", ahead.seq)
 
     def _launch_ahead(self, nxt, lanes, adapters) -> None:
         """Top the queue of launched ticks up to :meth:`_lookahead_depth`:
@@ -1956,7 +2039,7 @@ class ServingEngine:
             self._ahead.append(_Ahead(
                 out, fed, self._page_table.copy(), lengths,
                 self._active.copy(), self._exec.params, lanes, adapters,
-                seq))
+                seq, self._slot_gen.copy()))
             self.lookahead_launched += 1
 
     def _decode_tick(self, rid_map: Optional[Dict[str, str]] = None,
@@ -1983,11 +2066,13 @@ class ServingEngine:
                 sp.set(slot_rids=rid_map, ahead=len(self._ahead))
             maybe_fire(SITE_SERVE_DECODE, tick=self._tick)
             with self._armed(f"serve.decode tick {self._tick}"):
-                nxt, seq = (self._take_ahead(lanes, adapters)
-                            or self._launch_decode(self._lengths,
-                                                   self._last_tok, lanes,
-                                                   adapters, ahead=0))
-                if not held:
+                # ``own``: the slots a stale tick of a model with a state a
+                # slot is emitted for (None: every live slot)
+                nxt, seq, own = (self._take_ahead(lanes, adapters)
+                                 or (*self._launch_decode(
+                                     self._lengths, self._last_tok, lanes,
+                                     adapters, ahead=0), None))
+                if not held and own is None:
                     self._launch_ahead(nxt, lanes, adapters)
                 if rid_map is not None:
                     # the launch has returned; what is left of the span is
@@ -2001,6 +2086,12 @@ class ServingEngine:
                                live + 1, self.b_slots))
                     if self._ring:
                         self._set_kv_row_attrs(sp, live + 1, self.b_slots)
+                    if self._stateful:
+                        # live slots whose state the tick read and wrote,
+                        # and the bytes of one reading of them
+                        n = int(self._active.sum())
+                        sp.set(state_slots=n, state_bytes=n * (
+                            self._exec.state_bytes // self.b_slots))
                 # host fetch = device sync; an MoE model's expert counts
                 # come with the tokens
                 out = nxt
@@ -2011,7 +2102,7 @@ class ServingEngine:
                     self._set_moe_attrs(sp, counts,
                                         int(self._active.sum()))
         t_tok = time.monotonic()   # every token of this tick: its emit stamp
-        active_slots = np.flatnonzero(self._active)
+        active_slots = np.flatnonzero(self._active if own is None else own)
         trace_count("serve.tokens", float(len(active_slots)))
         with trace_span("serve.emit", tick=self._tick) as sp:
             for slot in active_slots:
@@ -2456,6 +2547,12 @@ class ServingEngine:
             # was fetched, and those of them found stale and not used
             "lookahead_launched_total": self.lookahead_launched,
             "lookahead_dropped_total": self.lookahead_dropped,
+            # of a model with a state a slot: stale ticks fetched and their
+            # tokens taken for the slots that went on (it drops none), and
+            # the bytes of the cache's leaves indexed by slot (counted in
+            # kv_pool_bytes_* too)
+            "lookahead_stale_taken_total": self.lookahead_stale_taken,
+            "state_pool_bytes": self._exec.state_bytes,
             # KV-page tiering (docs/SERVING.md "KV-page tiering"): the
             # demoted ledger and host-tier footprint, plus the cumulative
             # movement counters — what capacity planning reads to size the
@@ -2584,6 +2681,8 @@ class ServingEngine:
              float(self._prefix.evictions if self._prefix is not None
                    else 0), self._tick),
             ("serve/cow_copies_total", float(self.cow_copies), self._tick),
+            ("serve/lookahead_stale_taken_total",
+             float(self.lookahead_stale_taken), self._tick),
             ("serve/sampled_admissions_total",
              float(self.sampled_admissions), self._tick),
             ("serve/weight_epoch", float(self._weight_epoch), self._tick),

@@ -117,7 +117,7 @@ class ServingSupervisor:
         self._prefix_pages_base = 0
         self._prefix_evictions_base = 0
         self._cow_base = 0
-        self._lookahead_base = (0, 0)   # launched, dropped
+        self._lookahead_base = (0, 0, 0)   # launched, dropped, stale taken
         self._sampled_base = 0
         self._adapter_admissions_base = 0
         self._spec_ticks_base = 0
@@ -342,6 +342,7 @@ class ServingSupervisor:
         h["cow_copies_total"] += self._cow_base
         h["lookahead_launched_total"] += self._lookahead_base[0]
         h["lookahead_dropped_total"] += self._lookahead_base[1]
+        h["lookahead_stale_taken_total"] += self._lookahead_base[2]
         h["sampled_admissions_total"] += self._sampled_base
         h["adapter_admissions_total"] += self._adapter_admissions_base
         h["spec_verify_slot_ticks_total"] += self._spec_ticks_base
@@ -625,7 +626,8 @@ class ServingSupervisor:
         self._cow_base += old.cow_copies
         self._lookahead_base = (
             self._lookahead_base[0] + old.lookahead_launched,
-            self._lookahead_base[1] + old.lookahead_dropped)
+            self._lookahead_base[1] + old.lookahead_dropped,
+            self._lookahead_base[2] + old.lookahead_stale_taken)
         self._sampled_base += old.sampled_admissions
         self._adapter_admissions_base += old.adapter_admissions
         if old._spec is not None:

@@ -202,19 +202,22 @@ class CausalLM:
     #    kv_dtype="int8" narrows the pool's at-rest representation (per-page
     #    scale planes ride in the cache dict); None = compute dtype --
     def init_paged_cache(self, num_pages, page_size=PAGE_SIZE, dtype=None,
-                         kv_dtype=None, window_pages=None):
+                         kv_dtype=None, window_pages=None, slots=1):
         return init_paged_cache(self.config, num_pages, page_size, dtype,
-                                kv_dtype=kv_dtype, window_pages=window_pages)
+                                kv_dtype=kv_dtype, window_pages=window_pages,
+                                slots=slots)
 
     def paged_cache_specs(self, kv_dtype=None):
         return paged_cache_specs(self.config, kv_dtype=kv_dtype)
 
     def apply_paged(self, params, tokens, cache, page_table, start, seq_mask,
-                    adapters=None, expert_counts=False, pool_order=None):
+                    adapters=None, expert_counts=False, pool_order=None,
+                    state_slot=None, logits_at=None):
         return forward_paged(self.config, params, tokens, cache, page_table,
                              start, seq_mask, adapters=adapters,
                              expert_counts=expert_counts,
-                             pool_order=pool_order)
+                             pool_order=pool_order, state_slot=state_slot,
+                             logits_at=logits_at)
 
     @property
     def param_count(self) -> int:

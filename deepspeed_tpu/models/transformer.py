@@ -117,6 +117,38 @@ class TransformerConfig:
     # themselves with the up-projections absorbed into the query and the
     # output (:func:`_attend_latent_paged`).  None: K and V heads.
     kv_lora_rank: Optional[int] = None
+    # State-space layers beside attention (Falcon-H1, ``falcon_h1``): every
+    # block runs a Mamba-2 mixer and the attention side by side on ONE normed
+    # input, ``x + a * attn(n) + b * ssm(n)``, then the MLP on the sum
+    # (:func:`_block`).  ``ssm_heads`` heads of ``ssm_head_dim`` channels
+    # (``mamba_d_ssm`` together), each with a ``[ssm_head_dim, ssm_state]``
+    # float32 state a sequence; B and C shared by ``ssm_heads / ssm_groups``
+    # heads; a depthwise causal convolution of ``ssm_conv`` taps over x, B and
+    # C; a prompt runs the recurrence in chunks of ``ssm_chunk`` positions
+    # (:func:`_ssm_scan`), a decode token as the recurrence itself.  The
+    # paged cache then holds two leaves with NO page axis beside K and V:
+    # ``ssm_state [L, slots, heads, head_dim, state]`` and the convolution's
+    # tail ``ssm_conv [L, slots, taps - 1, channels]``.  0 heads: no mixer.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # the family's fixed multipliers (muP), every one a constant of the
+    # published config: on the embedding and the logits, on attention's
+    # input, keys and output, on the mixer's input, output and the five
+    # segments of its in-projection (z, x, B, C, dt), on the MLP's gate
+    # and output
+    embed_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
     tie_embeddings: bool = False
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -235,6 +267,12 @@ class TransformerConfig:
             attn += nh * hd + nkv * hd
         if self.window_attn_sink:
             attn += nh
+        if self.ssm_heads:
+            # the mixer beside attention: in- and out-projection, the
+            # convolution with its bias, A, D, dt's bias, the gated norm
+            ds, conv = ssm_widths(self)[:2]
+            attn += (d * ssm_in_width(self) + ds * d
+                     + conv * (self.ssm_conv + 1) + 3 * self.ssm_heads + ds)
         if self.moe_intermediate_size and self.num_experts != 1:
             f = self.moe_intermediate_size
         mlp = 3 * d * f if self.activation == "swiglu" else 2 * d * f
@@ -352,6 +390,26 @@ CONFIGS: Dict[str, TransformerConfig] = {
         moe_score_func="sigmoid", moe_select_bias=True,
         moe_norm_topk_prob=True, moe_routed_scale=2.448,
         moe_shared_experts=2, moe_drop_tokens=False, remat=False),
+    # tiiuae/Falcon-H1-34B-Instruct config.json (``falcon_h1``): 72 blocks,
+    # each a Mamba-2 mixer (32 heads of 128 channels = ``mamba_d_ssm`` 4,096,
+    # state 256, 2 groups, 4 taps, chunk 128) beside grouped-query attention
+    # (20 heads over 4 KV heads of 128, rotary theta 1e11) on one normed
+    # input, then a SwiGLU of 21,504; fixed multipliers on every branch;
+    # RMSNorm eps 1e-5, untied head over 261,120 ids
+    "falcon-h1-34b": TransformerConfig(
+        vocab_size=261120, hidden_size=5120, intermediate_size=21504,
+        num_layers=72, num_heads=20, num_kv_heads=4, head_dim=128,
+        max_seq_len=262144, norm_eps=1e-5, rope_theta=1e11,
+        ssm_heads=32, ssm_head_dim=128, ssm_state=256, ssm_groups=2,
+        ssm_conv=4, ssm_chunk=128,
+        embed_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        attn_in_multiplier=1.0, attn_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -434,6 +492,26 @@ def is_latent(cfg: TransformerConfig) -> bool:
     """Latent attention (``kv_lora_rank``): one cache leaf with no head
     axis, and two attention paths over it."""
     return bool(cfg.kv_lora_rank)
+
+
+def is_ssm(cfg: TransformerConfig) -> bool:
+    """State-space layers beside attention (``ssm_heads``): a fixed-size
+    state a sequence in every block, two cache leaves with no page axis."""
+    return bool(cfg.ssm_heads)
+
+
+def ssm_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """``(d_ssm, convolved channels, B's or C's width)`` of the mixer:
+    heads x head_dim; x, B and C together; groups x state."""
+    d_ssm, gn = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+    return d_ssm, d_ssm + 2 * gn, gn
+
+
+def ssm_in_width(cfg: TransformerConfig) -> int:
+    """The in-projection's outputs: ``[z | x | B | C | dt]`` (9,248 for
+    Falcon-H1-34B)."""
+    d_ssm, conv, _ = ssm_widths(cfg)
+    return d_ssm + conv + cfg.ssm_heads
 
 
 def window_ring_pages(window: int, page_size: int) -> int:
@@ -590,6 +668,33 @@ def _check_latent(cfg: TransformerConfig) -> None:
         raise NotImplementedError("shared experts are gated (swiglu) MLPs")
 
 
+def _check_ssm(cfg: TransformerConfig) -> None:
+    """What a block with a state-space mixer beside its attention is built
+    from, and what it leaves out."""
+    if not (cfg.ssm_head_dim and cfg.ssm_state and cfg.ssm_conv > 1
+            and cfg.ssm_heads % cfg.ssm_groups == 0
+            and len(cfg.ssm_multipliers) == 5
+            and len(cfg.mlp_multipliers) == 2):
+        raise ValueError(
+            "state-space layers (ssm_heads) take ssm_head_dim, ssm_state, "
+            "ssm_conv > 1, heads in whole groups, five ssm_multipliers and "
+            "two mlp_multipliers")
+    if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
+        raise NotImplementedError(
+            "state-space layers (ssm_heads) take RMSNorm and a gated MLP")
+    for on, what in ((cfg.parallel_residual, "parallel_residual"),
+                     (cfg.post_layernorm, "post_layernorm"),
+                     (cfg.num_experts != 1, "expert layers"),
+                     (is_latent(cfg), "latent attention"),
+                     (is_grouped(cfg), "layer_pattern / dense_layers"),
+                     (cfg.attention_layers is not None, "attention_layers"),
+                     (cfg.pipeline_stages > 1, "pipeline_stages"),
+                     (cfg.random_ltd, "random_ltd")):
+        if on:
+            raise NotImplementedError(
+                f"state-space layers (ssm_heads) do not take {what}")
+
+
 def _check_qk_norm(cfg: TransformerConfig) -> None:
     if cfg.norm != "rmsnorm":
         raise NotImplementedError(
@@ -649,6 +754,28 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # tokens at these weights (std^2 * d), so that it takes a real
         # share of a row's probability: a checkpoint learns it
         layers["attn_sink"] = dense(keys[16], (L, nh), std * std * d)
+    if is_ssm(cfg):
+        _check_ssm(cfg)
+        # the mixer beside attention.  What a normal draw would make
+        # meaningless gets Mamba-2's own initial ranges: A = -U(1, 16) as
+        # its log, dt's bias the inverse softplus of a log-uniform step in
+        # [1e-3, 1e-1], D = 1, the taps U(+-1/2) (1 / sqrt(taps) at 4)
+        H, K = cfg.ssm_heads, cfg.ssm_conv
+        d_ssm, conv, _ = ssm_widths(cfg)
+        sk = jax.random.split(jax.random.fold_in(rng, 19), 6)
+        step = jnp.exp(jax.random.uniform(
+            sk[3], (L, H), minval=math.log(1e-3), maxval=math.log(1e-1)))
+        layers.update(
+            ssm_in=dense(sk[0], (L, d, ssm_in_width(cfg))),
+            ssm_conv_w=jax.random.uniform(sk[1], (L, K, conv), minval=-0.5,
+                                          maxval=0.5),
+            ssm_conv_b=dense(sk[2], (L, conv)),
+            ssm_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+            ssm_A_log=jnp.log(jax.random.uniform(sk[4], (L, H), minval=1.0,
+                                                 maxval=16.0)),
+            ssm_D=jnp.ones((L, H)),
+            ssm_norm_scale=jnp.ones((L, d_ssm)),
+            ssm_out=dense(sk[5], (L, d_ssm, d), std / math.sqrt(2 * L)))
     if not cfg.shared_layernorm:   # GPT-J shares the attention LN
         layers["mlp_norm_scale"] = jnp.ones((L, d))
     if cfg.norm == "layernorm":
@@ -868,6 +995,13 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
                       k_norm_scale=P(None, "model"))
     if cfg.window_attn_sink:
         layers["attn_sink"] = P(None, "model")
+    if is_ssm(cfg):
+        # the mixer whole on every chip: its heads share B and C by group
+        # and a slot's state is one tensor (sharding them is ROADMAP R5's)
+        layers.update(ssm_in=P(None, None, None), ssm_out=P(None, None, None),
+                      ssm_conv_w=P(None, None, None), ssm_conv_b=rep,
+                      ssm_dt_bias=rep, ssm_A_log=rep, ssm_D=rep,
+                      ssm_norm_scale=rep)
     if not cfg.shared_layernorm:
         layers["mlp_norm_scale"] = rep
     if cfg.norm == "layernorm":
@@ -1011,6 +1145,15 @@ def _norm(cfg, x, scale, bias=None):
         return out.astype(x.dtype)
 
 
+def _scaled(x, m: float):
+    """``x * m`` for one of the family's fixed multipliers, the product
+    rounded once (the constant itself is not rounded to ``x``'s dtype
+    first); ``x`` as it is where ``m`` is 1."""
+    if m == 1.0:
+        return x
+    return (x.astype(jnp.float32) * m).astype(x.dtype)
+
+
 def _embed(cfg, params, tokens, positions, token_type_ids=None):
     """tokens ``[B,S]`` -> hidden states: token embedding, learned positions
     (``positions`` index the table as they are; a caller whose positions can
@@ -1018,6 +1161,7 @@ def _embed(cfg, params, tokens, positions, token_type_ids=None):
     LayerNorm of Bloom / BERT."""
     with jax.named_scope("embed"):
         x = params["embed"].astype(cfg.dtype)[tokens]
+        x = _scaled(x, cfg.embed_multiplier)
         if cfg.position == "learned":
             x = x + params["pos_embed"].astype(cfg.dtype)[positions]
         if "type_embed" in params:
@@ -1038,7 +1182,7 @@ def _lm_head(cfg, params, x):
         logits = x @ params["lm_head"].astype(cfg.dtype)
         if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
             logits = logits + params["lm_head_bias"].astype(cfg.dtype)
-        return logits
+        return _scaled(logits, cfg.lm_head_multiplier)
 
 
 def _head(cfg, params, x):
@@ -1292,13 +1436,14 @@ def _dense_mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, prefix=""):
     """Plain MLP body; ``prefix="res_"`` selects the PR-MoE residual branch's
     weights (biases only exist on the unprefixed dense path)."""
     bias = cfg.mlp_bias and not prefix
+    gate_mult, out_mult = cfg.mlp_multipliers
     if cfg.activation == "swiglu":
         g = checkpoint_name(h @ lp[prefix + "w_gate"], "mlp_gate")
         u = checkpoint_name(h @ lp[prefix + "w_up"], "mlp_up")
         if bias:
             g, u = g + lp["b_gate"], u + lp["b_up"]
-        m = jax.nn.silu(g) * u
-        m = m @ lp[prefix + "w_down"]
+        m = jax.nn.silu(_scaled(g, gate_mult)) * u
+        m = _scaled(m @ lp[prefix + "w_down"], out_mult)
     else:
         m = checkpoint_name(h @ lp[prefix + "w_in"], "mlp_up")
         if bias:
@@ -1415,6 +1560,7 @@ def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
         v = v.reshape(B, S, nkv, cfg.v_dims_per_head)
         if cfg.attn_value_scale != 1.0:
             v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
+        k = _scaled(k, cfg.key_multiplier)
         if cfg.position == "rope":
             q, k = _rope(q, k, positions, cfg.rope_theta, hd,
                          rotary_dim=cfg.rotary_dim,
@@ -1482,6 +1628,170 @@ def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None):
     return out
 
 
+def _ssm_project(cfg: TransformerConfig, lp: Dict[str, Any], h):
+    """Post-norm activations ``h [B,S,d]`` through the mixer's
+    in-projection, each of its five segments ``[z | x | B | C | dt]`` by its
+    own multiplier: ``(z [B,S,d_ssm], xBC [B,S,channels], dt [B,S,H])``,
+    ``dt`` in float32 before its bias and softplus."""
+    d_ssm, conv, gn = ssm_widths(cfg)
+    mup = np.repeat(np.asarray(cfg.ssm_multipliers, np.float32),
+                    (d_ssm, d_ssm, gn, gn, cfg.ssm_heads))
+    with jax.named_scope("ssm_in"):
+        p = _scaled(h, cfg.ssm_in_multiplier) @ lp["ssm_in"]
+        p = p.astype(jnp.float32) * mup
+    return (p[..., :d_ssm].astype(h.dtype),
+            p[..., d_ssm:d_ssm + conv].astype(h.dtype), p[..., d_ssm + conv:])
+
+
+def _ssm_conv(cfg: TransformerConfig, lp: Dict[str, Any], xbc, tail, n_real):
+    """The depthwise causal convolution over x, B and C, then SiLU:
+    ``xbc [B,S,C]`` behind the slot's ``tail [B,K-1,C]`` (the K - 1 inputs
+    before the block) -> ``(out [B,S,C], new tail)``.  The new tail is
+    gathered from the last K - 1 REAL positions (``n_real [B]``, real tokens
+    lead the block): a padded prompt leaves what the unpadded one does, and
+    a row with no real token the tail it had."""
+    K, S = cfg.ssm_conv, xbc.shape[1]
+    with jax.named_scope("ssm_conv"):
+        ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+        w = lp["ssm_conv_w"].astype(jnp.float32)
+        y = sum(ext[:, k:k + S].astype(jnp.float32) * w[k] for k in range(K))
+        y = y + lp["ssm_conv_b"].astype(jnp.float32)
+        tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+            e, n, K - 1, axis=0))(ext, n_real.astype(jnp.int32))
+    return jax.nn.silu(y).astype(xbc.dtype), tail
+
+
+def ssm_scan_chunks(cfg: TransformerConfig, block: int,
+                    tokens: Optional[int] = None) -> int:
+    """Chunks of ``ssm_chunk`` positions the scan of a block of ``block``
+    tokens runs, or those of them that hold one of its ``tokens`` real
+    ones (the ``scan_chunks`` span attrs of a prompt)."""
+    return -(-(block if tokens is None else min(tokens, block))
+             // cfg.ssm_chunk)
+
+
+def _ssm_scan(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
+    """The selective state update over a block, in chunks (the SSD form of
+    Mamba-2): ``x [B,S,H,P]``, ``Bm``/``Cm [B,S,G,N]``, ``dt [B,S,H]``
+    float32 and 0 at a masked position, ``A [H]`` float32 (< 0), ``state
+    [B,H,P,N]`` float32 -> ``(y [B,S,H,P] float32, the state after the
+    block)`` with
+
+        S_t = exp(dt_t A) S_t-1 + dt_t x_t (x) B_t        y_t = S_t C_t
+
+    Inside a chunk of Q positions the masked product ``(C B^T . decay) (dt
+    x)``; between chunks the carried state, decayed over each chunk and read
+    by C at every position.  ``dt = 0`` leaves the state as it was and adds
+    nothing, so padding behind the real tokens (the bucket's, or up to a
+    whole chunk) changes no number.  Decays, cumulative sums and the carried
+    state are float32; the four products take the compute dtype's operands
+    and accumulate in float32."""
+    B, S, H, P = x.shape
+    G, N = Bm.shape[2:]
+    Hg, Q = H // G, cfg.ssm_chunk
+    pad = -S % Q
+    if pad:
+        x, Bm, Cm, dt = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                                 (a.ndim - 2)) for a in (x, Bm, Cm, dt))
+    nc, f32, cd = (S + pad) // Q, jnp.float32, x.dtype
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
+    with jax.named_scope("ssm_scan"):
+        a = (dt * A).reshape(B, nc, Q, G, Hg)
+        cum = jnp.cumsum(a, axis=2)                     # inclusive, <= 0
+        xd = (x.astype(f32) * dt[..., None]).reshape(B, nc, Q, G, Hg, P)
+        Bc, Cc = Bm.reshape(B, nc, Q, G, N), Cm.reshape(B, nc, Q, G, N)
+        # within a chunk: position i reads j <= i, decayed from j to i
+        seg = cum[:, :, :, None] - cum[:, :, None, :]   # [B,nc,i,j,G,Hg]
+        tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None, None]
+        m = (jnp.exp(jnp.where(tri, seg, -jnp.inf))
+             * jnp.moveaxis(mm("bcign,bcjgn->bcgij", Cc, Bc), 2, 4)[..., None])
+        y = mm("bcijgk,bcjgkp->bcigkp", m.astype(cd), xd.astype(cd))
+        # what each chunk adds to the state by its end, and the state each
+        # chunk starts from
+        to_end = jnp.exp(cum[:, :, -1:] - cum)
+        s_c = mm("bcjgkp,bcjgn->bcgkpn", (xd * to_end[..., None]).astype(cd),
+                 Bc)
+        over = jnp.exp(cum[:, :, -1])                   # [B,nc,G,Hg]
+
+        def chunk(s, sc_over):
+            sc, t = sc_over
+            return s * t[..., None, None] + sc, s
+
+        state, s_in = jax.lax.scan(
+            chunk, state.reshape(B, G, Hg, P, N),
+            (jnp.moveaxis(s_c, 1, 0), jnp.moveaxis(over, 1, 0)))
+        y = y + (mm("bcign,bcgkpn->bcigkp", Cc,
+                    jnp.moveaxis(s_in, 0, 1).astype(cd))
+                 * jnp.exp(cum)[..., None])
+    return (y.reshape(B, S + pad, H, P)[:, :S], state.reshape(B, H, P, N))
+
+
+def _ssm_step(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
+    """:func:`_ssm_scan` for one token a row: the recurrence itself, every
+    number float32.  The sum over the state's columns is written out (a
+    product, then a reduction) so that no matrix unit rounds the state to
+    read it.  A masked row (``dt = 0``) keeps its state."""
+    B, _, H, P = x.shape
+    G, N = Bm.shape[2:]
+    Hg, f32 = H // G, jnp.float32
+    with jax.named_scope("ssm_step"):
+        dt1 = dt[:, 0].reshape(B, G, Hg)
+        xd = x[:, 0].astype(f32).reshape(B, G, Hg, P) * dt1[..., None]
+        s = (state.reshape(B, G, Hg, P, N)
+             * jnp.exp(dt1 * A.reshape(G, Hg))[..., None, None]
+             + xd[..., None] * Bm[:, 0].astype(f32)[:, :, None, None, :])
+        y = (s * Cm[:, 0].astype(f32)[:, :, None, None, :]).sum(-1)
+    return y.reshape(B, 1, H, P), s.reshape(B, H, P, N)
+
+
+def _ssm_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], y, z):
+    """The mixer's output gated by ``silu(z)`` and THEN RMS-normed within
+    each of the ``ssm_groups`` groups of channels (``mamba_rms_norm``,
+    ``mamba_norm_before_gate`` false), in float32."""
+    B, S, d_ssm = y.shape
+    with jax.named_scope("ssm_gate_norm"):
+        g = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+             ).reshape(B, S, cfg.ssm_groups, -1)
+        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
+                              + cfg.norm_eps)
+        return (g.reshape(B, S, d_ssm)
+                * lp["ssm_norm_scale"].astype(jnp.float32)).astype(cfg.dtype)
+
+
+def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h, seq_mask=None,
+               kept=None):
+    """The Mamba-2 mixer of a block on its post-norm input ``h [B,S,d]``:
+    in-projection, convolution, selective state update (one token a row:
+    :func:`_ssm_step`, a longer block: :func:`_ssm_scan`), the skip ``D x``,
+    gated norm, out-projection.  ``kept = (state [B,H,P,N] float32, tail
+    [B,K-1,C])`` is what the rows' sequences hold so far (``None``: they
+    start here); ``seq_mask [B,S]`` its real tokens, which lead the block.
+    Returns ``(out [B,S,d], (state, tail) after the block's real tokens)``."""
+    B, S, _ = h.shape
+    H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                  cfg.ssm_groups)
+    d_ssm, conv, gn = ssm_widths(cfg)
+    if seq_mask is None:
+        seq_mask = jnp.ones((B, S), bool)
+    state, tail = kept if kept is not None else (
+        jnp.zeros((B, H, P, N), jnp.float32),
+        jnp.zeros((B, cfg.ssm_conv - 1, conv), h.dtype))
+    z, xbc, dt = _ssm_project(cfg, lp, h)
+    xbc, tail = _ssm_conv(cfg, lp, xbc, tail, seq_mask.sum(1))
+    x = xbc[..., :d_ssm].reshape(B, S, H, P)
+    Bm = xbc[..., d_ssm:d_ssm + gn].reshape(B, S, G, N)
+    Cm = xbc[..., d_ssm + gn:].reshape(B, S, G, N)
+    dt = jnp.where(seq_mask[..., None], jax.nn.softplus(
+        dt + lp["ssm_dt_bias"].astype(jnp.float32)), 0.0)
+    A = -jnp.exp(lp["ssm_A_log"].astype(jnp.float32))
+    y, state = (_ssm_step if S == 1 else _ssm_scan)(cfg, x, Bm, Cm, dt, A,
+                                                    state)
+    y = y + lp["ssm_D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    with jax.named_scope("ssm_out"):
+        out = _ssm_gate_norm(cfg, lp, y.reshape(B, S, d_ssm), z) @ lp["ssm_out"]
+    return out, (state, tail)
+
+
 def _dropout(cfg: TransformerConfig, y, rng, deterministic: bool):
     """``(y, rng)``: residual dropout on a sublayer's output, and the key
     chain moved on by the split it took."""
@@ -1494,13 +1804,15 @@ def _dropout(cfg: TransformerConfig, y, rng, deterministic: bool):
 
 def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
            attend, deterministic: bool = True, proj=None, token_mask=None,
-           expert_offset=None):
+           expert_offset=None, ssm=None):
     """One transformer layer, every residual wiring the family has:
 
       pre-LN (GPT-2, OPT, Llama)   x += attn(LN(x));  x += mlp(LN'(x))
       parallel (NeoX; GPT-J)       x += attn(LN(x)) + mlp(LN'(x))  (GPT-J:
                                    one LN, the MLP reads attention's input)
       post-LN (BERT)               x = LN(x + attn(x));  x = LN'(x + mlp(x))
+      two mixers (Falcon-H1)       n = LN(x);  x += a attn(n) + b ssm(n);
+                                   x += mlp(LN'(x))
 
     What attention reads, and where K/V go, is the caller's:
     ``attend(q, k, v) -> (out [B,S,Hq,hd], state)`` is handed the layer's
@@ -1508,13 +1820,21 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     rows and its up-projection) and returns whatever it keeps (:func:`_attend_full`: nothing;
     :func:`_attend_cached`: the layer's cache buffers; :func:`_attend_paged`:
     the page pool).  ``proj``, ``token_mask`` and ``expert_offset`` are the
-    serving path's (:func:`_qkv`, :func:`_attn_out`, :func:`_mlp`).
+    serving path's (:func:`_qkv`, :func:`_attn_out`, :func:`_mlp`).  A model
+    with state-space layers hands in ``ssm(lp, h) -> (out [B,S,d], kept)``,
+    its mixer over the same normed input with whatever state it continues
+    and keeps (:func:`_ssm_mixer`); ``state`` is then ``(attend's, the
+    mixer's)``.
 
     Returns ``(x, moe_aux_loss, expert_counts, state)``."""
     post = cfg.post_layernorm
     h = x if post else _norm(cfg, x, lp["attn_norm_scale"],
                              lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, h)
+    if ssm is not None:
+        # both mixers read ONE normed input, each through its own multiplier
+        side, ssm_kept = ssm(lp, h)
+        h = _scaled(h, cfg.attn_in_multiplier)
     if is_latent(cfg):
         # latent attention: ``attend`` is handed the token's cache row in
         # place of k, and the layer's up-projection in place of v
@@ -1533,6 +1853,10 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     attn, state = attend(q, k, v)
     attn = _attn_out(cfg, lp, attn, proj)
     attn, rng = _dropout(cfg, attn, rng, deterministic)
+    if ssm is not None:
+        attn = (_scaled(attn, cfg.attn_out_multiplier)
+                + _scaled(side, cfg.ssm_out_multiplier))
+        state = (state, ssm_kept)
     res = x + attn
     if post:
         res = _norm(cfg, res, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
@@ -1598,10 +1922,12 @@ def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
     """One layer's apply fn ``block(lp, x, rng, positions)`` with the remat
     policy and random-LTD wrapping applied — shared by forward() and the
     1F1B pipeline executor."""
+    # a state-space mixer beside attention starts its sequence here
+    ssm = functools.partial(_ssm_mixer, cfg) if is_ssm(cfg) else None
     block = lambda lp, x, sub, pos, window=None: _block(  # noqa: E731
         cfg, lp, x, pos, sub,
         _attend_full(cfg, pos, attn_impl, custom_positions, window),
-        deterministic)[:2]
+        deterministic, ssm=ssm)[:2]
     if cfg.remat:
         name = ("nothing_saveable" if cfg.remat_policy == REMAT_AUTO
                 else cfg.remat_policy)
@@ -1686,6 +2012,9 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
         return (logits, {"moe_aux_loss": jnp.float32(0.0)}) if return_aux \
             else logits
 
+    if is_ssm(cfg) and (not deterministic or pld_theta is not None):
+        _hybrid_refuse("training (dropout, layer drop, a backward pass "
+                       "through the chunked scan)", cfg)
     block = _build_block(cfg, attn_impl, deterministic, custom_positions)
     step = functools.partial(_layer_step, block)
 
@@ -1954,7 +2283,7 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
     exactly twice.
     """
     _check_decodable(cfg, params, "cached decode")
-    if is_grouped(cfg) or is_latent(cfg):
+    if is_grouped(cfg) or is_latent(cfg) or is_ssm(cfg):
         _hybrid_refuse("the contiguous cache (forward_cached, generate())",
                        cfg)
     B, S = tokens.shape
@@ -2017,8 +2346,11 @@ KV_QUANT_DTYPES = ("int8",)
 # window layers' rings, each leaf with its kind's KV heads and its own width.
 # A latent-attention model (``kv_lora_rank``) keeps ONE leaf, ``latent``:
 # a token's normed latent and its shared rotated key row, no head axis.
+# A model with state-space layers (``ssm_heads``) keeps, beside ``k``/``v``,
+# two leaves with NO page axis, a row a slot: ``ssm_state`` and ``ssm_conv``.
+SSM_POOL_KEYS = ("ssm_state", "ssm_conv")
 PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window",
-                   "latent")
+                   "latent") + SSM_POOL_KEYS
 
 
 def paged_pool_tuple(cache: Dict[str, Any]) -> tuple:
@@ -2045,12 +2377,27 @@ def _normalize_kv_dtype(kv_dtype):
     return name
 
 
+def kv_leaf_head_major(cfg: TransformerConfig, width: int) -> bool:
+    """Whether the ``k`` or ``v`` leaf of a one-pool model is kept ``[L, P,
+    Hkv, page, width]`` (:func:`pool_leaf_head_major`'s reason).  Only a
+    model with state-space layers: every mover that addresses a pool by page
+    row (COW, tiering, the int8 scales, heads sharded over chips) refuses
+    such a model, so none of them has to know the second order."""
+    return is_ssm(cfg) and pool_leaf_head_major(cfg.kv_heads, width)
+
+
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      page_size: int = PAGE_SIZE, dtype=None,
-                     kv_dtype=None, window_pages: Optional[int] = None
-                     ) -> Dict[str, Any]:
+                     kv_dtype=None, window_pages: Optional[int] = None,
+                     slots: int = 1) -> Dict[str, Any]:
     """Allocate the physical page pool: ``k``/``v`` are
     ``[L, num_pages, page_size, Hkv, hd]``.
+
+    A model with state-space layers adds ``ssm_state [L, slots, heads,
+    head_dim, state]`` (float32) and ``ssm_conv [L, slots, taps - 1,
+    channels]``: one row a slot, ``slots`` of them, and no pages at all (a
+    state is not written position by position: it is reset when a block
+    starts at position 0 and advanced whole by every token after).
 
     Physical page 0 is RESERVED as the trash page: a page write none of
     whose rows is real (pad tokens, an inactive slot) is redirected there
@@ -2114,6 +2461,22 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
         return {"latent": jnp.zeros(
             (cfg.num_layers, num_pages, page_size,
              cfg.kv_lora_rank + cfg.rotary_dim), dtype)}
+    if is_ssm(cfg):
+        if _normalize_kv_dtype(kv_dtype) is not None:
+            _hybrid_refuse("the int8 pool", cfg)
+        conv = ssm_widths(cfg)[1]
+
+        def leaf(w):
+            rows = ((cfg.kv_heads, page_size) if kv_leaf_head_major(cfg, w)
+                    else (page_size, cfg.kv_heads))
+            return jnp.zeros((cfg.num_layers, num_pages) + rows + (w,), dtype)
+
+        return {"k": leaf(cfg.dims_per_head), "v": leaf(cfg.v_dims_per_head),
+                "ssm_state": jnp.zeros(
+                    (cfg.num_layers, slots, cfg.ssm_heads, cfg.ssm_head_dim,
+                     cfg.ssm_state), jnp.float32),
+                "ssm_conv": jnp.zeros(
+                    (cfg.num_layers, slots, cfg.ssm_conv - 1, conv), dtype)}
     lead = (cfg.num_layers, num_pages, page_size, cfg.kv_heads)
     kv = lead + (cfg.dims_per_head,)
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -2135,6 +2498,8 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
         return {"latent": P(None, None, None, None)}
     if is_hybrid(cfg):
         return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
+    if is_ssm(cfg):     # whole on one chip: sharded serving refuses it
+        return {"k": P(), "v": P(), "ssm_state": P(), "ssm_conv": P()}
     if _normalize_kv_dtype(kv_dtype) is None:
         return {"k": kv, "v": kv}
     sc = P(None, None, None)
@@ -2933,14 +3298,26 @@ def _hybrid_refuse(what: str, cfg: Optional[TransformerConfig] = None):
     model = ("window and full attention layers (layer_pattern)"
              if cfg is None or is_hybrid(cfg)
              else "latent attention (kv_lora_rank)" if is_latent(cfg)
+             else "state-space layers (a state a slot)" if is_ssm(cfg)
              else "leading dense layers (dense_layers)")
     raise NotImplementedError(
         f"{what} does not support a model with {model}: it runs through "
         "forward() and the paged serving path (forward_paged)")
 
 
+def _head_at(cfg, params, x, logits_at):
+    """:func:`_head` over every position of ``x [B,S,d]``, or over the one
+    position a row ``logits_at [B]`` names (``[B,1,V]``): a prompt's prefill
+    reads one row of logits, and the head over a whole bucket is a quarter
+    of its operations where the vocabulary is large."""
+    if logits_at is not None:
+        x = jnp.take_along_axis(x, logits_at[:, None, None], axis=1)
+    return _head(cfg, params, x)
+
+
 def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
-                          seq_mask, expert_counts, pool_order):
+                          seq_mask, expert_counts, pool_order,
+                          logits_at=None):
     """:func:`forward_paged` for a model with layers of two kinds: a pool
     and a plan per kind, the layers in their published order (a Python
     loop: the kinds' stacks differ in shape, so there is nothing to scan).
@@ -3033,7 +3410,7 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
         x = constrain_spec(x, P(BATCH_AXES, None, None))
         if c is not None:
             counts.append(c)
-    logits = _head(cfg, params, x)
+    logits = _head_at(cfg, params, x, logits_at)
     out = {n + suffix[kind]: (jnp.transpose(a, (0, 2, 1, 3))
                               if head_major[kind][n] else a
                               ).reshape(cache[n + suffix[kind]].shape)
@@ -3048,7 +3425,9 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                   page_table: jax.Array, start: jax.Array,
                   seq_mask: jax.Array, adapters=None,
                   expert_counts: bool = False,
-                  pool_order: Optional[Tuple[int, ...]] = None):
+                  pool_order: Optional[Tuple[int, ...]] = None,
+                  state_slot: Optional[jax.Array] = None,
+                  logits_at: Optional[jax.Array] = None):
     """Run ``tokens [B,S]`` against the paged pool, writing each real token's
     K/V at its slot position and attending each query to its own slot only.
 
@@ -3096,6 +3475,20 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     are dropless (masked tokens are in no group and in no count); ``None``
     for every other model.
 
+    ``logits_at [B]`` (optional): the one position of each row whose logits
+    are wanted, ``[B,1,V]`` in place of ``[B,S,V]`` (a prompt's prefill reads
+    its last real position's).
+
+    A model with state-space layers (``ssm_heads``) reads and writes, beside
+    the pages, one row a sequence of the cache's ``ssm_state`` / ``ssm_conv``
+    leaves: row ``b`` of the batch is state row ``b`` unless ``state_slot
+    [B]`` names the rows (the serving engine's one-row prefill passes its
+    slot).  A row whose ``start`` is 0 begins from the zero state and a zero
+    tail, any other continues what its state row holds; a masked token
+    leaves both as they are, so a padded prompt leaves what the unpadded one
+    does, and a row with no real token is not touched.  Real tokens lead
+    their block.
+
     ``adapters`` (optional) is the per-slot LoRA operand pytree of
     multi-tenant adapter serving (docs/SERVING.md): ``{"scale": [B] f32,
     "factors": {target: {"A": [L,B,d_in,R], "B": [L,B,R,d_out]}}}``.  The
@@ -3114,11 +3507,16 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                            "factors)")
         return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
                                      start, seq_mask, expert_counts,
-                                     pool_order)
-    if is_grouped(cfg) and adapters is not None:
+                                     pool_order, logits_at)
+    if (is_grouped(cfg) or is_ssm(cfg)) and adapters is not None:
         _hybrid_refuse("multi-tenant adapter serving (per-slot LoRA "
                        "factors)", cfg)
-    num_pages, ps = cache["latent" if is_latent(cfg) else "k"].shape[1:3]
+    # a K/V leaf kept head-major (kv_leaf_head_major) is seen through the
+    # transpose that moves nothing, as a two-kind model's (stacked below)
+    head_major = {n: kv_leaf_head_major(cfg, w) for n, w in (
+        ("k", cfg.dims_per_head), ("v", cfg.v_dims_per_head))}
+    lead = cache["latent" if is_latent(cfg) else "k"]
+    num_pages, ps = lead.shape[1], lead.shape[3 if head_major["k"] else 2]
     positions = (start[:, None]
                  + jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :])
     write = _paged_write_plan(page_table, start, seq_mask, ps)
@@ -3143,6 +3541,17 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     # whole around the loop, more than half of a decode tick (PERF.md, PR 25)
     pools = {k: cache[k].reshape(-1, *cache[k].shape[2:])
              for k in PAGED_POOL_KEYS if k in cache}
+    for n in ("k", "v"):
+        if head_major[n]:
+            pools[n] = jnp.transpose(pools[n], (0, 2, 1, 3))
+    if any(head_major.values()):
+        pool_order = {n: (0, 1, 3, 2, 4) if head_major[n] else
+                      pool_order.get(n) if isinstance(pool_order, dict)
+                      else pool_order for n in ("k", "v")}
+    # a state-space model's slot rows: ``slots`` a layer, the batch's at
+    # ``state_slot`` (None: rows 0 .. B - 1)
+    ssm = ((cache["ssm_state"].shape[1], state_slot, start)
+           if is_ssm(cfg) else None)
     # one scan a group of equal layers, in the published order: the whole
     # model, or a model's leading dense layers and then its expert layers
     groups = ({name: (g, n, params["layers"][name])
@@ -3153,21 +3562,63 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     for g, n, layers in groups.values():
         x, pools, c = _paged_layers(
             g, layers, x, pools, first, n, num_pages, positions, seq_mask,
-            write, read, within, pool_order, adapters)
+            write, read, within, pool_order, adapters, ssm)
         first, counts = first + n, (counts if c is None else c)
-    logits = _head(cfg, params, x)
-    cache = {k: a.reshape(cache[k].shape) for k, a in pools.items()}
+    logits = _head_at(cfg, params, x, logits_at)
+    cache = {k: (jnp.transpose(a, (0, 2, 1, 3)) if head_major.get(k) else a
+                 ).reshape(cache[k].shape) for k, a in pools.items()}
     return (logits, cache, counts) if expert_counts else (logits, cache)
+
+
+def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
+    """:func:`_block`'s ``ssm`` against the cache's two slot-indexed leaves,
+    stacked ``[L * slots, ...]`` with this layer's rows from ``row0`` on:
+    the batch's rows are taken (``state_slot`` None: the block ``row0 ..
+    row0 + B - 1`` where it lies; else the rows it names), a row that
+    starts its sequence begins from zeros, :func:`_ssm_mixer` advances
+    them, and they are written back where they were.  What is kept is the
+    two leaves."""
+    B = seq_mask.shape[0]
+    fresh = (start == 0) & seq_mask.any(axis=1)
+    if state_slot is None:
+        def take(a):
+            return jax.lax.dynamic_slice_in_dim(a, row0, B, axis=0)
+
+        def put(a, new):
+            return jax.lax.dynamic_update_slice_in_dim(
+                a, new.astype(a.dtype), row0, axis=0)
+    else:
+        rows = row0 + state_slot
+
+        def take(a):
+            return a[rows]
+
+        def put(a, new):
+            return a.at[rows].set(new.astype(a.dtype))
+
+    def ssm(lp, h):
+        # the recurrence is float32 whatever the leaf is kept in
+        state = take(pools["ssm_state"]).astype(jnp.float32)
+        tail = take(pools["ssm_conv"])
+        kept = (jnp.where(fresh[:, None, None, None], 0.0, state),
+                jnp.where(fresh[:, None, None], 0, tail))
+        out, (state, tail) = _ssm_mixer(cfg, lp, h, seq_mask, kept)
+        return out, {"ssm_state": put(pools["ssm_state"], state),
+                     "ssm_conv": put(pools["ssm_conv"], tail)}
+    return ssm
 
 
 def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
                   positions, seq_mask, write, read, within, pool_order,
-                  adapters):
+                  adapters, ssm=None):
     """``n`` equal layers of :func:`forward_paged` as one scan, the model's
     layers ``first .. first + n - 1``: ``(x, pools, counts)`` with the pool
     as carry, layer ``l``'s pages at ``l * num_pages`` of the stacked
     leaves.  ``cfg`` is the layers' uniform config and ``layers`` their
-    stack (the whole model's, or one group's of :func:`layer_groups`)."""
+    stack (the whole model's, or one group's of :func:`layer_groups`).
+    ``ssm = (slots, state_slot, start)``: the model has state-space layers,
+    whose two slot-indexed leaves ride the carry with the pages
+    (:func:`_ssm_paged`)."""
     rng = jax.random.PRNGKey(0)
     ad_scale = (adapters["scale"].astype(jnp.float32)
                 if adapters is not None else None)
@@ -3189,16 +3640,23 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
         wplan = (src, keep, write_pages + first_page)
         rplan = (None if read is None else
                  (read[0], read[1], read[2] + first_page, read[3]))
-        attend = (_attend_latent_paged(cfg, pools, wplan, rplan, within)
+        kv = {k: v for k, v in pools.items() if k not in SSM_POOL_KEYS}
+        attend = (_attend_latent_paged(cfg, kv, wplan, rplan, within)
                   if is_latent(cfg) else
-                  _attend_paged(cfg, pools, wplan, rplan, pool_order))
+                  _attend_paged(cfg, kv, wplan, rplan, pool_order))
         layer = first_page // num_pages
-        x, _, counts, pools = _block(
+        mixer = None
+        if ssm is not None:
+            slots, state_slot, start = ssm
+            mixer = _ssm_paged(cfg, pools, layer * slots, state_slot, start,
+                               seq_mask)
+        x, _, counts, kept = _block(
             cfg, {**lp, **experts}, x, positions, rng, attend,
             proj=_adapter_proj(factors, ad_scale), token_mask=seq_mask,
-            expert_offset=(layer - first) * held if experts else None)
+            expert_offset=(layer - first) * held if experts else None,
+            ssm=mixer)
         x = constrain_spec(x, P(BATCH_AXES, None, None))
-        return (x, pools), counts
+        return (x, kept if ssm is None else {**kept[0], **kept[1]}), counts
 
     # the per-slot factor stacks scan beside the layers where the call has
     # them: each step's slice is THAT layer's

@@ -1,0 +1,412 @@
+"""A model with state-space layers beside attention (Falcon-H1,
+``falcon_h1``: a Mamba-2 mixer and grouped-query attention on one normed
+input in every block, fixed multipliers) through the model and the serving
+engine: the mixer's two forms against each other and against the plain
+reference, the cache's two leaves with no page axis, admission's reset, the
+decode lookahead that never un-launches a tick for a slot that goes on, the
+span attrs, and the mechanisms that refuse such a model by name."""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from benchmark.lib import reference_falcon_h1 as R
+from deepspeed_tpu.inference.execution import MeshExecutor
+from deepspeed_tpu.inference.serving import Request
+from deepspeed_tpu.models import CausalLM, get_config, init_params
+from deepspeed_tpu.models import transformer as T
+
+SERVE_KW = dict(b_slots=3, page_size=8, max_model_len=96)
+
+
+def tiny(**over):
+    """Tiny widths, every multiplier away from 1, 2 groups, chunk 8."""
+    kw = dict(num_layers=2, hidden_size=64, intermediate_size=96,
+              num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=256,
+              ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2,
+              ssm_chunk=8, max_seq_len=512, embed_multiplier=3.0,
+              lm_head_multiplier=0.5, attn_in_multiplier=1.5,
+              attn_out_multiplier=0.7, key_multiplier=0.3,
+              ssm_in_multiplier=0.8, ssm_out_multiplier=1.3,
+              ssm_multipliers=(0.9, 1.2, 0.8, 1.1, 0.7),
+              mlp_multipliers=(1.4, 0.6), dtype=jnp.float32)
+    kw.update(over)
+    return get_config("falcon-h1-34b", **kw)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(tiny(), jax.random.PRNGKey(1))
+
+
+@pytest.fixture(scope="module")
+def engine(params):
+    from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh
+
+    return deepspeed_tpu.init_inference(
+        model=CausalLM(tiny()), params=params, dtype="fp32",
+        mesh=initialize_mesh(MeshLayout(), devices=jax.devices()[:1]))
+
+
+def _tokens(n, seed=0):
+    return jnp.asarray(np.random.default_rng(seed).integers(0, 256, (1, n)),
+                       jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _forward(cfg, params, toks):
+    return T.forward(cfg, params, toks)
+
+
+def _is_greedy(cfg, params, prompt, out) -> bool:
+    """Every token of ``out`` is the largest logit of ``forward`` over what
+    came before it (one teacher-forced pass)."""
+    seq = jnp.asarray(list(prompt) + list(out), jnp.int32)[None]
+    logits = _forward(cfg, params, seq)[0, len(prompt) - 1:-1]
+    return list(np.asarray(jnp.argmax(logits, -1))) == list(out)
+
+
+def test_the_named_base_is_the_published_model_and_counts_its_parameters():
+    cfg = get_config("falcon-h1-34b")
+    assert (cfg.hidden_size, cfg.num_layers, cfg.vocab_size, cfg.num_heads,
+            cfg.kv_heads, cfg.dims_per_head, cfg.intermediate_size) == (
+        5120, 72, 261120, 20, 4, 128, 21504)
+    assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups,
+            cfg.ssm_conv, cfg.ssm_chunk) == (32, 128, 256, 2, 4, 128)
+    assert T.ssm_widths(cfg) == (4096, 5120, 512)
+    assert T.ssm_in_width(cfg) == 9248
+    one = (get_config(cfg, num_layers=1).param_count
+           - get_config(cfg, num_layers=0).param_count)
+    assert one == 430_120_032
+    t = tiny()
+    leaves = jax.eval_shape(lambda: init_params(t, jax.random.PRNGKey(0)))
+    assert t.param_count == sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(leaves))
+    assert leaves["layers"]["ssm_in"].shape == (2, 64, 2 * 32 + 2 * 32 + 4)
+    assert leaves["layers"]["ssm_conv_w"].shape == (2, 4, 32 + 2 * 32)
+
+
+def test_forward_is_the_reference(params):
+    cfg, toks = tiny(), _tokens(29)
+    want = R.reference_logits(cfg, params, toks[0])
+    assert R.rel_err(T.forward(cfg, params, toks)[0], want) < 1e-5
+
+
+def test_paged_prefill_then_decode_is_the_reference(params):
+    """The benchmark's own call: one row, ``slots`` left at its default, a
+    padded prompt at start 0 and then teacher-forced single tokens."""
+    from benchmark.traffic_kinds.serve_backlog import parity_paged
+
+    class F32Cache(CausalLM):       # the harness asks for a bfloat16 pool
+        def init_paged_cache(self, *a, dtype=None, **kw):
+            return super().init_paged_cache(*a, dtype=jnp.float32, **kw)
+
+    err = parity_paged(R, F32Cache(tiny()), params, 16, 21, 8, seed=5)
+    assert max(err.values()) < 1e-5, err
+
+
+@pytest.mark.parametrize("length,carried", [(13, False), (29, True),
+                                             (8, False)])
+def test_the_chunked_scan_is_the_one_step_recurrence(length, carried):
+    """Over a length that is no multiple of the chunk (8), from zeros or
+    from a state carried in."""
+    cfg = tiny()
+    H, P, N, G = 4, 8, 16, 2
+    rng = np.random.default_rng(length)
+
+    def draw(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.float32)
+
+    x, Bm, Cm = draw(2, length, H, P), draw(2, length, G, N), draw(2, length, G, N)
+    dt = jax.nn.softplus(draw(2, length, H))
+    A = -jnp.exp(draw(H) * 0.5)
+    s0 = draw(2, H, P, N) if carried else jnp.zeros((2, H, P, N))
+    y, s = T._ssm_scan(cfg, x, Bm, Cm, dt, A, s0)
+    state, ys = s0, []
+    for t in range(length):
+        y_t, state = T._ssm_step(cfg, x[:, t:t + 1], Bm[:, t:t + 1],
+                                 Cm[:, t:t + 1], dt[:, t:t + 1], A, state)
+        ys.append(y_t)
+    np.testing.assert_allclose(y, jnp.concatenate(ys, 1), atol=2e-5)
+    np.testing.assert_allclose(s, state, atol=2e-5)
+
+
+def test_a_padded_bucket_leaves_state_and_tail_as_the_unpadded_prompt(params):
+    cfg = tiny()
+    lp = {k: v[0] for k, v in params["layers"].items()}
+    h = jnp.asarray(np.random.default_rng(2).standard_normal((1, 32, 64)),
+                    jnp.float32)
+    mixer = jax.jit(functools.partial(T._ssm_mixer, cfg))
+    out, (state, tail) = mixer(lp, h[:, :21])
+    mask = (jnp.arange(32) < 21)[None]
+    out_p, (state_p, tail_p) = mixer(lp, h, mask)
+    np.testing.assert_allclose(out_p[:, :21], out, atol=1e-5)
+    np.testing.assert_allclose(state_p, state, atol=1e-5)
+    np.testing.assert_array_equal(tail_p, tail)
+    # a row with no real token keeps what it had
+    kept = (state + 1.0, tail + 1.0)
+    _, (s2, t2) = mixer(lp, h[:, :1], jnp.zeros((1, 1), bool), kept)
+    np.testing.assert_array_equal(s2, kept[0])
+    np.testing.assert_array_equal(t2, kept[1])
+
+
+def test_the_cache_has_two_leaves_with_no_page_axis(params):
+    cfg = tiny()
+    cache = T.init_paged_cache(cfg, 7, 8, dtype=jnp.float32, slots=3)
+    assert cache["ssm_state"].shape == (2, 3, 4, 8, 16)
+    assert cache["ssm_state"].dtype == jnp.float32
+    assert cache["ssm_conv"].shape == (2, 3, 3, 32 + 2 * 32)
+    assert cache["k"].shape == (2, 7, 8, 2, 16)      # 16 wide: row-major
+    assert T.init_paged_cache(cfg, 7, 8)["ssm_state"].shape[1] == 1
+    assert T.PAGED_POOL_KEYS[-2:] == T.SSM_POOL_KEYS
+    # 128-wide heads under 8 KV heads: the K/V leaves head-major, the same
+    # numbers through them
+    wide = tiny(head_dim=128, num_heads=2, num_kv_heads=1)
+    assert T.kv_leaf_head_major(wide, 128) and not T.kv_leaf_head_major(cfg, 16)
+    assert T.init_paged_cache(wide, 7, 8)["k"].shape == (2, 7, 1, 8, 128)
+    p = init_params(wide, jax.random.PRNGKey(0))
+    toks = _tokens(21)
+    cache = T.init_paged_cache(wide, 5, 8, dtype=jnp.float32)
+    pad = jnp.zeros((1, 24), jnp.int32).at[:, :21].set(toks)
+    got, cache = jax.jit(functools.partial(T.forward_paged, wide))(
+        p, pad, cache, jnp.arange(1, 5, dtype=jnp.int32)[None],
+        jnp.zeros((1,), jnp.int32), (jnp.arange(24) < 21)[None])
+    assert cache["k"].shape == (2, 5, 1, 8, 128)
+    assert float(jnp.abs(got[0, :21] - _forward(wide, p, toks)[0]).max()) < 1e-5
+
+
+def test_state_rows_follow_state_slot_and_start(params):
+    """Row b of the batch is state row b unless ``state_slot`` says
+    otherwise; a start of 0 resets, any other continues."""
+    cfg, toks = tiny(), _tokens(24, seed=3)
+    table = jnp.arange(1, 4, dtype=jnp.int32)[None]
+    cache = T.init_paged_cache(cfg, 4, 8, dtype=jnp.float32, slots=3)
+    dirty = dict(cache, ssm_state=cache["ssm_state"] + 5.0,
+                 ssm_conv=cache["ssm_conv"] + 5.0)
+    run = jax.jit(functools.partial(T.forward_paged, cfg, params))
+    _, a = run(toks[:, :16], dirty, table, jnp.zeros((1,), jnp.int32),
+               jnp.ones((1, 16), bool), state_slot=jnp.asarray([2]))
+    _, b = run(toks[:, :16], cache, table, jnp.zeros((1,), jnp.int32),
+               jnp.ones((1, 16), bool))
+    np.testing.assert_allclose(a["ssm_state"][:, 2], b["ssm_state"][:, 0],
+                               atol=1e-6)
+    np.testing.assert_array_equal(a["ssm_state"][:, :2],
+                                  dirty["ssm_state"][:, :2])
+    # the next block continues the row: both halves = the whole
+    _, a2 = run(toks[:, 16:], a, table, jnp.full((1,), 16, jnp.int32),
+                jnp.ones((1, 8), bool), state_slot=jnp.asarray([2]))
+    _, whole = run(toks, cache, table, jnp.zeros((1,), jnp.int32),
+                   jnp.ones((1, 24), bool))
+    np.testing.assert_allclose(a2["ssm_state"][:, 2], whole["ssm_state"][:, 0],
+                               atol=1e-5)
+    np.testing.assert_allclose(a2["ssm_conv"][:, 2], whole["ssm_conv"][:, 0],
+                               atol=1e-6)
+
+
+@pytest.mark.parametrize("name", ["tiny", "tiny-gqa", "ssm"])
+def test_one_positions_logits_are_that_row_of_all(name, params):
+    """``logits_at``: the head over one position a row, for every model."""
+    cfg = tiny() if name == "ssm" else get_config(name, dtype=jnp.float32)
+    p = params if name == "ssm" else init_params(cfg, jax.random.PRNGKey(0))
+    toks = _tokens(16, seed=4)
+    cache = T.init_paged_cache(cfg, 3, 8, dtype=jnp.float32)
+    args = (toks, cache, jnp.arange(1, 3, dtype=jnp.int32)[None],
+            jnp.zeros((1,), jnp.int32), (jnp.arange(16) < 11)[None])
+    every, _ = T.forward_paged(cfg, p, *args)
+    one, _ = T.forward_paged(cfg, p, *args, logits_at=jnp.asarray([10]))
+    assert one.shape == (1, 1, cfg.vocab_size)
+    np.testing.assert_allclose(one[0, 0], every[0, 10], atol=1e-6)
+
+
+def _requests(n, seed=0, lo=3, hi=40, new=(6, 30), **kw):
+    rng = np.random.default_rng(seed)
+    return [Request(rid=f"r{i}", arrival_time=0.0,
+                    max_new_tokens=int(rng.integers(*new)),
+                    input_ids=rng.integers(0, 256, (int(rng.integers(lo, hi)),)
+                                           ).astype(np.int32), **kw)
+            for i in range(n)]
+
+
+def test_engine_serves_token_for_token_and_a_reused_slot_starts_clean(engine):
+    """Nine requests through three slots: every slot is taken again by a
+    request another just left, and each yields what greedy ``forward``
+    yields, which is what it yields alone on a fresh engine."""
+    cfg = engine.model.config
+    reqs = _requests(9)
+    sv = engine.serving(**SERVE_KW)
+    assert sv._exec._pool_keys == ("k", "v", "ssm_state", "ssm_conv")
+    results = {r.rid: r for r in sv.run(reqs)}
+    for q in reqs:
+        out = results[q.rid].output_ids
+        assert len(out) == q.max_new_tokens
+        assert _is_greedy(cfg, engine.params, q.input_ids, out), q.rid
+    h = sv.health()
+    assert sv.page_accounting()["balanced"]
+    assert h["lookahead_launched_total"] > 0
+    assert h["lookahead_dropped_total"] == 0
+    assert h["state_pool_bytes"] == 2 * 3 * (4 * 8 * 16 * 4 + 3 * 96 * 4)
+    assert h["state_pool_bytes"] < h["kv_pool_bytes_total"]
+    alone = engine.serving(**SERVE_KW).run([reqs[7]])
+    assert list(alone[0].output_ids) == list(results["r7"].output_ids)
+
+
+def _drive(sv, requests, start=0.0):
+    """Step the engine on a clock of one unit a tick (deadlines are then a
+    number of ticks, the same with the lookahead and without)."""
+    for q in requests:
+        sv.submit(q)
+    now = start
+    while sv.step(now=now):
+        now += 1.0
+    return {r.rid: list(r.output_ids) for r in sv.take_results()}, now
+
+
+def _deadline_mix():
+    reqs = _requests(4, seed=7, new=(24, 30))
+    reqs[0].deadline_s = 5.0        # expires while ticks are in flight
+    return reqs
+
+
+def test_lookahead_on_is_lookahead_off_with_a_slot_expired_in_flight(engine):
+    plain, _ = _drive(engine.serving(lookahead=False, **SERVE_KW),
+                      _deadline_mix())
+    sv = engine.serving(**SERVE_KW)
+    ahead, _ = _drive(sv, _deadline_mix())
+    assert ahead == plain
+    assert 0 < len(plain["r0"]) < 24
+    h = sv.health()
+    assert h["deadline_expired_total"] == 1
+    assert h["lookahead_stale_taken_total"] > 0
+    assert h["lookahead_dropped_total"] == 0
+    assert sv.page_accounting()["balanced"]
+
+
+def test_dropping_a_launched_tick_would_advance_the_state_twice(engine):
+    """The rule the K/V-row models keep (drop what was launched on another
+    state) gives wrong tokens for a state: the test above fails on a build
+    that drops ticks for this model."""
+    plain, _ = _drive(engine.serving(lookahead=False, **SERVE_KW),
+                      _deadline_mix())
+    sv = engine.serving(**SERVE_KW)
+    sv._stateful = False
+    dropped, _ = _drive(sv, _deadline_mix())
+    assert sv.health()["lookahead_dropped_total"] > 0
+    assert dropped["r0"] == plain["r0"]
+    assert any(dropped[r] != plain[r] for r in ("r1", "r2"))
+
+
+def test_lookahead_on_is_lookahead_off_with_update_params_in_flight(engine,
+                                                                    params):
+    cfg = engine.model.config
+    other = jax.tree_util.tree_map(lambda x: x * 1.25, params)
+
+    def run(**kw):
+        sv = engine.serving(**SERVE_KW, **kw)
+        first = _requests(3, seed=11, new=(24, 30), deadline_s=3.0)
+        out, now = _drive(sv, first)
+        in_flight = len(sv._ahead)
+        sv.update_params(other)
+        later = [Request(rid=f"n{i}", arrival_time=now, max_new_tokens=6,
+                         input_ids=q.input_ids) for i, q in enumerate(first)]
+        out2, _ = _drive(sv, later, start=now)
+        return sv, {**out, **out2}, in_flight, later
+
+    _, plain, none, _ = run(lookahead=False)
+    sv, ahead, in_flight, later = run()
+    assert none == 0 and in_flight > 0
+    assert ahead == plain
+    for q in later:     # under the new weights, from a state reset
+        assert len(ahead[q.rid]) == 6
+        assert _is_greedy(cfg, sv.params, q.input_ids, ahead[q.rid])
+    h = sv.health()
+    assert h["lookahead_stale_taken_total"] >= in_flight
+    assert h["lookahead_dropped_total"] == 0 and h["weight_epoch"] == 1
+
+
+def test_spans_carry_the_state_and_the_scan(engine):
+    from deepspeed_tpu.observability import (Span, configure_tracer,
+                                             get_tracer)
+
+    sv = engine.serving(**SERVE_KW)
+    configure_tracer(enabled=True)
+    try:
+        sv.run(_requests(5, seed=3))
+        spans = [s for s in get_tracer().recorder.snapshot()
+                 if isinstance(s, Span)]
+    finally:
+        configure_tracer(enabled=False)
+        get_tracer().reset()
+    decode = [s.attrs for s in spans if s.name == "serve.decode"]
+    prefill = [s.attrs for s in spans if s.name == "serve.prefill"]
+    assert decode and len(prefill) == 5
+    row = 2 * (4 * 8 * 16 * 4 + 3 * 96 * 4)
+    for a in decode:
+        assert 1 <= a["state_slots"] <= 3
+        assert a["state_bytes"] == a["state_slots"] * row
+    for a in prefill:
+        assert a["state_reset"] == 1
+        assert a["scan_chunks"] == -(-a["tokens"] // 8)
+        assert a["scan_chunks_bucket"] == a["bucket"] // 8
+    assert (sum(a["scan_chunks"] for a in prefill)
+            < sum(a["scan_chunks_bucket"] for a in prefill))
+
+
+REFUSALS = {
+    "prefix sharing": ("prefix sharing", lambda e: e.serving(
+        prefix_cache=True, **SERVE_KW)),
+    "tiering": ("KV-page tiering", lambda e: e.serving(
+        host_tier_pages=4, **SERVE_KW)),
+    "extract and inject": ("KV-page tiering", lambda e: MeshExecutor(
+        e.model, e.params, 13, 8, 3, prefix_cache=False, host_tier=True)),
+    "speculative": ("speculative decoding", lambda e: e.serving(
+        speculative=object(), **SERVE_KW)),
+    "int8 pool": ("int8 pool", lambda e: e.serving(
+        kv_dtype="int8", **SERVE_KW)),
+    "int8 cache": ("int8 pool", lambda e: e.model.init_paged_cache(
+        4, 8, kv_dtype="int8")),
+    "copy-on-write": ("copy-on-write", lambda e: MeshExecutor(
+        e.model, e.params, 13, 8, 3, prefix_cache=True)),
+    "adapters": ("adapter", lambda e: T.forward_paged(
+        e.model.config, e.params, jnp.zeros((1, 1), jnp.int32),
+        e.model.init_paged_cache(4, 8), jnp.ones((1, 3), jnp.int32),
+        jnp.zeros((1,), jnp.int32), jnp.ones((1, 1), bool),
+        adapters={"scale": jnp.ones((1,)), "factors": {}})),
+    "adapter registry": ("multi-tenant adapters", lambda e: MeshExecutor(
+        e.model, e.params, 13, 8, 3, prefix_cache=False, adapters=object())),
+    "contiguous cache": ("contiguous cache", lambda e: e.generate(
+        np.arange(4, dtype=np.int32)[None], max_new_tokens=2)),
+    "training": ("training", lambda e: T.forward(
+        e.model.config, e.params, jnp.zeros((1, 4), jnp.int32),
+        deterministic=False)),
+}
+
+
+@pytest.mark.parametrize("what", list(REFUSALS))
+def test_mechanisms_that_know_pages_alone_refuse_by_name(engine, what):
+    named, call = REFUSALS[what]
+    with pytest.raises(NotImplementedError,
+                       match=r"state-space layers \(a state a slot\)") as e:
+        call(engine)
+    assert named in str(e.value)
+
+
+def test_tensor_sharded_serving_refuses():
+    from deepspeed_tpu.parallel.mesh import initialize_serving_mesh
+
+    cfg = tiny()
+    with pytest.raises(NotImplementedError, match="tensor-sharded heads"):
+        MeshExecutor(CausalLM(cfg), jax.eval_shape(
+            lambda: init_params(cfg, jax.random.PRNGKey(0))), 13, 8, 3,
+            mesh=initialize_serving_mesh(tp=2), prefix_cache=False)
+
+
+def test_what_the_block_is_not_built_from_is_refused():
+    with pytest.raises(NotImplementedError, match="expert layers"):
+        init_params(tiny(num_experts=4), jax.random.PRNGKey(0))
+    with pytest.raises(NotImplementedError, match="RMSNorm"):
+        init_params(tiny(norm="layernorm"), jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="whole groups"):
+        init_params(tiny(ssm_groups=3), jax.random.PRNGKey(0))
