@@ -51,11 +51,11 @@ from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, SSM_POOL_KEYS,
-                                  cow_copy_pool, expert_counts_shape,
-                                  is_hybrid, is_latent, is_ssm,
-                                  paged_pool_cache, paged_pool_order,
+                                  SSM_STATE_PASSES, cow_copy_pool,
+                                  expert_counts_shape, is_hybrid, is_latent,
+                                  is_ssm, paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, per_layer_leaves,
-                                  window_ring_pages)
+                                  ssm_step_path, window_ring_pages)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .kv_tiering import extract_pool_page, inject_pool_page
@@ -300,6 +300,12 @@ class MeshExecutor:
         # the prefill program of a model with a state a slot is told which
         # slot's row it resets and writes
         self.stateful = is_ssm(cfg)
+        # and its decode tick holds one of two steps, chosen where the tick
+        # is traced from the backend and the state's shape: "one_pass" (the
+        # kernel: the leaf in place, a read and a write of a live slot's
+        # state a layer) or "xla" (three passes); None for any other model
+        self.ssm_step = ssm_step_path(cfg)
+        self.state_passes = SSM_STATE_PASSES.get(self.ssm_step, 0)
         if self.ring_pages:
             pool_kw = {"dtype": dtype, "window_pages": self.window_pages}
         elif self.stateful:
@@ -885,13 +891,16 @@ class MeshExecutor:
         ``weight_leaves_split`` stacks cut into a leaf a layer,
         ``weight_leaves_relaid`` leaves (``weight_bytes_relaid`` bytes)
         copied into the layout the decode program asked for; all 0 for a
-        tree that already lay so (a warm restart's)."""
+        tree that already lay so (a warm restart's).  ``ssm_step``: the
+        step the decode tick of a model with a state a slot holds
+        (``"one_pass"`` / ``"xla"``: ``models.transformer.ssm_step_path``),
+        ``None`` for any other model."""
         mesh = self.mesh
         return {"mesh_devices": 1 if mesh is None else int(mesh.size),
                 "mesh_axes": {} if mesh is None else {
                     a: int(mesh.shape[a]) for a in mesh.axis_names
                     if int(mesh.shape[a]) > 1},
-                **self.weight_placement}
+                **self.weight_placement, "ssm_step": self.ssm_step}
 
     # ----------------------------------------------------------- adoption
 

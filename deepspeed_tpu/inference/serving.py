@@ -741,7 +741,8 @@ class ServingEngine:
                if mesh is not None else "")
             + f" weights: {info['weight_leaves_split']} stack(s) held a "
             f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
-            f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out",
+            f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out"
+            + (f" ssm_step={info['ssm_step']}" if self._stateful else ""),
             ranks=[0])
 
     # ---------------------------------------------- device-half delegation
@@ -2088,10 +2089,13 @@ class ServingEngine:
                         self._set_kv_row_attrs(sp, live + 1, self.b_slots)
                     if self._stateful:
                         # live slots whose state the tick read and wrote,
-                        # and the bytes of one reading of them
+                        # the bytes of one reading of them, and the passes
+                        # the tick's step makes over them (2: the one-pass
+                        # kernel's read and write; 3: _ssm_step's)
                         n = int(self._active.sum())
                         sp.set(state_slots=n, state_bytes=n * (
-                            self._exec.state_bytes // self.b_slots))
+                            self._exec.state_bytes // self.b_slots),
+                            state_passes=self._exec.state_passes)
                 # host fetch = device sync; an MoE model's expert counts
                 # come with the tokens
                 out = nxt

@@ -1730,7 +1730,14 @@ def _ssm_step(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
     """:func:`_ssm_scan` for one token a row: the recurrence itself, every
     number float32.  The sum over the state's columns is written out (a
     product, then a reduction) so that no matrix unit rounds the state to
-    read it.  A masked row (``dt = 0``) keeps its state."""
+    read it.  A masked row (``dt = 0``) keeps its state.
+
+    Who runs it: the non-paged forward, a paged block whose rows are named
+    (``state_slot``), every backend that is not a TPU and every shape the
+    kernel's tile plan refuses (:func:`ssm_step_path`); the compiler makes an
+    in-place update of it and a reduction that reads the state again, three
+    passes.  A decode tick on a TPU runs :func:`_ssm_step_one_pass`, whose
+    yardstick in the tests this is."""
     B, _, H, P = x.shape
     G, N = Bm.shape[2:]
     Hg, f32 = H // G, jnp.float32
@@ -1742,6 +1749,67 @@ def _ssm_step(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
              + xd[..., None] * Bm[:, 0].astype(f32)[:, :, None, None, :])
         y = (s * Cm[:, 0].astype(f32)[:, :, None, None, :]).sum(-1)
     return y.reshape(B, 1, H, P), s.reshape(B, H, P, N)
+
+
+def _ssm_kernel_interpret() -> Optional[bool]:
+    """``interpret`` for the one-pass step's kernel where a program traced
+    now may hold it, ``None`` where it may not: Pallas kernels compile for
+    the TPU (``ops/pallas/common.py``'s own test of the backend) and
+    ``pallas_call`` has no partitioning rule, so any other backend, and a
+    mesh of more than one device, keep :func:`_ssm_step`.  Never a config
+    field or an environment variable; a test that compiles for a described
+    chip, or runs the kernel in interpret mode, replaces this function."""
+    from ..parallel import mesh as mesh_mod
+
+    m = mesh_mod._GLOBAL_MESH
+    if jax.default_backend() != "tpu" or (m is not None and m.size > 1):
+        return None
+    return False
+
+
+def ssm_step_path(cfg: TransformerConfig, tokens: int = 1,
+                  state_slot=None, dtype=jnp.float32) -> Optional[str]:
+    """Which step a paged program of ``tokens`` a row holds for ``cfg``'s
+    state-space layers: ``"one_pass"`` (``ops/pallas/ssm_step.py``: the pool
+    leaf updated in place and ``y`` read from the block in on-chip memory,
+    one read and one write of a slot's state) for one token a row over
+    contiguous slot rows (``state_slot`` None: a decode tick) of a float32
+    leaf, on a TPU, at a shape the kernel's tile plan takes; ``"xla"``
+    (:func:`_ssm_step`, three passes) for any other single token; ``None``
+    for a longer block (:func:`_ssm_scan`) and a model with no such layer.
+    Read at trace time from what the code can observe; the serving executor
+    reports it (``mesh_info()["ssm_step"]``)."""
+    from ..ops.pallas.ssm_step import head_block
+
+    if not is_ssm(cfg) or tokens != 1:
+        return None
+    if (state_slot is None and dtype == jnp.float32
+            and _ssm_kernel_interpret() is not None
+            and head_block(cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim,
+                           cfg.ssm_state) is not None):
+        return "one_pass"
+    return "xla"
+
+
+# passes over a live slot's state a layer a tick, by the step the tick holds
+SSM_STATE_PASSES = {"one_pass": 2, "xla": 3}
+
+
+def _ssm_step_one_pass(x, Bm, Cm, dt, A, leaf, row0, fresh):
+    """:func:`_ssm_step` for the rows ``row0 .. row0 + B - 1`` of the stacked
+    cache leaf ``leaf [L * slots, H, P, N]`` where they lie: ``(y
+    [B,1,H,P] float32, the leaf)``, the same formula for the state and for
+    ``y`` term for term, a ``fresh [B]`` row from zeros."""
+    from ..ops.pallas.ssm_step import ssm_step
+
+    with jax.named_scope("ssm_step"):
+        dt1 = dt[:, 0]
+        leaf, y = ssm_step(
+            leaf, row0, fresh, jnp.exp(dt1 * A),
+            x[:, 0].astype(jnp.float32) * dt1[..., None],
+            Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32),
+            interpret=_ssm_kernel_interpret())
+    return y[:, None], leaf
 
 
 def _ssm_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], y, z):
@@ -1759,14 +1827,18 @@ def _ssm_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], y, z):
 
 
 def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h, seq_mask=None,
-               kept=None):
+               kept=None, step=None):
     """The Mamba-2 mixer of a block on its post-norm input ``h [B,S,d]``:
     in-projection, convolution, selective state update (one token a row:
     :func:`_ssm_step`, a longer block: :func:`_ssm_scan`), the skip ``D x``,
     gated norm, out-projection.  ``kept = (state [B,H,P,N] float32, tail
     [B,K-1,C])`` is what the rows' sequences hold so far (``None``: they
     start here); ``seq_mask [B,S]`` its real tokens, which lead the block.
-    Returns ``(out [B,S,d], (state, tail) after the block's real tokens)``."""
+    Returns ``(out [B,S,d], (state, tail) after the block's real tokens)``.
+    ``step(x, Bm, Cm, dt, A, state) -> (y, state)`` stands in for the state
+    update where the caller holds the state in another form (a decode
+    tick's pool leaf: :func:`_ssm_paged`); ``state`` is then whatever it
+    takes and returns."""
     B, S, _ = h.shape
     H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                   cfg.ssm_groups)
@@ -1784,8 +1856,8 @@ def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h, seq_mask=None,
     dt = jnp.where(seq_mask[..., None], jax.nn.softplus(
         dt + lp["ssm_dt_bias"].astype(jnp.float32)), 0.0)
     A = -jnp.exp(lp["ssm_A_log"].astype(jnp.float32))
-    y, state = (_ssm_step if S == 1 else _ssm_scan)(cfg, x, Bm, Cm, dt, A,
-                                                    state)
+    y, state = (step or functools.partial(
+        _ssm_step if S == 1 else _ssm_scan, cfg))(x, Bm, Cm, dt, A, state)
     y = y + lp["ssm_D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
     with jax.named_scope("ssm_out"):
         out = _ssm_gate_norm(cfg, lp, y.reshape(B, S, d_ssm), z) @ lp["ssm_out"]
@@ -2348,6 +2420,10 @@ KV_QUANT_DTYPES = ("int8",)
 # a token's normed latent and its shared rotated key row, no head axis.
 # A model with state-space layers (``ssm_heads``) keeps, beside ``k``/``v``,
 # two leaves with NO page axis, a row a slot: ``ssm_state`` and ``ssm_conv``.
+# A prompt's block reads and writes its slot's rows around :func:`_ssm_scan`;
+# a decode tick on a TPU hands ``ssm_state`` itself to the one-pass kernel,
+# which updates this layer's rows where they lie (:func:`ssm_step_path`), and
+# any other single token cuts its rows out for :func:`_ssm_step`.
 SSM_POOL_KEYS = ("ssm_state", "ssm_conv")
 PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window",
                    "latent") + SSM_POOL_KEYS
@@ -3577,7 +3653,14 @@ def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
     row0 + B - 1`` where it lies; else the rows it names), a row that
     starts its sequence begins from zeros, :func:`_ssm_mixer` advances
     them, and they are written back where they were.  What is kept is the
-    two leaves."""
+    two leaves.
+
+    The state of a decode tick on a TPU is never taken: where
+    :func:`ssm_step_path` says ``"one_pass"`` the mixer's step is
+    :func:`_ssm_step_one_pass` over the ``ssm_state`` leaf itself, one read
+    and one write of each row.  Every other block (a prompt's scan, named
+    rows, another backend, a shape the kernel refuses) takes and puts its
+    rows around :func:`_ssm_scan` / :func:`_ssm_step` as before."""
     B = seq_mask.shape[0]
     fresh = (start == 0) & seq_mask.any(axis=1)
     if state_slot is None:
@@ -3596,14 +3679,23 @@ def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
         def put(a, new):
             return a.at[rows].set(new.astype(a.dtype))
 
+    one_pass = ssm_step_path(cfg, seq_mask.shape[1], state_slot,
+                             pools["ssm_state"].dtype) == "one_pass"
+
     def ssm(lp, h):
-        # the recurrence is float32 whatever the leaf is kept in
-        state = take(pools["ssm_state"]).astype(jnp.float32)
-        tail = take(pools["ssm_conv"])
-        kept = (jnp.where(fresh[:, None, None, None], 0.0, state),
-                jnp.where(fresh[:, None, None], 0, tail))
-        out, (state, tail) = _ssm_mixer(cfg, lp, h, seq_mask, kept)
-        return out, {"ssm_state": put(pools["ssm_state"], state),
+        tail = jnp.where(fresh[:, None, None], 0, take(pools["ssm_conv"]))
+        if one_pass:
+            out, (state, tail) = _ssm_mixer(
+                cfg, lp, h, seq_mask, (pools["ssm_state"], tail),
+                functools.partial(_ssm_step_one_pass, row0=row0, fresh=fresh))
+        else:
+            # the recurrence is float32 whatever the leaf is kept in
+            state = take(pools["ssm_state"]).astype(jnp.float32)
+            state = jnp.where(fresh[:, None, None, None], 0.0, state)
+            out, (state, tail) = _ssm_mixer(cfg, lp, h, seq_mask,
+                                            (state, tail))
+            state = put(pools["ssm_state"], state)
+        return out, {"ssm_state": state,
                      "ssm_conv": put(pools["ssm_conv"], tail)}
     return ssm
 

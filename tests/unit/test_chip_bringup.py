@@ -368,6 +368,84 @@ def test_hybrid_paged_decode_keeps_both_pools_in_place_on_v5e(one_v5e_chip):
         assert max(sizes) <= pairs * int(np.prod(kind)), kind
 
 
+def test_state_decode_tick_holds_one_kernel_over_the_leaf_on_v5e(
+        one_v5e_chip, monkeypatch):
+    """AOT: ``jit_serve_decode``'s body for Falcon-H1 at the published widths
+    (a state of 32 x 128 x 256 float32 a slot a layer; depth 2, 8 slots),
+    compiled by the installed libtpu with the cache donated and the step
+    chosen as on a TPU (the test answers for the backend; the kernel is
+    compiled, not interpreted).  Mosaic accepts the one-pass kernel; the
+    layer scan's body holds ONE ``tpu_custom_call``; the ``ssm_state`` leaf
+    goes into it and comes out of it and NOTHING else of the program reads
+    or writes a tensor of the state rows' size: no update fusion, no
+    ``multiply_reduce`` over ``[B,32,128,256]``, no copy of the leaf (an
+    alias the compiler could not honour would copy 2 GB a layer in the
+    benchmark's cell and push it over the chip); temporaries are
+    megabytes."""
+    import re
+
+    import numpy as np
+
+    from deepspeed_tpu.models import CausalLM, get_config, init_params
+    from deepspeed_tpu.models import transformer as T
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: False)
+    depth, slots = 2, 8
+    cfg = get_config("falcon-h1-34b", num_layers=depth, vocab_size=16384)
+    assert T.ssm_step_path(cfg) == "one_pass"
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * 16, 128, dtype=jnp.bfloat16, slots=slots)))
+    assert cache["ssm_state"].shape == (depth, slots, 32, 128, 256)
+
+    def tick(params, cache, tokens, table, start, mask):
+        logits, cache = T.forward_paged(cfg, params, tokens, cache, table,
+                                        start, mask)
+        return jnp.argmax(logits[:, -1], -1), cache
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, cache, S((slots, 1), jnp.int32), S((slots, 16), jnp.int32),
+        S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
+    # 2.3 MB with _ssm_step at this size; the kernel adds no state-sized one
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
+    text = compiled.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
+    assert len(calls) == 1
+    rows = slots * 32 * 128 * 256
+    bodies = set(re.findall(r" fusion\([^\n]*calls=%([\w.\-]+)", text))
+    passes = {"parameter", "get-tuple-element", "bitcast", "while", "tuple",
+              "custom-call"}
+    inside, state_sized = None, set()
+    lines = []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)",
+                     line)
+        if not m or inside in bodies:
+            continue
+        name, result, opcode, rest = m.groups()
+        lines.append((name, opcode, rest))
+        if any(int(np.prod([int(d) for d in dims.split(",")])) >= rows
+               for dims in re.findall(r"f32\[([\d,]+)\]", result)):
+            assert opcode in passes, line[:240]
+            state_sized.add(name)
+    assert state_sized
+    for name, opcode, rest in lines:
+        operands = set(re.findall(r"%([\w.\-]+)", rest.split("), ")[0]))
+        if operands & state_sized:
+            assert opcode in passes, (name, opcode)
+
+
 def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
     """AOT: ``jit_serve_decode``'s body for Kanana-2 at the benchmark's
     widths and geometry (depth 3: the dense layer and two expert layers, 16

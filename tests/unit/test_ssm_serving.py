@@ -346,12 +346,58 @@ def test_spans_carry_the_state_and_the_scan(engine):
     for a in decode:
         assert 1 <= a["state_slots"] <= 3
         assert a["state_bytes"] == a["state_slots"] * row
+        assert a["state_passes"] == 3       # the CPU's tick holds _ssm_step
     for a in prefill:
         assert a["state_reset"] == 1
         assert a["scan_chunks"] == -(-a["tokens"] // 8)
         assert a["scan_chunks_bucket"] == a["bucket"] // 8
     assert (sum(a["scan_chunks"] for a in prefill)
             < sum(a["scan_chunks_bucket"] for a in prefill))
+
+
+def test_the_one_pass_step_serves_token_for_token(monkeypatch):
+    """A state the kernel's tile plan takes (16 heads in 2 groups, 8 x 128)
+    served with the one-pass step in the decode tick (interpret mode: the
+    test answers in the backend's place, as a TPU would) against the engine
+    whose tick holds ``_ssm_step``: the same tokens, greedy ``forward``'s,
+    a slot taken again starts clean, and each engine's spans say how many
+    passes its tick makes over the state."""
+    from deepspeed_tpu.observability import (Span, configure_tracer,
+                                             get_tracer)
+    from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh
+
+    cfg = tiny(ssm_heads=16, ssm_state=128)
+    engine = deepspeed_tpu.init_inference(
+        model=CausalLM(cfg), params=init_params(cfg, jax.random.PRNGKey(1)),
+        dtype="fp32",
+        mesh=initialize_mesh(MeshLayout(), devices=jax.devices()[:1]))
+
+    def serve(step, passes):
+        sv = engine.serving(**SERVE_KW)
+        assert sv._exec.mesh_info()["ssm_step"] == step
+        assert sv.health()["ssm_step"] == step
+        configure_tracer(enabled=True)
+        try:
+            results = {r.rid: list(r.output_ids) for r in sv.run(_requests(9))}
+            decode = [s.attrs for s in get_tracer().recorder.snapshot()
+                      if isinstance(s, Span) and s.name == "serve.decode"]
+        finally:
+            configure_tracer(enabled=False)
+            get_tracer().reset()
+        assert decode and all(a["state_passes"] == passes for a in decode)
+        assert sv.page_accounting()["balanced"]
+        assert sv.health()["lookahead_dropped_total"] == 0
+        return results
+
+    plain = serve("xla", 3)
+    monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: True)
+    one_pass = serve("one_pass", 2)
+    assert one_pass == plain
+    for q in _requests(9):
+        assert len(one_pass[q.rid]) == q.max_new_tokens
+        assert _is_greedy(cfg, engine.params, q.input_ids, one_pass[q.rid])
+    alone = engine.serving(**SERVE_KW).run([_requests(9)[7]])
+    assert list(alone[0].output_ids) == one_pass["r7"]
 
 
 REFUSALS = {
