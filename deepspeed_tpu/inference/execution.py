@@ -83,6 +83,14 @@ _TIER_EXTRACT_PROG = jax.jit(extract_pool_page)
 _TIER_INJECT_PROG = jax.jit(inject_pool_page, donate_argnums=(0,))
 
 
+def feed_lane(fed, out, slot):
+    """``fed`` (a decode program's token operand) with lane ``slot`` set to
+    the token a prefill program's output ``out`` leads with: how an
+    admission's first token reaches the next tick without the host
+    (:meth:`MeshExecutor.feed_lane`)."""
+    return fed.at[slot].set(out.reshape(-1)[0].astype(fed.dtype))
+
+
 # What an executor compiles ahead of time to learn how the device wants its
 # state, by everything the compiled program is made from: the program that
 # makes the pool, and the layouts a decode program asked for its weights
@@ -432,6 +440,19 @@ class MeshExecutor:
         # invalidation contract as the lanes: constant across a request's
         # decode, rebuilt only when slot membership changes
         self._adapters_device = None
+        # the one small program beside the decode and prefill programs that
+        # the lookahead runs (docs/SERVING.md "Decode lookahead"): a
+        # prefill's token into a lane of the next tick's token operand, on
+        # the device.  Its output lies as a decode program's own does, so
+        # the tick it is fed to is that program and no second compile; warmed
+        # here on both operands' forms so that no admission compiles it
+        self._feed_prog = jax.jit(feed_lane,
+                                  out_shardings=self._token_sharding())
+        counts = self.moe_shape[0] * self.moe_shape[1] if self.moe_shape else 0
+        first = jax.device_put(np.zeros((1 + counts,) if counts else (),
+                                        np.int32), self._token_sharding())
+        self.feed_lane(self.feed_lane(np.zeros((self.b_slots,), np.int32),
+                                      first, 0), first, 0)
 
     # k/v pool views: the canonical state is the `pools` tuple (programs
     # consume/produce it whole so donation covers every leaf); kpool/vpool
@@ -688,6 +709,26 @@ class MeshExecutor:
             return NamedSharding(self.mesh, P())
         return self._pool_avals[0].sharding
 
+    def fed(self, last_tok):
+        """The token operand of :meth:`decode` on the device: the host's
+        [B_slots] vector placed as the program's own output is (room for an
+        MoE model's expert counts behind it), so that feeding that output
+        back is the same program and not a second compile; a device array
+        as it is."""
+        if isinstance(last_tok, np.ndarray):
+            if self.moe_shape is not None:
+                last_tok = np.concatenate([last_tok, np.zeros(
+                    self.moe_shape[0] * self.moe_shape[1], last_tok.dtype)])
+            last_tok = jax.device_put(last_tok, self._token_sharding())
+        return last_tok
+
+    def feed_lane(self, fed, out, slot: int):
+        """``fed`` (the host's vector or a tick's device output) with lane
+        ``slot`` taken from the prefill output ``out`` where it lies on the
+        device: the tick launched behind an admission's prefill includes
+        the new slot and no fetch stands between the two launches."""
+        return self._feed_prog(self.fed(fed), out, np.int32(slot))
+
     def decode(self, page_table, lengths, last_tok, active, lanes,
                adapters=None):
         """One fixed-shape decode step over all slots; returns the sampled
@@ -699,13 +740,7 @@ class MeshExecutor:
         adapter registry attached, ``adapters`` is the per-slot factor
         pytree (``adapter_stacks``); ``None`` rides the cached all-zero
         stacks (base-model traffic) — the program signature never changes."""
-        if isinstance(last_tok, np.ndarray):
-            if self.moe_shape is not None:
-                last_tok = np.concatenate([last_tok, np.zeros(
-                    self.moe_shape[0] * self.moe_shape[1], last_tok.dtype)])
-            # placed as the program's own output is, so that feeding that
-            # output back is the same program and not a second compile
-            last_tok = jax.device_put(last_tok, self._token_sharding())
+        last_tok = self.fed(last_tok)
         # ``page_table``: the slots' table, or (full, ring) tables of a
         # model with window layers
         args = (self.params, self.pools,

@@ -117,6 +117,7 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import itertools
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
@@ -339,25 +340,46 @@ class _Slot:
 # a stall of a few ticks' length where no arrival can be placed anyway
 # (PERF.md §6, PR 26: ~115 ms, two to four a run).
 LOOKAHEAD_TICKS = 8
+# prefills launched whose first token the host has not fetched yet: one runs
+# while the next is queued behind it.  A queued prefill's temporaries may be
+# held from its enqueue, so no more than the device needs to go from one
+# program into the next (PERF.md §6, PR 42)
+PREFILLS_IN_FLIGHT = 2
 
 
 @dataclasses.dataclass
 class _Ahead:
-    """A decode tick launched before the ticks ahead of it were fetched: its
-    device output, the output it was fed, and everything of the host's
-    state it was launched on.  It is used only if the state it finds when
-    its turn comes is that state (:meth:`ServingEngine._take_ahead`), so no
-    path that changes a slot between two ticks has to know that it exists."""
+    """A decode tick launched before the programs ahead of it were fetched:
+    its device output and, slot by slot, the inputs it was launched on.  It
+    is taken for every slot whose inputs those still are when its turn comes
+    (:meth:`ServingEngine._ahead_slots`), so no path that changes a slot
+    between two ticks has to know that it exists."""
     out: Any
-    fed: Any
-    page_table: np.ndarray
-    lengths: np.ndarray
-    active: np.ndarray
-    params: Any
-    lanes: Any
-    adapters: Any
     seq: int    # its ``serve.launch``'s, for the ``serve.fetch`` that reads it
-    gen: np.ndarray     # each slot's admission count at the launch
+    params: Any
+    page_table: np.ndarray
+    active: np.ndarray      # the slots it computes a token for (its own mask)
+    lengths: np.ndarray     # each slot's rows as it starts
+    src: np.ndarray     # the launch whose output is each slot's fed token
+    owed: np.ndarray    # tokens each slot is still owed once it is taken
+    past_end: bool      # its mask is narrower than the slots live at launch
+
+
+@dataclasses.dataclass
+class _FirstToken:
+    """A prefill launched and not fetched yet: the admission is booked (slot,
+    pages, lengths), its first token lies on the device, and the ticks
+    launched behind it take it from there
+    (:meth:`~.execution.MeshExecutor.feed_lane`).  The host reads it when
+    its turn in launch order comes (:meth:`ServingEngine._first_token`)."""
+    out: Any
+    seq: int
+    program: str
+    slot: int
+    span: Any       # its ``serve.prefill`` span: the expert counts come late
+    live_tokens: int
+    st: Optional["_Slot"] = None    # the admission it was booked as
+    fed: bool = False       # a tick took its lane on the device
 
 
 class ServingEngine:
@@ -422,9 +444,8 @@ class ServingEngine:
         # values, so it has to start its slot
         self._latent = is_latent(cfg)
         # a state a slot (docs/SERVING.md "A state a slot"): every tick
-        # advances each live slot's recurrent state whole, admission resets
-        # it through the prefill at position 0, and a tick that was launched
-        # is never un-launched for a slot that goes on (_take_ahead)
+        # advances each live slot's recurrent state whole and admission
+        # resets it through the prefill at position 0
         self._stateful = is_ssm(cfg)
         unlike = None
         if self._ring:
@@ -458,17 +479,28 @@ class ServingEngine:
         # t+1 on tick t's device-resident tokens before fetching them
         self.lookahead = bool(lookahead)
         self._ahead: Deque[_Ahead] = deque()
-        # the last tick emitted: (device output, its tokens on the host)
-        self._last_out: Optional[tuple] = None
+        # prefills in flight whose first token is still on the device alone
+        self._firsts: Deque[_FirstToken] = deque()
         self._in_run = False
         self.lookahead_launched = 0
+        # ticks not used because the weights were swapped under all of them
         self.lookahead_dropped = 0
-        # stale ticks of a model with a state a slot whose tokens were taken
-        # for the slots that went on (never dropped: _take_ahead)
+        # ticks taken for some of their slots only (the others had ended or
+        # been taken over since), or fetched for no one
         self.lookahead_stale_taken = 0
-        # admissions a slot has seen: a tick launched ahead names the
-        # request it advanced by (slot, count)
-        self._slot_gen = np.zeros((self.b_slots,), np.int64)
+        # ticks launched under a mask narrower than the live slots (past a
+        # slot's last token, which the host can count), and admissions
+        # whose first token reached a tick without the host
+        self.lookahead_past_end = 0
+        self.prefill_fed_on_device = 0
+        # the launch (its seq) whose output is each slot's last token: a
+        # tick launched ahead names the token it was fed by it
+        self._tok_src = np.zeros((self.b_slots,), np.int64)
+        # decode tokens each live slot is still owed (max_new_tokens less
+        # the prefill's and those emitted), and live requests that can stop
+        # on a token the host has not seen (eos_token_id)
+        self._owed = np.zeros((self.b_slots,), np.int32)
+        self._eos_live = 0
         # decode and prefill programs launched so far: the k-th launch is
         # the k-th such program the device runs (``seq`` of the
         # ``serve.launch`` / ``serve.fetch`` spans)
@@ -1459,6 +1491,11 @@ class ServingEngine:
                             and not self._quarantined[i])
             except StopIteration:
                 break
+            if (len(self._firsts) >= PREFILLS_IN_FLIGHT
+                    and not self._first_token_due()):
+                # PREFILLS_IN_FLIGHT unfetched behind a tick still to be
+                # fetched: the slot is filled once that tick has been
+                break
             admitted = freed_pins = promote_retry = False
             # the owning request's trace context (docs/OBSERVABILITY.md
             # "Distributed tracing"): every span this admission opens —
@@ -1501,6 +1538,16 @@ class ServingEngine:
                             # fresh, strictly smaller lookup
                             promote_retry = True
                         else:
+                            # a first token whose turn has come, with a tick
+                            # queued behind its prefill for the device to go
+                            # on with: read before this admission's own
+                            # spans open (the wait is no part of them)
+                            if self._first_token_due() and self._ahead:
+                                with self._armed("serve.first_token"):
+                                    while (self._first_token_due()
+                                           and self._ahead):
+                                        self._first_token(
+                                            self._firsts.popleft())
                             with trace_span("serve.admit", rid=req.rid,
                                             slot=slot):
                                 self._admit_one(req, slot, match, need, now)
@@ -1686,6 +1733,12 @@ class ServingEngine:
                     self._set_kv_row_attrs(sp, [S_tail], 1, block=s_pad)
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
+                # no more than PREFILLS_IN_FLIGHT unfetched: the oldest's
+                # turn has come (_admit starts no admission where it has
+                # not), and the wait for it is a wait for this one's start
+                while len(self._firsts) >= PREFILLS_IN_FLIGHT:
+                    self._first_token(self._firsts.popleft())
+                sp.set(queued_behind=len(self._ahead) + len(self._firsts))
                 if match.cow_src is not None:
                     # COW the partial boundary page: private[0] is the
                     # boundary logical page (shared full pages cover
@@ -1704,17 +1757,22 @@ class ServingEngine:
                 out, seq, pt_row, toks_j = self._launch_prefill(
                     s_pad, slot, toks, S_tail, n_shared,
                     lane_t, lane_k, lane_p, lane_s, adapter_row)
-                out, counts = self._exec.split_counts(
-                    self._fetch(out, f"prefill_{s_pad}", seq))
-                tok = int(out.flat[0])
-                # host fetch above lands inside the watchdog window
-                if counts is not None and get_tracer().enabled:
-                    self._set_moe_attrs(sp, counts, S_tail)
                 if self._spec is not None:
                     # draft-pool prefill of the same tail (same bucket,
                     # page-table row, start) — the draft emits nothing
                     self._spec.prefill(s_pad, pt_row, toks_j, S_tail,
                                        n_shared)
+                first = _FirstToken(out, seq, f"prefill_{s_pad}", slot, sp,
+                                    S_tail)
+                # read here and now only where something reads it on the
+                # host at once, a stop on that very token included (the
+                # fetch lands inside the watchdog window)
+                tok = (None if self._runs_ahead() and req.eos_token_id is None
+                       else self._fetch_first(first))
+        # the admission is booked from what the host knows without the
+        # device's answer: the slot, its pages and lengths, the tokens it is
+        # owed.  Its first token is recorded when its turn in launch order
+        # comes (_first_token)
         t = time.monotonic()
         self._slot_failures[slot] = 0   # quarantine counts CONSECUTIVE fails
         lc = self._lifecycle_pending.pop(req.rid, [])
@@ -1724,16 +1782,16 @@ class ServingEngine:
         if match.cow_src is not None:
             lc.append(("cow", t, inc))
         lc.append(("prefill", t, inc))
-        lc.append(("first_token", t, inc))
-        self._slots[slot] = _Slot(
-            request=req, pages=pages, tokens=[tok], bucket=s_pad,
+        first.st = self._slots[slot] = _Slot(
+            request=req, pages=pages, tokens=[], bucket=s_pad,
             arrival_s=self._arrival_abs(req), admit_s=self._t0 + now,
-            first_token_s=t, shared_tokens=n_shared, lifecycle=lc,
-            token_s=[t])
+            first_token_s=t, shared_tokens=n_shared, lifecycle=lc)
         self._lengths[slot] = S
-        self._last_tok[slot] = tok
+        self._last_tok[slot] = 0
         self._active[slot] = True
-        self._slot_gen[slot] += 1
+        self._tok_src[slot] = seq
+        self._owed[slot] = req.max_new_tokens - 1
+        self._eos_live += req.eos_token_id is not None
         self._lane_temp[slot] = lane_t
         self._lane_top_k[slot] = lane_k
         self._lane_top_p[slot] = lane_p
@@ -1745,9 +1803,11 @@ class ServingEngine:
             self.adapter_admissions += 1
             self._adapter_admit_by_id[req.adapter_id] = (
                 self._adapter_admit_by_id.get(req.adapter_id, 0) + 1)
-            self._adapter_tokens_by_id[req.adapter_id] = (
-                self._adapter_tokens_by_id.get(req.adapter_id, 0) + 1)
-        self._tokens_out += 1
+        reason = None
+        if tok is None:
+            self._firsts.append(first)
+        else:
+            reason = self._record_first(first, tok)
         if self._prefix is not None:
             if n_shared > 0:
                 self.prefix_hits += 1
@@ -1758,9 +1818,9 @@ class ServingEngine:
             # publish this prompt's chunks (full pages + the partial
             # boundary) so later requests can share them; the index takes
             # one reference per new entry.  Shared chunks just LRU-touch
-            # their existing entries.  Host time AFTER the first token's
-            # stamp: the other slots' next token and the next admission
-            # wait for it.
+            # their existing entries.  The pages are written by the program
+            # enqueued above, ahead of any program that maps them later.
+            # Host time AFTER a first token's stamp where that was read.
             with trace_span("serve.publish", rid=req.rid):
                 newly, released = self._prefix.publish(
                     req.input_ids, pages, salt=self._adapter_salt(req))
@@ -1768,13 +1828,67 @@ class ServingEngine:
                     self._share_page(p)
                 for p in released:
                     self._drop_page(p)
+        if reason is not None:
+            self._finish(slot, reason)
+
+    def _runs_ahead(self) -> bool:
+        """The engine launches from what the host knows without the device's
+        answer: not the plain loop (``lookahead=False``), not with a
+        speculative tick or a sampled catalog dispatch, which read a
+        program's output on the host as they launch it."""
+        catalog = self._exec.catalog
+        return (self.lookahead and self._spec is None
+                and not (catalog is not None and catalog.sample_every))
+
+    def _first_token_due(self) -> bool:
+        """The oldest program in flight is a prefill: its first token's
+        turn has come."""
+        return bool(self._firsts) and (
+            not self._ahead or self._firsts[0].seq < self._ahead[0].seq)
+
+    def _fetch_first(self, first: _FirstToken) -> int:
+        """The blocking read of a prefill's output (the caller holds a
+        watchdog window): the first token; an MoE model's expert counts go
+        to the prefill's own span."""
+        out, counts = self._exec.split_counts(
+            self._fetch(first.out, first.program, first.seq))
+        if counts is not None and get_tracer().enabled:
+            self._set_moe_attrs(first.span, counts, first.live_tokens)
+        return int(out.flat[0])
+
+    def _first_token(self, first: _FirstToken) -> None:
+        """The second half of an admission, when its prefill's turn in
+        launch order has come: fetch, stamp and record the first token, and
+        finish a request that asked for one token or stops on this one."""
+        reason = self._record_first(first, self._fetch_first(first))
+        if reason is not None:
+            self._finish(first.slot, reason)
+
+    def _record_first(self, first: _FirstToken, tok: int) -> Optional[str]:
+        """Stamp and record an admission's first token; the reason its
+        request ends with it, if it does.  A slot that ended while its
+        prefill was in flight (a deadline) has no one to give the token
+        to."""
+        st, slot = first.st, first.slot
+        if self._slots[slot] is not st:
+            return None
+        req = st.request
+        t = time.monotonic()
+        st.tokens.append(tok)
+        st.token_s.append(t)
+        st.first_token_s = t
+        st.lifecycle.append(("first_token", t, self.engine_incarnation))
+        self._last_tok[slot] = tok
+        self._tokens_out += 1
+        if req.adapter_id is not None:
+            self._adapter_tokens_by_id[req.adapter_id] = (
+                self._adapter_tokens_by_id.get(req.adapter_id, 0) + 1)
         if self.monitor is not None:
             self.monitor.write_events([
                 ("serve/ttft_s", t - self._arrival_abs(req), self._tick)])
         if req.eos_token_id is not None and tok == req.eos_token_id:
-            self._finish(slot, "eos")
-        elif req.max_new_tokens == 1:
-            self._finish(slot, "length")
+            return "eos"
+        return "length" if req.max_new_tokens == 1 else None
 
     def _slot_rid_map(self) -> Dict[str, str]:
         """Active slot → rid, stringified for trace-event ``args`` (only
@@ -1791,14 +1905,20 @@ class ServingEngine:
 
         return contextlib.nullcontext()
 
-    def _launch_decode(self, lengths, fed, lanes, adapters, ahead: int):
-        """Enqueue one decode program under a ``serve.launch`` span:
-        ``(its device output, its seq)``.  ``ahead``: ticks in flight
-        behind the one being fetched once this launch has returned."""
+    def _launch_decode(self, lengths, fed, active, lanes, adapters,
+                       ahead: int, firsts=()):
+        """Enqueue one decode program over the slots ``active`` under a
+        ``serve.launch`` span: ``(its device output, its seq)``.  ``ahead``:
+        ticks in flight behind the one being fetched once this launch has
+        returned.  ``firsts``: the prefills in flight whose token ``fed``
+        does not hold yet; each one's lane is taken from its output on the
+        device, inside the launch."""
         with trace_span("serve.launch", program="decode",
                         seq=self._launch_seq + 1, ahead=ahead):
-            out = self._exec.decode(self._tables(), lengths, fed,
-                                    self._active, lanes, adapters=adapters)
+            for first in firsts:
+                fed = self._exec.feed_lane(fed, first.out, first.slot)
+            out = self._exec.decode(self._tables(), lengths, fed, active,
+                                    lanes, adapters=adapters)
         self._launch_seq += 1
         return out, self._launch_seq
 
@@ -1896,163 +2016,179 @@ class ServingEngine:
                                self._page_table.shape[1], slots)
 
     def _arrival_waiting(self, now: float) -> bool:
-        """A request is due and a usable slot is free: the next admission
-        call would try to place it."""
+        """A request is due, a usable slot is free and a prefill may be
+        launched: the next admission call would try to place it."""
         return ((bool(self._queue) or (bool(self._pending)
                  and self._pending[0].arrival_time <= now))
-                and int(self._active.sum()) < self._usable_slots())
+                and int(self._active.sum()) < self._usable_slots()
+                and len(self._firsts) < PREFILLS_IN_FLIGHT)
+
+    def _placeable(self) -> bool:
+        """A slot that comes free could be given to a request: admission is
+        open, and this ``run()`` has something queued or still to arrive
+        (outside ``run()`` anything may be submitted)."""
+        return not self._draining and not (
+            self._in_run and not self._queue and not self._pending)
 
     def _lookahead_depth(self) -> int:
-        """How many ticks after this one can be launched now: those whose
-        inputs are this tick's, so many positions on.  Every live slot is
-        still live then (none reaches its length before, none can stop on a
-        token the host has not seen yet).  How many, by what an arrival
-        would find on the device:
+        """How many ticks may be in flight behind the one being fetched:
+        each launched from what the host knows without the device's answer
+        (a slot without ``eos_token_id`` ends at a tick the host can count;
+        a tick past that point runs under the mask of the slots that go
+        on).  How many, by what an arrival would find on the device:
 
-        - no arrival can be placed (every usable slot is busy, or admission
-          is closed, or this ``run()`` has nothing queued or still to
-          arrive): up to ``LOOKAHEAD_TICKS``;
+        - no request could be given a slot (:meth:`_placeable` is false),
+          or every usable slot is busy: up to ``LOOKAHEAD_TICKS``, of which
+          at most one lies past a slot's end while a request could take the
+          slot (:meth:`_launch_ahead`): a slot stands empty for one tick
+          between two requests, and the device never stands still;
         - a slot is free and admission is open, and nothing waits for it
           (``_decode_tick`` follows ``step()``'s admission call, so a
           request still queued is one the pool cannot hold yet): one, the
           most an arrival's prefill is ever launched behind.
 
+        None where a live request can stop on a token the host has not
+        seen yet, or a sampled catalog dispatch syncs on its own output.
         Nothing is launched over a request that can be placed: ``step()``
         puts its admission after the tick in flight and ``_decode_tick``
         then skips the launch.  A guess, not a guard: what was launched is
-        checked again when its turn comes."""
-        catalog = self._exec.catalog
-        if not self.lookahead or (catalog is not None
-                                  and catalog.sample_every):
-            return 0    # a sampled dispatch syncs on its own output
+        checked again, slot by slot, when its turn comes."""
+        if not self._runs_ahead() or self._eos_live:
+            return 0
         if (int(self._active.sum()) >= self._usable_slots()
-                or self._draining
-                or (self._in_run and not self._queue and not self._pending)):
-            depth = LOOKAHEAD_TICKS
+                or not self._placeable()):
+            return LOOKAHEAD_TICKS
+        return 1
+
+    def _next_inputs(self):
+        """``(active, lengths, src, owed)`` of the tick to launch next: the
+        host's own state where no tick is in flight, else what the last one
+        launched leaves if it is taken for every slot it was launched on;
+        a slot admitted since then comes with the host's (its prefill was
+        launched behind that tick).  ``owed``: before this tick."""
+        if not self._ahead:
+            lengths, src, owed = (self._lengths.copy(), self._tok_src.copy(),
+                                  self._owed)
         else:
-            depth = 1
-        for slot in np.flatnonzero(self._active):
-            st = self._slots[slot]
-            if st.request.eos_token_id is not None:
-                return 0
-            depth = min(depth, st.request.max_new_tokens - len(st.tokens) - 1)
-        return max(depth, 0)
+            last = self._ahead[-1]
+            since = self._tok_src > last.seq
+            lengths = np.where(since, self._lengths,
+                               last.lengths + last.active)
+            src = np.where(since, self._tok_src,
+                           np.where(last.active, last.seq, last.src))
+            owed = np.where(since, self._owed, last.owed)
+        return self._active & (owed > 0), lengths, src, owed
 
-    def _ahead_current(self, lanes, adapters) -> bool:
-        """The tick launched first was launched on exactly the state the
-        host now holds: fed the output of the tick emitted last, with the
-        tables, lengths, slots, last tokens, parameters, lanes and adapter
-        operand of this moment."""
-        ahead = self._ahead[0]
-        return (self._last_out is not None
-                and ahead.fed is self._last_out[0]
-                and ahead.params is self._exec.params
-                and ahead.lanes is lanes and ahead.adapters is adapters
-                and np.array_equal(ahead.active, self._active)
-                and np.array_equal(ahead.lengths, self._lengths)
-                and np.array_equal(ahead.page_table, self._page_table)
-                and np.array_equal(self._last_out[1][ahead.active],
-                                   self._last_tok[self._active]))
+    def _launch_ahead(self, lanes, adapters, depth: int) -> int:
+        """Top the queue of launched ticks up to the one to fetch and
+        ``depth`` behind it, and say how many were launched: each on the
+        output of the one before it where it lies on the device (the host's
+        last tokens where none is in flight), a slot admitted since on its
+        prefill's, each under its own mask and lengths, so the device goes
+        from one program into the next while the host fetches, emits and
+        admits."""
+        launched = 0
+        while len(self._ahead) <= depth:
+            active, lengths, src, owed = self._next_inputs()
+            if not active.any():
+                break
+            # under a mask narrower than the live slots: past a slot's last
+            # token.  One such tick behind the one being fetched is all a
+            # request that could take the slot is made to wait for
+            past_end = bool((self._active & ~active).any())
+            if (past_end and self._placeable()
+                    and any(a.past_end for a in
+                            itertools.islice(self._ahead, 1, None))):
+                break
+            last = self._ahead[-1] if self._ahead else None
+            firsts = [f for f in self._firsts
+                      if last is None or f.seq > last.seq]
+            out, seq = self._launch_decode(
+                lengths, self._last_tok if last is None else last.out, active,
+                lanes, adapters, ahead=len(self._ahead), firsts=firsts)
+            for first in firsts:
+                self.prefill_fed_on_device += not first.fed
+                first.fed = True
+            self._ahead.append(_Ahead(
+                out, seq, self._exec.params, self._page_table.copy(), active,
+                lengths, src, owed - active, past_end))
+            self.lookahead_launched += len(self._ahead) > 1
+            self.lookahead_past_end += past_end
+            launched += 1
+        return launched
 
-    def _take_ahead(self, lanes, adapters):
-        """``(the device output of this tick, its launch's seq, the slots it
-        is emitted for)`` if a tick launched ahead stands for this one, else
-        ``None``.
+    def _ahead_slots(self, ahead: _Ahead) -> np.ndarray:
+        """The slots ``[b_slots]`` (bool) for which the launched tick
+        ``ahead`` computed the token the host would ask for now: live then
+        and now, fed the token the slot holds as its last (by the launch
+        that made it: the same admission, the same position), at the same
+        length and page row."""
+        return (ahead.active & self._active
+                & (ahead.src == self._tok_src)
+                & (ahead.lengths == self._lengths)
+                & (ahead.page_table == self._page_table).all(axis=1))
 
-        **Models whose cache is K/V rows alone.**  The tick is used only if
-        it was launched on exactly the state the host now holds (every live
-        slot: ``None`` for the slots); else it and every tick launched after
-        it are dropped.  What they wrote is one K/V row a slot each, past
-        the slot's length, which the ticks launched in their place write
-        again; and where a slot ended under them (a deadline) and its pages
-        went to a request admitted since, the order of the programs on the
-        device keeps the pages right: the stale ticks' rows land first, the
-        new request's prefill, launched after them, over them, and a row
-        past its prompt is one no read reaches before the slot's own decode
-        writes it.
+    def _take_ahead(self):
+        """``(the launched tick whose turn has come, the slots it is
+        emitted for)``, or ``None`` where no tick in flight is anyone's.
+        What lies ahead of it in launch order is read first: an admission's
+        first token (:meth:`_first_token`), a tick no slot goes on under
+        (fetched and read by no one).
 
-        **A model with a state a slot.**  That argument is about rows and is
-        false for a state: a dropped tick has also advanced every live
-        slot's recurrent state by one token, and the tick launched in its
-        place would advance it again.  So a launched tick is never
-        un-launched for a slot that goes on: a stale tick is fetched and its
-        token emitted for every slot whose OWN inputs it was launched on
-        (the same admission of the slot, its length, its last token, its
-        page row: a mask of slots), since no slot's arithmetic depends on
-        another's.  A slot that ended under it is reset by its next
-        admission's prefill, which the device runs after the stale ticks
-        because it was launched after them; a slot admitted since waits
-        until the stale ticks are through (nothing is launched ahead over
-        them).  A stale tick under which no slot goes on is fetched and
-        read by no one."""
-        while self._ahead:
-            if self._ahead_current(lanes, adapters):
-                ahead = self._ahead.popleft()
-                return ahead.out, ahead.seq, None
-            if not self._stateful:
-                self.lookahead_dropped += len(self._ahead)
-                self._ahead.clear()
-                return None
+        **One rule for every model: a launched tick is taken, slot by slot,
+        for every slot whose own inputs it was launched on**
+        (:meth:`_ahead_slots`), since no slot's arithmetic depends on
+        another's.  It is never un-launched for a slot that goes on: for a
+        model with a state a slot a tick has advanced every live slot's
+        recurrent state, and one launched in its place would advance it
+        again.  A slot that ended under it (a deadline; an end by length is
+        under no later tick's mask) left a K/V row past its length, and
+        where its pages went to a request admitted since, the order of the
+        programs on the device keeps them right: the stale tick's row lands
+        first, the new request's prefill, launched after it, over it, and a
+        row past its prompt is one no read reaches before the slot's own
+        decode writes it; its state is reset by that prefill.
+
+        What per-slot validity cannot save drops the whole queue
+        (``lookahead_dropped``): ticks launched on other weights
+        (``update_params`` settles the queue first, :meth:`_settle_ahead`)."""
+        while self._ahead or self._firsts:
+            if self._first_token_due():
+                self._first_token(self._firsts.popleft())
+                continue
             ahead = self._ahead.popleft()
-            self.lookahead_stale_taken += 1
             own = self._ahead_slots(ahead)
+            if ahead.params is not self._exec.params:
+                self.lookahead_dropped += 1 + len(self._ahead)
+                self._ahead.clear()
+                continue
+            self.lookahead_stale_taken += not np.array_equal(own,
+                                                             ahead.active)
             if own.any():
-                return ahead.out, ahead.seq, own
+                return ahead, own
             self._fetch(ahead.out, "decode", ahead.seq)
         return None
 
-    def _ahead_slots(self, ahead: _Ahead) -> np.ndarray:
-        """The slots ``[b_slots]`` (bool) for which the stale tick ``ahead``
-        computed the token the host would ask for now: fed the tick emitted
-        last under the weights of now, and the slot live then and now under
-        the same admission, at the same length, last token and page row."""
-        if (self._last_out is None or ahead.fed is not self._last_out[0]
-                or ahead.params is not self._exec.params):
-            return np.zeros((self.b_slots,), bool)
-        return (ahead.active & self._active
-                & (ahead.gen == self._slot_gen)
-                & (ahead.lengths == self._lengths)
-                & (self._last_out[1][:self.b_slots] == self._last_tok)
-                & (ahead.page_table == self._page_table).all(axis=1))
-
     def _settle_ahead(self) -> None:
-        """Before the weights change under a model with a state a slot: the
-        ticks in flight are fetched (no slot is live, so their tokens are no
-        one's) and counted as taken, not dropped.  Any other model's are
-        found stale by their parameters when their turn comes."""
-        while self._stateful and self._ahead:
-            ahead = self._ahead.popleft()
-            self.lookahead_stale_taken += 1
-            self._fetch(ahead.out, "decode", ahead.seq)
-
-    def _launch_ahead(self, nxt, lanes, adapters) -> None:
-        """Top the queue of launched ticks up to :meth:`_lookahead_depth`:
-        each on the output of the one before it where it lies on the
-        device (``nxt``: this tick's), so the device goes from one program
-        into the next while the host fetches, emits and schedules."""
-        depth = self._lookahead_depth()
-        while len(self._ahead) < depth:
-            fed = self._ahead[-1].out if self._ahead else nxt
-            lengths = self._lengths + np.int32(len(self._ahead) + 1) * \
-                self._active.astype(np.int32)
-            out, seq = self._launch_decode(lengths, fed, lanes, adapters,
-                                           ahead=len(self._ahead) + 1)
-            self._ahead.append(_Ahead(
-                out, fed, self._page_table.copy(), lengths,
-                self._active.copy(), self._exec.params, lanes, adapters,
-                seq, self._slot_gen.copy()))
-            self.lookahead_launched += 1
+        """Before the weights change: what is in flight is read in launch
+        order.  No slot is live (``update_params`` refuses otherwise), so
+        :meth:`_take_ahead` finds every tick no one's (counted as taken, not
+        dropped) and gives a first token whose slot has ended to no one."""
+        with self._armed("serve.settle"):
+            self._take_ahead()
 
     def _decode_tick(self, rid_map: Optional[Dict[str, str]] = None,
                      held: bool = False) -> None:
-        """One decode step over the live slots: take the tick launched
-        ahead or launch it, launch ahead of it (not where ``step()`` holds
-        an admission back behind this tick, ``held``), fetch, emit."""
+        """One decode step over the live slots: top the queue of launched
+        ticks up (only to this tick itself where ``step()`` holds an
+        admission back behind it, ``held``), read what lies ahead of this
+        tick in launch order, fetch it, emit."""
         if self._spec is not None:
             self._spec_tick(rid_map)
             return
         lanes = self._lanes_jnp()
         adapters = self._adapter_operand()
+        own = np.zeros((self.b_slots,), bool)
         with trace_span("serve.decode", tick=self._tick) as sp:
             t_open = time.monotonic() if rid_map is not None else 0.0
             # tick-level slot→rid map (docs/OBSERVABILITY.md "Distributed
@@ -2067,46 +2203,50 @@ class ServingEngine:
                 sp.set(slot_rids=rid_map, ahead=len(self._ahead))
             maybe_fire(SITE_SERVE_DECODE, tick=self._tick)
             with self._armed(f"serve.decode tick {self._tick}"):
-                # ``own``: the slots a stale tick of a model with a state a
-                # slot is emitted for (None: every live slot)
-                nxt, seq, own = (self._take_ahead(lanes, adapters)
-                                 or (*self._launch_decode(
-                                     self._lengths, self._last_tok, lanes,
-                                     adapters, ahead=0), None))
-                if not held and own is None:
-                    self._launch_ahead(nxt, lanes, adapters)
+                self._launch_ahead(lanes, adapters,
+                                   0 if held else self._lookahead_depth())
                 if rid_map is not None:
-                    # the launch has returned; what is left of the span is
-                    # the wait for the device in the fetch below.  Rows the
-                    # slots hold against rows the program's read covers
-                    # (each live slot's own pages, the row it writes too).
-                    live = self._lengths[self._active]
-                    sp.set(dispatch_ms=(time.monotonic() - t_open) * 1e3,
-                           live_rows=int(live.sum()),
-                           gathered_rows=self._gathered_rows(
-                               live + 1, self.b_slots))
-                    if self._ring:
-                        self._set_kv_row_attrs(sp, live + 1, self.b_slots)
-                    if self._stateful:
-                        # live slots whose state the tick read and wrote,
-                        # the bytes of one reading of them, and the passes
-                        # the tick's step makes over them (2: the one-pass
-                        # kernel's read and write; 3: _ssm_step's)
-                        n = int(self._active.sum())
-                        sp.set(state_slots=n, state_bytes=n * (
-                            self._exec.state_bytes // self.b_slots),
-                            state_passes=self._exec.state_passes)
-                # host fetch = device sync; an MoE model's expert counts
-                # come with the tokens
-                out = nxt
-                nxt, counts = self._exec.split_counts(
-                    self._fetch(out, "decode", seq))
-                self._last_out = (out, nxt)
-                if counts is not None and rid_map is not None:
-                    self._set_moe_attrs(sp, counts,
-                                        int(self._active.sum()))
+                    # the launches have returned; what is left of the span
+                    # is waiting for the device
+                    sp.set(dispatch_ms=(time.monotonic() - t_open) * 1e3)
+                taken = self._take_ahead()
+                if taken is None and self._launch_ahead(lanes, adapters, 0):
+                    # every tick in flight was no one's: this one is
+                    # launched on what the host holds now
+                    taken = self._take_ahead()
+                if taken is not None:
+                    tick, own = taken
+                    if rid_map is not None:
+                        # rows the tick's slots hold against rows its read
+                        # covers (each slot's own pages, the row it writes
+                        # too), by its own mask and lengths; ``own_slots``:
+                        # the slots it is emitted for
+                        live = tick.lengths[tick.active]
+                        sp.set(own_slots=int(own.sum()),
+                               live_rows=int(live.sum()),
+                               gathered_rows=self._gathered_rows(
+                                   live + 1, self.b_slots))
+                        if self._ring:
+                            self._set_kv_row_attrs(sp, live + 1, self.b_slots)
+                        if self._stateful:
+                            # slots whose state the tick read and wrote, the
+                            # bytes of one reading of them, and the passes
+                            # the tick's step makes over them (2: the
+                            # one-pass kernel's read and write; 3:
+                            # _ssm_step's)
+                            n = int(tick.active.sum())
+                            sp.set(state_slots=n, state_bytes=n * (
+                                self._exec.state_bytes // self.b_slots),
+                                state_passes=self._exec.state_passes)
+                    # host fetch = device sync; an MoE model's expert counts
+                    # come with the tokens
+                    nxt, counts = self._exec.split_counts(
+                        self._fetch(tick.out, "decode", tick.seq))
+                    if counts is not None and rid_map is not None:
+                        self._set_moe_attrs(sp, counts,
+                                            int(tick.active.sum()))
         t_tok = time.monotonic()   # every token of this tick: its emit stamp
-        active_slots = np.flatnonzero(self._active if own is None else own)
+        active_slots = np.flatnonzero(own)
         trace_count("serve.tokens", float(len(active_slots)))
         with trace_span("serve.emit", tick=self._tick) as sp:
             for slot in active_slots:
@@ -2118,6 +2258,8 @@ class ServingEngine:
                 st.decode_ticks += 1
                 self._lengths[slot] += 1
                 self._last_tok[slot] = tok
+                self._tok_src[slot] = tick.seq
+                self._owed[slot] -= 1
                 self._tokens_out += 1
                 if req.adapter_id is not None:
                     self._adapter_tokens_by_id[req.adapter_id] = (
@@ -2220,6 +2362,8 @@ class ServingEngine:
         self._active[slot] = False
         self._lengths[slot] = 0
         self._last_tok[slot] = 0
+        self._owed[slot] = 0
+        self._eos_live -= st.request.eos_token_id is not None
         self._page_table[slot, :] = 0
         self._lane_temp[slot] = 0.0
         self._lane_top_k[slot] = 0
@@ -2343,15 +2487,13 @@ class ServingEngine:
             if (self.probe_after_ticks is not None and not self._draining
                     and self._quarantined.any()):
                 self._probe_quarantined()
-            # an arrival that finds a tick in flight (launched on the state
-            # the host still holds) is placed by the admission call that
-            # follows that tick, and nothing is launched over it: its
-            # prefill waits for one decode program at most, and no device
-            # work of the live streams is thrown away
-            held = (not self._draining and bool(self._ahead)
-                    and self._arrival_waiting(now)
-                    and self._ahead_current(self._lanes_jnp(),
-                                            self._adapter_operand()))
+            # an arrival that finds one tick in flight is placed by the
+            # admission call that follows that tick, and nothing is launched
+            # over it: its prefill waits for one decode program at most
+            # (more than one in flight: every usable slot was busy when they
+            # were launched, and the prefill goes behind them now)
+            held = (not self._draining and len(self._ahead) == 1
+                    and self._arrival_waiting(now))
             if not self._draining and not held:
                 self._admit(now)
             if self._active.any():
@@ -2548,14 +2690,20 @@ class ServingEngine:
                                      if self._prefix is not None else 0),
             "cow_copies_total": self.cow_copies,
             # decode lookahead: ticks launched before the one ahead of them
-            # was fetched, and those of them found stale and not used
+            # was fetched; those dropped whole (the weights swapped under
+            # them); those taken for some of their slots only (the others
+            # ended under them) or read by no one; those launched under a
+            # mask narrower than the live slots (past a slot's last token);
+            # admissions whose first token reached a tick without the host,
+            # and prefills in flight whose first token is not fetched yet
             "lookahead_launched_total": self.lookahead_launched,
             "lookahead_dropped_total": self.lookahead_dropped,
-            # of a model with a state a slot: stale ticks fetched and their
-            # tokens taken for the slots that went on (it drops none), and
-            # the bytes of the cache's leaves indexed by slot (counted in
-            # kv_pool_bytes_* too)
             "lookahead_stale_taken_total": self.lookahead_stale_taken,
+            "lookahead_past_end_total": self.lookahead_past_end,
+            "prefill_fed_on_device_total": self.prefill_fed_on_device,
+            "first_tokens_in_flight": len(self._firsts),
+            # the bytes of the cache's leaves indexed by slot, of a model
+            # with a state a slot (counted in kv_pool_bytes_* too)
             "state_pool_bytes": self._exec.state_bytes,
             # KV-page tiering (docs/SERVING.md "KV-page tiering"): the
             # demoted ledger and host-tier footprint, plus the cumulative
@@ -2687,6 +2835,10 @@ class ServingEngine:
             ("serve/cow_copies_total", float(self.cow_copies), self._tick),
             ("serve/lookahead_stale_taken_total",
              float(self.lookahead_stale_taken), self._tick),
+            ("serve/lookahead_past_end_total",
+             float(self.lookahead_past_end), self._tick),
+            ("serve/prefill_fed_on_device_total",
+             float(self.prefill_fed_on_device), self._tick),
             ("serve/sampled_admissions_total",
              float(self.sampled_admissions), self._tick),
             ("serve/weight_epoch", float(self._weight_epoch), self._tick),

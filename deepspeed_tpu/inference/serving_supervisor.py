@@ -117,7 +117,8 @@ class ServingSupervisor:
         self._prefix_pages_base = 0
         self._prefix_evictions_base = 0
         self._cow_base = 0
-        self._lookahead_base = (0, 0, 0)   # launched, dropped, stale taken
+        # launched, dropped, stale taken, past a slot's end, fed on device
+        self._lookahead_base = (0, 0, 0, 0, 0)
         self._sampled_base = 0
         self._adapter_admissions_base = 0
         self._spec_ticks_base = 0
@@ -343,6 +344,8 @@ class ServingSupervisor:
         h["lookahead_launched_total"] += self._lookahead_base[0]
         h["lookahead_dropped_total"] += self._lookahead_base[1]
         h["lookahead_stale_taken_total"] += self._lookahead_base[2]
+        h["lookahead_past_end_total"] += self._lookahead_base[3]
+        h["prefill_fed_on_device_total"] += self._lookahead_base[4]
         h["sampled_admissions_total"] += self._sampled_base
         h["adapter_admissions_total"] += self._adapter_admissions_base
         h["spec_verify_slot_ticks_total"] += self._spec_ticks_base
@@ -627,7 +630,9 @@ class ServingSupervisor:
         self._lookahead_base = (
             self._lookahead_base[0] + old.lookahead_launched,
             self._lookahead_base[1] + old.lookahead_dropped,
-            self._lookahead_base[2] + old.lookahead_stale_taken)
+            self._lookahead_base[2] + old.lookahead_stale_taken,
+            self._lookahead_base[3] + old.lookahead_past_end,
+            self._lookahead_base[4] + old.prefill_fed_on_device)
         self._sampled_base += old.sampled_admissions
         self._adapter_admissions_base += old.adapter_admissions
         if old._spec is not None:

@@ -18,10 +18,10 @@ from deepspeed_tpu.observability.trace import configure_tracer
 
 MODELS = {"dense": ("tiny", {}),
           "moe": ("tiny-moe", {"moe_drop_tokens": False})}
-DECODE_ATTRS = {"tick", "slot_rids", "ahead", "dispatch_ms", "live_rows",
-                "gathered_rows"}
+DECODE_ATTRS = {"tick", "slot_rids", "ahead", "own_slots", "dispatch_ms",
+                "live_rows", "gathered_rows"}
 PREFILL_ATTRS = {"rid", "slot", "bucket", "tokens", "shared_tokens",
-                 "gathered_rows"}
+                 "gathered_rows", "queued_behind"}
 MOE_ATTRS = {"moe_live_rows", "moe_rows", "moe_experts_touched",
              "moe_max_load", "moe_pairs", "moe_local_pairs",
              "moe_experts_held"}
@@ -103,14 +103,54 @@ def test_one_launch_span_a_program_and_one_fetch_an_output(engine, tracer,
         launch = by_seq[f.attrs["seq"]]
         assert f.attrs["program"] == launch.attrs["program"]
         assert launch.t0 + launch.dur_s <= f.t0
-        assert f.parent == ("serve.decode" if f.attrs["program"] == "decode"
-                            else "serve.prefill")
+        # a tick's output is read in its tick; a prefill's in its own span
+        # by the plain loop, and with the lookahead when its turn in launch
+        # order comes: in a tick, before an admission (``serve.tick``), or
+        # in the next prefill's span (no third unfetched)
+        assert f.parent in (
+            {"serve.decode"} if f.attrs["program"] == "decode" else
+            {"serve.prefill", "serve.decode", "serve.tick"} if lookahead
+            else {"serve.prefill"})
     # ``ahead``: what the launch left in flight behind the tick being fetched
     ahead = [s.attrs["ahead"] for s in launches]
     assert sum(a >= 1 for a in ahead) == sv.lookahead_launched
     assert max(ahead) == (LOOKAHEAD_TICKS if lookahead else 0)
     assert all(s.attrs["ahead"] == 0 for s in launches
                if s.attrs["program"] != "decode")
+    # ``queued_behind``: programs in flight as a prefill is launched (the
+    # plain loop has fetched everything by then)
+    behind = [s.attrs["queued_behind"] for s in _named(tracer,
+                                                       "serve.prefill")]
+    assert (max(behind) > 0) == lookahead and min(behind) == 0
+
+
+def test_the_new_counters_stand_beside_the_old_on_health(engine, tracer):
+    """``lookahead_past_end_total`` and ``prefill_fed_on_device_total``
+    beside ``lookahead_stale_taken_total``; ``lookahead_launched_total`` and
+    ``lookahead_dropped_total`` keep their names (the benchmark's note reads
+    them); ``own_slots`` on a tick's span is the slots it was emitted for."""
+    sv, _ = _run(engine, True)
+    keys = list(sv.health())
+    at = keys.index("lookahead_launched_total")
+    assert keys[at:at + 6] == [
+        "lookahead_launched_total", "lookahead_dropped_total",
+        "lookahead_stale_taken_total", "lookahead_past_end_total",
+        "prefill_fed_on_device_total", "first_tokens_in_flight"]
+    h = sv.health()
+    assert h["lookahead_dropped_total"] == 0 == h["first_tokens_in_flight"]
+    # seven requests through three slots: the first fill's first was read
+    # as the third was launched (no third unfetched), every other first
+    # token reached its tick on the device; four admissions found a tick in
+    # flight, launched past the end of the slot they took
+    assert h["prefill_fed_on_device_total"] == 6
+    assert h["lookahead_past_end_total"] >= 4
+    decodes = _named(tracer, "serve.decode")
+    emitted = [s.attrs["emitted"] for s in _named(tracer, "serve.emit")]
+    assert [s.attrs["own_slots"] for s in decodes] == emitted
+    plain, _ = _run(engine, False)
+    h = plain.health()
+    assert (h["lookahead_launched_total"], h["lookahead_past_end_total"],
+            h["prefill_fed_on_device_total"]) == (0, 0, 0)
 
 
 def test_the_spans_around_them_carry_what_they_carried(engine, tracer):
@@ -132,7 +172,10 @@ def test_the_spans_around_them_carry_what_they_carried(engine, tracer):
         returned = d.t0 + d.attrs["dispatch_ms"] * 1e-3
         assert all(s.t0 + s.dur_s <= returned + 1e-6 for s in inside)
         mine = [f for f in fetches if d.t0 <= f.t0 < d.t0 + d.dur_s]
-        assert len(mine) == 1 and mine[0].t0 >= returned - 1e-6
+        # its own tick's, after the first tokens whose turn came before it
+        assert [f.attrs["program"] for f in mine].count("decode") == 1
+        assert mine[-1].attrs["program"] == "decode"
+        assert mine[0].t0 >= returned - 1e-6
 
 
 @pytest.mark.parametrize("lookahead", [True, False],

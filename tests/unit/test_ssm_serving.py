@@ -3,8 +3,9 @@
 input in every block, fixed multipliers) through the model and the serving
 engine: the mixer's two forms against each other and against the plain
 reference, the cache's two leaves with no page axis, admission's reset, the
-decode lookahead that never un-launches a tick for a slot that goes on, the
-span attrs, and the mechanisms that refuse such a model by name."""
+decode lookahead that never un-launches a tick for a slot that goes on and
+feeds an admission's first token to the next tick on the device, the span
+attrs, and the mechanisms that refuse such a model by name."""
 import functools
 
 import jax
@@ -18,6 +19,8 @@ from deepspeed_tpu.inference.execution import MeshExecutor
 from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+
+from .test_serving_lookahead import bare_fetches, launches_and_fetches
 
 SERVE_KW = dict(b_slots=3, page_size=8, max_model_len=96)
 
@@ -285,17 +288,96 @@ def test_lookahead_on_is_lookahead_off_with_a_slot_expired_in_flight(engine):
 
 
 def test_dropping_a_launched_tick_would_advance_the_state_twice(engine):
-    """The rule the K/V-row models keep (drop what was launched on another
-    state) gives wrong tokens for a state: the test above fails on a build
-    that drops ticks for this model."""
-    plain, _ = _drive(engine.serving(lookahead=False, **SERVE_KW),
-                      _deadline_mix())
+    """Why a launched tick is never un-launched for a slot that goes on
+    (one rule for every model now): a tick that ran has advanced every live
+    slot's state, so with the queue thrown away under live slots the ticks
+    launched in its place advance it again and the tokens are wrong."""
+    def reqs():
+        return _requests(3, seed=7, new=(24, 30))
+
+    plain, _ = _drive(engine.serving(lookahead=False, **SERVE_KW), reqs())
     sv = engine.serving(**SERVE_KW)
-    sv._stateful = False
-    dropped, _ = _drive(sv, _deadline_mix())
-    assert sv.health()["lookahead_dropped_total"] > 0
-    assert dropped["r0"] == plain["r0"]
-    assert any(dropped[r] != plain[r] for r in ("r1", "r2"))
+    for q in reqs():
+        sv.submit(q)
+    for now in range(5):
+        sv.step(now=float(now))
+    assert len(sv._ahead) > 1
+    sv._ahead.clear()       # what no path of the engine does
+    dropped, _ = _drive(sv, [], start=5.0)
+    assert any(dropped[r] != plain[r] for r in plain)
+    kept, _ = _drive(engine.serving(**SERVE_KW), reqs())
+    assert kept == plain
+
+
+def test_backlog_with_a_slot_ending_every_tick_keeps_the_plain_streams(engine):
+    """The state model's case of ``test_serving_lookahead.py``'s: nine
+    requests for three slots, one ending in every tick; a slot that ends by
+    length is under no later tick's mask (a masked token changes nothing of
+    its state), the request admitted behind the ticks in flight resets the
+    state through its prefill and feeds its first token to the next tick on
+    the device.  Token for token the plain loop's, and greedy ``forward``'s;
+    no fetch stands between two launches."""
+    from deepspeed_tpu.observability import configure_tracer, get_tracer
+
+    def reqs():
+        out = _requests(9, seed=5, new=(6, 7))
+        for i, q in enumerate(out):
+            q.max_new_tokens = 2 + i if i < 3 else 4
+        return out
+
+    plain = {r.rid: list(r.output_ids) for r in
+             engine.serving(lookahead=False, **SERVE_KW).run(reqs())}
+    sv = engine.serving(**SERVE_KW)
+    configure_tracer(enabled=True)
+    try:
+        got = {r.rid: list(r.output_ids) for r in sv.run(reqs())}
+        events = launches_and_fetches(get_tracer().recorder.snapshot())
+    finally:
+        configure_tracer(enabled=False)
+        get_tracer().reset()
+    assert got == plain
+    cfg = engine.model.config
+    for q in reqs():
+        assert _is_greedy(cfg, engine.params, q.input_ids, got[q.rid]), q.rid
+    h = sv.health()
+    assert h["lookahead_dropped_total"] == 0
+    assert h["lookahead_past_end_total"] >= 5
+    assert h["prefill_fed_on_device_total"] >= 7
+    assert h["first_tokens_in_flight"] == 0 and not sv._ahead
+    assert bare_fetches(events) == [sv._launch_seq]
+
+
+def test_a_deadline_passes_with_the_first_token_still_on_the_device(engine):
+    """A request expires between its prefill's launch and its first token's
+    fetch: its result holds no token, the tick launched behind the prefill
+    is taken for the other slot, and the slot's next request starts from a
+    reset state."""
+    def drive(lookahead):
+        sv = engine.serving(lookahead=lookahead, **SERVE_KW)
+        a, b, c = _requests(3, seed=13, new=(10, 12))
+        b.arrival_time, b.deadline_s = 2.0, 0.5
+        c.arrival_time = 4.0
+        for q in (a, b, c):
+            sv.submit(q)
+        sv.step(now=0.0)
+        sv.step(now=1.0)
+        sv.step(now=2.0)            # b is admitted behind the tick in flight
+        if lookahead:
+            assert [f.slot for f in sv._firsts] == [1]
+        now = 3.0                   # ... and expires unfetched
+        while sv.step(now=now):
+            now += 1.0
+        return sv, {r.rid: (list(r.output_ids), r.finish_reason)
+                    for r in sv.take_results()}
+
+    _, plain = drive(False)
+    sv, ahead = drive(True)
+    assert ahead["r1"] == ([], "deadline")
+    assert plain["r1"][1] == "deadline"
+    assert {r: ahead[r] for r in ("r0", "r2")} == {
+        r: plain[r] for r in ("r0", "r2")}
+    assert sv.health()["lookahead_dropped_total"] == 0
+    assert sv.page_accounting()["balanced"]
 
 
 def test_lookahead_on_is_lookahead_off_with_update_params_in_flight(engine,
