@@ -259,7 +259,8 @@ def extract_tag_names(modules: Sequence[ModuleInfo],
     ``trace_tags(...)`` call, plus the implicit ``trace_id``/``rid`` keys
     a ``trace_context`` with positional identity arguments injects, plus
     mid-span attrs set through ``<span>.set(key=...)`` (the slot→rid map
-    rides that path).  Keyword'd ``.set`` calls are matched by method
+    rides that path) or built by a ``*_attrs`` function a span is given
+    whole.  Keyword'd ``.set`` calls are matched by method
     name — in this tree only span contexts take keyword ``set`` args, and
     a future non-span hit just prompts a registry row or a rename.  Tag
     keys become Perfetto ``args`` keys and fleet-trace filter terms — the
@@ -267,6 +268,22 @@ def extract_tag_names(modules: Sequence[ModuleInfo],
     out: List[CodeName] = []
     for mod in modules:
         for node in ast.walk(mod.tree):
+            if (isinstance(node, ast.FunctionDef)
+                    and node.name.endswith("_attrs")):
+                # a function that builds the attrs a span is then given
+                # whole (``sp.set(**layout.decode_attrs(...))``): the string
+                # keys of its dict literals, the keywords of its ``update``s
+                for sub in ast.walk(node):
+                    if isinstance(sub, ast.Dict):
+                        keys = [_const_str(k) for k in sub.keys]
+                    elif (isinstance(sub, ast.Call)
+                            and _name_of_call(sub) == "update"):
+                        keys = [kw.arg for kw in sub.keywords]
+                    else:
+                        continue
+                    out.extend(CodeName(k, mod.relpath, sub.lineno,
+                                        dynamic=False) for k in keys if k)
+                continue
             if not isinstance(node, ast.Call):
                 continue
             fname = _name_of_call(node)

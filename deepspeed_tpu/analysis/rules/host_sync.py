@@ -35,6 +35,7 @@ from ._util import dotted_name, qualname, walk_scoped
 
 DEFAULT_HOST_MODULES: Tuple[str, ...] = (
     "deepspeed_tpu/inference/fleet.py",
+    "deepspeed_tpu/inference/page_pool.py",
     "deepspeed_tpu/inference/serving_supervisor.py",
 )
 
@@ -48,10 +49,7 @@ DEFAULT_HOST_FUNCTIONS: Mapping[str, Tuple[str, ...]] = {
         "ServingEngine._usable_slots",
         "ServingEngine._arrival_abs",
         "ServingEngine._pages_needed",
-        "ServingEngine._alloc_pages",
-        "ServingEngine._share_page",
-        "ServingEngine._drop_page",
-        "ServingEngine._leak_pages",
+        "ServingEngine._release",
         "ServingEngine.page_accounting",
         "ServingEngine._prefix_lookup",
         "ServingEngine._reclaim_cached",

@@ -1,0 +1,162 @@
+"""What a slot's cache is made of, said once (docs/SERVING.md "What a slot
+holds"): arithmetic over the model's config and the engine's geometry.
+:class:`~.execution.MeshExecutor` builds a :class:`CacheLayout`;
+:class:`~.serving.ServingEngine` reads it from there (and builds an equal
+one first, to refuse what it must before anything is compiled).  It says
+how each set of leaves a slot holds is addressed (pages of the slot's table,
+a ring of pages in the window pool, a row a slot) and from that the pools of
+pages to allocate and what ``init_paged_cache`` is called with; which
+mechanisms work on it (:data:`REFUSED`: mechanism x kind of cache, the kinds
+``models.transformer.cache_kind``'s); and what a tick and a prompt read of
+it, as the span attrs the benchmark's per-layer readers take.  The next kind
+of cache is a row here and its forward in ``models/``, not the scheduler.
+"""
+from __future__ import annotations
+
+from typing import Any, Dict, Tuple
+
+import numpy as np
+
+from ..models.transformer import (block_read_rows, cache_kind,
+                                  causal_walk_steps, kind_layers,
+                                  paged_read_rows, ssm_scan_chunks,
+                                  window_read_rows, window_ring_pages)
+
+__all__ = ["CacheLayout", "REFUSED"]
+
+# the kinds whose cache is more than K and V pages of one pool
+_NOT_PAGES_ALONE = ("window", "latent", "state")
+
+# mechanism -> the kinds of cache (``cache_kind``) it cannot work on: five
+# the executor asks for, then the engine's two
+REFUSED: Dict[str, Tuple[str, ...]] = {
+    "tensor-sharded heads (tp > 1)": _NOT_PAGES_ALONE,
+    "copy-on-write page snapshots (prefix_cache=True)": _NOT_PAGES_ALONE,
+    "KV-page tiering": _NOT_PAGES_ALONE,
+    "the int8 pool": _NOT_PAGES_ALONE,
+    # per-slot factors ride a scan over one stack of equal layers
+    "multi-tenant adapters": _NOT_PAGES_ALONE + ("grouped",),
+    "prefix sharing (prefix_cache=True)": _NOT_PAGES_ALONE,
+    "speculative decoding": _NOT_PAGES_ALONE,
+}
+
+
+class CacheLayout:
+    """The cache of ``b_slots`` slots of ``pages_per_slot`` pages of
+    ``page_size`` tokens over ``num_pages`` pages, for the model ``cfg``."""
+
+    def __init__(self, cfg, b_slots: int, page_size: int,
+                 pages_per_slot: int, num_pages: int):
+        self.cfg = cfg
+        self.b_slots, self.page_size = int(b_slots), int(page_size)
+        self.pages_per_slot = int(pages_per_slot)
+        self.kind, self.description = cache_kind(cfg)
+        # window layers: a ring a slot in a pool of its own, reused in place
+        self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
+                           if self.kind == "window" else 0)
+        self.window_pages = self.ring_pages and 1 + b_slots * self.ring_pages
+        # state-space layers: two leaves a row a slot beside the pages
+        self.stateful = self.kind == "state"
+        # a pool of pages each: ``(pages, page 0 its trash page; those a
+        # slot's row of its table names)``.  The slots' table first, then
+        # what is taken a whole row a slot and never shared
+        self.pools: Tuple[Tuple[int, int], ...] = (
+            (int(num_pages), self.pages_per_slot),)
+        if self.ring_pages:
+            self.pools += ((self.window_pages, self.ring_pages),)
+        self.pool_kw = {"window_pages": self.window_pages or None,
+                        "slots": self.b_slots}
+        # K/V head rows a token row of each kind of layer stands for, over
+        # the kind's layers (the kv_rows_* span attrs)
+        self.kind_heads = ({k: g.kv_heads * n
+                            for k, (g, n) in kind_layers(cfg).items()}
+                           if self.kind == "window" else {})
+        # a prompt of such a model attends within itself, gathers nothing back
+        self.block_attends_itself = self.kind in ("window", "latent")
+        # a slot's bytes of state and the passes the tick's step makes over
+        # them: the executor that made the pool and traced the tick says
+        self.state_slot_bytes = self.state_passes = 0
+
+    # ------------------------------------------------------- mechanisms
+
+    def allows(self, mechanism: str) -> bool:
+        return self.kind not in REFUSED[mechanism]
+
+    def refuse(self, mechanism: str, on: Any = True) -> None:
+        """Raise, by both names, where ``mechanism`` is asked for (``on``)
+        and cannot work on this cache."""
+        if on and not self.allows(mechanism):
+            raise NotImplementedError(
+                f"{mechanism} does not support a model with "
+                f"{self.description}")
+
+    # ------------------------------------------------ what a call reads
+
+    def _kv_row_attrs(self, full: int, full_live: int, window: int,
+                      window_live: int) -> Dict[str, int]:
+        """Token rows read and live a kind of layer -> K/V head rows."""
+        hf, hw = self.kind_heads["full"], self.kind_heads["window"]
+        return {"kv_rows_full": full * hf, "kv_live_rows_full": full_live * hf,
+                "kv_rows_window": window * hw,
+                "kv_live_rows_window": window_live * hw}
+
+    def decode_attrs(self, lengths, slots: int) -> Dict[str, Any]:
+        """The ``serve.decode`` span attrs of a tick of ``slots`` slots whose
+        live ones hold ``lengths`` rows, the row being written counted in.
+        ``gathered_rows``: K/V rows its read covers a layer, each slot's own
+        pages in whole steps.  Two kinds of layer: K/V head rows read and
+        live a kind (a window layer reads the ring pages under its window).
+        A state a slot: the slots whose state the tick read and wrote, the
+        bytes of one reading, its step's passes."""
+        lengths = np.asarray(lengths, np.int64)
+        rows = paged_read_rows(lengths, self.page_size, self.pages_per_slot,
+                               slots)
+        attrs: Dict[str, Any] = {"gathered_rows": rows}
+        if self.kind == "window":
+            W = self.cfg.window_size
+            attrs.update(self._kv_row_attrs(
+                rows, int(lengths.sum()),
+                window_read_rows(lengths, self.page_size, W, slots),
+                int(np.minimum(lengths, W).sum())))
+        if self.stateful:
+            attrs.update(state_slots=len(lengths),
+                         state_bytes=len(lengths) * self.state_slot_bytes,
+                         state_passes=self.state_passes)
+        return attrs
+
+    def tick_attrs(self, pools) -> Dict[str, int]:
+        """The ``serve.tick`` span attrs of two kinds of layer: pages of each
+        of the engine's ``pools`` (:attr:`pools`' order) that hold a
+        request's K/V."""
+        if self.kind != "window":
+            return {}
+        return {"pages_full": pools[0].referenced(),
+                "pages_window": pools[1].referenced()}
+
+    def prefill_attrs(self, bucket: int, tokens: int, shared: int
+                      ) -> Dict[str, Any]:
+        """The ``serve.prefill`` span attrs of a prompt's ``tokens`` real
+        tokens in a block of ``bucket`` behind ``shared`` tokens of shared
+        pages.  ``gathered_rows`` as a tick's, or 0 where the block attends
+        within itself: then ``walk_steps``, the chunk steps its full or
+        latent layers run as far as its tokens reach, beside the bucket's,
+        and what a block reads of itself a kind of layer.  A state a slot:
+        the scan's chunks that hold a real token beside the bucket's, and
+        whether the call resets its slot's state."""
+        attrs: Dict[str, Any] = {"gathered_rows": (
+            0 if self.block_attends_itself else paged_read_rows(
+                [shared + tokens], self.page_size, self.pages_per_slot, 1))}
+        if self.stateful:
+            attrs.update(
+                scan_chunks=ssm_scan_chunks(self.cfg, bucket, tokens),
+                scan_chunks_bucket=ssm_scan_chunks(self.cfg, bucket),
+                state_reset=int(shared == 0))
+        if self.block_attends_itself:
+            attrs.update(walk_steps=causal_walk_steps(bucket, tokens),
+                         walk_steps_bucket=causal_walk_steps(bucket))
+        if self.kind == "window":
+            attrs.update(self._kv_row_attrs(
+                block_read_rows(bucket, tokens=tokens), tokens,
+                block_read_rows(bucket, self.cfg.window_size, tokens=tokens),
+                tokens))
+        return attrs

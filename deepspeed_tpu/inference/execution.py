@@ -1,16 +1,14 @@
 """Mesh-wide execution tier of the serving engine.
 
-``serving.py`` used to own BOTH halves of the serving loop: per-request
-host scheduling (admission, page tables, the prefix index, deadlines,
-journaling hooks) AND the device-facing state (the paged KV pool and the
-jitted fixed-shape programs).  The multi-chip refactor splits them:
-:class:`~.serving.ServingEngine` keeps scheduling — pure Python over
-numpy page tables — and :class:`MeshExecutor` owns everything that
-touches a device: the pool and its :class:`~jax.sharding.NamedSharding`
-placement, the decode / bucketed-prefill / COW programs, and the device
-copy of the per-slot sampling lanes.  Page-table scatter/gather,
-copy-on-write, sampling lanes and the speculative draft pool all ride
-the sharded programs unchanged, because they only ever see this surface.
+:class:`~.serving.ServingEngine` keeps scheduling — admission, page tables,
+the prefix index, deadlines: pure Python over numpy — and
+:class:`MeshExecutor` owns everything that touches a device: the pool (made
+as its :class:`~.cache_layout.CacheLayout` says) and its
+:class:`~jax.sharding.NamedSharding` placement, the decode /
+bucketed-prefill / COW programs, and the device copy of the per-slot
+sampling lanes.  Page-table scatter/gather, copy-on-write, sampling lanes
+and the speculative draft pool all ride the sharded programs unchanged,
+because they only ever see this surface.
 
 Sharding layout (GSPMD over the ``parallel/mesh.py`` named mesh — the
 same NamedSharding/PartitionSpec pattern training and ``generate()``
@@ -52,12 +50,13 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.transformer import (PAGED_POOL_KEYS, SSM_POOL_KEYS,
                                   SSM_STATE_PASSES, cow_copy_pool,
-                                  expert_counts_shape, is_hybrid, is_latent,
-                                  is_ssm, paged_pool_cache, paged_pool_order,
+                                  expert_counts_shape, is_hybrid,
+                                  paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, per_layer_leaves,
-                                  ssm_step_path, window_ring_pages)
+                                  ssm_step_path)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
+from .cache_layout import CacheLayout
 from .kv_tiering import extract_pool_page, inject_pool_page
 from .sampling import position_keys, sample_tokens
 
@@ -251,6 +250,13 @@ class MeshExecutor:
         # its decode and prefill programs append the rows each expert
         # computed to the token output (split_counts); None for any other
         self.moe_shape = expert_counts_shape(cfg)
+        self.pages_per_slot = int(pages_per_slot or max(
+            1, (self.num_pages - 1) // self.b_slots))
+        # what a slot's cache is made of (inference/cache_layout.py).  What
+        # moves, shares or shards pages as K and V of some heads in ONE pool
+        # refuses any other by name instead of serving a wrong answer
+        self.layout = layout = CacheLayout(
+            cfg, b_slots, page_size, self.pages_per_slot, num_pages)
         self.tp = 1
         if mesh is not None:
             if "model" not in mesh.axis_names:
@@ -260,66 +266,25 @@ class MeshExecutor:
                     "initialize_serving_mesh), got axes "
                     f"{tuple(mesh.axis_names)}")
             self.tp = int(mesh.shape["model"])
-            if self.tp > 1 and not is_latent(cfg) \
-                    and cfg.kv_heads % self.tp != 0:
-                raise ValueError(
-                    f"kv_heads={cfg.kv_heads} not divisible by the mesh's "
-                    f"model axis ({self.tp}): the paged KV pool shards its "
-                    "head dim over 'model' (paged_cache_specs) — pick tp "
-                    "dividing kv_heads or replicate with tp=1")
-        # a model with window layers keeps a second pool, its slots' rings
-        # (docs/SERVING.md "Two kinds of layer"): what moves or shares
-        # pages of ONE pool says so instead of serving a wrong answer
-        self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
-                           if is_hybrid(cfg) else 0)
-        # the window pool: a ring a slot, and its own trash page
-        self.window_pages = (1 + self.b_slots * self.ring_pages
-                             if self.ring_pages else 0)
-        # what moves, shares or shards pages as K and V of some heads in
-        # ONE pool says so instead of serving a wrong answer
-        unlike = None
-        if self.ring_pages:
-            unlike = ("window layers (layer_pattern): its window layers "
-                      "keep a ring of pages a slot in a pool of their own")
-        elif is_latent(cfg):
-            # one leaf of latent rows with no head axis, read back only by
-            # one token a slot (docs/SERVING.md "A latent cache")
-            unlike = ("latent attention (kv_lora_rank): its cache rows have "
-                      "no head axis to shard or scale, and a block of more "
-                      "than one token attends within itself, so it has to "
-                      "start its slot")
-        elif is_ssm(cfg):
-            # two leaves a row a slot beside the pages (docs/SERVING.md "A
-            # state a slot"): a page's contents say nothing of the state
-            # that went with them
-            unlike = ("state-space layers (a state a slot): a slot's state "
-                      "is one tensor that no page holds, so a page copied, "
-                      "parked, rescaled or split by head leaves it behind")
-        if unlike:
-            for on, what in ((self.tp > 1, "tensor-sharded heads (tp > 1)"),
-                             (prefix_cache, "copy-on-write page snapshots "
-                              "(prefix_cache=True)"),
-                             (host_tier, "KV-page tiering"),
-                             (kv_dtype is not None, "the int8 pool"),
-                             (adapters is not None, "multi-tenant adapters")):
-                if on:
-                    raise NotImplementedError(
-                        f"{what} does not support a model with {unlike}")
-        # the prefill program of a model with a state a slot is told which
-        # slot's row it resets and writes
-        self.stateful = is_ssm(cfg)
-        # and its decode tick holds one of two steps, chosen where the tick
-        # is traced from the backend and the state's shape: "one_pass" (the
-        # kernel: the leaf in place, a read and a write of a live slot's
-        # state a layer) or "xla" (three passes); None for any other model
+        layout.refuse("tensor-sharded heads (tp > 1)", self.tp > 1)
+        layout.refuse("copy-on-write page snapshots (prefix_cache=True)",
+                      prefix_cache)
+        layout.refuse("KV-page tiering", host_tier)
+        layout.refuse("the int8 pool", kv_dtype is not None)
+        layout.refuse("multi-tenant adapters", adapters is not None)
+        if self.tp > 1 and cfg.kv_heads % self.tp != 0:
+            raise ValueError(
+                f"kv_heads={cfg.kv_heads} not divisible by the mesh's "
+                f"model axis ({self.tp}): the paged KV pool shards its "
+                "head dim over 'model' (paged_cache_specs) — pick tp "
+                "dividing kv_heads or replicate with tp=1")
+        # the decode tick of a model with a state a slot holds one of two
+        # steps, chosen where it is traced from the backend and the state's
+        # shape: "one_pass" (the kernel: the leaf in place, a read and a write
+        # of a live slot's state a layer) or "xla" (three passes); else None
         self.ssm_step = ssm_step_path(cfg)
         self.state_passes = SSM_STATE_PASSES.get(self.ssm_step, 0)
-        if self.ring_pages:
-            pool_kw = {"dtype": dtype, "window_pages": self.window_pages}
-        elif self.stateful:
-            pool_kw = {"dtype": dtype, "slots": self.b_slots}
-        else:
-            pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype}
+        pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype, **layout.pool_kw}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
         # (k, v) full precision, (k, v, k_scale, v_scale) quantized — every
@@ -335,7 +300,6 @@ class MeshExecutor:
         self._pool_keys = tuple(k for k in PAGED_POOL_KEYS if k in shapes)
         self.quantized = "k_scale" in shapes
         self._pool_specs = tuple(specs[k] for k in self._pool_keys)
-        self._kv_spec = specs[self._pool_keys[0]]
         # The program that makes the pool, compiled before it runs: what it
         # reports of its results is how the device will store each leaf, so
         # the weights can be placed against the decode program while no pool
@@ -360,7 +324,7 @@ class MeshExecutor:
         # be stored differently, so a pool a kind gives the order a leaf
         orders = [paged_pool_order(f) for f in make_pool.output_formats]
         self.pool_order = (dict(zip(self._pool_keys, orders))
-                           if self.ring_pages else orders[0])
+                           if len(layout.pools) > 1 else orders[0])
         self._decode_prog = self._build_decode()
         # The weights, placed ONCE in the form the decode program consumes
         # (docs/SERVING.md "Weight placement"), as the pool is stored the
@@ -369,8 +333,6 @@ class MeshExecutor:
         # asks for.  Every program of the engine is compiled against this
         # placement.  ``tree`` is rebound at each step, so a tree that was
         # handed over is freed as it is replaced, before the pool exists.
-        self.pages_per_slot = int(pages_per_slot or max(
-            1, (self.num_pages - 1) // self.b_slots))
         # (first onto the auto-TP shardings generate() uses; a tree already
         # committed to this mesh, InferenceEngine.serving()'s, passes through)
         tree = place_params(params() if callable(params) else params, mesh)
@@ -431,6 +393,8 @@ class MeshExecutor:
         self.state_bytes = sum(
             int(a.nbytes) for k, a in zip(self._pool_keys, self.pools)
             if k in SSM_POOL_KEYS)
+        layout.state_slot_bytes = self.state_bytes // self.b_slots
+        layout.state_passes = self.state_passes
         # device copy of the lane vectors, rebuilt only when a lane
         # changes (admission / retirement) — unlike lengths/last_tok the
         # lanes are constant across a request's whole decode, so the
@@ -453,25 +417,6 @@ class MeshExecutor:
                                         np.int32), self._token_sharding())
         self.feed_lane(self.feed_lane(np.zeros((self.b_slots,), np.int32),
                                       first, 0), first, 0)
-
-    # k/v pool views: the canonical state is the `pools` tuple (programs
-    # consume/produce it whole so donation covers every leaf); kpool/vpool
-    # stay as named accessors because tests and health checks read them
-    @property
-    def kpool(self):
-        return self.pools[0]
-
-    @kpool.setter
-    def kpool(self, value):
-        self.pools = (value,) + self.pools[1:]
-
-    @property
-    def vpool(self):
-        return self.pools[1]
-
-    @vpool.setter
-    def vpool(self, value):
-        self.pools = self.pools[:1] + (value,) + self.pools[2:]
 
     # ------------------------------------------------------------ programs
 
@@ -581,10 +526,10 @@ class MeshExecutor:
         def vec(dtype, n=B):
             return jax.ShapeDtypeStruct((n,), dtype)
 
-        table = jax.ShapeDtypeStruct((B, self.pages_per_slot), jnp.int32)
-        if self.ring_pages:
-            table = (table, jax.ShapeDtypeStruct((B, self.ring_pages),
-                                                 jnp.int32))
+        # the slots' table, or a table a pool (full, ring)
+        table = tuple(jax.ShapeDtypeStruct((B, per_slot), jnp.int32)
+                      for _, per_slot in self.layout.pools)
+        table = table if len(table) > 1 else table[0]
         counts = self.moe_shape[0] * self.moe_shape[1] if self.moe_shape else 0
         args = (params, self._pool_avals, table, vec(jnp.int32),
                 jax.ShapeDtypeStruct((B + counts,), jnp.int32,
@@ -642,7 +587,8 @@ class MeshExecutor:
         apply_paged, with_counts = self._apply_paged, self._with_counts
         keys = self._pool_keys
 
-        stateful = self.stateful
+        # a state a slot: the program is told whose row it resets and writes
+        stateful = self.layout.stateful
 
         def prog(params, pools, pt_row, tokens, n_real, start,
                  temp, top_k, top_p, seed, *rest):
@@ -779,7 +725,7 @@ class MeshExecutor:
         if self.adapters is not None:
             args += (adapter_row if adapter_row is not None
                      else self._adapter_zero_row(),)
-        elif self.stateful:
+        elif self.layout.stateful:
             args += (jnp.int32(slot),)
         t0 = account(self.catalog, f"prefill_{s_pad}", prog, args)
         nxt, self.pools = prog(*args)
@@ -916,8 +862,8 @@ class MeshExecutor:
     # ------------------------------------------------------------- health
 
     def pool_alive(self) -> bool:
-        dead = getattr(self.kpool, "is_deleted", None)
-        return not (dead and self.kpool.is_deleted())
+        dead = getattr(self.pools[0], "is_deleted", None)
+        return not (dead and self.pools[0].is_deleted())
 
     def mesh_info(self) -> Dict[str, Any]:
         """Static facts for health()/gauges: device count and the

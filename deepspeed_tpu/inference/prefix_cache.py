@@ -24,7 +24,7 @@ holding their K/V, keyed by a rolling (chained) hash over the whole prefix:
   boundary page under ``(prev_key, partial_tokens)``.  The page is still
   mutable (its owner keeps appending generated tokens to later rows), so a
   matching request never maps it directly: it **copy-on-writes** the page
-  into a private page of its own (``ServingEngine._cow_prog``) and
+  into a private page of its own (``MeshExecutor.cow``) and
   overwrites every row past the matched prefix itself before causality can
   expose it.  Matching is longest-common-prefix, so a partial entry also
   serves requests that diverge inside the chunk.

@@ -127,11 +127,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..models.transformer import (PAGE_SIZE, block_read_rows,
-                                  causal_walk_steps, is_hybrid, is_latent,
-                                  is_ssm, kind_layers, paged_read_rows,
-                                  ssm_scan_chunks, window_read_rows,
-                                  window_ring_pages)
+from ..models.transformer import PAGE_SIZE
 from ..observability.device_profiler import (device_trace_unit,
                                              maybe_capture_from_env)
 from ..observability.program_stats import ProgramCatalog
@@ -142,9 +138,11 @@ from ..resilience import (SITE_SERVE_ADMIT, SITE_SERVE_DECODE,
                           SITE_SERVE_PREFILL, SITE_SERVE_TICK, maybe_fire)
 from ..utils.logging import log_dist, logger
 from .adapters import AdapterRegistry
+from .cache_layout import CacheLayout
 from .engine import InferenceEngine
 from .execution import MeshExecutor
 from .kv_tiering import HostTier
+from .page_pool import PagePool
 from .prefix_cache import PrefixIndex, PrefixMatch
 from .sampling import SamplingParams, as_lanes
 from .speculative import SpeculativeConfig, SpeculativeDecoder
@@ -432,47 +430,16 @@ class ServingEngine:
                 f"num_pages={self.num_pages} cannot hold one full slot "
                 f"({self.pages_per_slot} pages of {self.page_size} tokens "
                 f"+ the trash page)")
-        # layers of two kinds (docs/SERVING.md "Two kinds of layer"): a
-        # window layer keeps a ring of ``ring`` pages a slot in a pool of
-        # its own.  What shares, moves or re-reads pages of one pool is
-        # refused by name; prefix sharing is on by default everywhere else
-        cfg = model.config
-        self._ring = (window_ring_pages(cfg.window_size, self.page_size)
-                      if is_hybrid(cfg) else 0)
-        # a latent cache (docs/SERVING.md "A latent cache"): a block of more
-        # than one token attends within itself over its expanded keys and
-        # values, so it has to start its slot
-        self._latent = is_latent(cfg)
-        # a state a slot (docs/SERVING.md "A state a slot"): every tick
-        # advances each live slot's recurrent state whole and admission
-        # resets it through the prefill at position 0
-        self._stateful = is_ssm(cfg)
-        unlike = None
-        if self._ring:
-            unlike = ("window layers (layer_pattern): a window layer's ring "
-                      "holds its slot's last positions only, so there is no "
-                      "page of it to share, park or verify against")
-        elif self._latent:
-            unlike = ("latent attention (kv_lora_rank): only one token a "
-                      "slot reads its latent rows back, so a prompt's tail "
-                      "behind shared pages, or a block of draft tokens, has "
-                      "nothing to attend through")
-        elif self._stateful:
-            unlike = ("state-space layers (a state a slot): a slot's state "
-                      "after a prefix, a parked page or a rejected draft "
-                      "token is held nowhere, so there is nothing to start "
-                      "a tail from or to go back to")
-        if unlike:
-            for on, what in (
-                    (prefix_cache, "prefix sharing (prefix_cache=True)"),
-                    (host_tier_pages is not None, "KV-page tiering"),
-                    (speculative is not None, "speculative decoding")):
-                if on:
-                    raise NotImplementedError(
-                        f"{what} does not support a model with {unlike}")
+        # what shares, parks or re-reads pages of one K/V pool refuses, by
+        # name and before anything is compiled, a cache that is more than that
+        # (inference/cache_layout.py); prefix sharing is on everywhere else
+        layout = CacheLayout(model.config, self.b_slots, self.page_size,
+                             self.pages_per_slot, self.num_pages)
+        layout.refuse("prefix sharing (prefix_cache=True)", prefix_cache)
+        layout.refuse("KV-page tiering", host_tier_pages is not None)
+        layout.refuse("speculative decoding", speculative is not None)
         if prefix_cache is None:
-            prefix_cache = not (self._ring or self._latent
-                                or self._stateful)
+            prefix_cache = layout.allows("prefix sharing (prefix_cache=True)")
         self.monitor = monitor
         self.watchdog = watchdog
         # decode lookahead (docs/SERVING.md "Decode lookahead"): launch tick
@@ -559,6 +526,14 @@ class ServingEngine:
                                   pages_per_slot=self.pages_per_slot)
         # auto-TP-sharded on a mesh, and held as the decode program reads it
         self.params = self._exec.params
+        # the executor's own (it knows a state's bytes too), and an allocator
+        # a pool of pages it lists, each with the table the programs are fed
+        # from: the slots' pages, taken as a request needs them and shared
+        # by reference, then what a slot takes a whole row of at admission
+        self._layout = self._exec.layout
+        self._pools = [PagePool(pages, self.b_slots, per_slot)
+                       for pages, per_slot in self._layout.pools]
+        self._pages = self._pools[0]
         # ---- multi-tenant adapter serving (docs/SERVING.md "Multi-tenant
         # adapter serving"): with a registry attached, every decode/prefill
         # /verify program takes the per-slot LoRA factor stacks as ONE
@@ -586,13 +561,6 @@ class ServingEngine:
         # accounting, prefix sharing, COW, tiering and epoch stamps are
         # dtype-blind
         self.kv_dtype = self._exec.kv_dtype
-        self._free_pages: List[int] = list(range(self.num_pages - 1, 0, -1))
-        # per-page reference counts (page 0, the trash page, is never
-        # counted): 0 = free or quarantined, >0 = held by slots and/or the
-        # prefix index.  Pages return to the free list only at refcount 0,
-        # so an indexed page's contents can never be recycled under a
-        # reader (docs/SERVING.md "Cross-request KV reuse").
-        self._refcount = np.zeros((self.num_pages,), np.int64)
         self._prefix = (PrefixIndex(self.page_size,
                                     max_entries=prefix_index_entries)
                         if prefix_cache else None)
@@ -628,27 +596,11 @@ class ServingEngine:
         self.kv_flushed_pages = 0     # HBM prefix pages flushed by updates
         self.kv_flushed_slabs = 0     # host-tier slabs flushed by updates
         self._refresh_lat_s: Deque[float] = deque(maxlen=2048)
-        self._page_epoch = np.zeros((self.num_pages,), np.int64)
         self.prefix_hits = 0
         self.prefix_misses = 0
         self.prefix_shared_tokens = 0
         self.prefix_pages_shared = 0   # full pages mapped instead of prefilled
         self.cow_copies = 0
-        self._pages_hwm = 0            # high-water mark of occupied pages
-        self._page_table = np.zeros((self.b_slots, self.pages_per_slot),
-                                    np.int32)
-        # the window pool's allocator: page 0 its trash page, a slot's ring
-        # taken whole at admission and given back when the request ends (the
-        # ring is reused in place as positions fall out of the window)
-        self._free_ring: List[int] = list(range(
-            self._exec.window_pages - 1, 0, -1))
-        self._ring_table = np.zeros((self.b_slots, self._ring), np.int32)
-        self._quarantined_ring: List[int] = []
-        if self._ring:
-            # K/V head rows a token row of each kind stands for, over the
-            # kind's layers (the kv_rows_* span attrs)
-            self._kind_heads = {kind: g.kv_heads * n
-                                for kind, (g, n) in kind_layers(cfg).items()}
         self._lengths = np.zeros((self.b_slots,), np.int32)
         self._last_tok = np.zeros((self.b_slots,), np.int32)
         self._active = np.zeros((self.b_slots,), bool)
@@ -684,7 +636,6 @@ class ServingEngine:
         self._t0 = time.monotonic()
         # ---- resilience state (docs/SERVING.md "Failure handling")
         self._quarantined = np.zeros((self.b_slots,), bool)
-        self._quarantined_pages: List[int] = []   # leaked-and-accounted
         self._slot_failures = np.zeros((self.b_slots,), np.int64)
         # background probe/unfence: after `probe_after_ticks` clean ticks
         # (no slot-attributable failure anywhere on the fleet) a fenced
@@ -696,7 +647,6 @@ class ServingEngine:
         if self.probe_after_ticks is not None and self.probe_after_ticks < 1:
             raise ValueError(
                 f"probe_after_ticks={self.probe_after_ticks} must be >= 1")
-        self._quarantine_pages_by_slot: Dict[int, List[int]] = {}
         self._fence_tick: Dict[int, int] = {}
         self._last_failure_tick = 0
         self.probe_count = 0
@@ -761,10 +711,10 @@ class ServingEngine:
                 self.b_slots, dtype=dtype, kv_dtype=kv_dtype, mesh=mesh,
                 catalog=self._catalog, adapters=adapters,
                 target_pool_order=self._exec.pool_order)
-            if self._cow_prog is not None:
+            if self._exec._cow_prog is not None:
                 # pre-warm the COW jit on the DRAFT pool aval too: a
                 # boundary COW at admission must never compile
-                self._spec.cow(self._cow_prog, 0, 0)
+                self._spec.cow(self._exec._cow_prog, 0, 0)
         log_dist(
             f"serving engine ready: b_slots={self.b_slots} "
             f"pages={self.num_pages}x{self.page_size} "
@@ -774,50 +724,17 @@ class ServingEngine:
             + f" weights: {info['weight_leaves_split']} stack(s) held a "
             f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
             f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out"
-            + (f" ssm_step={info['ssm_step']}" if self._stateful else ""),
+            + (f" ssm_step={info['ssm_step']}" if info["ssm_step"] else ""),
             ranks=[0])
-
-    # ---------------------------------------------- device-half delegation
-    # The executor owns the pool and the compiled programs
-    # (inference/execution.py).  These views exist for the
-    # supervisor's adoption checks, the probe/canary tests that swap a
-    # bucket's program, and the speculative tick's pool handoff.
-
-    @property
-    def _kpool(self):
-        return self._exec.kpool
-
-    @_kpool.setter
-    def _kpool(self, value):
-        self._exec.kpool = value
-
-    @property
-    def _vpool(self):
-        return self._exec.vpool
-
-    @_vpool.setter
-    def _vpool(self, value):
-        self._exec.vpool = value
-
-    @property
-    def _decode_prog(self):
-        return self._exec._decode_prog
-
-    @property
-    def _prefill_progs(self) -> Dict[int, Any]:
-        return self._exec._prefill_progs
-
-    @property
-    def _cow_prog(self):
-        return self._exec._cow_prog
 
     def program_inventory(self) -> Dict[str, Any]:
         """The full set of program shapes this engine has built: one decode
         step + one prefill per prompt bucket (+ the one fixed-shape COW
         page copy when prefix caching is on, compiled at init).  Constant
         at steady state — admission never grows it beyond the bucket set."""
-        inv = {"decode": 1, "prefill_buckets": sorted(self._prefill_progs)}
-        if self._cow_prog is not None:
+        inv = {"decode": 1,
+               "prefill_buckets": sorted(self._exec._prefill_progs)}
+        if self._exec._cow_prog is not None:
             inv["cow"] = 1
         if self._tier is not None:
             # the tier movers compile at init (traced page ids = one shape
@@ -858,65 +775,28 @@ class ServingEngine:
     def _pages_needed(self, req: Request) -> int:
         return -(-(len(req.input_ids) + req.max_new_tokens) // self.page_size)
 
-    # ------------------------------------------------- page refcounting
-
-    def _alloc_pages(self, n: int) -> List[int]:
-        """Pop ``n`` free pages and take the first reference on each.
-        Every allocation stamps the page with the current weight epoch —
-        the content about to be written is a function of the LIVE params
-        (docs/HYBRID.md)."""
-        pages = [self._free_pages.pop() for _ in range(n)]
-        for p in pages:
-            self._refcount[p] = 1
-            self._page_epoch[p] = self._weight_epoch
-        occupied = (self.num_pages - 1) - len(self._free_pages)
-        if occupied > self._pages_hwm:
-            self._pages_hwm = occupied
-        return pages
-
-    def _share_page(self, p: int) -> None:
-        self._refcount[p] += 1
-
-    def _drop_page(self, p: int) -> None:
-        """Release one reference; the last reference frees the page.  A
-        negative count means a double-free — fail loudly, the pool can no
-        longer be trusted."""
-        c = int(self._refcount[p]) - 1
-        if c < 0:
-            raise RuntimeError(
-                f"page {p} dropped below zero references — double-free "
-                "(page accounting is corrupt; rebuild the engine)")
-        self._refcount[p] = c
-        if c == 0:
-            self._free_pages.append(p)
-
-    def _leak_pages(self, pages: List[int]) -> None:
-        """Quarantine path: zero the refs WITHOUT freeing — suspect
-        contents are leaked-and-accounted, never recycled."""
-        for p in pages:
-            self._refcount[p] = 0
-        self._quarantined_pages.extend(pages)
+    # ------------------------------------------------------- page pools
 
     def _tables(self, slot: Optional[int] = None):
         """What a paged program takes as its page table: the slots' table
-        (one slot's row), beside the rings' where the model has window
-        layers."""
+        (one slot's row), beside any other pool's (a window layer's rings)."""
         rows = slice(None) if slot is None else slice(slot, slot + 1)
-        if not self._ring:
-            return self._page_table[rows]
-        return self._page_table[rows], self._ring_table[rows]
+        tables = tuple(pool.table[rows] for pool in self._pools)
+        return tables if len(tables) > 1 else tables[0]
 
-    def _take_ring(self, slot: int) -> None:
-        self._ring_table[slot] = [self._free_ring.pop()
-                                  for _ in range(self._ring)]
-
-    def _give_ring(self, slot: int, leak: bool = False) -> None:
-        """A slot's ring back to the window pool's free list, or (``leak``:
-        the slot is being fenced) into its quarantine account."""
-        pages = [int(p) for p in self._ring_table[slot] if p]
-        (self._quarantined_ring if leak else self._free_ring).extend(pages)
-        if not leak:
-            self._ring_table[slot] = 0
+    def _release(self, slot: int, pages: List[int], fence=False) -> None:
+        """Give back what ``slot`` holds of each pool: a reference on each of
+        ``pages`` of the slots' pool and the whole row of any other.  Or
+        (``fence``: ``pages`` a failed attempt's own) leak all of it into the
+        quarantine accounts until a canary passes (:meth:`_probe_slot`)."""
+        held = [pages] + [pool.row(slot) for pool in self._pools[1:]]
+        for pool, mine in zip(self._pools, held):
+            pool.table[slot] = 0
+            if fence:
+                pool.leak(slot, mine)
+            else:
+                for p in mine:
+                    pool.drop(p)
 
     def page_accounting(self) -> Dict[str, Any]:
         """The refcount pool invariant, one call: every page (minus the
@@ -929,28 +809,20 @@ class ServingEngine:
         but every demoted index entry must have exactly one host-tier
         buffer (``demoted == host tier size``) — ``balanced`` checks both.
         """
-        referenced = int((self._refcount[1:] > 0).sum())
-        free = len(self._free_pages)
-        quarantined = len(self._quarantined_pages)
+        acct = self._pages.accounting()
         demoted = self._prefix.demoted if self._prefix is not None else 0
-        # the window pool: every ring page is free, quarantined or in the
-        # ring of exactly one slot that holds a request or is fenced
-        held = self._ring_table[self._ring_table > 0]
-        window = {"free": len(self._free_ring),
-                  "quarantined": len(self._quarantined_ring),
-                  "referenced": int(held.size) - len(self._quarantined_ring),
-                  "total": self.b_slots * self._ring}
-        window["balanced"] = not self._ring or (
-            len(set(held.tolist())) == held.size
-            and window["free"] + held.size == window["total"]
-            and all(self._ring_table[s].all() == bool(
-                self._active[s] or self._quarantined[s])
-                for s in range(self.b_slots)))
+        # a pool whose pages are never shared: every referenced page is in
+        # the row of exactly one slot that holds a request
+        rest = [pool.accounting() for pool in self._pools[1:]]
+        for pool, a in zip(self._pools[1:], rest):
+            a["balanced"] = bool(
+                a["balanced"] and not pool.table[~self._active].any()
+                and a["referenced"] == int(
+                    (pool.table[self._active] > 0).sum()))
         return {
-            "window": window,
-            "free": free,
-            "quarantined": quarantined,
-            "referenced": referenced,
+            # the window layers' rings (no pages for any other model)
+            "window": rest[0] if rest else PagePool(1, 0, 0).accounting(),
+            **acct,
             # entry↔page is one-to-one over HBM entries (PrefixIndex pins
             # each published page until its entry dies or demotes), so the
             # HBM entry count IS the distinct-page count — O(1), and
@@ -962,12 +834,10 @@ class ServingEngine:
             "demoted": demoted,
             "host_tier_bytes": self._tier.bytes() if self._tier is not None
             else 0,
-            "total": self.num_pages - 1,
-            "balanced": free + quarantined + referenced
-            == self.num_pages - 1
+            "balanced": acct["balanced"]
             and demoted == (len(self._tier) if self._tier is not None
                             else 0)
-            and window["balanced"],
+            and all(a["balanced"] for a in rest),
         }
 
     def _adapter_salt(self, req: Request) -> int:
@@ -1010,14 +880,14 @@ class ServingEngine:
         freed = 0
         while freed < n_pages and self._prefix is not None \
                 and len(self._prefix):
-            before = len(self._free_pages)
+            before = len(self._pages.free)
             if self._tier is not None:
                 if not self._demote_lru_entry():
                     break   # every remaining entry is already on the host
             else:
                 for p in self._prefix.evict(1):
-                    self._drop_page(p)
-            freed += len(self._free_pages) - before
+                    self._pages.drop(p)
+            freed += len(self._pages.free) - before
 
     # ------------------------------------------------------ KV-page tiering
 
@@ -1036,7 +906,7 @@ class ServingEngine:
             # exactly as the untiered engine would
             p = self._prefix.evict_key(key)
             if p is not None:
-                self._drop_page(p)
+                self._pages.drop(p)
             return True
         self._tier_make_room()
         with trace_span("serve.demote", page=int(e.page)):
@@ -1044,7 +914,7 @@ class ServingEngine:
             slabs = self._exec.extract(int(e.page))
             self._tier.put(key, *slabs, epoch=self._weight_epoch)
             page = self._prefix.demote(key)
-            self._drop_page(page)
+            self._pages.drop(page)
             self._demote_lat_s.append(time.monotonic() - t0)
         self.demotions += 1
         if self._prefix.demoted > self._demoted_hwm:
@@ -1087,11 +957,11 @@ class ServingEngine:
                 return False
             with trace_span("serve.promote"):
                 t0 = time.monotonic()
-                (dst,) = self._alloc_pages(1)
+                (dst,) = self._pages.take(1, self._weight_epoch)
                 try:
                     self._exec.inject(data, dst)
                 except BaseException:
-                    self._drop_page(dst)
+                    self._pages.drop(dst)
                     raise
                 self._prefix.promote(key, dst)
                 self._tier.pop(key)
@@ -1253,7 +1123,7 @@ class ServingEngine:
         if self._prefix is not None:
             flushed_slabs = self._prefix.demoted
             for p in self._prefix.flush():
-                self._drop_page(p)
+                self._pages.drop(p)
                 flushed_pages += 1
         if self._tier is not None and len(self._tier):
             # every demoted entry's removal dropped its slab via the
@@ -1515,7 +1385,7 @@ class ServingEngine:
                 if match.cow_src is not None:
                     pinned.append(match.cow_src)
                 for p in pinned:
-                    self._share_page(p)
+                    self._pages.share(p)
                 n_demoted = sum(1 for p in match.pages if p < 0)
                 if n_demoted and self._tier is not None:
                     for i, p in enumerate(match.pages):
@@ -1525,13 +1395,13 @@ class ServingEngine:
                     # demoted chunks each need one free device page for
                     # their promotion on top of the private remainder
                     need = self._pages_needed(req) - len(match.pages)
-                    if len(self._free_pages) < need + n_demoted:
+                    if len(self._pages.free) < need + n_demoted:
                         # reclaim (demote/evict) cached-but-idle prefix
                         # pages before blocking: a cache must never starve
                         # admission
                         self._reclaim_cached(need + n_demoted
-                                             - len(self._free_pages))
-                    if len(self._free_pages) >= need + n_demoted:
+                                             - len(self._pages.free))
+                    if len(self._pages.free) >= need + n_demoted:
                         if n_demoted and not self._promote_match(match):
                             # a matched host buffer vanished (host-capacity
                             # eviction raced the lookup): retry with a
@@ -1559,10 +1429,10 @@ class ServingEngine:
                     # pins are now the last references — dropping them
                     # frees the pages.
                     if not admitted:
-                        freed_pins = any(self._refcount[p] == 1
+                        freed_pins = any(self._pages.refcount[p] == 1
                                          for p in pinned)
                     for p in pinned:
-                        self._drop_page(p)
+                        self._pages.drop(p)
             if admitted:
                 continue
             if freed_pins or promote_retry:
@@ -1592,10 +1462,11 @@ class ServingEngine:
             self._waiting_deadlines -= 1
         shared = list(match.pages)
         for p in shared:
-            self._share_page(p)
-        pages = self._alloc_pages(need)
-        if self._ring:
-            self._take_ring(slot)
+            self._pages.share(p)
+        pages = self._pages.take(need, self._weight_epoch)
+        for pool in self._pools[1:]:
+            pool.table[slot] = pool.take(pool.table.shape[1],
+                                         self._weight_epoch)
         try:
             self._prefill(slot, req, shared, pages, match, now)
         except BaseException as e:
@@ -1616,40 +1487,29 @@ class ServingEngine:
             # PoolConsumedError; the unwind still leaves the queue
             # replayable (ServingSupervisor rebuilds + replays).
             if self._slots[slot] is None:
-                self._page_table[slot, :] = 0
                 self._queue.appendleft(req)
                 if req.deadline_s is not None:
                     self._waiting_deadlines += 1
                 for p in shared:
-                    self._drop_page(p)
+                    self._pages.drop(p)
                 if not isinstance(e, Exception):
                     # KeyboardInterrupt/SystemExit is the operator, not
                     # the slot: plain unwind, no quarantine accounting
-                    for p in pages:
-                        self._drop_page(p)
-                    self._give_ring(slot)
+                    self._release(slot, pages)
                     raise
                 self._slot_failures[slot] += 1
                 self._last_failure_tick = self._tick
                 fails = int(self._slot_failures[slot])
                 fenced = fails >= self.quarantine_limit
-                # the slot's ring goes the way of its private pages
-                self._give_ring(slot, leak=fenced)
+                self._release(slot, pages, fence=fenced)
                 if fenced:
                     self._quarantined[slot] = True
-                    self._leak_pages(pages)
-                    # remembered per slot so a later successful canary
-                    # probe can hand exactly these pages back to the pool
-                    self._quarantine_pages_by_slot[slot] = list(pages)
                     self._fence_tick[slot] = self._tick
                     logger.error(
                         "serve: slot %d quarantined after %d consecutive "
                         "prefill failures; %d page(s) leaked-and-"
                         "accounted, %d slot(s) remain", slot, fails,
                         len(pages), self._usable_slots())
-                else:
-                    for p in pages:
-                        self._drop_page(p)
                 raise SlotPrefillError(
                     f"prefill failed in slot {slot} for request "
                     f"{req.rid!r} (failure {fails}/"
@@ -1678,20 +1538,19 @@ class ServingEngine:
         # than emit tokens conditioned on retired weights.
         suspects = shared + ([match.cow_src]
                              if match.cow_src is not None else [])
-        stale = [p for p in suspects
-                 if self._page_epoch[p] != self._weight_epoch]
+        stale = self._pages.stale(suspects, self._weight_epoch)
         if stale:
             raise RuntimeError(
                 f"weight-epoch invariant violated: request {req.rid!r} "
                 f"would map page(s) {stale} stamped "
-                f"{[int(self._page_epoch[p]) for p in stale]} at weight "
+                f"{[int(self._pages.epoch[p]) for p in stale]} at weight "
                 f"epoch {self._weight_epoch} — pre-update K/V must never "
                 "be served (docs/HYBRID.md)")
         tail = req.input_ids[n_shared:]
         S_tail = len(tail)   # >= 1: lookup is capped at prompt-1
         s_pad = _bucket(S_tail)
-        self._page_table[slot, :] = 0
-        self._page_table[slot, :len(pages)] = pages
+        self._pages.table[slot, :] = 0
+        self._pages.table[slot, :len(pages)] = pages
         toks = np.zeros((1, s_pad), np.int32)
         toks[0, :S_tail] = tail
         lane_t, lane_k, lane_p, lane_s = as_lanes(req.sampling)
@@ -1711,26 +1570,10 @@ class ServingEngine:
             adapter_row = self._exec.adapter_row(self._adapter_stacks, slot)
         with trace_span("serve.prefill", rid=req.rid, slot=slot,
                         bucket=s_pad, tokens=S_tail,
-                        shared_tokens=n_shared,
-                        # a model with window layers (kv_rows_*) or a
-                        # latent cache gathers nothing back: its prompt
-                        # attends within itself
-                        gathered_rows=0 if self._ring or self._latent else
-                        self._gathered_rows([n_shared + S_tail], 1)) as sp:
-            if self._stateful and get_tracer().enabled:
-                # chunks of the scan that hold a real token beside the
-                # bucket's, and that the call resets its slot's state
-                cfg = self.model.config
-                sp.set(scan_chunks=ssm_scan_chunks(cfg, s_pad, S_tail),
-                       scan_chunks_bucket=ssm_scan_chunks(cfg, s_pad),
-                       state_reset=int(n_shared == 0))
-            if (self._ring or self._latent) and get_tracer().enabled:
-                # the chunk steps its full or latent layers run, bounded by
-                # the prompt's own length, beside the whole bucket's
-                sp.set(walk_steps=causal_walk_steps(s_pad, S_tail),
-                       walk_steps_bucket=causal_walk_steps(s_pad))
-                if self._ring:
-                    self._set_kv_row_attrs(sp, [S_tail], 1, block=s_pad)
+                        shared_tokens=n_shared) as sp:
+            if get_tracer().enabled:
+                # what the call reads of the slot's cache
+                sp.set(**self._layout.prefill_attrs(s_pad, S_tail, n_shared))
             maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
             with self._armed(f"serve.prefill rid={req.rid!r}"):
                 # no more than PREFILLS_IN_FLIGHT unfetched: the oldest's
@@ -1752,8 +1595,8 @@ class ServingEngine:
                         # mirror the snapshot in the draft pool — the
                         # sharer's draft-side boundary must hold the same
                         # donor prefix its target-side boundary does
-                        self._spec.cow(self._cow_prog, match.cow_src,
-                                       private[0])
+                        self._spec.cow(self._exec._cow_prog,
+                                       match.cow_src, private[0])
                 out, seq, pt_row, toks_j = self._launch_prefill(
                     s_pad, slot, toks, S_tail, n_shared,
                     lane_t, lane_k, lane_p, lane_s, adapter_row)
@@ -1825,9 +1668,9 @@ class ServingEngine:
                 newly, released = self._prefix.publish(
                     req.input_ids, pages, salt=self._adapter_salt(req))
                 for p in newly:
-                    self._share_page(p)
+                    self._pages.share(p)
                 for p in released:
-                    self._drop_page(p)
+                    self._pages.drop(p)
         if reason is not None:
             self._finish(slot, reason)
 
@@ -1974,47 +1817,6 @@ class ServingEngine:
                moe_pairs=pairs, moe_local_pairs=int(counts.sum()),
                moe_experts_held=int(counts.size))
 
-    def _set_kv_row_attrs(self, sp, lengths, slots: int, block: int = 0
-                          ) -> None:
-        """On a ``serve.decode`` / ``serve.prefill`` span, the read of a
-        model with two kinds of layer, a kind: K/V head
-        rows (token rows x the kind's KV heads x its layers) the program
-        reads and those of them that pass the mask, when its live slots hold
-        ``lengths`` rows, the rows being written counted in.  A decode tick
-        reads by its plans: a full layer each slot's own pages, a window
-        layer the ring pages under the last ``window`` positions.  A
-        ``block`` of tokens (a prompt's bucket) reads itself: a full layer
-        each chunk of queries that holds a real token the chunks of keys at
-        or before it, a window layer each chunk two chunks of keys, of a
-        long block the groups of chunks that hold a real token (a short
-        block: all of itself, once)."""
-        lengths = np.asarray(lengths, np.int64)
-        W = self.model.config.window_size
-        full, window = self._kind_heads["full"], self._kind_heads["window"]
-        if block:
-            tokens = int(lengths.max())
-            rows = block_read_rows(block, tokens=tokens)
-            ring_rows = block_read_rows(block, W, tokens=tokens)
-            ring_live = int(lengths.sum())
-        else:
-            rows = self._gathered_rows(lengths, slots)
-            ring_rows = window_read_rows(lengths, self.page_size, W, slots)
-            ring_live = int(np.minimum(lengths, W).sum())
-        sp.set(kv_rows_full=rows * full,
-               kv_live_rows_full=int(lengths.sum()) * full,
-               kv_rows_window=ring_rows * window,
-               kv_live_rows_window=ring_live * window)
-
-    def _gathered_rows(self, lengths, slots: int) -> int:
-        """K/V rows a paged program of ``slots`` slots reads a layer when
-        its live slots hold ``lengths`` rows, the rows being written
-        counted in: each slot's own pages, and none of an idle slot, in the
-        read's whole steps (``models.transformer.paged_read_rows``, the
-        host's copy of the list the program computes from its own
-        inputs)."""
-        return paged_read_rows(lengths, self.page_size,
-                               self._page_table.shape[1], slots)
-
     def _arrival_waiting(self, now: float) -> bool:
         """A request is due, a usable slot is free and a prefill may be
         launched: the next admission call would try to place it."""
@@ -2110,7 +1912,7 @@ class ServingEngine:
                 self.prefill_fed_on_device += not first.fed
                 first.fed = True
             self._ahead.append(_Ahead(
-                out, seq, self._exec.params, self._page_table.copy(), active,
+                out, seq, self._exec.params, self._pages.table.copy(), active,
                 lengths, src, owed - active, past_end))
             self.lookahead_launched += len(self._ahead) > 1
             self.lookahead_past_end += past_end
@@ -2126,7 +1928,7 @@ class ServingEngine:
         return (ahead.active & self._active
                 & (ahead.src == self._tok_src)
                 & (ahead.lengths == self._lengths)
-                & (ahead.page_table == self._page_table).all(axis=1))
+                & (ahead.page_table == self._pages.table).all(axis=1))
 
     def _take_ahead(self):
         """``(the launched tick whose turn has come, the slots it is
@@ -2224,20 +2026,8 @@ class ServingEngine:
                         live = tick.lengths[tick.active]
                         sp.set(own_slots=int(own.sum()),
                                live_rows=int(live.sum()),
-                               gathered_rows=self._gathered_rows(
-                                   live + 1, self.b_slots))
-                        if self._ring:
-                            self._set_kv_row_attrs(sp, live + 1, self.b_slots)
-                        if self._stateful:
-                            # slots whose state the tick read and wrote, the
-                            # bytes of one reading of them, and the passes
-                            # the tick's step makes over them (2: the
-                            # one-pass kernel's read and write; 3:
-                            # _ssm_step's)
-                            n = int(tick.active.sum())
-                            sp.set(state_slots=n, state_bytes=n * (
-                                self._exec.state_bytes // self.b_slots),
-                                state_passes=self._exec.state_passes)
+                               **self._layout.decode_attrs(live + 1,
+                                                           self.b_slots))
                     # host fetch = device sync; an MoE model's expert counts
                     # come with the tokens
                     nxt, counts = self._exec.split_counts(
@@ -2285,7 +2075,7 @@ class ServingEngine:
                              f"(speculative k={self._spec.k})"):
                 emitted, n_emit, self._exec.pools = self._spec.tick(
                     self.params, self._exec.pools,
-                    self._page_table, self._lengths, self._last_tok,
+                    self._pages.table, self._lengths, self._last_tok,
                     self._active, *self._lanes_jnp(),
                     adapters=self._adapter_operand())
         t_tok = time.monotonic()   # the tick's 1..k tokens share one stamp
@@ -2355,16 +2145,13 @@ class ServingEngine:
         # drop one reference per page — shared pages stay resident for
         # their other readers (and the prefix index), private pages whose
         # last reference this was return to the free list
-        for p in st.pages:
-            self._drop_page(p)
-        self._give_ring(slot)
+        self._release(slot, st.pages)
         self._slots[slot] = None
         self._active[slot] = False
         self._lengths[slot] = 0
         self._last_tok[slot] = 0
         self._owed[slot] = 0
         self._eos_live -= st.request.eos_token_id is not None
-        self._page_table[slot, :] = 0
         self._lane_temp[slot] = 0.0
         self._lane_top_k[slot] = 0
         self._lane_top_p[slot] = 1.0
@@ -2394,7 +2181,7 @@ class ServingEngine:
                 self._probe_slot(slot)
 
     def _probe_slot(self, slot: int) -> None:
-        pages = self._quarantine_pages_by_slot.get(slot)
+        pages = self._pages.fenced.get(slot)
         if not pages:
             return   # fenced without a page record (defensive): stay fenced
         self.probe_count += 1
@@ -2402,8 +2189,9 @@ class ServingEngine:
         # one-token canary through the slot's own quarantined pages: the
         # same program shape real admissions use, against the same page row
         toks = np.zeros((1, s_pad), np.int32)
-        self._page_table[slot, :] = 0
-        self._page_table[slot, :len(pages)] = pages
+        for pool in self._pools:
+            fenced = pool.fenced.get(slot, [])
+            pool.table[slot, :len(fenced)] = fenced
         try:
             with trace_span("serve.probe", slot=slot):
                 maybe_fire(SITE_SERVE_PREFILL, rid="__canary__", slot=slot)
@@ -2414,7 +2202,6 @@ class ServingEngine:
                         s_pad, slot, toks, 1, 0, 0.0, 0, 1.0, 0)
                     self._fetch(out, f"prefill_{s_pad}", seq)
         except BaseException as e:
-            self._page_table[slot, :] = 0
             self._fence_tick[slot] = self._tick
             self._last_failure_tick = self._tick
             if not isinstance(e, Exception):
@@ -2434,19 +2221,14 @@ class ServingEngine:
                     f"quarantined slot {slot}; rebuild the engine "
                     "(ServingSupervisor automates this)") from e
             return
-        self._page_table[slot, :] = 0
+        finally:
+            for pool in self._pools:
+                pool.table[slot] = 0
+        for pool in self._pools:
+            pool.restore(slot)
         self._quarantined[slot] = False
         self._slot_failures[slot] = 0
         self._fence_tick.pop(slot, None)
-        self._quarantine_pages_by_slot.pop(slot, None)
-        for p in pages:
-            self._quarantined_pages.remove(p)
-        self._free_pages.extend(pages)
-        for p in self._ring_table[slot]:
-            if p:     # the fenced slot's ring comes back with its pages
-                self._quarantined_ring.remove(int(p))
-                self._free_ring.append(int(p))
-        self._ring_table[slot] = 0
         self.unfence_count += 1
         logger.info(
             "serve: slot %d passed its canary probe after quarantine; "
@@ -2502,11 +2284,7 @@ class ServingEngine:
                 if rid_map is not None:
                     # tick span carries the slot→rid map it decoded under
                     sp.set(slot_rids=rid_map)
-                    if self._ring:
-                        # pages of each kind that hold a request's K/V
-                        sp.set(pages_full=int((self._refcount[1:] > 0).sum()),
-                               pages_window=int(
-                                   (self._ring_table[self._active] > 0).sum()))
+                    sp.set(**self._layout.tick_attrs(self._pools))
                 self._decode_tick(rid_map, held)
                 # refill slots the decode just retired — the queue head
                 # starts its prefill this tick instead of idling one
@@ -2675,7 +2453,7 @@ class ServingEngine:
             # (surfaced on /metrics via the serve/* gauges too)
             "referenced_pages": acct["referenced"],
             "cached_pages": acct["cached"],
-            "pages_hwm": self._pages_hwm,
+            "pages_hwm": self._pages.hwm,
             "shed_total": self.shed_count,
             "deadline_expired_total": self.deadline_count,
             "probes_total": self.probe_count,
@@ -2807,20 +2585,20 @@ class ServingEngine:
              float(len(self._queue) + len(self._pending)), self._tick),
             ("serve/active_slots", active, self._tick),
             ("serve/slot_occupancy", active / self.b_slots, self._tick),
-            ("serve/free_pages", float(len(self._free_pages)), self._tick),
+            ("serve/free_pages", float(len(self._pages.free)), self._tick),
             ("serve/tokens_per_sec", self._tokens_out / elapsed, self._tick),
             ("serve/shed_total", float(self.shed_count), self._tick),
             ("serve/deadline_expired_total", float(self.deadline_count),
              self._tick),
             ("serve/quarantined_slots", float(self._quarantined.sum()),
              self._tick),
-            ("serve/quarantined_pages", float(len(self._quarantined_pages)),
-             self._tick),
+            ("serve/quarantined_pages",
+             float(len(self._pages.quarantined)), self._tick),
             ("serve/probes_total", float(self.probe_count), self._tick),
             ("serve/unfenced_total", float(self.unfence_count), self._tick),
-            ("serve/referenced_pages",
-             float((self._refcount[1:] > 0).sum()), self._tick),
-            ("serve/pages_hwm", float(self._pages_hwm), self._tick),
+            ("serve/referenced_pages", float(self._pages.referenced()),
+             self._tick),
+            ("serve/pages_hwm", float(self._pages.hwm), self._tick),
             ("serve/prefix_hits_total", float(self.prefix_hits), self._tick),
             ("serve/prefix_misses_total", float(self.prefix_misses),
              self._tick),

@@ -646,9 +646,10 @@ class ServingSupervisor:
         self._kv_flushed_slabs_base += old.kv_flushed_slabs
         self._demoted_hwm_base = max(self._demoted_hwm_base,
                                      old._demoted_hwm)
-        self._pages_hwm_base = max(self._pages_hwm_base, old._pages_hwm)
-        self._quarantined_slots_lifetime += int(old._quarantined.sum())
-        self._quarantined_pages_lifetime += len(old._quarantined_pages)
+        h = old.health()
+        self._pages_hwm_base = max(self._pages_hwm_base, h["pages_hwm"])
+        self._quarantined_slots_lifetime += h["quarantined_slots"]
+        self._quarantined_pages_lifetime += h["quarantined_pages"]
 
     # ----------------------------------------------------- rolling restart
 

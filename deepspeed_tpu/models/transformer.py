@@ -2503,15 +2503,14 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     page; only its at-rest representation narrows.
     """
     dtype = dtype or cfg.dtype
+    if ((is_hybrid(cfg) or is_latent(cfg) or is_ssm(cfg))
+            and _normalize_kv_dtype(kv_dtype) is not None):
+        _hybrid_refuse("the int8 pool", cfg)
     if is_hybrid(cfg):
         # a pool per kind of layer: the full layers' ``k``/``v`` over
         # ``num_pages`` pages, the window layers' ``k_window``/``v_window``
         # over ``window_pages`` (each slot a ring of
         # :func:`window_ring_pages`; page 0 the trash page of its own pool)
-        if _normalize_kv_dtype(kv_dtype) is not None:
-            raise NotImplementedError(
-                "the int8 pool does not support a model with window layers "
-                "(layer_pattern): its scale planes are one pool's")
         kinds = kind_layers(cfg)
         cache = {}
         for kind, suffix, pages in (
@@ -2529,17 +2528,10 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                         (layers, pages) + rows + (w,), dtype)
         return cache
     if is_latent(cfg):
-        if _normalize_kv_dtype(kv_dtype) is not None:
-            raise NotImplementedError(
-                "the int8 pool does not support a model with latent "
-                "attention (kv_lora_rank): its row scales are over K and V "
-                "heads, and a latent row is both at once")
         return {"latent": jnp.zeros(
             (cfg.num_layers, num_pages, page_size,
              cfg.kv_lora_rank + cfg.rotary_dim), dtype)}
     if is_ssm(cfg):
-        if _normalize_kv_dtype(kv_dtype) is not None:
-            _hybrid_refuse("the int8 pool", cfg)
         conv = ssm_widths(cfg)[1]
 
         def leaf(w):
@@ -3369,16 +3361,37 @@ def _ring_read_plan(ring_table, start, seq_mask, ps: int, window: int):
             low.reshape(-1, pairs, S))
 
 
-def _hybrid_refuse(what: str, cfg: Optional[TransformerConfig] = None):
+def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
+    """``(kind, description)`` of the cache a sequence of this model keeps:
+    which model it is, and why a page of it cannot be shared, parked,
+    rescaled or split by head.  Written once, for :func:`_hybrid_refuse`
+    and the serving engine's refusals (``inference/cache_layout.py``)."""
+    if is_hybrid(cfg):
+        return "window", (
+            "window and full attention layers (layer_pattern): a window "
+            "layer's ring (a pool of its own) holds its slot's last positions "
+            "only: no page of it to share, park, verify against or rescale")
+    if is_latent(cfg):
+        return "latent", (
+            "latent attention (kv_lora_rank): its rows have no head axis to "
+            "shard or scale and only one token a slot reads them back: a tail "
+            "behind shared pages or a draft block has nothing to attend through")
+    if is_ssm(cfg):
+        return "state", (
+            "state-space layers (a state a slot): a slot's state is one "
+            "tensor that no page holds, so a page copied, parked, rescaled "
+            "or split by head leaves it behind, and there is nothing to "
+            "start a tail from or to go back to")
+    if is_grouped(cfg):
+        return "grouped", "leading dense layers (dense_layers)"
+    return "uniform", "one stack of equal layers over K and V pages"
+
+
+def _hybrid_refuse(what: str, cfg: TransformerConfig):
     """What only the uniform K/V models go through, refused by name."""
-    model = ("window and full attention layers (layer_pattern)"
-             if cfg is None or is_hybrid(cfg)
-             else "latent attention (kv_lora_rank)" if is_latent(cfg)
-             else "state-space layers (a state a slot)" if is_ssm(cfg)
-             else "leading dense layers (dense_layers)")
     raise NotImplementedError(
-        f"{what} does not support a model with {model}: it runs through "
-        "forward() and the paged serving path (forward_paged)")
+        f"{what} does not support a model with {cache_kind(cfg)[1]} (it "
+        "runs through forward() and the paged serving path, forward_paged)")
 
 
 def _head_at(cfg, params, x, logits_at):
@@ -3577,16 +3590,13 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         raise NotImplementedError(
             "paged decode does not support per-layer attention windows "
             "(attention_layers); use the contiguous cache path")
-    if is_hybrid(cfg):
-        if adapters is not None:
-            _hybrid_refuse("multi-tenant adapter serving (per-slot LoRA "
-                           "factors)")
-        return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
-                                     start, seq_mask, expert_counts,
-                                     pool_order, logits_at)
     if (is_grouped(cfg) or is_ssm(cfg)) and adapters is not None:
         _hybrid_refuse("multi-tenant adapter serving (per-slot LoRA "
                        "factors)", cfg)
+    if is_hybrid(cfg):
+        return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
+                                     start, seq_mask, expert_counts,
+                                     pool_order, logits_at)
     # a K/V leaf kept head-major (kv_leaf_head_major) is seen through the
     # transpose that moves nothing, as a two-kind model's (stacked below)
     head_major = {n: kv_leaf_head_major(cfg, w) for n, w in (

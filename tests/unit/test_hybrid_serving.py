@@ -106,7 +106,8 @@ def test_engine_serves_both_pools_and_gives_the_rings_back(engine,
     if chunk == 4:      # and its window layers their chunks of 16 one by one
         monkeypatch.setattr(T, "WINDOW_BLOCK_CHUNKS", 1)
     sv = engine.serving(**SERVE_KW)
-    assert sv._ring == 3 and sv._exec.window_pages == 1 + 3 * 3
+    lay = sv._exec.layout
+    assert lay.ring_pages == 3 and lay.window_pages == 1 + 3 * 3
     assert sv._prefix is None                       # off for this model
     reqs = _requests(7) + _sparse_prompts()
     results = sv.run(reqs, max_ticks=4000)
@@ -139,16 +140,16 @@ def test_accounting_after_admit_wrap_and_finish(engine):
     sv.step(now=0.0)
     acct = sv.page_accounting()
     assert acct["balanced"] and acct["window"]["referenced"] == 6
-    ring = sv._ring_table[0].copy()
-    assert ring.all() and len(set(ring) | set(sv._ring_table[1])) == 6
+    ring = sv._pools[1].table[0].copy()
+    assert ring.all() and len(set(ring) | set(sv._pools[1].table[1])) == 6
     while sv._active[1]:
         sv.step()
     acct = sv.page_accounting()
     assert acct["balanced"] and acct["window"]["referenced"] == 3
-    assert acct["window"]["free"] == 6 and not sv._ring_table[1].any()
+    assert acct["window"]["free"] == 6 and not sv._pools[1].table[1].any()
     while sv._lengths[0] < 5 + 40:          # 45 positions: the ring of 3
         sv.step()                           # pages has wrapped, in place
-    assert (sv._ring_table[0] == ring).all()
+    assert (sv._pools[1].table[0] == ring).all()
     assert sv.page_accounting()["balanced"]
     while sv._active.any():
         sv.step()

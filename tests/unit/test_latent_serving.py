@@ -135,7 +135,7 @@ def test_engine_serves_the_latent_pool_token_for_token(engine, monkeypatch,
 
     monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", chunk)
     sv = engine.serving(**SERVE_KW)
-    assert sv._prefix is None and sv._latent        # sharing off for it
+    assert sv._prefix is None and sv._layout.kind == "latent"  # sharing off
     assert sv._exec._pool_keys == ("latent",)
     assert sv._exec.moe_shape == (3, 4)
     results = sv.run(_requests(7) + _sparse_prompts(), max_ticks=4000)

@@ -333,7 +333,7 @@ def test_epoch_tag_defenses_refuse_stale_entries(inference_stack):
     # the admission guard instead of being mapped (simulates a flush hole)
     pages = serve._prefix.pages()
     assert pages
-    serve._page_epoch[pages[0]] = 77
+    serve._pages.epoch[pages[0]] = 77
     rng = np.random.default_rng(13)
     with pytest.raises(RuntimeError, match="weight-epoch invariant"):
         serve.run([Request(rid="stale", input_ids=np.concatenate(

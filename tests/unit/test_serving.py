@@ -193,12 +193,12 @@ def test_serving_prefill_failure_unwinds_reservation(tiny_engine, tiny_serve):
     """A prefill that dies on the device call must not leak pages or drop
     the request: the reservation unwinds and the request stays at the
     queue head for a retry."""
-    real_prog = tiny_serve._prefill_progs.get(16)
+    real_prog = tiny_serve._exec._prefill_progs.get(16)
 
     def boom(*a, **k):
         raise RuntimeError("injected prefill failure")
 
-    tiny_serve._prefill_progs[16] = boom
+    tiny_serve._exec._prefill_progs[16] = boom
     req = Request(rid="pf", input_ids=np.array([1, 2, 3], np.int32),
                   max_new_tokens=3)
     tiny_serve.submit(req)
@@ -212,9 +212,9 @@ def test_serving_prefill_failure_unwinds_reservation(tiny_engine, tiny_serve):
         assert not tiny_serve._active.any()
     finally:
         if real_prog is None:
-            del tiny_serve._prefill_progs[16]
+            del tiny_serve._exec._prefill_progs[16]
         else:
-            tiny_serve._prefill_progs[16] = real_prog
+            tiny_serve._exec._prefill_progs[16] = real_prog
     (res,) = tiny_serve.run([])                             # retry succeeds
     assert res.rid == "pf" and len(res.output_ids) == 3
 

@@ -230,7 +230,7 @@ def test_a_slot_ended_under_a_tick_in_flight_hands_its_pages_on(
         assert len(sv._queue) == 1           # a slot free, no pages for it
         in_flight = len(sv._ahead)
         # the row the tick in flight writes for the first request
-        stale_page = sv._page_table[0, (sv._lengths[0] + in_flight) // 8]
+        stale_page = sv._pages.table[0, (sv._lengths[0] + in_flight) // 8]
         sv.step(now=1e6)                     # the deadline, then the admission
         assert sv._slots[0].request.rid == "r2"
         assert stale_page in sv._slots[0].pages

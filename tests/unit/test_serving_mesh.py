@@ -124,7 +124,7 @@ def test_sharded_token_exact_vs_unsharded_and_generate(sharded_engine,
     assert h["mesh_devices"] == jax.device_count()
     assert h["mesh_axes"]["model"] == TP
     assert h["kv_pool_bytes_per_device"] * TP == h["kv_pool_bytes_total"]
-    spec = sharded_serve._kpool.sharding.spec
+    spec = sharded_serve._exec.pools[0].sharding.spec
     assert tuple(spec) == (None, None, None, "model", None)
 
 
@@ -154,7 +154,7 @@ def test_supervisor_warm_restart_adopts_sharded_programs(sharded_engine,
 
     sup = engine2.supervised_serving(max_restarts=3, **SERVE_KW)
     sup.run(_stream(6, seed=4))                  # warm the supervised engine
-    old_sharding = sup.engine._kpool.sharding
+    old_sharding = sup.engine._exec.pools[0].sharding
     inj = install_injector(FaultInjector())
     inj.add(site=SITE_SERVE_DECODE, kind="raise", at_call=3)
     try:
@@ -166,7 +166,7 @@ def test_supervisor_warm_restart_adopts_sharded_programs(sharded_engine,
     assert sup.restarts == 1
     assert sup.restart_log[-1]["programs_reused"] is True
     assert compiles == 0, "warm restart recompiled on the mesh"
-    assert sup.engine._kpool.sharding == old_sharding
+    assert sup.engine._exec.pools[0].sharding == old_sharding
     by_rid = {r.rid: r for r in results}
     for rid, out in ref.items():
         np.testing.assert_array_equal(by_rid[rid].output_ids, out,
@@ -185,12 +185,12 @@ def test_recycle_reuses_sharded_programs_and_gauges(sharded_engine):
                                      **SERVE_KW)
     first = sup.run(_stream(4, seed=5))
     assert len(first) == 4
-    old_sharding = sup.engine._kpool.sharding
+    old_sharding = sup.engine._exec.pools[0].sharding
     assert not sup.drain(max_ticks=500)          # idle: nothing unserved
     base = _count()
     assert sup.recycle() is True
     assert _count() - base == 0, "recycle recompiled on the mesh"
-    assert sup.engine._kpool.sharding == old_sharding
+    assert sup.engine._exec.pools[0].sharding == old_sharding
     results = sup.run(_stream(4, seed=6))
     assert len(results) == 4
     h = sup.health()

@@ -196,7 +196,7 @@ def test_repeated_prefill_failure_quarantines_slot(tiny_engine):
     assert all(r.finish_reason == "length" for r in results)
     eng = sup.engine
     assert bool(eng._quarantined[0]) and not eng._quarantined[1:].any()
-    assert len(eng._quarantined_pages) > 0
+    assert len(eng._pages.quarantined) > 0
     h = sup.health()
     assert h["quarantined_slots"] == 1
     assert h["usable_slots"] == SERVE_KW["b_slots"] - 1
@@ -840,7 +840,7 @@ def test_failed_probe_keeps_slot_fenced_until_a_clean_canary(tiny_engine):
     def broken_canary(*args, **kwargs):
         raise RuntimeError("canary boom")
 
-    serve._prefill_progs[16] = broken_canary
+    serve._exec._prefill_progs[16] = broken_canary
     for r in _stream(6, seed=22, smin=17, smax=30):
         serve.submit(r)
     fenced_again = False
@@ -853,7 +853,8 @@ def test_failed_probe_keeps_slot_fenced_until_a_clean_canary(tiny_engine):
             # the first canary failed: still fenced, clock restarted
             fenced_again = True
             assert serve.health()["quarantined_slots"] == 1
-            serve._prefill_progs.pop(16, None)   # next canary rebuilds clean
+            # the next canary rebuilds clean
+            serve._exec._prefill_progs.pop(16, None)
         if n == 0:
             break
     h = serve.health()

@@ -287,13 +287,13 @@ def test_serving_programs_carry_their_names_and_scopes(tiny_serve):
     assert prefill.__name__ == f"serve_prefill_{s_pad}"
     lanes = tiny_serve._lanes_jnp()
     decode = ex._decode_prog.lower(
-        ex.params, ex.pools, jnp.asarray(tiny_serve._page_table),
+        ex.params, ex.pools, jnp.asarray(tiny_serve._pages.table),
         jnp.asarray(tiny_serve._lengths), jnp.asarray(tiny_serve._last_tok),
         jnp.asarray(tiny_serve._active), *lanes).as_text(debug_info=True)
     assert "module @jit_serve_decode" in decode
     one = lambda dtype: np.zeros((1,), dtype)     # noqa: E731
     fill = prefill.lower(
-        ex.params, ex.pools, jnp.asarray(tiny_serve._page_table[:1]),
+        ex.params, ex.pools, jnp.asarray(tiny_serve._pages.table[:1]),
         jnp.zeros((1, s_pad), jnp.int32), jnp.int32(5), jnp.int32(0),
         one(np.float32), one(np.int32), one(np.float32),
         one(np.uint32)).as_text(debug_info=True)
