@@ -4,8 +4,10 @@ holds"): arithmetic over the model's config and the engine's geometry.
 :class:`~.serving.ServingEngine` reads it from there (and builds an equal
 one first, to refuse what it must before anything is compiled).  It says
 how each set of leaves a slot holds is addressed (pages of the slot's table,
-a ring of pages in the window pool, a row a slot) and from that the pools of
-pages to allocate and what ``init_paged_cache`` is called with; which
+a ring of pages in the window pool, a row a slot), how deep they are (a
+page's rows a layer of the model, or of a looped model a layer of EVERY pass:
+``models.transformer.cache_depth``) and from that the pools of pages to
+allocate and what ``init_paged_cache`` is called with; which
 mechanisms work on it (:data:`REFUSED`: mechanism x kind of cache, the kinds
 ``models.transformer.cache_kind``'s); and what a tick and a prompt read of
 it, as the span attrs the benchmark's per-layer readers take.  The next kind
@@ -17,7 +19,7 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
-from ..models.transformer import (block_read_rows, cache_kind,
+from ..models.transformer import (block_read_rows, cache_depth, cache_kind,
                                   causal_walk_steps, kind_layers,
                                   paged_read_rows, ssm_scan_chunks,
                                   window_read_rows, window_ring_pages)
@@ -43,7 +45,9 @@ REFUSED: Dict[str, Tuple[str, ...]] = {
 
 class CacheLayout:
     """The cache of ``b_slots`` slots of ``pages_per_slot`` pages of
-    ``page_size`` tokens over ``num_pages`` pages, for the model ``cfg``."""
+    ``page_size`` tokens over ``num_pages`` pages, for the model ``cfg``:
+    each page ``depth`` layers deep, ``passes`` passes of the model's own
+    layers (1 for any model but a looped one)."""
 
     def __init__(self, cfg, b_slots: int, page_size: int,
                  pages_per_slot: int, num_pages: int):
@@ -51,6 +55,8 @@ class CacheLayout:
         self.b_slots, self.page_size = int(b_slots), int(page_size)
         self.pages_per_slot = int(pages_per_slot)
         self.kind, self.description = cache_kind(cfg)
+        # a page holds its tokens' rows of every layer of every pass
+        self.passes, self.depth = cfg.loop_passes, cache_depth(cfg)
         # window layers: a ring a slot in a pool of its own, reused in place
         self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
                            if self.kind == "window" else 0)
@@ -74,8 +80,10 @@ class CacheLayout:
         # a prompt of such a model attends within itself, gathers nothing back
         self.block_attends_itself = self.kind in ("window", "latent")
         # a slot's bytes of state and the passes the tick's step makes over
-        # them: the executor that made the pool and traced the tick says
-        self.state_slot_bytes = self.state_passes = 0
+        # them, and the bytes one token's rows take in the paged leaves over
+        # every layer and pass: the executor that made the pool and traced
+        # the tick says
+        self.state_slot_bytes = self.state_passes = self.kv_token_bytes = 0
 
     # ------------------------------------------------------- mechanisms
 
@@ -104,14 +112,18 @@ class CacheLayout:
         """The ``serve.decode`` span attrs of a tick of ``slots`` slots whose
         live ones hold ``lengths`` rows, the row being written counted in.
         ``gathered_rows``: K/V rows its read covers a layer, each slot's own
-        pages in whole steps.  Two kinds of layer: K/V head rows read and
-        live a kind (a window layer reads the ring pages under its window).
+        pages in whole steps; ``passes`` times the model's layers read them,
+        and ``kv_bytes`` is what the rows held take over all of those.  Two
+        kinds of layer: K/V head rows read and live a kind (a window layer
+        reads the ring pages under its window).
         A state a slot: the slots whose state the tick read and wrote, the
         bytes of one reading, its step's passes."""
         lengths = np.asarray(lengths, np.int64)
         rows = paged_read_rows(lengths, self.page_size, self.pages_per_slot,
                                slots)
-        attrs: Dict[str, Any] = {"gathered_rows": rows}
+        attrs: Dict[str, Any] = {
+            "gathered_rows": rows, "passes": self.passes,
+            "kv_bytes": int(lengths.sum()) * self.kv_token_bytes}
         if self.kind == "window":
             W = self.cfg.window_size
             attrs.update(self._kv_row_attrs(
@@ -124,14 +136,18 @@ class CacheLayout:
                          state_passes=self.state_passes)
         return attrs
 
-    def tick_attrs(self, pools) -> Dict[str, int]:
-        """The ``serve.tick`` span attrs of two kinds of layer: pages of each
-        of the engine's ``pools`` (:attr:`pools`' order) that hold a
-        request's K/V."""
-        if self.kind != "window":
-            return {}
-        return {"pages_full": pools[0].referenced(),
-                "pages_window": pools[1].referenced()}
+    def tick_attrs(self, pools, page_wait: bool) -> Dict[str, int]:
+        """The ``serve.tick`` span attrs: ``pages_free`` of the slots' pool
+        and ``page_wait``, 1 where the tick's admission left the head of the
+        queue waiting for pages with a slot free (pages, not slots, bound
+        the batch).  Two kinds of layer: pages of each of the engine's
+        ``pools`` (:attr:`pools`' order) that hold a request's K/V."""
+        attrs = {"page_wait": int(page_wait),
+                 "pages_free": len(pools[0].free)}
+        if self.kind == "window":
+            attrs.update(pages_full=pools[0].referenced(),
+                         pages_window=pools[1].referenced())
+        return attrs
 
     def prefill_attrs(self, bucket: int, tokens: int, shared: int
                       ) -> Dict[str, Any]:
@@ -140,12 +156,15 @@ class CacheLayout:
         pages.  ``gathered_rows`` as a tick's, or 0 where the block attends
         within itself: then ``walk_steps``, the chunk steps its full or
         latent layers run as far as its tokens reach, beside the bucket's,
-        and what a block reads of itself a kind of layer.  A state a slot:
-        the scan's chunks that hold a real token beside the bucket's, and
-        whether the call resets its slot's state."""
+        and what a block reads of itself a kind of layer.  ``passes`` and
+        ``kv_bytes`` as a tick's, over the rows the slot holds after it.  A
+        state a slot: the scan's chunks that hold a real token beside the
+        bucket's, and whether the call resets its slot's state."""
         attrs: Dict[str, Any] = {"gathered_rows": (
             0 if self.block_attends_itself else paged_read_rows(
-                [shared + tokens], self.page_size, self.pages_per_slot, 1))}
+                [shared + tokens], self.page_size, self.pages_per_slot, 1)),
+            "passes": self.passes,
+            "kv_bytes": (shared + tokens) * self.kv_token_bytes}
         if self.stateful:
             attrs.update(
                 scan_chunks=ssm_scan_chunks(self.cfg, bucket, tokens),

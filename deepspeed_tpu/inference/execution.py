@@ -395,6 +395,11 @@ class MeshExecutor:
             if k in SSM_POOL_KEYS)
         layout.state_slot_bytes = self.state_bytes // self.b_slots
         layout.state_passes = self.state_passes
+        # one token's rows in every paged leaf, over every layer and pass
+        layout.kv_token_bytes = sum(
+            int(a.nbytes) // (a.shape[1] * self.page_size)
+            for k, a in zip(self._pool_keys, self.pools)
+            if k not in SSM_POOL_KEYS)
         # device copy of the lane vectors, rebuilt only when a lane
         # changes (admission / retirement) — unlike lengths/last_tok the
         # lanes are constant across a request's whole decode, so the
@@ -875,13 +880,18 @@ class MeshExecutor:
         tree that already lay so (a warm restart's).  ``ssm_step``: the
         step the decode tick of a model with a state a slot holds
         (``"one_pass"`` / ``"xla"``: ``models.transformer.ssm_step_path``),
-        ``None`` for any other model."""
+        ``None`` for any other model.  ``loop_passes``: how often a token
+        runs the model's layers (a looped model's ``loop_passes``, else 1),
+        and ``kv_bytes_per_token``: what one token's rows take in the paged
+        leaves over every layer and pass."""
         mesh = self.mesh
         return {"mesh_devices": 1 if mesh is None else int(mesh.size),
                 "mesh_axes": {} if mesh is None else {
                     a: int(mesh.shape[a]) for a in mesh.axis_names
                     if int(mesh.shape[a]) > 1},
-                **self.weight_placement, "ssm_step": self.ssm_step}
+                **self.weight_placement, "ssm_step": self.ssm_step,
+                "loop_passes": self.layout.passes,
+                "kv_bytes_per_token": self.layout.kv_token_bytes}
 
     # ----------------------------------------------------------- adoption
 

@@ -460,6 +460,11 @@ class ServingEngine:
         # whose first token reached a tick without the host
         self.lookahead_past_end = 0
         self.prefill_fed_on_device = 0
+        # admission passes that left the head of the queue waiting for pages
+        # with a slot free (head-of-line: the pool, not the slots, bound the
+        # batch), and whether the last one did
+        self.page_waits = 0
+        self._page_wait = False
         # the launch (its seq) whose output is each slot's last token: a
         # tick launched ahead names the token it was fed by it
         self._tok_src = np.zeros((self.b_slots,), np.int64)
@@ -694,7 +699,10 @@ class ServingEngine:
                  ("serve/kvq_page_bytes",
                   float(pb["total"] // self.num_pages), 0),
                  ("serve/state_pool_bytes",
-                  float(self._exec.state_bytes), 0)]
+                  float(self._exec.state_bytes), 0),
+                 ("serve/loop_passes", float(info["loop_passes"]), 0),
+                 ("serve/kv_bytes_per_token",
+                  float(info["kv_bytes_per_token"]), 0)]
                 + [(f"serve/mesh_axis_{a}", float(s), 0)
                    for a, s in info["mesh_axes"].items()])
 
@@ -718,7 +726,10 @@ class ServingEngine:
         log_dist(
             f"serving engine ready: b_slots={self.b_slots} "
             f"pages={self.num_pages}x{self.page_size} "
-            f"(max_model_len={self.max_model_len})"
+            f"(max_model_len={self.max_model_len}) "
+            f"kv_bytes_per_token={info['kv_bytes_per_token']}"
+            + (f" loop_passes={info['loop_passes']}"
+               if info["loop_passes"] > 1 else "")
             + (f" mesh={info['mesh_devices']}dev {info['mesh_axes']}"
                if mesh is not None else "")
             + f" weights: {info['weight_leaves_split']} stack(s) held a "
@@ -1353,6 +1364,7 @@ class ServingEngine:
         if k:
             self._queue.extend(self._pending[:k])
             del self._pending[:k]
+        self._page_wait = False
         while self._queue:
             req = self._queue[0]
             try:
@@ -1443,7 +1455,11 @@ class ServingEngine:
                 # instead of misreading this as head-of-line blocking.
                 # Terminates: each retry means the index strictly shrank.
                 continue
-            break   # head-of-line: wait for retirements
+            # head-of-line: wait for retirements, a slot free and the pool
+            # short of the head's pages
+            self._page_wait = True
+            self.page_waits += 1
+            break
 
     def _admit_one(self, req: Request, slot: int, match: PrefixMatch,
                    need: int, now: float) -> None:
@@ -2284,7 +2300,8 @@ class ServingEngine:
                 if rid_map is not None:
                     # tick span carries the slot→rid map it decoded under
                     sp.set(slot_rids=rid_map)
-                    sp.set(**self._layout.tick_attrs(self._pools))
+                    sp.set(**self._layout.tick_attrs(self._pools,
+                                                     self._page_wait))
                 self._decode_tick(rid_map, held)
                 # refill slots the decode just retired — the queue head
                 # starts its prefill this tick instead of idling one
@@ -2480,6 +2497,10 @@ class ServingEngine:
             "lookahead_past_end_total": self.lookahead_past_end,
             "prefill_fed_on_device_total": self.prefill_fed_on_device,
             "first_tokens_in_flight": len(self._firsts),
+            # admission passes that ended with a slot free and the head of
+            # the queue waiting for pages (num_pages under the full
+            # reservation: pages, not slots, bound the batch)
+            "admission_page_waits_total": self.page_waits,
             # the bytes of the cache's leaves indexed by slot, of a model
             # with a state a slot (counted in kv_pool_bytes_* too)
             "state_pool_bytes": self._exec.state_bytes,
@@ -2586,6 +2607,8 @@ class ServingEngine:
             ("serve/active_slots", active, self._tick),
             ("serve/slot_occupancy", active / self.b_slots, self._tick),
             ("serve/free_pages", float(len(self._pages.free)), self._tick),
+            ("serve/admission_page_waits_total", float(self.page_waits),
+             self._tick),
             ("serve/tokens_per_sec", self._tokens_out / elapsed, self._tick),
             ("serve/shed_total", float(self.shed_count), self._tick),
             ("serve/deadline_expired_total", float(self.deadline_count),

@@ -119,6 +119,7 @@ class ServingSupervisor:
         self._cow_base = 0
         # launched, dropped, stale taken, past a slot's end, fed on device
         self._lookahead_base = (0, 0, 0, 0, 0)
+        self._page_waits_base = 0
         self._sampled_base = 0
         self._adapter_admissions_base = 0
         self._spec_ticks_base = 0
@@ -346,6 +347,7 @@ class ServingSupervisor:
         h["lookahead_stale_taken_total"] += self._lookahead_base[2]
         h["lookahead_past_end_total"] += self._lookahead_base[3]
         h["prefill_fed_on_device_total"] += self._lookahead_base[4]
+        h["admission_page_waits_total"] += self._page_waits_base
         h["sampled_admissions_total"] += self._sampled_base
         h["adapter_admissions_total"] += self._adapter_admissions_base
         h["spec_verify_slot_ticks_total"] += self._spec_ticks_base
@@ -633,6 +635,7 @@ class ServingSupervisor:
             self._lookahead_base[2] + old.lookahead_stale_taken,
             self._lookahead_base[3] + old.lookahead_past_end,
             self._lookahead_base[4] + old.prefill_fed_on_device)
+        self._page_waits_base += old.page_waits
         self._sampled_base += old.sampled_admissions
         self._adapter_admissions_base += old.adapter_admissions
         if old._spec is not None:

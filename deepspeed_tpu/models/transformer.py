@@ -149,6 +149,21 @@ class TransformerConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    # A looped model (Ouro, ``ouro``): the whole stack of ``num_layers``
+    # layers runs ``loop_passes`` times over a token with the SAME weights,
+    # the final norm after EVERY pass (the next pass starts from the normed
+    # x) and the head after the last.  Every (pass, layer) keeps its own keys
+    # and values, so a cache is ``loop_passes * num_layers`` layers deep
+    # (:func:`cache_depth`), pass ``r``'s layer ``l`` at ``r * num_layers +
+    # l``, while the weights' stack stays ``num_layers`` deep.  x is carried
+    # in float32 from layer to layer (the branches read it in ``dtype``): in
+    # bfloat16 the 2 x loop_passes x num_layers adds of a token each round
+    # it, 384 times for Ouro-2.6B, and that alone reads 0.03-0.046 on the
+    # logits against a float32 reference (PERF.md, PR 44).
+    # ``sandwich_norm``: a norm after each branch as well as before it,
+    # ``x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))``, two more scales a layer
+    loop_passes: int = 1
+    sandwich_norm: bool = False
     tie_embeddings: bool = False
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -293,7 +308,8 @@ class TransformerConfig:
                 if self.moe_use_residual:
                     m += mlp + 2 * d  # dense residual branch + coefficient
             total_mlp += m
-        n_norms = 1 if self.shared_layernorm else 2
+        n_norms = ((1 if self.shared_layernorm else 2)
+                   + (2 if self.sandwich_norm else 0))
         norms = n_norms * d * (2 if self.norm == "layernorm" else 1)
         embed = v * d * (1 if self.tie_embeddings else 2)
         if self.lm_head_bias and not self.tie_embeddings:
@@ -410,6 +426,17 @@ CONFIGS: Dict[str, TransformerConfig] = {
                          0.3535533905932738),
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
         remat=False),
+    # ByteDance/Ouro-2.6B config.json (``ouro``, a looped language model):
+    # ONE stack of 48 layers (16 heads of 128 over 16 KV heads, a SwiGLU of
+    # 5,632, four RMSNorms a layer, eps 1e-6, full rotary theta 1e6) run
+    # ``total_ut_steps`` 4 times a token, the final norm after every pass, an
+    # untied head over 49,152 ids after the last; ``early_exit_threshold`` 1:
+    # every token runs all four passes, and the exit gate is not built
+    "ouro-2.6b": TransformerConfig(
+        vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+        num_layers=48, num_heads=16, num_kv_heads=16, head_dim=128,
+        max_seq_len=65536, norm_eps=1e-6, rope_theta=1e6,
+        loop_passes=4, sandwich_norm=True, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -498,6 +525,13 @@ def is_ssm(cfg: TransformerConfig) -> bool:
     """State-space layers beside attention (``ssm_heads``): a fixed-size
     state a sequence in every block, two cache leaves with no page axis."""
     return bool(cfg.ssm_heads)
+
+
+def cache_depth(cfg: TransformerConfig) -> int:
+    """Layers of K/V a sequence keeps: a looped model's every (pass, layer)
+    has its own, ``loop_passes * num_layers``; any other model's its
+    ``num_layers``.  The weights' stack is ``num_layers`` deep either way."""
+    return cfg.loop_passes * cfg.num_layers
 
 
 def ssm_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
@@ -701,6 +735,41 @@ def _check_qk_norm(cfg: TransformerConfig) -> None:
             "qk_norm is an RMSNorm (OLMoE): it carries a scale and no offset")
 
 
+def _check_loop(cfg: TransformerConfig) -> None:
+    """What a looped model (``loop_passes`` > 1) and a block with a norm
+    after each branch (``sandwich_norm``) are built from, and what they
+    leave out."""
+    if cfg.sandwich_norm:
+        if cfg.norm != "rmsnorm":
+            raise NotImplementedError(
+                "sandwich_norm is four RMSNorms a layer: a scale and no "
+                "offset")
+        for on, what in ((cfg.post_layernorm, "post_layernorm"),
+                         (cfg.parallel_residual, "parallel_residual"),
+                         (is_ssm(cfg), "state-space layers")):
+            if on:
+                raise NotImplementedError(
+                    f"sandwich_norm does not take {what}")
+    if cfg.loop_passes < 1:
+        raise ValueError(f"loop_passes={cfg.loop_passes} must be >= 1")
+    if cfg.loop_passes > 1:
+        if not cfg.final_norm:
+            raise NotImplementedError(
+                "a looped model (loop_passes) norms x after every pass: it "
+                "takes final_norm")
+        for on, what in ((is_grouped(cfg), "layer_pattern / dense_layers"),
+                         (is_latent(cfg), "latent attention"),
+                         (is_ssm(cfg), "state-space layers"),
+                         (cfg.num_experts != 1, "expert layers"),
+                         (cfg.attention_layers is not None,
+                          "attention_layers"),
+                         (cfg.pipeline_stages > 1, "pipeline_stages"),
+                         (not cfg.scan_layers, "scan_layers=False")):
+            if on:
+                raise NotImplementedError(
+                    f"a looped model (loop_passes) does not take {what}")
+
+
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     """Initialize fp32 params. Layer params are stacked on a leading [L] dim
     so the forward can lax.scan over them.  PR-MoE pyramid configs
@@ -778,6 +847,17 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             ssm_out=dense(sk[5], (L, d_ssm, d), std / math.sqrt(2 * L)))
     if not cfg.shared_layernorm:   # GPT-J shares the attention LN
         layers["mlp_norm_scale"] = jnp.ones((L, d))
+    _check_loop(cfg)
+    if cfg.sandwich_norm:
+        # the norms after the branches start at 1 / sqrt(2L): the residual
+        # scaling the GPT-2 init gives w_o and w_down above, which a norm
+        # behind them erases.  At 1 every branch adds a unit-RMS vector to
+        # a unit-RMS stream and a looped stack of random layers is an
+        # expanding map: a rounding error grows 2.4 x a pass (PERF.md, PR
+        # 44), which no checkpoint that runs in bfloat16 does
+        post = jnp.full((L, d), 1.0 / math.sqrt(2 * L))
+        layers["attn_post_norm_scale"] = post
+        layers["mlp_post_norm_scale"] = post
     if cfg.norm == "layernorm":
         layers["attn_norm_bias"] = jnp.zeros((L, d))
         if not cfg.shared_layernorm:
@@ -894,6 +974,10 @@ def _init_params_het(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     if cfg.mlp_bias or cfg.attn_bias:
         raise NotImplementedError(
             "PR-MoE pyramid configs do not support attn/mlp biases")
+    if cfg.sandwich_norm or cfg.loop_passes != 1:
+        raise NotImplementedError(
+            "PR-MoE pyramid configs do not support sandwich_norm or "
+            "loop_passes")
     d, f = cfg.hidden_size, cfg.intermediate_size
     hd, nh, nkv, L = (cfg.dims_per_head, cfg.num_heads, cfg.kv_heads,
                       cfg.num_layers)
@@ -1004,6 +1088,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
                       ssm_norm_scale=rep)
     if not cfg.shared_layernorm:
         layers["mlp_norm_scale"] = rep
+    if cfg.sandwich_norm:
+        layers.update(attn_post_norm_scale=rep, mlp_post_norm_scale=rep)
     if cfg.norm == "layernorm":
         layers["attn_norm_bias"] = rep
         if not cfg.shared_layernorm:
@@ -1185,13 +1271,44 @@ def _lm_head(cfg, params, x):
         return _scaled(logits, cfg.lm_head_multiplier)
 
 
+def _off_stream(cfg, h):
+    """What a branch or the head reads of x: a looped model carries x in
+    float32 (:func:`_passes`) and computes in ``cfg.dtype``; any other
+    model's x is what it computes in."""
+    return h.astype(cfg.dtype) if cfg.loop_passes > 1 else h
+
+
+def _final_norm(cfg, params, x):
+    return _norm(cfg, x, params["final_norm_scale"],
+                 params.get("final_norm_bias"))
+
+
 def _head(cfg, params, x):
     """The last layer's output -> logits: the final norm (post-LN blocks end
-    normalised and have none), then :func:`_lm_head`."""
-    if cfg.final_norm:
-        x = _norm(cfg, x, params["final_norm_scale"],
-                  params.get("final_norm_bias"))
-    return _lm_head(cfg, params, x)
+    normalised and have none; a looped model's last pass has ended with it,
+    :func:`_passes`), then :func:`_lm_head`."""
+    if cfg.final_norm and cfg.loop_passes == 1:
+        x = _final_norm(cfg, params, x)
+    return _lm_head(cfg, params, _off_stream(cfg, x))
+
+
+def _passes(cfg, params, run_pass, carry, xs=None):
+    """``run_pass(carry, xs_r) -> (carry, ys_r)`` runs the whole stack once
+    (``carry`` leads with x).  Any model but a looped one: that call, on
+    ``xs`` as it is.  A looped model: ``loop_passes`` calls as ONE scan on
+    the device over the leading axis of ``xs`` (what a pass has of its own: a
+    contiguous cache's layers, a pool's offset), the same weights in every
+    pass and the final norm on x after each."""
+    if cfg.loop_passes == 1:
+        return run_pass(carry, xs)
+
+    def one(carry, xs_r):
+        (x, *rest), ys_r = run_pass(carry, xs_r)
+        return (_final_norm(cfg, params, x), *rest), ys_r
+
+    # x in float32 from here to the head: every add of a branch rounds it
+    carry = (carry[0].astype(jnp.float32), *carry[1:])
+    return jax.lax.scan(one, carry, xs, length=cfg.loop_passes)
 
 
 def _rope(q, k, positions, theta, head_dim, rotary_dim=None,
@@ -1885,6 +2002,7 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
       post-LN (BERT)               x = LN(x + attn(x));  x = LN'(x + mlp(x))
       two mixers (Falcon-H1)       n = LN(x);  x += a attn(n) + b ssm(n);
                                    x += mlp(LN'(x))
+      sandwich (Ouro)              x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))
 
     What attention reads, and where K/V go, is the caller's:
     ``attend(q, k, v) -> (out [B,S,Hq,hd], state)`` is handed the layer's
@@ -1902,7 +2020,7 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     post = cfg.post_layernorm
     h = x if post else _norm(cfg, x, lp["attn_norm_scale"],
                              lp.get("attn_norm_bias"))
-    h = _maybe_act_quant(cfg, h)
+    h = _maybe_act_quant(cfg, _off_stream(cfg, h))
     if ssm is not None:
         # both mixers read ONE normed input, each through its own multiplier
         side, ssm_kept = ssm(lp, h)
@@ -1924,6 +2042,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     # vjp, in the layout its backward reads), so a remat policy can keep it
     attn, state = attend(q, k, v)
     attn = _attn_out(cfg, lp, attn, proj)
+    if cfg.sandwich_norm:
+        attn = _norm(cfg, attn, lp["attn_post_norm_scale"])
     attn, rng = _dropout(cfg, attn, rng, deterministic)
     if ssm is not None:
         attn = (_scaled(attn, cfg.attn_out_multiplier)
@@ -1936,12 +2056,14 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     elif cfg.parallel_residual and cfg.shared_layernorm:
         h2 = h
     else:
-        h2 = _maybe_act_quant(cfg, _norm(
+        h2 = _maybe_act_quant(cfg, _off_stream(cfg, _norm(
             cfg, x if cfg.parallel_residual else res,
-            lp["mlp_norm_scale"], lp.get("mlp_norm_bias")))
+            lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))))
     rng, sub = jax.random.split(rng)
     m, aux, counts = _mlp(cfg, lp, h2, sub, deterministic,
                           token_mask=token_mask, expert_offset=expert_offset)
+    if cfg.sandwich_norm:
+        m = _norm(cfg, m, lp["mlp_post_norm_scale"])
     m, rng = _dropout(cfg, m, rng, deterministic)
     x = res + m
     if post:
@@ -2138,9 +2260,14 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
             (x, r, aux), _ = step(positions, carry, xs)
             return (constrain_spec(x, act_spec), r, aux), None
 
-        (x, _, aux_total), _ = jax.lax.scan(
-            body, (x, rng, jnp.float32(0.0)),
-            (params["layers"], keep, windows))
+        if keep is not None and cfg.loop_passes > 1:
+            raise NotImplementedError(
+                "progressive layer drop does not take a looped model "
+                "(loop_passes)")
+        (x, _, aux_total), _ = _passes(
+            cfg, params, lambda carry, _: jax.lax.scan(
+                body, carry, (params["layers"], keep, windows)),
+            (x, rng, jnp.float32(0.0)))
     else:
         carry = (x, rng, jnp.float32(0.0))
         for i in range(cfg.num_layers):
@@ -2251,13 +2378,14 @@ def init_cache(cfg: TransformerConfig, batch_size: int, max_len: int,
     ``max_len`` total tokens (prompt + generated).
 
     Layout: ``k``/``v`` are ``[L, B, T, Hkv, hd]`` (stacked over layers so the
-    layer scan consumes/produces them as xs/ys); ``valid`` marks attended
+    layer scan consumes/produces them as xs/ys; ``L`` is :func:`cache_depth`,
+    a looped model's ``loop_passes * num_layers``); ``valid`` marks attended
     slots, ``pos`` stores each slot's position id (alibi needs relative
     positions), ``next_slot`` is the global write cursor (identical across
     rows because pad tokens occupy slots too).
     """
     dtype = dtype or cfg.dtype
-    L, B, T = cfg.num_layers, batch_size, max_len
+    L, B, T = cache_depth(cfg), batch_size, max_len
     kv = (L, B, T, cfg.kv_heads, cfg.dims_per_head)
     return {
         "k": jnp.zeros(kv, dtype),
@@ -2378,9 +2506,18 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
             cfg, ck, cv, positions, q_slot, valid, kpos, next_slot, w))
         return constrain_spec(x, P(BATCH_AXES, None, None)), kv
 
-    x, (ck_all, cv_all) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"],
-                  layer_windows(cfg)))
+    # a looped model's cache is cut into its passes' own layers
+    kv = tuple(a.reshape(cfg.loop_passes, -1, *a.shape[1:])
+               if cfg.loop_passes > 1 else a
+               for a in (cache["k"], cache["v"]))
+
+    def run_pass(carry, kv):
+        x, ys = jax.lax.scan(body, carry[0], (params["layers"], *kv,
+                                              layer_windows(cfg)))
+        return (x,), ys
+
+    (x,), kv = _passes(cfg, params, run_pass, (x,), kv)
+    ck_all, cv_all = (a.reshape(cache["k"].shape) for a in kv)
     logits = _head(cfg, params, x)
     new_cache = {"k": ck_all, "v": cv_all, "valid": valid, "pos": kpos,
                  "next_slot": next_slot + S}
@@ -2467,7 +2604,10 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      kv_dtype=None, window_pages: Optional[int] = None,
                      slots: int = 1) -> Dict[str, Any]:
     """Allocate the physical page pool: ``k``/``v`` are
-    ``[L, num_pages, page_size, Hkv, hd]``.
+    ``[L, num_pages, page_size, Hkv, hd]``, ``L`` the cache's depth
+    (:func:`cache_depth`: a looped model's ``loop_passes * num_layers``, pass
+    ``r``'s layer ``l`` at ``r * num_layers + l``; a page id addresses every
+    pass's and every layer's rows of its 128 tokens).
 
     A model with state-space layers adds ``ssm_state [L, slots, heads,
     head_dim, state]`` (float32) and ``ssm_conv [L, slots, taps - 1,
@@ -2545,12 +2685,12 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      cfg.ssm_state), jnp.float32),
                 "ssm_conv": jnp.zeros(
                     (cfg.num_layers, slots, cfg.ssm_conv - 1, conv), dtype)}
-    lead = (cfg.num_layers, num_pages, page_size, cfg.kv_heads)
+    lead = (cache_depth(cfg), num_pages, page_size, cfg.kv_heads)
     kv = lead + (cfg.dims_per_head,)
     if _normalize_kv_dtype(kv_dtype) is None:
         return {"k": jnp.zeros(kv, dtype),
                 "v": jnp.zeros(lead + (cfg.v_dims_per_head,), dtype)}
-    sc = (cfg.num_layers, num_pages, page_size)
+    sc = lead[:3]
     return {"k": jnp.zeros(kv, jnp.int8), "v": jnp.zeros(kv, jnp.int8),
             "k_scale": jnp.zeros(sc, jnp.float32),
             "v_scale": jnp.zeros(sc, jnp.float32)}
@@ -3384,7 +3524,14 @@ def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
             "start a tail from or to go back to")
     if is_grouped(cfg):
         return "grouped", "leading dense layers (dense_layers)"
-    return "uniform", "one stack of equal layers over K and V pages"
+    if cfg.loop_passes > 1:
+        return "looped", (
+            f"one stack of equal layers run {cfg.loop_passes} times "
+            "(loop_passes): K and V pages of one pool, loop_passes x "
+            "num_layers layers deep, so a page is every pass's rows of its "
+            "tokens")
+    return "uniform", ("one stack of equal layers over K and V pages of one "
+                       "pool, num_layers layers deep")
 
 
 def _hybrid_refuse(what: str, cfg: TransformerConfig):
@@ -3644,12 +3791,23 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                for name, (g, n) in layer_groups(cfg).items()}
               if is_grouped(cfg)
               else {None: (cfg, cfg.num_layers, params["layers"])})
-    first, counts = 0, None
-    for g, n, layers in groups.values():
-        x, pools, c = _paged_layers(
-            g, layers, x, pools, first, n, num_pages, positions, seq_mask,
-            write, read, within, pool_order, adapters, ssm)
-        first, counts = first + n, (counts if c is None else c)
+    def run_pass(carry, pool_first):
+        x, pools = carry
+        first, counts = 0, None
+        for g, n, layers in groups.values():
+            x, pools, c = _paged_layers(
+                g, layers, x, pools, first, n, num_pages, positions,
+                seq_mask, write, read, within, pool_order, adapters, ssm,
+                pool_first)
+            first, counts = first + n, (counts if c is None else c)
+        return (x, pools), counts
+
+    # a looped model: every pass the same weights over its own layers of
+    # the pool, pass r's from layer r * num_layers on
+    (x, pools), counts = _passes(
+        cfg, params, run_pass, (x, pools),
+        None if cfg.loop_passes == 1 else
+        jnp.arange(cfg.loop_passes, dtype=jnp.int32) * cfg.num_layers)
     logits = _head_at(cfg, params, x, logits_at)
     cache = {k: (jnp.transpose(a, (0, 2, 1, 3)) if head_major.get(k) else a
                  ).reshape(cache[k].shape) for k, a in pools.items()}
@@ -3712,12 +3870,18 @@ def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
 
 def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
                   positions, seq_mask, write, read, within, pool_order,
-                  adapters, ssm=None):
+                  adapters, ssm=None, pool_first=None):
     """``n`` equal layers of :func:`forward_paged` as one scan, the model's
     layers ``first .. first + n - 1``: ``(x, pools, counts)`` with the pool
     as carry, layer ``l``'s pages at ``l * num_pages`` of the stacked
     leaves.  ``cfg`` is the layers' uniform config and ``layers`` their
     stack (the whole model's, or one group's of :func:`layer_groups`).
+    ``first`` is an index into the WEIGHTS' layers (whose experts, whose
+    state rows); where the pool is deeper than the weights (a looped model's
+    pass ``r``) ``pool_first`` (a traced scalar, ``r * num_layers``) is the
+    pool layer that weight layer 0 reads and writes in this call, and layer
+    ``l``'s pages lie at ``(pool_first + l) * num_pages``.  None: the two
+    are one.
     ``ssm = (slots, state_slot, start)``: the model has state-space layers,
     whose two slot-indexed leaves ride the carry with the pages
     (:func:`_ssm_paged`)."""
@@ -3739,9 +3903,11 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
     def body(carry, layer):
         x, pools = carry
         lp, first_page, factors = layer
-        wplan = (src, keep, write_pages + first_page)
+        pool_page = (first_page if pool_first is None
+                     else first_page + pool_first * num_pages)
+        wplan = (src, keep, write_pages + pool_page)
         rplan = (None if read is None else
-                 (read[0], read[1], read[2] + first_page, read[3]))
+                 (read[0], read[1], read[2] + pool_page, read[3]))
         kv = {k: v for k, v in pools.items() if k not in SSM_POOL_KEYS}
         attend = (_attend_latent_paged(cfg, kv, wplan, rplan, within)
                   if is_latent(cfg) else

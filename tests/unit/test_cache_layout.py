@@ -36,15 +36,20 @@ CONFIGS = {
         num_heads=4, num_kv_heads=2, head_dim=16, vocab_size=256,
         ssm_heads=4, ssm_head_dim=8, ssm_state=16, ssm_groups=2, ssm_chunk=8,
         max_seq_len=512, dtype=jnp.float32),
+    "looped": lambda: get_config(
+        "ouro-2.6b", num_layers=3, hidden_size=64, intermediate_size=96,
+        num_heads=4, num_kv_heads=4, head_dim=16, vocab_size=256,
+        max_seq_len=512, dtype=jnp.float32),
 }
 # what each kind's refusal names the model by (tier-1 matches on these)
 NAMED = {"grouped": ("leading dense layers",),
          "window": ("window", "layer_pattern"),
          "latent": ("latent",),
-         "state": ("state-space layers (a state a slot)",)}
+         "state": ("state-space layers (a state a slot)",),
+         "looped": ("loop_passes",)}
 # mechanism x kind, written out: True = works on that kind of cache
 PAGES_ALONE = dict(uniform=True, grouped=True, window=False, latent=False,
-                   state=False)
+                   state=False, looped=True)
 TABLE = {
     "tensor-sharded heads (tp > 1)": PAGES_ALONE,
     "copy-on-write page snapshots (prefix_cache=True)": PAGES_ALONE,
@@ -91,7 +96,10 @@ def test_pools_and_what_the_cache_is_made_with(kind):
     assert lay.pools[0] == (1 + SLOTS * MAXP, MAXP)
     assert lay.stateful == (kind == "state")
     assert lay.allows("prefix sharing (prefix_cache=True)") == (
-        kind in ("uniform", "grouped"))
+        kind in ("uniform", "grouped", "looped"))
+    # a page's rows: a layer of the model, or of a looped model of every pass
+    assert (lay.passes, lay.depth) == ((4, 12) if kind == "looped" else
+                                       (1, lay.cfg.num_layers))
     if kind == "window":
         ring = T.window_ring_pages(16, PAGE)
         assert lay.ring_pages == ring == 3
@@ -99,13 +107,20 @@ def test_pools_and_what_the_cache_is_made_with(kind):
         assert lay.kind_heads == {"full": 2 * 2, "window": 4 * 5}
         pools = [PagePool(n, SLOTS, per) for n, per in lay.pools]
         pools[0].take(7), pools[1].take(6)
-        assert lay.tick_attrs(pools) == {"pages_full": 7, "pages_window": 6}
+        assert lay.tick_attrs(pools, False) == {
+            "page_wait": 0, "pages_free": SLOTS * MAXP - 7,
+            "pages_full": 7, "pages_window": 6}
     else:
         assert len(lay.pools) == 1 and not lay.ring_pages
-        assert lay.tick_attrs([]) == {}
+        pool = PagePool(lay.pools[0][0], SLOTS, lay.pools[0][1])
+        pool.take(5)
+        assert lay.tick_attrs([pool], True) == {
+            "page_wait": 1, "pages_free": SLOTS * MAXP - 5}
         assert lay.window_pages == 0 and lay.kind_heads == {}
     cache = jax.eval_shape(lambda: T.init_paged_cache(
         lay.cfg, lay.pools[0][0], PAGE, **lay.pool_kw))
+    if kind in ("uniform", "looped"):
+        assert cache["k"].shape[0] == lay.depth
     leaves = {"window": {"k", "v", "k_window", "v_window"},
               "latent": {"latent"},
               "state": {"k", "v", "ssm_state", "ssm_conv"}}
@@ -124,10 +139,13 @@ LENGTHS = [[1], [8, 9, 30], [17, 64], [96, 3, 40]]
 def test_decode_attrs_are_the_hosts_row_counts(kind, lengths):
     lay = layout(kind)
     lay.state_slot_bytes, lay.state_passes = 1000, 3    # the executor's
+    lay.kv_token_bytes = 96
     a = lay.decode_attrs(lengths, SLOTS)
     rows = T.paged_read_rows(lengths, PAGE, MAXP, SLOTS)
     assert a["gathered_rows"] == rows >= sum(lengths)
-    want = {"gathered_rows"}
+    assert a["passes"] == (4 if kind == "looped" else 1)
+    assert a["kv_bytes"] == 96 * sum(lengths)
+    want = {"gathered_rows", "passes", "kv_bytes"}
     if kind == "window":
         want |= {"kv_rows_full", "kv_live_rows_full", "kv_rows_window",
                  "kv_live_rows_window"}
@@ -152,8 +170,11 @@ def test_prefill_attrs_are_the_hosts_trip_counts(monkeypatch, kind, bucket,
     monkeypatch.setattr(T, "CAUSAL_BLOCK_CHUNK", 4)     # tiny prompts walk
     monkeypatch.setattr(T, "WINDOW_BLOCK_CHUNKS", 1)
     lay = layout(kind)
+    lay.kv_token_bytes = 96                             # the executor's
     a = lay.prefill_attrs(bucket, tokens, shared)
-    want = {"gathered_rows"}
+    assert a["passes"] == (4 if kind == "looped" else 1)
+    assert a["kv_bytes"] == 96 * (shared + tokens)
+    want = {"gathered_rows", "passes", "kv_bytes"}
     if kind in ("window", "latent"):
         want |= {"walk_steps", "walk_steps_bucket"}
         assert a["gathered_rows"] == 0
