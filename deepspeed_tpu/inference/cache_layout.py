@@ -81,9 +81,10 @@ class CacheLayout:
         self.block_attends_itself = self.kind in ("window", "latent")
         # a slot's bytes of state and the passes the tick's step makes over
         # them, and the bytes one token's rows take in the paged leaves over
-        # every layer and pass: the executor that made the pool and traced
-        # the tick says
+        # every layer and pass; the paged leaves a tick writes by row and by
+        # page: the executor that made the pool and traced the tick says
         self.state_slot_bytes = self.state_passes = self.kv_token_bytes = 0
+        self.kv_write_leaves = (0, 0)
 
     # ------------------------------------------------------- mechanisms
 
@@ -113,7 +114,10 @@ class CacheLayout:
         live ones hold ``lengths`` rows, the row being written counted in.
         ``gathered_rows``: K/V rows its read covers a layer, each slot's own
         pages in whole steps; ``passes`` times the model's layers read them,
-        and ``kv_bytes`` is what the rows held take over all of those.  Two
+        and ``kv_bytes`` is what the rows held take over all of those.
+        ``kv_row_write_leaves`` / ``kv_page_write_leaves``: the paged leaves
+        into which the tick stores a token's row where it lies, and those it
+        merges a slot's whole page into (static a program).  Two
         kinds of layer: K/V head rows read and live a kind (a window layer
         reads the ring pages under its window).
         A state a slot: the slots whose state the tick read and wrote, the
@@ -123,7 +127,9 @@ class CacheLayout:
                                slots)
         attrs: Dict[str, Any] = {
             "gathered_rows": rows, "passes": self.passes,
-            "kv_bytes": int(lengths.sum()) * self.kv_token_bytes}
+            "kv_bytes": int(lengths.sum()) * self.kv_token_bytes,
+            "kv_row_write_leaves": self.kv_write_leaves[0],
+            "kv_page_write_leaves": self.kv_write_leaves[1]}
         if self.kind == "window":
             W = self.cfg.window_size
             attrs.update(self._kv_row_attrs(

@@ -53,7 +53,7 @@ from ..models.transformer import (PAGED_POOL_KEYS, SSM_POOL_KEYS,
                                   expert_counts_shape, is_hybrid,
                                   paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, per_layer_leaves,
-                                  ssm_step_path)
+                                  kv_write_paths, ssm_step_path)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .cache_layout import CacheLayout
@@ -325,6 +325,11 @@ class MeshExecutor:
         orders = [paged_pool_order(f) for f in make_pool.output_formats]
         self.pool_order = (dict(zip(self._pool_keys, orders))
                            if len(layout.pools) > 1 else orders[0])
+        # how a decode tick lays its rows into each paged leaf, chosen where
+        # it is traced from the backend, the leaf's shape and the order just
+        # observed: "row" (the kernel: one row a slot stored where it lies)
+        # or "page" (each slot's page gathered, merged and scattered back)
+        self.kv_write = kv_write_paths(cfg, shapes, self.pool_order)
         self._decode_prog = self._build_decode()
         # The weights, placed ONCE in the form the decode program consumes
         # (docs/SERVING.md "Weight placement"), as the pool is stored the
@@ -395,6 +400,9 @@ class MeshExecutor:
             if k in SSM_POOL_KEYS)
         layout.state_slot_bytes = self.state_bytes // self.b_slots
         layout.state_passes = self.state_passes
+        layout.kv_write_leaves = tuple(
+            sum(path == by for path in self.kv_write.values())
+            for by in ("row", "page"))
         # one token's rows in every paged leaf, over every layer and pass
         layout.kv_token_bytes = sum(
             int(a.nbytes) // (a.shape[1] * self.page_size)
@@ -880,7 +888,9 @@ class MeshExecutor:
         tree that already lay so (a warm restart's).  ``ssm_step``: the
         step the decode tick of a model with a state a slot holds
         (``"one_pass"`` / ``"xla"``: ``models.transformer.ssm_step_path``),
-        ``None`` for any other model.  ``loop_passes``: how often a token
+        ``None`` for any other model.  ``kv_write``: how the decode tick
+        lays a token's rows into each paged leaf (``"row"`` / ``"page"``:
+        ``models.transformer.kv_write_path``).  ``loop_passes``: how often a token
         runs the model's layers (a looped model's ``loop_passes``, else 1),
         and ``kv_bytes_per_token``: what one token's rows take in the paged
         leaves over every layer and pass."""
@@ -890,6 +900,7 @@ class MeshExecutor:
                     a: int(mesh.shape[a]) for a in mesh.axis_names
                     if int(mesh.shape[a]) > 1},
                 **self.weight_placement, "ssm_step": self.ssm_step,
+                "kv_write": dict(self.kv_write),
                 "loop_passes": self.layout.passes,
                 "kv_bytes_per_token": self.layout.kv_token_bytes}
 

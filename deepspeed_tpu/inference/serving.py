@@ -735,7 +735,9 @@ class ServingEngine:
             + f" weights: {info['weight_leaves_split']} stack(s) held a "
             f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
             f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out"
-            + (f" ssm_step={info['ssm_step']}" if info["ssm_step"] else ""),
+            + (f" ssm_step={info['ssm_step']}" if info["ssm_step"] else "")
+            + " kv_write=" + ",".join(
+                f"{leaf}:{path}" for leaf, path in info["kv_write"].items()),
             ranks=[0])
 
     def program_inventory(self) -> Dict[str, Any]:

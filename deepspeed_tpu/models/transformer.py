@@ -1868,14 +1868,15 @@ def _ssm_step(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
     return y.reshape(B, 1, H, P), s.reshape(B, H, P, N)
 
 
-def _ssm_kernel_interpret() -> Optional[bool]:
-    """``interpret`` for the one-pass step's kernel where a program traced
-    now may hold it, ``None`` where it may not: Pallas kernels compile for
-    the TPU (``ops/pallas/common.py``'s own test of the backend) and
-    ``pallas_call`` has no partitioning rule, so any other backend, and a
-    mesh of more than one device, keep :func:`_ssm_step`.  Never a config
-    field or an environment variable; a test that compiles for a described
-    chip, or runs the kernel in interpret mode, replaces this function."""
+def _pallas_interpret() -> Optional[bool]:
+    """``interpret`` for a Pallas kernel over a cache leaf (the one-pass
+    state step, the K/V row write) where a program traced now may hold one,
+    ``None`` where it may not: Pallas kernels compile for the TPU
+    (``ops/pallas/common.py``'s own test of the backend) and ``pallas_call``
+    has no partitioning rule, so any other backend, and a mesh of more than
+    one device, keep the ``jax.numpy`` path.  Never a config field or an
+    environment variable; a test that compiles for a described chip, or runs
+    a kernel in interpret mode, replaces this function."""
     from ..parallel import mesh as mesh_mod
 
     m = mesh_mod._GLOBAL_MESH
@@ -1901,7 +1902,7 @@ def ssm_step_path(cfg: TransformerConfig, tokens: int = 1,
     if not is_ssm(cfg) or tokens != 1:
         return None
     if (state_slot is None and dtype == jnp.float32
-            and _ssm_kernel_interpret() is not None
+            and _pallas_interpret() is not None
             and head_block(cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim,
                            cfg.ssm_state) is not None):
         return "one_pass"
@@ -1925,7 +1926,7 @@ def _ssm_step_one_pass(x, Bm, Cm, dt, A, leaf, row0, fresh):
             leaf, row0, fresh, jnp.exp(dt1 * A),
             x[:, 0].astype(jnp.float32) * dt1[..., None],
             Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32),
-            interpret=_ssm_kernel_interpret())
+            interpret=_pallas_interpret())
     return y[:, None], leaf
 
 
@@ -2599,6 +2600,58 @@ def kv_leaf_head_major(cfg: TransformerConfig, width: int) -> bool:
     return is_ssm(cfg) and pool_leaf_head_major(cfg.kv_heads, width)
 
 
+# what a kind of layer's K/V leaves add to ``k``/``v`` in the cache's keys
+_KIND_SUFFIX = {"full": "", "window": "_window"}
+
+
+def _head_major_leaves(cfg: TransformerConfig) -> Dict[str, bool]:
+    """Which K/V leaves of ``cfg``'s cache are kept head-major, by the
+    cache's own keys: ``k``/``v`` (:func:`kv_leaf_head_major`), and of a model
+    with two kinds of layer each kind's (``k_window``/``v_window`` the window
+    layers': :func:`pool_leaf_head_major`)."""
+    if not is_hybrid(cfg):
+        return {n: kv_leaf_head_major(cfg, w) for n, w in (
+            ("k", cfg.dims_per_head), ("v", cfg.v_dims_per_head))}
+    return {n + _KIND_SUFFIX[kind]: pool_leaf_head_major(g.kv_heads, w)
+            for kind, (g, _) in kind_layers(cfg).items()
+            for n, w in (("k", g.dims_per_head), ("v", g.v_dims_per_head))}
+
+
+def _leaf_order(pool_order, name: str):
+    """The stored order of the pool leaf ``name``: ``pool_order`` is one
+    observed order for every leaf, or one a leaf (K and V of different
+    widths may be stored differently)."""
+    return (pool_order.get(name) if isinstance(pool_order, dict)
+            else pool_order)
+
+
+def _seen_order(head_major: Dict[str, bool], pool_order, key: str):
+    """The stored order of the cache leaf ``key`` as the paged forward sees
+    it, ``[N, page, Hkv, w]``: a head-major leaf through the transpose that
+    moves nothing, any other as the caller observed it."""
+    return ((0, 1, 3, 2, 4) if head_major.get(key)
+            else _leaf_order(pool_order, key))
+
+
+def kv_write_paths(cfg: TransformerConfig, cache: Dict[str, Any],
+                   pool_order) -> Dict[str, str]:
+    """:func:`kv_write_path` of a decode tick for every paged leaf of
+    ``cache`` (``init_paged_cache``'s arrays or their shapes) stored in
+    ``pool_order``, as :func:`forward_paged` hands the leaf to its layers:
+    ``{leaf: "row" | "page"}``.  The same function of the same leaf and
+    order that :func:`_attend_paged` asks when the tick is traced (a latent
+    leaf, which :func:`_attend_latent_paged` merges without asking, has no
+    head axis for the tile plan to take);
+    ``tests/unit/test_kv_row_write_kernel.py`` holds this report to the
+    kernels a traced tick holds, leaf by leaf."""
+    head_major = _head_major_leaves(cfg)
+    return {key: kv_write_path(
+        jax.ShapeDtypeStruct((a.shape[0] * a.shape[1],) + tuple(a.shape[2:]),
+                             a.dtype),
+        _seen_order(head_major, pool_order, key))
+        for key, a in cache.items() if key not in SSM_POOL_KEYS}
+
+
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      page_size: int = PAGE_SIZE, dtype=None,
                      kv_dtype=None, window_pages: Optional[int] = None,
@@ -2853,10 +2906,7 @@ def _pool_views(pools, pool_order):
     pool ``[N, page]`` go as they are."""
     views, axes = {}, {}
     for n, a in pools.items():
-        # one observed order for every leaf, or one a leaf (K and V of
-        # different widths may be stored differently)
-        order = pool_order.get(n) if isinstance(pool_order, dict) \
-            else pool_order
+        order = _leaf_order(pool_order, n)
         perm = (0, 1, 2, 3)
         if order is not None and tuple(order[:2]) == (0, 1):
             perm = (0,) + tuple(i - 1 for i in order[2:])
@@ -3033,12 +3083,36 @@ def _adapter_proj(adapters, ad_scale):
     return proj
 
 
+def kv_write_path(leaf, order, tokens: int = 1) -> str:
+    """How a block of ``tokens`` a slot lays its rows into the pool leaf
+    ``leaf [N, page, ...]`` (an array or its shape and dtype) that the device
+    stores in ``order`` (:func:`paged_pool_order`; ``None``: row-major):
+    ``"row"`` (``ops/pallas/kv_row_write.py``: one row a slot stored where it
+    lies) for one token a slot into a row-major K or V leaf whose shape the
+    kernel's tile plan takes, in a program that may hold a Pallas kernel
+    (:func:`_pallas_interpret`); ``"page"`` (:func:`_merge_pages`: each
+    slot's pages gathered, merged and scattered back whole) for every other
+    write: a prompt or a verify block, the scale planes of a quantized pool
+    and its int8 rows, the latent leaf, a leaf stored page-rows-minor or kept
+    head-major (the kernel would have the whole pool copied into row-major
+    order and back), a mesh of more than one device, any other backend.  One
+    plan says where a row goes either way (:func:`_paged_write_plan`,
+    :func:`_ring_write_plan`).  Read at trace time from what the code can
+    observe; the serving executor reports it (``mesh_info()["kv_write"]``)."""
+    from ..ops.pallas.kv_row_write import row_block
+
+    if (tokens == 1 and order is None and _pallas_interpret() is not None
+            and row_block(leaf.shape, leaf.dtype) is not None):
+        return "row"
+    return "page"
+
+
 def _merge_pages(pool, rows, write):
     """``rows [B,S,...]`` of a block of tokens laid into ``pool [N, page,
     ...]`` by the block's page-merge plan ``write = (src, keep, pages)``
     (:func:`_paged_write_plan`): the rows of their pages ``[B,n_pg,page,
     ...]`` over what those pages hold, gathered and scattered back whole."""
-    src, keep, pages = write
+    src, keep, pages = write[:3]
     w = (1,) * (rows.ndim - 2)
     new = jnp.take_along_axis(rows, src.reshape(*src.shape, *w),
                               axis=1).astype(pool.dtype)
@@ -3159,23 +3233,39 @@ def _attend_latent_paged(cfg, pools, write, read, within=None):
 
 def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
                   within=None):
-    """:func:`_block`'s ``attend`` against the paged pool, addressed a whole
-    page at a time; the pool is what is kept.  ``pools`` maps each pool leaf
-    (``k``/``v``, plus ``k_scale``/``v_scale`` on a quantized pool) to its
-    array with the page axis leading: ``[N, page, Hkv, hd]`` (scales ``[N,
-    page]``) — the stacked pool with ``N = L*P`` from :func:`forward_paged`.
+    """:func:`_block`'s ``attend`` against the paged pool; the pool is what
+    is kept.  ``pools`` maps each pool leaf (``k``/``v``, plus
+    ``k_scale``/``v_scale`` on a quantized pool) to its array with the page
+    axis leading: ``[N, page, Hkv, hd]`` (scales ``[N, page]``) — the
+    stacked pool with ``N = L*P`` from :func:`forward_paged`.
 
-    Write: ``write = (src, keep, pages)`` is the block's page-merge plan
+    Write: ``write = (src, keep, pages, row)`` is the block's plan
     (:func:`_paged_write_plan`, one for all layers, ``pages`` moved to this
-    layer's): the pages are gathered, merged and scattered back whole.
+    layer's), laid into each leaf at one of two granularities
+    (:func:`kv_write_path`).  **A page at a time** (:func:`_merge_pages`):
+    the slots' pages are gathered, merged and scattered back whole: every
+    prompt and verify block, and every block into a leaf that is not
+    row-major as the device stores it (``pool_order``), that the row write's
+    tile plan refuses (scale planes, int8 rows, a head that is not whole
+    lanes, a number of KV heads that is no whole tile of sublanes), or in a
+    program that may hold no Pallas kernel (not a TPU, a mesh of several
+    devices).  **A row** (``ops/pallas/kv_row_write.py``):
+    one token a slot into a row-major K or V leaf stores the slot's one row
+    where it lies, the leaf aliased through the kernel: 4 KB a slot where
+    the merge moves two pages (PERF.md, PR 45).
     Read: ``read`` is the call's list of live (slot, page) pairs
     (:func:`_paged_read_plan`, one for all layers, its pages moved to this
     layer's), which :func:`_attention_paged` gathers a step's worth of
-    whole pages at a time.  Every pool op thus slices all
-    trailing axes, so it runs in whatever layout the pool is stored in
-    (``pool_order``, :func:`_pool_views`) and the pool stays in place; a
-    row-granular scatter or gather makes the TPU compiler re-lay the whole
-    pool out around the layer scan (PERF.md, PR 25).
+    whole pages at a time.  The XLA pool ops thus slice all
+    trailing axes, so they run in whatever layout the pool is stored in
+    (``pool_order``, :func:`_pool_views`) and the pool stays in place.  What
+    is known of a row-granular write: an XLA scatter of rows makes the TPU
+    compiler re-lay the whole pool out around the layer scan (PERF.md,
+    PR 25); a Pallas kernel fixes its operands' layout, so over a row-major
+    leaf it leaves the pool where it is, through the layer scan and a looped
+    model's scan of passes; over a leaf stored page-rows-minor the compiler
+    would copy the whole pool into row-major order in front of the kernel
+    and back behind it, which is why the rule reads the stored order.
 
     A quantized pool quantizes each written row on store (symmetric absmax,
     :func:`kv_quantize_rows`), merges its scale through the SAME plan, and
@@ -3193,7 +3283,14 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
     (:func:`_attention_causal_block`) and reads nothing back; ``reach``
     (:func:`_block_reach`) is how far into the block real tokens reach,
     where both stop."""
-    def merge(pool, rows):
+    def merge(name, pool, rows):
+        if kv_write_path(pool, _leaf_order(pool_order, name),
+                         rows.shape[1]) == "row":
+            from ..ops.pallas.kv_row_write import kv_row_write
+
+            return kv_row_write(pool, rows[:, 0].astype(pool.dtype),
+                                write[2][:, 0], write[3],
+                                interpret=_pallas_interpret())
         return _merge_pages(pool, rows, write)
 
     def attend(q, k, v):
@@ -3206,7 +3303,7 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
                     q8, sc = kv_quantize_rows(r.reshape(B * S, nkv, hd))
                     rows[name] = q8.reshape(B, S, nkv, hd)
                     rows[name + "_scale"] = sc.reshape(B, S)
-            new = {name: merge(pool, rows[name])
+            new = {name: merge(name, pool, rows[name])
                    for name, pool in pools.items()}
             for name in ("k", "v"):
                 new[name] = constrain_spec(new[name],
@@ -3402,10 +3499,32 @@ def _attention_window_block(cfg, q, k, v, positions, window: int, sink=None,
     return out.reshape(B, S, Hq, v.shape[-1])
 
 
+def _kept_row(keep, tokens: int):
+    """The fourth member of a write plan: for a block of ONE token a slot,
+    the row ``[B]`` of its one page that the plan keeps (``start % page``; -1
+    where it keeps none: a masked or idle slot, a position past the page
+    table), which is all a write by row needs beside ``pages``; ``None`` for
+    a longer block.  Whether a leaf is written by row is
+    :func:`kv_write_path`'s to say, leaf by leaf; where none is, nothing
+    reads the row and the compiler drops it."""
+    if tokens != 1:
+        return None
+    kept = keep[:, 0]
+    return jnp.where(kept.any(-1), jnp.argmax(kept, axis=-1), -1).astype(
+        jnp.int32)
+
+
+def _plan_at(write, first_page):
+    """A block's write plan moved to the layer whose pages start at
+    ``first_page`` of the stacked pool."""
+    src, keep, pages, row = write
+    return src, keep, pages + first_page, row
+
+
 def _paged_write_plan(page_table, start, seq_mask, ps: int):
     """How a block of tokens ``[B,S]`` at slot positions ``start + s`` lands
     in whole pages: ``(src [B, n_pg*ps], keep [B, n_pg, ps], pages [B,
-    n_pg])``, one plan for all layers.
+    n_pg], row)``, one plan for all layers (``row``: :func:`_kept_row`).
 
     S consecutive positions fall in at most ``n_pg`` logical pages; row r
     of the j-th of them holds position ``(start // ps + j) * ps + r``,
@@ -3430,14 +3549,14 @@ def _paged_write_plan(page_table, start, seq_mask, ps: int):
         keep.any(-1),
         jnp.take_along_axis(page_table, jnp.minimum(lpage, maxp - 1), axis=1),
         0)
-    return src, keep, pages
+    return src, keep, pages, _kept_row(keep, S)
 
 
 def _ring_write_plan(ring_table, start, seq_mask, ps: int):
     """:func:`_paged_write_plan` for a window layer, whose slot keeps a ring
     of ``R = ring_table.shape[1]`` pages (logical page ``j`` in ring page
-    ``j % R``): ``(src, keep, pages)`` over the LAST pages the block's real
-    tokens reach, at most ``R`` of them.  A decode token lands in its page
+    ``j % R``): ``(src, keep, pages, row)`` over the LAST pages the block's
+    real tokens reach, at most ``R`` of them.  A decode token lands in its page
     (what the page held of logical page ``j - R`` behind it is past every
     query's mask); of a prompt longer than the ring only the last rows are
     kept, the rest is never read again (a window layer's queries attend
@@ -3457,7 +3576,7 @@ def _ring_write_plan(ring_table, start, seq_mask, ps: int):
                                           axis=1).reshape(in_block.shape)
     pages = jnp.where(keep.any(-1),
                       jnp.take_along_axis(ring_table, lpage % R, axis=1), 0)
-    return src, keep, pages
+    return src, keep, pages, _kept_row(keep, S)
 
 
 def window_read_rows(lengths, page_size: int, window: int,
@@ -3570,20 +3689,18 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                                                        (tuple, list))
                               else (page_table, None))
     groups = layer_groups(cfg)
-    suffix = {"full": "", "window": "_window"}
+    suffix = _KIND_SUFFIX
     kind_cfg = {kind: g for kind, (g, _) in kind_layers(cfg).items()}
     # each kind's pool stacked [L_kind * P_kind, page, Hkv, w], a leaf kept
     # head-major (pool_leaf_head_major) seen through the transpose that
     # moves nothing, its layers' pages at l * P_kind
-    head_major = {
-        kind: {"k": pool_leaf_head_major(g.kv_heads, g.dims_per_head),
-               "v": pool_leaf_head_major(g.kv_heads, g.v_dims_per_head)}
-        for kind, g in kind_cfg.items()}
+    head_major = _head_major_leaves(cfg)
 
     def stacked(kind, n):
         a = cache[n + suffix[kind]]
         a = a.reshape(-1, *a.shape[2:])
-        return jnp.transpose(a, (0, 2, 1, 3)) if head_major[kind][n] else a
+        return (jnp.transpose(a, (0, 2, 1, 3))
+                if head_major[n + suffix[kind]] else a)
 
     pools = {kind: {n: stacked(kind, n) for n in ("k", "v")}
              for kind in kind_cfg}
@@ -3609,9 +3726,7 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     x = constrain_spec(x, P(BATCH_AXES, None, None))
     rng = jax.random.PRNGKey(0)
     n_pages = {kind: cache["k" + suffix[kind]].shape[1] for kind in pools}
-    orders = {kind: {n: ((0, 1, 3, 2, 4) if head_major[kind][n]
-                         else pool_order.get(n + suffix[kind])
-                         if isinstance(pool_order, dict) else pool_order)
+    orders = {kind: {n: _seen_order(head_major, pool_order, n + suffix[kind])
                      for n in ("k", "v")} for kind in pools}
     # each group's expert stacks whole [n * E, ...] with a layer's experts
     # at l * E: nothing of a layer's size is cut out
@@ -3629,12 +3744,12 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
               if k not in experts[group]}
         first_page = seen[kind] * n_pages[kind]
         seen[kind] += 1
-        (src, keep, wpages), read = plans[kind]
+        write, read = plans[kind]
         if read is not None:
             read = (read[0], read[1], read[2] + first_page) + read[3:]
         x, _, c, pools[kind] = _block(
             g, {**lp, **experts[group]}, x, positions, rng,
-            _attend_paged(g, pools[kind], (src, keep, wpages + first_page),
+            _attend_paged(g, pools[kind], _plan_at(write, first_page),
                           read, orders[kind], sink=lp.get("attn_sink"),
                           within=(None if S == 1 else
                                   (positions, W if kind == "window"
@@ -3648,7 +3763,7 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
             counts.append(c)
     logits = _head_at(cfg, params, x, logits_at)
     out = {n + suffix[kind]: (jnp.transpose(a, (0, 2, 1, 3))
-                              if head_major[kind][n] else a
+                              if head_major[n + suffix[kind]] else a
                               ).reshape(cache[n + suffix[kind]].shape)
            for kind, leaves in pools.items() for n, a in leaves.items()}
     if not expert_counts:
@@ -3746,8 +3861,7 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
                                      pool_order, logits_at)
     # a K/V leaf kept head-major (kv_leaf_head_major) is seen through the
     # transpose that moves nothing, as a two-kind model's (stacked below)
-    head_major = {n: kv_leaf_head_major(cfg, w) for n, w in (
-        ("k", cfg.dims_per_head), ("v", cfg.v_dims_per_head))}
+    head_major = _head_major_leaves(cfg)
     lead = cache["latent" if is_latent(cfg) else "k"]
     num_pages, ps = lead.shape[1], lead.shape[3 if head_major["k"] else 2]
     positions = (start[:, None]
@@ -3778,9 +3892,8 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         if head_major[n]:
             pools[n] = jnp.transpose(pools[n], (0, 2, 1, 3))
     if any(head_major.values()):
-        pool_order = {n: (0, 1, 3, 2, 4) if head_major[n] else
-                      pool_order.get(n) if isinstance(pool_order, dict)
-                      else pool_order for n in ("k", "v")}
+        pool_order = {n: _seen_order(head_major, pool_order, n)
+                      for n in ("k", "v")}
     # a state-space model's slot rows: ``slots`` a layer, the batch's at
     # ``state_slot`` (None: rows 0 .. B - 1)
     ssm = ((cache["ssm_state"].shape[1], state_slot, start)
@@ -3888,7 +4001,6 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
     rng = jax.random.PRNGKey(0)
     ad_scale = (adapters["scale"].astype(jnp.float32)
                 if adapters is not None else None)
-    src, keep, write_pages = write
     # The expert stacks of a dropless model stay out of the scan's xs, for
     # the pool's reason (an 800 MB slice a layer, cut out and copied before
     # the grouped matmuls read it): whole, [n*E, ...], and layer l's experts
@@ -3905,7 +4017,7 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
         lp, first_page, factors = layer
         pool_page = (first_page if pool_first is None
                      else first_page + pool_first * num_pages)
-        wplan = (src, keep, write_pages + pool_page)
+        wplan = _plan_at(write, pool_page)
         rplan = (None if read is None else
                  (read[0], read[1], read[2] + pool_page, read[3]))
         kv = {k: v for k, v in pools.items() if k not in SSM_POOL_KEYS}

@@ -139,13 +139,15 @@ LENGTHS = [[1], [8, 9, 30], [17, 64], [96, 3, 40]]
 def test_decode_attrs_are_the_hosts_row_counts(kind, lengths):
     lay = layout(kind)
     lay.state_slot_bytes, lay.state_passes = 1000, 3    # the executor's
-    lay.kv_token_bytes = 96
+    lay.kv_token_bytes, lay.kv_write_leaves = 96, (1, 3)
     a = lay.decode_attrs(lengths, SLOTS)
     rows = T.paged_read_rows(lengths, PAGE, MAXP, SLOTS)
     assert a["gathered_rows"] == rows >= sum(lengths)
     assert a["passes"] == (4 if kind == "looped" else 1)
     assert a["kv_bytes"] == 96 * sum(lengths)
-    want = {"gathered_rows", "passes", "kv_bytes"}
+    assert (a["kv_row_write_leaves"], a["kv_page_write_leaves"]) == (1, 3)
+    want = {"gathered_rows", "passes", "kv_bytes", "kv_row_write_leaves",
+            "kv_page_write_leaves"}
     if kind == "window":
         want |= {"kv_rows_full", "kv_live_rows_full", "kv_rows_window",
                  "kv_live_rows_window"}

@@ -392,7 +392,7 @@ def test_state_decode_tick_holds_one_kernel_over_the_leaf_on_v5e(
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: False)
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
     depth, slots = 2, 8
     cfg = get_config("falcon-h1-34b", num_layers=depth, vocab_size=16384)
     assert T.ssm_step_path(cfg) == "one_pass"
@@ -446,29 +446,107 @@ def test_state_decode_tick_holds_one_kernel_over_the_leaf_on_v5e(
             assert opcode in passes, (name, opcode)
 
 
-def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
-    """AOT: ``jit_serve_decode``'s body for Kanana-2 at the benchmark's
-    widths and geometry (depth 3: the dense layer and two expert layers, 16
-    experts held, 32 slots of 64 pages), compiled by the installed libtpu
-    with the latent leaf donated.  The v5e stores ``[L, P, 128, 576]`` with
-    the page rows minor-most (``major_to_minor`` (0, 1, 3, 2): 576 is no
-    whole number of 128 lanes), and the absorbed read takes the gathered
-    pages in that order whatever the caller observed: no op's result is the
-    size of the leaf but the in-place page scatters, a gather is one step's
-    (slot, page) pairs, and the program's temporaries are megabytes.  With
-    the products written over ``[N, page, 576]`` the compiler copied the
-    whole leaf in front of every layer's read (0.68 GB of temp at depth 2:
-    PERF.md, PR 32)."""
+def test_looped_decode_tick_writes_its_rows_where_they_lie_on_v5e(
+        one_v5e_chip, monkeypatch):
+    """AOT: ``jit_serve_decode``'s body for Ouro at the published widths (K
+    and V leaves of 16 x 128 a row; depth 2 x 4 passes, 16 slots of 8 pages
+    over the benchmark's 40), compiled by the installed libtpu with the pool
+    donated and the K/V write chosen as on a TPU (the test answers for the
+    backend; the kernel is compiled, not interpreted).  Mosaic accepts the
+    row write; the layer scan's body holds its TWO ``tpu_custom_call``s (K,
+    V); each leaf goes into its call and comes out of it through both scans
+    and NOTHING else of the program writes a tensor of a leaf's size: no page
+    scatter, no copy into another order in front of the call or behind it
+    (an alias the compiler could not honour would copy 4 GB a (pass, layer) in
+    the benchmark's cell); temporaries are a third of one leaf."""
     import re
 
     import numpy as np
 
-    from deepspeed_tpu.models import get_config, init_params
-    from deepspeed_tpu.models.transformer import (forward_paged,
-                                                  paged_read_pairs)
+    from deepspeed_tpu.models import CausalLM, get_config, init_params
+    from deepspeed_tpu.models import transformer as T
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    depth, slots, pages = 2, 16, 40
+    cfg = get_config("ouro-2.6b", num_layers=depth)
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(pages, 128,
+                                                   dtype=jnp.bfloat16)))
+    assert cache["k"].shape == (4 * depth, pages, 128, 16, 128)
+    assert T.kv_write_paths(cfg, cache, None) == {"k": "row", "v": "row"}
+
+    def tick(params, cache, tokens, table, start, mask):
+        logits, cache = T.forward_paged(cfg, params, tokens, cache, table,
+                                        start, mask)
+        return jnp.argmax(logits[:, -1], -1), cache
+
+    compiled = jax.jit(tick, donate_argnums=(1,)).lower(
+        params, cache, S((slots, 1), jnp.int32), S((slots, 8), jnp.int32),
+        S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
+    # 52.0 MB, the page merge's program 52.1: a layer's weight slices
+    # (PERF.md S19d), a third of one leaf
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 2
+    leaf = int(np.prod(cache["k"].shape))
+    passes = {"parameter", "get-tuple-element", "bitcast", "while", "tuple",
+              "custom-call"}
+    leaf_sized = [(name, opcode) for name, opcode, results in
+                  _materialised(text)
+                  if any(int(np.prod(dims)) >= leaf for dims, _ in results)]
+    assert leaf_sized and {op for _, op in leaf_sized} <= passes, leaf_sized
+    assert "custom-call" in {op for _, op in leaf_sized}
+
+
+@pytest.mark.parametrize("width", [128, 256])
+@pytest.mark.parametrize("heads", [2, 4, 8, 16, 24])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_row_write_compiles_for_every_row_its_tile_plan_takes(
+        one_v5e_chip, dtype, heads, width):
+    """AOT: ``kv_row_write`` alone over a donated leaf of every class of row
+    ``row_block`` admits (a decode program that takes the row write has no
+    other path to fall back on when Mosaic refuses its leaf, so the plan
+    admits only what is compiled here).  Mosaic accepts it, and the compiler
+    keeps the leaf row-major and in place: no copy in front of the call or
+    behind it, no temporary."""
+    from deepspeed_tpu.ops.pallas.kv_row_write import kv_row_write, row_block
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    leaf = S((12, 128, heads, width), dtype)
+    assert row_block(leaf.shape, leaf.dtype) == (heads, width)
+    compiled = jax.jit(
+        lambda a, new, pages, rows: kv_row_write(a, new, pages, rows,
+                                                 interpret=False),
+        donate_argnums=(0,)).lower(
+            leaf, S((16, heads, width), dtype), S((16,), jnp.int32),
+            S((16,), jnp.int32)).compile()
+    assert tuple(compiled.input_formats[0][0].layout.major_to_minor) == (
+        0, 1, 2, 3)
+    assert " copy(" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
+
+
+def _latent_tick(chip):
+    """Kanana-2's decode tick at the benchmark's widths and geometry (depth
+    3: the dense layer and two expert layers, 16 experts held, 32 slots of 64
+    pages of 128 rows of 576), the latent leaf donated, compiled for
+    ``chip``: ``(compiled, depth, slots, maxp, page, width)``."""
+    from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models.transformer import forward_paged
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     depth = 3
     cfg = get_config("kanana-2-30b-a3b", num_layers=depth,
@@ -489,6 +567,29 @@ def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
     compiled = jax.jit(tick, donate_argnums=(1,)).lower(
         params, cache, S((slots, 1), jnp.int32), S((slots, maxp), jnp.int32),
         S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
+    return compiled, depth, slots, maxp, page, width
+
+
+def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
+    """AOT: ``jit_serve_decode``'s body for Kanana-2 at the benchmark's
+    widths and geometry (:func:`_latent_tick`), compiled by the installed
+    libtpu with the latent leaf donated.  The v5e stores ``[L, P, 128, 576]``
+    with the page rows minor-most (``major_to_minor`` (0, 1, 3, 2): 576 is no
+    whole number of 128 lanes), and the absorbed read takes the gathered
+    pages in that order whatever the caller observed: no op's result is the
+    size of the leaf but the in-place page scatters, a gather is one step's
+    (slot, page) pairs, and the program's temporaries are megabytes.  With
+    the products written over ``[N, page, 576]`` the compiler copied the
+    whole leaf in front of every layer's read (0.68 GB of temp at depth 2:
+    PERF.md, PR 32)."""
+    import re
+
+    import numpy as np
+
+    from deepspeed_tpu.models.transformer import paged_read_pairs
+
+    compiled, depth, slots, maxp, page, width = _latent_tick(one_v5e_chip)
+    pages = 1 + slots * maxp
     layout = compiled.input_formats[0][1]["latent"].layout
     assert tuple(layout.major_to_minor) == (0, 1, 3, 2)
     assert compiled.memory_analysis().temp_size_in_bytes < 16e6
@@ -511,6 +612,28 @@ def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
             assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
                              r"\(| scatter\(|/scatter\"", line), line[:240]
     assert gathers and max(gathers) == read * page * width
+
+
+def test_latent_tick_is_the_same_program_under_the_tpus_rule(one_v5e_chip,
+                                                             monkeypatch):
+    """AOT: the same tick with the K/V write chosen as on a TPU (the test
+    answers for the backend, as the row write's own test does).  The latent
+    leaf has no head axis, so it keeps the page merge: the program holds no
+    row-write kernel and is, instruction for instruction, the one compiled
+    where no kernel may run (the plan's kept row has no reader there and the
+    compiler drops it), so its temporaries and its leaf-sized ops are the
+    page merge's."""
+    from deepspeed_tpu.models import transformer as T
+
+    assert T._pallas_interpret() is None        # the CPU's answer
+    merge = _latent_tick(one_v5e_chip)[0]
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    on_tpu = _latent_tick(one_v5e_chip)[0]
+    assert "kv_row_write" not in on_tpu.as_text()
+    assert (_without_source_locations(on_tpu.as_text())
+            == _without_source_locations(merge.as_text()))
+    assert (on_tpu.memory_analysis().temp_size_in_bytes
+            == merge.memory_analysis().temp_size_in_bytes)
 
 
 @pytest.mark.parametrize(
@@ -589,8 +712,22 @@ PROGRAMS_AT_PR_29 = {
 }
 
 
-@pytest.mark.parametrize("program", list(PROGRAMS_AT_PR_29))
-def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
+def _without_source_locations(text):
+    """A compiled module's text with nothing in it that names a line of
+    source: two programs of the same instructions read the same."""
+    import re
+
+    text = re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"stack_frame_id=\d+", "", text)
+    text = re.sub(r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:\d+ .*\n)*", "\n", text)
+    assert text.count("\n") > 1000          # the computations, not a header
+    return text
+
+
+def _program_hash(one_v5e_chip, program):
+    """``(hash, text)`` of ``program`` (a key of ``PROGRAMS_AT_PR_29``)
+    compiled for the described chip."""
     import hashlib
     import json
     import re
@@ -669,13 +806,31 @@ def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
             shapes(cfg), pool, pool, S((b, s), jnp.int32),
             S((b, 16), jnp.int32), S((b,), jnp.int32),
             S((b, s), jnp.bool_)).compile()
-    text = re.sub(r",? ?metadata=\{[^}]*\}", "", compiled.as_text())
-    text = re.sub(r"stack_frame_id=\d+", "", text)
-    text = re.sub(r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
-                  r"(?:\d+ .*\n)*", "\n", text)
-    assert text.count("\n") > 1000          # the computations, not a header
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+    text = _without_source_locations(compiled.as_text())
+    return hashlib.sha256(text.encode()).hexdigest()[:16], text
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS_AT_PR_29))
+def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
+    assert _program_hash(one_v5e_chip, program)[0] == \
         PROGRAMS_AT_PR_29[program]
+
+
+def test_a_page_rows_minor_pool_keeps_the_page_merge_on_a_tpu(one_v5e_chip,
+                                                              monkeypatch):
+    """AOT: OPT's decode tick with its pool in the order the v5e stores a
+    64-wide head (page rows minor-most), the K/V write chosen as on a TPU
+    (the test answers for the backend).  The rule reads the stored order and
+    keeps the page merge: no kernel in the program (over such a leaf the row
+    write would have the whole pool copied into row-major order and back),
+    and the program is still the parent of PR 30's, instruction for
+    instruction."""
+    from deepspeed_tpu.models import transformer as T
+
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    digest, text = _program_hash(one_v5e_chip, "opt-1.3b_decode")
+    assert "tpu_custom_call" not in text
+    assert digest == PROGRAMS_AT_PR_29["opt-1.3b_decode"]
 
 
 # What the v5e reports as ``memory_stats()["bytes_limit"]`` (my chip run,

@@ -158,6 +158,7 @@ def test_span_rows_are_what_the_launched_programs_read(engine):
         spans = tracer.recorder.snapshot()
     finally:
         configure_tracer(enabled=False)
+        tracer.reset()      # leave no span behind for the next test's run
     assert len(results) == 8 and all(r.finish_reason == "length"
                                      for r in results)
     # the bound is in the program's inputs: launching ahead needs no change

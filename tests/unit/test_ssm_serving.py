@@ -328,6 +328,7 @@ def test_backlog_with_a_slot_ending_every_tick_keeps_the_plain_streams(engine):
     plain = {r.rid: list(r.output_ids) for r in
              engine.serving(lookahead=False, **SERVE_KW).run(reqs())}
     sv = engine.serving(**SERVE_KW)
+    get_tracer().reset()    # another test's spans are not this run's
     configure_tracer(enabled=True)
     try:
         got = {r.rid: list(r.output_ids) for r in sv.run(reqs())}
@@ -472,7 +473,7 @@ def test_the_one_pass_step_serves_token_for_token(monkeypatch):
         return results
 
     plain = serve("xla", 3)
-    monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: True)
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
     one_pass = serve("one_pass", 2)
     assert one_pass == plain
     for q in _requests(9):

@@ -113,7 +113,7 @@ def test_a_shape_the_tile_plan_refuses_keeps_ssm_step(shape, monkeypatch):
                  jnp.ones((2, H)), jnp.zeros((2, H, Pd)),
                  jnp.zeros((2, G, Nd)), jnp.zeros((2, G, Nd)),
                  interpret=True)
-    monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: True)
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
     assert T.ssm_step_path(cfg) == "xla"
     assert T.ssm_step_path(_cfg()) == "one_pass"
     ex = MeshExecutor(CausalLM(cfg), init_params(cfg, jax.random.PRNGKey(0)),
@@ -134,7 +134,7 @@ RULE = {
 def test_the_rule_reads_what_the_trace_can_observe(case, monkeypatch):
     kw, interpret, want = RULE[case]
     if interpret is not None:
-        monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: interpret)
+        monkeypatch.setattr(T, "_pallas_interpret", lambda: interpret)
     assert T.ssm_step_path(_cfg(), **kw) == want
     # a model with no state a slot has no step, wherever it runs
     assert T.ssm_step_path(get_config("tiny")) is None
@@ -146,12 +146,12 @@ def test_a_sharded_mesh_keeps_ssm_step(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", None)
-    assert T._ssm_kernel_interpret() is False
+    assert T._pallas_interpret() is False
     assert T.ssm_step_path(_cfg()) == "one_pass"
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH",
                         mesh_mod.build_mesh(mesh_mod.MeshLayout(dp=2),
                                             jax.devices()[:2]))
-    assert T._ssm_kernel_interpret() is None
+    assert T._pallas_interpret() is None
     assert T.ssm_step_path(_cfg()) == "xla"
 
 
@@ -181,7 +181,7 @@ def test_a_decode_tick_through_forward_paged_is_the_xla_ticks(monkeypatch):
         return outs, cache
 
     want, cache_x = ticks()
-    monkeypatch.setattr(T, "_ssm_kernel_interpret", lambda: True)
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
     got, cache_k = ticks()
     for a, b in zip(got, want):
         np.testing.assert_allclose(a[:2], b[:2], rtol=2e-4, atol=2e-4)
