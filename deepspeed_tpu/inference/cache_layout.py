@@ -30,7 +30,7 @@ __all__ = ["CacheLayout", "REFUSED"]
 _NOT_PAGES_ALONE = ("window", "latent", "state")
 
 # mechanism -> the kinds of cache (``cache_kind``) it cannot work on: five
-# the executor asks for, then the engine's two
+# the executor asks for, then the engine's three
 REFUSED: Dict[str, Tuple[str, ...]] = {
     "tensor-sharded heads (tp > 1)": _NOT_PAGES_ALONE,
     "copy-on-write page snapshots (prefix_cache=True)": _NOT_PAGES_ALONE,
@@ -40,6 +40,10 @@ REFUSED: Dict[str, Tuple[str, ...]] = {
     "multi-tenant adapters": _NOT_PAGES_ALONE + ("grouped",),
     "prefix sharing (prefix_cache=True)": _NOT_PAGES_ALONE,
     "speculative decoding": _NOT_PAGES_ALONE,
+    # a slot that gave its pages up is rebuilt by prefills that start behind
+    # rows already in its pages, as a shared prefix's tail is
+    "pages that follow a slot's length (recompute preemption)":
+        _NOT_PAGES_ALONE,
 }
 
 

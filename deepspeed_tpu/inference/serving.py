@@ -38,11 +38,27 @@ and per-device KV bytes shrink ~1/tp.  The zero-recompile inventory,
 warm-restart program adoption and all the resilience paths below are
 mesh-agnostic: they live on the host side of the split.
 
-Scheduling policy (documented, deliberately simple): FIFO admission with
-head-of-line blocking (no request skipping, so no starvation), and pages for
-the whole request (prompt + max_new) are reserved at admission — a running
-slot can never run out of pages mid-flight, so there is no preemption/swap
-path to get wrong.
+Scheduling policy (docs/SERVING.md "Scheduling policy"): FIFO admission
+with head-of-line blocking (no request skipping, so no starvation), and **a
+slot's pages follow its length**.  Admission reserves the pages that hold
+the prompt's rows and the row of the first decode tick; a live slot takes
+one more page before the tick whose row starts it is launched
+(:meth:`ServingEngine._grow_pages`); and when the pool has none, after the
+reclaim of cold prefix pages that admission runs too, the slot admitted
+last gives its pages up (never the one asking unless it is the youngest):
+its request goes back to the HEAD of the queue with the tokens it has
+emitted kept beside it, and its readmission rebuilds the rows of prompt and
+tokens with the prefill programs already built, in pieces no longer than
+the largest of them, and goes on where it stopped — every token emitted
+once, one :class:`RequestResult` a ``rid``.  It cannot deadlock: the oldest
+slot is never the victim while another is live, one live slot may own the
+whole pool, and ``submit`` refuses a request the whole pool cannot hold.
+In a pool of the full reservation (the default ``num_pages``) the next page
+is always there, nothing is preempted and slots bound admission.  A cache a
+tail prefill cannot rebuild (``cache_layout.REFUSED``: a window's rings, a
+latent leaf, a state a slot) and a speculative engine, whose verify block
+writes rows past a tick's one, keep the whole reservation (prompt +
+max_new) taken at admission.
 
 Cross-request KV reuse (docs/SERVING.md "Cross-request KV reuse"): physical
 pages are REFCOUNTED and immutable-once-full, and a prefix index
@@ -242,10 +258,17 @@ class RequestResult:
     # re-prefilled it (ServingSupervisor stamps both when stitching replayed
     # results).  Prefill-emitted tokens (one per incarnation) are not decode
     # ticks, so for any result that generated tokens
-    # decode_ticks == len(output_ids) - 1 - replays; empty-output terminals
-    # (shed / queue-expired) carry 0/0.
+    # decode_ticks == len(output_ids) - 1 - replays (less one more for
+    # each of `preemptions` whose readmission's prefill emitted a token in
+    # a tick's place); empty-output terminals (shed / queue-expired) carry
+    # 0/0.
     decode_ticks: int = 0
     replays: int = 0
+    # times the request gave its pages up to an older slot and was
+    # readmitted (docs/SERVING.md "Scheduling policy"): each readmission
+    # rebuilt its rows by prefill and emitted one token from it.  Always 0
+    # in a pool of the full reservation.
+    preemptions: int = 0
     # prompt tokens served from the prefix index at admission (shared full
     # pages + the COW boundary) instead of being re-prefilled — 0 on a cold
     # admission or when prefix caching is disabled.  For a replayed request
@@ -329,6 +352,10 @@ class _Slot:
     lifecycle: List = dataclasses.field(default_factory=list)
     # emit stamp of every token in `tokens` (RequestResult.token_s)
     token_s: List[float] = dataclasses.field(default_factory=list)
+    # its place in admission order, kept over a readmission: the slot with
+    # the highest gives its pages up first (ServingEngine._preempt)
+    order: int = 0
+    preemptions: int = 0
 
 
 # decode ticks kept launched ahead of the one being fetched (docs/SERVING.md
@@ -440,6 +467,12 @@ class ServingEngine:
         layout.refuse("speculative decoding", speculative is not None)
         if prefix_cache is None:
             prefix_cache = layout.allows("prefix sharing (prefix_cache=True)")
+        # a slot's pages follow its length wherever a slot that gave them up
+        # can be rebuilt by tail prefills and a tick writes one row a slot
+        # (a speculative verify block writes k more): elsewhere the whole
+        # reservation is taken at admission and the next page is always there
+        self._grow = speculative is None and layout.allows(
+            "pages that follow a slot's length (recompute preemption)")
         self.monitor = monitor
         self.watchdog = watchdog
         # decode lookahead (docs/SERVING.md "Decode lookahead"): launch tick
@@ -465,6 +498,17 @@ class ServingEngine:
         # batch), and whether the last one did
         self.page_waits = 0
         self._page_wait = False
+        # pages live slots took as they grew into them, slots that gave
+        # their pages up to an older one, and the rows their readmissions
+        # rebuilt; a request that gave up waits at the head of the queue
+        # with what it has emitted (its slot's record, no pages) kept here
+        self.page_grows = 0
+        self.preemptions = 0
+        self.recomputed_tokens = 0
+        self._preempted: Dict[Any, _Slot] = {}
+        self._admitted = 0      # admissions so far: the next one's `order`
+        self._slot_ids = np.arange(self.b_slots)
+        self._cols = np.arange(self.pages_per_slot)[None, :]
         # the launch (its seq) whose output is each slot's last token: a
         # tick launched ahead names the token it was fed by it
         self._tok_src = np.zeros((self.b_slots,), np.int64)
@@ -785,8 +829,33 @@ class ServingEngine:
 
     # ---------------------------------------------------------- scheduling
 
-    def _pages_needed(self, req: Request) -> int:
+    def _pages_whole(self, req: Request) -> int:
+        """The pages ``req`` holds rows in at its end."""
         return -(-(len(req.input_ids) + req.max_new_tokens) // self.page_size)
+
+    def _emitted(self, req: Request) -> List[int]:
+        """The tokens ``req`` emitted before it gave its pages up."""
+        st = self._preempted.get(req.rid)
+        return st.tokens if st is not None else []
+
+    def _rows_of(self, req: Request) -> np.ndarray:
+        """The token rows an admission of ``req`` builds: its prompt, then
+        what it had emitted before it gave its pages up."""
+        emitted = self._emitted(req)
+        if not emitted:
+            return req.input_ids
+        return np.concatenate([req.input_ids,
+                               np.asarray(emitted, np.int32)])
+
+    def _pages_needed(self, req: Request) -> int:
+        """The pages an admission of ``req`` takes: those that hold the rows
+        it builds (the prompt, and what a preempted request had emitted) and
+        the row of the first decode tick; the whole reservation where the
+        slot's pages cannot follow its length."""
+        if not self._grow:
+            return self._pages_whole(req)
+        rows = len(req.input_ids) + len(self._emitted(req))
+        return -(-(rows + 1) // self.page_size)
 
     # ------------------------------------------------------- page pools
 
@@ -870,9 +939,9 @@ class ServingEngine:
         token reads off the last real prefill position)."""
         if self._prefix is None or len(self._prefix) == 0:
             return PrefixMatch(pages=[], n_tokens=0)
+        ids = self._rows_of(req)
         with trace_span("serve.prefix_match", rid=req.rid):
-            m = self._prefix.lookup(req.input_ids,
-                                    limit=len(req.input_ids) - 1,
+            m = self._prefix.lookup(ids, limit=len(ids) - 1,
                                     salt=self._adapter_salt(req))
         if m.cow_src is not None and m.cow_valid < MIN_COW_TOKENS:
             # not worth a pool-shaped page snapshot: keep the full-page
@@ -1071,9 +1140,11 @@ class ServingEngine:
         Returns the update stats (also mirrored on the ``serve/weight_*``
         gauges): new epoch, flushed HBM pages / host slabs, the refresh
         wall time, and the post-flip ``page_accounting()`` verdict."""
-        if self._active.any():
+        if self._active.any() or self._preempted:
+            # (a request waiting to be readmitted is a live stream too)
             raise RuntimeError(
-                f"update_params with {int(self._active.sum())} slot(s) "
+                f"update_params with "
+                f"{int(self._active.sum()) + len(self._preempted)} slot(s) "
                 "in flight: a live stream's K/V would straddle two weight "
                 "epochs — drain or finish the tick loop first "
                 "(RolloutEngine sequences rounds so this cannot happen)")
@@ -1254,7 +1325,7 @@ class ServingEngine:
                     t = time.monotonic()
                     lc = self._lifecycle_pending.pop(req.rid, [])
                     lc.append(("deadline", t, self.engine_incarnation))
-                    self._results[req.rid] = RequestResult(
+                    result = RequestResult(
                         rid=req.rid, input_ids=req.input_ids,
                         output_ids=np.zeros((0,), np.int32),
                         finish_reason="deadline", prefill_bucket=0,
@@ -1262,6 +1333,18 @@ class ServingEngine:
                         first_token_s=t, finish_s=t,
                         retry_after_s=self._retry_after_hint(),
                         trace_id=req.trace_id, lifecycle=lc)
+                    gave_up = self._preempted.pop(req.rid, None)
+                    if gave_up is not None:
+                        # waiting to be readmitted: what it had emitted
+                        result = dataclasses.replace(
+                            result, prefill_bucket=gave_up.bucket,
+                            output_ids=np.asarray(gave_up.tokens, np.int32),
+                            token_s=np.asarray(gave_up.token_s, np.float64),
+                            admit_s=gave_up.admit_s,
+                            first_token_s=gave_up.first_token_s,
+                            decode_ticks=gave_up.decode_ticks,
+                            preemptions=gave_up.preemptions)
+                    self._results[req.rid] = result
                     self._finished_order.append(req.rid)
                     self.deadline_count += 1
                     logger.warning("serve: request %r expired in queue "
@@ -1300,9 +1383,9 @@ class ServingEngine:
                 f"request {request.rid!r}: prompt {ids.size} + max_new "
                 f"{request.max_new_tokens} exceeds max_model_len "
                 f"{self.max_model_len}")
-        if self._pages_needed(request) > self.num_pages - 1:
+        if self._pages_whole(request) > self.num_pages - 1:
             raise ValueError(
-                f"request {request.rid!r} needs {self._pages_needed(request)} "
+                f"request {request.rid!r} needs {self._pages_whole(request)} "
                 f"pages but the pool holds {self.num_pages - 1}")
         if request.deadline_s is not None and request.deadline_s <= 0:
             raise ValueError(
@@ -1432,8 +1515,11 @@ class ServingEngine:
                                            and self._ahead):
                                         self._first_token(
                                             self._firsts.popleft())
+                            # ``emitted``: above 0 for a readmission, whose
+                            # prefills build the prompt's rows and theirs
                             with trace_span("serve.admit", rid=req.rid,
-                                            slot=slot):
+                                            slot=slot, pages=need,
+                                            emitted=len(self._emitted(req))):
                                 self._admit_one(req, slot, match, need, now)
                             admitted = True
                 finally:
@@ -1546,7 +1632,8 @@ class ServingEngine:
         through the ordinary causal gather.  When the match ends mid-page,
         the donor's partial boundary page is first snapshotted into this
         slot's own boundary page (copy-on-write)."""
-        S = len(req.input_ids)
+        ids = self._rows_of(req)
+        S = len(ids)
         n_shared = match.n_tokens
         pages = shared + private
         # weight-epoch invariant (docs/HYBRID.md): a mapped shared page (or
@@ -1564,14 +1651,13 @@ class ServingEngine:
                 f"{[int(self._pages.epoch[p]) for p in stale]} at weight "
                 f"epoch {self._weight_epoch} — pre-update K/V must never "
                 "be served (docs/HYBRID.md)")
-        tail = req.input_ids[n_shared:]
-        S_tail = len(tail)   # >= 1: lookup is capped at prompt-1
-        s_pad = _bucket(S_tail)
+        # the unshared tail (>= 1 token: lookup is capped at the last), in
+        # one launch; a readmission's in the programs already built
+        old = self._preempted.get(req.rid)
+        pieces = self._prefill_pieces(S - n_shared, old is not None)
         self._pages.table[slot, :] = 0
         self._pages.table[slot, :len(pages)] = pages
-        toks = np.zeros((1, s_pad), np.int32)
-        toks[0, :S_tail] = tail
-        lane_t, lane_k, lane_p, lane_s = as_lanes(req.sampling)
+        lanes = as_lanes(req.sampling)
         adapter_row = None
         if self.adapters is not None:
             # install the tenant's factors into this slot of the host
@@ -1586,50 +1672,62 @@ class ServingEngine:
             self.adapters.write_slot(self._adapter_stacks, slot, ad)
             self._exec.invalidate_adapters()
             adapter_row = self._exec.adapter_row(self._adapter_stacks, slot)
-        with trace_span("serve.prefill", rid=req.rid, slot=slot,
-                        bucket=s_pad, tokens=S_tail,
-                        shared_tokens=n_shared) as sp:
-            if get_tracer().enabled:
-                # what the call reads of the slot's cache
-                sp.set(**self._layout.prefill_attrs(s_pad, S_tail, n_shared))
-            maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
-            with self._armed(f"serve.prefill rid={req.rid!r}"):
-                # no more than PREFILLS_IN_FLIGHT unfetched: the oldest's
-                # turn has come (_admit starts no admission where it has
-                # not), and the wait for it is a wait for this one's start
-                while len(self._firsts) >= PREFILLS_IN_FLIGHT:
-                    self._first_token(self._firsts.popleft())
-                sp.set(queued_behind=len(self._ahead) + len(self._firsts))
-                if match.cow_src is not None:
-                    # COW the partial boundary page: private[0] is the
-                    # boundary logical page (shared full pages cover
-                    # exactly len(shared) logical pages before it).  Rows
-                    # past cow_valid in the snapshot are donor garbage the
-                    # tail prefill/decode overwrites before causality can
-                    # expose them.
-                    self._exec.cow(match.cow_src, private[0])
-                    self.cow_copies += 1
+        start = n_shared
+        for i, (n_tok, s_pad) in enumerate(pieces):
+            toks = np.zeros((1, s_pad), np.int32)
+            toks[0, :n_tok] = ids[start:start + n_tok]
+            with trace_span("serve.prefill", rid=req.rid, slot=slot,
+                            bucket=s_pad, tokens=n_tok,
+                            shared_tokens=start) as sp:
+                if get_tracer().enabled:
+                    # what the call reads of the slot's cache
+                    sp.set(**self._layout.prefill_attrs(s_pad, n_tok, start))
+                if i == 0:
+                    maybe_fire(SITE_SERVE_PREFILL, rid=req.rid, slot=slot)
+                with self._armed(f"serve.prefill rid={req.rid!r}"):
+                    # no more than PREFILLS_IN_FLIGHT unfetched: the oldest's
+                    # turn has come (_admit starts no admission where it has
+                    # not), and the wait for it is a wait for this one's
+                    # start
+                    while len(self._firsts) >= PREFILLS_IN_FLIGHT:
+                        self._first_token(self._firsts.popleft())
+                    sp.set(queued_behind=len(self._ahead) + len(self._firsts))
+                    if i == 0 and match.cow_src is not None:
+                        # COW the partial boundary page: private[0] is the
+                        # boundary logical page (shared full pages cover
+                        # exactly len(shared) logical pages before it).
+                        # Rows past cow_valid in the snapshot are donor
+                        # garbage the tail prefill/decode overwrites before
+                        # causality can expose them.
+                        self._exec.cow(match.cow_src, private[0])
+                        self.cow_copies += 1
+                        if self._spec is not None:
+                            # mirror the snapshot in the draft pool — the
+                            # sharer's draft-side boundary must hold the
+                            # same donor prefix its target-side boundary
+                            # does
+                            self._spec.cow(self._exec._cow_prog,
+                                           match.cow_src, private[0])
+                    out, seq, pt_row, toks_j = self._launch_prefill(
+                        s_pad, slot, toks, n_tok, start, *lanes, adapter_row)
                     if self._spec is not None:
-                        # mirror the snapshot in the draft pool — the
-                        # sharer's draft-side boundary must hold the same
-                        # donor prefix its target-side boundary does
-                        self._spec.cow(self._exec._cow_prog,
-                                       match.cow_src, private[0])
-                out, seq, pt_row, toks_j = self._launch_prefill(
-                    s_pad, slot, toks, S_tail, n_shared,
-                    lane_t, lane_k, lane_p, lane_s, adapter_row)
-                if self._spec is not None:
-                    # draft-pool prefill of the same tail (same bucket,
-                    # page-table row, start) — the draft emits nothing
-                    self._spec.prefill(s_pad, pt_row, toks_j, S_tail,
-                                       n_shared)
-                first = _FirstToken(out, seq, f"prefill_{s_pad}", slot, sp,
-                                    S_tail)
-                # read here and now only where something reads it on the
-                # host at once, a stop on that very token included (the
-                # fetch lands inside the watchdog window)
-                tok = (None if self._runs_ahead() and req.eos_token_id is None
-                       else self._fetch_first(first))
+                        # draft-pool prefill of the same tail (same bucket,
+                        # page-table row, start) — the draft emits nothing
+                        self._spec.prefill(s_pad, pt_row, toks_j, n_tok,
+                                           start)
+                    start += n_tok
+                    if start < S:
+                        # a piece of a readmission: the next starts behind
+                        # its rows, and the token it sampled is no one's
+                        continue
+                    first = _FirstToken(out, seq, f"prefill_{s_pad}", slot,
+                                        sp, n_tok)
+                    # read here and now only where something reads it on
+                    # the host at once, a stop on that very token included
+                    # (the fetch lands inside the watchdog window)
+                    tok = (None if self._runs_ahead()
+                           and req.eos_token_id is None
+                           else self._fetch_first(first))
         # the admission is booked from what the host knows without the
         # device's answer: the slot, its pages and lengths, the tokens it is
         # owed.  Its first token is recorded when its turn in launch order
@@ -1643,20 +1741,30 @@ class ServingEngine:
         if match.cow_src is not None:
             lc.append(("cow", t, inc))
         lc.append(("prefill", t, inc))
-        first.st = self._slots[slot] = _Slot(
-            request=req, pages=pages, tokens=[], bucket=s_pad,
-            arrival_s=self._arrival_abs(req), admit_s=self._t0 + now,
-            first_token_s=t, shared_tokens=n_shared, lifecycle=lc)
+        if old is None:
+            st = _Slot(
+                request=req, pages=pages, tokens=[], bucket=s_pad,
+                arrival_s=self._arrival_abs(req), admit_s=self._t0 + now,
+                first_token_s=t, shared_tokens=n_shared, lifecycle=lc,
+                order=self._admitted)
+            self._admitted += 1
+        else:
+            # what the request has emitted, its stamps and its place in
+            # admission order, in a record of its own: a prefill of the old
+            # one may still be in flight, and gives its token to no one
+            del self._preempted[req.rid]
+            st = dataclasses.replace(old, pages=pages, lifecycle=lc,
+                                     shared_tokens=n_shared)
+            self.recomputed_tokens += S - n_shared
+        first.st = self._slots[slot] = st
         self._lengths[slot] = S
         self._last_tok[slot] = 0
         self._active[slot] = True
         self._tok_src[slot] = seq
-        self._owed[slot] = req.max_new_tokens - 1
+        self._owed[slot] = req.max_new_tokens - len(st.tokens) - 1
         self._eos_live += req.eos_token_id is not None
-        self._lane_temp[slot] = lane_t
-        self._lane_top_k[slot] = lane_k
-        self._lane_top_p[slot] = lane_p
-        self._lane_seed[slot] = lane_s
+        (self._lane_temp[slot], self._lane_top_k[slot],
+         self._lane_top_p[slot], self._lane_seed[slot]) = lanes
         self._exec.invalidate_lanes()
         if req.sampling is not None and not req.sampling.greedy:
             self.sampled_admissions += 1
@@ -1684,13 +1792,29 @@ class ServingEngine:
             # Host time AFTER a first token's stamp where that was read.
             with trace_span("serve.publish", rid=req.rid):
                 newly, released = self._prefix.publish(
-                    req.input_ids, pages, salt=self._adapter_salt(req))
+                    ids, pages, salt=self._adapter_salt(req))
                 for p in newly:
                     self._pages.share(p)
                 for p in released:
                     self._pages.drop(p)
         if reason is not None:
             self._finish(slot, reason)
+
+    def _prefill_pieces(self, n: int, rebuilt: bool) -> List[tuple]:
+        """``(tokens, bucket)`` of the prefill launches that build ``n``
+        rows of a slot: one, in the bucket of its length.  A readmission's
+        (``rebuilt``) run in the programs the engine has built and compile
+        nothing: pieces of the largest bucket, each after the first a tail
+        prefill behind the rows before it, the last in the smallest bucket
+        that holds it."""
+        built = sorted(self._exec._prefill_progs)
+        if not rebuilt or not built:
+            return [(n, _bucket(n))]
+        pieces = []
+        while n > built[-1]:
+            pieces.append((built[-1], built[-1]))
+            n -= built[-1]
+        return pieces + [(n, next(b for b in built if b >= n))]
 
     def _runs_ahead(self) -> bool:
         """The engine launches from what the host knows without the device's
@@ -1737,19 +1861,22 @@ class ServingEngine:
         t = time.monotonic()
         st.tokens.append(tok)
         st.token_s.append(t)
-        st.first_token_s = t
-        st.lifecycle.append(("first_token", t, self.engine_incarnation))
         self._last_tok[slot] = tok
         self._tokens_out += 1
         if req.adapter_id is not None:
             self._adapter_tokens_by_id[req.adapter_id] = (
                 self._adapter_tokens_by_id.get(req.adapter_id, 0) + 1)
-        if self.monitor is not None:
-            self.monitor.write_events([
-                ("serve/ttft_s", t - self._arrival_abs(req), self._tick)])
+        if len(st.tokens) == 1:
+            # not a readmission's: the request's first keeps its stamp
+            st.first_token_s = t
+            st.lifecycle.append(("first_token", t, self.engine_incarnation))
+            if self.monitor is not None:
+                self.monitor.write_events([
+                    ("serve/ttft_s", t - self._arrival_abs(req),
+                     self._tick)])
         if req.eos_token_id is not None and tok == req.eos_token_id:
             return "eos"
-        return "length" if req.max_new_tokens == 1 else None
+        return "length" if len(st.tokens) >= req.max_new_tokens else None
 
     def _slot_rid_map(self) -> Dict[str, str]:
         """Active slot → rid, stringified for trace-event ``args`` (only
@@ -1910,6 +2037,8 @@ class ServingEngine:
         launched = 0
         while len(self._ahead) <= depth:
             active, lengths, src, owed = self._next_inputs()
+            if not self._grow_pages(active, lengths):
+                continue    # a slot gave its pages up: the inputs are others
             if not active.any():
                 break
             # under a mask narrower than the live slots: past a slot's last
@@ -1937,16 +2066,76 @@ class ServingEngine:
             launched += 1
         return launched
 
+    def _grow_pages(self, active: np.ndarray, lengths: np.ndarray) -> bool:
+        """Before the tick over the slots ``active`` at ``lengths`` is
+        launched: a slot whose row of this tick starts a page takes that
+        page, so no launched tick writes into a page its slot does not own.
+        Where the pool has none, cold prefix pages are reclaimed as
+        admission does, and then the slot admitted last gives its pages up
+        (:meth:`_preempt`), the one asking only where it is that one, until
+        a page is free.  False where a slot gave up: the tick's inputs are
+        others now.  In a pool of the full reservation, and for a request
+        that holds its whole reservation, the page is always there."""
+        page = np.minimum(lengths // self.page_size, self.pages_per_slot - 1)
+        short = active & (self._pages.table[self._slot_ids, page] == 0)
+        if not short.any():
+            return True
+        gave_up = False
+        # the oldest first: whoever gives up is younger than who takes
+        for slot in sorted(np.flatnonzero(short),
+                           key=lambda i: self._slots[i].order):
+            st = self._slots[slot]
+            while self._active[slot] and len(st.pages) <= page[slot]:
+                if not self._pages.free:
+                    self._reclaim_cached(1)
+                if not self._pages.free:
+                    self._preempt(max(np.flatnonzero(self._active),
+                                      key=lambda i: self._slots[i].order))
+                    gave_up = True
+                    continue
+                st.pages += self._pages.take(1, self._weight_epoch)
+                self._pages.table[slot, len(st.pages) - 1] = st.pages[-1]
+                self.page_grows += 1
+        return not gave_up
+
+    def _preempt(self, slot: int) -> None:
+        """``slot`` gives its pages up to an older one (recompute
+        preemption: the slot with the least to redo).  Its request goes back
+        to the head of the queue with what it has emitted kept beside it
+        (``_preempted``): nothing is emitted or counted twice, and its
+        readmission builds prompt and tokens anew and goes on behind them
+        (:meth:`_prefill`).  Its lanes of ticks already launched are no
+        one's (:meth:`_ahead_slots`), and a first token still in flight is
+        given to no one (:meth:`_record_first`)."""
+        st = self._slots[slot]
+        req = st.request
+        with trace_span("serve.preempt", rid=req.rid, slot=slot,
+                        tokens=len(st.tokens), pages=len(st.pages)):
+            self._vacate(slot)
+            st.pages = []
+            st.preemptions += 1
+            self._preempted[req.rid] = st
+            self._lifecycle_pending[req.rid] = st.lifecycle + [
+                ("preempt", time.monotonic(), self.engine_incarnation)]
+            self._queue.appendleft(req)
+            if req.deadline_s is not None:
+                self._waiting_deadlines += 1
+        self.preemptions += 1
+
     def _ahead_slots(self, ahead: _Ahead) -> np.ndarray:
         """The slots ``[b_slots]`` (bool) for which the launched tick
         ``ahead`` computed the token the host would ask for now: live then
         and now, fed the token the slot holds as its last (by the launch
         that made it: the same admission, the same position), at the same
-        length and page row."""
+        length, over the same pages as far as its length reaches (the page
+        of the row it wrote the last: a page the slot has taken since lies
+        behind every row the tick read or wrote)."""
+        reach = self._cols <= (ahead.lengths // self.page_size)[:, None]
         return (ahead.active & self._active
                 & (ahead.src == self._tok_src)
                 & (ahead.lengths == self._lengths)
-                & (ahead.page_table == self._pages.table).all(axis=1))
+                & ((ahead.page_table == self._pages.table) | ~reach
+                   ).all(axis=1))
 
     def _take_ahead(self):
         """``(the launched tick whose turn has come, the slots it is
@@ -1967,7 +2156,12 @@ class ServingEngine:
         programs on the device keeps them right: the stale tick's row lands
         first, the new request's prefill, launched after it, over it, and a
         row past its prompt is one no read reaches before the slot's own
-        decode writes it; its state is reset by that prefill.
+        decode writes it; its state is reset by that prefill.  The same
+        holds for a slot that gave its pages up (:meth:`_preempt`) and a
+        page a live slot then took as it grew (:meth:`_grow_pages`): the
+        stale row lies at or past the new owner's length, and the owner's
+        own write of that row, in a tick launched later, lands on it before
+        any read reaches it.
 
         What per-slot validity cannot save drops the whole queue
         (``lookahead_dropped``): ticks launched on other weights
@@ -2145,7 +2339,7 @@ class ServingEngine:
             # the prefill produced tokens[0]; every later token came from a
             # decode-program invocation (== len(tokens) - 1 without
             # speculation; a speculative verify tick emits several)
-            decode_ticks=st.decode_ticks,
+            decode_ticks=st.decode_ticks, preemptions=st.preemptions,
             shared_prefix_tokens=st.shared_tokens,
             trace_id=st.request.trace_id,
             adapter_id=st.request.adapter_id, lifecycle=st.lifecycle,
@@ -2160,6 +2354,11 @@ class ServingEngine:
                                    else 0.8 * self._ema_service_s + 0.2 * dt)
         self._results[st.request.rid] = result
         self._finished_order.append(st.request.rid)
+        self._vacate(slot)
+
+    def _vacate(self, slot: int) -> None:
+        """``slot``'s request leaves it, at its end or to be readmitted."""
+        st = self._slots[slot]
         # drop one reference per page — shared pages stay resident for
         # their other readers (and the prefix index), private pages whose
         # last reference this was return to the free list
@@ -2305,6 +2504,12 @@ class ServingEngine:
                     sp.set(**self._layout.tick_attrs(self._pools,
                                                      self._page_wait))
                 self._decode_tick(rid_map, held)
+                if rid_map is not None:
+                    # how often a slot's pages followed its length so far,
+                    # and how often the pool had none (totals)
+                    sp.set(page_grows=self.page_grows,
+                           preemptions=self.preemptions,
+                           recomputed_tokens=self.recomputed_tokens)
                 # refill slots the decode just retired — the queue head
                 # starts its prefill this tick instead of idling one
                 # scheduler round — and place what was held back behind
@@ -2503,6 +2708,16 @@ class ServingEngine:
             # the queue waiting for pages (num_pages under the full
             # reservation: pages, not slots, bound the batch)
             "admission_page_waits_total": self.page_waits,
+            # a slot's pages follow its length (docs/SERVING.md "Scheduling
+            # policy"): pages live slots took at a page boundary, slots
+            # that gave their pages up to an older one when the pool had
+            # none, the rows their readmissions rebuilt by prefill, and
+            # the requests waiting to be readmitted now.  The last three
+            # stay 0 in a pool of the full reservation
+            "page_grows_total": self.page_grows,
+            "preemptions_total": self.preemptions,
+            "recomputed_tokens_total": self.recomputed_tokens,
+            "preempted_waiting": len(self._preempted),
             # the bytes of the cache's leaves indexed by slot, of a model
             # with a state a slot (counted in kv_pool_bytes_* too)
             "state_pool_bytes": self._exec.state_bytes,
@@ -2592,7 +2807,10 @@ class ServingEngine:
         for r in unserved:
             # the hand-off target's submit() starts a fresh queued stamp;
             # keeping these would leak entries for requests we no longer own
+            # (one that gave its pages up is handed back whole: the target
+            # emits it from its first token)
             self._lifecycle_pending.pop(r.rid, None)
+            self._preempted.pop(r.rid, None)
         log_dist(f"serve: drained — {len(unserved)} unserved request(s) "
                  f"handed back, {len(self._finished_order)} result(s) "
                  "claimable", ranks=[0])
@@ -2610,6 +2828,10 @@ class ServingEngine:
             ("serve/slot_occupancy", active / self.b_slots, self._tick),
             ("serve/free_pages", float(len(self._pages.free)), self._tick),
             ("serve/admission_page_waits_total", float(self.page_waits),
+             self._tick),
+            ("serve/page_grows_total", float(self.page_grows), self._tick),
+            ("serve/preemptions_total", float(self.preemptions), self._tick),
+            ("serve/recomputed_tokens_total", float(self.recomputed_tokens),
              self._tick),
             ("serve/tokens_per_sec", self._tokens_out / elapsed, self._tick),
             ("serve/shed_total", float(self.shed_count), self._tick),

@@ -120,6 +120,8 @@ class ServingSupervisor:
         # launched, dropped, stale taken, past a slot's end, fed on device
         self._lookahead_base = (0, 0, 0, 0, 0)
         self._page_waits_base = 0
+        # pages grown into, slots preempted, rows their readmissions rebuilt
+        self._growth_base = (0, 0, 0)
         self._sampled_base = 0
         self._adapter_admissions_base = 0
         self._spec_ticks_base = 0
@@ -318,7 +320,9 @@ class ServingSupervisor:
         last durable token instead of re-decoding the whole stream."""
         out: Dict[Any, List[int]] = {rid: [int(t) for t in toks]
                                      for rid, toks in self._prefix.items()}
-        for st in self.engine._slots:
+        # (a request that gave its pages up and waits to be readmitted
+        # holds its tokens beside the queue)
+        for st in (*self.engine._slots, *self.engine._preempted.values()):
             if st is not None:
                 rid = st.request.rid
                 out[rid] = out.get(rid, []) + [int(t) for t in st.tokens]
@@ -348,6 +352,9 @@ class ServingSupervisor:
         h["lookahead_past_end_total"] += self._lookahead_base[3]
         h["prefill_fed_on_device_total"] += self._lookahead_base[4]
         h["admission_page_waits_total"] += self._page_waits_base
+        h["page_grows_total"] += self._growth_base[0]
+        h["preemptions_total"] += self._growth_base[1]
+        h["recomputed_tokens_total"] += self._growth_base[2]
         h["sampled_admissions_total"] += self._sampled_base
         h["adapter_admissions_total"] += self._adapter_admissions_base
         h["spec_verify_slot_ticks_total"] += self._spec_ticks_base
@@ -481,10 +488,15 @@ class ServingSupervisor:
         # arrival restarts on the new engine's clock — without the
         # deduction every restart would silently hand the request a fresh
         # full deadline window).
-        inflight = sorted((st for st in old._slots if st is not None),
-                          key=lambda st: st.admit_s)
+        # A request that gave its pages up and waits at the head of the
+        # queue to be readmitted is in flight too: it replays with what it
+        # had emitted, not from its prompt.
+        inflight = sorted((st for st in (*old._slots,
+                                         *old._preempted.values())
+                           if st is not None), key=lambda st: st.admit_s)
         elapsed = time.monotonic() - old._t0
-        waiting = [self._rebase(r, elapsed, old._t0) for r in old._queue]
+        waiting = [self._rebase(r, elapsed, old._t0) for r in old._queue
+                   if r.rid not in old._preempted]
         # pending requests whose arrival offset already elapsed (the crash
         # beat the _admit that would have promoted them) have ARRIVED just
         # like the queue — rebase them too so their epoch survives; only
@@ -636,6 +648,9 @@ class ServingSupervisor:
             self._lookahead_base[3] + old.lookahead_past_end,
             self._lookahead_base[4] + old.prefill_fed_on_device)
         self._page_waits_base += old.page_waits
+        self._growth_base = (self._growth_base[0] + old.page_grows,
+                             self._growth_base[1] + old.preemptions,
+                             self._growth_base[2] + old.recomputed_tokens)
         self._sampled_base += old.sampled_admissions
         self._adapter_admissions_base += old.adapter_admissions
         if old._spec is not None:

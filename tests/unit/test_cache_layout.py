@@ -58,6 +58,7 @@ TABLE = {
     "multi-tenant adapters": {**PAGES_ALONE, "grouped": False},
     "prefix sharing (prefix_cache=True)": PAGES_ALONE,
     "speculative decoding": PAGES_ALONE,
+    "pages that follow a slot's length (recompute preemption)": PAGES_ALONE,
 }
 
 

@@ -347,8 +347,10 @@ def test_the_cache_is_sized_by_its_depth(engine):
 def test_a_pool_under_the_full_reservation_admits_head_of_line(engine):
     """Twelve requests of 2-4 pages through four slots over nine pages: the
     head of the queue waits for pages with slots free, nothing overtakes it,
-    every request finishes with what greedy ``forward`` yields, and the
-    pool ends balanced.  One decode program; a program a prefill bucket."""
+    a slot takes its pages as it grows and the youngest gives them up when
+    the pool has none, every request finishes with what greedy ``forward``
+    yields, and the pool ends balanced.  One decode program; a program a
+    prefill bucket."""
     from deepspeed_tpu.observability import (Span, configure_tracer,
                                              get_tracer)
 
@@ -386,7 +388,13 @@ def test_a_pool_under_the_full_reservation_admits_head_of_line(engine):
     decode = [s.attrs for s in spans if s.name == "serve.decode"
               and s.attrs and "kv_bytes" in s.attrs]
     prefill = [s.attrs for s in spans if s.name == "serve.prefill"]
-    assert ticks and decode and len(prefill) == 12
+    # a prefill an admission, and one or more a readmission
+    assert h["preemptions_total"] >= 1
+    assert sum(r.preemptions for r in results.values()) == h[
+        "preemptions_total"]
+    assert ticks and decode and len(prefill) >= 12 + h["preemptions_total"]
+    assert len([s for s in spans if s.name == "serve.admit"]) == 12 + h[
+        "preemptions_total"]
     waited = [a for a in ticks if a["page_wait"]]
     assert waited and len(waited) < len(ticks)
     # a tick that waited for pages had a slot free and too few pages for
@@ -551,7 +559,22 @@ def _speculative(engine, plain):
     assert sv.page_accounting()["balanced"]
 
 
+def _pages_follow_length(engine, plain):
+    """A pool under the full reservation: a slot of a looped model grows a
+    page (every pass deep) at a time, gives its pages up when the pool has
+    none and is rebuilt by tail prefills through all four passes."""
+    reqs = _requests(10, seed=31, new=(20, 40))
+    want = _outputs(engine.serving(**SERVE_KW), reqs)
+    sv = engine.serving(num_pages=9, **SERVE_KW)
+    assert _outputs(sv, reqs) == want
+    h = sv.health()
+    assert h["preemptions_total"] >= 1 and h["page_grows_total"] > 0
+    assert sv.page_accounting()["balanced"]
+
+
 MECHANISMS = {
+    "pages that follow a slot's length (recompute preemption)":
+        _pages_follow_length,
     "tensor-sharded heads (tp > 1)": _tensor_sharded,
     "copy-on-write page snapshots (prefix_cache=True)":
         _prefix_sharing_and_cow,

@@ -200,7 +200,7 @@ def test_an_arrival_is_admitted_after_the_one_tick_in_flight(engine):
     assert _streams(sv.take_results()) == _streams(plain.run([first, late]))
 
 
-@pytest.mark.parametrize("n_prompt", [44, 12], ids=[
+@pytest.mark.parametrize("n_prompt", [20, 2], ids=[
     "prompt-over-the-stale-row", "prompt-short-of-it"])
 @greedy_and_sampled
 def test_a_slot_ended_under_a_tick_in_flight_hands_its_pages_on(
@@ -213,14 +213,16 @@ def test_a_slot_ended_under_a_tick_in_flight_hands_its_pages_on(
     is taken for the slot that went on, so the streams are the plain loop's
     and every page is accounted for."""
     def run(lookahead):
-        # 8 pages of 8 rows: 7 for the first request, 1 for the second,
-        # and all 7 again for the third once the first has gone
+        # 8 pages of 8 rows: 5 for the first request's prompt and first
+        # row, 3 for the second's, none for the third until the first has
+        # gone; the first's last page, which holds the row the tick in
+        # flight writes (past its middle), is then the third's first
         sv = _serving(engine, lookahead, num_pages=9, prefix_cache=False)
         rng = np.random.default_rng(2)
         reqs = [Request(rid=f"r{i}", max_new_tokens=n_new, sampling=sampling,
                         input_ids=rng.integers(1, 200, (n,)).astype(np.int32))
                 for i, (n, n_new) in enumerate((
-                    (30, 20), (1, 7), (n_prompt, 50 - n_prompt)))]
+                    (34, 20), (17, 7), (n_prompt, 50 - n_prompt)))]
         reqs[0].deadline_s = 100.0
         for r in reqs:
             sv.submit(r)
@@ -233,7 +235,7 @@ def test_a_slot_ended_under_a_tick_in_flight_hands_its_pages_on(
         stale_page = sv._pages.table[0, (sv._lengths[0] + in_flight) // 8]
         sv.step(now=1e6)                     # the deadline, then the admission
         assert sv._slots[0].request.rid == "r2"
-        assert stale_page in sv._slots[0].pages
+        assert stale_page == sv._slots[0].pages[0]
         while sv.step(now=1e6):
             pass
         return sv, in_flight, _streams(sv.take_results())
