@@ -20,9 +20,10 @@ from typing import Any, Dict, Tuple
 import numpy as np
 
 from ..models.transformer import (block_read_rows, cache_depth, cache_kind,
-                                  causal_walk_steps, kind_layers,
-                                  paged_read_rows, ssm_scan_chunks,
-                                  window_read_rows, window_ring_pages)
+                                  cache_layers, causal_walk_steps, is_hybrid,
+                                  kind_layers, paged_read_rows,
+                                  ssm_scan_chunks, window_read_rows,
+                                  window_ring_pages)
 
 __all__ = ["CacheLayout", "REFUSED"]
 
@@ -65,8 +66,12 @@ class CacheLayout:
         self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
                            if self.kind == "window" else 0)
         self.window_pages = self.ring_pages and 1 + b_slots * self.ring_pages
-        # state-space layers: two leaves a row a slot beside the pages
+        # state-space layers: two leaves a row a slot beside the pages.  The
+        # paged leaves cover the layers with attention, the state leaves
+        # those with a mixer: every layer both (a parallel block), or each
+        # layer one of the two (a layer_pattern's "ssm" and "full" kinds)
         self.stateful = self.kind == "state"
+        self.kv_layers, self.state_layers = cache_layers(cfg)
         # a pool of pages each: ``(pages, page 0 its trash page; those a
         # slot's row of its table names)``.  The slots' table first, then
         # what is taken a whole row a slot and never shared
@@ -81,8 +86,9 @@ class CacheLayout:
         self.kind_heads = ({k: g.kv_heads * n
                             for k, (g, n) in kind_layers(cfg).items()}
                            if self.kind == "window" else {})
-        # a prompt of such a model attends within itself, gathers nothing back
-        self.block_attends_itself = self.kind in ("window", "latent")
+        # a prompt of such a model attends within itself, gathers nothing
+        # back: a latent model's, and one whose layers are walked by kind
+        self.block_attends_itself = self.kind == "latent" or is_hybrid(cfg)
         # a slot's bytes of state and the passes the tick's step makes over
         # them, and the bytes one token's rows take in the paged leaves over
         # every layer and pass; the paged leaves a tick writes by row and by
@@ -125,7 +131,9 @@ class CacheLayout:
         kinds of layer: K/V head rows read and live a kind (a window layer
         reads the ring pages under its window).
         A state a slot: the slots whose state the tick read and wrote, the
-        bytes of one reading, its step's passes."""
+        bytes of one reading over the layers that have one
+        (``state_layers``), its step's passes, and the live token rows over
+        the layers that have K/V (``kv_layers``)."""
         lengths = np.asarray(lengths, np.int64)
         rows = paged_read_rows(lengths, self.page_size, self.pages_per_slot,
                                slots)
@@ -141,9 +149,14 @@ class CacheLayout:
                 window_read_rows(lengths, self.page_size, W, slots),
                 int(np.minimum(lengths, W).sum())))
         if self.stateful:
+            # ``state_bytes`` over the layers with a mixer, ``kv_live_rows``
+            # (token rows x layers) over those with attention
             attrs.update(state_slots=len(lengths),
                          state_bytes=len(lengths) * self.state_slot_bytes,
-                         state_passes=self.state_passes)
+                         state_passes=self.state_passes,
+                         state_layers=self.state_layers,
+                         kv_layers=self.kv_layers,
+                         kv_live_rows=int(lengths.sum()) * self.kv_layers)
         return attrs
 
     def tick_attrs(self, pools, page_wait: bool) -> Dict[str, int]:

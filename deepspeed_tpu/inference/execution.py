@@ -893,7 +893,10 @@ class MeshExecutor:
         ``models.transformer.kv_write_path``).  ``loop_passes``: how often a token
         runs the model's layers (a looped model's ``loop_passes``, else 1),
         and ``kv_bytes_per_token``: what one token's rows take in the paged
-        leaves over every layer and pass."""
+        leaves over every layer and pass.  ``cache_kind``
+        (``models.transformer.cache_kind``) and how deep its leaves are:
+        ``kv_layers`` layers with K/V pages, ``state_layers`` with a state row
+        a slot."""
         mesh = self.mesh
         return {"mesh_devices": 1 if mesh is None else int(mesh.size),
                 "mesh_axes": {} if mesh is None else {
@@ -902,7 +905,10 @@ class MeshExecutor:
                 **self.weight_placement, "ssm_step": self.ssm_step,
                 "kv_write": dict(self.kv_write),
                 "loop_passes": self.layout.passes,
-                "kv_bytes_per_token": self.layout.kv_token_bytes}
+                "kv_bytes_per_token": self.layout.kv_token_bytes,
+                "cache_kind": self.layout.kind,
+                "kv_layers": self.layout.kv_layers,
+                "state_layers": self.layout.state_layers}
 
     # ----------------------------------------------------------- adoption
 

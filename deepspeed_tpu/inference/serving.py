@@ -779,7 +779,9 @@ class ServingEngine:
             + f" weights: {info['weight_leaves_split']} stack(s) held a "
             f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
             f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out"
-            + (f" ssm_step={info['ssm_step']}" if info["ssm_step"] else "")
+            + f" cache={info['cache_kind']} kv_layers={info['kv_layers']}"
+            + (f" state_layers={info['state_layers']} "
+               f"ssm_step={info['ssm_step']}" if info["ssm_step"] else "")
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items()),
             ranks=[0])
@@ -1952,15 +1954,19 @@ class ServingEngine:
         (``counts [L, E]``, fetched with the tokens) and the real tokens
         the call was given."""
         pairs = live_tokens * self.model.config.moe_top_k * counts.shape[0]
-        sp.set(moe_live_rows=pairs,
-               moe_rows=int(counts.sum()),
-               moe_experts_touched=int((counts > 0).sum()),
+        rows, touched = int(counts.sum()), int((counts > 0).sum())
+        sp.set(moe_live_rows=pairs, moe_rows=rows,
+               moe_experts_touched=touched,
                moe_max_load=int(counts.max()),
                # the (token, expert) pairs the routers chose, those whose
                # expert is held here (all of them unless the model holds a
                # share, ``moe_experts_held``), and the experts held, a layer
-               moe_pairs=pairs, moe_local_pairs=int(counts.sum()),
-               moe_experts_held=int(counts.size))
+               moe_pairs=pairs, moe_local_pairs=rows,
+               moe_experts_held=int(counts.size),
+               # the same three by what they count, for readers that
+               # take any model with a held share
+               pairs_total=pairs, pairs_held=rows,
+               experts_touched_held=touched)
 
     def _arrival_waiting(self, now: float) -> bool:
         """A request is due, a usable slot is free and a prefill may be
@@ -2721,6 +2727,11 @@ class ServingEngine:
             # the bytes of the cache's leaves indexed by slot, of a model
             # with a state a slot (counted in kv_pool_bytes_* too)
             "state_pool_bytes": self._exec.state_bytes,
+            # what the cache is made of: its kind and how many layers its
+            # paged leaves and its slot-indexed leaves are deep
+            "cache_kind": self._layout.kind,
+            "kv_layers": self._layout.kv_layers,
+            "state_layers": self._layout.state_layers,
             # KV-page tiering (docs/SERVING.md "KV-page tiering"): the
             # demoted ledger and host-tier footprint, plus the cumulative
             # movement counters — what capacity planning reads to size the

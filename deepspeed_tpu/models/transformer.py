@@ -49,7 +49,7 @@ class TransformerConfig:
     max_seq_len: int = 2048
     norm: str = "rmsnorm"                     # rmsnorm | layernorm
     activation: str = "swiglu"    # swiglu | gelu | gelu_exact | relu | quick_gelu
-    position: str = "rope"                    # rope | learned | alibi
+    position: str = "rope"            # rope | learned | alibi | none (NoPE)
     rope_theta: float = 10000.0
     # partial rotary (GPT-J/NeoX): apply rope to the first rotary_dim dims
     rotary_dim: Optional[int] = None          # None => full head_dim
@@ -94,7 +94,11 @@ class TransformerConfig:
     # probability in the softmax and gives no value).  The layers are then
     # stacked by kind (:func:`layer_groups`) and run in the published order;
     # the paged cache holds a pool per kind, a window layer's a ring of
-    # :func:`window_ring_pages` pages a slot.
+    # :func:`window_ring_pages` pages a slot.  A third kind, "ssm" (Granite
+    # 4.0-H): a layer whose mixer is the state-space one ALONE, where a
+    # "full" layer of the same model is attention alone (:func:`sublayers`);
+    # K/V leaves then cover the attention layers and the state leaves the
+    # "ssm" ones.
     layer_pattern: Optional[tuple] = None
     window_kv_heads: Optional[int] = None     # None => num_kv_heads
     window_rope_theta: Optional[float] = None  # None => rope_theta
@@ -129,12 +133,16 @@ class TransformerConfig:
     # paged cache then holds two leaves with NO page axis beside K and V:
     # ``ssm_state [L, slots, heads, head_dim, state]`` and the convolution's
     # tail ``ssm_conv [L, slots, taps - 1, channels]``.  0 heads: no mixer.
+    # Under a ``layer_pattern`` the mixer is the "ssm" layers' and theirs
+    # alone; ``ssm_alone`` is how :func:`layer_groups` says so of such a
+    # group's uniform config, never a model's own setting.
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    ssm_alone: bool = False
     # the family's fixed multipliers (muP), every one a constant of the
     # published config: on the embedding and the logits, on attention's
     # input, keys and output, on the mixer's input, output and the five
@@ -149,6 +157,9 @@ class TransformerConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
     mlp_multipliers: Tuple[float, float] = (1.0, 1.0)
+    # on every sublayer's output before it is added to x (Granite's
+    # ``residual_multiplier``): ``x += r * mix(n1(x));  x += r * mlp(n2(x))``
+    residual_multiplier: float = 1.0
     # A looped model (Ouro, ``ouro``): the whole stack of ``num_layers``
     # layers runs ``loop_passes`` times over a token with the SAME weights,
     # the final norm after EVERY pass (the next pass starts from the normed
@@ -282,9 +293,11 @@ class TransformerConfig:
             attn += nh * hd + nkv * hd
         if self.window_attn_sink:
             attn += nh
+        if self.ssm_alone:      # the mixer in attention's place
+            attn = 0
         if self.ssm_heads:
-            # the mixer beside attention: in- and out-projection, the
-            # convolution with its bias, A, D, dt's bias, the gated norm
+            # the mixer: in- and out-projection, the convolution with its
+            # bias, A, D, dt's bias, the gated norm
             ds, conv = ssm_widths(self)[:2]
             attn += (d * ssm_in_width(self) + ds * d
                      + conv * (self.ssm_conv + 1) + 3 * self.ssm_heads + ds)
@@ -437,6 +450,28 @@ CONFIGS: Dict[str, TransformerConfig] = {
         num_layers=48, num_heads=16, num_kv_heads=16, head_dim=128,
         max_seq_len=65536, norm_eps=1e-6, rope_theta=1e6,
         loop_passes=4, sandwich_norm=True, remat=False),
+    # ibm-granite/granite-4.0-h-small config.json (``granitemoehybrid``,
+    # 32B-A9B): 40 layers in the order 5 x mamba, attention, 4 x mamba, ... A
+    # mamba layer is a Mamba-2 mixer ALONE (128 heads of 64 = 2 x hidden,
+    # state 128, 1 group, 4 taps with a bias, chunk 256), an attention layer
+    # grouped-query attention alone (32 heads over 8 KV heads of 128, no
+    # position embedding, scores x 1/128); behind every one 72 experts of
+    # 768, the 10 largest logits softmaxed, beside one shared expert of
+    # 1,536; embedding x 12, every sublayer's output x 0.22, logits / 16
+    # over the tied embedding; RMSNorm eps 1e-5
+    "granite-4.0-h-small": TransformerConfig(
+        vocab_size=100352, hidden_size=4096, intermediate_size=768,
+        num_layers=40, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=131072, norm_eps=1e-5, position="none",
+        layer_pattern=tuple("full" if i in (5, 15, 25, 35) else "ssm"
+                            for i in range(40)),
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=1,
+        ssm_conv=4, ssm_chunk=256,
+        num_experts=72, moe_top_k=10, moe_score_func="softmax",
+        moe_norm_topk_prob=True, moe_shared_experts=2, moe_drop_tokens=False,
+        embed_multiplier=12.0, attn_softmax_scale=0.0078125,
+        residual_multiplier=0.22, lm_head_multiplier=0.0625,
+        tie_embeddings=True, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -485,6 +520,10 @@ def has_moe(cfg: TransformerConfig) -> bool:
     return max(moe_layer_experts(cfg)) > 1
 
 
+# the attention projections: what a layer with the mixer in attention's
+# place (:func:`sublayers`) does not have
+_ATTN_LEAVES = ("wq", "wk", "wv", "wo")
+
 # The per-expert leaves of an MoE layer, ``[L, E, ...]`` in the layer stack:
 # the matmul weights and (gelu experts) their per-expert biases.
 _EXPERT_LEAVES = ("w_gate", "w_up", "w_in", "w_down", "b_in", "b_down")
@@ -503,8 +542,8 @@ def expert_counts_shape(cfg) -> Optional[Tuple[int, int]]:
 
 
 def is_hybrid(cfg: TransformerConfig) -> bool:
-    """Attention layers of two kinds (``layer_pattern``): a K/V pool a
-    kind."""
+    """Layers of more than one kind (``layer_pattern``): cache leaves of
+    its own a kind, and the layers walked in their published order."""
     return cfg.layer_pattern is not None
 
 
@@ -522,16 +561,38 @@ def is_latent(cfg: TransformerConfig) -> bool:
 
 
 def is_ssm(cfg: TransformerConfig) -> bool:
-    """State-space layers beside attention (``ssm_heads``): a fixed-size
-    state a sequence in every block, two cache leaves with no page axis."""
+    """State-space layers (``ssm_heads``): a fixed-size state a sequence in
+    every layer that has the mixer, two cache leaves with no page axis."""
     return bool(cfg.ssm_heads)
+
+
+def sublayers(cfg: TransformerConfig) -> Tuple[bool, bool]:
+    """``(attention, mixer)``: which of the two a layer of the uniform stack
+    ``cfg`` has before its MLP.  The one rule :func:`_block`, the parameters
+    and the cache's leaves follow: attention unless the stack is a
+    pattern's "ssm" group (``ssm_alone``), the state-space mixer where the
+    stack has its heads (both: Falcon-H1's parallel block; under a
+    ``layer_pattern`` :func:`layer_groups` gives each kind's group one)."""
+    return not cfg.ssm_alone, bool(cfg.ssm_heads)
+
+
+def cache_layers(cfg: TransformerConfig) -> Tuple[int, int]:
+    """``(layers with K/V pages, layers with a state row a slot)`` of a
+    model's own layers, what its cache's leaves are deep: the layers with
+    attention and those with a mixer (:func:`sublayers`)."""
+    groups = (layer_groups(cfg).values() if is_grouped(cfg)
+              else [(cfg, cfg.num_layers)])
+    has = [(sublayers(g), n) for g, n in groups]
+    return (sum(n for (attn, _), n in has if attn),
+            sum(n for (_, mixer), n in has if mixer))
 
 
 def cache_depth(cfg: TransformerConfig) -> int:
     """Layers of K/V a sequence keeps: a looped model's every (pass, layer)
-    has its own, ``loop_passes * num_layers``; any other model's its
-    ``num_layers``.  The weights' stack is ``num_layers`` deep either way."""
-    return cfg.loop_passes * cfg.num_layers
+    has its own, ``loop_passes * num_layers``; any other model's its layers
+    with attention (:func:`cache_layers`: all of them but a pattern's "ssm"
+    ones).  The weights' stack is ``num_layers`` deep either way."""
+    return cfg.loop_passes * cache_layers(cfg)[0]
 
 
 def ssm_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
@@ -578,8 +639,13 @@ def layer_plan(cfg: TransformerConfig):
     plan, seen = [], {}
     # a model cut in depth runs the first layers of the published pattern
     for i, kind in enumerate(pattern[:cfg.num_layers]):
-        if kind not in ("full", "window"):
-            raise ValueError(f"layer_pattern[{i}] = {kind!r}: full | window")
+        if kind not in ("full", "window", "ssm"):
+            raise ValueError(
+                f"layer_pattern[{i}] = {kind!r}: full | window | ssm")
+        if kind == "ssm" and not is_ssm(cfg):
+            raise ValueError(
+                f"layer_pattern[{i}] = 'ssm' in a model with no state-space "
+                "mixer (ssm_heads = 0)")
         dense = i < cfg.dense_layers or not has_moe(cfg)
         group = f"{kind}_{'dense' if dense else 'moe'}"
         plan.append((group, seen.get(group, 0), kind, dense))
@@ -591,12 +657,17 @@ def layer_groups(cfg: TransformerConfig):
     """``{group: (the uniform config of its layers, how many)}`` of a
     grouped model, in order of first appearance: each group is a plain stack that
     :func:`init_params`, :func:`param_specs` and :func:`_block` take as they
-    take any model's, with the kind's KV heads, theta, sink and MLP."""
+    take any model's, with the kind's KV heads, theta, sink and MLP, and
+    under a pattern the kind's one mixer (:func:`sublayers`): the state-space
+    one alone in an "ssm" group, attention alone in any other."""
     groups: Dict[str, Any] = {}
     for group, index, kind, dense in layer_plan(cfg):
         window = kind == "window"
         groups[group] = (dataclasses.replace(
             cfg, layer_pattern=None, dense_layers=0, num_layers=index + 1,
+            ssm_alone=kind == "ssm",
+            ssm_heads=(cfg.ssm_heads if kind == "ssm"
+                       or cfg.layer_pattern is None else 0),
             num_kv_heads=(cfg.window_kv_heads if window
                           and cfg.window_kv_heads else cfg.num_kv_heads),
             rope_theta=(cfg.window_rope_theta if window
@@ -703,8 +774,9 @@ def _check_latent(cfg: TransformerConfig) -> None:
 
 
 def _check_ssm(cfg: TransformerConfig) -> None:
-    """What a block with a state-space mixer beside its attention is built
-    from, and what it leaves out."""
+    """What a layer with a state-space mixer (beside its attention, or in
+    its place under a ``layer_pattern``) is built from, and what it leaves
+    out.  Takes a model's config or a group's (:func:`layer_groups`)."""
     if not (cfg.ssm_head_dim and cfg.ssm_state and cfg.ssm_conv > 1
             and cfg.ssm_heads % cfg.ssm_groups == 0
             and len(cfg.ssm_multipliers) == 5
@@ -716,11 +788,21 @@ def _check_ssm(cfg: TransformerConfig) -> None:
     if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
         raise NotImplementedError(
             "state-space layers (ssm_heads) take RMSNorm and a gated MLP")
+    run = (cfg.layer_pattern or ())[:cfg.num_layers]
     for on, what in ((cfg.parallel_residual, "parallel_residual"),
                      (cfg.post_layernorm, "post_layernorm"),
-                     (cfg.num_experts != 1, "expert layers"),
+                     (isinstance(cfg.num_experts, (tuple, list)),
+                      "per-layer expert counts (a num_experts tuple)"),
                      (is_latent(cfg), "latent attention"),
-                     (is_grouped(cfg), "layer_pattern / dense_layers"),
+                     (cfg.dense_layers > 0,
+                      "leading dense layers (dense_layers)"),
+                     ("window" in run,
+                      "window layers in one layer_pattern with them"),
+                     (bool(run) and (cfg.attn_bias or cfg.qk_norm),
+                      "attn_bias or qk_norm under a layer_pattern"),
+                     (bool(run) and not ("ssm" in run and "full" in run),
+                      "a layer_pattern whose layers run are not of both "
+                      "kinds, ssm and full"),
                      (cfg.attention_layers is not None, "attention_layers"),
                      (cfg.pipeline_stages > 1, "pipeline_stages"),
                      (cfg.random_ltd, "random_ltd")):
@@ -780,6 +862,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     if is_grouped(cfg):
         # the parts outside the layers from a one-layer model of the first
         # group, then each group's own stack: ``params["layers"][group]``
+        if is_ssm(cfg):
+            _check_ssm(cfg)
         groups = layer_groups(cfg)
         first = next(iter(groups.values()))[0]
         params = init_params(dataclasses.replace(first, num_layers=1), rng)
@@ -823,9 +907,12 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # tokens at these weights (std^2 * d), so that it takes a real
         # share of a row's probability: a checkpoint learns it
         layers["attn_sink"] = dense(keys[16], (L, nh), std * std * d)
+    if cfg.ssm_alone:
+        for name in _ATTN_LEAVES:
+            del layers[name]
     if is_ssm(cfg):
         _check_ssm(cfg)
-        # the mixer beside attention.  What a normal draw would make
+        # the mixer (:func:`sublayers`).  What a normal draw would make
         # meaningless gets Mamba-2's own initial ranges: A = -U(1, 16) as
         # its log, dt's bias the inverse softplus of a log-uniform step in
         # [1e-3, 1e-1], D = 1, the taps U(+-1/2) (1 / sqrt(taps) at 4)
@@ -1079,6 +1166,9 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
                       k_norm_scale=P(None, "model"))
     if cfg.window_attn_sink:
         layers["attn_sink"] = P(None, "model")
+    if cfg.ssm_alone:
+        for name in _ATTN_LEAVES:
+            del layers[name]
     if is_ssm(cfg):
         # the mixer whole on every chip: its heads share B and C by group
         # and a slot's state is one tensor (sharding them is ROADMAP R5's)
@@ -1264,7 +1354,8 @@ def _lm_head(cfg, params, x):
     """Final hidden states -> logits (tied or untied head, GPT-J's bias)."""
     with jax.named_scope("lm_head"):
         if cfg.tie_embeddings:
-            return x @ params["embed"].astype(cfg.dtype).T
+            return _scaled(x @ params["embed"].astype(cfg.dtype).T,
+                           cfg.lm_head_multiplier)
         logits = x @ params["lm_head"].astype(cfg.dtype)
         if "lm_head_bias" in params:   # GPT-J ties a bias to the LM head
             logits = logits + params["lm_head_bias"].astype(cfg.dtype)
@@ -1944,8 +2035,51 @@ def _ssm_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], y, z):
                 * lp["ssm_norm_scale"].astype(jnp.float32)).astype(cfg.dtype)
 
 
+def _ssm_start(cfg: TransformerConfig, rows: int, dtype):
+    """``(state, tail)`` of ``rows`` sequences that start here: zeros."""
+    return (jnp.zeros((rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                      jnp.float32),
+            jnp.zeros((rows, cfg.ssm_conv - 1, ssm_widths(cfg)[1]), dtype))
+
+
+# Positions of one prompt the mixer takes at a time: the in-projection's
+# output is 16,768 wide and the chunked scan keeps ``[chunk, chunk, heads]``
+# float32 a chunk (the decays between every two positions), together 3 GB
+# over a 16,384-token block of 128 heads in chunks of 256 and 0.4 GB over
+# 2,048 of them
+SSM_BLOCK_TOKENS = 2048
+
+
 def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h, seq_mask=None,
                kept=None, step=None):
+    """:func:`_ssm_mixer_block` over a block of any length: one longer than
+    ``SSM_BLOCK_TOKENS`` (in whole pieces of that many) runs as one scan on
+    the device over the pieces, the state and the convolution's tail carried
+    from piece to piece as they are from call to call, so that the
+    temporaries are a piece's and not the prompt's.  The same numbers either
+    way: real tokens lead the block, so they lead every piece."""
+    B, S, _ = h.shape
+    n = SSM_BLOCK_TOKENS
+    if S <= n or S % n or step is not None:
+        return _ssm_mixer_block(cfg, lp, h, seq_mask, kept, step)
+    if seq_mask is None:
+        seq_mask = jnp.ones((B, S), bool)
+    if kept is None:
+        kept = _ssm_start(cfg, B, h.dtype)
+
+    def pieces(a):      # [B, S, ...] -> [S / n, B, n, ...]
+        return jnp.moveaxis(a.reshape(B, S // n, n, *a.shape[2:]), 1, 0)
+
+    def piece(kept, xs):
+        out, kept = _ssm_mixer_block(cfg, lp, xs[0], xs[1], kept)
+        return kept, out
+
+    kept, out = jax.lax.scan(piece, kept, (pieces(h), pieces(seq_mask)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, S, -1), kept
+
+
+def _ssm_mixer_block(cfg: TransformerConfig, lp: Dict[str, Any], h,
+                     seq_mask=None, kept=None, step=None):
     """The Mamba-2 mixer of a block on its post-norm input ``h [B,S,d]``:
     in-projection, convolution, selective state update (one token a row:
     :func:`_ssm_step`, a longer block: :func:`_ssm_scan`), the skip ``D x``,
@@ -1963,9 +2097,7 @@ def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h, seq_mask=None,
     d_ssm, conv, gn = ssm_widths(cfg)
     if seq_mask is None:
         seq_mask = jnp.ones((B, S), bool)
-    state, tail = kept if kept is not None else (
-        jnp.zeros((B, H, P, N), jnp.float32),
-        jnp.zeros((B, cfg.ssm_conv - 1, conv), h.dtype))
+    state, tail = kept if kept is not None else _ssm_start(cfg, B, h.dtype)
     z, xbc, dt = _ssm_project(cfg, lp, h)
     xbc, tail = _ssm_conv(cfg, lp, xbc, tail, seq_mask.sum(1))
     x = xbc[..., :d_ssm].reshape(B, S, H, P)
@@ -2003,6 +2135,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
       post-LN (BERT)               x = LN(x + attn(x));  x = LN'(x + mlp(x))
       two mixers (Falcon-H1)       n = LN(x);  x += a attn(n) + b ssm(n);
                                    x += mlp(LN'(x))
+      one mixer a layer (Granite   x += r ssm(LN(x))  or  x += r attn(LN(x));
+      4.0-H, ``residual_multiplier``)                 x += r mlp(LN'(x))
       sandwich (Ouro)              x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))
 
     What attention reads, and where K/V go, is the caller's:
@@ -2011,46 +2145,56 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     rows and its up-projection) and returns whatever it keeps (:func:`_attend_full`: nothing;
     :func:`_attend_cached`: the layer's cache buffers; :func:`_attend_paged`:
     the page pool).  ``proj``, ``token_mask`` and ``expert_offset`` are the
-    serving path's (:func:`_qkv`, :func:`_attn_out`, :func:`_mlp`).  A model
-    with state-space layers hands in ``ssm(lp, h) -> (out [B,S,d], kept)``,
-    its mixer over the same normed input with whatever state it continues
+    serving path's (:func:`_qkv`, :func:`_attn_out`, :func:`_mlp`).  A layer
+    with a state-space mixer is handed ``ssm(lp, h) -> (out [B,S,d], kept)``,
+    the mixer over the same normed input with whatever state it continues
     and keeps (:func:`_ssm_mixer`); ``state`` is then ``(attend's, the
-    mixer's)``.
+    mixer's)``.  Which of the two a layer has is :func:`sublayers`' rule: a
+    layer with no attention takes no ``attend`` (None), and ``state`` is
+    ``(None, the mixer's)``.
 
     Returns ``(x, moe_aux_loss, expert_counts, state)``."""
     post = cfg.post_layernorm
+    has_attn, has_mixer = sublayers(cfg)
     h = x if post else _norm(cfg, x, lp["attn_norm_scale"],
                              lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, _off_stream(cfg, h))
-    if ssm is not None:
-        # both mixers read ONE normed input, each through its own multiplier
+    if has_mixer:
+        # the mixers read ONE normed input, each through its own multiplier
         side, ssm_kept = ssm(lp, h)
-        h = _scaled(h, cfg.attn_in_multiplier)
-    if is_latent(cfg):
-        # latent attention: ``attend`` is handed the token's cache row in
-        # place of k, and the layer's up-projection in place of v
-        q, k = _qkv_latent(cfg, lp, h, positions)
-        v = lp["wkv_b"]
+    if has_attn:
+        if has_mixer:
+            h = _scaled(h, cfg.attn_in_multiplier)
+        if is_latent(cfg):
+            # latent attention: ``attend`` is handed the token's cache row
+            # in place of k, and the layer's up-projection in place of v
+            q, k = _qkv_latent(cfg, lp, h, positions)
+            v = lp["wkv_b"]
+        else:
+            q, k, v = _qkv(cfg, lp, h, positions, proj)
+            # named so "save_matmuls" can pin the projection outputs
+            # (post-rope, so the attention backward starts from exactly
+            # these tensors)
+            q = checkpoint_name(q, "q_proj")
+            k = checkpoint_name(k, "k_proj")
+            v = checkpoint_name(v, "v_proj")
+        # the attention's output is named "attn_out" where it is made
+        # (:func:`_attention`; the flash kernel names its own output inside
+        # its vjp, in the layout its backward reads), so a remat policy can
+        # keep it
+        attn, state = attend(q, k, v)
+        attn = _attn_out(cfg, lp, attn, proj)
+        if cfg.sandwich_norm:
+            attn = _norm(cfg, attn, lp["attn_post_norm_scale"])
+        attn, rng = _dropout(cfg, attn, rng, deterministic)
+        if has_mixer:
+            attn = (_scaled(attn, cfg.attn_out_multiplier)
+                    + _scaled(side, cfg.ssm_out_multiplier))
+            state = (state, ssm_kept)
     else:
-        q, k, v = _qkv(cfg, lp, h, positions, proj)
-        # named so "save_matmuls" can pin the projection outputs (post-rope,
-        # so the attention backward starts from exactly these tensors)
-        q = checkpoint_name(q, "q_proj")
-        k = checkpoint_name(k, "k_proj")
-        v = checkpoint_name(v, "v_proj")
-    # the attention's output is named "attn_out" where it is made
-    # (:func:`_attention`; the flash kernel names its own output inside its
-    # vjp, in the layout its backward reads), so a remat policy can keep it
-    attn, state = attend(q, k, v)
-    attn = _attn_out(cfg, lp, attn, proj)
-    if cfg.sandwich_norm:
-        attn = _norm(cfg, attn, lp["attn_post_norm_scale"])
-    attn, rng = _dropout(cfg, attn, rng, deterministic)
-    if ssm is not None:
-        attn = (_scaled(attn, cfg.attn_out_multiplier)
-                + _scaled(side, cfg.ssm_out_multiplier))
-        state = (state, ssm_kept)
-    res = x + attn
+        attn = _scaled(side, cfg.ssm_out_multiplier)
+        state = (None, ssm_kept)
+    res = x + _scaled(attn, cfg.residual_multiplier)
     if post:
         res = _norm(cfg, res, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
         h2 = _maybe_act_quant(cfg, res)
@@ -2066,7 +2210,7 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     if cfg.sandwich_norm:
         m = _norm(cfg, m, lp["mlp_post_norm_scale"])
     m, rng = _dropout(cfg, m, rng, deterministic)
-    x = res + m
+    x = res + _scaled(m, cfg.residual_multiplier)
     if post:
         x = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
     return x, aux, counts, state
@@ -2198,10 +2342,14 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
             # a stack, or a tuple of the layers' own arrays where the
             # serving executor holds the tree (per_layer_leaves)
             lp = {k: v[index] for k, v in params["layers"][group].items()}
-            x = _block(groups[group][0], lp, x, positions, rng, _attend_full(
+            g = groups[group][0]
+            has_attn, has_mixer = sublayers(g)
+            x = _block(g, lp, x, positions, rng, _attend_full(
                 cfg, positions, "xla", custom_positions,
                 window=cfg.window_size if kind == "window" else None,
-                sink=lp.get("attn_sink")))[0]
+                sink=lp.get("attn_sink")) if has_attn else None,
+                ssm=functools.partial(_ssm_mixer, g) if has_mixer else None
+                )[0]
             x = constrain_spec(x, act_spec)
         logits = _head(cfg, params, x)
         return (logits, {"moe_aux_loss": jnp.float32(0.0)}) if return_aux \
@@ -2601,6 +2749,7 @@ def kv_leaf_head_major(cfg: TransformerConfig, width: int) -> bool:
 
 
 # what a kind of layer's K/V leaves add to ``k``/``v`` in the cache's keys
+# (an "ssm" layer has none: its leaves are SSM_POOL_KEYS)
 _KIND_SUFFIX = {"full": "", "window": "_window"}
 
 
@@ -2613,7 +2762,7 @@ def _head_major_leaves(cfg: TransformerConfig) -> Dict[str, bool]:
         return {n: kv_leaf_head_major(cfg, w) for n, w in (
             ("k", cfg.dims_per_head), ("v", cfg.v_dims_per_head))}
     return {n + _KIND_SUFFIX[kind]: pool_leaf_head_major(g.kv_heads, w)
-            for kind, (g, _) in kind_layers(cfg).items()
+            for kind, (g, _) in kind_layers(cfg).items() if kind != "ssm"
             for n, w in (("k", g.dims_per_head), ("v", g.v_dims_per_head))}
 
 
@@ -2650,6 +2799,17 @@ def kv_write_paths(cfg: TransformerConfig, cache: Dict[str, Any],
                              a.dtype),
         _seen_order(head_major, pool_order, key))
         for key, a in cache.items() if key not in SSM_POOL_KEYS}
+
+
+def _state_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
+                  ) -> Dict[str, Any]:
+    """The two slot-indexed leaves of ``layers`` layers with a mixer: the
+    float32 state and the convolution's tail, a row a slot."""
+    return {"ssm_state": jnp.zeros(
+                (layers, slots, cfg.ssm_heads, cfg.ssm_head_dim,
+                 cfg.ssm_state), jnp.float32),
+            "ssm_conv": jnp.zeros(
+                (layers, slots, cfg.ssm_conv - 1, ssm_widths(cfg)[1]), dtype)}
 
 
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
@@ -2719,25 +2879,22 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                             else (page_size, g.kv_heads))
                     cache[n + suffix] = jnp.zeros(
                         (layers, pages) + rows + (w,), dtype)
+        if "ssm" in kinds:
+            # the state leaves cover the "ssm" layers and no other
+            cache.update(_state_leaves(*kinds["ssm"], slots, dtype))
         return cache
     if is_latent(cfg):
         return {"latent": jnp.zeros(
             (cfg.num_layers, num_pages, page_size,
              cfg.kv_lora_rank + cfg.rotary_dim), dtype)}
     if is_ssm(cfg):
-        conv = ssm_widths(cfg)[1]
-
         def leaf(w):
             rows = ((cfg.kv_heads, page_size) if kv_leaf_head_major(cfg, w)
                     else (page_size, cfg.kv_heads))
             return jnp.zeros((cfg.num_layers, num_pages) + rows + (w,), dtype)
 
         return {"k": leaf(cfg.dims_per_head), "v": leaf(cfg.v_dims_per_head),
-                "ssm_state": jnp.zeros(
-                    (cfg.num_layers, slots, cfg.ssm_heads, cfg.ssm_head_dim,
-                     cfg.ssm_state), jnp.float32),
-                "ssm_conv": jnp.zeros(
-                    (cfg.num_layers, slots, cfg.ssm_conv - 1, conv), dtype)}
+                **_state_leaves(cfg, cfg.num_layers, slots, dtype)}
     lead = (cache_depth(cfg), num_pages, page_size, cfg.kv_heads)
     kv = lead + (cfg.dims_per_head,)
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -2757,10 +2914,10 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
     kv = P(None, None, None, "model", None)
     if is_latent(cfg):      # no head axis: every chip holds whole rows
         return {"latent": P(None, None, None, None)}
-    if is_hybrid(cfg):
-        return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
     if is_ssm(cfg):     # whole on one chip: sharded serving refuses it
         return {"k": P(), "v": P(), "ssm_state": P(), "ssm_conv": P()}
+    if is_hybrid(cfg):
+        return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
     if _normalize_kv_dtype(kv_dtype) is None:
         return {"k": kv, "v": kv}
     sc = P(None, None, None)
@@ -3625,6 +3782,14 @@ def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
     which model it is, and why a page of it cannot be shared, parked,
     rescaled or split by head.  Written once, for :func:`_hybrid_refuse`
     and the serving engine's refusals (``inference/cache_layout.py``)."""
+    if is_ssm(cfg):
+        # beside attention in every layer, or in its place in some
+        # (layer_pattern): the leaves differ in depth, the reason does not
+        return "state", (
+            "state-space layers (a state a slot): a slot's state is one "
+            "tensor that no page holds, so a page copied, parked, rescaled "
+            "or split by head leaves it behind, and there is nothing to "
+            "start a tail from or to go back to")
     if is_hybrid(cfg):
         return "window", (
             "window and full attention layers (layer_pattern): a window "
@@ -3635,12 +3800,6 @@ def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
             "latent attention (kv_lora_rank): its rows have no head axis to "
             "shard or scale and only one token a slot reads them back: a tail "
             "behind shared pages or a draft block has nothing to attend through")
-    if is_ssm(cfg):
-        return "state", (
-            "state-space layers (a state a slot): a slot's state is one "
-            "tensor that no page holds, so a page copied, parked, rescaled "
-            "or split by head leaves it behind, and there is nothing to "
-            "start a tail from or to go back to")
     if is_grouped(cfg):
         return "grouped", "leading dense layers (dense_layers)"
     if cfg.loop_passes > 1:
@@ -3672,10 +3831,11 @@ def _head_at(cfg, params, x, logits_at):
 
 def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                           seq_mask, expert_counts, pool_order,
-                          logits_at=None):
-    """:func:`forward_paged` for a model with layers of two kinds: a pool
-    and a plan per kind, the layers in their published order (a Python
-    loop: the kinds' stacks differ in shape, so there is nothing to scan).
+                          logits_at=None, state_slot=None):
+    """:func:`forward_paged` for a model with layers of more than one kind:
+    leaves and a plan per kind, the layers in their published order (a
+    Python loop: the kinds' stacks differ in shape, so there is nothing to
+    scan).
 
     ``page_table`` is ``(full [B, maxp], ring [B, R])``; a lone table's first
     ``R`` columns serve as the ring (one sequence over pools of equal page
@@ -3684,7 +3844,10 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     slot reads the ring through the window (:func:`_ring_read_plan`).  A
     longer block must start its slot (the engine refuses what would start
     one elsewhere: prefix sharing, speculation) and attends inside itself in
-    both kinds of layer, writing its K/V for the tokens to come."""
+    both kinds of layer, writing its K/V for the tokens to come.  An "ssm"
+    layer has no pages: its two slot-indexed leaves, ``[layers of the kind
+    * slots, ...]`` stacked, are read and written where they lie
+    (:func:`_ssm_paged`; ``state_slot`` as :func:`forward_paged`'s)."""
     full_table, ring_table = (page_table if isinstance(page_table,
                                                        (tuple, list))
                               else (page_table, None))
@@ -3703,8 +3866,13 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                 if head_major[n + suffix[kind]] else a)
 
     pools = {kind: {n: stacked(kind, n) for n in ("k", "v")}
-             for kind in kind_cfg}
+             for kind in kind_cfg if kind != "ssm"}
     ps = next(iter(pools.values()))["k"].shape[1]
+    slots = 0
+    if "ssm" in kind_cfg:
+        slots = cache["ssm_state"].shape[1]
+        pools["ssm"] = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+                        for n in SSM_POOL_KEYS}
     W = cfg.window_size
     R = window_ring_pages(W, ps)
     if ring_table is None:
@@ -3713,21 +3881,23 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     positions = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
     # one token a slot reads each kind's pool by its plan; a longer block
     # starts its slot and attends within itself, in both kinds
-    plans = {
-        "full": (_paged_write_plan(full_table, start, seq_mask, ps),
-                 _paged_read_plan(full_table, start, seq_mask, ps)
-                 if S == 1 else None),
-        "window": (_ring_write_plan(ring_table, start, seq_mask, ps),
-                   _ring_read_plan(ring_table, start, seq_mask, ps, W)
-                   if S == 1 else None)}
+    plans = {}
+    if "full" in pools:
+        plans["full"] = (_paged_write_plan(full_table, start, seq_mask, ps),
+                         _paged_read_plan(full_table, start, seq_mask, ps)
+                         if S == 1 else None)
+    if "window" in pools:
+        plans["window"] = (_ring_write_plan(ring_table, start, seq_mask, ps),
+                           _ring_read_plan(ring_table, start, seq_mask, ps, W)
+                           if S == 1 else None)
     reach = None if S == 1 else _block_reach(seq_mask)
     x = _embed(cfg, params, tokens,
                jnp.minimum(positions, cfg.max_seq_len - 1))
     x = constrain_spec(x, P(BATCH_AXES, None, None))
     rng = jax.random.PRNGKey(0)
-    n_pages = {kind: cache["k" + suffix[kind]].shape[1] for kind in pools}
+    n_pages = {kind: cache["k" + suffix[kind]].shape[1] for kind in plans}
     orders = {kind: {n: _seen_order(head_major, pool_order, n + suffix[kind])
-                     for n in ("k", "v")} for kind in pools}
+                     for n in ("k", "v")} for kind in plans}
     # each group's expert stacks whole [n * E, ...] with a layer's experts
     # at l * E: nothing of a layer's size is cut out
     experts = {name: {k: v.reshape(-1, *v.shape[2:]) for k, v in lp.items()
@@ -3735,6 +3905,14 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                for name, lp in params["layers"].items()}
     seen = {kind: 0 for kind in pools}
     counts = []
+    # A prompt's head reads ONE row of x (``logits_at``).  Left to itself the
+    # compiler takes that row out of every layer's two branch outputs at the
+    # very end and keeps them all until then: 0.54 GB a layer of a
+    # 16,384-token block, 5.3 GB over ten layers beside 12.5 GB of weights
+    # and cache.  x is pinned after each layer so that the next reads the
+    # sum and the branches die.  (A model of window and full layers keeps
+    # the program it had: its seven layers fit.)
+    pin = logits_at is not None and "ssm" in kind_cfg
     for group, index, kind, _ in layer_plan(cfg):
         g = groups[group][0]
         # ``v`` is the group's stack or, as the serving executor holds it, a
@@ -3742,30 +3920,41 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
         # cut out of a stack here
         lp = {k: v[index] for k, v in params["layers"][group].items()
               if k not in experts[group]}
-        first_page = seen[kind] * n_pages[kind]
+        layer = seen[kind]
         seen[kind] += 1
-        write, read = plans[kind]
-        if read is not None:
-            read = (read[0], read[1], read[2] + first_page) + read[3:]
-        x, _, c, pools[kind] = _block(
-            g, {**lp, **experts[group]}, x, positions, rng,
-            _attend_paged(g, pools[kind], _plan_at(write, first_page),
-                          read, orders[kind], sink=lp.get("attn_sink"),
-                          within=(None if S == 1 else
-                                  (positions, W if kind == "window"
-                                   else None, reach))),
+        attend = mixer = None
+        if kind == "ssm":
+            mixer = _ssm_paged(g, pools[kind], layer * slots, state_slot,
+                               start, seq_mask)
+        else:
+            first_page = layer * n_pages[kind]
+            write, read = plans[kind]
+            if read is not None:
+                read = (read[0], read[1], read[2] + first_page) + read[3:]
+            attend = _attend_paged(
+                g, pools[kind], _plan_at(write, first_page), read,
+                orders[kind], sink=lp.get("attn_sink"),
+                within=(None if S == 1 else
+                        (positions, W if kind == "window" else None, reach)))
+        x, _, c, kept = _block(
+            g, {**lp, **experts[group]}, x, positions, rng, attend,
             token_mask=seq_mask,
             expert_offset=(jnp.int32(index * (g.moe_experts_held
                                               or g.num_experts))
-                           if experts[group] else None))
+                           if experts[group] else None), ssm=mixer)
+        pools[kind] = kept[1] if kind == "ssm" else kept
         x = constrain_spec(x, P(BATCH_AXES, None, None))
+        if pin:
+            x = jax.lax.optimization_barrier(x)
         if c is not None:
             counts.append(c)
     logits = _head_at(cfg, params, x, logits_at)
+    state = pools.pop("ssm", {})
     out = {n + suffix[kind]: (jnp.transpose(a, (0, 2, 1, 3))
                               if head_major[n + suffix[kind]] else a
                               ).reshape(cache[n + suffix[kind]].shape)
            for kind, leaves in pools.items() for n, a in leaves.items()}
+    out.update({n: a.reshape(cache[n].shape) for n, a in state.items()})
     if not expert_counts:
         return logits, out
     return logits, out, (jnp.stack(counts) if counts else None)
@@ -3858,7 +4047,7 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     if is_hybrid(cfg):
         return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
                                      start, seq_mask, expert_counts,
-                                     pool_order, logits_at)
+                                     pool_order, logits_at, state_slot)
     # a K/V leaf kept head-major (kv_leaf_head_major) is seen through the
     # transpose that moves nothing, as a two-kind model's (stacked below)
     head_major = _head_major_leaves(cfg)

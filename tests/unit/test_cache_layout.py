@@ -159,9 +159,13 @@ def test_decode_attrs_are_the_hosts_row_counts(kind, lengths):
         assert a["kv_live_rows_window"] == 20 * sum(
             min(n, 16) for n in lengths)
     if kind == "state":
-        want |= {"state_slots", "state_bytes", "state_passes"}
+        want |= {"state_slots", "state_bytes", "state_passes",
+                 "state_layers", "kv_layers", "kv_live_rows"}
         assert (a["state_slots"], a["state_bytes"], a["state_passes"]) == (
             len(lengths), 1000 * len(lengths), 3)
+        # a parallel block: every layer has both; rows x layers with K/V
+        assert a["state_layers"] == a["kv_layers"] == lay.cfg.num_layers
+        assert a["kv_live_rows"] == sum(lengths) * lay.cfg.num_layers
     assert set(a) == want
 
 

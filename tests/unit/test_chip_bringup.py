@@ -926,3 +926,70 @@ def test_pythia_cell_resolves_the_chip_tables_rung_on_v5e(one_v5e_chip,
     calls = {r: t.count('custom_call_target="tpu_custom_call"')
              for r, t in texts.items()}
     assert calls[PYTHIA_CELL_RUNG] == 3 and calls["nothing_saveable"] == 4
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_16384"])
+def test_mamba_or_attention_layers_fit_the_chip_at_the_cells_size(
+        one_v5e_chip, monkeypatch, program):
+    """AOT: the two programs of ``granite-4.0-h-small-ep2-d10.ragdoc-backlog``
+    that decide whether its 12.5 GB of weights and cache leave room, at the
+    cell's own sizes (ten layers at the published widths, 36 of 72 experts,
+    32 slots of 104 pages), the weights held a leaf a layer and the cache
+    donated.  The tick: one one-pass kernel a mamba layer over the
+    ``ssm_state`` leaf where it lies, megabytes of temporaries.  The
+    16,384-token prefill: the mixer a piece of 2,048 tokens at a time, the
+    expert layer too, x pinned after each layer, 1.5 GB of temporaries
+    (5.3 GB, and over the chip, where the compiler reads the head's one row
+    out of every layer's branch outputs at the end; PERF.md, PR 47)."""
+    import json
+
+    from benchmark.lib import system
+    from deepspeed_tpu.models import CausalLM, init_params
+    from deepspeed_tpu.models import transformer as T
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "granite-4.0-h-small-ep2-d10.json")) as f:
+        cfg = system.transformer_config(json.load(f), False)
+    slots, maxp = 32, 104
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: T.per_layer_leaves(cfg, init_params(
+                cfg, jax.random.PRNGKey(0)))[0]))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * maxp, 128, dtype=jnp.bfloat16, slots=slots)))
+    assert cache["k"].shape[0] == 1 and cache["ssm_state"].shape[:2] == (
+        9, slots)
+    b, s = (slots, 1) if program == "decode" else (1, 16384)
+
+    def run(params, cache, tokens, table, start, mask, slot, at):
+        kw = {} if program == "decode" else {"state_slot": slot,
+                                             "logits_at": at}
+        logits, cache, counts = T.forward_paged(
+            cfg, params, tokens, cache, table, start, mask,
+            expert_counts=True, **kw)
+        return jnp.argmax(logits[:, -1], -1), cache, counts
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, S((b, s), jnp.int32), S((b, maxp), jnp.int32),
+        S((b,), jnp.int32), S((b, s), jnp.bool_), S((b,), jnp.int32),
+        S((b,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 12.6e9
+    assert mem.alias_size_in_bytes > 2.9e9          # the cache in place
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 0.1e9
+        text = compiled.as_text()
+        kernels = [ln for ln in text.splitlines()
+                   if "custom_call_target=\"tpu_custom_call\"" in ln]
+        # one state step a mamba layer, the attention layer's K and V rows
+        # written where they lie; the rest are the grouped products
+        assert sum("ssm_step" in ln for ln in kernels) == 9
+        assert sum("kv_write" in ln for ln in kernels) == 2
+    else:
+        assert mem.temp_size_in_bytes < 2.0e9

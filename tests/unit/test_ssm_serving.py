@@ -533,8 +533,15 @@ def test_tensor_sharded_serving_refuses():
 
 
 def test_what_the_block_is_not_built_from_is_refused():
-    with pytest.raises(NotImplementedError, match="expert layers"):
-        init_params(tiny(num_experts=4), jax.random.PRNGKey(0))
+    # expert layers behind a mixer are built since PR 47; a count a layer
+    # (the pyramid) and leading dense layers are still not
+    with pytest.raises(NotImplementedError, match="per-layer expert counts"):
+        T._check_ssm(tiny(num_experts=(4, 4)))
+    with pytest.raises(NotImplementedError, match="dense_layers"):
+        init_params(tiny(num_experts=4, dense_layers=1, moe_drop_tokens=False),
+                    jax.random.PRNGKey(0))
+    assert "router" in init_params(tiny(num_experts=4),
+                                   jax.random.PRNGKey(0))["layers"]
     with pytest.raises(NotImplementedError, match="RMSNorm"):
         init_params(tiny(norm="layernorm"), jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="whole groups"):
