@@ -54,6 +54,7 @@ from ..models.transformer import (PAGED_POOL_KEYS, SSM_POOL_KEYS,
                                   paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, per_layer_leaves,
                                   expert_matmul_path, expert_products,
+                                  expert_rows_moved,
                                   kv_write_paths, ssm_step_path)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
@@ -934,6 +935,17 @@ class MeshExecutor:
         kernel = n if self.expert_matmul().get(program) == "kernel" else 0
         return {"moe_kernel_products": kernel,
                 "moe_ragged_products": n - kernel}
+
+    def expert_row_attrs(self, program: str, counts: np.ndarray,
+                         live_tokens: int) -> Dict[str, int]:
+        """The rows ``program``'s expert layers sorted and the rows their
+        way in moved in one call (``models.transformer.expert_rows_moved``),
+        from the call's fetched ``counts``: host arithmetic."""
+        B, S = ((self.b_slots, 1) if program == "decode"
+                else (1, int(program.rsplit("_", 1)[1])))
+        total, moved = expert_rows_moved(self.model.config, B, S, counts,
+                                         live_tokens)
+        return {"moe_sorted_rows": total, "moe_moved_rows": moved}
 
     # ----------------------------------------------------------- adoption
 

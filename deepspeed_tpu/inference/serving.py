@@ -1959,7 +1959,8 @@ class ServingEngine:
         (``counts [L, E]``, fetched with the tokens) and the real tokens
         the call was given; and the grouped products the program holds, by
         the way they went (``moe_kernel_products`` / ``moe_ragged_products``:
-        static a program)."""
+        static a program), with the rows they sorted and the rows the way in
+        moved (``moe_sorted_rows`` / ``moe_moved_rows``)."""
         pairs = live_tokens * self.model.config.moe_top_k * counts.shape[0]
         rows, touched = int(counts.sum()), int((counts > 0).sum())
         sp.set(moe_live_rows=pairs, moe_rows=rows,
@@ -1974,7 +1975,11 @@ class ServingEngine:
                # take any model with a held share
                pairs_total=pairs, pairs_held=rows,
                experts_touched_held=touched,
-               **self._exec.expert_product_attrs(program))
+               **self._exec.expert_product_attrs(program),
+               # the sorted rows and those the way in filled: all of them
+               # on the ragged_dot side, the live ones in whole tiles on
+               # the kernel's
+               **self._exec.expert_row_attrs(program, counts, live_tokens))
 
     def _arrival_waiting(self, now: float) -> bool:
         """A request is due, a usable slot is free and a prefill may be

@@ -1702,6 +1702,34 @@ def expert_matmul_path(cfg: TransformerConfig, B: int, S: int
                 cfg.dtype, _pallas_interpret())
 
 
+def expert_rows_moved(cfg: TransformerConfig, B: int, S: int, counts,
+                      live_tokens: int) -> Tuple[int, int]:
+    """``(sorted, moved)``: the (token, expert) rows the dropless expert
+    layers of a program over a block ``[B, S]`` sort, and the rows their way
+    in fills, from the rows each expert computed (``counts [layers,
+    experts]``, the program's own) and the block's real tokens (its first
+    ``live_tokens``).  On the ``lax.ragged_dot`` side of the rule every
+    sorted row is gathered; on the kernel's (``moe/live_rows.py``) a call's
+    live rows in whole tiles.  ``counts`` are summed over a prompt's chunks,
+    so for a block in several chunks each further chunk that holds a real
+    token is reckoned a whole tile more, the most its own rounding can add:
+    exact for a block in one call, an upper bound (under a tile a chunk)
+    else."""
+    from ..moe.live_rows import ROWS_IN_TILE, moved_rows
+
+    n = _moe_chunks(cfg, B, S)
+    tokens = B * S // n
+    rows = tokens * cfg.moe_top_k + -(tokens * cfg.moe_top_k) % 8
+    total = len(counts) * n * rows
+    if expert_matmul_path(cfg, B, S) != "kernel":
+        return total, total
+    chunks = min(n, -(-live_tokens // tokens))
+    return total, sum(
+        min(chunks * rows, int(moved_rows(int(r), rows))
+            + (chunks - 1) * min(ROWS_IN_TILE, rows))
+        for r in counts.sum(axis=1) if r)
+
+
 def expert_products(cfg: TransformerConfig) -> int:
     """Grouped products a program of ``cfg`` holds: gate, up and down (in
     and down for experts that are not gated) a dropless expert layer."""

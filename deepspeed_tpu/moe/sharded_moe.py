@@ -147,15 +147,18 @@ def expert_matmul_path(tokens: int, top_k: int, num_experts: int,
                        d_model: int, d_ff: int, dtype,
                        pallas_interpret: Optional[bool] = None) -> str:
     """How one call of :func:`moe_ffn_nodrop` over ``tokens`` tokens runs its
-    grouped products: ``"kernel"`` (``ops/pallas/grouped_matmul.py``: only
-    the row tiles that hold rows of a group here, in tiles set by the shape)
+    grouped products and moves its sorted rows: ``"kernel"``
+    (``ops/pallas/grouped_matmul.py``: only the row tiles that hold rows of a
+    group here, in tiles set by the shape; ``moe/live_rows.py``: only those
+    rows gathered in and summed back)
     where the call's static shape says its groups are many rows deep,
     ``tokens x top_k / num_experts`` (the router's width, whatever share is
     held) at or over :data:`KERNEL_ROWS_AN_EXPERT`, in bfloat16, in a program
     that may hold a Pallas kernel (``pallas_interpret`` not ``None``: the
     caller's word, see :func:`moe_ffn_nodrop`) and at a shape the kernel's
     tile plan takes; ``"ragged_dot"`` (``lax.ragged_dot``, the compiler's own
-    grouped product) for every other call: a decode tick's few rows a group,
+    grouped product, between plain gathers of every sorted row) for every
+    other call: a decode tick's few rows a group,
     a short bucket, any other backend, a mesh.  Read at trace time from what
     the code can observe; the serving executor reports it program by program
     (``mesh_info()["expert_matmul"]``)."""
@@ -223,6 +226,12 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     ``lax.ragged_dot``; else the kernels' ``interpret`` flag (``False`` on a
     TPU with one device: ``models.transformer`` says which for its
     programs), and :func:`expert_matmul_path` chooses by the call's shape.
+    The call that takes the kernel also moves its live rows alone
+    (``moe/live_rows.py``): the way in fills the sorted rows of some group
+    here and no other, the way back sums in float32 the held pairs' rows as
+    they are stored, up to the last real token; the rows past the last group
+    are unwritten memory from ``xs`` to the down product and nothing reads
+    them.  Every other call gathers every sorted row each way, as it did.
 
     Returns ``(out [B,S,D], aux, counts [E or count] int32)``; ``counts``
     are the group sizes the matmuls ran with, the rows each expert here
@@ -264,6 +273,11 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
         if cfg.routed_scale != 1.0:
             vals = vals * cfg.routed_scale
 
+    # one rule for the call's three ways through the sorted rows (in, the
+    # products, back), static in its shape
+    kernel = expert_matmul_path(T, k, cfg.num_experts, D,
+                                expert_params["w_down"].shape[1], x.dtype,
+                                pallas_interpret) == "kernel"
     with jax.named_scope("moe_dispatch"):
         flat_expert = idx.reshape(T * k)
         if cfg.held is not None:
@@ -283,7 +297,18 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
         flat_expert = jnp.pad(flat_expert, (0, -(T * k) % 8),
                               constant_values=E)
         order = jnp.argsort(flat_expert, stable=True)            # [rows]
-        xs = x.reshape(T, D)[jnp.minimum(order // k, T - 1)]     # [rows, D]
+        if kernel:
+            from . import live_rows
+
+            # the rows of some group lie first: they alone are filled
+            # (the group sizes' sum, counted here: the other side's
+            # operations keep the order they had)
+            n_live = jnp.sum(flat_expert < E, dtype=jnp.int32)
+            xs = live_rows.rows_in(x.reshape(T, D),
+                                   jnp.minimum(order // k, T - 1), n_live,
+                                   pallas_interpret)
+        else:
+            xs = x.reshape(T, D)[jnp.minimum(order // k, T - 1)]  # [rows, D]
         group_sizes = jnp.bincount(flat_expert, length=E).astype(jnp.int32)
         row_expert = flat_expert[order]                          # [rows]
         live = row_expert < E                    # in some expert's group
@@ -299,12 +324,10 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     row_expert = jnp.minimum(row_expert, n_groups - 1)
     # the three products as lax.ragged_dot or, for a call whose groups are
     # many rows deep on a TPU, as the kernel that visits the groups' row
-    # tiles alone; a row in no group then holds whatever memory held, here
-    # and in ``h``, until the combine drops it
+    # tiles alone; a row in no group then holds whatever memory held, in
+    # ``xs``, here and in ``h``, and the way back never reads it
     product = jax.lax.ragged_dot
-    if expert_matmul_path(T, k, cfg.num_experts, D,
-                          expert_params["w_down"].shape[1], x.dtype,
-                          pallas_interpret) == "kernel":
+    if kernel:
         from ..ops.pallas.grouped_matmul import grouped_matmul
 
         product = lambda *a: grouped_matmul(  # noqa: E731
@@ -327,9 +350,17 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
         # back to token order: assignment j of token t sits in sorted row
         # inv[t*k + j]; a row in no group (a masked token's) adds nothing
         inv = jnp.argsort(order)[:T * k]
-        out = jnp.where(live[:, None], out, 0)[inv]
-        y = jnp.sum(out.reshape(T, k, D).astype(jnp.float32)
-                    * vals[:, :, None], axis=1).astype(x.dtype)
+        if kernel:
+            # the held pairs' rows alone, as they are stored, up to the
+            # last real token
+            n_tokens = T if token_mask is None else jnp.max(jnp.where(
+                token_mask.reshape(T), jnp.arange(1, T + 1), 0))
+            y = live_rows.rows_back(out, inv.reshape(T, k), vals, n_live,
+                                    n_tokens)
+        else:
+            out = jnp.where(live[:, None], out, 0)[inv]
+            y = jnp.sum(out.reshape(T, k, D).astype(jnp.float32)
+                        * vals[:, :, None], axis=1).astype(x.dtype)
     return y.reshape(B, S, D), aux.astype(jnp.float32), counts
 
 

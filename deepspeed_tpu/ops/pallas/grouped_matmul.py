@@ -23,10 +23,11 @@ its other tiles.  bfloat16 operands, float32 sums, the caller's dtype out:
 ``lax.ragged_dot``'s arithmetic.
 
 **Rows of no group are not computed and not stored: their rows of the output
-hold whatever that memory held.**  ``moe_ffn_nodrop`` zeroes them on the way
-back (``jnp.where(live[:, None], out, 0)``), and ``silu(gate) * up`` of such
-rows feeds only rows that the down product does not visit either.  Under
-differentiation the forward zeroes them itself and the backward is
+hold whatever that memory held.**  ``moe_ffn_nodrop`` never reads them: its
+way back (``moe/live_rows.py`` :func:`rows_back`) fetches the rows under the
+group sizes' sum alone, its way in fills no others, and ``silu(gate) * up``
+of such rows feeds only rows that the down product does not visit either.
+Under differentiation the forward zeroes them itself and the backward is
 ``lax.ragged_dot``'s own, so a training step computes what it computed.
 """
 from __future__ import annotations

@@ -819,7 +819,8 @@ def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
 
 
 # Prefill programs under the TPU's rule (the test answers for the backend),
-# at PR 48, a bucket on each side of ``moe.sharded_moe.KERNEL_ROWS_AN_EXPERT``
+# at PR 48 (the kernel side re-pinned at PR 50: the sorted rows' way in and
+# way back over the live rows alone), a bucket on each side of ``moe.sharded_moe.KERNEL_ROWS_AN_EXPERT``
 # for each model: program -> (rows an expert a call, the path, the hash, with
 # the bodies of this repo's kernels cut out).  MiMo's expert layers take a long prompt
 # 2,048 tokens at a time (64 rows an expert); OLMoE's buckets are whole
@@ -829,11 +830,26 @@ def test_other_configurations_programs_are_the_parents(one_v5e_chip, program):
 PREFILLS_AT_PR_48 = {
     "mimo-v2.5_prefill_256": (8, "ragged_dot",
                               PROGRAMS_AT_PR_29["mimo-v2.5_prefill_256"]),
-    "mimo-v2.5_prefill_8192": (64, "kernel", "62132be2f0e64878"),
+    "mimo-v2.5_prefill_8192": (64, "kernel", "7f17f92dfbfba7ad"),
     "olmoe-1b-7b_prefill_256": (32, "ragged_dot",
                                 PROGRAMS_AT_PR_29["olmoe-1b-7b_prefill_256"]),
-    "olmoe-1b-7b_prefill_512": (64, "kernel", "c573b7fc39fcdabc"),
+    "olmoe-1b-7b_prefill_512": (64, "kernel", "6a6be069caf54c58"),
 }
+
+
+def _holds_no_pass_over_dead_rows(text, tokens, top_k, d_model):
+    """A compiled prompt program whose expert layers take ``tokens`` tokens a
+    call moves its live rows alone (``moe/live_rows.py``, PR 50): no float32
+    ``[tokens, top_k, d]`` value (the gather back written as float32) and
+    no select over the ``[tokens x top_k, d]`` sorted rows (the pass that
+    zeroed the dead ones) anywhere in it."""
+    import re
+
+    assert f"f32[{tokens},{top_k},{d_model}]" not in text
+    rows = rf"\w+\[{tokens * top_k},{d_model}\]"
+    assert not re.search(rf"= {rows}\S* select\(", text)
+    # the way in: a buffer nobody wrote, filled by tiles under a loop
+    assert "unwritten_rows" in text
 
 
 @pytest.mark.parametrize("program", list(PREFILLS_AT_PR_48))
@@ -843,7 +859,9 @@ def test_a_prompts_expert_products_by_the_depth_of_its_groups(
     the expert layer are at or over the bound holds gate, up and down of
     every expert layer as ``ops/pallas/grouped_matmul.py`` and none as the
     compiler's ``ragged-dot``; one under it is the parent's program,
-    instruction for instruction; every tick keeps the compiler's."""
+    instruction for instruction; every tick keeps the compiler's.  Since
+    PR 50 the kernel side also moves its live rows alone each way (re-pinned
+    there; the ``ragged_dot`` side's hashes are PR 29's still)."""
     import hashlib
     import json
     import re
@@ -878,6 +896,9 @@ def test_a_prompts_expert_products_by_the_depth_of_its_groups(
     # gate, up and down an expert layer, once where the layers are a scan
     assert held in (T.expert_products(cfg), 3)
     assert not any("ragged" in ln for ln in kernels)
+    _holds_no_pass_over_dead_rows(
+        text, bucket // T._moe_chunks(cfg, 1, bucket), cfg.moe_top_k,
+        cfg.hidden_size)
     # a Pallas kernel's compiled body names the files and lines of its call
     # stack (PERF.md, PR 41): the pin is of the program around the kernels,
     # their operands and layouts; the body has its own tests
@@ -1075,3 +1096,7 @@ def test_mamba_or_attention_layers_fit_the_chip_at_the_cells_size(
         assert sum("grouped_matmul" in ln for ln in kernels) == 30
         assert not any("ragged" in ln for ln in kernels)
         assert T.expert_matmul_path(cfg, b, s) == "kernel"
+        # and the sorted rows around them move live rows alone: no float32
+        # [2048, 10, 4096], no select over [20480, 4096]
+        _holds_no_pass_over_dead_rows(text, 2048, cfg.moe_top_k,
+                                      cfg.hidden_size)

@@ -279,6 +279,20 @@ def test_the_executor_names_the_path_of_every_compiled_program(monkeypatch):
     decode = next(a for (n, _), a in by.items() if n == "serve.decode")
     assert (decode["moe_kernel_products"], decode["moe_ragged_products"]) \
         == (0, 6)
+    # the rows sorted and the rows the way in moved (PR 50): the kernel's
+    # program fills its live rows in whole tiles, a layer; every other
+    # program gathers every sorted row, padding and idle slots included
+    from deepspeed_tpu.moe import live_rows
+    long = by[("serve.prefill", 256)]
+    assert long["moe_sorted_rows"] == 2 * 256 * TOPK
+    assert long["moe_rows"] == 2 * 250 * TOPK
+    assert long["moe_rows"] <= long["moe_moved_rows"] == 2 * \
+        live_rows.moved_rows(250 * TOPK, 256 * TOPK) <= long["moe_sorted_rows"]
+    short = by[("serve.prefill", 16)]
+    assert short["moe_moved_rows"] == short["moe_sorted_rows"] \
+        == 2 * 16 * TOPK > short["moe_rows"]
+    assert decode["moe_moved_rows"] == decode["moe_sorted_rows"] \
+        == 2 * 3 * TOPK + 2 * (-(3 * TOPK) % 8)
     # and the traces hold what was said of them
     ex = sv._exec
     for program, path in said.items():
