@@ -1,0 +1,25 @@
+"""The operations of the traced prefills of a model of gated-delta-rule
+layers and attention layers, each at its REAL tokens (``lib/delta_work.py``:
+the recurrence as the equations count it, never the chunk form's own
+arithmetic), at the chip's published bf16 peak, over the device time of
+their programs, %.  The trace holds the window's first prefills: the k-th
+``serve.prefill`` span of the trace is the k-th the program recorded, and the
+sums run over those both have.  A bucket's padding reads as a lower share.
+None where the model is another, the spans carry no such attrs or there is no
+device trace."""
+from benchmark.lib import delta_work, flops, trace_reduce
+
+
+def read(record):
+    tr = record["trace"]
+    calls = delta_work.prefill_calls(record)
+    if tr is None or not calls:
+        return None
+    ms = trace_reduce.program_ms_in_span(tr, "serve.prefill")
+    n = min(len(ms), len(calls))
+    if not n:
+        return None
+    cfg = record["serve"]["cfg"]
+    ops = sum(delta_work.prefill_flops(cfg, a["tokens"]) for a in calls[:n])
+    peak = flops.peaks(record["device"]["kind"])["bf16_flops_per_s"]
+    return 100.0 * (ops / peak) / (sum(ms[:n]) * 1e-3)

@@ -66,10 +66,12 @@ class CacheLayout:
         self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
                            if self.kind == "window" else 0)
         self.window_pages = self.ring_pages and 1 + b_slots * self.ring_pages
-        # state-space layers: two leaves a row a slot beside the pages.  The
-        # paged leaves cover the layers with attention, the state leaves
-        # those with a mixer: every layer both (a parallel block), or each
-        # layer one of the two (a layer_pattern's "ssm" and "full" kinds)
+        # state-space or delta layers: two leaves a row a slot beside the
+        # pages (``ssm_state`` / ``ssm_conv``, ``delta_state`` /
+        # ``delta_conv``).  The paged leaves cover the layers with attention,
+        # the state leaves those with a mixer: every layer both (a parallel
+        # block), or each layer one of the two (a layer_pattern's "ssm" or
+        # "linear" kind and its "full" kind)
         self.stateful = self.kind == "state"
         self.kv_layers, self.state_layers = cache_layers(cfg)
         # a pool of pages each: ``(pages, page 0 its trash page; those a
@@ -181,8 +183,10 @@ class CacheLayout:
         latent layers run as far as its tokens reach, beside the bucket's,
         and what a block reads of itself a kind of layer.  ``passes`` and
         ``kv_bytes`` as a tick's, over the rows the slot holds after it.  A
-        state a slot: the scan's chunks that hold a real token beside the
-        bucket's, and whether the call resets its slot's state."""
+        state a slot: the scan's chunks (of the kind's own length:
+        ``ssm_chunk``, or ``linear_chunk`` for delta layers) that hold a real
+        token beside the bucket's, and whether the call resets its slot's
+        state."""
         attrs: Dict[str, Any] = {"gathered_rows": (
             0 if self.block_attends_itself else paged_read_rows(
                 [shared + tokens], self.page_size, self.pages_per_slot, 1)),

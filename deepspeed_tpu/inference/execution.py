@@ -48,8 +48,9 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import (PAGED_POOL_KEYS, SSM_POOL_KEYS,
-                                  SSM_STATE_PASSES, cow_copy_pool,
+from ..models.transformer import (DELTA_STATE_PASSES, PAGED_POOL_KEYS,
+                                  SSM_STATE_PASSES, STATE_POOL_KEYS,
+                                  cow_copy_pool, delta_step_path,
                                   expert_counts_shape, is_hybrid,
                                   paged_pool_cache, paged_pool_order,
                                   paged_pool_tuple, per_layer_leaves,
@@ -285,7 +286,11 @@ class MeshExecutor:
         # shape: "one_pass" (the kernel: the leaf in place, a read and a write
         # of a live slot's state a layer) or "xla" (three passes); else None
         self.ssm_step = ssm_step_path(cfg)
-        self.state_passes = SSM_STATE_PASSES.get(self.ssm_step, 0)
+        # likewise a model with delta layers: "one_pass" (one read and one
+        # write) or "plain" (three passes)
+        self.delta_step = delta_step_path(cfg)
+        self.state_passes = (SSM_STATE_PASSES.get(self.ssm_step)
+                             or DELTA_STATE_PASSES.get(self.delta_step, 0))
         pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype, **layout.pool_kw}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
@@ -399,7 +404,7 @@ class MeshExecutor:
         # of which the leaves indexed by slot (a state-space model's)
         self.state_bytes = sum(
             int(a.nbytes) for k, a in zip(self._pool_keys, self.pools)
-            if k in SSM_POOL_KEYS)
+            if k in STATE_POOL_KEYS)
         layout.state_slot_bytes = self.state_bytes // self.b_slots
         layout.state_passes = self.state_passes
         layout.kv_write_leaves = tuple(
@@ -409,7 +414,7 @@ class MeshExecutor:
         layout.kv_token_bytes = sum(
             int(a.nbytes) // (a.shape[1] * self.page_size)
             for k, a in zip(self._pool_keys, self.pools)
-            if k not in SSM_POOL_KEYS)
+            if k not in STATE_POOL_KEYS)
         # device copy of the lane vectors, rebuilt only when a lane
         # changes (admission / retirement) — unlike lengths/last_tok the
         # lanes are constant across a request's whole decode, so the
@@ -890,7 +895,9 @@ class MeshExecutor:
         tree that already lay so (a warm restart's).  ``ssm_step``: the
         step the decode tick of a model with a state a slot holds
         (``"one_pass"`` / ``"xla"``: ``models.transformer.ssm_step_path``),
-        ``None`` for any other model.  ``kv_write``: how the decode tick
+        ``None`` for any other model; ``delta_step`` the same for delta
+        layers (``"one_pass"`` / ``"plain"``:
+        ``models.transformer.delta_step_path``).  ``kv_write``: how the decode tick
         lays a token's rows into each paged leaf (``"row"`` / ``"page"``:
         ``models.transformer.kv_write_path``).  ``expert_matmul``: how each
         program compiled so far runs its expert layers' grouped products
@@ -907,6 +914,7 @@ class MeshExecutor:
                     a: int(mesh.shape[a]) for a in mesh.axis_names
                     if int(mesh.shape[a]) > 1},
                 **self.weight_placement, "ssm_step": self.ssm_step,
+                "delta_step": self.delta_step,
                 "kv_write": dict(self.kv_write),
                 "expert_matmul": self.expert_matmul(),
                 "loop_passes": self.layout.passes,

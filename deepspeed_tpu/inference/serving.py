@@ -780,8 +780,9 @@ class ServingEngine:
             f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
             f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out"
             + f" cache={info['cache_kind']} kv_layers={info['kv_layers']}"
-            + (f" state_layers={info['state_layers']} "
-               f"ssm_step={info['ssm_step']}" if info["ssm_step"] else "")
+            + "".join(f" state_layers={info['state_layers']} "
+                      f"{step}={info[step]}"
+                      for step in ("ssm_step", "delta_step") if info[step])
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items())
             + (" expert_matmul=" + ",".join(

@@ -143,6 +143,27 @@ class TransformerConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 128
     ssm_alone: bool = False
+    # Gated-delta-rule layers (Olmo-Hybrid, ``olmo_hybrid``; Gated DeltaNet,
+    # arXiv:2412.06464): a fourth kind of ``layer_pattern`` entry, "linear",
+    # whose mixer stands in attention's place.  ``linear_heads`` heads, each
+    # with a ``[linear_key_dim, linear_value_dim]`` float32 MATRIX state a
+    # sequence: q, k and v through a depthwise causal convolution of
+    # ``linear_conv`` taps (no bias) and SiLU, q and k L2-normed by head, the
+    # state decayed by ``alpha = exp(-exp(A_log) softplus(a + dt_bias))``
+    # and corrected by the delta rule with the write strength ``beta =
+    # sigmoid(b)`` (doubled under ``linear_neg_eigval``: eigenvalues of the
+    # transition in (-1, 1)), the output RMS-normed by head and gated
+    # (:func:`_delta_mixer`).  A prompt runs the recurrence in chunks of
+    # ``linear_chunk`` positions (:func:`_delta_scan`), a decode token as the
+    # recurrence itself.  The paged cache holds ``delta_state`` and the
+    # convolutions' tail ``delta_conv`` for the "linear" layers, a row a
+    # slot, beside K and V pages for the "full" ones.  0 heads: no such layer.
+    linear_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    linear_chunk: int = 64
+    linear_neg_eigval: bool = True
     # the family's fixed multipliers (muP), every one a constant of the
     # published config: on the embedding and the logits, on attention's
     # input, keys and output, on the mixer's input, output and the five
@@ -173,8 +194,12 @@ class TransformerConfig:
     # logits against a float32 reference (PERF.md, PR 44).
     # ``sandwich_norm``: a norm after each branch as well as before it,
     # ``x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))``, two more scales a layer
+    # ``norm_after`` (Olmo 2's order): the norm on each branch's OUTPUT and
+    # none on its input, ``x += N1(mix(x));  x += N2(mlp(x))``; the two
+    # scales are the leaves ``attn_norm_scale`` / ``mlp_norm_scale``
     loop_passes: int = 1
     sandwich_norm: bool = False
+    norm_after: bool = False
     tie_embeddings: bool = False
     attn_bias: bool = False
     mlp_bias: bool = False
@@ -293,7 +318,7 @@ class TransformerConfig:
             attn += nh * hd + nkv * hd
         if self.window_attn_sink:
             attn += nh
-        if self.ssm_alone:      # the mixer in attention's place
+        if not sublayers(self)[0]:      # a mixer in attention's place
             attn = 0
         if self.ssm_heads:
             # the mixer: in- and out-projection, the convolution with its
@@ -301,6 +326,13 @@ class TransformerConfig:
             ds, conv = ssm_widths(self)[:2]
             attn += (d * ssm_in_width(self) + ds * d
                      + conv * (self.ssm_conv + 1) + 3 * self.ssm_heads + ds)
+        if is_delta(self):
+            # the delta mixer: q, k, v and the gate, b and a, the output
+            # projection, the taps, A, dt's bias, the norm by head
+            qkv = delta_widths(self)[2]
+            attn += (d * (delta_in_width(self) + 2 * self.linear_heads)
+                     + delta_widths(self)[1] * d + self.linear_conv * qkv
+                     + 2 * self.linear_heads + self.linear_value_dim)
         if self.moe_intermediate_size and self.num_experts != 1:
             f = self.moe_intermediate_size
         mlp = 3 * d * f if self.activation == "swiglu" else 2 * d * f
@@ -472,6 +504,21 @@ CONFIGS: Dict[str, TransformerConfig] = {
         embed_multiplier=12.0, attn_softmax_scale=0.0078125,
         residual_multiplier=0.22, lm_head_multiplier=0.0625,
         tie_embeddings=True, remat=False),
+    # allenai/Olmo-Hybrid-7B config.json (``olmo_hybrid``): 32 layers, three
+    # gated-delta-rule layers (30 heads, keys 96 and values 192 wide, 4 taps,
+    # eigenvalues in (-1, 1)) then one of full attention (30 heads over 30 KV
+    # heads of 128, QK-norm, no rotary embedding: ``rope_theta`` null), eight
+    # times; the norm on each branch's output and none on its input; a
+    # SwiGLU of 11,008; RMSNorm eps 1e-6, untied head over 100,352 ids
+    "olmo-hybrid-7b": TransformerConfig(
+        vocab_size=100352, hidden_size=3840, intermediate_size=11008,
+        num_layers=32, num_heads=30, num_kv_heads=30, head_dim=128,
+        max_seq_len=65536, norm_eps=1e-6, position="none", qk_norm=True,
+        norm_after=True,
+        layer_pattern=tuple("full" if i % 4 == 3 else "linear"
+                            for i in range(32)),
+        linear_heads=30, linear_key_dim=96, linear_value_dim=192,
+        linear_conv=4, linear_chunk=64, linear_neg_eigval=True, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -566,14 +613,28 @@ def is_ssm(cfg: TransformerConfig) -> bool:
     return bool(cfg.ssm_heads)
 
 
+def is_delta(cfg: TransformerConfig) -> bool:
+    """Gated-delta-rule layers (``linear_heads``): a matrix state a head a
+    sequence in every "linear" layer of the pattern, two cache leaves with
+    no page axis."""
+    return bool(cfg.linear_heads)
+
+
+def has_state(cfg: TransformerConfig) -> bool:
+    """A state a slot of either kind (:func:`cache_kind` ``"state"``)."""
+    return is_ssm(cfg) or is_delta(cfg)
+
+
 def sublayers(cfg: TransformerConfig) -> Tuple[bool, bool]:
     """``(attention, mixer)``: which of the two a layer of the uniform stack
     ``cfg`` has before its MLP.  The one rule :func:`_block`, the parameters
     and the cache's leaves follow: attention unless the stack is a
-    pattern's "ssm" group (``ssm_alone``), the state-space mixer where the
-    stack has its heads (both: Falcon-H1's parallel block; under a
-    ``layer_pattern`` :func:`layer_groups` gives each kind's group one)."""
-    return not cfg.ssm_alone, bool(cfg.ssm_heads)
+    pattern's "ssm" group (``ssm_alone``) or its "linear" group (the only
+    stack with ``linear_heads``), a mixer where the stack has its heads
+    (both: Falcon-H1's parallel block; under a ``layer_pattern``
+    :func:`layer_groups` gives each kind's group one)."""
+    return (not (cfg.ssm_alone or is_delta(cfg)),
+            bool(cfg.ssm_heads) or is_delta(cfg))
 
 
 def cache_layers(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -609,6 +670,32 @@ def ssm_in_width(cfg: TransformerConfig) -> int:
     return d_ssm + conv + cfg.ssm_heads
 
 
+def delta_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
+    """``(keys' width, values' width, convolved channels)`` of the delta
+    mixer: heads x key dim (q's and k's each), heads x value dim, q, k and
+    v together (2,880, 5,760 and 11,520 for Olmo-Hybrid-7B)."""
+    dk = cfg.linear_heads * cfg.linear_key_dim
+    dv = cfg.linear_heads * cfg.linear_value_dim
+    return dk, dv, 2 * dk + dv
+
+
+def delta_in_width(cfg: TransformerConfig) -> int:
+    """The in-projection's outputs: ``[q | k | v | gate]`` (17,280)."""
+    return delta_widths(cfg)[2] + delta_widths(cfg)[1]
+
+
+def delta_pack(cfg: TransformerConfig) -> int:
+    """Heads of a delta layer whose value columns share one row of the
+    ``delta_state`` leaf, ``[.., heads / pack, key dim, pack x value dim]``:
+    the fewest that make the row whole 128-lane tiles (2 at 192 columns: 384
+    lanes and nothing padded, where ``[.., 96, 192]`` pads each row to 256),
+    1 where no count of heads does."""
+    for p in range(1, 5):
+        if (p * cfg.linear_value_dim) % 128 == 0 and cfg.linear_heads % p == 0:
+            return p
+    return 1
+
+
 def window_ring_pages(window: int, page_size: int) -> int:
     """Pages of a window layer's ring, a slot: what ``window`` consecutive
     positions can span, and one being written.  Position ``p`` lives in
@@ -623,8 +710,11 @@ def pool_leaf_head_major(kv_heads: int, width: int) -> bool:
     width the heads are a partial tile, and the compiler copies such a pool
     into the head-major order before every page scatter and back after it
     (2 x 1 GB a tick for MiMo's 4 x 128 values; PERF.md, PR 30).  Kept
-    head-major from the start it is updated where it lies."""
-    return kv_heads < 8 and width % 128 == 0
+    head-major from the start it is updated where it lies.  The same holds
+    for heads that are not whole tiles of 8 (Olmo-Hybrid's 30 x 128: the
+    compiler pads 30 to 32 and copies 2 x 1.9 GB of pool a tick, over the
+    chip, PERF.md PR 51)."""
+    return (kv_heads < 8 or kv_heads % 8 != 0) and width % 128 == 0
 
 
 def layer_plan(cfg: TransformerConfig):
@@ -639,13 +729,17 @@ def layer_plan(cfg: TransformerConfig):
     plan, seen = [], {}
     # a model cut in depth runs the first layers of the published pattern
     for i, kind in enumerate(pattern[:cfg.num_layers]):
-        if kind not in ("full", "window", "ssm"):
+        if kind not in ("full", "window", "ssm", "linear"):
             raise ValueError(
-                f"layer_pattern[{i}] = {kind!r}: full | window | ssm")
+                f"layer_pattern[{i}] = {kind!r}: full | window | ssm | linear")
         if kind == "ssm" and not is_ssm(cfg):
             raise ValueError(
                 f"layer_pattern[{i}] = 'ssm' in a model with no state-space "
                 "mixer (ssm_heads = 0)")
+        if kind == "linear" and not is_delta(cfg):
+            raise ValueError(
+                f"layer_pattern[{i}] = 'linear' in a model with no delta "
+                "mixer (linear_heads = 0)")
         dense = i < cfg.dense_layers or not has_moe(cfg)
         group = f"{kind}_{'dense' if dense else 'moe'}"
         plan.append((group, seen.get(group, 0), kind, dense))
@@ -659,7 +753,8 @@ def layer_groups(cfg: TransformerConfig):
     :func:`init_params`, :func:`param_specs` and :func:`_block` take as they
     take any model's, with the kind's KV heads, theta, sink and MLP, and
     under a pattern the kind's one mixer (:func:`sublayers`): the state-space
-    one alone in an "ssm" group, attention alone in any other."""
+    one alone in an "ssm" group, the delta one alone in a "linear" group,
+    attention alone in any other."""
     groups: Dict[str, Any] = {}
     for group, index, kind, dense in layer_plan(cfg):
         window = kind == "window"
@@ -668,6 +763,7 @@ def layer_groups(cfg: TransformerConfig):
             ssm_alone=kind == "ssm",
             ssm_heads=(cfg.ssm_heads if kind == "ssm"
                        or cfg.layer_pattern is None else 0),
+            linear_heads=cfg.linear_heads if kind == "linear" else 0,
             num_kv_heads=(cfg.window_kv_heads if window
                           and cfg.window_kv_heads else cfg.num_kv_heads),
             rope_theta=(cfg.window_rope_theta if window
@@ -798,8 +894,10 @@ def _check_ssm(cfg: TransformerConfig) -> None:
                       "leading dense layers (dense_layers)"),
                      ("window" in run,
                       "window layers in one layer_pattern with them"),
-                     (bool(run) and (cfg.attn_bias or cfg.qk_norm),
-                      "attn_bias or qk_norm under a layer_pattern"),
+                     (bool(run) and cfg.attn_bias,
+                      "attn_bias under a layer_pattern"),
+                     ("linear" in run,
+                      "linear layers in one layer_pattern with them"),
                      (bool(run) and not ("ssm" in run and "full" in run),
                       "a layer_pattern whose layers run are not of both "
                       "kinds, ssm and full"),
@@ -809,6 +907,41 @@ def _check_ssm(cfg: TransformerConfig) -> None:
         if on:
             raise NotImplementedError(
                 f"state-space layers (ssm_heads) do not take {what}")
+
+
+def _check_delta(cfg: TransformerConfig) -> None:
+    """What a model with gated-delta-rule layers (``linear_heads``; "linear"
+    entries of its ``layer_pattern``) is built from, and what it leaves out.
+    Takes a model's config or a group's (:func:`layer_groups`)."""
+    if not (cfg.linear_key_dim and cfg.linear_value_dim
+            and cfg.linear_conv > 1 and cfg.linear_chunk > 0):
+        raise ValueError(
+            "delta layers (linear_heads) take linear_key_dim, "
+            "linear_value_dim, linear_conv > 1 and linear_chunk > 0")
+    if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
+        raise NotImplementedError(
+            "delta layers (linear_heads) take RMSNorm and a gated MLP")
+    run = (cfg.layer_pattern or ())[:cfg.num_layers]
+    for on, what in ((is_ssm(cfg), "state-space layers (ssm_heads)"),
+                     (cfg.parallel_residual, "parallel_residual"),
+                     (cfg.post_layernorm, "post_layernorm"),
+                     (cfg.num_experts != 1, "expert layers"),
+                     (is_latent(cfg), "latent attention"),
+                     (cfg.dense_layers > 0,
+                      "leading dense layers (dense_layers)"),
+                     ("window" in run or "ssm" in run,
+                      "window or ssm layers in one layer_pattern with them"),
+                     (cfg.attn_bias, "attn_bias"),
+                     (bool(run) and not ("linear" in run and "full" in run),
+                      "a layer_pattern whose layers run are not of both "
+                      "kinds, linear and full"),
+                     (cfg.attention_layers is not None, "attention_layers"),
+                     (cfg.loop_passes > 1, "loop_passes"),
+                     (cfg.pipeline_stages > 1, "pipeline_stages"),
+                     (cfg.random_ltd, "random_ltd")):
+        if on:
+            raise NotImplementedError(
+                f"delta layers (linear_heads) do not take {what}")
 
 
 def _check_qk_norm(cfg: TransformerConfig) -> None:
@@ -832,6 +965,16 @@ def _check_loop(cfg: TransformerConfig) -> None:
             if on:
                 raise NotImplementedError(
                     f"sandwich_norm does not take {what}")
+    if cfg.norm_after:
+        if cfg.norm != "rmsnorm":
+            raise NotImplementedError(
+                "norm_after is two RMSNorms a layer: a scale and no offset")
+        for on, what in ((cfg.post_layernorm, "post_layernorm"),
+                         (cfg.parallel_residual, "parallel_residual"),
+                         (cfg.sandwich_norm, "sandwich_norm"),
+                         (is_ssm(cfg), "state-space layers")):
+            if on:
+                raise NotImplementedError(f"norm_after does not take {what}")
     if cfg.loop_passes < 1:
         raise ValueError(f"loop_passes={cfg.loop_passes} must be >= 1")
     if cfg.loop_passes > 1:
@@ -864,6 +1007,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # group, then each group's own stack: ``params["layers"][group]``
         if is_ssm(cfg):
             _check_ssm(cfg)
+        if is_delta(cfg):
+            _check_delta(cfg)
         groups = layer_groups(cfg)
         first = next(iter(groups.values()))[0]
         params = init_params(dataclasses.replace(first, num_layers=1), rng)
@@ -898,7 +1043,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         layers["wkv_a"] = dense(keys[1], (L, d, r + rd))
         layers["kv_a_norm_scale"] = jnp.ones((L, r))
         layers["wkv_b"] = dense(keys[2], (L, r, nh * (hd - rd + vd)))
-    if cfg.qk_norm:
+    has_attn = sublayers(cfg)[0]
+    if cfg.qk_norm and has_attn:
         _check_qk_norm(cfg)
         layers["q_norm_scale"] = jnp.ones((L, nh * hd))
         layers["k_norm_scale"] = jnp.ones((L, nkv * hd))
@@ -907,9 +1053,30 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # tokens at these weights (std^2 * d), so that it takes a real
         # share of a row's probability: a checkpoint learns it
         layers["attn_sink"] = dense(keys[16], (L, nh), std * std * d)
-    if cfg.ssm_alone:
+    if not has_attn:
         for name in _ATTN_LEAVES:
             del layers[name]
+    if is_delta(cfg):
+        _check_delta(cfg)
+        # the delta mixer.  ``A_log`` and dt's bias are Mamba-2's draws (the
+        # decay ``exp(-A softplus(a + dt_bias))`` then lies in (0.2, 1) where
+        # a normal draw would leave every head at one rate), the taps
+        # U(+-1/2), the norm by head 1
+        H, K = cfg.linear_heads, cfg.linear_conv
+        _, dv, conv = delta_widths(cfg)
+        sk = jax.random.split(jax.random.fold_in(rng, 20), 6)
+        step = jnp.exp(jax.random.uniform(
+            sk[3], (L, H), minval=math.log(1e-3), maxval=math.log(1e-1)))
+        layers.update(
+            delta_in=dense(sk[0], (L, d, delta_in_width(cfg))),
+            delta_ba=dense(sk[1], (L, d, 2 * H)),
+            delta_conv_w=jax.random.uniform(sk[2], (L, K, conv), minval=-0.5,
+                                            maxval=0.5),
+            delta_dt_bias=step + jnp.log(-jnp.expm1(-step)),
+            delta_A_log=jnp.log(jax.random.uniform(sk[4], (L, H), minval=1.0,
+                                                   maxval=16.0)),
+            delta_norm_scale=jnp.ones((L, cfg.linear_value_dim)),
+            delta_out=dense(sk[5], (L, dv, d), std / math.sqrt(2 * L)))
     if is_ssm(cfg):
         _check_ssm(cfg)
         # the mixer (:func:`sublayers`).  What a normal draw would make
@@ -945,6 +1112,11 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         post = jnp.full((L, d), 1.0 / math.sqrt(2 * L))
         layers["attn_post_norm_scale"] = post
         layers["mlp_post_norm_scale"] = post
+    if cfg.norm_after:
+        # the two norms stand BEHIND w_o and w_down and erase the residual
+        # scaling their init carries: they start at it, as sandwich_norm's
+        layers["attn_norm_scale"] = layers["mlp_norm_scale"] = jnp.full(
+            (L, d), 1.0 / math.sqrt(2 * L))
     if cfg.norm == "layernorm":
         layers["attn_norm_bias"] = jnp.zeros((L, d))
         if not cfg.shared_layernorm:
@@ -1022,7 +1194,13 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         layers["b_down"] = jnp.zeros(mlp_shape(d))
 
     params: Dict[str, Any] = {
-        "embed": dense(keys[7], (cfg.vocab_size, d)),
+        # a block with no norm on its input (norm_after) reads the embedding
+        # raw: rows of unit scale, as a trained one's are.  Drawn at std 0.02
+        # every branch of the first layers works under its norm's eps (mean
+        # squares under 1e-6): the block is then a quadratic map of x that
+        # doubles a rounding error a layer (PERF.md, PR 51)
+        "embed": dense(keys[7], (cfg.vocab_size, d),
+                       1.0 if cfg.norm_after else std),
         "layers": layers,
     }
     if cfg.final_norm:
@@ -1161,14 +1339,22 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         del layers["wk"], layers["wv"]
         layers.update(wkv_a=P(None, None, None), kv_a_norm_scale=rep,
                       wkv_b=col)
-    if cfg.qk_norm:     # over the column-parallel projection, as bq / bk
+    has_attn = sublayers(cfg)[0]
+    if cfg.qk_norm and has_attn:    # over the column-parallel projection
         layers.update(q_norm_scale=P(None, "model"),
                       k_norm_scale=P(None, "model"))
     if cfg.window_attn_sink:
         layers["attn_sink"] = P(None, "model")
-    if cfg.ssm_alone:
+    if not has_attn:
         for name in _ATTN_LEAVES:
             del layers[name]
+    if is_delta(cfg):
+        # whole on every chip, as the state-space mixer below: a slot's
+        # state is one tensor (heads over chips: ROADMAP R5)
+        layers.update(delta_in=P(None, None, None), delta_ba=P(None, None, None),
+                      delta_conv_w=P(None, None, None), delta_dt_bias=rep,
+                      delta_A_log=rep, delta_norm_scale=rep,
+                      delta_out=P(None, None, None))
     if is_ssm(cfg):
         # the mixer whole on every chip: its heads share B and C by group
         # and a slot's state is one tensor (sharding them is ROADMAP R5's)
@@ -1921,24 +2107,36 @@ def _ssm_conv(cfg: TransformerConfig, lp: Dict[str, Any], xbc, tail, n_real):
     gathered from the last K - 1 REAL positions (``n_real [B]``, real tokens
     lead the block): a padded prompt leaves what the unpadded one does, and
     a row with no real token the tail it had."""
-    K, S = cfg.ssm_conv, xbc.shape[1]
     with jax.named_scope("ssm_conv"):
-        ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-        w = lp["ssm_conv_w"].astype(jnp.float32)
-        y = sum(ext[:, k:k + S].astype(jnp.float32) * w[k] for k in range(K))
-        y = y + lp["ssm_conv_b"].astype(jnp.float32)
-        tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
-            e, n, K - 1, axis=0))(ext, n_real.astype(jnp.int32))
-    return jax.nn.silu(y).astype(xbc.dtype), tail
+        y, tail = _causal_conv(lp["ssm_conv_w"], lp["ssm_conv_b"], xbc, tail,
+                               n_real)
+    return y.astype(xbc.dtype), tail
+
+
+def _causal_conv(w, bias, x, tail, n_real):
+    """A depthwise causal convolution of ``w [K, C]`` (and ``bias [C]`` or
+    None) over ``x [B,S,C]`` behind ``tail [B,K-1,C]``, then SiLU, in
+    float32: ``(out [B,S,C] float32, the last K - 1 inputs before position
+    n_real [B])``."""
+    K, S = w.shape[0], x.shape[1]
+    ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    w = w.astype(jnp.float32)
+    y = sum(ext[:, k:k + S].astype(jnp.float32) * w[k] for k in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
+        e, n, K - 1, axis=0))(ext, n_real.astype(jnp.int32))
+    return jax.nn.silu(y), tail
 
 
 def ssm_scan_chunks(cfg: TransformerConfig, block: int,
                     tokens: Optional[int] = None) -> int:
-    """Chunks of ``ssm_chunk`` positions the scan of a block of ``block``
-    tokens runs, or those of them that hold one of its ``tokens`` real
-    ones (the ``scan_chunks`` span attrs of a prompt)."""
+    """Chunks of ``ssm_chunk`` positions (``linear_chunk`` for a model with
+    delta layers) the scan of a block of ``block`` tokens runs, or those of
+    them that hold one of its ``tokens`` real ones (the ``scan_chunks`` span
+    attrs of a prompt)."""
     return -(-(block if tokens is None else min(tokens, block))
-             // cfg.ssm_chunk)
+             // (cfg.linear_chunk if cfg.linear_heads else cfg.ssm_chunk))
 
 
 def _ssm_scan(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
@@ -2178,6 +2376,265 @@ def _ssm_mixer_block(cfg: TransformerConfig, lp: Dict[str, Any], h,
     return out, (state, tail)
 
 
+# ---------------------------------------------------------------------------
+# Gated-delta-rule layers ("linear" entries of a ``layer_pattern``)
+# ---------------------------------------------------------------------------
+
+def _delta_project(cfg: TransformerConfig, lp: Dict[str, Any], h):
+    """The layer's input ``h [B,S,d]`` through the delta mixer's projections:
+    ``(qkv [B,S,channels] before the convolution, the output gate's
+    pre-activation [B,S,H*dv], b [B,S,H], a [B,S,H])``, ``b`` and ``a`` in
+    float32 (the write strength's and the decay's pre-activations)."""
+    conv = delta_widths(cfg)[2]
+    with jax.named_scope("delta_in"):
+        p = h @ lp["delta_in"]
+        ba = (h @ lp["delta_ba"]).astype(jnp.float32)
+    H = cfg.linear_heads
+    return p[..., :conv], p[..., conv:], ba[..., :H], ba[..., H:]
+
+
+def _delta_conv(cfg: TransformerConfig, lp: Dict[str, Any], qkv, tail, n_real):
+    """The depthwise causal convolution over q, k and v (no bias), then
+    SiLU, in float32 (the L2 norms read it so): :func:`_ssm_conv`'s form,
+    the new tail gathered from the last ``K - 1`` REAL positions."""
+    with jax.named_scope("delta_conv"):
+        return _causal_conv(lp["delta_conv_w"], None, qkv, tail, n_real)
+
+
+def _unit_lower_inverse(A):
+    """``(I + A)^-1 - I`` for strictly lower-triangular ``A [.., C, C]``
+    float32, by forward substitution a row at a time on the vector unit
+    (row ``i`` is ``-A_i - sum_j<i A_ij row_j``): backward stable whatever
+    the keys are, where a product of powers of ``A`` is not."""
+    C = A.shape[-1]
+
+    def row(i, T):
+        r = jax.lax.dynamic_index_in_dim(T, i, axis=-2, keepdims=False)
+        new = r + (r[..., :, None] * T).sum(-2)
+        return jax.lax.dynamic_update_index_in_dim(T, new, i, axis=-2)
+
+    return jax.lax.fori_loop(1, C, row, -A)
+
+
+def _delta_scan(cfg: TransformerConfig, q, k, v, g, beta, state):
+    """The gated delta rule over a block, in chunks (the WY / UT form of
+    Gated DeltaNet): ``q``/``k [B,S,H,dk]`` (L2-normed, q scaled), ``v
+    [B,S,H,dv]``, ``g [B,S,H]`` float32 (the log decay, <= 0) and ``beta
+    [B,S,H]`` float32, both 0 at a masked position, ``state [B,H,dk,dv]``
+    float32 -> ``(o [B,S,H,dv] float32, the state after the block)`` with
+
+        S_t = a_t S_t-1 + b_t k_t (v_t - (a_t S_t-1)^T k_t)^T     o_t = S_t^T q_t
+
+    Inside a chunk of C positions, with ``c`` the running sum of ``g``: ``A
+    = strict-lower(diag(b) (K K^T . e^(c_i - c_j)))``, ``T = (I + A)^-1
+    diag(b)``, ``W = T (K . e^c)``, ``U = T V``; between chunks the carried
+    state: ``V' = U - W S``, ``O = (Q . e^c) S + (Q K^T . e^(c_i - c_j) .
+    lower) V'``, ``S <- e^(c_C) S + (K . e^(c_C - c))^T V'``.  ``g = beta =
+    0`` leaves the state as it was and adds nothing, so padding behind the
+    real tokens changes no number.  Decays, sums, the substitution and the
+    carried state are float32; the products take the compute dtype's
+    operands and accumulate in float32."""
+    B, S, H, dk = q.shape
+    C = cfg.linear_chunk
+    pad = -S % C
+    if pad:
+        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
+                                    (a.ndim - 2)) for a in (q, k, v, g, beta))
+    nc, f32, cd = (S + pad) // C, jnp.float32, v.dtype
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
+
+    def chunks(a):      # [B, S, H, ...] -> [nc, B, H, C, ...]
+        a = a.reshape(B, nc, C, H, *a.shape[3:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    with jax.named_scope("delta_scan"):
+        qc, kc, vc = chunks(q), chunks(k), chunks(v)
+        bc = chunks(beta)
+        cum = jnp.cumsum(chunks(g), axis=-1)            # inclusive, <= 0
+        seg = cum[..., :, None] - cum[..., None, :]     # [nc,B,H,i,j]
+        incl = jnp.tril(jnp.ones((C, C), bool))
+        decay = jnp.exp(jnp.where(incl, seg, -jnp.inf))
+        kk = mm("nbhik,nbhjk->nbhij", kc, kc)
+        A = jnp.where(jnp.tril(incl, -1), bc[..., None] * kk * decay, 0.0)
+        T = _unit_lower_inverse(A) + jnp.eye(C, dtype=f32)
+        e = jnp.exp(cum)[..., None]
+        rhs = bc[..., None] * jnp.concatenate(
+            [kc.astype(f32) * e, vc.astype(f32)], axis=-1)
+        WU = mm("nbhij,nbhjx->nbhix", T.astype(cd), rhs.astype(cd))
+        qk = (mm("nbhik,nbhjk->nbhij", qc, kc) * decay).astype(cd)
+        qe = (qc.astype(f32) * e).astype(cd)
+        to_end = jnp.exp(cum[..., -1:] - cum)[..., None]
+        ke = (kc.astype(f32) * to_end).astype(cd)
+        over = jnp.exp(cum[..., -1])                    # [nc,B,H]
+
+        def chunk(s, xs):
+            wu, qk, qe, ke, over = xs
+            sc = s.astype(cd)
+            vp = (wu[..., dk:] - mm("bhck,bhkv->bhcv", wu[..., :dk].astype(cd),
+                                    sc)).astype(cd)
+            o = mm("bhck,bhkv->bhcv", qe, sc) + mm("bhij,bhjv->bhiv", qk, vp)
+            return (s * over[..., None, None]
+                    + mm("bhck,bhcv->bhkv", ke, vp)), o
+
+        state, o = jax.lax.scan(chunk, state, (WU, qk, qe, ke, over))
+        o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)   # [B,nc,C,H,dv]
+    return o.reshape(B, S + pad, H, -1)[:, :S], state
+
+
+def _delta_step(cfg: TransformerConfig, q, k, v, g, beta, state):
+    """:func:`_delta_scan` for one token a row: the recurrence itself, every
+    number float32, the two reads of the state (``S^T k`` of the decayed
+    state, ``S^T q`` of the new one) products and reductions on the vector
+    unit.  A masked row (``g = beta = 0``) keeps its state.
+
+    Who runs it: the non-paged forward, a paged block whose rows are named
+    (``state_slot``), every backend that is not a TPU and every shape the
+    kernel's tile plan refuses (:func:`delta_step_path`).  A decode tick on
+    a TPU runs :func:`_delta_step_one_pass`, whose yardstick in the tests
+    this is."""
+    f32 = jnp.float32
+    with jax.named_scope("delta_step"):
+        q1, k1, v1 = (a[:, 0].astype(f32) for a in (q, k, v))
+        s = state * jnp.exp(g[:, 0])[..., None, None]
+        u = (s * k1[..., None]).sum(-2)                 # [B,H,dv]
+        s = s + k1[..., None] * (beta[:, 0][..., None] * (v1 - u))[..., None, :]
+        o = (s * q1[..., None]).sum(-2)
+    return o[:, None], s
+
+
+def delta_state_pack(cfg: TransformerConfig, state):
+    """``[.., H, dk, dv]`` -> the ``delta_state`` leaf's row ``[.., H / p,
+    dk, p x dv]`` (:func:`delta_pack`): head ``h``'s columns at ``(h % p) x
+    dv`` of row block ``h // p``."""
+    p = delta_pack(cfg)
+    if p == 1:
+        return state
+    *lead, H, dk, dv = state.shape
+    s = state.reshape(*lead, H // p, p, dk, dv)
+    return jnp.swapaxes(s, -3, -2).reshape(*lead, H // p, dk, p * dv)
+
+
+def delta_state_heads(cfg: TransformerConfig, leaf):
+    """:func:`delta_state_pack`'s inverse: a leaf's rows as ``[.., H, dk,
+    dv]``."""
+    p = delta_pack(cfg)
+    if p == 1:
+        return leaf
+    *lead, Hp, dk, pdv = leaf.shape
+    s = leaf.reshape(*lead, Hp, dk, p, pdv // p)
+    return jnp.swapaxes(s, -3, -2).reshape(*lead, Hp * p, dk, pdv // p)
+
+
+def delta_step_path(cfg: TransformerConfig, tokens: int = 1,
+                    state_slot=None, dtype=jnp.float32) -> Optional[str]:
+    """:func:`ssm_step_path` for the delta layers: ``"one_pass"``
+    (``ops/pallas/delta_step.py``: the pool leaf updated in place, both
+    reads of a slot's state from the block in on-chip memory) for a decode
+    tick over a float32 leaf on a TPU at a shape the kernel's tile plan
+    takes; ``"plain"`` (:func:`_delta_step`) for any other single token;
+    ``None`` for a longer block and a model with no such layer."""
+    from ..ops.pallas.delta_step import head_block
+
+    if not is_delta(cfg) or tokens != 1:
+        return None
+    p = delta_pack(cfg)
+    if (state_slot is None and dtype == jnp.float32
+            and _pallas_interpret() is not None
+            and head_block(cfg.linear_heads // p, cfg.linear_key_dim,
+                           p * cfg.linear_value_dim) is not None):
+        return "one_pass"
+    return "plain"
+
+
+# passes over a live slot's state a layer a tick, by the step the tick holds
+# (the plain step: the decay and the update in place, and two reductions
+# that each read the state again)
+DELTA_STATE_PASSES = {"one_pass": 1, "plain": 3}
+
+
+def _delta_step_one_pass(q, k, v, g, beta, leaf, row0, fresh):
+    """:func:`_delta_step` for the rows ``row0 .. row0 + B - 1`` of the
+    stacked cache leaf ``leaf [L * slots, H / p, dk, p * dv]`` where they
+    lie: ``(o [B,1,H,dv] float32, the leaf)``, a ``fresh [B]`` row from
+    zeros."""
+    from ..ops.pallas.delta_step import delta_step
+
+    f32 = jnp.float32
+    with jax.named_scope("delta_step"):
+        leaf, o = delta_step(
+            leaf, row0, fresh, jnp.exp(g[:, 0]), beta[:, 0],
+            q[:, 0].astype(f32), k[:, 0].astype(f32), v[:, 0].astype(f32),
+            interpret=_pallas_interpret())
+    return o[:, None], leaf
+
+
+def _delta_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], o, gate):
+    """The mixer's output RMS-normed WITHIN each head (one learned scale of
+    ``linear_value_dim`` for all heads) and then gated by ``silu(gate)``,
+    in float32: ``o [B,S,H,dv]``, ``gate [B,S,H*dv]`` -> ``[B,S,H*dv]``."""
+    B, S, H, dv = o.shape
+    with jax.named_scope("delta_gate_norm"):
+        o = o.astype(jnp.float32)
+        o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
+                               + cfg.norm_eps)
+             * lp["delta_norm_scale"].astype(jnp.float32))
+        return (o.reshape(B, S, H * dv)
+                * jax.nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype)
+
+
+def _delta_start(cfg: TransformerConfig, rows: int, dtype):
+    """``(state, tail)`` of ``rows`` sequences that start here: zeros."""
+    return (jnp.zeros((rows, cfg.linear_heads, cfg.linear_key_dim,
+                       cfg.linear_value_dim), jnp.float32),
+            jnp.zeros((rows, cfg.linear_conv - 1, delta_widths(cfg)[2]),
+                      dtype))
+
+
+def _delta_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h,
+                 seq_mask=None, kept=None, step=None):
+    """The gated-delta-rule mixer of a block on the layer's input ``h
+    [B,S,d]``: projections, convolution, L2 norm of q and k by head, decay
+    and write strength, the delta rule (one token a row: :func:`_delta_step`,
+    a longer block: :func:`_delta_scan`), norm by head, gate,
+    out-projection.  ``kept``, ``seq_mask``, ``step`` and the result as
+    :func:`_ssm_mixer_block`'s, the state ``[B,H,dk,dv]`` float32."""
+    B, S, _ = h.shape
+    H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
+    wk = delta_widths(cfg)[0]
+    f32 = jnp.float32
+    if seq_mask is None:
+        seq_mask = jnp.ones((B, S), bool)
+    state, tail = kept if kept is not None else _delta_start(cfg, B, h.dtype)
+    qkv, gate, b, a = _delta_project(cfg, lp, h)
+    qkv, tail = _delta_conv(cfg, lp, qkv, tail, seq_mask.sum(1))
+
+    def unit(x):        # float32 [B,S,H,dk], L2-normed by head
+        x = x.reshape(B, S, H, dk)
+        return x * jax.lax.rsqrt(jnp.square(x).sum(-1, keepdims=True) + 1e-6)
+
+    q = (unit(qkv[..., :wk]) * dk ** -0.5).astype(h.dtype)
+    k = unit(qkv[..., wk:2 * wk]).astype(h.dtype)
+    v = qkv[..., 2 * wk:].reshape(B, S, H, dv).astype(h.dtype)
+    live = seq_mask[..., None]
+    beta = jnp.where(live, jax.nn.sigmoid(b) * (
+        2.0 if cfg.linear_neg_eigval else 1.0), 0.0)
+    g = jnp.where(live, -jnp.exp(lp["delta_A_log"].astype(f32))
+                  * jax.nn.softplus(a + lp["delta_dt_bias"].astype(f32)), 0.0)
+    o, state = (step or functools.partial(
+        _delta_step if S == 1 else _delta_scan, cfg))(q, k, v, g, beta, state)
+    with jax.named_scope("delta_out"):
+        out = _delta_gate_norm(cfg, lp, o, gate) @ lp["delta_out"]
+    return out, (state, tail)
+
+
+def _mixer_of(cfg: TransformerConfig):
+    """The mixer a layer of the uniform stack ``cfg`` runs over a block that
+    starts its sequences (:func:`_block`'s ``ssm``), or None."""
+    if is_delta(cfg):
+        return functools.partial(_delta_mixer, cfg)
+    return functools.partial(_ssm_mixer, cfg) if is_ssm(cfg) else None
+
+
 def _dropout(cfg: TransformerConfig, y, rng, deterministic: bool):
     """``(y, rng)``: residual dropout on a sublayer's output, and the key
     chain moved on by the split it took."""
@@ -2202,6 +2659,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
       one mixer a layer (Granite   x += r ssm(LN(x))  or  x += r attn(LN(x));
       4.0-H, ``residual_multiplier``)                 x += r mlp(LN'(x))
       sandwich (Ouro)              x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))
+      norm after (Olmo-Hybrid,     x += N1(mix(x));  x += N2(mlp(x)), mix the
+      ``norm_after``)              delta mixer or attention by the layer's kind
 
     What attention reads, and where K/V go, is the caller's:
     ``attend(q, k, v) -> (out [B,S,Hq,hd], state)`` is handed the layer's
@@ -2220,8 +2679,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     Returns ``(x, moe_aux_loss, expert_counts, state)``."""
     post = cfg.post_layernorm
     has_attn, has_mixer = sublayers(cfg)
-    h = x if post else _norm(cfg, x, lp["attn_norm_scale"],
-                             lp.get("attn_norm_bias"))
+    h = x if post or cfg.norm_after else _norm(
+        cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, _off_stream(cfg, h))
     if has_mixer:
         # the mixers read ONE normed input, each through its own multiplier
@@ -2258,12 +2717,16 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     else:
         attn = _scaled(side, cfg.ssm_out_multiplier)
         state = (None, ssm_kept)
+    if cfg.norm_after:
+        attn = _norm(cfg, attn, lp["attn_norm_scale"])
     res = x + _scaled(attn, cfg.residual_multiplier)
     if post:
         res = _norm(cfg, res, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
         h2 = _maybe_act_quant(cfg, res)
     elif cfg.parallel_residual and cfg.shared_layernorm:
         h2 = h
+    elif cfg.norm_after:
+        h2 = _maybe_act_quant(cfg, res)
     else:
         h2 = _maybe_act_quant(cfg, _off_stream(cfg, _norm(
             cfg, x if cfg.parallel_residual else res,
@@ -2273,6 +2736,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
                           token_mask=token_mask, expert_offset=expert_offset)
     if cfg.sandwich_norm:
         m = _norm(cfg, m, lp["mlp_post_norm_scale"])
+    if cfg.norm_after:
+        m = _norm(cfg, m, lp["mlp_norm_scale"])
     m, rng = _dropout(cfg, m, rng, deterministic)
     x = res + _scaled(m, cfg.residual_multiplier)
     if post:
@@ -2326,7 +2791,7 @@ def _build_block(cfg: TransformerConfig, attn_impl: str, deterministic: bool,
     policy and random-LTD wrapping applied — shared by forward() and the
     1F1B pipeline executor."""
     # a state-space mixer beside attention starts its sequence here
-    ssm = functools.partial(_ssm_mixer, cfg) if is_ssm(cfg) else None
+    ssm = _mixer_of(cfg)
     block = lambda lp, x, sub, pos, window=None: _block(  # noqa: E731
         cfg, lp, x, pos, sub,
         _attend_full(cfg, pos, attn_impl, custom_positions, window),
@@ -2407,13 +2872,12 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any], tokens: jax.Array,
             # serving executor holds the tree (per_layer_leaves)
             lp = {k: v[index] for k, v in params["layers"][group].items()}
             g = groups[group][0]
-            has_attn, has_mixer = sublayers(g)
+            has_attn = sublayers(g)[0]
             x = _block(g, lp, x, positions, rng, _attend_full(
                 cfg, positions, "xla", custom_positions,
                 window=cfg.window_size if kind == "window" else None,
                 sink=lp.get("attn_sink")) if has_attn else None,
-                ssm=functools.partial(_ssm_mixer, g) if has_mixer else None
-                )[0]
+                ssm=_mixer_of(g))[0]
             x = constrain_spec(x, act_spec)
         logits = _head(cfg, params, x)
         return (logits, {"moe_aux_loss": jnp.float32(0.0)}) if return_aux \
@@ -2696,7 +3160,7 @@ def forward_cached(cfg: TransformerConfig, params: Dict[str, Any],
     exactly twice.
     """
     _check_decodable(cfg, params, "cached decode")
-    if is_grouped(cfg) or is_latent(cfg) or is_ssm(cfg):
+    if is_grouped(cfg) or is_latent(cfg) or has_state(cfg):
         _hybrid_refuse("the contiguous cache (forward_cached, generate())",
                        cfg)
     B, S = tokens.shape
@@ -2774,9 +3238,13 @@ KV_QUANT_DTYPES = ("int8",)
 # a decode tick on a TPU hands ``ssm_state`` itself to the one-pass kernel,
 # which updates this layer's rows where they lie (:func:`ssm_step_path`), and
 # any other single token cuts its rows out for :func:`_ssm_step`.
+# A model with delta layers keeps ``delta_state`` and ``delta_conv`` the same
+# way for its "linear" layers (:func:`_delta_paged`).
 SSM_POOL_KEYS = ("ssm_state", "ssm_conv")
+DELTA_POOL_KEYS = ("delta_state", "delta_conv")
+STATE_POOL_KEYS = DELTA_POOL_KEYS + SSM_POOL_KEYS   # a row a slot, no pages
 PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window",
-                   "latent") + SSM_POOL_KEYS
+                   "latent") + STATE_POOL_KEYS
 
 
 def paged_pool_tuple(cache: Dict[str, Any]) -> tuple:
@@ -2813,7 +3281,7 @@ def kv_leaf_head_major(cfg: TransformerConfig, width: int) -> bool:
 
 
 # what a kind of layer's K/V leaves add to ``k``/``v`` in the cache's keys
-# (an "ssm" layer has none: its leaves are SSM_POOL_KEYS)
+# (an "ssm" or "linear" layer has none: its leaves are STATE_POOL_KEYS)
 _KIND_SUFFIX = {"full": "", "window": "_window"}
 
 
@@ -2826,7 +3294,8 @@ def _head_major_leaves(cfg: TransformerConfig) -> Dict[str, bool]:
         return {n: kv_leaf_head_major(cfg, w) for n, w in (
             ("k", cfg.dims_per_head), ("v", cfg.v_dims_per_head))}
     return {n + _KIND_SUFFIX[kind]: pool_leaf_head_major(g.kv_heads, w)
-            for kind, (g, _) in kind_layers(cfg).items() if kind != "ssm"
+            for kind, (g, _) in kind_layers(cfg).items()
+            if kind in _KIND_SUFFIX
             for n, w in (("k", g.dims_per_head), ("v", g.v_dims_per_head))}
 
 
@@ -2862,7 +3331,25 @@ def kv_write_paths(cfg: TransformerConfig, cache: Dict[str, Any],
         jax.ShapeDtypeStruct((a.shape[0] * a.shape[1],) + tuple(a.shape[2:]),
                              a.dtype),
         _seen_order(head_major, pool_order, key))
-        for key, a in cache.items() if key not in SSM_POOL_KEYS}
+        for key, a in cache.items() if key not in STATE_POOL_KEYS}
+
+
+def _delta_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
+                  ) -> Dict[str, Any]:
+    """The two slot-indexed leaves of ``layers`` delta layers: the float32
+    matrix states, ``pack`` heads' value columns a row
+    (:func:`delta_state_pack`), and the three convolutions' tail, a slot's
+    ``taps - 1`` inputs side by side in ONE row (kept ``[.., 3, 11520]`` the
+    3 pads to a tile of 16 sublanes, 5.3 x the bytes, and every layer of a
+    prompt re-lays the leaf out around its update: 39 ms a prompt on the
+    v5e, PERF.md PR 51)."""
+    p = delta_pack(cfg)
+    return {"delta_state": jnp.zeros(
+                (layers, slots, cfg.linear_heads // p, cfg.linear_key_dim,
+                 p * cfg.linear_value_dim), jnp.float32),
+            "delta_conv": jnp.zeros(
+                (layers, slots,
+                 (cfg.linear_conv - 1) * delta_widths(cfg)[2]), dtype)}
 
 
 def _state_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
@@ -2920,7 +3407,7 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     page; only its at-rest representation narrows.
     """
     dtype = dtype or cfg.dtype
-    if ((is_hybrid(cfg) or is_latent(cfg) or is_ssm(cfg))
+    if ((is_hybrid(cfg) or is_latent(cfg) or has_state(cfg))
             and _normalize_kv_dtype(kv_dtype) is not None):
         _hybrid_refuse("the int8 pool", cfg)
     if is_hybrid(cfg):
@@ -2946,6 +3433,8 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
         if "ssm" in kinds:
             # the state leaves cover the "ssm" layers and no other
             cache.update(_state_leaves(*kinds["ssm"], slots, dtype))
+        if "linear" in kinds:
+            cache.update(_delta_leaves(*kinds["linear"], slots, dtype))
         return cache
     if is_latent(cfg):
         return {"latent": jnp.zeros(
@@ -2978,8 +3467,9 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
     kv = P(None, None, None, "model", None)
     if is_latent(cfg):      # no head axis: every chip holds whole rows
         return {"latent": P(None, None, None, None)}
-    if is_ssm(cfg):     # whole on one chip: sharded serving refuses it
-        return {"k": P(), "v": P(), "ssm_state": P(), "ssm_conv": P()}
+    if has_state(cfg):  # whole on one chip: sharded serving refuses it
+        return {k: P() for k in ("k", "v") + (
+            DELTA_POOL_KEYS if is_delta(cfg) else SSM_POOL_KEYS)}
     if is_hybrid(cfg):
         return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -3846,6 +4336,12 @@ def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
     which model it is, and why a page of it cannot be shared, parked,
     rescaled or split by head.  Written once, for :func:`_hybrid_refuse`
     and the serving engine's refusals (``inference/cache_layout.py``)."""
+    if is_delta(cfg):
+        return "state", (
+            "gated-delta-rule layers (a matrix state a head a slot): a "
+            "slot's state is one tensor that no page holds, so a page "
+            "copied, parked, rescaled or split by head leaves it behind, "
+            "and there is nothing to start a tail from or to go back to")
     if is_ssm(cfg):
         # beside attention in every layer, or in its place in some
         # (layer_pattern): the leaves differ in depth, the reason does not
@@ -3930,13 +4426,17 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                 if head_major[n + suffix[kind]] else a)
 
     pools = {kind: {n: stacked(kind, n) for n in ("k", "v")}
-             for kind in kind_cfg if kind != "ssm"}
+             for kind in kind_cfg if kind in suffix}
     ps = next(iter(pools.values()))["k"].shape[1]
     slots = 0
-    if "ssm" in kind_cfg:
-        slots = cache["ssm_state"].shape[1]
-        pools["ssm"] = {n: cache[n].reshape(-1, *cache[n].shape[2:])
-                        for n in SSM_POOL_KEYS}
+    # a kind with a state a slot: its two leaves stacked, and its mixer
+    stateful = {"ssm": (SSM_POOL_KEYS, _ssm_paged),
+                "linear": (DELTA_POOL_KEYS, _delta_paged)}
+    for kind, (keys, _) in stateful.items():
+        if kind in kind_cfg:
+            slots = cache[keys[0]].shape[1]
+            pools[kind] = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+                           for n in keys}
     W = cfg.window_size
     R = window_ring_pages(W, ps)
     if ring_table is None:
@@ -3976,7 +4476,7 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     # and cache.  x is pinned after each layer so that the next reads the
     # sum and the branches die.  (A model of window and full layers keeps
     # the program it had: its seven layers fit.)
-    pin = logits_at is not None and "ssm" in kind_cfg
+    pin = logits_at is not None and slots > 0
     for group, index, kind, _ in layer_plan(cfg):
         g = groups[group][0]
         # ``v`` is the group's stack or, as the serving executor holds it, a
@@ -3987,9 +4487,9 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
         layer = seen[kind]
         seen[kind] += 1
         attend = mixer = None
-        if kind == "ssm":
-            mixer = _ssm_paged(g, pools[kind], layer * slots, state_slot,
-                               start, seq_mask)
+        if kind in stateful:
+            mixer = stateful[kind][1](g, pools[kind], layer * slots,
+                                      state_slot, start, seq_mask)
         else:
             first_page = layer * n_pages[kind]
             write, read = plans[kind]
@@ -4006,14 +4506,15 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
             expert_offset=(jnp.int32(index * (g.moe_experts_held
                                               or g.num_experts))
                            if experts[group] else None), ssm=mixer)
-        pools[kind] = kept[1] if kind == "ssm" else kept
+        pools[kind] = kept[1] if kind in stateful else kept
         x = constrain_spec(x, P(BATCH_AXES, None, None))
         if pin:
             x = jax.lax.optimization_barrier(x)
         if c is not None:
             counts.append(c)
     logits = _head_at(cfg, params, x, logits_at)
-    state = pools.pop("ssm", {})
+    state = {n: a for kind in stateful
+             for n, a in pools.pop(kind, {}).items()}
     out = {n + suffix[kind]: (jnp.transpose(a, (0, 2, 1, 3))
                               if head_major[n + suffix[kind]] else a
                               ).reshape(cache[n + suffix[kind]].shape)
@@ -4105,7 +4606,7 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         raise NotImplementedError(
             "paged decode does not support per-layer attention windows "
             "(attention_layers); use the contiguous cache path")
-    if (is_grouped(cfg) or is_ssm(cfg)) and adapters is not None:
+    if (is_grouped(cfg) or has_state(cfg)) and adapters is not None:
         _hybrid_refuse("multi-tenant adapter serving (per-slot LoRA "
                        "factors)", cfg)
     if is_hybrid(cfg):
@@ -4180,23 +4681,11 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     return (logits, cache, counts) if expert_counts else (logits, cache)
 
 
-def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
-    """:func:`_block`'s ``ssm`` against the cache's two slot-indexed leaves,
-    stacked ``[L * slots, ...]`` with this layer's rows from ``row0`` on:
-    the batch's rows are taken (``state_slot`` None: the block ``row0 ..
-    row0 + B - 1`` where it lies; else the rows it names), a row that
-    starts its sequence begins from zeros, :func:`_ssm_mixer` advances
-    them, and they are written back where they were.  What is kept is the
-    two leaves.
-
-    The state of a decode tick on a TPU is never taken: where
-    :func:`ssm_step_path` says ``"one_pass"`` the mixer's step is
-    :func:`_ssm_step_one_pass` over the ``ssm_state`` leaf itself, one read
-    and one write of each row.  Every other block (a prompt's scan, named
-    rows, another backend, a shape the kernel refuses) takes and puts its
-    rows around :func:`_ssm_scan` / :func:`_ssm_step` as before."""
-    B = seq_mask.shape[0]
-    fresh = (start == 0) & seq_mask.any(axis=1)
+def _slot_rows(row0, state_slot, B: int):
+    """``(take, put)`` over a stacked slot-indexed leaf ``[L * slots, ...]``
+    for a batch of ``B`` rows of the layer whose rows start at ``row0``:
+    the block ``row0 .. row0 + B - 1`` where it lies (``state_slot`` None),
+    else the rows ``state_slot [B]`` names."""
     if state_slot is None:
         def take(a):
             return jax.lax.dynamic_slice_in_dim(a, row0, B, axis=0)
@@ -4212,7 +4701,26 @@ def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
 
         def put(a, new):
             return a.at[rows].set(new.astype(a.dtype))
+    return take, put
 
+
+def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
+    """:func:`_block`'s ``ssm`` against the cache's two slot-indexed leaves,
+    stacked ``[L * slots, ...]`` with this layer's rows from ``row0`` on:
+    the batch's rows are taken (``state_slot`` None: the block ``row0 ..
+    row0 + B - 1`` where it lies; else the rows it names), a row that
+    starts its sequence begins from zeros, :func:`_ssm_mixer` advances
+    them, and they are written back where they were.  What is kept is the
+    two leaves.
+
+    The state of a decode tick on a TPU is never taken: where
+    :func:`ssm_step_path` says ``"one_pass"`` the mixer's step is
+    :func:`_ssm_step_one_pass` over the ``ssm_state`` leaf itself, one read
+    and one write of each row.  Every other block (a prompt's scan, named
+    rows, another backend, a shape the kernel refuses) takes and puts its
+    rows around :func:`_ssm_scan` / :func:`_ssm_step` as before."""
+    fresh = (start == 0) & seq_mask.any(axis=1)
+    take, put = _slot_rows(row0, state_slot, seq_mask.shape[0])
     one_pass = ssm_step_path(cfg, seq_mask.shape[1], state_slot,
                              pools["ssm_state"].dtype) == "one_pass"
 
@@ -4232,6 +4740,49 @@ def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
         return out, {"ssm_state": state,
                      "ssm_conv": put(pools["ssm_conv"], tail)}
     return ssm
+
+
+def _delta_paged(cfg, pools, row0, state_slot, start, seq_mask):
+    """:func:`_ssm_paged` for a delta layer: :func:`_block`'s ``ssm`` against
+    the cache's ``delta_state`` / ``delta_conv`` leaves, stacked ``[L *
+    slots, ...]`` with this layer's rows from ``row0`` on.  Where
+    :func:`delta_step_path` says ``"one_pass"`` (a decode tick on a TPU) the
+    state is never taken: :func:`_delta_step_one_pass` updates the leaf's
+    rows where they lie.  Every other block takes its rows as ``[B,H,dk,dv]``
+    (:func:`delta_state_heads`) around :func:`_delta_scan` /
+    :func:`_delta_step` and packs them back."""
+    B = seq_mask.shape[0]
+    fresh = (start == 0) & seq_mask.any(axis=1)
+    if state_slot is not None and B == 1:
+        # one named row (a prompt's): a slice and an update where the row
+        # lies.  A scatter into the leaf makes the compiler keep a version
+        # of it a layer
+        take, put = _slot_rows(row0 + state_slot[0], None, 1)
+    else:
+        take, put = _slot_rows(row0, state_slot, B)
+    one_pass = delta_step_path(cfg, seq_mask.shape[1], state_slot,
+                               pools["delta_state"].dtype) == "one_pass"
+
+    def mixer(lp, h):
+        # the tail leaf keeps a slot's inputs in one row
+        tail = jnp.where(fresh[:, None, None], 0, take(
+            pools["delta_conv"]).reshape(B, cfg.linear_conv - 1, -1))
+        if one_pass:
+            out, (state, tail) = _delta_mixer(
+                cfg, lp, h, seq_mask, (pools["delta_state"], tail),
+                functools.partial(_delta_step_one_pass, row0=row0,
+                                  fresh=fresh))
+        else:
+            # the recurrence is float32 whatever the leaf is kept in
+            state = delta_state_heads(
+                cfg, take(pools["delta_state"]).astype(jnp.float32))
+            state = jnp.where(fresh[:, None, None, None], 0.0, state)
+            out, (state, tail) = _delta_mixer(cfg, lp, h, seq_mask,
+                                              (state, tail))
+            state = put(pools["delta_state"], delta_state_pack(cfg, state))
+        return out, {"delta_state": state, "delta_conv": put(
+            pools["delta_conv"], tail.reshape(B, -1))}
+    return mixer
 
 
 def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
@@ -4273,7 +4824,7 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
         wplan = _plan_at(write, pool_page)
         rplan = (None if read is None else
                  (read[0], read[1], read[2] + pool_page, read[3]))
-        kv = {k: v for k, v in pools.items() if k not in SSM_POOL_KEYS}
+        kv = {k: v for k, v in pools.items() if k not in STATE_POOL_KEYS}
         attend = (_attend_latent_paged(cfg, kv, wplan, rplan, within)
                   if is_latent(cfg) else
                   _attend_paged(cfg, kv, wplan, rplan, pool_order))

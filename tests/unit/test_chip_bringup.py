@@ -1100,3 +1100,72 @@ def test_mamba_or_attention_layers_fit_the_chip_at_the_cells_size(
         # [2048, 10, 4096], no select over [20480, 4096]
         _holds_no_pass_over_dead_rows(text, 2048, cfg.moe_top_k,
                                       cfg.hidden_size)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_1024"])
+def test_delta_or_attention_layers_fit_the_chip_at_the_cells_size(
+        one_v5e_chip, monkeypatch, program):
+    """AOT: the two programs of ``olmo-hybrid-7b-d16.thinkrollout-backlog``
+    at the cell's own sizes (sixteen layers at the published widths, 32
+    slots of 16 pages), the weights held a leaf a layer and the cache
+    donated.  The tick: one one-pass kernel a delta layer over the
+    ``delta_state`` leaf where it lies (two heads a row, [15, 96, 384]: what
+    the leaf takes is what the equations hold, 849 MB), the K/V leaves of 30
+    heads head-major (row-major the compiler pads 30 heads to 32 and copies
+    2 x 1.9 GB of pool a tick, over the chip), 0.2 GB of temporaries.  The
+    1,024-token prefill: the chunk form, no kernel; the slot's one row of
+    each state leaf sliced and updated where it lies (a scatter into
+    ``delta_conv [.., 3, 11520]`` kept a padded version of the leaf a layer
+    and 1.4 GB of temporaries)."""
+    import json
+
+    from benchmark.lib import system
+    from deepspeed_tpu.models import CausalLM, init_params
+    from deepspeed_tpu.models import transformer as T
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "olmo-hybrid-7b-d16.json")) as f:
+        cfg = system.transformer_config(json.load(f), False)
+    assert T.delta_step_path(cfg) == "one_pass"
+    slots, maxp = 32, 16
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: T.per_layer_leaves(cfg, init_params(
+                cfg, jax.random.PRNGKey(0)))[0]))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * maxp, 128, dtype=jnp.bfloat16, slots=slots)))
+    assert cache["k"].shape == (4, 513, 30, 128, 128)
+    assert cache["delta_state"].shape == (12, slots, 15, 96, 384)
+    b, s = (slots, 1) if program == "decode" else (1, 1024)
+
+    def run(params, cache, tokens, table, start, mask, slot, at):
+        kw = {} if program == "decode" else {"state_slot": slot,
+                                             "logits_at": at}
+        logits, cache = T.forward_paged(cfg, params, tokens, cache, table,
+                                        start, mask, **kw)
+        return jnp.argmax(logits[:, -1], -1), cache
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, S((b, s), jnp.int32), S((b, maxp), jnp.int32),
+        S((b,), jnp.int32), S((b, s), jnp.bool_), S((b,), jnp.int32),
+        S((b,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 13.2e9
+    assert mem.alias_size_in_bytes > 4.9e9          # the cache in place
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 0.3e9
+        assert sum("delta_step" in ln for ln in kernels) == 12
+        # 30 heads are no row the row-write kernel's tile plan takes
+        assert not any("kv_row_write" in ln for ln in kernels)
+    else:
+        assert mem.temp_size_in_bytes < 0.8e9
+        assert not kernels
+        assert "remat_compressed" not in compiled.as_text()

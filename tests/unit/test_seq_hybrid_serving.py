@@ -121,7 +121,7 @@ def test_what_is_still_not_built_is_refused_by_name(what):
             "a num_experts tuple": "per-layer expert counts",
             "parallel_residual": "parallel_residual",
             "pipeline_stages": "pipeline_stages",
-            "attn_bias": "attn_bias or qk_norm"}[what]
+            "attn_bias": "attn_bias under a layer_pattern"}[what]
     with pytest.raises(NotImplementedError, match=said):
         T._check_ssm(_rehearse_cfg(**REFUSED[what]))
 

@@ -42,6 +42,10 @@ KINDS = {
         num_layers=2, hidden_size=64, intermediate_size=96, num_heads=4,
         num_kv_heads=2, head_dim=16, ssm_heads=4, ssm_head_dim=8,
         ssm_state=16, ssm_groups=2, ssm_chunk=8, **F32)),
+    "delta": ("olmo-hybrid-7b", dict(
+        num_layers=4, hidden_size=64, intermediate_size=96, num_heads=4,
+        num_kv_heads=4, head_dim=16, linear_heads=4, linear_key_dim=8,
+        linear_value_dim=64, linear_chunk=8, **F32)),
 }
 
 
@@ -71,7 +75,7 @@ def main() -> None:
             jax.tree_util.tree_map(jnp.asarray, sv._tables(0)),
             jnp.zeros((1, 16), jnp.int32), jnp.int32(5), jnp.int32(0),
             one(np.float32), one(np.int32), one(np.float32), one(np.uint32),
-            *((jnp.int32(0),) if cfg.ssm_heads else ())).as_text()
+            *((jnp.int32(0),) if ex.layout.stateful else ())).as_text()
         print(f"{kind:8s} decode {sha(decode)} prefill_16 {sha(prefill)} "
               f"inventory {json.dumps(sv.program_inventory())}", flush=True)
 
