@@ -97,6 +97,9 @@ class CacheLayout:
         # page: the executor that made the pool and traced the tick says
         self.state_slot_bytes = self.state_passes = self.kv_token_bytes = 0
         self.kv_write_leaves = (0, 0)
+        # whether the tick's read fetches a page at a time (the kernel) or
+        # gathers whole steps of pairs: the executor says that too
+        self.kv_read_pages = False
 
     # ------------------------------------------------------- mechanisms
 
@@ -125,7 +128,9 @@ class CacheLayout:
         """The ``serve.decode`` span attrs of a tick of ``slots`` slots whose
         live ones hold ``lengths`` rows, the row being written counted in.
         ``gathered_rows``: K/V rows its read covers a layer, each slot's own
-        pages in whole steps; ``passes`` times the model's layers read them,
+        pages, in whole steps where the read gathers and to the last live
+        page where it fetches a page at a time (``mesh_info()["kv_read"]``);
+        ``passes`` times the model's layers read them,
         and ``kv_bytes`` is what the rows held take over all of those.
         ``kv_row_write_leaves`` / ``kv_page_write_leaves``: the paged leaves
         into which the tick stores a token's row where it lies, and those it
@@ -138,7 +143,7 @@ class CacheLayout:
         the layers that have K/V (``kv_layers``)."""
         lengths = np.asarray(lengths, np.int64)
         rows = paged_read_rows(lengths, self.page_size, self.pages_per_slot,
-                               slots)
+                               slots, whole_steps=not self.kv_read_pages)
         attrs: Dict[str, Any] = {
             "gathered_rows": rows, "passes": self.passes,
             "kv_bytes": int(lengths.sum()) * self.kv_token_bytes,

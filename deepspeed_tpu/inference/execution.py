@@ -56,7 +56,8 @@ from ..models.transformer import (DELTA_STATE_PASSES, PAGED_POOL_KEYS,
                                   paged_pool_tuple, per_layer_leaves,
                                   expert_matmul_path, expert_products,
                                   expert_rows_moved,
-                                  kv_write_paths, ssm_step_path)
+                                  kv_read_paths, kv_write_paths,
+                                  ssm_step_path)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .cache_layout import CacheLayout
@@ -368,6 +369,14 @@ class MeshExecutor:
         self._param_avals = [(tuple(getattr(x, "shape", ())),
                               str(getattr(x, "dtype", type(x).__name__)))
                              for x in leaves]
+        # and how a tick reads each paged leaf: "pages" (the kernel: each
+        # live page fetched once from where it lies) or "gather" (a step's
+        # pages copied out of the pool, then attended); its queries are as
+        # wide as the widest of the model's dtype and the placed weights'
+        self.kv_read = kv_read_paths(
+            cfg, shapes, self.pool_order, b_slots, jnp.result_type(
+                cfg.dtype, *{x.dtype for x in leaves
+                             if jnp.issubdtype(x.dtype, jnp.floating)}))
         # the fresh pool, committed to its placement: a jit caches on the
         # arg's committed-ness, so an UNcommitted initial pool would cost
         # each program one extra compile when the second call arrives
@@ -410,6 +419,7 @@ class MeshExecutor:
         layout.kv_write_leaves = tuple(
             sum(path == by for path in self.kv_write.values())
             for by in ("row", "page"))
+        layout.kv_read_pages = self.kv_read.get("k") == "pages"
         # one token's rows in every paged leaf, over every layer and pass
         layout.kv_token_bytes = sum(
             int(a.nbytes) // (a.shape[1] * self.page_size)
@@ -899,7 +909,9 @@ class MeshExecutor:
         layers (``"one_pass"`` / ``"plain"``:
         ``models.transformer.delta_step_path``).  ``kv_write``: how the decode tick
         lays a token's rows into each paged leaf (``"row"`` / ``"page"``:
-        ``models.transformer.kv_write_path``).  ``expert_matmul``: how each
+        ``models.transformer.kv_write_path``), ``kv_read`` how it reads each
+        (``"pages"`` / ``"gather"``: ``models.transformer.kv_read_path``).
+        ``expert_matmul``: how each
         program compiled so far runs its expert layers' grouped products
         (:meth:`expert_matmul`).  ``loop_passes``: how often a token
         runs the model's layers (a looped model's ``loop_passes``, else 1),
@@ -916,6 +928,7 @@ class MeshExecutor:
                 **self.weight_placement, "ssm_step": self.ssm_step,
                 "delta_step": self.delta_step,
                 "kv_write": dict(self.kv_write),
+                "kv_read": dict(self.kv_read),
                 "expert_matmul": self.expert_matmul(),
                 "loop_passes": self.layout.passes,
                 "kv_bytes_per_token": self.layout.kv_token_bytes,

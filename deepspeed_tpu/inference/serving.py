@@ -785,6 +785,8 @@ class ServingEngine:
                       for step in ("ssm_step", "delta_step") if info[step])
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items())
+            + " kv_read=" + ",".join(
+                f"{leaf}:{path}" for leaf, path in info["kv_read"].items())
             + (" expert_matmul=" + ",".join(
                 f"{prog}:{path}"
                 for prog, path in info["expert_matmul"].items())

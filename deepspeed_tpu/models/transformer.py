@@ -2222,8 +2222,9 @@ def _ssm_step(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
 
 def _pallas_interpret() -> Optional[bool]:
     """``interpret`` for a Pallas kernel inside a model's program (the
-    one-pass state step and the K/V row write over a cache leaf, the expert
-    layer's grouped product, which ``_mlp`` hands ``moe_ffn``) where a
+    one-pass state step, the K/V row write and the tick's read by pages over
+    a cache leaf, the expert layer's grouped product, which ``_mlp`` hands
+    ``moe_ffn``) where a
     program traced now may hold one, ``None`` where it may not: Pallas kernels compile for the TPU
     (``ops/pallas/common.py``'s own test of the backend) and ``pallas_call``
     has no partitioning rule, so any other backend, and a mesh of more than
@@ -3334,6 +3335,52 @@ def kv_write_paths(cfg: TransformerConfig, cache: Dict[str, Any],
         for key, a in cache.items() if key not in STATE_POOL_KEYS}
 
 
+def kv_read_paths(cfg: TransformerConfig, cache: Dict[str, Any],
+                  pool_order, slots: int = 1, dtype=None) -> Dict[str, str]:
+    """:func:`kv_read_path` of a decode tick for every paged leaf of
+    ``cache`` stored in ``pool_order``, as :func:`forward_paged` hands the
+    leaves to its layers: ``{leaf: "pages" | "gather"}``, the leaves of one
+    kind of layer together (K and V are read by one call, with the scale
+    planes of a quantised pool), for a tick of ``slots`` slots whose queries
+    are of ``dtype`` (``None``: the model's own).  The same function of the
+    same leaves and orders that :func:`_attention_paged` asks when the tick
+    is traced; a
+    window layer's read carries its window's lower bound and its sink, and a
+    latent leaf is read by :func:`_attention_latent_paged`, which does not
+    ask: both keep the gather."""
+    head_major = _head_major_leaves(cfg)
+    suffix = _KIND_SUFFIX["window"]
+    kinds: Dict[bool, Dict[str, str]] = {}      # window? -> {"k": its key}
+    for key in cache:
+        if key not in STATE_POOL_KEYS:
+            window = key.endswith(suffix)
+            kinds.setdefault(window, {})[
+                key[:-len(suffix)] if window else key] = key
+
+    def seen(key):              # [N, page, Hkv, w], whichever way it is kept
+        a = cache[key]
+        rest = tuple(a.shape[2:])
+        if head_major.get(key):
+            rest = (rest[1], rest[0]) + rest[2:]
+        return jax.ShapeDtypeStruct((a.shape[0] * a.shape[1],) + rest,
+                                    a.dtype)
+
+    paths = {}
+    groups = ({kind: g for kind, (g, _) in kind_layers(cfg).items()}
+              if is_hybrid(cfg) else {})
+    for window, keys in kinds.items():
+        heads = groups.get("window" if window else "full", cfg).num_heads
+        path = kv_read_path(
+            {n: seen(key) for n, key in keys.items()},
+            {n: _seen_order(head_major, pool_order, key)
+             for n, key in keys.items()},
+            jax.ShapeDtypeStruct((slots, heads),
+                                 jnp.dtype(dtype or cfg.dtype)),
+            plain=not window and cfg.position != "alibi")
+        paths.update({key: path for key in keys.values()})
+    return paths
+
+
 def _delta_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
                   ) -> Dict[str, Any]:
     """The two slot-indexed leaves of ``layers`` delta layers: the float32
@@ -3542,16 +3589,18 @@ def paged_read_pairs(slots: int, max_pages: int) -> int:
 
 
 def paged_read_rows(lengths, page_size: int, max_pages: int,
-                    slots: int) -> int:
+                    slots: int, whole_steps: bool = True) -> int:
     """K/V rows a layer of a paged forward of ``slots`` slots reads when its
     live slots hold ``lengths`` rows each (the rows being written counted
     in; a slot with no real token is left out or 0): the slots' own whole
-    pages, at most the page-table row each, summed and rounded up to whole
-    steps of :func:`paged_read_pairs` pairs.  The host's copy of what
-    :func:`_paged_read_plan` computes from ``start``, ``seq_mask`` and the
-    table's shape on the device (the serving engine's ``gathered_rows`` span
-    attr)."""
-    pairs = paged_read_pairs(slots, max_pages)
+    pages, at most the page-table row each, summed and, where the read
+    gathers (``whole_steps``), rounded up to whole steps of
+    :func:`paged_read_pairs` pairs; the kernel that fetches a page at a time
+    (:func:`kv_read_path`'s ``"pages"``) stops at the last live one.  The
+    host's copy of what :func:`_paged_read_plan` computes from ``start``,
+    ``seq_mask`` and the table's shape on the device (the serving engine's
+    ``gathered_rows`` span attr)."""
+    pairs = paged_read_pairs(slots, max_pages) if whole_steps else 1
     live = int(np.minimum(-(-np.asarray(lengths, np.int64) // page_size),
                           max_pages).sum())
     return -(-live // pairs) * pairs * page_size
@@ -3617,14 +3666,22 @@ def _pool_views(pools, pool_order):
     pool ``[N, page]`` go as they are."""
     views, axes = {}, {}
     for n, a in pools.items():
-        order = _leaf_order(pool_order, n)
-        perm = (0, 1, 2, 3)
-        if order is not None and tuple(order[:2]) == (0, 1):
-            perm = (0,) + tuple(i - 1 for i in order[2:])
+        perm, letters = _view_axes(_leaf_order(pool_order, n))
         views[n] = jnp.transpose(a, perm) if a.ndim == 4 else a
         if a.ndim == 4:
-            axes[n] = "".join(" tkd"[i] for i in perm[1:])
+            axes[n] = letters
     return views, axes
+
+
+def _view_axes(order) -> Tuple[Tuple[int, ...], str]:
+    """``(perm, letters)``: the axes of a stacked K/V leaf ``[N, page, Hkv,
+    hd]`` in the order the device stores them, given the observed ``order``
+    of the unstacked leaf (:func:`paged_pool_order`; ``None``: row-major),
+    as :func:`_pool_views`' transpose and as its einsum letters."""
+    perm = (0, 1, 2, 3)
+    if order is not None and tuple(order[:2]) == (0, 1):
+        perm = (0,) + tuple(i - 1 for i in order[2:])
+    return perm, "".join(" tkd"[i] for i in perm[1:])
 
 
 def _attention_paged(cfg, q, pools, read, pool_order=None, sink=None):
@@ -3669,6 +3726,12 @@ def _attention_paged(cfg, q, pools, read, pool_order=None, sink=None):
     (rows ``low <= r <= limit`` pass: the window).  ``sink [Hq]`` is a
     learned logit a head that joins each row's softmax at the end of the
     walk, one more term of the sum that adds nothing to the accumulator.
+
+    Where :func:`kv_read_path` says ``"pages"`` (one token a slot over
+    bfloat16 leaves the kernel's tile plan takes, on a TPU) the same list,
+    mask and softmax run in ``ops/pallas/paged_read.py``, which fetches each
+    live page once from the pool and copies nothing
+    (:func:`_attention_pages`); the loop below is every other read.
     """
     steps, slot, pages, limit = read[:4]
     low = read[4] if len(read) > 4 else None
@@ -3681,6 +3744,10 @@ def _attention_paged(cfg, q, pools, read, pool_order=None, sink=None):
     views, axes = _pool_views(pools, pool_order)
     slopes = (jnp.asarray(_alibi_slopes(Hq)).reshape(Hkv, G)
               if cfg.position == "alibi" else None)
+    if kv_read_path(pools, pool_order, jax.ShapeDtypeStruct((B, Hq), q.dtype),
+                    S, plain=(slopes is None and low is None
+                              and sink is None)) == "pages":
+        return _attention_pages(cfg, q, views, axes, read)
     r = jnp.arange(ps, dtype=jnp.int32)
 
     def step(i, carry):
@@ -3757,6 +3824,72 @@ def _attention_paged(cfg, q, pools, read, pool_order=None, sink=None):
     # a slot that was not read has l == 0: its output is 0, not NaN
     out = acc / jnp.moveaxis(jnp.where(l > 0, l, 1.0), 3, 1)[..., None]
     return out.astype(q.dtype).reshape(B, S, Hq, vd)
+
+
+def kv_read_path(pools, pool_order, query, tokens: int = 1,
+                 plain: bool = True) -> str:
+    """How a block of ``tokens`` a slot reads the live pages of the pool
+    leaves ``pools`` (``{"k", "v"[, "k_scale", "v_scale"]}``: arrays ``[N,
+    page, Hkv, hd]`` or their shapes and dtypes) that the device stores in
+    ``pool_order`` (:func:`paged_pool_order`'s, one order or one a leaf):
+    ``"pages"`` (``ops/pallas/paged_read.py``: each live page fetched once
+    from where it lies into on-chip memory and attended there) for one token
+    a slot with its queries (``query``: ``[B, Hq]`` and their dtype, an array
+    or its shape and dtype) and the K and V leaves in bfloat16, every slot's
+    softmax state small enough to stay on chip for the call
+    (``resident_bytes``), no scale planes, heads of whole 128 lanes, both
+    leaves stored row-major or both head-major with the page block's
+    second-minor axis in whole tiles (``page_block``), a page block of at least
+    ``MIN_BLOCK_BYTES`` (the smallest the chip has read: a model of fewer KV
+    heads was not measured), a ``plain`` softmax (no ALiBi, no window's
+    lower bound, no sink), in a program that may hold a Pallas kernel
+    (:func:`_pallas_interpret`: a TPU, one device); ``"gather"``
+    (:func:`_attention_paged`'s loop: a step's pages copied out of the pool,
+    then attended) for every other read: a prompt or a verify block, a
+    quantised pool, a leaf stored page-rows-minor (a 64-wide or 192-wide head
+    on the v5e), a sharded mesh, any other backend.  One plan
+    (:func:`_paged_read_plan`), one mask and one blockwise softmax either
+    way.  Read at trace time from what the code can observe; the serving
+    executor reports it (``mesh_info()["kv_read"]``)."""
+    from ..ops.pallas.paged_read import (MIN_BLOCK_BYTES, RESIDENT_BYTES,
+                                         page_block, resident_bytes)
+
+    if (tokens != 1 or not plain or set(pools) != {"k", "v"}
+            or _pallas_interpret() is None
+            or not pools["k"].dtype == pools["v"].dtype == query.dtype
+            or resident_bytes(*query.shape, pools["k"].shape[3],
+                              pools["v"].shape[3]) > RESIDENT_BYTES):
+        return "gather"
+    shapes, letters = {}, set()
+    for n, a in pools.items():
+        perm, axes = _view_axes(_leaf_order(pool_order, n))
+        shapes[n] = tuple(a.shape[i] for i in perm)
+        letters.add(axes)
+    block = len(letters) == 1 and page_block(
+        shapes["k"], shapes["v"], pools["k"].dtype, letters.pop())
+    return "pages" if block and block >= MIN_BLOCK_BYTES else "gather"
+
+
+def _attention_pages(cfg, q, views, axes, read):
+    """:func:`_attention_paged` where :func:`kv_read_path` says ``"pages"``:
+    the same list of live pairs (flat: the kernel's grid is as long as the
+    live ones, so the list's rounding to whole steps is not read), the same
+    mask and the same blockwise softmax in float32, each page fetched once
+    from the pool where it lies (``ops/pallas/paged_read.py``)."""
+    from ..ops.pallas.paged_read import paged_read
+
+    _, slot, pages, limit = read
+    B, hd = q.shape[0], q.shape[3]
+    slot = slot.reshape(-1)
+    with jax.named_scope("kv_read"):
+        acc, l = paged_read(
+            q[:, 0], views["k"], views["v"],
+            jnp.sum(slot < B, dtype=jnp.int32), slot, pages.reshape(-1),
+            limit.reshape(-1), axes=axes["k"], scale=_sm_scale(cfg, hd),
+            interpret=_pallas_interpret())
+    # a slot that was not read has l == 0: its output is 0, not NaN
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return out.astype(q.dtype)[:, None]
 
 
 def _adapter_delta(h, ab, scale):
@@ -3967,7 +4100,10 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
     Read: ``read`` is the call's list of live (slot, page) pairs
     (:func:`_paged_read_plan`, one for all layers, its pages moved to this
     layer's), which :func:`_attention_paged` gathers a step's worth of
-    whole pages at a time.  The XLA pool ops thus slice all
+    whole pages at a time, or, for one token a slot where the leaves' shape
+    allows (:func:`kv_read_path`), fetches a page at a time into on-chip
+    memory through ``ops/pallas/paged_read.py`` and copies nothing (PERF.md,
+    PR 52).  The XLA pool ops thus slice all
     trailing axes, so they run in whatever layout the pool is stored in
     (``pool_order``, :func:`_pool_views`) and the pool stays in place.  What
     is known of a row-granular write: an XLA scatter of rows makes the TPU

@@ -375,7 +375,8 @@ def test_state_decode_tick_holds_one_kernel_over_the_leaf_on_v5e(
     compiled by the installed libtpu with the cache donated and the step
     chosen as on a TPU (the test answers for the backend; the kernel is
     compiled, not interpreted).  Mosaic accepts the one-pass kernel; the
-    layer scan's body holds ONE ``tpu_custom_call``; the ``ssm_state`` leaf
+    layer scan's body holds ONE ``tpu_custom_call`` over the state (and one
+    that reads K/V by pages); the ``ssm_state`` leaf
     goes into it and comes out of it and NOTHING else of the program reads
     or writes a tensor of the state rows' size: no update fusion, no
     ``multiply_reduce`` over ``[B,32,128,256]``, no copy of the leaf (an
@@ -416,8 +417,13 @@ def test_state_decode_tick_holds_one_kernel_over_the_leaf_on_v5e(
     # 2.3 MB with _ssm_step at this size; the kernel adds no state-sized one
     assert compiled.memory_analysis().temp_size_in_bytes < 8e6
     text = compiled.as_text()
-    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"", text)
-    assert len(calls) == 1
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    # the state's step, and since PR 52 the read of the 4 x 128 K/V leaves
+    # by pages (ops/pallas/paged_read.py), once each in the scan's body
+    assert len(calls) == 2
+    assert sum("/ssm_step/pallas_call" in ln for ln in calls) == 1
+    assert sum("/paged_read/pallas_call" in ln for ln in calls) == 1
     rows = slots * 32 * 128 * 256
     bodies = set(re.findall(r" fusion\([^\n]*calls=%([\w.\-]+)", text))
     passes = {"parameter", "get-tuple-element", "bitcast", "while", "tuple",
@@ -454,8 +460,8 @@ def test_looped_decode_tick_writes_its_rows_where_they_lie_on_v5e(
     donated and the K/V write chosen as on a TPU (the test answers for the
     backend; the kernel is compiled, not interpreted).  Mosaic accepts the
     row write; the layer scan's body holds its TWO ``tpu_custom_call``s (K,
-    V); each leaf goes into its call and comes out of it through both scans
-    and NOTHING else of the program writes a tensor of a leaf's size: no page
+    V) and the one that reads both by pages; each leaf goes into its call and
+    comes out of it through both scans and NOTHING else of the program writes a tensor of a leaf's size: no page
     scatter, no copy into another order in front of the call or behind it
     (an alias the compiler could not honour would copy 4 GB a (pass, layer) in
     the benchmark's cell); temporaries are a third of one leaf."""
@@ -494,8 +500,14 @@ def test_looped_decode_tick_writes_its_rows_where_they_lie_on_v5e(
     # (PERF.md S19d), a third of one leaf
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
     text = compiled.as_text()
-    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
-                          text)) == 2
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln]
+    # K's and V's row, and since PR 52 their read by pages
+    # (ops/pallas/paged_read.py), once each in the scans' body
+    assert len(calls) == 3
+    assert sum("/kv_row_write/pallas_call" in ln for ln in calls) == 2
+    assert sum("/paged_read/pallas_call" in ln for ln in calls) == 1
+    assert T.kv_read_paths(cfg, cache, None) == {"k": "pages", "v": "pages"}
     leaf = int(np.prod(cache["k"].shape))
     passes = {"parameter", "get-tuple-element", "bitcast", "while", "tuple",
               "custom-call"}
@@ -1165,6 +1177,13 @@ def test_delta_or_attention_layers_fit_the_chip_at_the_cells_size(
         assert sum("delta_step" in ln for ln in kernels) == 12
         # 30 heads are no row the row-write kernel's tile plan takes
         assert not any("kv_row_write" in ln for ln in kernels)
+        # the read's kernel takes a head-major block of any number of heads
+        # (PR 52): one call an attention layer, and no gathered copy of a
+        # step's 64 pages is left in the program
+        assert T.kv_read_paths(cfg, cache, None) == {"k": "pages",
+                                                     "v": "pages"}
+        assert sum("/paged_read/pallas_call" in ln for ln in kernels) == 4
+        assert "bf16[64,30,128,128]" not in compiled.as_text()
     else:
         assert mem.temp_size_in_bytes < 0.8e9
         assert not kernels
