@@ -66,12 +66,12 @@ class CacheLayout:
         self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
                            if self.kind == "window" else 0)
         self.window_pages = self.ring_pages and 1 + b_slots * self.ring_pages
-        # state-space or delta layers: two leaves a row a slot beside the
+        # state-space, delta or conv layers: leaves a row a slot beside the
         # pages (``ssm_state`` / ``ssm_conv``, ``delta_state`` /
-        # ``delta_conv``).  The paged leaves cover the layers with attention,
-        # the state leaves those with a mixer: every layer both (a parallel
-        # block), or each layer one of the two (a layer_pattern's "ssm" or
-        # "linear" kind and its "full" kind)
+        # ``delta_conv``, ``conv_tail`` alone).  The paged leaves cover the
+        # layers with attention, the state leaves those with a mixer: every
+        # layer both (a parallel block), or each layer one of the two (a
+        # layer_pattern's "ssm", "linear" or "conv" kind and its "full" kind)
         self.stateful = self.kind == "state"
         self.kv_layers, self.state_layers = cache_layers(cfg)
         # a pool of pages each: ``(pages, page 0 its trash page; those a
@@ -188,20 +188,23 @@ class CacheLayout:
         latent layers run as far as its tokens reach, beside the bucket's,
         and what a block reads of itself a kind of layer.  ``passes`` and
         ``kv_bytes`` as a tick's, over the rows the slot holds after it.  A
-        state a slot: the scan's chunks (of the kind's own length:
-        ``ssm_chunk``, or ``linear_chunk`` for delta layers) that hold a real
-        token beside the bucket's, and whether the call resets its slot's
-        state."""
+        state a slot: whether the call resets its slot's state and, where
+        the kind's prompt runs a scan (not a convolution's tail alone), the
+        scan's chunks (of the kind's own length: ``ssm_chunk``, or
+        ``linear_chunk`` for delta layers) that hold a real token beside the
+        bucket's."""
         attrs: Dict[str, Any] = {"gathered_rows": (
             0 if self.block_attends_itself else paged_read_rows(
                 [shared + tokens], self.page_size, self.pages_per_slot, 1)),
             "passes": self.passes,
             "kv_bytes": (shared + tokens) * self.kv_token_bytes}
         if self.stateful:
-            attrs.update(
-                scan_chunks=ssm_scan_chunks(self.cfg, bucket, tokens),
-                scan_chunks_bucket=ssm_scan_chunks(self.cfg, bucket),
-                state_reset=int(shared == 0))
+            chunks = ssm_scan_chunks(self.cfg, bucket, tokens)
+            if chunks is not None:      # a kind whose prompt runs a scan
+                attrs.update(
+                    scan_chunks=chunks,
+                    scan_chunks_bucket=ssm_scan_chunks(self.cfg, bucket))
+            attrs.update(state_reset=int(shared == 0))
         if self.block_attends_itself:
             attrs.update(walk_steps=causal_walk_steps(bucket, tokens),
                          walk_steps_bucket=causal_walk_steps(bucket))

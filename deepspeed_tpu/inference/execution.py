@@ -48,8 +48,9 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import (DELTA_STATE_PASSES, PAGED_POOL_KEYS,
-                                  SSM_STATE_PASSES, STATE_POOL_KEYS,
+from ..models.transformer import (CONV_STATE_PASSES, DELTA_STATE_PASSES,
+                                  PAGED_POOL_KEYS, SSM_STATE_PASSES,
+                                  STATE_POOL_KEYS, conv_step_path,
                                   cow_copy_pool, delta_step_path,
                                   expert_counts_shape, is_hybrid,
                                   paged_pool_cache, paged_pool_order,
@@ -290,8 +291,11 @@ class MeshExecutor:
         # likewise a model with delta layers: "one_pass" (one read and one
         # write) or "plain" (three passes)
         self.delta_step = delta_step_path(cfg)
+        # and one with conv layers: "plain" (a tail read and written once)
+        self.conv_step = conv_step_path(cfg)
         self.state_passes = (SSM_STATE_PASSES.get(self.ssm_step)
-                             or DELTA_STATE_PASSES.get(self.delta_step, 0))
+                             or DELTA_STATE_PASSES.get(self.delta_step)
+                             or CONV_STATE_PASSES.get(self.conv_step, 0))
         pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype, **layout.pool_kw}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
@@ -907,7 +911,9 @@ class MeshExecutor:
         (``"one_pass"`` / ``"xla"``: ``models.transformer.ssm_step_path``),
         ``None`` for any other model; ``delta_step`` the same for delta
         layers (``"one_pass"`` / ``"plain"``:
-        ``models.transformer.delta_step_path``).  ``kv_write``: how the decode tick
+        ``models.transformer.delta_step_path``) and ``conv_step`` for conv
+        layers (``"plain"``: ``models.transformer.conv_step_path``).
+        ``kv_write``: how the decode tick
         lays a token's rows into each paged leaf (``"row"`` / ``"page"``:
         ``models.transformer.kv_write_path``), ``kv_read`` how it reads each
         (``"pages"`` / ``"gather"``: ``models.transformer.kv_read_path``).
@@ -927,6 +933,7 @@ class MeshExecutor:
                     if int(mesh.shape[a]) > 1},
                 **self.weight_placement, "ssm_step": self.ssm_step,
                 "delta_step": self.delta_step,
+                "conv_step": self.conv_step,
                 "kv_write": dict(self.kv_write),
                 "kv_read": dict(self.kv_read),
                 "expert_matmul": self.expert_matmul(),

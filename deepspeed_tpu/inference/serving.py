@@ -782,7 +782,8 @@ class ServingEngine:
             + f" cache={info['cache_kind']} kv_layers={info['kv_layers']}"
             + "".join(f" state_layers={info['state_layers']} "
                       f"{step}={info[step]}"
-                      for step in ("ssm_step", "delta_step") if info[step])
+                      for step in ("ssm_step", "delta_step", "conv_step")
+                      if info[step])
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items())
             + " kv_read=" + ",".join(

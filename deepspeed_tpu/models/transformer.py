@@ -82,10 +82,13 @@ class TransformerConfig:
     # softmax scale override: GPT-Neo applies NO 1/sqrt(hd) scaling
     # (modeling_gpt_neo scales by 1.0); None = the standard 1/sqrt(hd)
     attn_softmax_scale: Optional[float] = None
-    # QK-norm (OLMoE): the norm of ``norm`` with a learned scale over the
-    # WHOLE q and k projections, before the head split and the rotary
-    # embedding; two more leaves a layer (q_norm_scale, k_norm_scale)
-    qk_norm: bool = False
+    # QK-norm, two more leaves a layer (q_norm_scale, k_norm_scale).  True
+    # (OLMoE): the norm of ``norm`` with a learned scale over the WHOLE q and
+    # k projections, before the head split and the rotary embedding.
+    # ``"head"`` (LFM2's ``q_layernorm`` / ``k_layernorm``): the norm over
+    # each head's own dims after the split, one scale of ``head_dim`` that
+    # the heads share, before the rotary embedding
+    qk_norm: Any = False                      # False | True | "head"
     # Two kinds of attention layer in one model (MiMo-V2): a tuple of
     # "full"/"window" per layer (the first ``num_layers`` entries are used:
     # a cut in depth keeps the published pattern).  A window layer sees the last
@@ -164,6 +167,17 @@ class TransformerConfig:
     linear_conv: int = 4
     linear_chunk: int = 64
     linear_neg_eigval: bool = True
+    # Gated short-convolution layers (LFM2, ``lfm2`` / ``lfm2_moe``): a fifth
+    # kind of ``layer_pattern`` entry, "conv", whose operator stands in
+    # attention's place: ``[B | C | u] = W_in n``, ``z = B . u``, a depthwise
+    # causal convolution of ``conv_taps`` taps over z (``conv_bias``: with a
+    # bias; NO activation), ``W_out (C . conv(z))`` (:func:`_conv_mixer`).  A
+    # sequence's whole state is the convolution's tail, the last ``conv_taps
+    # - 1`` rows of z: the paged cache holds ONE leaf ``conv_tail`` for the
+    # "conv" layers, a row a slot, beside K and V pages for the "full" ones.
+    # 0 taps: no such layer.
+    conv_taps: int = 0
+    conv_bias: bool = False
     # the family's fixed multipliers (muP), every one a constant of the
     # published config: on the embedding and the logits, on attention's
     # input, keys and output, on the mixer's input, output and the five
@@ -220,6 +234,9 @@ class TransformerConfig:
     # keeps the k largest softmax probabilities as they are (OLMoE
     # ``norm_topk_prob=false``).  Top-1 never renormalises.
     moe_norm_topk_prob: bool = True
+    # added to the chosen gates' sum before it divides them (LFM2: 1e-6); 0:
+    # the sum itself
+    moe_norm_topk_eps: float = 0.0
     # an expert's width where it is not the dense MLP's (None =>
     # intermediate_size)
     moe_intermediate_size: Optional[int] = None
@@ -315,11 +332,14 @@ class TransformerConfig:
         if self.attn_bias:
             attn += nh * hd + nkv * hd + nkv * vd + d
         if self.qk_norm:
-            attn += nh * hd + nkv * hd
+            attn += sum(qk_norm_widths(self))
         if self.window_attn_sink:
             attn += nh
         if not sublayers(self)[0]:      # a mixer in attention's place
             attn = 0
+        if is_conv(self):
+            # the operator: in- and out-projection, the taps (and bias)
+            attn += 4 * d * d + (self.conv_taps + self.conv_bias) * d
         if self.ssm_heads:
             # the mixer: in- and out-projection, the convolution with its
             # bias, A, D, dt's bias, the gated norm
@@ -519,6 +539,24 @@ CONFIGS: Dict[str, TransformerConfig] = {
                             for i in range(32)),
         linear_heads=30, linear_key_dim=96, linear_value_dim=192,
         linear_conv=4, linear_chunk=64, linear_neg_eigval=True, remat=False),
+    # LiquidAI/LFM2-8B-A1B config.json (``lfm2_moe``): 24 layers, 18 gated
+    # short convolutions (3 taps, no bias) and 6 of grouped-query attention
+    # (32 heads over 8 KV heads of 64, QK-norm by head, full rotary theta
+    # 1e6) at 2, 6, 10, 14, 18 and 21; layers 0 and 1 a dense SwiGLU of
+    # 7,168, the rest 32 experts of 1,792, sigmoid scores, 4 a token chosen
+    # on score + bias, gates renormalised over the chosen (sum + 1e-6);
+    # RMSNorm eps 1e-5, the head the embedding's transpose over 65,536 ids
+    "lfm2-8b-a1b": TransformerConfig(
+        vocab_size=65536, hidden_size=2048, intermediate_size=7168,
+        moe_intermediate_size=1792, num_layers=24, num_heads=32,
+        num_kv_heads=8, head_dim=64, max_seq_len=128000, norm_eps=1e-5,
+        rope_theta=1e6, qk_norm="head",
+        layer_pattern=tuple("full" if i in (2, 6, 10, 14, 18, 21) else "conv"
+                            for i in range(24)),
+        conv_taps=3, conv_bias=False, dense_layers=2,
+        num_experts=32, moe_top_k=4, moe_score_func="sigmoid",
+        moe_select_bias=True, moe_norm_topk_prob=True, moe_norm_topk_eps=1e-6,
+        moe_drop_tokens=False, tie_embeddings=True, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -620,21 +658,38 @@ def is_delta(cfg: TransformerConfig) -> bool:
     return bool(cfg.linear_heads)
 
 
+def is_conv(cfg: TransformerConfig) -> bool:
+    """Gated short-convolution layers (``conv_taps``): the convolution's
+    tail a sequence in every "conv" layer of the pattern, one cache leaf
+    with no page axis."""
+    return bool(cfg.conv_taps)
+
+
 def has_state(cfg: TransformerConfig) -> bool:
-    """A state a slot of either kind (:func:`cache_kind` ``"state"``)."""
-    return is_ssm(cfg) or is_delta(cfg)
+    """A state a slot of any kind (:func:`cache_kind` ``"state"``)."""
+    return is_ssm(cfg) or is_delta(cfg) or is_conv(cfg)
+
+
+def qk_norm_widths(cfg: TransformerConfig) -> Tuple[int, int]:
+    """The widths of ``q_norm_scale`` and ``k_norm_scale``: a head's dims
+    under ``qk_norm="head"``, the whole projections' otherwise."""
+    hd = cfg.dims_per_head
+    if cfg.qk_norm == "head":
+        return hd, hd
+    return cfg.num_heads * hd, cfg.kv_heads * hd
 
 
 def sublayers(cfg: TransformerConfig) -> Tuple[bool, bool]:
     """``(attention, mixer)``: which of the two a layer of the uniform stack
     ``cfg`` has before its MLP.  The one rule :func:`_block`, the parameters
     and the cache's leaves follow: attention unless the stack is a
-    pattern's "ssm" group (``ssm_alone``) or its "linear" group (the only
-    stack with ``linear_heads``), a mixer where the stack has its heads
-    (both: Falcon-H1's parallel block; under a ``layer_pattern``
+    pattern's "ssm" group (``ssm_alone``), its "linear" group (the only
+    stack with ``linear_heads``) or its "conv" group (the only one with
+    ``conv_taps``), a mixer where the stack has its heads or taps (both:
+    Falcon-H1's parallel block; under a ``layer_pattern``
     :func:`layer_groups` gives each kind's group one)."""
-    return (not (cfg.ssm_alone or is_delta(cfg)),
-            bool(cfg.ssm_heads) or is_delta(cfg))
+    alone = cfg.ssm_alone or is_delta(cfg) or is_conv(cfg)
+    return not alone, bool(cfg.ssm_heads) or is_delta(cfg) or is_conv(cfg)
 
 
 def cache_layers(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -729,9 +784,10 @@ def layer_plan(cfg: TransformerConfig):
     plan, seen = [], {}
     # a model cut in depth runs the first layers of the published pattern
     for i, kind in enumerate(pattern[:cfg.num_layers]):
-        if kind not in ("full", "window", "ssm", "linear"):
+        if kind not in ("full", "window", "ssm", "linear", "conv"):
             raise ValueError(
-                f"layer_pattern[{i}] = {kind!r}: full | window | ssm | linear")
+                f"layer_pattern[{i}] = {kind!r}: "
+                "full | window | ssm | linear | conv")
         if kind == "ssm" and not is_ssm(cfg):
             raise ValueError(
                 f"layer_pattern[{i}] = 'ssm' in a model with no state-space "
@@ -740,6 +796,10 @@ def layer_plan(cfg: TransformerConfig):
             raise ValueError(
                 f"layer_pattern[{i}] = 'linear' in a model with no delta "
                 "mixer (linear_heads = 0)")
+        if kind == "conv" and not is_conv(cfg):
+            raise ValueError(
+                f"layer_pattern[{i}] = 'conv' in a model with no gated "
+                "convolution (conv_taps = 0)")
         dense = i < cfg.dense_layers or not has_moe(cfg)
         group = f"{kind}_{'dense' if dense else 'moe'}"
         plan.append((group, seen.get(group, 0), kind, dense))
@@ -754,7 +814,8 @@ def layer_groups(cfg: TransformerConfig):
     take any model's, with the kind's KV heads, theta, sink and MLP, and
     under a pattern the kind's one mixer (:func:`sublayers`): the state-space
     one alone in an "ssm" group, the delta one alone in a "linear" group,
-    attention alone in any other."""
+    the gated convolution alone in a "conv" group, attention alone in any
+    other."""
     groups: Dict[str, Any] = {}
     for group, index, kind, dense in layer_plan(cfg):
         window = kind == "window"
@@ -764,6 +825,7 @@ def layer_groups(cfg: TransformerConfig):
             ssm_heads=(cfg.ssm_heads if kind == "ssm"
                        or cfg.layer_pattern is None else 0),
             linear_heads=cfg.linear_heads if kind == "linear" else 0,
+            conv_taps=cfg.conv_taps if kind == "conv" else 0,
             num_kv_heads=(cfg.window_kv_heads if window
                           and cfg.window_kv_heads else cfg.num_kv_heads),
             rope_theta=(cfg.window_rope_theta if window
@@ -898,6 +960,8 @@ def _check_ssm(cfg: TransformerConfig) -> None:
                       "attn_bias under a layer_pattern"),
                      ("linear" in run,
                       "linear layers in one layer_pattern with them"),
+                     ("conv" in run,
+                      "conv layers in one layer_pattern with them"),
                      (bool(run) and not ("ssm" in run and "full" in run),
                       "a layer_pattern whose layers run are not of both "
                       "kinds, ssm and full"),
@@ -931,6 +995,8 @@ def _check_delta(cfg: TransformerConfig) -> None:
                       "leading dense layers (dense_layers)"),
                      ("window" in run or "ssm" in run,
                       "window or ssm layers in one layer_pattern with them"),
+                     ("conv" in run,
+                      "conv layers in one layer_pattern with them"),
                      (cfg.attn_bias, "attn_bias"),
                      (bool(run) and not ("linear" in run and "full" in run),
                       "a layer_pattern whose layers run are not of both "
@@ -944,7 +1010,46 @@ def _check_delta(cfg: TransformerConfig) -> None:
                 f"delta layers (linear_heads) do not take {what}")
 
 
+def _check_conv(cfg: TransformerConfig) -> None:
+    """What a model with gated short-convolution layers (``conv_taps``;
+    "conv" entries of its ``layer_pattern``) is built from, and what it
+    leaves out.  Takes a model's config or a group's (:func:`layer_groups`).
+    Leading dense layers and expert layers it takes: the walk by kind runs
+    each layer its own group's MLP."""
+    if cfg.conv_taps < 2:
+        raise ValueError("conv layers take conv_taps > 1")
+    if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
+        raise NotImplementedError(
+            "conv layers (conv_taps) take RMSNorm and a gated MLP")
+    run = (cfg.layer_pattern or ())[:cfg.num_layers]
+    for on, what in ((is_ssm(cfg), "state-space layers (ssm_heads)"),
+                     (is_delta(cfg), "delta layers (linear_heads)"),
+                     (cfg.parallel_residual, "parallel_residual"),
+                     (cfg.post_layernorm, "post_layernorm"),
+                     (cfg.sandwich_norm or cfg.norm_after,
+                      "sandwich_norm or norm_after"),
+                     (isinstance(cfg.num_experts, (tuple, list)),
+                      "per-layer expert counts (a num_experts tuple)"),
+                     (is_latent(cfg), "latent attention"),
+                     ("window" in run or "ssm" in run or "linear" in run,
+                      "window, ssm or linear layers in one layer_pattern "
+                      "with them"),
+                     (cfg.attn_bias, "attn_bias"),
+                     (bool(run) and not ("conv" in run and "full" in run),
+                      "a layer_pattern whose layers run are not of both "
+                      "kinds, conv and full"),
+                     (cfg.attention_layers is not None, "attention_layers"),
+                     (cfg.loop_passes > 1, "loop_passes"),
+                     (cfg.pipeline_stages > 1, "pipeline_stages"),
+                     (cfg.random_ltd, "random_ltd")):
+        if on:
+            raise NotImplementedError(
+                f"conv layers (conv_taps) do not take {what}")
+
+
 def _check_qk_norm(cfg: TransformerConfig) -> None:
+    if cfg.qk_norm not in (True, "head"):
+        raise ValueError(f"qk_norm={cfg.qk_norm!r}: False | True | 'head'")
     if cfg.norm != "rmsnorm":
         raise NotImplementedError(
             "qk_norm is an RMSNorm (OLMoE): it carries a scale and no offset")
@@ -1009,6 +1114,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             _check_ssm(cfg)
         if is_delta(cfg):
             _check_delta(cfg)
+        if is_conv(cfg):
+            _check_conv(cfg)
         groups = layer_groups(cfg)
         first = next(iter(groups.values()))[0]
         params = init_params(dataclasses.replace(first, num_layers=1), rng)
@@ -1046,8 +1153,9 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     has_attn = sublayers(cfg)[0]
     if cfg.qk_norm and has_attn:
         _check_qk_norm(cfg)
-        layers["q_norm_scale"] = jnp.ones((L, nh * hd))
-        layers["k_norm_scale"] = jnp.ones((L, nkv * hd))
+        wq, wk = qk_norm_widths(cfg)
+        layers["q_norm_scale"] = jnp.ones((L, wq))
+        layers["k_norm_scale"] = jnp.ones((L, wk))
     if cfg.window_attn_sink:
         # one logit a query head, of the order of a score between two
         # tokens at these weights (std^2 * d), so that it takes a real
@@ -1056,6 +1164,17 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     if not has_attn:
         for name in _ATTN_LEAVES:
             del layers[name]
+    if is_conv(cfg):
+        _check_conv(cfg)
+        # the gated convolution: the taps U(+-1/2), as the other mixers'
+        sk = jax.random.split(jax.random.fold_in(rng, 21), 4)
+        layers.update(
+            conv_in=dense(sk[0], (L, d, 3 * d)),
+            conv_w=jax.random.uniform(sk[1], (L, cfg.conv_taps, d),
+                                      minval=-0.5, maxval=0.5),
+            conv_out=dense(sk[2], (L, d, d), std / math.sqrt(2 * L)))
+        if cfg.conv_bias:
+            layers["conv_b"] = dense(sk[3], (L, d))
     if is_delta(cfg):
         _check_delta(cfg)
         # the delta mixer.  ``A_log`` and dt's bias are Mamba-2's draws (the
@@ -1265,8 +1384,9 @@ def _init_params_het(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         }
         if cfg.qk_norm:
             _check_qk_norm(cfg)
-            lp["q_norm_scale"] = jnp.ones((nh * hd,))
-            lp["k_norm_scale"] = jnp.ones((nkv * hd,))
+            wq, wk = qk_norm_widths(cfg)
+            lp["q_norm_scale"] = jnp.ones((wq,))
+            lp["k_norm_scale"] = jnp.ones((wk,))
         if not cfg.shared_layernorm:
             lp["mlp_norm_scale"] = jnp.ones((d,))
         if cfg.norm == "layernorm":
@@ -1340,14 +1460,22 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         layers.update(wkv_a=P(None, None, None), kv_a_norm_scale=rep,
                       wkv_b=col)
     has_attn = sublayers(cfg)[0]
-    if cfg.qk_norm and has_attn:    # over the column-parallel projection
-        layers.update(q_norm_scale=P(None, "model"),
-                      k_norm_scale=P(None, "model"))
+    if cfg.qk_norm and has_attn:
+        # over the column-parallel projection; by head: every chip's heads
+        # share the one scale
+        by = None if cfg.qk_norm == "head" else "model"
+        layers.update(q_norm_scale=P(None, by), k_norm_scale=P(None, by))
     if cfg.window_attn_sink:
         layers["attn_sink"] = P(None, "model")
     if not has_attn:
         for name in _ATTN_LEAVES:
             del layers[name]
+    if is_conv(cfg):
+        # whole on every chip, as the other mixers: a slot's tail is one row
+        layers.update(conv_in=P(None, None, None), conv_w=P(None, None, None),
+                      conv_out=P(None, None, None))
+        if cfg.conv_bias:
+            layers["conv_b"] = rep
     if is_delta(cfg):
         # whole on every chip, as the state-space mixer below: a slot's
         # state is one tensor (heads over chips: ROADMAP R5)
@@ -1441,7 +1569,8 @@ def _param_specs_het(cfg: TransformerConfig) -> Dict[str, Any]:
         lp: Dict[str, Any] = {"attn_norm_scale": rep,
                               "wq": col, "wk": col, "wv": col, "wo": row}
         if cfg.qk_norm:
-            lp.update(q_norm_scale=P("model"), k_norm_scale=P("model"))
+            by = None if cfg.qk_norm == "head" else "model"
+            lp.update(q_norm_scale=P(by), k_norm_scale=P(by))
         if not cfg.shared_layernorm:
             lp["mlp_norm_scale"] = rep
         if cfg.norm == "layernorm":
@@ -1948,6 +2077,7 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
                             noisy_gate_policy=cfg.noisy_gate_policy,
                             drop_tokens=cfg.moe_drop_tokens,
                             norm_topk_prob=cfg.moe_norm_topk_prob,
+                            norm_topk_eps=cfg.moe_norm_topk_eps,
                             score_func=cfg.moe_score_func,
                             held=((cfg.moe_expert_first, cfg.moe_experts_held)
                                   if cfg.moe_experts_held else None),
@@ -2009,11 +2139,16 @@ def _qkv(cfg: TransformerConfig, lp: Dict[str, Any], h, positions, proj=None):
             q, k, v = proj(q, "wq", h), proj(k, "wk", h), proj(v, "wv", h)
         if cfg.attn_bias:
             q, k, v = q + lp["bq"], k + lp["bk"], v + lp["bv"]
-        if cfg.qk_norm:   # over the whole projection, before the head split
+        by_head = cfg.qk_norm == "head"
+        if cfg.qk_norm and not by_head:
+            # over the whole projection, before the head split
             q = _norm(cfg, q, lp["q_norm_scale"])
             k = _norm(cfg, k, lp["k_norm_scale"])
         q = q.reshape(B, S, nh, hd)
         k = k.reshape(B, S, nkv, hd)
+        if by_head:     # over each head's own dims, one scale for all heads
+            q = _norm(cfg, q, lp["q_norm_scale"])
+            k = _norm(cfg, k, lp["k_norm_scale"])
         v = v.reshape(B, S, nkv, cfg.v_dims_per_head)
         if cfg.attn_value_scale != 1.0:
             v = v * jnp.asarray(cfg.attn_value_scale, v.dtype)
@@ -2114,10 +2249,18 @@ def _ssm_conv(cfg: TransformerConfig, lp: Dict[str, Any], xbc, tail, n_real):
 
 
 def _causal_conv(w, bias, x, tail, n_real):
+    """:func:`_causal_taps`, then SiLU (the state-space and the delta
+    mixers' convolution)."""
+    y, tail = _causal_taps(w, bias, x, tail, n_real)
+    return jax.nn.silu(y), tail
+
+
+def _causal_taps(w, bias, x, tail, n_real):
     """A depthwise causal convolution of ``w [K, C]`` (and ``bias [C]`` or
-    None) over ``x [B,S,C]`` behind ``tail [B,K-1,C]``, then SiLU, in
-    float32: ``(out [B,S,C] float32, the last K - 1 inputs before position
-    n_real [B])``."""
+    None) over ``x [B,S,C]`` behind ``tail [B,K-1,C]``, in float32: ``(out
+    [B,S,C] float32, the last K - 1 inputs before position n_real [B])``.
+    One token a row, it is the K-term sum over the tail and the new row,
+    and the tail shifted by one (or kept, ``n_real`` 0)."""
     K, S = w.shape[0], x.shape[1]
     ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     w = w.astype(jnp.float32)
@@ -2126,15 +2269,18 @@ def _causal_conv(w, bias, x, tail, n_real):
         y = y + bias.astype(jnp.float32)
     tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
         e, n, K - 1, axis=0))(ext, n_real.astype(jnp.int32))
-    return jax.nn.silu(y), tail
+    return y, tail
 
 
 def ssm_scan_chunks(cfg: TransformerConfig, block: int,
-                    tokens: Optional[int] = None) -> int:
+                    tokens: Optional[int] = None) -> Optional[int]:
     """Chunks of ``ssm_chunk`` positions (``linear_chunk`` for a model with
     delta layers) the scan of a block of ``block`` tokens runs, or those of
     them that hold one of its ``tokens`` real ones (the ``scan_chunks`` span
-    attrs of a prompt)."""
+    attrs of a prompt).  None for a model whose prompts run no scan (no
+    state a slot, or a convolution's tail alone)."""
+    if not (cfg.linear_heads or cfg.ssm_heads):
+        return None
     return -(-(block if tokens is None else min(tokens, block))
              // (cfg.linear_chunk if cfg.linear_heads else cfg.ssm_chunk))
 
@@ -2628,9 +2774,59 @@ def _delta_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h,
     return out, (state, tail)
 
 
+# ---------------------------------------------------------------------------
+# Gated short-convolution layers ("conv" entries of a ``layer_pattern``)
+# ---------------------------------------------------------------------------
+
+def _conv_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h,
+                seq_mask=None, tail=None):
+    """LFM2's operator on the layer's input ``h [B,S,d]``: ``[B | C | u] =
+    h W_in``, ``z = B . u``, the depthwise causal convolution of z behind the
+    sequence's ``tail [B, taps - 1, d]`` (None: it starts here, zeros) with
+    NO activation, ``(C . conv) W_out``: ``(out [B,S,d], the new tail)``.
+    The tail is the last ``taps - 1`` rows of z at REAL positions
+    (``seq_mask [B,S]``, real tokens lead; :func:`_causal_taps`), so a
+    padded block leaves what the unpadded one does and a row with no real
+    token keeps the tail it had.  The projections' outputs, z, the tail and
+    the gated sum are of ``h``'s dtype; each product of two of them and the
+    three-term sum are taken in float32 and rounded once."""
+    B, S, d = h.shape
+    f32 = jnp.float32
+    if seq_mask is None:
+        seq_mask = jnp.ones((B, S), bool)
+    if tail is None:
+        tail = jnp.zeros((B, cfg.conv_taps - 1, d), h.dtype)
+    with jax.named_scope("conv_in"):
+        p = h @ lp["conv_in"]
+        z = (p[..., :d].astype(f32) * p[..., 2 * d:].astype(f32)
+             ).astype(h.dtype)
+    with jax.named_scope("conv_taps"):
+        c, tail = _causal_taps(lp["conv_w"], lp.get("conv_b"), z, tail,
+                               seq_mask.sum(1))
+    with jax.named_scope("conv_out"):
+        gated = (p[..., d:2 * d].astype(f32) * c).astype(h.dtype)
+        out = gated @ lp["conv_out"]
+    return out, tail
+
+
+def conv_step_path(cfg: TransformerConfig) -> Optional[str]:
+    """How a decode tick advances the "conv" layers' tails: ``"plain"`` (the
+    three-term sum and the shift, fused by the compiler: a slot's tail is
+    two rows, read once and written once), ``None`` for a model with no such
+    layer.  The serving executor reports it (``mesh_info()["conv_step"]``)
+    beside ``ssm_step`` / ``delta_step``."""
+    return "plain" if is_conv(cfg) else None
+
+
+# passes over a live slot's tail a layer a tick
+CONV_STATE_PASSES = {"plain": 1}
+
+
 def _mixer_of(cfg: TransformerConfig):
     """The mixer a layer of the uniform stack ``cfg`` runs over a block that
     starts its sequences (:func:`_block`'s ``ssm``), or None."""
+    if is_conv(cfg):
+        return functools.partial(_conv_mixer, cfg)
     if is_delta(cfg):
         return functools.partial(_delta_mixer, cfg)
     return functools.partial(_ssm_mixer, cfg) if is_ssm(cfg) else None
@@ -3240,10 +3436,14 @@ KV_QUANT_DTYPES = ("int8",)
 # which updates this layer's rows where they lie (:func:`ssm_step_path`), and
 # any other single token cuts its rows out for :func:`_ssm_step`.
 # A model with delta layers keeps ``delta_state`` and ``delta_conv`` the same
-# way for its "linear" layers (:func:`_delta_paged`).
+# way for its "linear" layers (:func:`_delta_paged`); one with gated
+# convolutions ONE leaf, ``conv_tail``, for its "conv" layers
+# (:func:`_conv_paged`): the tail is all the state there is.
 SSM_POOL_KEYS = ("ssm_state", "ssm_conv")
 DELTA_POOL_KEYS = ("delta_state", "delta_conv")
-STATE_POOL_KEYS = DELTA_POOL_KEYS + SSM_POOL_KEYS   # a row a slot, no pages
+CONV_POOL_KEYS = ("conv_tail",)
+# a row a slot, no pages
+STATE_POOL_KEYS = CONV_POOL_KEYS + DELTA_POOL_KEYS + SSM_POOL_KEYS
 PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window",
                    "latent") + STATE_POOL_KEYS
 
@@ -3282,7 +3482,8 @@ def kv_leaf_head_major(cfg: TransformerConfig, width: int) -> bool:
 
 
 # what a kind of layer's K/V leaves add to ``k``/``v`` in the cache's keys
-# (an "ssm" or "linear" layer has none: its leaves are STATE_POOL_KEYS)
+# (an "ssm", "linear" or "conv" layer has none: its leaves are
+# STATE_POOL_KEYS)
 _KIND_SUFFIX = {"full": "", "window": "_window"}
 
 
@@ -3399,6 +3600,15 @@ def _delta_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
                  (cfg.linear_conv - 1) * delta_widths(cfg)[2]), dtype)}
 
 
+def _conv_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
+                 ) -> Dict[str, Any]:
+    """The one slot-indexed leaf of ``layers`` conv layers: a slot's ``taps -
+    1`` rows of z side by side in ONE row (2 x 2,048 for LFM2), as
+    ``delta_conv`` is kept and for its reason (:func:`_delta_leaves`)."""
+    return {"conv_tail": jnp.zeros(
+        (layers, slots, (cfg.conv_taps - 1) * cfg.hidden_size), dtype)}
+
+
 def _state_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
                   ) -> Dict[str, Any]:
     """The two slot-indexed leaves of ``layers`` layers with a mixer: the
@@ -3482,6 +3692,8 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
             cache.update(_state_leaves(*kinds["ssm"], slots, dtype))
         if "linear" in kinds:
             cache.update(_delta_leaves(*kinds["linear"], slots, dtype))
+        if "conv" in kinds:
+            cache.update(_conv_leaves(*kinds["conv"], slots, dtype))
         return cache
     if is_latent(cfg):
         return {"latent": jnp.zeros(
@@ -3516,6 +3728,7 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
         return {"latent": P(None, None, None, None)}
     if has_state(cfg):  # whole on one chip: sharded serving refuses it
         return {k: P() for k in ("k", "v") + (
+            CONV_POOL_KEYS if is_conv(cfg) else
             DELTA_POOL_KEYS if is_delta(cfg) else SSM_POOL_KEYS)}
     if is_hybrid(cfg):
         return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
@@ -4472,6 +4685,12 @@ def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
     which model it is, and why a page of it cannot be shared, parked,
     rescaled or split by head.  Written once, for :func:`_hybrid_refuse`
     and the serving engine's refusals (``inference/cache_layout.py``)."""
+    if is_conv(cfg):
+        return "state", (
+            "gated short-convolution layers (a convolution's tail a slot): "
+            "a slot's tail is a row that no page holds, so a page copied, "
+            "parked, rescaled or split by head leaves it behind, and there "
+            "is nothing to start a tail from or to go back to")
     if is_delta(cfg):
         return "state", (
             "gated-delta-rule layers (a matrix state a head a slot): a "
@@ -4565,9 +4784,10 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
              for kind in kind_cfg if kind in suffix}
     ps = next(iter(pools.values()))["k"].shape[1]
     slots = 0
-    # a kind with a state a slot: its two leaves stacked, and its mixer
+    # a kind with a state a slot: its leaves stacked, and its mixer
     stateful = {"ssm": (SSM_POOL_KEYS, _ssm_paged),
-                "linear": (DELTA_POOL_KEYS, _delta_paged)}
+                "linear": (DELTA_POOL_KEYS, _delta_paged),
+                "conv": (CONV_POOL_KEYS, _conv_paged)}
     for kind, (keys, _) in stateful.items():
         if kind in kind_cfg:
             slots = cache[keys[0]].shape[1]
@@ -4840,6 +5060,15 @@ def _slot_rows(row0, state_slot, B: int):
     return take, put
 
 
+def _slot_rows_in_place(row0, state_slot, B: int):
+    """:func:`_slot_rows`, one named row (a prompt's) as a slice and an
+    update where the row lies: a scatter into the leaf makes the compiler
+    keep a version of it a layer."""
+    if state_slot is not None and B == 1:
+        return _slot_rows(row0 + state_slot[0], None, 1)
+    return _slot_rows(row0, state_slot, B)
+
+
 def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
     """:func:`_block`'s ``ssm`` against the cache's two slot-indexed leaves,
     stacked ``[L * slots, ...]`` with this layer's rows from ``row0`` on:
@@ -4889,13 +5118,7 @@ def _delta_paged(cfg, pools, row0, state_slot, start, seq_mask):
     :func:`_delta_step` and packs them back."""
     B = seq_mask.shape[0]
     fresh = (start == 0) & seq_mask.any(axis=1)
-    if state_slot is not None and B == 1:
-        # one named row (a prompt's): a slice and an update where the row
-        # lies.  A scatter into the leaf makes the compiler keep a version
-        # of it a layer
-        take, put = _slot_rows(row0 + state_slot[0], None, 1)
-    else:
-        take, put = _slot_rows(row0, state_slot, B)
+    take, put = _slot_rows_in_place(row0, state_slot, B)
     one_pass = delta_step_path(cfg, seq_mask.shape[1], state_slot,
                                pools["delta_state"].dtype) == "one_pass"
 
@@ -4918,6 +5141,26 @@ def _delta_paged(cfg, pools, row0, state_slot, start, seq_mask):
             state = put(pools["delta_state"], delta_state_pack(cfg, state))
         return out, {"delta_state": state, "delta_conv": put(
             pools["delta_conv"], tail.reshape(B, -1))}
+    return mixer
+
+
+def _conv_paged(cfg, pools, row0, state_slot, start, seq_mask):
+    """:func:`_ssm_paged` for a conv layer: :func:`_block`'s ``ssm`` against
+    the cache's one ``conv_tail`` leaf, stacked ``[L * slots, (taps - 1) *
+    d]`` with this layer's rows from ``row0`` on.  The batch's rows are
+    taken (:func:`_slot_rows_in_place`), a row that starts its sequence
+    begins from zeros, :func:`_conv_mixer` advances them, and they are put
+    back where they were."""
+    B = seq_mask.shape[0]
+    fresh = (start == 0) & seq_mask.any(axis=1)
+    take, put = _slot_rows_in_place(row0, state_slot, B)
+
+    def mixer(lp, h):
+        tail = jnp.where(fresh[:, None, None], 0, take(
+            pools["conv_tail"]).reshape(B, cfg.conv_taps - 1, -1))
+        out, tail = _conv_mixer(cfg, lp, h, seq_mask, tail)
+        return out, {"conv_tail": put(pools["conv_tail"],
+                                      tail.reshape(B, -1))}
     return mixer
 
 

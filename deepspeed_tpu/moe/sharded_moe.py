@@ -39,6 +39,9 @@ class MoEConfig:
     # the k largest softmax probabilities as they are (OLMoE).  Top-1 never
     # renormalises.
     norm_topk_prob: bool = True
+    # added to the chosen gates' sum before it divides them (LFM2: 1e-6; the
+    # dropless path only); 0: the sum itself
+    norm_topk_eps: float = 0.0
     # the scores the router's logits become: "softmax" over all experts, or
     # "sigmoid" of each logit on its own (the dropless path only)
     score_func: str = "softmax"
@@ -269,7 +272,9 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
             jnp.mean(gates.reshape(B, S, E), axis=1)
             * jnp.mean(mask1.reshape(B, S, E), axis=1), axis=-1))
         if k > 1 and cfg.norm_topk_prob:
-            vals = vals / jnp.maximum(vals.sum(-1, keepdims=True), 1e-9)
+            total = vals.sum(-1, keepdims=True)
+            vals = vals / (total + cfg.norm_topk_eps if cfg.norm_topk_eps
+                           else jnp.maximum(total, 1e-9))
         if cfg.routed_scale != 1.0:
             vals = vals * cfg.routed_scale
 

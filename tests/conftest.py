@@ -30,7 +30,8 @@ import pytest  # noqa: E402
 # markers are declared once, in pyproject.toml [tool.pytest.ini_options]
 
 # the benchmark tests' own set-asides, which their conftest.py may not take
-pytest_plugins = ("tests.benchmark.pinned_sets", "tests.benchmark.pinned_tail")
+pytest_plugins = ("tests.benchmark.pinned_sets", "tests.benchmark.pinned_tail",
+                  "tests.benchmark.pinned_thirteenth")
 
 
 def pytest_collection_modifyitems(config, items):

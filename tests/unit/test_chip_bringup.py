@@ -1188,3 +1188,76 @@ def test_delta_or_attention_layers_fit_the_chip_at_the_cells_size(
         assert mem.temp_size_in_bytes < 0.8e9
         assert not kernels
         assert "remat_compressed" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_1024"])
+def test_conv_or_attention_layers_fit_the_chip_at_the_cells_size(
+        one_v5e_chip, monkeypatch, program):
+    """AOT: the two programs of ``lfm2-8b-a1b-d14.agentturn-backlog`` at the
+    cell's own sizes (fourteen layers at the published widths with all 32
+    experts of each of the twelve expert layers, 128 slots of 24 pages), the
+    weights held a leaf a layer and the cache donated, the K/V leaves in the
+    order the TPU stores 64-wide heads (page rows minor-most, as OPT's).  The
+    tick: 9.33 GB of weights and 2.43 GB of cache in place (the eleven
+    layers' tails are 11.5 MB of it, one row of 4,096 a slot), no kernel of
+    its own (the three-term sum and the shift fuse; 16 rows an expert stay on
+    ``lax.ragged_dot``).  The 1,024-token prefill: 4,096 sorted rows, 128 an
+    expert, the grouped-product kernel's side of the rule."""
+    import json
+
+    from benchmark.lib import system
+    from deepspeed_tpu.models import CausalLM, init_params
+    from deepspeed_tpu.models import transformer as T
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "lfm2-8b-a1b-d14.json")) as f:
+        cfg = system.transformer_config(json.load(f), False)
+    assert T.conv_step_path(cfg) == "plain"
+    slots, maxp = 128, 24
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: T.per_layer_leaves(cfg, init_params(
+                cfg, jax.random.PRNGKey(0)))[0]))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * maxp, 128, dtype=jnp.bfloat16, slots=slots)))
+    assert cache["k"].shape == cache["v"].shape == (3, 3073, 128, 8, 64)
+    assert cache["conv_tail"].shape == (11, slots, 4096)
+    b, s = (slots, 1) if program == "decode" else (1, 1024)
+    order = (0, 1, 3, 4, 2)
+
+    def run(params, cache, tokens, table, start, mask, slot, at):
+        kw = {} if program == "decode" else {"state_slot": slot,
+                                             "logits_at": at}
+        logits, cache, counts = T.forward_paged(
+            cfg, params, tokens, cache, table, start, mask,
+            expert_counts=True, pool_order=order, **kw)
+        return jnp.argmax(logits[:, -1], -1), cache, counts
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, S((b, s), jnp.int32), S((b, maxp), jnp.int32),
+        S((b,), jnp.int32), S((b, s), jnp.bool_), S((b,), jnp.int32),
+        S((b,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert 11.7e9 < mem.argument_size_in_bytes < 11.9e9
+    assert mem.alias_size_in_bytes > 2.4e9          # the cache in place
+    text = compiled.as_text()
+    kernels = [ln for ln in text.splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 0.5e9
+        assert T.expert_matmul_path(cfg, slots, 1) == "ragged_dot"
+        assert T.kv_read_paths(cfg, cache, order) == {"k": "gather",
+                                                      "v": "gather"}
+        assert not any("grouped_matmul" in ln for ln in kernels)
+    else:
+        assert mem.temp_size_in_bytes < 1.5e9
+        assert T.expert_matmul_path(cfg, 1, 1024) == "kernel"
+    # no copy of the tails' leaf: it is updated where it lies
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and (
+        "bf16[11,128,4096]" in ln or "bf16[1408,4096]" in ln)]

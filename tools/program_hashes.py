@@ -46,6 +46,10 @@ KINDS = {
         num_layers=4, hidden_size=64, intermediate_size=96, num_heads=4,
         num_kv_heads=4, head_dim=16, linear_heads=4, linear_key_dim=8,
         linear_value_dim=64, linear_chunk=8, **F32)),
+    "conv": ("lfm2-8b-a1b", dict(
+        num_layers=6, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_heads=4, num_kv_heads=2, head_dim=16,
+        num_experts=8, moe_top_k=3, **F32)),
 }
 
 
