@@ -66,12 +66,10 @@ class CacheLayout:
         self.ring_pages = (window_ring_pages(cfg.window_size, self.page_size)
                            if self.kind == "window" else 0)
         self.window_pages = self.ring_pages and 1 + b_slots * self.ring_pages
-        # state-space, delta or conv layers: leaves a row a slot beside the
-        # pages (``ssm_state`` / ``ssm_conv``, ``delta_state`` /
-        # ``delta_conv``, ``conv_tail`` alone).  The paged leaves cover the
-        # layers with attention, the state leaves those with a mixer: every
-        # layer both (a parallel block), or each layer one of the two (a
-        # layer_pattern's "ssm", "linear" or "conv" kind and its "full" kind)
+        # a mixer of ``models.mixers.MIXERS``: its leaves a row a slot beside
+        # the pages.  The paged leaves cover the layers with attention, the
+        # state leaves those with a mixer: every layer both (a parallel
+        # block), or each layer one of the two (a layer_pattern)
         self.stateful = self.kind == "state"
         self.kv_layers, self.state_layers = cache_layers(cfg)
         # a pool of pages each: ``(pages, page 0 its trash page; those a

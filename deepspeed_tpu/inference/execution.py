@@ -48,17 +48,14 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.transformer import (CONV_STATE_PASSES, DELTA_STATE_PASSES,
-                                  PAGED_POOL_KEYS, SSM_STATE_PASSES,
-                                  STATE_POOL_KEYS, conv_step_path,
-                                  cow_copy_pool, delta_step_path,
-                                  expert_counts_shape, is_hybrid,
-                                  paged_pool_cache, paged_pool_order,
-                                  paged_pool_tuple, per_layer_leaves,
-                                  expert_matmul_path, expert_products,
-                                  expert_rows_moved,
-                                  kv_read_paths, kv_write_paths,
-                                  ssm_step_path)
+from ..models.mixers import MIXERS, state_step_paths
+from ..models.transformer import (PAGED_POOL_KEYS, STATE_POOL_KEYS,
+                                  cow_copy_pool, expert_counts_shape,
+                                  is_hybrid, paged_pool_cache,
+                                  paged_pool_order, paged_pool_tuple,
+                                  per_layer_leaves, expert_matmul_path,
+                                  expert_products, expert_rows_moved,
+                                  kv_read_paths, kv_write_paths)
 from ..observability.program_stats import (ProgramCatalog, account,
                                            finish_sample)
 from .cache_layout import CacheLayout
@@ -283,19 +280,12 @@ class MeshExecutor:
                 f"model axis ({self.tp}): the paged KV pool shards its "
                 "head dim over 'model' (paged_cache_specs) — pick tp "
                 "dividing kv_heads or replicate with tp=1")
-        # the decode tick of a model with a state a slot holds one of two
-        # steps, chosen where it is traced from the backend and the state's
-        # shape: "one_pass" (the kernel: the leaf in place, a read and a write
-        # of a live slot's state a layer) or "xla" (three passes); else None
-        self.ssm_step = ssm_step_path(cfg)
-        # likewise a model with delta layers: "one_pass" (one read and one
-        # write) or "plain" (three passes)
-        self.delta_step = delta_step_path(cfg)
-        # and one with conv layers: "plain" (a tail read and written once)
-        self.conv_step = conv_step_path(cfg)
-        self.state_passes = (SSM_STATE_PASSES.get(self.ssm_step)
-                             or DELTA_STATE_PASSES.get(self.delta_step)
-                             or CONV_STATE_PASSES.get(self.conv_step, 0))
+        # the step the decode tick of a model with a state a slot holds, by
+        # the kind of its mixer (``models.mixers.MIXERS``: chosen where the
+        # tick is traced), and that step's passes over a live slot's state
+        self.state_steps = state_step_paths(cfg)
+        self.state_passes = max((MIXERS[kind].passes[step] for kind, step
+                                 in self.state_steps.items()), default=0)
         pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype, **layout.pool_kw}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
@@ -906,13 +896,11 @@ class MeshExecutor:
         ``weight_leaves_split`` stacks cut into a leaf a layer,
         ``weight_leaves_relaid`` leaves (``weight_bytes_relaid`` bytes)
         copied into the layout the decode program asked for; all 0 for a
-        tree that already lay so (a warm restart's).  ``ssm_step``: the
-        step the decode tick of a model with a state a slot holds
-        (``"one_pass"`` / ``"xla"``: ``models.transformer.ssm_step_path``),
-        ``None`` for any other model; ``delta_step`` the same for delta
-        layers (``"one_pass"`` / ``"plain"``:
-        ``models.transformer.delta_step_path``) and ``conv_step`` for conv
-        layers (``"plain"``: ``models.transformer.conv_step_path``).
+        tree that already lay so (a warm restart's).  ``ssm_step``,
+        ``delta_step``, ``conv_step`` (each row's ``step_key`` of
+        ``models.mixers.MIXERS``): the step the decode tick holds for that
+        kind of mixer (the row's ``step_path``: ``"one_pass"`` or the plain
+        step's name), ``None`` for a model with no such layer.
         ``kv_write``: how the decode tick
         lays a token's rows into each paged leaf (``"row"`` / ``"page"``:
         ``models.transformer.kv_write_path``), ``kv_read`` how it reads each
@@ -931,9 +919,9 @@ class MeshExecutor:
                 "mesh_axes": {} if mesh is None else {
                     a: int(mesh.shape[a]) for a in mesh.axis_names
                     if int(mesh.shape[a]) > 1},
-                **self.weight_placement, "ssm_step": self.ssm_step,
-                "delta_step": self.delta_step,
-                "conv_step": self.conv_step,
+                **self.weight_placement,
+                **{m.step_key: self.state_steps.get(m.kind)
+                   for m in MIXERS.values()},
                 "kv_write": dict(self.kv_write),
                 "kv_read": dict(self.kv_read),
                 "expert_matmul": self.expert_matmul(),

@@ -143,6 +143,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..models.mixers import MIXERS
 from ..models.transformer import PAGE_SIZE
 from ..observability.device_profiler import (device_trace_unit,
                                              maybe_capture_from_env)
@@ -782,7 +783,7 @@ class ServingEngine:
             + f" cache={info['cache_kind']} kv_layers={info['kv_layers']}"
             + "".join(f" state_layers={info['state_layers']} "
                       f"{step}={info[step]}"
-                      for step in ("ssm_step", "delta_step", "conv_step")
+                      for step in (m.step_key for m in MIXERS.values())
                       if info[step])
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items())

@@ -36,6 +36,15 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import BATCH_AXES, constrain_spec
+from . import mixers
+from .mixers import MIXERS, mixers_of
+from .mixers import common as _common
+from .mixers.common import _scaled
+# what the benchmark's references and cells reach for by these names
+from .mixers.conv import _conv_mixer  # noqa: F401
+from .mixers.delta import (_delta_mixer, delta_state_heads,  # noqa: F401
+                           delta_widths)
+from .mixers.ssm import _ssm_mixer, ssm_in_width  # noqa: F401
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -132,7 +141,7 @@ class TransformerConfig:
     # float32 state a sequence; B and C shared by ``ssm_heads / ssm_groups``
     # heads; a depthwise causal convolution of ``ssm_conv`` taps over x, B and
     # C; a prompt runs the recurrence in chunks of ``ssm_chunk`` positions
-    # (:func:`_ssm_scan`), a decode token as the recurrence itself.  The
+    # (``mixers.ssm._ssm_scan``), a decode token as the recurrence itself.  The
     # paged cache then holds two leaves with NO page axis beside K and V:
     # ``ssm_state [L, slots, heads, head_dim, state]`` and the convolution's
     # tail ``ssm_conv [L, slots, taps - 1, channels]``.  0 heads: no mixer.
@@ -157,8 +166,8 @@ class TransformerConfig:
     # sigmoid(b)`` (doubled under ``linear_neg_eigval``: eigenvalues of the
     # transition in (-1, 1)), the output RMS-normed by head and gated
     # (:func:`_delta_mixer`).  A prompt runs the recurrence in chunks of
-    # ``linear_chunk`` positions (:func:`_delta_scan`), a decode token as the
-    # recurrence itself.  The paged cache holds ``delta_state`` and the
+    # ``linear_chunk`` positions (``mixers.delta._delta_scan``), a decode token
+    # as the recurrence itself.  The paged cache holds ``delta_state`` and the
     # convolutions' tail ``delta_conv`` for the "linear" layers, a row a
     # slot, beside K and V pages for the "full" ones.  0 heads: no such layer.
     linear_heads: int = 0
@@ -337,22 +346,7 @@ class TransformerConfig:
             attn += nh
         if not sublayers(self)[0]:      # a mixer in attention's place
             attn = 0
-        if is_conv(self):
-            # the operator: in- and out-projection, the taps (and bias)
-            attn += 4 * d * d + (self.conv_taps + self.conv_bias) * d
-        if self.ssm_heads:
-            # the mixer: in- and out-projection, the convolution with its
-            # bias, A, D, dt's bias, the gated norm
-            ds, conv = ssm_widths(self)[:2]
-            attn += (d * ssm_in_width(self) + ds * d
-                     + conv * (self.ssm_conv + 1) + 3 * self.ssm_heads + ds)
-        if is_delta(self):
-            # the delta mixer: q, k, v and the gate, b and a, the output
-            # projection, the taps, A, dt's bias, the norm by head
-            qkv = delta_widths(self)[2]
-            attn += (d * (delta_in_width(self) + 2 * self.linear_heads)
-                     + delta_widths(self)[1] * d + self.linear_conv * qkv
-                     + 2 * self.linear_heads + self.linear_value_dim)
+        attn += sum(m.param_count(self) for m in mixers_of(self))
         if self.moe_intermediate_size and self.num_experts != 1:
             f = self.moe_intermediate_size
         mlp = 3 * d * f if self.activation == "swiglu" else 2 * d * f
@@ -646,28 +640,23 @@ def is_latent(cfg: TransformerConfig) -> bool:
 
 
 def is_ssm(cfg: TransformerConfig) -> bool:
-    """State-space layers (``ssm_heads``): a fixed-size state a sequence in
-    every layer that has the mixer, two cache leaves with no page axis."""
+    """State-space layers (``ssm_heads``; ``mixers/ssm.py``)."""
     return bool(cfg.ssm_heads)
 
 
 def is_delta(cfg: TransformerConfig) -> bool:
-    """Gated-delta-rule layers (``linear_heads``): a matrix state a head a
-    sequence in every "linear" layer of the pattern, two cache leaves with
-    no page axis."""
+    """Gated-delta-rule layers (``linear_heads``; ``mixers/delta.py``)."""
     return bool(cfg.linear_heads)
 
 
 def is_conv(cfg: TransformerConfig) -> bool:
-    """Gated short-convolution layers (``conv_taps``): the convolution's
-    tail a sequence in every "conv" layer of the pattern, one cache leaf
-    with no page axis."""
+    """Gated short-convolution layers (``conv_taps``; ``mixers/conv.py``)."""
     return bool(cfg.conv_taps)
 
 
 def has_state(cfg: TransformerConfig) -> bool:
-    """A state a slot of any kind (:func:`cache_kind` ``"state"``)."""
-    return is_ssm(cfg) or is_delta(cfg) or is_conv(cfg)
+    """A state a slot (:func:`cache_kind` ``"state"``): a mixer of ``MIXERS``."""
+    return bool(mixers_of(cfg))
 
 
 def qk_norm_widths(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -682,14 +671,13 @@ def qk_norm_widths(cfg: TransformerConfig) -> Tuple[int, int]:
 def sublayers(cfg: TransformerConfig) -> Tuple[bool, bool]:
     """``(attention, mixer)``: which of the two a layer of the uniform stack
     ``cfg`` has before its MLP.  The one rule :func:`_block`, the parameters
-    and the cache's leaves follow: attention unless the stack is a
-    pattern's "ssm" group (``ssm_alone``), its "linear" group (the only
-    stack with ``linear_heads``) or its "conv" group (the only one with
-    ``conv_taps``), a mixer where the stack has its heads or taps (both:
-    Falcon-H1's parallel block; under a ``layer_pattern``
-    :func:`layer_groups` gives each kind's group one)."""
-    alone = cfg.ssm_alone or is_delta(cfg) or is_conv(cfg)
-    return not alone, bool(cfg.ssm_heads) or is_delta(cfg) or is_conv(cfg)
+    and the cache's leaves follow: a mixer where the stack turns one of
+    :data:`~.mixers.MIXERS` on, attention unless that mixer stands in its
+    place (any but one that may stand ``beside`` it, and that one too in a
+    pattern's group of its own, ``ssm_alone``; both: Falcon-H1's parallel
+    block)."""
+    has = mixers_of(cfg)
+    return not (cfg.ssm_alone or any(not m.beside for m in has)), bool(has)
 
 
 def cache_layers(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -709,46 +697,6 @@ def cache_depth(cfg: TransformerConfig) -> int:
     with attention (:func:`cache_layers`: all of them but a pattern's "ssm"
     ones).  The weights' stack is ``num_layers`` deep either way."""
     return cfg.loop_passes * cache_layers(cfg)[0]
-
-
-def ssm_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
-    """``(d_ssm, convolved channels, B's or C's width)`` of the mixer:
-    heads x head_dim; x, B and C together; groups x state."""
-    d_ssm, gn = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
-    return d_ssm, d_ssm + 2 * gn, gn
-
-
-def ssm_in_width(cfg: TransformerConfig) -> int:
-    """The in-projection's outputs: ``[z | x | B | C | dt]`` (9,248 for
-    Falcon-H1-34B)."""
-    d_ssm, conv, _ = ssm_widths(cfg)
-    return d_ssm + conv + cfg.ssm_heads
-
-
-def delta_widths(cfg: TransformerConfig) -> Tuple[int, int, int]:
-    """``(keys' width, values' width, convolved channels)`` of the delta
-    mixer: heads x key dim (q's and k's each), heads x value dim, q, k and
-    v together (2,880, 5,760 and 11,520 for Olmo-Hybrid-7B)."""
-    dk = cfg.linear_heads * cfg.linear_key_dim
-    dv = cfg.linear_heads * cfg.linear_value_dim
-    return dk, dv, 2 * dk + dv
-
-
-def delta_in_width(cfg: TransformerConfig) -> int:
-    """The in-projection's outputs: ``[q | k | v | gate]`` (17,280)."""
-    return delta_widths(cfg)[2] + delta_widths(cfg)[1]
-
-
-def delta_pack(cfg: TransformerConfig) -> int:
-    """Heads of a delta layer whose value columns share one row of the
-    ``delta_state`` leaf, ``[.., heads / pack, key dim, pack x value dim]``:
-    the fewest that make the row whole 128-lane tiles (2 at 192 columns: 384
-    lanes and nothing padded, where ``[.., 96, 192]`` pads each row to 256),
-    1 where no count of heads does."""
-    for p in range(1, 5):
-        if (p * cfg.linear_value_dim) % 128 == 0 and cfg.linear_heads % p == 0:
-            return p
-    return 1
 
 
 def window_ring_pages(window: int, page_size: int) -> int:
@@ -784,22 +732,13 @@ def layer_plan(cfg: TransformerConfig):
     plan, seen = [], {}
     # a model cut in depth runs the first layers of the published pattern
     for i, kind in enumerate(pattern[:cfg.num_layers]):
-        if kind not in ("full", "window", "ssm", "linear", "conv"):
+        if kind not in ("full", "window", *MIXERS):
+            raise ValueError(f"layer_pattern[{i}] = {kind!r}: "
+                             + " | ".join(("full", "window", *MIXERS)))
+        if kind in MIXERS and MIXERS[kind] not in mixers_of(cfg):
             raise ValueError(
-                f"layer_pattern[{i}] = {kind!r}: "
-                "full | window | ssm | linear | conv")
-        if kind == "ssm" and not is_ssm(cfg):
-            raise ValueError(
-                f"layer_pattern[{i}] = 'ssm' in a model with no state-space "
-                "mixer (ssm_heads = 0)")
-        if kind == "linear" and not is_delta(cfg):
-            raise ValueError(
-                f"layer_pattern[{i}] = 'linear' in a model with no delta "
-                "mixer (linear_heads = 0)")
-        if kind == "conv" and not is_conv(cfg):
-            raise ValueError(
-                f"layer_pattern[{i}] = 'conv' in a model with no gated "
-                "convolution (conv_taps = 0)")
+                f"layer_pattern[{i}] = {kind!r} in a model with no "
+                f"{MIXERS[kind].words}")
         dense = i < cfg.dense_layers or not has_moe(cfg)
         group = f"{kind}_{'dense' if dense else 'moe'}"
         plan.append((group, seen.get(group, 0), kind, dense))
@@ -812,20 +751,19 @@ def layer_groups(cfg: TransformerConfig):
     grouped model, in order of first appearance: each group is a plain stack that
     :func:`init_params`, :func:`param_specs` and :func:`_block` take as they
     take any model's, with the kind's KV heads, theta, sink and MLP, and
-    under a pattern the kind's one mixer (:func:`sublayers`): the state-space
-    one alone in an "ssm" group, the delta one alone in a "linear" group,
-    the gated convolution alone in a "conv" group, attention alone in any
-    other."""
+    under a pattern the kind's one mixer (:func:`sublayers`): a kind of
+    :data:`~.mixers.MIXERS` its mixer alone, any other attention alone."""
     groups: Dict[str, Any] = {}
     for group, index, kind, dense in layer_plan(cfg):
         window = kind == "window"
         groups[group] = (dataclasses.replace(
             cfg, layer_pattern=None, dense_layers=0, num_layers=index + 1,
             ssm_alone=kind == "ssm",
-            ssm_heads=(cfg.ssm_heads if kind == "ssm"
-                       or cfg.layer_pattern is None else 0),
-            linear_heads=cfg.linear_heads if kind == "linear" else 0,
-            conv_taps=cfg.conv_taps if kind == "conv" else 0,
+            # a kind's mixer in its own layers alone; without a pattern the
+            # one that stands beside attention in every layer
+            **{m.field: (getattr(cfg, m.field) if kind == m.kind
+                         or (m.beside and cfg.layer_pattern is None) else 0)
+               for m in MIXERS.values()},
             num_kv_heads=(cfg.window_kv_heads if window
                           and cfg.window_kv_heads else cfg.num_kv_heads),
             rope_theta=(cfg.window_rope_theta if window
@@ -931,122 +869,6 @@ def _check_latent(cfg: TransformerConfig) -> None:
         raise NotImplementedError("shared experts are gated (swiglu) MLPs")
 
 
-def _check_ssm(cfg: TransformerConfig) -> None:
-    """What a layer with a state-space mixer (beside its attention, or in
-    its place under a ``layer_pattern``) is built from, and what it leaves
-    out.  Takes a model's config or a group's (:func:`layer_groups`)."""
-    if not (cfg.ssm_head_dim and cfg.ssm_state and cfg.ssm_conv > 1
-            and cfg.ssm_heads % cfg.ssm_groups == 0
-            and len(cfg.ssm_multipliers) == 5
-            and len(cfg.mlp_multipliers) == 2):
-        raise ValueError(
-            "state-space layers (ssm_heads) take ssm_head_dim, ssm_state, "
-            "ssm_conv > 1, heads in whole groups, five ssm_multipliers and "
-            "two mlp_multipliers")
-    if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
-        raise NotImplementedError(
-            "state-space layers (ssm_heads) take RMSNorm and a gated MLP")
-    run = (cfg.layer_pattern or ())[:cfg.num_layers]
-    for on, what in ((cfg.parallel_residual, "parallel_residual"),
-                     (cfg.post_layernorm, "post_layernorm"),
-                     (isinstance(cfg.num_experts, (tuple, list)),
-                      "per-layer expert counts (a num_experts tuple)"),
-                     (is_latent(cfg), "latent attention"),
-                     (cfg.dense_layers > 0,
-                      "leading dense layers (dense_layers)"),
-                     ("window" in run,
-                      "window layers in one layer_pattern with them"),
-                     (bool(run) and cfg.attn_bias,
-                      "attn_bias under a layer_pattern"),
-                     ("linear" in run,
-                      "linear layers in one layer_pattern with them"),
-                     ("conv" in run,
-                      "conv layers in one layer_pattern with them"),
-                     (bool(run) and not ("ssm" in run and "full" in run),
-                      "a layer_pattern whose layers run are not of both "
-                      "kinds, ssm and full"),
-                     (cfg.attention_layers is not None, "attention_layers"),
-                     (cfg.pipeline_stages > 1, "pipeline_stages"),
-                     (cfg.random_ltd, "random_ltd")):
-        if on:
-            raise NotImplementedError(
-                f"state-space layers (ssm_heads) do not take {what}")
-
-
-def _check_delta(cfg: TransformerConfig) -> None:
-    """What a model with gated-delta-rule layers (``linear_heads``; "linear"
-    entries of its ``layer_pattern``) is built from, and what it leaves out.
-    Takes a model's config or a group's (:func:`layer_groups`)."""
-    if not (cfg.linear_key_dim and cfg.linear_value_dim
-            and cfg.linear_conv > 1 and cfg.linear_chunk > 0):
-        raise ValueError(
-            "delta layers (linear_heads) take linear_key_dim, "
-            "linear_value_dim, linear_conv > 1 and linear_chunk > 0")
-    if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
-        raise NotImplementedError(
-            "delta layers (linear_heads) take RMSNorm and a gated MLP")
-    run = (cfg.layer_pattern or ())[:cfg.num_layers]
-    for on, what in ((is_ssm(cfg), "state-space layers (ssm_heads)"),
-                     (cfg.parallel_residual, "parallel_residual"),
-                     (cfg.post_layernorm, "post_layernorm"),
-                     (cfg.num_experts != 1, "expert layers"),
-                     (is_latent(cfg), "latent attention"),
-                     (cfg.dense_layers > 0,
-                      "leading dense layers (dense_layers)"),
-                     ("window" in run or "ssm" in run,
-                      "window or ssm layers in one layer_pattern with them"),
-                     ("conv" in run,
-                      "conv layers in one layer_pattern with them"),
-                     (cfg.attn_bias, "attn_bias"),
-                     (bool(run) and not ("linear" in run and "full" in run),
-                      "a layer_pattern whose layers run are not of both "
-                      "kinds, linear and full"),
-                     (cfg.attention_layers is not None, "attention_layers"),
-                     (cfg.loop_passes > 1, "loop_passes"),
-                     (cfg.pipeline_stages > 1, "pipeline_stages"),
-                     (cfg.random_ltd, "random_ltd")):
-        if on:
-            raise NotImplementedError(
-                f"delta layers (linear_heads) do not take {what}")
-
-
-def _check_conv(cfg: TransformerConfig) -> None:
-    """What a model with gated short-convolution layers (``conv_taps``;
-    "conv" entries of its ``layer_pattern``) is built from, and what it
-    leaves out.  Takes a model's config or a group's (:func:`layer_groups`).
-    Leading dense layers and expert layers it takes: the walk by kind runs
-    each layer its own group's MLP."""
-    if cfg.conv_taps < 2:
-        raise ValueError("conv layers take conv_taps > 1")
-    if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
-        raise NotImplementedError(
-            "conv layers (conv_taps) take RMSNorm and a gated MLP")
-    run = (cfg.layer_pattern or ())[:cfg.num_layers]
-    for on, what in ((is_ssm(cfg), "state-space layers (ssm_heads)"),
-                     (is_delta(cfg), "delta layers (linear_heads)"),
-                     (cfg.parallel_residual, "parallel_residual"),
-                     (cfg.post_layernorm, "post_layernorm"),
-                     (cfg.sandwich_norm or cfg.norm_after,
-                      "sandwich_norm or norm_after"),
-                     (isinstance(cfg.num_experts, (tuple, list)),
-                      "per-layer expert counts (a num_experts tuple)"),
-                     (is_latent(cfg), "latent attention"),
-                     ("window" in run or "ssm" in run or "linear" in run,
-                      "window, ssm or linear layers in one layer_pattern "
-                      "with them"),
-                     (cfg.attn_bias, "attn_bias"),
-                     (bool(run) and not ("conv" in run and "full" in run),
-                      "a layer_pattern whose layers run are not of both "
-                      "kinds, conv and full"),
-                     (cfg.attention_layers is not None, "attention_layers"),
-                     (cfg.loop_passes > 1, "loop_passes"),
-                     (cfg.pipeline_stages > 1, "pipeline_stages"),
-                     (cfg.random_ltd, "random_ltd")):
-        if on:
-            raise NotImplementedError(
-                f"conv layers (conv_taps) do not take {what}")
-
-
 def _check_qk_norm(cfg: TransformerConfig) -> None:
     if cfg.qk_norm not in (True, "head"):
         raise ValueError(f"qk_norm={cfg.qk_norm!r}: False | True | 'head'")
@@ -1065,8 +887,7 @@ def _check_loop(cfg: TransformerConfig) -> None:
                 "sandwich_norm is four RMSNorms a layer: a scale and no "
                 "offset")
         for on, what in ((cfg.post_layernorm, "post_layernorm"),
-                         (cfg.parallel_residual, "parallel_residual"),
-                         (is_ssm(cfg), "state-space layers")):
+                         (cfg.parallel_residual, "parallel_residual")):
             if on:
                 raise NotImplementedError(
                     f"sandwich_norm does not take {what}")
@@ -1076,8 +897,7 @@ def _check_loop(cfg: TransformerConfig) -> None:
                 "norm_after is two RMSNorms a layer: a scale and no offset")
         for on, what in ((cfg.post_layernorm, "post_layernorm"),
                          (cfg.parallel_residual, "parallel_residual"),
-                         (cfg.sandwich_norm, "sandwich_norm"),
-                         (is_ssm(cfg), "state-space layers")):
+                         (cfg.sandwich_norm, "sandwich_norm")):
             if on:
                 raise NotImplementedError(f"norm_after does not take {what}")
     if cfg.loop_passes < 1:
@@ -1089,7 +909,6 @@ def _check_loop(cfg: TransformerConfig) -> None:
                 "takes final_norm")
         for on, what in ((is_grouped(cfg), "layer_pattern / dense_layers"),
                          (is_latent(cfg), "latent attention"),
-                         (is_ssm(cfg), "state-space layers"),
                          (cfg.num_experts != 1, "expert layers"),
                          (cfg.attention_layers is not None,
                           "attention_layers"),
@@ -1107,15 +926,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     differ per layer, so there is nothing to scan."""
     if isinstance(cfg.num_experts, (tuple, list)):
         return _init_params_het(cfg, rng)
+    mixers.check(cfg)   # a model's config, and each of its groups' in turn
     if is_grouped(cfg):
         # the parts outside the layers from a one-layer model of the first
         # group, then each group's own stack: ``params["layers"][group]``
-        if is_ssm(cfg):
-            _check_ssm(cfg)
-        if is_delta(cfg):
-            _check_delta(cfg)
-        if is_conv(cfg):
-            _check_conv(cfg)
         groups = layer_groups(cfg)
         first = next(iter(groups.values()))[0]
         params = init_params(dataclasses.replace(first, num_layers=1), rng)
@@ -1164,60 +978,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
     if not has_attn:
         for name in _ATTN_LEAVES:
             del layers[name]
-    if is_conv(cfg):
-        _check_conv(cfg)
-        # the gated convolution: the taps U(+-1/2), as the other mixers'
-        sk = jax.random.split(jax.random.fold_in(rng, 21), 4)
-        layers.update(
-            conv_in=dense(sk[0], (L, d, 3 * d)),
-            conv_w=jax.random.uniform(sk[1], (L, cfg.conv_taps, d),
-                                      minval=-0.5, maxval=0.5),
-            conv_out=dense(sk[2], (L, d, d), std / math.sqrt(2 * L)))
-        if cfg.conv_bias:
-            layers["conv_b"] = dense(sk[3], (L, d))
-    if is_delta(cfg):
-        _check_delta(cfg)
-        # the delta mixer.  ``A_log`` and dt's bias are Mamba-2's draws (the
-        # decay ``exp(-A softplus(a + dt_bias))`` then lies in (0.2, 1) where
-        # a normal draw would leave every head at one rate), the taps
-        # U(+-1/2), the norm by head 1
-        H, K = cfg.linear_heads, cfg.linear_conv
-        _, dv, conv = delta_widths(cfg)
-        sk = jax.random.split(jax.random.fold_in(rng, 20), 6)
-        step = jnp.exp(jax.random.uniform(
-            sk[3], (L, H), minval=math.log(1e-3), maxval=math.log(1e-1)))
-        layers.update(
-            delta_in=dense(sk[0], (L, d, delta_in_width(cfg))),
-            delta_ba=dense(sk[1], (L, d, 2 * H)),
-            delta_conv_w=jax.random.uniform(sk[2], (L, K, conv), minval=-0.5,
-                                            maxval=0.5),
-            delta_dt_bias=step + jnp.log(-jnp.expm1(-step)),
-            delta_A_log=jnp.log(jax.random.uniform(sk[4], (L, H), minval=1.0,
-                                                   maxval=16.0)),
-            delta_norm_scale=jnp.ones((L, cfg.linear_value_dim)),
-            delta_out=dense(sk[5], (L, dv, d), std / math.sqrt(2 * L)))
-    if is_ssm(cfg):
-        _check_ssm(cfg)
-        # the mixer (:func:`sublayers`).  What a normal draw would make
-        # meaningless gets Mamba-2's own initial ranges: A = -U(1, 16) as
-        # its log, dt's bias the inverse softplus of a log-uniform step in
-        # [1e-3, 1e-1], D = 1, the taps U(+-1/2) (1 / sqrt(taps) at 4)
-        H, K = cfg.ssm_heads, cfg.ssm_conv
-        d_ssm, conv, _ = ssm_widths(cfg)
-        sk = jax.random.split(jax.random.fold_in(rng, 19), 6)
-        step = jnp.exp(jax.random.uniform(
-            sk[3], (L, H), minval=math.log(1e-3), maxval=math.log(1e-1)))
-        layers.update(
-            ssm_in=dense(sk[0], (L, d, ssm_in_width(cfg))),
-            ssm_conv_w=jax.random.uniform(sk[1], (L, K, conv), minval=-0.5,
-                                          maxval=0.5),
-            ssm_conv_b=dense(sk[2], (L, conv)),
-            ssm_dt_bias=step + jnp.log(-jnp.expm1(-step)),
-            ssm_A_log=jnp.log(jax.random.uniform(sk[4], (L, H), minval=1.0,
-                                                 maxval=16.0)),
-            ssm_D=jnp.ones((L, H)),
-            ssm_norm_scale=jnp.ones((L, d_ssm)),
-            ssm_out=dense(sk[5], (L, d_ssm, d), std / math.sqrt(2 * L)))
+    for m in mixers_of(cfg):
+        layers.update(m.init(cfg, rng, dense))
     if not cfg.shared_layernorm:   # GPT-J shares the attention LN
         layers["mlp_norm_scale"] = jnp.ones((L, d))
     _check_loop(cfg)
@@ -1470,26 +1232,8 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     if not has_attn:
         for name in _ATTN_LEAVES:
             del layers[name]
-    if is_conv(cfg):
-        # whole on every chip, as the other mixers: a slot's tail is one row
-        layers.update(conv_in=P(None, None, None), conv_w=P(None, None, None),
-                      conv_out=P(None, None, None))
-        if cfg.conv_bias:
-            layers["conv_b"] = rep
-    if is_delta(cfg):
-        # whole on every chip, as the state-space mixer below: a slot's
-        # state is one tensor (heads over chips: ROADMAP R5)
-        layers.update(delta_in=P(None, None, None), delta_ba=P(None, None, None),
-                      delta_conv_w=P(None, None, None), delta_dt_bias=rep,
-                      delta_A_log=rep, delta_norm_scale=rep,
-                      delta_out=P(None, None, None))
-    if is_ssm(cfg):
-        # the mixer whole on every chip: its heads share B and C by group
-        # and a slot's state is one tensor (sharding them is ROADMAP R5's)
-        layers.update(ssm_in=P(None, None, None), ssm_out=P(None, None, None),
-                      ssm_conv_w=P(None, None, None), ssm_conv_b=rep,
-                      ssm_dt_bias=rep, ssm_A_log=rep, ssm_D=rep,
-                      ssm_norm_scale=rep)
+    for m in mixers_of(cfg):
+        layers.update(m.specs(cfg))
     if not cfg.shared_layernorm:
         layers["mlp_norm_scale"] = rep
     if cfg.sandwich_norm:
@@ -1634,15 +1378,6 @@ def _norm(cfg, x, scale, bias=None):
             out = ((x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps) * scale
                    + bias)
         return out.astype(x.dtype)
-
-
-def _scaled(x, m: float):
-    """``x * m`` for one of the family's fixed multipliers, the product
-    rounded once (the constant itself is not rounded to ``x``'s dtype
-    first); ``x`` as it is where ``m`` is 1."""
-    if m == 1.0:
-        return x
-    return (x.astype(jnp.float32) * m).astype(x.dtype)
 
 
 def _embed(cfg, params, tokens, positions, token_type_ids=None):
@@ -2014,7 +1749,7 @@ def expert_matmul_path(cfg: TransformerConfig, B: int, S: int
     return path(B * S // _moe_chunks(cfg, B, S), cfg.moe_top_k,
                 cfg.num_experts, cfg.hidden_size,
                 cfg.moe_intermediate_size or cfg.intermediate_size,
-                cfg.dtype, _pallas_interpret())
+                cfg.dtype, _common._pallas_interpret())
 
 
 def expert_rows_moved(cfg: TransformerConfig, B: int, S: int, counts,
@@ -2089,7 +1824,7 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
                     deterministic=deterministic, rng=rng, token_mask=mask,
                     expert_offset=expert_offset,
                     select_bias=lp.get("router_bias"),
-                    pallas_interpret=_pallas_interpret())
+                    pallas_interpret=_common._pallas_interpret())
 
             B, S, D = h.shape
             n = _moe_chunks(cfg, B, S)
@@ -2220,616 +1955,23 @@ def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None):
     return out
 
 
-def _ssm_project(cfg: TransformerConfig, lp: Dict[str, Any], h):
-    """Post-norm activations ``h [B,S,d]`` through the mixer's
-    in-projection, each of its five segments ``[z | x | B | C | dt]`` by its
-    own multiplier: ``(z [B,S,d_ssm], xBC [B,S,channels], dt [B,S,H])``,
-    ``dt`` in float32 before its bias and softplus."""
-    d_ssm, conv, gn = ssm_widths(cfg)
-    mup = np.repeat(np.asarray(cfg.ssm_multipliers, np.float32),
-                    (d_ssm, d_ssm, gn, gn, cfg.ssm_heads))
-    with jax.named_scope("ssm_in"):
-        p = _scaled(h, cfg.ssm_in_multiplier) @ lp["ssm_in"]
-        p = p.astype(jnp.float32) * mup
-    return (p[..., :d_ssm].astype(h.dtype),
-            p[..., d_ssm:d_ssm + conv].astype(h.dtype), p[..., d_ssm + conv:])
-
-
-def _ssm_conv(cfg: TransformerConfig, lp: Dict[str, Any], xbc, tail, n_real):
-    """The depthwise causal convolution over x, B and C, then SiLU:
-    ``xbc [B,S,C]`` behind the slot's ``tail [B,K-1,C]`` (the K - 1 inputs
-    before the block) -> ``(out [B,S,C], new tail)``.  The new tail is
-    gathered from the last K - 1 REAL positions (``n_real [B]``, real tokens
-    lead the block): a padded prompt leaves what the unpadded one does, and
-    a row with no real token the tail it had."""
-    with jax.named_scope("ssm_conv"):
-        y, tail = _causal_conv(lp["ssm_conv_w"], lp["ssm_conv_b"], xbc, tail,
-                               n_real)
-    return y.astype(xbc.dtype), tail
-
-
-def _causal_conv(w, bias, x, tail, n_real):
-    """:func:`_causal_taps`, then SiLU (the state-space and the delta
-    mixers' convolution)."""
-    y, tail = _causal_taps(w, bias, x, tail, n_real)
-    return jax.nn.silu(y), tail
-
-
-def _causal_taps(w, bias, x, tail, n_real):
-    """A depthwise causal convolution of ``w [K, C]`` (and ``bias [C]`` or
-    None) over ``x [B,S,C]`` behind ``tail [B,K-1,C]``, in float32: ``(out
-    [B,S,C] float32, the last K - 1 inputs before position n_real [B])``.
-    One token a row, it is the K-term sum over the tail and the new row,
-    and the tail shifted by one (or kept, ``n_real`` 0)."""
-    K, S = w.shape[0], x.shape[1]
-    ext = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
-    w = w.astype(jnp.float32)
-    y = sum(ext[:, k:k + S].astype(jnp.float32) * w[k] for k in range(K))
-    if bias is not None:
-        y = y + bias.astype(jnp.float32)
-    tail = jax.vmap(lambda e, n: jax.lax.dynamic_slice_in_dim(
-        e, n, K - 1, axis=0))(ext, n_real.astype(jnp.int32))
-    return y, tail
-
-
 def ssm_scan_chunks(cfg: TransformerConfig, block: int,
                     tokens: Optional[int] = None) -> Optional[int]:
-    """Chunks of ``ssm_chunk`` positions (``linear_chunk`` for a model with
-    delta layers) the scan of a block of ``block`` tokens runs, or those of
-    them that hold one of its ``tokens`` real ones (the ``scan_chunks`` span
-    attrs of a prompt).  None for a model whose prompts run no scan (no
-    state a slot, or a convolution's tail alone)."""
-    if not (cfg.linear_heads or cfg.ssm_heads):
-        return None
-    return -(-(block if tokens is None else min(tokens, block))
-             // (cfg.linear_chunk if cfg.linear_heads else cfg.ssm_chunk))
-
-
-def _ssm_scan(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
-    """The selective state update over a block, in chunks (the SSD form of
-    Mamba-2): ``x [B,S,H,P]``, ``Bm``/``Cm [B,S,G,N]``, ``dt [B,S,H]``
-    float32 and 0 at a masked position, ``A [H]`` float32 (< 0), ``state
-    [B,H,P,N]`` float32 -> ``(y [B,S,H,P] float32, the state after the
-    block)`` with
-
-        S_t = exp(dt_t A) S_t-1 + dt_t x_t (x) B_t        y_t = S_t C_t
-
-    Inside a chunk of Q positions the masked product ``(C B^T . decay) (dt
-    x)``; between chunks the carried state, decayed over each chunk and read
-    by C at every position.  ``dt = 0`` leaves the state as it was and adds
-    nothing, so padding behind the real tokens (the bucket's, or up to a
-    whole chunk) changes no number.  Decays, cumulative sums and the carried
-    state are float32; the four products take the compute dtype's operands
-    and accumulate in float32."""
-    B, S, H, P = x.shape
-    G, N = Bm.shape[2:]
-    Hg, Q = H // G, cfg.ssm_chunk
-    pad = -S % Q
-    if pad:
-        x, Bm, Cm, dt = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
-                                 (a.ndim - 2)) for a in (x, Bm, Cm, dt))
-    nc, f32, cd = (S + pad) // Q, jnp.float32, x.dtype
-    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
-    with jax.named_scope("ssm_scan"):
-        a = (dt * A).reshape(B, nc, Q, G, Hg)
-        cum = jnp.cumsum(a, axis=2)                     # inclusive, <= 0
-        xd = (x.astype(f32) * dt[..., None]).reshape(B, nc, Q, G, Hg, P)
-        Bc, Cc = Bm.reshape(B, nc, Q, G, N), Cm.reshape(B, nc, Q, G, N)
-        # within a chunk: position i reads j <= i, decayed from j to i
-        seg = cum[:, :, :, None] - cum[:, :, None, :]   # [B,nc,i,j,G,Hg]
-        tri = jnp.tril(jnp.ones((Q, Q), bool))[None, None, :, :, None, None]
-        m = (jnp.exp(jnp.where(tri, seg, -jnp.inf))
-             * jnp.moveaxis(mm("bcign,bcjgn->bcgij", Cc, Bc), 2, 4)[..., None])
-        y = mm("bcijgk,bcjgkp->bcigkp", m.astype(cd), xd.astype(cd))
-        # what each chunk adds to the state by its end, and the state each
-        # chunk starts from
-        to_end = jnp.exp(cum[:, :, -1:] - cum)
-        s_c = mm("bcjgkp,bcjgn->bcgkpn", (xd * to_end[..., None]).astype(cd),
-                 Bc)
-        over = jnp.exp(cum[:, :, -1])                   # [B,nc,G,Hg]
-
-        def chunk(s, sc_over):
-            sc, t = sc_over
-            return s * t[..., None, None] + sc, s
-
-        state, s_in = jax.lax.scan(
-            chunk, state.reshape(B, G, Hg, P, N),
-            (jnp.moveaxis(s_c, 1, 0), jnp.moveaxis(over, 1, 0)))
-        y = y + (mm("bcign,bcgkpn->bcigkp", Cc,
-                    jnp.moveaxis(s_in, 0, 1).astype(cd))
-                 * jnp.exp(cum)[..., None])
-    return (y.reshape(B, S + pad, H, P)[:, :S], state.reshape(B, H, P, N))
-
-
-def _ssm_step(cfg: TransformerConfig, x, Bm, Cm, dt, A, state):
-    """:func:`_ssm_scan` for one token a row: the recurrence itself, every
-    number float32.  The sum over the state's columns is written out (a
-    product, then a reduction) so that no matrix unit rounds the state to
-    read it.  A masked row (``dt = 0``) keeps its state.
-
-    Who runs it: the non-paged forward, a paged block whose rows are named
-    (``state_slot``), every backend that is not a TPU and every shape the
-    kernel's tile plan refuses (:func:`ssm_step_path`); the compiler makes an
-    in-place update of it and a reduction that reads the state again, three
-    passes.  A decode tick on a TPU runs :func:`_ssm_step_one_pass`, whose
-    yardstick in the tests this is."""
-    B, _, H, P = x.shape
-    G, N = Bm.shape[2:]
-    Hg, f32 = H // G, jnp.float32
-    with jax.named_scope("ssm_step"):
-        dt1 = dt[:, 0].reshape(B, G, Hg)
-        xd = x[:, 0].astype(f32).reshape(B, G, Hg, P) * dt1[..., None]
-        s = (state.reshape(B, G, Hg, P, N)
-             * jnp.exp(dt1 * A.reshape(G, Hg))[..., None, None]
-             + xd[..., None] * Bm[:, 0].astype(f32)[:, :, None, None, :])
-        y = (s * Cm[:, 0].astype(f32)[:, :, None, None, :]).sum(-1)
-    return y.reshape(B, 1, H, P), s.reshape(B, H, P, N)
-
-
-def _pallas_interpret() -> Optional[bool]:
-    """``interpret`` for a Pallas kernel inside a model's program (the
-    one-pass state step, the K/V row write and the tick's read by pages over
-    a cache leaf, the expert layer's grouped product, which ``_mlp`` hands
-    ``moe_ffn``) where a
-    program traced now may hold one, ``None`` where it may not: Pallas kernels compile for the TPU
-    (``ops/pallas/common.py``'s own test of the backend) and ``pallas_call``
-    has no partitioning rule, so any other backend, and a mesh of more than
-    one device, keep the ``jax.numpy`` path.  Never a config field or an
-    environment variable; a test that compiles for a described chip, or runs
-    a kernel in interpret mode, replaces this function."""
-    from ..parallel import mesh as mesh_mod
-
-    m = mesh_mod._GLOBAL_MESH
-    if jax.default_backend() != "tpu" or (m is not None and m.size > 1):
-        return None
-    return False
-
-
-def ssm_step_path(cfg: TransformerConfig, tokens: int = 1,
-                  state_slot=None, dtype=jnp.float32) -> Optional[str]:
-    """Which step a paged program of ``tokens`` a row holds for ``cfg``'s
-    state-space layers: ``"one_pass"`` (``ops/pallas/ssm_step.py``: the pool
-    leaf updated in place and ``y`` read from the block in on-chip memory,
-    one read and one write of a slot's state) for one token a row over
-    contiguous slot rows (``state_slot`` None: a decode tick) of a float32
-    leaf, on a TPU, at a shape the kernel's tile plan takes; ``"xla"``
-    (:func:`_ssm_step`, three passes) for any other single token; ``None``
-    for a longer block (:func:`_ssm_scan`) and a model with no such layer.
-    Read at trace time from what the code can observe; the serving executor
-    reports it (``mesh_info()["ssm_step"]``)."""
-    from ..ops.pallas.ssm_step import head_block
-
-    if not is_ssm(cfg) or tokens != 1:
-        return None
-    if (state_slot is None and dtype == jnp.float32
-            and _pallas_interpret() is not None
-            and head_block(cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim,
-                           cfg.ssm_state) is not None):
-        return "one_pass"
-    return "xla"
-
-
-# passes over a live slot's state a layer a tick, by the step the tick holds
-SSM_STATE_PASSES = {"one_pass": 2, "xla": 3}
-
-
-def _ssm_step_one_pass(x, Bm, Cm, dt, A, leaf, row0, fresh):
-    """:func:`_ssm_step` for the rows ``row0 .. row0 + B - 1`` of the stacked
-    cache leaf ``leaf [L * slots, H, P, N]`` where they lie: ``(y
-    [B,1,H,P] float32, the leaf)``, the same formula for the state and for
-    ``y`` term for term, a ``fresh [B]`` row from zeros."""
-    from ..ops.pallas.ssm_step import ssm_step
-
-    with jax.named_scope("ssm_step"):
-        dt1 = dt[:, 0]
-        leaf, y = ssm_step(
-            leaf, row0, fresh, jnp.exp(dt1 * A),
-            x[:, 0].astype(jnp.float32) * dt1[..., None],
-            Bm[:, 0].astype(jnp.float32), Cm[:, 0].astype(jnp.float32),
-            interpret=_pallas_interpret())
-    return y[:, None], leaf
-
-
-def _ssm_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], y, z):
-    """The mixer's output gated by ``silu(z)`` and THEN RMS-normed within
-    each of the ``ssm_groups`` groups of channels (``mamba_rms_norm``,
-    ``mamba_norm_before_gate`` false), in float32."""
-    B, S, d_ssm = y.shape
-    with jax.named_scope("ssm_gate_norm"):
-        g = (y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-             ).reshape(B, S, cfg.ssm_groups, -1)
-        g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True)
-                              + cfg.norm_eps)
-        return (g.reshape(B, S, d_ssm)
-                * lp["ssm_norm_scale"].astype(jnp.float32)).astype(cfg.dtype)
-
-
-def _ssm_start(cfg: TransformerConfig, rows: int, dtype):
-    """``(state, tail)`` of ``rows`` sequences that start here: zeros."""
-    return (jnp.zeros((rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                      jnp.float32),
-            jnp.zeros((rows, cfg.ssm_conv - 1, ssm_widths(cfg)[1]), dtype))
-
-
-# Positions of one prompt the mixer takes at a time: the in-projection's
-# output is 16,768 wide and the chunked scan keeps ``[chunk, chunk, heads]``
-# float32 a chunk (the decays between every two positions), together 3 GB
-# over a 16,384-token block of 128 heads in chunks of 256 and 0.4 GB over
-# 2,048 of them
-SSM_BLOCK_TOKENS = 2048
-
-
-def _ssm_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h, seq_mask=None,
-               kept=None, step=None):
-    """:func:`_ssm_mixer_block` over a block of any length: one longer than
-    ``SSM_BLOCK_TOKENS`` (in whole pieces of that many) runs as one scan on
-    the device over the pieces, the state and the convolution's tail carried
-    from piece to piece as they are from call to call, so that the
-    temporaries are a piece's and not the prompt's.  The same numbers either
-    way: real tokens lead the block, so they lead every piece."""
-    B, S, _ = h.shape
-    n = SSM_BLOCK_TOKENS
-    if S <= n or S % n or step is not None:
-        return _ssm_mixer_block(cfg, lp, h, seq_mask, kept, step)
-    if seq_mask is None:
-        seq_mask = jnp.ones((B, S), bool)
-    if kept is None:
-        kept = _ssm_start(cfg, B, h.dtype)
-
-    def pieces(a):      # [B, S, ...] -> [S / n, B, n, ...]
-        return jnp.moveaxis(a.reshape(B, S // n, n, *a.shape[2:]), 1, 0)
-
-    def piece(kept, xs):
-        out, kept = _ssm_mixer_block(cfg, lp, xs[0], xs[1], kept)
-        return kept, out
-
-    kept, out = jax.lax.scan(piece, kept, (pieces(h), pieces(seq_mask)))
-    return jnp.moveaxis(out, 0, 1).reshape(B, S, -1), kept
-
-
-def _ssm_mixer_block(cfg: TransformerConfig, lp: Dict[str, Any], h,
-                     seq_mask=None, kept=None, step=None):
-    """The Mamba-2 mixer of a block on its post-norm input ``h [B,S,d]``:
-    in-projection, convolution, selective state update (one token a row:
-    :func:`_ssm_step`, a longer block: :func:`_ssm_scan`), the skip ``D x``,
-    gated norm, out-projection.  ``kept = (state [B,H,P,N] float32, tail
-    [B,K-1,C])`` is what the rows' sequences hold so far (``None``: they
-    start here); ``seq_mask [B,S]`` its real tokens, which lead the block.
-    Returns ``(out [B,S,d], (state, tail) after the block's real tokens)``.
-    ``step(x, Bm, Cm, dt, A, state) -> (y, state)`` stands in for the state
-    update where the caller holds the state in another form (a decode
-    tick's pool leaf: :func:`_ssm_paged`); ``state`` is then whatever it
-    takes and returns."""
-    B, S, _ = h.shape
-    H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
-                  cfg.ssm_groups)
-    d_ssm, conv, gn = ssm_widths(cfg)
-    if seq_mask is None:
-        seq_mask = jnp.ones((B, S), bool)
-    state, tail = kept if kept is not None else _ssm_start(cfg, B, h.dtype)
-    z, xbc, dt = _ssm_project(cfg, lp, h)
-    xbc, tail = _ssm_conv(cfg, lp, xbc, tail, seq_mask.sum(1))
-    x = xbc[..., :d_ssm].reshape(B, S, H, P)
-    Bm = xbc[..., d_ssm:d_ssm + gn].reshape(B, S, G, N)
-    Cm = xbc[..., d_ssm + gn:].reshape(B, S, G, N)
-    dt = jnp.where(seq_mask[..., None], jax.nn.softplus(
-        dt + lp["ssm_dt_bias"].astype(jnp.float32)), 0.0)
-    A = -jnp.exp(lp["ssm_A_log"].astype(jnp.float32))
-    y, state = (step or functools.partial(
-        _ssm_step if S == 1 else _ssm_scan, cfg))(x, Bm, Cm, dt, A, state)
-    y = y + lp["ssm_D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-    with jax.named_scope("ssm_out"):
-        out = _ssm_gate_norm(cfg, lp, y.reshape(B, S, d_ssm), z) @ lp["ssm_out"]
-    return out, (state, tail)
-
-
-# ---------------------------------------------------------------------------
-# Gated-delta-rule layers ("linear" entries of a ``layer_pattern``)
-# ---------------------------------------------------------------------------
-
-def _delta_project(cfg: TransformerConfig, lp: Dict[str, Any], h):
-    """The layer's input ``h [B,S,d]`` through the delta mixer's projections:
-    ``(qkv [B,S,channels] before the convolution, the output gate's
-    pre-activation [B,S,H*dv], b [B,S,H], a [B,S,H])``, ``b`` and ``a`` in
-    float32 (the write strength's and the decay's pre-activations)."""
-    conv = delta_widths(cfg)[2]
-    with jax.named_scope("delta_in"):
-        p = h @ lp["delta_in"]
-        ba = (h @ lp["delta_ba"]).astype(jnp.float32)
-    H = cfg.linear_heads
-    return p[..., :conv], p[..., conv:], ba[..., :H], ba[..., H:]
-
-
-def _delta_conv(cfg: TransformerConfig, lp: Dict[str, Any], qkv, tail, n_real):
-    """The depthwise causal convolution over q, k and v (no bias), then
-    SiLU, in float32 (the L2 norms read it so): :func:`_ssm_conv`'s form,
-    the new tail gathered from the last ``K - 1`` REAL positions."""
-    with jax.named_scope("delta_conv"):
-        return _causal_conv(lp["delta_conv_w"], None, qkv, tail, n_real)
-
-
-def _unit_lower_inverse(A):
-    """``(I + A)^-1 - I`` for strictly lower-triangular ``A [.., C, C]``
-    float32, by forward substitution a row at a time on the vector unit
-    (row ``i`` is ``-A_i - sum_j<i A_ij row_j``): backward stable whatever
-    the keys are, where a product of powers of ``A`` is not."""
-    C = A.shape[-1]
-
-    def row(i, T):
-        r = jax.lax.dynamic_index_in_dim(T, i, axis=-2, keepdims=False)
-        new = r + (r[..., :, None] * T).sum(-2)
-        return jax.lax.dynamic_update_index_in_dim(T, new, i, axis=-2)
-
-    return jax.lax.fori_loop(1, C, row, -A)
-
-
-def _delta_scan(cfg: TransformerConfig, q, k, v, g, beta, state):
-    """The gated delta rule over a block, in chunks (the WY / UT form of
-    Gated DeltaNet): ``q``/``k [B,S,H,dk]`` (L2-normed, q scaled), ``v
-    [B,S,H,dv]``, ``g [B,S,H]`` float32 (the log decay, <= 0) and ``beta
-    [B,S,H]`` float32, both 0 at a masked position, ``state [B,H,dk,dv]``
-    float32 -> ``(o [B,S,H,dv] float32, the state after the block)`` with
-
-        S_t = a_t S_t-1 + b_t k_t (v_t - (a_t S_t-1)^T k_t)^T     o_t = S_t^T q_t
-
-    Inside a chunk of C positions, with ``c`` the running sum of ``g``: ``A
-    = strict-lower(diag(b) (K K^T . e^(c_i - c_j)))``, ``T = (I + A)^-1
-    diag(b)``, ``W = T (K . e^c)``, ``U = T V``; between chunks the carried
-    state: ``V' = U - W S``, ``O = (Q . e^c) S + (Q K^T . e^(c_i - c_j) .
-    lower) V'``, ``S <- e^(c_C) S + (K . e^(c_C - c))^T V'``.  ``g = beta =
-    0`` leaves the state as it was and adds nothing, so padding behind the
-    real tokens changes no number.  Decays, sums, the substitution and the
-    carried state are float32; the products take the compute dtype's
-    operands and accumulate in float32."""
-    B, S, H, dk = q.shape
-    C = cfg.linear_chunk
-    pad = -S % C
-    if pad:
-        q, k, v, g, beta = (jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) *
-                                    (a.ndim - 2)) for a in (q, k, v, g, beta))
-    nc, f32, cd = (S + pad) // C, jnp.float32, v.dtype
-    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
-
-    def chunks(a):      # [B, S, H, ...] -> [nc, B, H, C, ...]
-        a = a.reshape(B, nc, C, H, *a.shape[3:])
-        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
-
-    with jax.named_scope("delta_scan"):
-        qc, kc, vc = chunks(q), chunks(k), chunks(v)
-        bc = chunks(beta)
-        cum = jnp.cumsum(chunks(g), axis=-1)            # inclusive, <= 0
-        seg = cum[..., :, None] - cum[..., None, :]     # [nc,B,H,i,j]
-        incl = jnp.tril(jnp.ones((C, C), bool))
-        decay = jnp.exp(jnp.where(incl, seg, -jnp.inf))
-        kk = mm("nbhik,nbhjk->nbhij", kc, kc)
-        A = jnp.where(jnp.tril(incl, -1), bc[..., None] * kk * decay, 0.0)
-        T = _unit_lower_inverse(A) + jnp.eye(C, dtype=f32)
-        e = jnp.exp(cum)[..., None]
-        rhs = bc[..., None] * jnp.concatenate(
-            [kc.astype(f32) * e, vc.astype(f32)], axis=-1)
-        WU = mm("nbhij,nbhjx->nbhix", T.astype(cd), rhs.astype(cd))
-        qk = (mm("nbhik,nbhjk->nbhij", qc, kc) * decay).astype(cd)
-        qe = (qc.astype(f32) * e).astype(cd)
-        to_end = jnp.exp(cum[..., -1:] - cum)[..., None]
-        ke = (kc.astype(f32) * to_end).astype(cd)
-        over = jnp.exp(cum[..., -1])                    # [nc,B,H]
-
-        def chunk(s, xs):
-            wu, qk, qe, ke, over = xs
-            sc = s.astype(cd)
-            vp = (wu[..., dk:] - mm("bhck,bhkv->bhcv", wu[..., :dk].astype(cd),
-                                    sc)).astype(cd)
-            o = mm("bhck,bhkv->bhcv", qe, sc) + mm("bhij,bhjv->bhiv", qk, vp)
-            return (s * over[..., None, None]
-                    + mm("bhck,bhcv->bhkv", ke, vp)), o
-
-        state, o = jax.lax.scan(chunk, state, (WU, qk, qe, ke, over))
-        o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 2, 3)   # [B,nc,C,H,dv]
-    return o.reshape(B, S + pad, H, -1)[:, :S], state
-
-
-def _delta_step(cfg: TransformerConfig, q, k, v, g, beta, state):
-    """:func:`_delta_scan` for one token a row: the recurrence itself, every
-    number float32, the two reads of the state (``S^T k`` of the decayed
-    state, ``S^T q`` of the new one) products and reductions on the vector
-    unit.  A masked row (``g = beta = 0``) keeps its state.
-
-    Who runs it: the non-paged forward, a paged block whose rows are named
-    (``state_slot``), every backend that is not a TPU and every shape the
-    kernel's tile plan refuses (:func:`delta_step_path`).  A decode tick on
-    a TPU runs :func:`_delta_step_one_pass`, whose yardstick in the tests
-    this is."""
-    f32 = jnp.float32
-    with jax.named_scope("delta_step"):
-        q1, k1, v1 = (a[:, 0].astype(f32) for a in (q, k, v))
-        s = state * jnp.exp(g[:, 0])[..., None, None]
-        u = (s * k1[..., None]).sum(-2)                 # [B,H,dv]
-        s = s + k1[..., None] * (beta[:, 0][..., None] * (v1 - u))[..., None, :]
-        o = (s * q1[..., None]).sum(-2)
-    return o[:, None], s
-
-
-def delta_state_pack(cfg: TransformerConfig, state):
-    """``[.., H, dk, dv]`` -> the ``delta_state`` leaf's row ``[.., H / p,
-    dk, p x dv]`` (:func:`delta_pack`): head ``h``'s columns at ``(h % p) x
-    dv`` of row block ``h // p``."""
-    p = delta_pack(cfg)
-    if p == 1:
-        return state
-    *lead, H, dk, dv = state.shape
-    s = state.reshape(*lead, H // p, p, dk, dv)
-    return jnp.swapaxes(s, -3, -2).reshape(*lead, H // p, dk, p * dv)
-
-
-def delta_state_heads(cfg: TransformerConfig, leaf):
-    """:func:`delta_state_pack`'s inverse: a leaf's rows as ``[.., H, dk,
-    dv]``."""
-    p = delta_pack(cfg)
-    if p == 1:
-        return leaf
-    *lead, Hp, dk, pdv = leaf.shape
-    s = leaf.reshape(*lead, Hp, dk, p, pdv // p)
-    return jnp.swapaxes(s, -3, -2).reshape(*lead, Hp * p, dk, pdv // p)
-
-
-def delta_step_path(cfg: TransformerConfig, tokens: int = 1,
-                    state_slot=None, dtype=jnp.float32) -> Optional[str]:
-    """:func:`ssm_step_path` for the delta layers: ``"one_pass"``
-    (``ops/pallas/delta_step.py``: the pool leaf updated in place, both
-    reads of a slot's state from the block in on-chip memory) for a decode
-    tick over a float32 leaf on a TPU at a shape the kernel's tile plan
-    takes; ``"plain"`` (:func:`_delta_step`) for any other single token;
-    ``None`` for a longer block and a model with no such layer."""
-    from ..ops.pallas.delta_step import head_block
-
-    if not is_delta(cfg) or tokens != 1:
-        return None
-    p = delta_pack(cfg)
-    if (state_slot is None and dtype == jnp.float32
-            and _pallas_interpret() is not None
-            and head_block(cfg.linear_heads // p, cfg.linear_key_dim,
-                           p * cfg.linear_value_dim) is not None):
-        return "one_pass"
-    return "plain"
-
-
-# passes over a live slot's state a layer a tick, by the step the tick holds
-# (the plain step: the decay and the update in place, and two reductions
-# that each read the state again)
-DELTA_STATE_PASSES = {"one_pass": 1, "plain": 3}
-
-
-def _delta_step_one_pass(q, k, v, g, beta, leaf, row0, fresh):
-    """:func:`_delta_step` for the rows ``row0 .. row0 + B - 1`` of the
-    stacked cache leaf ``leaf [L * slots, H / p, dk, p * dv]`` where they
-    lie: ``(o [B,1,H,dv] float32, the leaf)``, a ``fresh [B]`` row from
-    zeros."""
-    from ..ops.pallas.delta_step import delta_step
-
-    f32 = jnp.float32
-    with jax.named_scope("delta_step"):
-        leaf, o = delta_step(
-            leaf, row0, fresh, jnp.exp(g[:, 0]), beta[:, 0],
-            q[:, 0].astype(f32), k[:, 0].astype(f32), v[:, 0].astype(f32),
-            interpret=_pallas_interpret())
-    return o[:, None], leaf
-
-
-def _delta_gate_norm(cfg: TransformerConfig, lp: Dict[str, Any], o, gate):
-    """The mixer's output RMS-normed WITHIN each head (one learned scale of
-    ``linear_value_dim`` for all heads) and then gated by ``silu(gate)``,
-    in float32: ``o [B,S,H,dv]``, ``gate [B,S,H*dv]`` -> ``[B,S,H*dv]``."""
-    B, S, H, dv = o.shape
-    with jax.named_scope("delta_gate_norm"):
-        o = o.astype(jnp.float32)
-        o = (o * jax.lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
-                               + cfg.norm_eps)
-             * lp["delta_norm_scale"].astype(jnp.float32))
-        return (o.reshape(B, S, H * dv)
-                * jax.nn.silu(gate.astype(jnp.float32))).astype(cfg.dtype)
-
-
-def _delta_start(cfg: TransformerConfig, rows: int, dtype):
-    """``(state, tail)`` of ``rows`` sequences that start here: zeros."""
-    return (jnp.zeros((rows, cfg.linear_heads, cfg.linear_key_dim,
-                       cfg.linear_value_dim), jnp.float32),
-            jnp.zeros((rows, cfg.linear_conv - 1, delta_widths(cfg)[2]),
-                      dtype))
-
-
-def _delta_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h,
-                 seq_mask=None, kept=None, step=None):
-    """The gated-delta-rule mixer of a block on the layer's input ``h
-    [B,S,d]``: projections, convolution, L2 norm of q and k by head, decay
-    and write strength, the delta rule (one token a row: :func:`_delta_step`,
-    a longer block: :func:`_delta_scan`), norm by head, gate,
-    out-projection.  ``kept``, ``seq_mask``, ``step`` and the result as
-    :func:`_ssm_mixer_block`'s, the state ``[B,H,dk,dv]`` float32."""
-    B, S, _ = h.shape
-    H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
-    wk = delta_widths(cfg)[0]
-    f32 = jnp.float32
-    if seq_mask is None:
-        seq_mask = jnp.ones((B, S), bool)
-    state, tail = kept if kept is not None else _delta_start(cfg, B, h.dtype)
-    qkv, gate, b, a = _delta_project(cfg, lp, h)
-    qkv, tail = _delta_conv(cfg, lp, qkv, tail, seq_mask.sum(1))
-
-    def unit(x):        # float32 [B,S,H,dk], L2-normed by head
-        x = x.reshape(B, S, H, dk)
-        return x * jax.lax.rsqrt(jnp.square(x).sum(-1, keepdims=True) + 1e-6)
-
-    q = (unit(qkv[..., :wk]) * dk ** -0.5).astype(h.dtype)
-    k = unit(qkv[..., wk:2 * wk]).astype(h.dtype)
-    v = qkv[..., 2 * wk:].reshape(B, S, H, dv).astype(h.dtype)
-    live = seq_mask[..., None]
-    beta = jnp.where(live, jax.nn.sigmoid(b) * (
-        2.0 if cfg.linear_neg_eigval else 1.0), 0.0)
-    g = jnp.where(live, -jnp.exp(lp["delta_A_log"].astype(f32))
-                  * jax.nn.softplus(a + lp["delta_dt_bias"].astype(f32)), 0.0)
-    o, state = (step or functools.partial(
-        _delta_step if S == 1 else _delta_scan, cfg))(q, k, v, g, beta, state)
-    with jax.named_scope("delta_out"):
-        out = _delta_gate_norm(cfg, lp, o, gate) @ lp["delta_out"]
-    return out, (state, tail)
-
-
-# ---------------------------------------------------------------------------
-# Gated short-convolution layers ("conv" entries of a ``layer_pattern``)
-# ---------------------------------------------------------------------------
-
-def _conv_mixer(cfg: TransformerConfig, lp: Dict[str, Any], h,
-                seq_mask=None, tail=None):
-    """LFM2's operator on the layer's input ``h [B,S,d]``: ``[B | C | u] =
-    h W_in``, ``z = B . u``, the depthwise causal convolution of z behind the
-    sequence's ``tail [B, taps - 1, d]`` (None: it starts here, zeros) with
-    NO activation, ``(C . conv) W_out``: ``(out [B,S,d], the new tail)``.
-    The tail is the last ``taps - 1`` rows of z at REAL positions
-    (``seq_mask [B,S]``, real tokens lead; :func:`_causal_taps`), so a
-    padded block leaves what the unpadded one does and a row with no real
-    token keeps the tail it had.  The projections' outputs, z, the tail and
-    the gated sum are of ``h``'s dtype; each product of two of them and the
-    three-term sum are taken in float32 and rounded once."""
-    B, S, d = h.shape
-    f32 = jnp.float32
-    if seq_mask is None:
-        seq_mask = jnp.ones((B, S), bool)
-    if tail is None:
-        tail = jnp.zeros((B, cfg.conv_taps - 1, d), h.dtype)
-    with jax.named_scope("conv_in"):
-        p = h @ lp["conv_in"]
-        z = (p[..., :d].astype(f32) * p[..., 2 * d:].astype(f32)
-             ).astype(h.dtype)
-    with jax.named_scope("conv_taps"):
-        c, tail = _causal_taps(lp["conv_w"], lp.get("conv_b"), z, tail,
-                               seq_mask.sum(1))
-    with jax.named_scope("conv_out"):
-        gated = (p[..., d:2 * d].astype(f32) * c).astype(h.dtype)
-        out = gated @ lp["conv_out"]
-    return out, tail
-
-
-def conv_step_path(cfg: TransformerConfig) -> Optional[str]:
-    """How a decode tick advances the "conv" layers' tails: ``"plain"`` (the
-    three-term sum and the shift, fused by the compiler: a slot's tail is
-    two rows, read once and written once), ``None`` for a model with no such
-    layer.  The serving executor reports it (``mesh_info()["conv_step"]``)
-    beside ``ssm_step`` / ``delta_step``."""
-    return "plain" if is_conv(cfg) else None
-
-
-# passes over a live slot's tail a layer a tick
-CONV_STATE_PASSES = {"plain": 1}
+    """Chunks of its mixer's ``chunk`` positions the scan of a block of
+    ``block`` tokens runs, or those of them that hold one of its ``tokens``
+    real ones (the ``scan_chunks`` span attrs of a prompt).  None for a model
+    whose prompts run no scan (no state a slot, or a tail alone)."""
+    for m in mixers_of(cfg):
+        if m.chunk:
+            return -(-(block if tokens is None else min(tokens, block))
+                     // getattr(cfg, m.chunk))
+    return None
 
 
 def _mixer_of(cfg: TransformerConfig):
-    """The mixer a layer of the uniform stack ``cfg`` runs over a block that
-    starts its sequences (:func:`_block`'s ``ssm``), or None."""
-    if is_conv(cfg):
-        return functools.partial(_conv_mixer, cfg)
-    if is_delta(cfg):
-        return functools.partial(_delta_mixer, cfg)
-    return functools.partial(_ssm_mixer, cfg) if is_ssm(cfg) else None
+    """:func:`_block`'s ``ssm`` over a block that starts its sequences."""
+    has = mixers_of(cfg)
+    return functools.partial(has[0].mixer, cfg) if has else None
 
 
 def _dropout(cfg: TransformerConfig, y, rng, deterministic: bool):
@@ -3429,21 +2571,10 @@ KV_QUANT_DTYPES = ("int8",)
 # window layers' rings, each leaf with its kind's KV heads and its own width.
 # A latent-attention model (``kv_lora_rank``) keeps ONE leaf, ``latent``:
 # a token's normed latent and its shared rotated key row, no head axis.
-# A model with state-space layers (``ssm_heads``) keeps, beside ``k``/``v``,
-# two leaves with NO page axis, a row a slot: ``ssm_state`` and ``ssm_conv``.
-# A prompt's block reads and writes its slot's rows around :func:`_ssm_scan`;
-# a decode tick on a TPU hands ``ssm_state`` itself to the one-pass kernel,
-# which updates this layer's rows where they lie (:func:`ssm_step_path`), and
-# any other single token cuts its rows out for :func:`_ssm_step`.
-# A model with delta layers keeps ``delta_state`` and ``delta_conv`` the same
-# way for its "linear" layers (:func:`_delta_paged`); one with gated
-# convolutions ONE leaf, ``conv_tail``, for its "conv" layers
-# (:func:`_conv_paged`): the tail is all the state there is.
-SSM_POOL_KEYS = ("ssm_state", "ssm_conv")
-DELTA_POOL_KEYS = ("delta_state", "delta_conv")
-CONV_POOL_KEYS = ("conv_tail",)
-# a row a slot, no pages
-STATE_POOL_KEYS = CONV_POOL_KEYS + DELTA_POOL_KEYS + SSM_POOL_KEYS
+# A model with a mixer of ``MIXERS`` keeps, beside ``k``/``v``, that row's
+# ``pool_keys``: leaves with NO page axis, a row a slot (a state and its
+# convolution's tail, or the tail alone; :func:`~.mixers.paged`).
+STATE_POOL_KEYS = tuple(k for m in MIXERS.values() for k in m.pool_keys)
 PAGED_POOL_KEYS = ("k", "v", "k_scale", "v_scale", "k_window", "v_window",
                    "latent") + STATE_POOL_KEYS
 
@@ -3472,15 +2603,6 @@ def _normalize_kv_dtype(kv_dtype):
     return name
 
 
-def kv_leaf_head_major(cfg: TransformerConfig, width: int) -> bool:
-    """Whether the ``k`` or ``v`` leaf of a one-pool model is kept ``[L, P,
-    Hkv, page, width]`` (:func:`pool_leaf_head_major`'s reason).  Only a
-    model with state-space layers: every mover that addresses a pool by page
-    row (COW, tiering, the int8 scales, heads sharded over chips) refuses
-    such a model, so none of them has to know the second order."""
-    return is_ssm(cfg) and pool_leaf_head_major(cfg.kv_heads, width)
-
-
 # what a kind of layer's K/V leaves add to ``k``/``v`` in the cache's keys
 # (an "ssm", "linear" or "conv" layer has none: its leaves are
 # STATE_POOL_KEYS)
@@ -3489,12 +2611,16 @@ _KIND_SUFFIX = {"full": "", "window": "_window"}
 
 def _head_major_leaves(cfg: TransformerConfig) -> Dict[str, bool]:
     """Which K/V leaves of ``cfg``'s cache are kept head-major, by the
-    cache's own keys: ``k``/``v`` (:func:`kv_leaf_head_major`), and of a model
-    with two kinds of layer each kind's (``k_window``/``v_window`` the window
-    layers': :func:`pool_leaf_head_major`)."""
+    cache's own keys (:func:`pool_leaf_head_major`): of a model with two
+    kinds of layer each kind's (``k_window``/``v_window`` the window layers'),
+    of a one-pool model ``k``/``v`` where it keeps a state a slot and no
+    other: every mover that addresses a pool by page row (COW, tiering, the
+    int8 scales, heads sharded over chips) refuses such a model, so none of
+    them has to know the second order."""
     if not is_hybrid(cfg):
-        return {n: kv_leaf_head_major(cfg, w) for n, w in (
-            ("k", cfg.dims_per_head), ("v", cfg.v_dims_per_head))}
+        return {n: has_state(cfg) and pool_leaf_head_major(cfg.kv_heads, w)
+                for n, w in (("k", cfg.dims_per_head),
+                             ("v", cfg.v_dims_per_head))}
     return {n + _KIND_SUFFIX[kind]: pool_leaf_head_major(g.kv_heads, w)
             for kind, (g, _) in kind_layers(cfg).items()
             if kind in _KIND_SUFFIX
@@ -3582,44 +2708,6 @@ def kv_read_paths(cfg: TransformerConfig, cache: Dict[str, Any],
     return paths
 
 
-def _delta_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
-                  ) -> Dict[str, Any]:
-    """The two slot-indexed leaves of ``layers`` delta layers: the float32
-    matrix states, ``pack`` heads' value columns a row
-    (:func:`delta_state_pack`), and the three convolutions' tail, a slot's
-    ``taps - 1`` inputs side by side in ONE row (kept ``[.., 3, 11520]`` the
-    3 pads to a tile of 16 sublanes, 5.3 x the bytes, and every layer of a
-    prompt re-lays the leaf out around its update: 39 ms a prompt on the
-    v5e, PERF.md PR 51)."""
-    p = delta_pack(cfg)
-    return {"delta_state": jnp.zeros(
-                (layers, slots, cfg.linear_heads // p, cfg.linear_key_dim,
-                 p * cfg.linear_value_dim), jnp.float32),
-            "delta_conv": jnp.zeros(
-                (layers, slots,
-                 (cfg.linear_conv - 1) * delta_widths(cfg)[2]), dtype)}
-
-
-def _conv_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
-                 ) -> Dict[str, Any]:
-    """The one slot-indexed leaf of ``layers`` conv layers: a slot's ``taps -
-    1`` rows of z side by side in ONE row (2 x 2,048 for LFM2), as
-    ``delta_conv`` is kept and for its reason (:func:`_delta_leaves`)."""
-    return {"conv_tail": jnp.zeros(
-        (layers, slots, (cfg.conv_taps - 1) * cfg.hidden_size), dtype)}
-
-
-def _state_leaves(cfg: TransformerConfig, layers: int, slots: int, dtype
-                  ) -> Dict[str, Any]:
-    """The two slot-indexed leaves of ``layers`` layers with a mixer: the
-    float32 state and the convolution's tail, a row a slot."""
-    return {"ssm_state": jnp.zeros(
-                (layers, slots, cfg.ssm_heads, cfg.ssm_head_dim,
-                 cfg.ssm_state), jnp.float32),
-            "ssm_conv": jnp.zeros(
-                (layers, slots, cfg.ssm_conv - 1, ssm_widths(cfg)[1]), dtype)}
-
-
 def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                      page_size: int = PAGE_SIZE, dtype=None,
                      kv_dtype=None, window_pages: Optional[int] = None,
@@ -3667,12 +2755,16 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
     if ((is_hybrid(cfg) or is_latent(cfg) or has_state(cfg))
             and _normalize_kv_dtype(kv_dtype) is not None):
         _hybrid_refuse("the int8 pool", cfg)
-    if is_hybrid(cfg):
+    if is_hybrid(cfg) or has_state(cfg):
         # a pool per kind of layer: the full layers' ``k``/``v`` over
         # ``num_pages`` pages, the window layers' ``k_window``/``v_window``
         # over ``window_pages`` (each slot a ring of
-        # :func:`window_ring_pages`; page 0 the trash page of its own pool)
-        kinds = kind_layers(cfg)
+        # :func:`window_ring_pages`; page 0 the trash page of its own pool);
+        # a mixer beside attention in every layer (no pattern): both kinds'
+        # leaves over every layer
+        kinds = (kind_layers(cfg) if is_hybrid(cfg) else
+                 {kind: (cfg, cfg.num_layers)
+                  for kind in ("full", mixers_of(cfg)[0].kind)})
         cache = {}
         for kind, suffix, pages in (
                 ("full", "", num_pages),
@@ -3687,26 +2779,15 @@ def init_paged_cache(cfg: TransformerConfig, num_pages: int,
                             else (page_size, g.kv_heads))
                     cache[n + suffix] = jnp.zeros(
                         (layers, pages) + rows + (w,), dtype)
-        if "ssm" in kinds:
-            # the state leaves cover the "ssm" layers and no other
-            cache.update(_state_leaves(*kinds["ssm"], slots, dtype))
-        if "linear" in kinds:
-            cache.update(_delta_leaves(*kinds["linear"], slots, dtype))
-        if "conv" in kinds:
-            cache.update(_conv_leaves(*kinds["conv"], slots, dtype))
+        for kind in kinds:
+            if kind in MIXERS:
+                # the state leaves cover the kind's layers and no other
+                cache.update(MIXERS[kind].leaves(*kinds[kind], slots, dtype))
         return cache
     if is_latent(cfg):
         return {"latent": jnp.zeros(
             (cfg.num_layers, num_pages, page_size,
              cfg.kv_lora_rank + cfg.rotary_dim), dtype)}
-    if is_ssm(cfg):
-        def leaf(w):
-            rows = ((cfg.kv_heads, page_size) if kv_leaf_head_major(cfg, w)
-                    else (page_size, cfg.kv_heads))
-            return jnp.zeros((cfg.num_layers, num_pages) + rows + (w,), dtype)
-
-        return {"k": leaf(cfg.dims_per_head), "v": leaf(cfg.v_dims_per_head),
-                **_state_leaves(cfg, cfg.num_layers, slots, dtype)}
     lead = (cache_depth(cfg), num_pages, page_size, cfg.kv_heads)
     kv = lead + (cfg.dims_per_head,)
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -3727,9 +2808,7 @@ def paged_cache_specs(cfg: TransformerConfig, kv_dtype=None) -> Dict[str, P]:
     if is_latent(cfg):      # no head axis: every chip holds whole rows
         return {"latent": P(None, None, None, None)}
     if has_state(cfg):  # whole on one chip: sharded serving refuses it
-        return {k: P() for k in ("k", "v") + (
-            CONV_POOL_KEYS if is_conv(cfg) else
-            DELTA_POOL_KEYS if is_delta(cfg) else SSM_POOL_KEYS)}
+        return {k: P() for k in ("k", "v") + mixers_of(cfg)[0].pool_keys}
     if is_hybrid(cfg):
         return {"k": kv, "v": kv, "k_window": kv, "v_window": kv}
     if _normalize_kv_dtype(kv_dtype) is None:
@@ -4056,7 +3135,7 @@ def kv_read_path(pools, pool_order, query, tokens: int = 1,
     ``MIN_BLOCK_BYTES`` (the smallest the chip has read: a model of fewer KV
     heads was not measured), a ``plain`` softmax (no ALiBi, no window's
     lower bound, no sink), in a program that may hold a Pallas kernel
-    (:func:`_pallas_interpret`: a TPU, one device); ``"gather"``
+    (``mixers.common._pallas_interpret``: a TPU, one device); ``"gather"``
     (:func:`_attention_paged`'s loop: a step's pages copied out of the pool,
     then attended) for every other read: a prompt or a verify block, a
     quantised pool, a leaf stored page-rows-minor (a 64-wide or 192-wide head
@@ -4068,7 +3147,7 @@ def kv_read_path(pools, pool_order, query, tokens: int = 1,
                                          page_block, resident_bytes)
 
     if (tokens != 1 or not plain or set(pools) != {"k", "v"}
-            or _pallas_interpret() is None
+            or _common._pallas_interpret() is None
             or not pools["k"].dtype == pools["v"].dtype == query.dtype
             or resident_bytes(*query.shape, pools["k"].shape[3],
                               pools["v"].shape[3]) > RESIDENT_BYTES):
@@ -4099,7 +3178,7 @@ def _attention_pages(cfg, q, views, axes, read):
             q[:, 0], views["k"], views["v"],
             jnp.sum(slot < B, dtype=jnp.int32), slot, pages.reshape(-1),
             limit.reshape(-1), axes=axes["k"], scale=_sm_scale(cfg, hd),
-            interpret=_pallas_interpret())
+            interpret=_common._pallas_interpret())
     # a slot that was not read has l == 0: its output is 0, not NaN
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
     return out.astype(q.dtype)[:, None]
@@ -4147,7 +3226,7 @@ def kv_write_path(leaf, order, tokens: int = 1) -> str:
     ``"row"`` (``ops/pallas/kv_row_write.py``: one row a slot stored where it
     lies) for one token a slot into a row-major K or V leaf whose shape the
     kernel's tile plan takes, in a program that may hold a Pallas kernel
-    (:func:`_pallas_interpret`); ``"page"`` (:func:`_merge_pages`: each
+    (``mixers.common._pallas_interpret``); ``"page"`` (:func:`_merge_pages`: each
     slot's pages gathered, merged and scattered back whole) for every other
     write: a prompt or a verify block, the scale planes of a quantized pool
     and its int8 rows, the latent leaf, a leaf stored page-rows-minor or kept
@@ -4158,7 +3237,8 @@ def kv_write_path(leaf, order, tokens: int = 1) -> str:
     observe; the serving executor reports it (``mesh_info()["kv_write"]``)."""
     from ..ops.pallas.kv_row_write import row_block
 
-    if (tokens == 1 and order is None and _pallas_interpret() is not None
+    if (tokens == 1 and order is None
+            and _common._pallas_interpret() is not None
             and row_block(leaf.shape, leaf.dtype) is not None):
         return "row"
     return "page"
@@ -4350,7 +3430,7 @@ def _attend_paged(cfg, pools, write, read, pool_order=None, sink=None,
 
             return kv_row_write(pool, rows[:, 0].astype(pool.dtype),
                                 write[2][:, 0], write[3],
-                                interpret=_pallas_interpret())
+                                interpret=_common._pallas_interpret())
         return _merge_pages(pool, rows, write)
 
     def attend(q, k, v):
@@ -4685,26 +3765,8 @@ def cache_kind(cfg: TransformerConfig) -> Tuple[str, str]:
     which model it is, and why a page of it cannot be shared, parked,
     rescaled or split by head.  Written once, for :func:`_hybrid_refuse`
     and the serving engine's refusals (``inference/cache_layout.py``)."""
-    if is_conv(cfg):
-        return "state", (
-            "gated short-convolution layers (a convolution's tail a slot): "
-            "a slot's tail is a row that no page holds, so a page copied, "
-            "parked, rescaled or split by head leaves it behind, and there "
-            "is nothing to start a tail from or to go back to")
-    if is_delta(cfg):
-        return "state", (
-            "gated-delta-rule layers (a matrix state a head a slot): a "
-            "slot's state is one tensor that no page holds, so a page "
-            "copied, parked, rescaled or split by head leaves it behind, "
-            "and there is nothing to start a tail from or to go back to")
-    if is_ssm(cfg):
-        # beside attention in every layer, or in its place in some
-        # (layer_pattern): the leaves differ in depth, the reason does not
-        return "state", (
-            "state-space layers (a state a slot): a slot's state is one "
-            "tensor that no page holds, so a page copied, parked, rescaled "
-            "or split by head leaves it behind, and there is nothing to "
-            "start a tail from or to go back to")
+    if has_state(cfg):
+        return "state", mixers_of(cfg)[0].keeps
     if is_hybrid(cfg):
         return "window", (
             "window and full attention layers (layer_pattern): a window "
@@ -4759,10 +3821,11 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     slot reads the ring through the window (:func:`_ring_read_plan`).  A
     longer block must start its slot (the engine refuses what would start
     one elsewhere: prefix sharing, speculation) and attends inside itself in
-    both kinds of layer, writing its K/V for the tokens to come.  An "ssm"
-    layer has no pages: its two slot-indexed leaves, ``[layers of the kind
-    * slots, ...]`` stacked, are read and written where they lie
-    (:func:`_ssm_paged`; ``state_slot`` as :func:`forward_paged`'s)."""
+    both kinds of layer, writing its K/V for the tokens to come.  A layer
+    of a kind of :data:`~.mixers.MIXERS` has no pages: its slot-indexed
+    leaves, ``[layers of the kind * slots, ...]`` stacked, are read and
+    written where they lie (:func:`~.mixers.paged`; ``state_slot`` as
+    :func:`forward_paged`'s)."""
     full_table, ring_table = (page_table if isinstance(page_table,
                                                        (tuple, list))
                               else (page_table, None))
@@ -4784,15 +3847,13 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
              for kind in kind_cfg if kind in suffix}
     ps = next(iter(pools.values()))["k"].shape[1]
     slots = 0
-    # a kind with a state a slot: its leaves stacked, and its mixer
-    stateful = {"ssm": (SSM_POOL_KEYS, _ssm_paged),
-                "linear": (DELTA_POOL_KEYS, _delta_paged),
-                "conv": (CONV_POOL_KEYS, _conv_paged)}
-    for kind, (keys, _) in stateful.items():
-        if kind in kind_cfg:
-            slots = cache[keys[0]].shape[1]
-            pools[kind] = {n: cache[n].reshape(-1, *cache[n].shape[2:])
-                           for n in keys}
+    # a kind with a state a slot: its leaves stacked
+    stateful = [kind for kind in MIXERS if kind in kind_cfg]
+    for kind in stateful:
+        keys = MIXERS[kind].pool_keys
+        slots = cache[keys[0]].shape[1]
+        pools[kind] = {n: cache[n].reshape(-1, *cache[n].shape[2:])
+                       for n in keys}
     W = cfg.window_size
     R = window_ring_pages(W, ps)
     if ring_table is None:
@@ -4844,8 +3905,8 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
         seen[kind] += 1
         attend = mixer = None
         if kind in stateful:
-            mixer = stateful[kind][1](g, pools[kind], layer * slots,
-                                      state_slot, start, seq_mask)
+            mixer = mixers.paged(MIXERS[kind], g, pools[kind],
+                                 layer * slots, state_slot, start, seq_mask)
         else:
             first_page = layer * n_pages[kind]
             write, read = plans[kind]
@@ -4969,7 +4030,7 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
         return _forward_paged_hybrid(cfg, params, tokens, cache, page_table,
                                      start, seq_mask, expert_counts,
                                      pool_order, logits_at, state_slot)
-    # a K/V leaf kept head-major (kv_leaf_head_major) is seen through the
+    # a K/V leaf kept head-major (_head_major_leaves) is seen through the
     # transpose that moves nothing, as a two-kind model's (stacked below)
     head_major = _head_major_leaves(cfg)
     lead = cache["latent" if is_latent(cfg) else "k"]
@@ -5004,10 +4065,14 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     if any(head_major.values()):
         pool_order = {n: _seen_order(head_major, pool_order, n)
                       for n in ("k", "v")}
-    # a state-space model's slot rows: ``slots`` a layer, the batch's at
-    # ``state_slot`` (None: rows 0 .. B - 1)
-    ssm = ((cache["ssm_state"].shape[1], state_slot, start)
-           if is_ssm(cfg) else None)
+    # the slot rows of a model with a mixer in every layer: ``slots`` a
+    # layer, the batch's at ``state_slot`` (None: rows 0 .. B - 1)
+    ssm = None
+    if has_state(cfg):
+        row = mixers_of(cfg)[0]
+        slots = cache[row.pool_keys[0]].shape[1]
+        ssm = lambda pools, layer: mixers.paged(  # noqa: E731
+            row, cfg, pools, layer * slots, state_slot, start, seq_mask)
     # one scan a group of equal layers, in the published order: the whole
     # model, or a model's leading dense layers and then its expert layers
     groups = ({name: (g, n, params["layers"][name])
@@ -5037,133 +4102,6 @@ def forward_paged(cfg: TransformerConfig, params: Dict[str, Any],
     return (logits, cache, counts) if expert_counts else (logits, cache)
 
 
-def _slot_rows(row0, state_slot, B: int):
-    """``(take, put)`` over a stacked slot-indexed leaf ``[L * slots, ...]``
-    for a batch of ``B`` rows of the layer whose rows start at ``row0``:
-    the block ``row0 .. row0 + B - 1`` where it lies (``state_slot`` None),
-    else the rows ``state_slot [B]`` names."""
-    if state_slot is None:
-        def take(a):
-            return jax.lax.dynamic_slice_in_dim(a, row0, B, axis=0)
-
-        def put(a, new):
-            return jax.lax.dynamic_update_slice_in_dim(
-                a, new.astype(a.dtype), row0, axis=0)
-    else:
-        rows = row0 + state_slot
-
-        def take(a):
-            return a[rows]
-
-        def put(a, new):
-            return a.at[rows].set(new.astype(a.dtype))
-    return take, put
-
-
-def _slot_rows_in_place(row0, state_slot, B: int):
-    """:func:`_slot_rows`, one named row (a prompt's) as a slice and an
-    update where the row lies: a scatter into the leaf makes the compiler
-    keep a version of it a layer."""
-    if state_slot is not None and B == 1:
-        return _slot_rows(row0 + state_slot[0], None, 1)
-    return _slot_rows(row0, state_slot, B)
-
-
-def _ssm_paged(cfg, pools, row0, state_slot, start, seq_mask):
-    """:func:`_block`'s ``ssm`` against the cache's two slot-indexed leaves,
-    stacked ``[L * slots, ...]`` with this layer's rows from ``row0`` on:
-    the batch's rows are taken (``state_slot`` None: the block ``row0 ..
-    row0 + B - 1`` where it lies; else the rows it names), a row that
-    starts its sequence begins from zeros, :func:`_ssm_mixer` advances
-    them, and they are written back where they were.  What is kept is the
-    two leaves.
-
-    The state of a decode tick on a TPU is never taken: where
-    :func:`ssm_step_path` says ``"one_pass"`` the mixer's step is
-    :func:`_ssm_step_one_pass` over the ``ssm_state`` leaf itself, one read
-    and one write of each row.  Every other block (a prompt's scan, named
-    rows, another backend, a shape the kernel refuses) takes and puts its
-    rows around :func:`_ssm_scan` / :func:`_ssm_step` as before."""
-    fresh = (start == 0) & seq_mask.any(axis=1)
-    take, put = _slot_rows(row0, state_slot, seq_mask.shape[0])
-    one_pass = ssm_step_path(cfg, seq_mask.shape[1], state_slot,
-                             pools["ssm_state"].dtype) == "one_pass"
-
-    def ssm(lp, h):
-        tail = jnp.where(fresh[:, None, None], 0, take(pools["ssm_conv"]))
-        if one_pass:
-            out, (state, tail) = _ssm_mixer(
-                cfg, lp, h, seq_mask, (pools["ssm_state"], tail),
-                functools.partial(_ssm_step_one_pass, row0=row0, fresh=fresh))
-        else:
-            # the recurrence is float32 whatever the leaf is kept in
-            state = take(pools["ssm_state"]).astype(jnp.float32)
-            state = jnp.where(fresh[:, None, None, None], 0.0, state)
-            out, (state, tail) = _ssm_mixer(cfg, lp, h, seq_mask,
-                                            (state, tail))
-            state = put(pools["ssm_state"], state)
-        return out, {"ssm_state": state,
-                     "ssm_conv": put(pools["ssm_conv"], tail)}
-    return ssm
-
-
-def _delta_paged(cfg, pools, row0, state_slot, start, seq_mask):
-    """:func:`_ssm_paged` for a delta layer: :func:`_block`'s ``ssm`` against
-    the cache's ``delta_state`` / ``delta_conv`` leaves, stacked ``[L *
-    slots, ...]`` with this layer's rows from ``row0`` on.  Where
-    :func:`delta_step_path` says ``"one_pass"`` (a decode tick on a TPU) the
-    state is never taken: :func:`_delta_step_one_pass` updates the leaf's
-    rows where they lie.  Every other block takes its rows as ``[B,H,dk,dv]``
-    (:func:`delta_state_heads`) around :func:`_delta_scan` /
-    :func:`_delta_step` and packs them back."""
-    B = seq_mask.shape[0]
-    fresh = (start == 0) & seq_mask.any(axis=1)
-    take, put = _slot_rows_in_place(row0, state_slot, B)
-    one_pass = delta_step_path(cfg, seq_mask.shape[1], state_slot,
-                               pools["delta_state"].dtype) == "one_pass"
-
-    def mixer(lp, h):
-        # the tail leaf keeps a slot's inputs in one row
-        tail = jnp.where(fresh[:, None, None], 0, take(
-            pools["delta_conv"]).reshape(B, cfg.linear_conv - 1, -1))
-        if one_pass:
-            out, (state, tail) = _delta_mixer(
-                cfg, lp, h, seq_mask, (pools["delta_state"], tail),
-                functools.partial(_delta_step_one_pass, row0=row0,
-                                  fresh=fresh))
-        else:
-            # the recurrence is float32 whatever the leaf is kept in
-            state = delta_state_heads(
-                cfg, take(pools["delta_state"]).astype(jnp.float32))
-            state = jnp.where(fresh[:, None, None, None], 0.0, state)
-            out, (state, tail) = _delta_mixer(cfg, lp, h, seq_mask,
-                                              (state, tail))
-            state = put(pools["delta_state"], delta_state_pack(cfg, state))
-        return out, {"delta_state": state, "delta_conv": put(
-            pools["delta_conv"], tail.reshape(B, -1))}
-    return mixer
-
-
-def _conv_paged(cfg, pools, row0, state_slot, start, seq_mask):
-    """:func:`_ssm_paged` for a conv layer: :func:`_block`'s ``ssm`` against
-    the cache's one ``conv_tail`` leaf, stacked ``[L * slots, (taps - 1) *
-    d]`` with this layer's rows from ``row0`` on.  The batch's rows are
-    taken (:func:`_slot_rows_in_place`), a row that starts its sequence
-    begins from zeros, :func:`_conv_mixer` advances them, and they are put
-    back where they were."""
-    B = seq_mask.shape[0]
-    fresh = (start == 0) & seq_mask.any(axis=1)
-    take, put = _slot_rows_in_place(row0, state_slot, B)
-
-    def mixer(lp, h):
-        tail = jnp.where(fresh[:, None, None], 0, take(
-            pools["conv_tail"]).reshape(B, cfg.conv_taps - 1, -1))
-        out, tail = _conv_mixer(cfg, lp, h, seq_mask, tail)
-        return out, {"conv_tail": put(pools["conv_tail"],
-                                      tail.reshape(B, -1))}
-    return mixer
-
-
 def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
                   positions, seq_mask, write, read, within, pool_order,
                   adapters, ssm=None, pool_first=None):
@@ -5178,9 +4116,8 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
     pool layer that weight layer 0 reads and writes in this call, and layer
     ``l``'s pages lie at ``(pool_first + l) * num_pages``.  None: the two
     are one.
-    ``ssm = (slots, state_slot, start)``: the model has state-space layers,
-    whose two slot-indexed leaves ride the carry with the pages
-    (:func:`_ssm_paged`)."""
+    ``ssm(pools, layer)``: the layers have a mixer, whose slot-indexed leaves
+    ride the carry with the pages (:func:`~.mixers.paged` at this layer)."""
     rng = jax.random.PRNGKey(0)
     ad_scale = (adapters["scale"].astype(jnp.float32)
                 if adapters is not None else None)
@@ -5208,11 +4145,7 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
                   if is_latent(cfg) else
                   _attend_paged(cfg, kv, wplan, rplan, pool_order))
         layer = first_page // num_pages
-        mixer = None
-        if ssm is not None:
-            slots, state_slot, start = ssm
-            mixer = _ssm_paged(cfg, pools, layer * slots, state_slot, start,
-                               seq_mask)
+        mixer = None if ssm is None else ssm(pools, layer)
         x, _, counts, kept = _block(
             cfg, {**lp, **experts}, x, positions, rng, attend,
             proj=_adapter_proj(factors, ad_scale), token_mask=seq_mask,

@@ -19,7 +19,7 @@ to 256 lanes in memory and on the way through: +33% bytes a tick.  Kept
 ``[.., heads, 18,432]`` nothing pads, but a row of the matrix then straddles
 the 128-lane tiles and neither product is a plain reduction.  The leaf is
 kept ``[.., heads / 2, 96, 384]``: TWO heads' value columns side by side in
-one row (``models.transformer.delta_pack``), 384 = 3 x 128 lanes, nothing
+one row (``models.mixers.delta.delta_pack``), 384 = 3 x 128 lanes, nothing
 padded, and every operation below is a row-wise one over both heads at once
 (each head's key is laid over its own 192 lanes by a select).  This packed
 form is the one measured on the chip (PERF.md, PR 51); the padded and the

@@ -389,14 +389,16 @@ def test_state_decode_tick_holds_one_kernel_over_the_leaf_on_v5e(
 
     from deepspeed_tpu.models import CausalLM, get_config, init_params
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
+    from deepspeed_tpu.models.mixers import ssm as SSM
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     depth, slots = 2, 8
     cfg = get_config("falcon-h1-34b", num_layers=depth, vocab_size=16384)
-    assert T.ssm_step_path(cfg) == "one_pass"
+    assert SSM.ssm_step_path(cfg) == "one_pass"
     params = jax.tree_util.tree_map(
         lambda a: S(a.shape, jnp.bfloat16),
         jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
@@ -471,11 +473,12 @@ def test_looped_decode_tick_writes_its_rows_where_they_lie_on_v5e(
 
     from deepspeed_tpu.models import CausalLM, get_config, init_params
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     depth, slots, pages = 2, 16, 40
     cfg = get_config("ouro-2.6b", num_layers=depth)
     params = jax.tree_util.tree_map(
@@ -636,10 +639,11 @@ def test_latent_tick_is_the_same_program_under_the_tpus_rule(one_v5e_chip,
     compiler drops it), so its temporaries and its leaf-sized ops are the
     page merge's."""
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
 
-    assert T._pallas_interpret() is None        # the CPU's answer
+    assert MX._pallas_interpret() is None        # the CPU's answer
     merge = _latent_tick(one_v5e_chip)[0]
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     on_tpu = _latent_tick(one_v5e_chip)[0]
     assert "kv_row_write" not in on_tpu.as_text()
     assert (_without_source_locations(on_tpu.as_text())
@@ -885,9 +889,10 @@ def test_a_prompts_expert_products_by_the_depth_of_its_groups(
     from benchmark.lib import system
     from deepspeed_tpu.models import get_config
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
     from deepspeed_tpu.moe.sharded_moe import KERNEL_ROWS_AN_EXPERT
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     rows, path, pinned = PREFILLS_AT_PR_48[program]
     if program.startswith("mimo"):
         with open(os.path.join(REPO, "benchmark", "configs",
@@ -932,8 +937,9 @@ def test_a_page_rows_minor_pool_keeps_the_page_merge_on_a_tpu(one_v5e_chip,
     and the program is still the parent of PR 30's, instruction for
     instruction."""
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     digest, text = _program_hash(one_v5e_chip, "opt-1.3b_decode")
     assert "tpu_custom_call" not in text
     assert digest == PROGRAMS_AT_PR_29["opt-1.3b_decode"]
@@ -1052,11 +1058,12 @@ def test_mamba_or_attention_layers_fit_the_chip_at_the_cells_size(
     from benchmark.lib import system
     from deepspeed_tpu.models import CausalLM, init_params
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     with open(os.path.join(REPO, "benchmark", "configs",
                            "granite-4.0-h-small-ep2-d10.json")) as f:
         cfg = system.transformer_config(json.load(f), False)
@@ -1138,15 +1145,17 @@ def test_delta_or_attention_layers_fit_the_chip_at_the_cells_size(
     from benchmark.lib import system
     from deepspeed_tpu.models import CausalLM, init_params
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
+    from deepspeed_tpu.models.mixers import delta as DELTA
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     with open(os.path.join(REPO, "benchmark", "configs",
                            "olmo-hybrid-7b-d16.json")) as f:
         cfg = system.transformer_config(json.load(f), False)
-    assert T.delta_step_path(cfg) == "one_pass"
+    assert DELTA.delta_step_path(cfg) == "one_pass"
     slots, maxp = 32, 16
     params = jax.tree_util.tree_map(
         lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
@@ -1214,15 +1223,17 @@ def test_conv_or_attention_layers_fit_the_chip_at_the_cells_size(
     from benchmark.lib import system
     from deepspeed_tpu.models import CausalLM, init_params
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
+    from deepspeed_tpu.models.mixers import conv as CONV
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     with open(os.path.join(REPO, "benchmark", "configs",
                            "lfm2-8b-a1b-d14.json")) as f:
         cfg = system.transformer_config(json.load(f), False)
-    assert T.conv_step_path(cfg) == "plain"
+    assert CONV.conv_step_path(cfg) == "plain"
     slots, maxp = 128, 24
     params = jax.tree_util.tree_map(
         lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(

@@ -5,7 +5,6 @@ before 32 experts top 4) through the model and the serving engine: both
 against the plain reference, the terms a wrong build would leave out, the
 tail's leaf, admission's reset, an idle slot, the three groups in one walk,
 the span attrs, and the mechanisms that refuse such a model by name."""
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +15,10 @@ import deepspeed_tpu
 from benchmark.lib import reference_lfm2 as R
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models import mixers
+from deepspeed_tpu.models.mixers import conv as CONV
 
-from .test_ssm_serving import REFUSALS, _is_greedy, _requests, _tokens
+from .test_ssm_serving import _is_greedy, _requests, _tokens
 
 SERVE_KW = dict(b_slots=3, page_size=8, max_model_len=96)
 # float32 on both sides: what is left is the order of the sums (a masked
@@ -221,30 +222,6 @@ def test_layer_checks_pass_and_a_narrower_tail_fails_them(params):
     assert narrow["attention_operator"]["rel_err"] < F32_TOL
 
 
-def test_a_padded_bucket_leaves_the_tail_as_the_unpadded_prompt(params):
-    cfg = T.layer_groups(tiny())["conv_moe"][0]
-    lp = {k: v[0] for k, v in params["layers"]["conv_moe"].items()
-          if k.startswith("conv_")}
-    h = jnp.asarray(np.random.default_rng(2).standard_normal((1, 32, 64)),
-                    jnp.float32)
-    mixer = jax.jit(functools.partial(T._conv_mixer, cfg))
-    out, tail = mixer(lp, h[:, :21])
-    mask = (jnp.arange(32) < 21)[None]
-    out_p, tail_p = mixer(lp, h, mask)
-    np.testing.assert_allclose(out_p[:, :21], out, atol=1e-6)
-    np.testing.assert_array_equal(tail_p, tail)
-    assert tail.shape == (1, 2, 64)
-    # a row with no real token keeps what it had
-    kept = tail + 1.0
-    _, t2 = mixer(lp, h[:, :1], jnp.zeros((1, 1), bool), kept)
-    np.testing.assert_array_equal(t2, kept)
-    # one token behind a tail: the three-term sum, the tail shifted by one
-    out1, t1 = mixer(lp, h[:, 21:22], None, tail)
-    np.testing.assert_allclose(out1[:, 0], mixer(lp, h[:, :22])[0][:, 21],
-                               atol=1e-6)
-    np.testing.assert_array_equal(t1[:, 0], tail[:, 1])
-
-
 def test_a_bias_on_the_taps_joins_the_sum_before_the_gate_after():
     """``conv_bias`` (false in LFM2-8B-A1B, a key of the family's config):
     one more leaf, counted, added to the three-term sum."""
@@ -260,65 +237,11 @@ def test_a_bias_on_the_taps_joins_the_sum_before_the_gate_after():
           "conv_b": rng.standard_normal((64,)).astype(np.float32),
           "conv_out": np.eye(64, dtype=np.float32)}
     h = rng.standard_normal((1, 5, 64)).astype(np.float32)
-    out, _ = T._conv_mixer(g, lp, jnp.asarray(h))
+    out, _ = CONV._conv_mixer(g, lp, jnp.asarray(h))
     p = h[0] @ lp["conv_in"]
     z = np.concatenate([np.zeros((2, 64), np.float32), p[:, :64] * p[:, 128:]])
     c = sum(z[k:k + 5] * lp["conv_w"][k] for k in range(3)) + lp["conv_b"]
     np.testing.assert_allclose(out[0], p[:, 64:128] * c, atol=1e-5)
-
-
-def test_the_cache_has_one_leaf_with_no_page_axis():
-    cfg = tiny()
-    cache = T.init_paged_cache(cfg, 7, 8, dtype=jnp.float32, slots=3)
-    assert set(cache) == {"k", "v", "conv_tail"}
-    # a slot's two rows side by side in ONE row
-    assert cache["conv_tail"].shape == (8, 3, 2 * 64)
-    assert cache["k"].shape == (2, 7, 8, 2, 16)
-    assert T.cache_kind(cfg)[0] == "state" and T.cache_layers(cfg) == (2, 8)
-    assert set(T.CONV_POOL_KEYS) <= set(T.STATE_POOL_KEYS) <= set(
-        T.PAGED_POOL_KEYS)
-    wide = jax.eval_shape(lambda: T.init_paged_cache(
-        get_config("lfm2-8b-a1b", num_layers=14), 5, 128, slots=4))
-    assert wide["k"].shape == (3, 5, 128, 8, 64)        # 6,144 B a token
-    assert wide["conv_tail"].shape == (11, 4, 4096)     # 90,112 B a slot
-    assert T.ssm_scan_chunks(cfg, 64, 21) is None
-
-
-def test_tail_rows_follow_state_slot_and_start(params):
-    """Row b of the batch is tail row b unless ``state_slot`` says
-    otherwise; a start of 0 resets, any other continues; two slots of
-    different lengths advance side by side and an idle one keeps its
-    tail."""
-    cfg, toks = tiny(), _tokens(24, seed=3)
-    table = jnp.arange(1, 4, dtype=jnp.int32)[None]
-    cache = T.init_paged_cache(cfg, 4, 8, dtype=jnp.float32, slots=3)
-    dirty = dict(cache, conv_tail=cache["conv_tail"] + 5.0)
-    run = jax.jit(functools.partial(T.forward_paged, cfg, params))
-    _, a = run(toks[:, :16], dirty, table, jnp.zeros((1,), jnp.int32),
-               jnp.ones((1, 16), bool), state_slot=jnp.asarray([2]))
-    _, b = run(toks[:, :16], cache, table, jnp.zeros((1,), jnp.int32),
-               jnp.ones((1, 16), bool))
-    np.testing.assert_allclose(a["conv_tail"][:, 2], b["conv_tail"][:, 0],
-                               atol=1e-6)
-    np.testing.assert_array_equal(a["conv_tail"][:, :2],
-                                  dirty["conv_tail"][:, :2])
-    # a tick of three slots: slot 2 live behind its 16 rows, slot 0 live
-    # behind a tail nobody reset (its start is not 0), slot 1 idle
-    tables = jnp.zeros((3, 3), jnp.int32).at[2].set(table[0])
-    tick_tok = jnp.zeros((3, 1), jnp.int32).at[2, 0].set(toks[0, 16])
-    mask = jnp.asarray([[True], [False], [True]])
-    logits, c = run(tick_tok, a, tables, jnp.asarray([4, 9, 16], jnp.int32),
-                    mask)
-    np.testing.assert_array_equal(c["conv_tail"][:, 1], a["conv_tail"][:, 1])
-    assert float(jnp.abs(c["conv_tail"][:, 0] - a["conv_tail"][:, 0]).max()
-                 ) > 0
-    want = T.forward(cfg, params, toks[:, :17])[0, 16]
-    np.testing.assert_allclose(logits[2, 0], want, atol=2e-4)
-    # the first conv layer's tail of slot 2 is (z_15, z_16) of the sequence
-    _, whole = run(toks[:, :17], cache, table, jnp.zeros((1,), jnp.int32),
-                   jnp.ones((1, 17), bool))
-    np.testing.assert_allclose(c["conv_tail"][0, 2], whole["conv_tail"][0, 0],
-                               atol=1e-6)
 
 
 def test_engine_serves_token_for_token_and_a_reused_slot_starts_clean(engine):
@@ -385,42 +308,6 @@ def test_spans_carry_the_tail_and_name_no_scan(engine):
         assert a["pairs_held"] == a["tokens"] * 3 * 8
 
 
-@pytest.mark.parametrize("what", list(REFUSALS))
-def test_mechanisms_that_know_pages_alone_refuse_by_name(engine, what):
-    """``cache_layout.REFUSED``'s rows for the kind ``state``, every one
-    inherited."""
-    named, call = REFUSALS[what]
-    with pytest.raises(NotImplementedError,
-                       match=r"gated short-convolution layers \(a "
-                             r"convolution's tail a slot\)") as e:
-        call(engine)
-    assert named in str(e.value)
-
-
-def test_what_the_block_is_not_built_from_is_refused():
-    key = jax.random.PRNGKey(0)
-    for over, match in (
-            (dict(ssm_heads=4, ssm_head_dim=8, ssm_state=16),
-             "state-space layers"),
-            (dict(linear_heads=4, linear_key_dim=8, linear_value_dim=16),
-             "delta layers"),
-            (dict(layer_pattern=("conv", "window") * 5), "window, ssm or"),
-            (dict(layer_pattern=("conv",) * 10), "not of both kinds"),
-            (dict(attn_bias=True), "attn_bias"),
-            (dict(norm="layernorm"), "RMSNorm"),
-            (dict(norm_after=True), "sandwich_norm or norm_after"),
-            (dict(loop_passes=2), "loop_passes"),
-            (dict(num_experts=(1,) * 10), "per-layer expert counts")):
-        with pytest.raises(NotImplementedError, match=match):
-            T._check_conv(tiny(**over))
-    with pytest.raises(ValueError, match="conv_taps > 1"):
-        init_params(tiny(conv_taps=1), key)
-    with pytest.raises(ValueError, match="no gated convolution"):
-        T.layer_plan(tiny(conv_taps=0))
-    with pytest.raises(ValueError, match="full | window | ssm | linear | conv"):
-        T.layer_plan(tiny(layer_pattern=("conv", "mamba") * 5))
-
-
 def test_the_refusals_that_stay_for_the_other_state_kinds():
     """Leading dense layers before a state kind are built for "conv" and
     for no other; expert layers behind a delta layer are still refused; no
@@ -431,9 +318,9 @@ def test_the_refusals_that_stay_for_the_other_state_kinds():
         vocab_size=256, ssm_heads=4, ssm_head_dim=32, ssm_state=16,
         ssm_chunk=8, num_experts=4, moe_top_k=2, dtype=jnp.float32)
     with pytest.raises(NotImplementedError, match="leading dense layers"):
-        T._check_ssm(get_config(granite, dense_layers=1))
+        mixers.check(get_config(granite, dense_layers=1))
     with pytest.raises(NotImplementedError, match="conv layers in one"):
-        T._check_ssm(get_config(granite, layer_pattern=("ssm", "conv",
+        mixers.check(get_config(granite, layer_pattern=("ssm", "conv",
                                                         "full") * 2))
     olmo = get_config(
         "olmo-hybrid-7b", num_layers=8, hidden_size=64, intermediate_size=96,
@@ -441,12 +328,12 @@ def test_the_refusals_that_stay_for_the_other_state_kinds():
         linear_heads=4, linear_key_dim=8, linear_value_dim=64,
         linear_chunk=8, dtype=jnp.float32)
     with pytest.raises(NotImplementedError, match="leading dense layers"):
-        T._check_delta(get_config(olmo, dense_layers=1))
+        mixers.check(get_config(olmo, dense_layers=1))
     with pytest.raises(NotImplementedError, match="expert layers"):
-        T._check_delta(get_config(olmo, num_experts=4))
+        mixers.check(get_config(olmo, num_experts=4))
     with pytest.raises(NotImplementedError, match="conv layers in one"):
-        T._check_delta(get_config(olmo, layer_pattern=(
+        mixers.check(get_config(olmo, layer_pattern=(
             "linear", "conv", "linear", "full") * 2))
     # what is lifted: a dense group under a pattern with the tail's kind
-    assert T._check_conv(tiny()) is None
+    assert mixers.check(tiny()) is None
     assert tiny().dense_layers == 2

@@ -5,7 +5,6 @@ engine: the chunk form against the recurrence and both against the plain
 reference, the four terms a wrong build would leave out, a state kept in
 bfloat16, the cache's leaves, admission's reset, the decode lookahead, the
 span attrs, and the mechanisms that refuse such a model by name."""
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,8 +15,10 @@ import deepspeed_tpu
 from benchmark.lib import reference_olmo_hybrid as R
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import common as MX
+from deepspeed_tpu.models.mixers import delta as DELTA
 
-from .test_ssm_serving import (REFUSALS, _deadline_mix, _drive, _is_greedy,
+from .test_ssm_serving import (_deadline_mix, _drive, _is_greedy,
                                _requests, _tokens)
 
 SERVE_KW = dict(b_slots=3, page_size=8, max_model_len=96)
@@ -65,8 +66,8 @@ def test_the_named_base_is_the_published_model_and_counts_its_parameters():
     assert (cfg.position, cfg.qk_norm, cfg.norm_after,
             cfg.tie_embeddings) == ("none", True, True, False)
     assert cfg.layer_pattern == ("linear", "linear", "linear", "full") * 8
-    assert T.delta_widths(cfg) == (2880, 5760, 11520)
-    assert T.delta_in_width(cfg) == 17280
+    assert DELTA.delta_widths(cfg) == (2880, 5760, 11520)
+    assert DELTA.delta_in_width(cfg) == 17280
     assert T.cache_layers(cfg) == (8, 24)
     groups = T.layer_groups(cfg)
     assert list(groups) == ["linear_dense", "full_dense"]
@@ -102,7 +103,7 @@ def test_the_drawn_gates_cover_both_signs_and_decay(params):
     lp = {k: v[3] for k, v in params["layers"]["linear_dense"].items()}
     h = jnp.asarray(np.random.default_rng(0).standard_normal((1, 64, 64)),
                     jnp.float32)
-    _, _, b, a = T._delta_project(g, lp, h)
+    _, _, b, a = DELTA._delta_project(g, lp, h)
     beta = 2 * jax.nn.sigmoid(b)
     alpha = jnp.exp(-jnp.exp(lp["delta_A_log"])
                     * jax.nn.softplus(a + lp["delta_dt_bias"]))
@@ -195,10 +196,10 @@ def test_the_chunk_form_is_the_one_step_recurrence(length, carried):
     g = -jax.nn.softplus(draw(2, length, H))
     beta = 2 * jax.nn.sigmoid(draw(2, length, H))
     s0 = draw(2, H, dk, dv) if carried else jnp.zeros((2, H, dk, dv))
-    o, s = T._delta_scan(cfg, q, k, v, g, beta, s0)
+    o, s = DELTA._delta_scan(cfg, q, k, v, g, beta, s0)
     state, os_ = s0, []
     for t in range(length):
-        o_t, state = T._delta_step(
+        o_t, state = DELTA._delta_step(
             cfg, q[:, t:t + 1], k[:, t:t + 1], v[:, t:t + 1], g[:, t:t + 1],
             beta[:, t:t + 1], state)
         os_.append(o_t)
@@ -211,77 +212,10 @@ def test_the_substitution_inverts_a_matrix_whose_powers_overflow():
     ``A^32`` has entries past 1e20, and the inverse is +-2."""
     C = 64
     A = jnp.tril(jnp.full((C, C), 2.0), -1)
-    T_inv = T._unit_lower_inverse(A) + jnp.eye(C)
+    T_inv = DELTA._unit_lower_inverse(A) + jnp.eye(C)
     np.testing.assert_allclose(T_inv @ (jnp.eye(C) + A), jnp.eye(C),
                                atol=1e-4)
     assert float(jnp.abs(T_inv).max()) == 2.0
-
-
-def test_a_padded_bucket_leaves_state_and_tail_as_the_unpadded_prompt(params):
-    cfg = T.layer_groups(tiny())["linear_dense"][0]
-    lp = {k: v[0] for k, v in params["layers"]["linear_dense"].items()}
-    h = jnp.asarray(np.random.default_rng(2).standard_normal((1, 32, 64)),
-                    jnp.float32)
-    mixer = jax.jit(functools.partial(T._delta_mixer, cfg))
-    out, (state, tail) = mixer(lp, h[:, :21])
-    mask = (jnp.arange(32) < 21)[None]
-    out_p, (state_p, tail_p) = mixer(lp, h, mask)
-    np.testing.assert_allclose(out_p[:, :21], out, atol=1e-5)
-    np.testing.assert_allclose(state_p, state, atol=1e-5)
-    np.testing.assert_array_equal(tail_p, tail)
-    # a row with no real token keeps what it had
-    kept = (state + 1.0, tail + 1.0)
-    _, (s2, t2) = mixer(lp, h[:, :1], jnp.zeros((1, 1), bool), kept)
-    np.testing.assert_array_equal(s2, kept[0])
-    np.testing.assert_array_equal(t2, kept[1])
-
-
-def test_the_cache_has_two_leaves_with_no_page_axis():
-    cfg = tiny()
-    cache = T.init_paged_cache(cfg, 7, 8, dtype=jnp.float32, slots=3)
-    assert set(cache) == {"k", "v", "delta_state", "delta_conv"}
-    assert cache["delta_state"].shape == (6, 3, 2, 8, 128)
-    assert cache["delta_state"].dtype == jnp.float32
-    assert cache["delta_conv"].shape == (6, 3, 3 * (2 * 32 + 256))
-    assert cache["k"].shape == (2, 7, 8, 4, 16)      # 16 wide: row-major
-    assert T.cache_kind(cfg)[0] == "state" and T.cache_layers(cfg) == (2, 6)
-    assert set(T.DELTA_POOL_KEYS) <= set(T.PAGED_POOL_KEYS)
-    # 30 heads of 128 are no whole tiles of 8: the K/V leaves head-major
-    assert T.pool_leaf_head_major(30, 128) and not T.pool_leaf_head_major(16, 128)
-    wide = jax.eval_shape(lambda: T.init_paged_cache(
-        get_config("olmo-hybrid-7b", num_layers=4), 5, 128))
-    assert wide["k"].shape == (1, 5, 30, 128, 128)
-
-
-def test_state_rows_follow_state_slot_and_start(params):
-    """Row b of the batch is state row b unless ``state_slot`` says
-    otherwise; a start of 0 resets, any other continues."""
-    cfg, toks = tiny(), _tokens(24, seed=3)
-    table = jnp.arange(1, 4, dtype=jnp.int32)[None]
-    cache = T.init_paged_cache(cfg, 4, 8, dtype=jnp.float32, slots=3)
-    dirty = dict(cache, delta_state=cache["delta_state"] + 5.0,
-                 delta_conv=cache["delta_conv"] + 5.0)
-    run = jax.jit(functools.partial(T.forward_paged, cfg, params))
-    _, a = run(toks[:, :16], dirty, table, jnp.zeros((1,), jnp.int32),
-               jnp.ones((1, 16), bool), state_slot=jnp.asarray([2]))
-    _, b = run(toks[:, :16], cache, table, jnp.zeros((1,), jnp.int32),
-               jnp.ones((1, 16), bool))
-    np.testing.assert_allclose(a["delta_state"][:, 2], b["delta_state"][:, 0],
-                               atol=1e-6)
-    np.testing.assert_array_equal(a["delta_state"][:, :2],
-                                  dirty["delta_state"][:, :2])
-    # the next block continues the row: both halves = the whole, in the
-    # delta layers before the first attention layer (a block of more than
-    # one token attends within itself in a model walked by kind: the engine
-    # starts no block behind rows a slot already holds)
-    _, a2 = run(toks[:, 16:], a, table, jnp.full((1,), 16, jnp.int32),
-                jnp.ones((1, 8), bool), state_slot=jnp.asarray([2]))
-    _, whole = run(toks, cache, table, jnp.zeros((1,), jnp.int32),
-                   jnp.ones((1, 24), bool))
-    np.testing.assert_allclose(a2["delta_state"][:3, 2],
-                               whole["delta_state"][:3, 0], atol=1e-5)
-    np.testing.assert_allclose(a2["delta_conv"][:3, 2],
-                               whole["delta_conv"][:3, 0], atol=1e-6)
 
 
 def test_engine_serves_token_for_token_and_a_reused_slot_starts_clean(engine):
@@ -398,56 +332,10 @@ def test_the_one_pass_step_serves_token_for_token(engine, monkeypatch):
         return results
 
     plain = serve("plain", 3)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     one_pass = serve("one_pass", 1)
     assert one_pass == plain
     for q in _requests(9):
         assert len(one_pass[q.rid]) == q.max_new_tokens
         assert _is_greedy(cfg, engine.params, q.input_ids, one_pass[q.rid])
 
-
-@pytest.mark.parametrize("what", list(REFUSALS))
-def test_mechanisms_that_know_pages_alone_refuse_by_name(engine, what):
-    """``cache_layout.REFUSED``'s rows for the kind ``state``, every one
-    inherited."""
-    named, call = REFUSALS[what]
-    with pytest.raises(NotImplementedError,
-                       match=r"gated-delta-rule layers \(a matrix state a "
-                             r"head a slot\)") as e:
-        call(engine)
-    assert named in str(e.value)
-
-
-def test_what_the_block_is_not_built_from_is_refused():
-    key = jax.random.PRNGKey(0)
-    for over, match in (
-            (dict(num_experts=4), "expert layers"),
-            (dict(ssm_heads=4, ssm_head_dim=8, ssm_state=16),
-             "state-space layers"),
-            (dict(layer_pattern=("linear", "window") * 4), "window or ssm"),
-            (dict(layer_pattern=("linear",) * 8), "not of both kinds"),
-            (dict(attn_bias=True), "attn_bias"),
-            (dict(norm="layernorm"), "RMSNorm"),
-            (dict(loop_passes=2), "loop_passes")):
-        with pytest.raises(NotImplementedError, match=match):
-            init_params(tiny(**over), key)
-    with pytest.raises(ValueError, match="linear_key_dim"):
-        init_params(tiny(linear_key_dim=0), key)
-    with pytest.raises(ValueError, match="no delta mixer"):
-        T.layer_plan(tiny(linear_heads=0))
-    with pytest.raises(NotImplementedError, match="sandwich_norm"):
-        T._check_loop(tiny(sandwich_norm=True))
-    # QK-norm under a layer_pattern with state-space layers (R5 (f)): the
-    # attention layers' alone, as here
-    granite = get_config(
-        "granite-4.0-h-small", num_layers=6, hidden_size=64,
-        intermediate_size=24, num_heads=4, num_kv_heads=2, head_dim=16,
-        vocab_size=256, ssm_heads=4, ssm_head_dim=32, ssm_state=16,
-        ssm_chunk=8, num_experts=4, moe_top_k=2, qk_norm=True,
-        dtype=jnp.float32)
-    leaves = jax.eval_shape(lambda: init_params(granite, key))["layers"]
-    assert "q_norm_scale" in leaves["full_moe"]
-    assert "q_norm_scale" not in leaves["ssm_moe"]
-    assert granite.param_count == sum(
-        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
-            jax.eval_shape(lambda: init_params(granite, key))))

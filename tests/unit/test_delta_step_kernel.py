@@ -13,6 +13,9 @@ import pytest
 from deepspeed_tpu.inference.execution import MeshExecutor
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import MIXERS
+from deepspeed_tpu.models.mixers import common as MX
+from deepspeed_tpu.models.mixers import delta as DELTA
 from deepspeed_tpu.ops.pallas.delta_step import (BLOCK_BYTES, delta_step,
                                                  head_block)
 
@@ -66,15 +69,15 @@ def test_kernel_is_delta_step_on_the_rows_and_touches_no_other(case):
     fresh = jnp.asarray(np.isin(np.arange(SLOTS), kw.pop("fresh", ())))
     cfg = _cfg(**kw)
     state, q, k, v, g, beta = _inputs(cfg, 3, masked)
-    leaf = T.delta_state_pack(cfg, state)
-    p = T.delta_pack(cfg)
+    leaf = DELTA.delta_state_pack(cfg, state)
+    p = DELTA.delta_pack(cfg)
     assert leaf.shape == (3 * SLOTS, cfg.linear_heads // p,
                           cfg.linear_key_dim, p * cfg.linear_value_dim)
     assert leaf.shape[-1] % 128 == 0
-    assert np.array_equal(T.delta_state_heads(cfg, leaf), state)
+    assert np.array_equal(DELTA.delta_state_heads(cfg, leaf), state)
     row0 = layer * SLOTS
     before = state[row0:row0 + SLOTS]
-    o_ref, s_ref = T._delta_step(
+    o_ref, s_ref = DELTA._delta_step(
         cfg, q, k, v, g, beta,
         jnp.where(fresh[:, None, None, None], 0.0, before))
 
@@ -84,7 +87,7 @@ def test_kernel_is_delta_step_on_the_rows_and_touches_no_other(case):
                           q[:, 0], k[:, 0], v[:, 0], interpret=True)
 
     out, o = run(leaf, jnp.int32(row0))
-    out = np.asarray(T.delta_state_heads(cfg, out))
+    out = np.asarray(DELTA.delta_state_heads(cfg, out))
     # the same formula term for term: equal to float32 rounding (only the
     # order of the sums over the key axis is the implementation's)
     np.testing.assert_allclose(out[row0:row0 + SLOTS], np.asarray(s_ref),
@@ -108,7 +111,7 @@ def test_kernel_is_delta_step_on_the_rows_and_touches_no_other(case):
 
 def test_the_published_state_is_packed_without_padding():
     cfg = get_config("olmo-hybrid-7b")
-    assert T.delta_pack(cfg) == 2
+    assert DELTA.delta_pack(cfg) == 2
     shapes = jax.eval_shape(lambda: CausalLM(get_config(
         "olmo-hybrid-7b", num_layers=16)).init_paged_cache(
             513, 128, dtype=jnp.bfloat16, slots=32))
@@ -133,7 +136,7 @@ def test_a_shape_the_tile_plan_refuses_keeps_delta_step(shape, monkeypatch):
     """The kernel raises, the rule never reaches it, and the executor says
     which step its tick holds."""
     cfg = _cfg(**REFUSED[shape])
-    p = T.delta_pack(cfg)
+    p = DELTA.delta_pack(cfg)
     H, dk, dv = cfg.linear_heads, cfg.linear_key_dim, cfg.linear_value_dim
     assert head_block(H // p, dk, p * dv) is None
     with pytest.raises(NotImplementedError, match="no tile plan"):
@@ -141,9 +144,9 @@ def test_a_shape_the_tile_plan_refuses_keeps_delta_step(shape, monkeypatch):
                    jnp.zeros((2,), bool), jnp.ones((2, H)), jnp.ones((2, H)),
                    jnp.zeros((2, H, dk)), jnp.zeros((2, H, dk)),
                    jnp.zeros((2, H, dv)), interpret=True)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
-    assert T.delta_step_path(cfg) == "plain"
-    assert T.delta_step_path(_cfg()) == "one_pass"
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
+    assert DELTA.delta_step_path(cfg) == "plain"
+    assert DELTA.delta_step_path(_cfg()) == "one_pass"
     ex = MeshExecutor(CausalLM(cfg), init_params(cfg, jax.random.PRNGKey(0)),
                       13, 8, 3, prefix_cache=False)
     info = ex.mesh_info()
@@ -165,11 +168,11 @@ RULE = {
 def test_the_rule_reads_what_the_trace_can_observe(case, monkeypatch):
     kw, interpret, want = RULE[case]
     if interpret is not None:
-        monkeypatch.setattr(T, "_pallas_interpret", lambda: interpret)
-    assert T.delta_step_path(_cfg(), **kw) == want
+        monkeypatch.setattr(MX, "_pallas_interpret", lambda: interpret)
+    assert DELTA.delta_step_path(_cfg(), **kw) == want
     # a model with no delta layer has no such step, wherever it runs
-    assert T.delta_step_path(get_config("tiny")) is None
-    assert T.delta_step_path(get_config("falcon-h1-34b")) is None
+    assert DELTA.delta_step_path(get_config("tiny")) is None
+    assert DELTA.delta_step_path(get_config("falcon-h1-34b")) is None
 
 
 def test_a_decode_tick_through_forward_paged_is_the_plain_ticks(monkeypatch):
@@ -200,11 +203,11 @@ def test_a_decode_tick_through_forward_paged_is_the_plain_ticks(monkeypatch):
         return outs, cache
 
     want, cache_x = ticks()
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     got, cache_k = ticks()
     for a, b in zip(got, want):
         np.testing.assert_allclose(a[:2], b[:2], rtol=2e-4, atol=2e-4)
-    for leaf in T.DELTA_POOL_KEYS:
+    for leaf in MIXERS["linear"].pool_keys:
         np.testing.assert_allclose(np.asarray(cache_k[leaf]),
                                    np.asarray(cache_x[leaf]), rtol=1e-5,
                                    atol=1e-5)

@@ -13,6 +13,7 @@ import pytest
 from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import common as MX
 from deepspeed_tpu.moe import sharded_moe as M
 from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 from deepspeed_tpu.observability import configure_tracer, get_tracer
@@ -226,14 +227,14 @@ def test_a_cells_tick_by_the_depth_of_its_groups(monkeypatch, cell):
     with open(os.path.join(root, "benchmark", "configs",
                            entry["config"] + ".json")) as f:
         cfg = system.transformer_config(json.load(f), False)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: False)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
     assert T.expert_matmul_path(cfg, slots, 1) == CELL_TICKS[cell]
 
 
 def test_this_host_keeps_ragged_dot():
     """No TPU here and nobody answered in the backend's place: the model
     hands the layer ``None`` and the layer asks for no kernel of its own."""
-    assert T._pallas_interpret() is None
+    assert MX._pallas_interpret() is None
     assert T.expert_matmul_path(_tiny(), 1, 256) == "ragged_dot"
     assert M.expert_matmul_path(4096, TOPK, E, D, F,
                                 jnp.bfloat16) == "ragged_dot"
@@ -328,7 +329,7 @@ def test_the_executor_names_the_path_of_every_compiled_program(monkeypatch):
     import deepspeed_tpu
     from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     monkeypatch.setattr(T, "kv_write_path", lambda *a, **k: "page")
     cfg = _tiny()
     mesh = initialize_mesh(MeshLayout(), devices=jax.devices()[:1])
@@ -413,7 +414,7 @@ def test_a_tick_on_the_kernel_emits_the_plain_engines_tokens(monkeypatch):
     new = rng.integers(2, 9, 40)
 
     def streams(interpret):
-        monkeypatch.setattr(T, "_pallas_interpret", lambda: interpret)
+        monkeypatch.setattr(MX, "_pallas_interpret", lambda: interpret)
         engine = deepspeed_tpu.init_inference(
             model=CausalLM(cfg), params=params, dtype="bf16", mesh=mesh)
         sv = engine.serving(b_slots=32, page_size=16, max_model_len=32)

@@ -13,6 +13,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import common as MX
 from deepspeed_tpu.ops.pallas.kv_row_write import kv_row_write, row_block
 
 PAGE, SLOTS, MAXP = 8, 6, 3
@@ -48,7 +49,7 @@ def test_kernel_leaves_the_leaf_as_the_page_merge_does(heads, dtype, plan,
     """Three layers' pages in one leaf, the write into the middle layer's
     (``pool_first``'s offset): bit for bit the merge's leaf, the trash pages
     and the other layers' pages included."""
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     write = T._plan_at(_plan(plan), PAGES)
     src, keep, pages, row = write
     kept = KEPT[plan]
@@ -94,7 +95,7 @@ def test_a_shape_the_tile_plan_refuses_keeps_the_merge(shape, monkeypatch):
         kv_row_write(jnp.zeros(dims, dtype), jnp.zeros((2,) + dims[2:], dtype),
                      jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
                      interpret=True)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     assert T.kv_write_path(jax.ShapeDtypeStruct(dims, dtype), None) == "page"
 
 
@@ -122,7 +123,7 @@ RULE = {
 def test_the_rule_reads_what_the_trace_can_observe(case, monkeypatch):
     kw, interpret, want = RULE[case]
     if interpret is not None:
-        monkeypatch.setattr(T, "_pallas_interpret", lambda: interpret)
+        monkeypatch.setattr(MX, "_pallas_interpret", lambda: interpret)
     assert T.kv_write_path(**kw) == want
     # the plan carries the kept row of every block of one token a slot,
     # whatever the backend: the rule above is the one gate
@@ -183,7 +184,7 @@ CACHES = {
 @pytest.mark.parametrize("case", list(CACHES))
 def test_every_leaf_of_a_cache_says_its_path(case, monkeypatch):
     name, over, cache_kw, order, want = CACHES[case]
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     cfg = get_config(name, **{**dict(
         num_layers=2, hidden_size=64, intermediate_size=96, vocab_size=256,
         max_seq_len=512), **over})
@@ -207,7 +208,7 @@ def test_every_leaf_of_a_cache_says_its_path(case, monkeypatch):
         walked.get("window" if leaf.endswith("_window") else "full", 1)
         for leaf, path in want.items() if path == "row")
     # nowhere but on a TPU: every leaf a page at a time
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: None)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: None)
     assert set(T.kv_write_paths(cfg, cache, order).values()) == {"page"}
 
 
@@ -252,7 +253,7 @@ def test_a_decode_tick_through_forward_paged_is_the_xla_ticks(name,
 
     want, cache_x, text_x = ticks()
     assert "kv_row_write" not in text_x
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     got, cache_k, text_k = ticks()
     assert text_k.count("name=kv_row_write") == 2         # K and V, a layer
     for a, b in zip(got, want):
@@ -300,7 +301,7 @@ def test_the_engine_says_which_leaves_write_by_row(monkeypatch):
     assert sv._exec.mesh_info()["kv_write"] == {"k": "page", "v": "page"}
     assert sv.health()["kv_write"] == {"k": "page", "v": "page"}
     assert leaves == {(0, 2)}
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     sv, got, leaves = run()
     assert sv._exec.mesh_info()["kv_write"] == {"k": "row", "v": "row"}
     assert leaves == {(2, 0)} and got == want

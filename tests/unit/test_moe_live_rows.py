@@ -253,6 +253,7 @@ def test_the_moved_rows_of_a_program_from_its_counts(monkeypatch, bucket,
     holds ``lax.ragged_dot``."""
     from deepspeed_tpu.models import get_config
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
 
     cfg = get_config("olmoe-1b-7b", num_layers=2, hidden_size=D,
                      intermediate_size=F, num_heads=4, vocab_size=256,
@@ -262,10 +263,10 @@ def test_the_moved_rows_of_a_program_from_its_counts(monkeypatch, bucket,
     counts[0, :3] = (live_tokens * TOPK - 7, 4, 3)      # every pair held
     counts[1, 5] = 77
     rows = 2048 * TOPK
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: None)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: None)
     assert T.expert_rows_moved(cfg, 1, bucket, counts, live_tokens) == (
         2 * bucket * TOPK,) * 2
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     assert T._moe_chunks(cfg, 1, bucket) == bucket // 2048
     total, moved = T.expert_rows_moved(cfg, 1, bucket, counts, live_tokens)
     tile = live_rows.ROWS_IN_TILE

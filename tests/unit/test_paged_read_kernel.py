@@ -17,6 +17,7 @@ import deepspeed_tpu
 from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import common as MX
 from deepspeed_tpu.ops.pallas import paged_read as PR
 
 HEAD_MAJOR = (0, 1, 3, 2, 4)
@@ -52,9 +53,9 @@ def _both_ways(cfg, q, pools, read, order, monkeypatch):
         return np.asarray(jax.jit(lambda q, p: T._attention_paged(
             cfg, q, p, read, order))(q, pools), np.float32)
 
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: None)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: None)
     want = attend()
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     assert T.kv_read_path(pools, order, _queries(*q.shape[::2])) == "pages"
     return attend(), want
 
@@ -186,14 +187,14 @@ def test_the_rule_reads_what_the_trace_can_observe(case, every_block,
                                                    monkeypatch):
     kw, interpret, want = RULE[case]
     if interpret is not None:
-        monkeypatch.setattr(T, "_pallas_interpret", lambda: interpret)
+        monkeypatch.setattr(MX, "_pallas_interpret", lambda: interpret)
     assert T.kv_read_path(**{"query": _queries(), **kw}) == want
 
 
 def test_a_block_too_small_to_pay_for_its_step_keeps_the_gather(monkeypatch):
     """The observable that tells a page of many heads from one of few: the
     bytes of one ``[Hkv, page, hd]`` block."""
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     sizes = {heads: T.kv_read_path(_pools(heads, page=128), HEAD_MAJOR,
                                    _queries(heads=heads))
              for heads in (2, 4, 30)}
@@ -273,7 +274,7 @@ CACHES = {
 @pytest.mark.parametrize("case", list(CACHES))
 def test_every_leaf_of_a_cache_says_its_path(case, every_block, monkeypatch):
     cfg, cache_kw, order, want = CACHES[case]
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     cache = jax.eval_shape(lambda: T.init_paged_cache(
         cfg, 9, 16, dtype=jnp.bfloat16, **cache_kw))
     assert T.kv_read_paths(cfg, cache, order) == want
@@ -293,7 +294,7 @@ def test_every_leaf_of_a_cache_says_its_path(case, every_block, monkeypatch):
     assert len(re.findall(r"jit\[\s*name=paged_read", traced)) == (
         walked if want.get("k") == "pages" else 0)
     # nowhere but on a TPU: every leaf gathered
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: None)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: None)
     assert set(T.kv_read_paths(cfg, cache, order).values()) == {"gather"}
 
 
@@ -309,7 +310,7 @@ def test_a_decode_tick_through_forward_paged_is_the_gather_ticks(
         init_params(cfg, jax.random.PRNGKey(2)))
     table = jnp.arange(1, 9, dtype=jnp.int32).reshape(4, 2)
     mask = jnp.array([[True], [True], [False], [True]])
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
 
     def ticks():
         cache = T.init_paged_cache(cfg, 9, 16, dtype=jnp.bfloat16, slots=4)
@@ -407,7 +408,7 @@ def test_the_engine_says_how_it_reads(every_block, monkeypatch):
     assert "kv_read=k:gather,v:gather" in ready
     # 3 slots x 2 pairs a step x 16 rows: whole steps
     assert {a["gathered_rows"] % 96 for a in attrs} == {0}
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     sv, got, attrs, ready = run()
     pages = {"k": "pages", "v": "pages"}
     assert sv._exec.mesh_info()["kv_read"] == pages

@@ -18,6 +18,9 @@ from benchmark.lib import reference_granite4h as R
 from benchmark.lib import system
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models import mixers
+from deepspeed_tpu.models.mixers import common as MX
+from deepspeed_tpu.models.mixers import ssm as SSM
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 PAGE, CHUNK = 16, 8
@@ -97,7 +100,7 @@ def test_the_caches_leaves_cover_different_layers(tiny):
     assert shapes["ssm_state"].shape == (9, 2, 128, 64, 128)
     assert shapes["ssm_conv"].shape == (9, 2, 3, 8448)
     assert 128 * 64 * 128 * 4 + 3 * 8448 * 2 == 4_244_992
-    assert T.ssm_in_width(big) == 16768
+    assert SSM.ssm_in_width(big) == 16768
 
 
 REFUSED = {
@@ -123,13 +126,13 @@ def test_what_is_still_not_built_is_refused_by_name(what):
             "pipeline_stages": "pipeline_stages",
             "attn_bias": "attn_bias under a layer_pattern"}[what]
     with pytest.raises(NotImplementedError, match=said):
-        T._check_ssm(_rehearse_cfg(**REFUSED[what]))
+        mixers.check(_rehearse_cfg(**REFUSED[what]))
 
 
 def test_an_unknown_kind_and_a_mixerless_ssm_layer_are_value_errors():
     with pytest.raises(ValueError, match="full | window | ssm"):
         T.layer_plan(_rehearse_cfg(layer_pattern=("ssm", "mamba") * 5))
-    with pytest.raises(ValueError, match="no state-space mixer"):
+    with pytest.raises(ValueError, match="no state-space layers"):
         T.layer_plan(get_config("tiny", layer_pattern=("ssm", "full")))
 
 
@@ -364,13 +367,13 @@ def test_a_long_prompts_mixer_in_pieces_is_the_mixer_whole(monkeypatch):
     g = T.layer_groups(cfg)["ssm_moe"][0]
     lp = {k: v[0] for k, v in init_params(
         cfg, jax.random.PRNGKey(2))["layers"]["ssm_moe"].items()}
-    monkeypatch.setattr(T, "SSM_BLOCK_TOKENS", 32)
+    monkeypatch.setattr(SSM, "SSM_BLOCK_TOKENS", 32)
     h = jnp.asarray(np.random.default_rng(1).standard_normal((1, 128, 64)),
                     jnp.float32)
     for n_real in (128, 70, 33, 31, 1):
         mask = (jnp.arange(128) < n_real)[None]
-        out, (state, tail) = T._ssm_mixer(g, lp, h, mask)
-        want, (ws, wt) = T._ssm_mixer_block(g, lp, h, mask)
+        out, (state, tail) = SSM._ssm_mixer(g, lp, h, mask)
+        want, (ws, wt) = SSM._ssm_mixer_block(g, lp, h, mask)
         np.testing.assert_allclose(out[:, :n_real], want[:, :n_real],
                                    rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(state, ws, rtol=1e-5, atol=1e-7)
@@ -397,10 +400,10 @@ def test_a_tick_with_the_one_pass_step_is_the_xla_tick(monkeypatch):
     args = (params, jnp.asarray(rng.integers(0, 256, (B, 1)), jnp.int32),
             cache, table, jnp.asarray([5, 0, 9], jnp.int32),
             jnp.asarray([[True], [True], [False]]))
-    assert T.ssm_step_path(cfg) == "xla"
+    assert SSM.ssm_step_path(cfg) == "xla"
     want, want_cache = model.apply_paged(*args)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
-    assert T.ssm_step_path(cfg) == "one_pass"
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
+    assert SSM.ssm_step_path(cfg) == "one_pass"
     got, got_cache = model.apply_paged(*args)
     np.testing.assert_allclose(got[:2], want[:2], rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(got_cache["ssm_state"],

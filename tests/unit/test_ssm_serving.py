@@ -19,6 +19,8 @@ from deepspeed_tpu.inference.execution import MeshExecutor
 from deepspeed_tpu.inference.serving import Request
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import common as MX
+from deepspeed_tpu.models.mixers import ssm as SSM
 
 from .test_serving_lookahead import bare_fetches, launches_and_fetches
 
@@ -79,8 +81,8 @@ def test_the_named_base_is_the_published_model_and_counts_its_parameters():
         5120, 72, 261120, 20, 4, 128, 21504)
     assert (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups,
             cfg.ssm_conv, cfg.ssm_chunk) == (32, 128, 256, 2, 4, 128)
-    assert T.ssm_widths(cfg) == (4096, 5120, 512)
-    assert T.ssm_in_width(cfg) == 9248
+    assert SSM.ssm_widths(cfg) == (4096, 5120, 512)
+    assert SSM.ssm_in_width(cfg) == 9248
     one = (get_config(cfg, num_layers=1).param_count
            - get_config(cfg, num_layers=0).param_count)
     assert one == 430_120_032
@@ -127,86 +129,14 @@ def test_the_chunked_scan_is_the_one_step_recurrence(length, carried):
     dt = jax.nn.softplus(draw(2, length, H))
     A = -jnp.exp(draw(H) * 0.5)
     s0 = draw(2, H, P, N) if carried else jnp.zeros((2, H, P, N))
-    y, s = T._ssm_scan(cfg, x, Bm, Cm, dt, A, s0)
+    y, s = SSM._ssm_scan(cfg, x, Bm, Cm, dt, A, s0)
     state, ys = s0, []
     for t in range(length):
-        y_t, state = T._ssm_step(cfg, x[:, t:t + 1], Bm[:, t:t + 1],
+        y_t, state = SSM._ssm_step(cfg, x[:, t:t + 1], Bm[:, t:t + 1],
                                  Cm[:, t:t + 1], dt[:, t:t + 1], A, state)
         ys.append(y_t)
     np.testing.assert_allclose(y, jnp.concatenate(ys, 1), atol=2e-5)
     np.testing.assert_allclose(s, state, atol=2e-5)
-
-
-def test_a_padded_bucket_leaves_state_and_tail_as_the_unpadded_prompt(params):
-    cfg = tiny()
-    lp = {k: v[0] for k, v in params["layers"].items()}
-    h = jnp.asarray(np.random.default_rng(2).standard_normal((1, 32, 64)),
-                    jnp.float32)
-    mixer = jax.jit(functools.partial(T._ssm_mixer, cfg))
-    out, (state, tail) = mixer(lp, h[:, :21])
-    mask = (jnp.arange(32) < 21)[None]
-    out_p, (state_p, tail_p) = mixer(lp, h, mask)
-    np.testing.assert_allclose(out_p[:, :21], out, atol=1e-5)
-    np.testing.assert_allclose(state_p, state, atol=1e-5)
-    np.testing.assert_array_equal(tail_p, tail)
-    # a row with no real token keeps what it had
-    kept = (state + 1.0, tail + 1.0)
-    _, (s2, t2) = mixer(lp, h[:, :1], jnp.zeros((1, 1), bool), kept)
-    np.testing.assert_array_equal(s2, kept[0])
-    np.testing.assert_array_equal(t2, kept[1])
-
-
-def test_the_cache_has_two_leaves_with_no_page_axis(params):
-    cfg = tiny()
-    cache = T.init_paged_cache(cfg, 7, 8, dtype=jnp.float32, slots=3)
-    assert cache["ssm_state"].shape == (2, 3, 4, 8, 16)
-    assert cache["ssm_state"].dtype == jnp.float32
-    assert cache["ssm_conv"].shape == (2, 3, 3, 32 + 2 * 32)
-    assert cache["k"].shape == (2, 7, 8, 2, 16)      # 16 wide: row-major
-    assert T.init_paged_cache(cfg, 7, 8)["ssm_state"].shape[1] == 1
-    assert T.PAGED_POOL_KEYS[-2:] == T.SSM_POOL_KEYS
-    # 128-wide heads under 8 KV heads: the K/V leaves head-major, the same
-    # numbers through them
-    wide = tiny(head_dim=128, num_heads=2, num_kv_heads=1)
-    assert T.kv_leaf_head_major(wide, 128) and not T.kv_leaf_head_major(cfg, 16)
-    assert T.init_paged_cache(wide, 7, 8)["k"].shape == (2, 7, 1, 8, 128)
-    p = init_params(wide, jax.random.PRNGKey(0))
-    toks = _tokens(21)
-    cache = T.init_paged_cache(wide, 5, 8, dtype=jnp.float32)
-    pad = jnp.zeros((1, 24), jnp.int32).at[:, :21].set(toks)
-    got, cache = jax.jit(functools.partial(T.forward_paged, wide))(
-        p, pad, cache, jnp.arange(1, 5, dtype=jnp.int32)[None],
-        jnp.zeros((1,), jnp.int32), (jnp.arange(24) < 21)[None])
-    assert cache["k"].shape == (2, 5, 1, 8, 128)
-    assert float(jnp.abs(got[0, :21] - _forward(wide, p, toks)[0]).max()) < 1e-5
-
-
-def test_state_rows_follow_state_slot_and_start(params):
-    """Row b of the batch is state row b unless ``state_slot`` says
-    otherwise; a start of 0 resets, any other continues."""
-    cfg, toks = tiny(), _tokens(24, seed=3)
-    table = jnp.arange(1, 4, dtype=jnp.int32)[None]
-    cache = T.init_paged_cache(cfg, 4, 8, dtype=jnp.float32, slots=3)
-    dirty = dict(cache, ssm_state=cache["ssm_state"] + 5.0,
-                 ssm_conv=cache["ssm_conv"] + 5.0)
-    run = jax.jit(functools.partial(T.forward_paged, cfg, params))
-    _, a = run(toks[:, :16], dirty, table, jnp.zeros((1,), jnp.int32),
-               jnp.ones((1, 16), bool), state_slot=jnp.asarray([2]))
-    _, b = run(toks[:, :16], cache, table, jnp.zeros((1,), jnp.int32),
-               jnp.ones((1, 16), bool))
-    np.testing.assert_allclose(a["ssm_state"][:, 2], b["ssm_state"][:, 0],
-                               atol=1e-6)
-    np.testing.assert_array_equal(a["ssm_state"][:, :2],
-                                  dirty["ssm_state"][:, :2])
-    # the next block continues the row: both halves = the whole
-    _, a2 = run(toks[:, 16:], a, table, jnp.full((1,), 16, jnp.int32),
-                jnp.ones((1, 8), bool), state_slot=jnp.asarray([2]))
-    _, whole = run(toks, cache, table, jnp.zeros((1,), jnp.int32),
-                   jnp.ones((1, 24), bool))
-    np.testing.assert_allclose(a2["ssm_state"][:, 2], whole["ssm_state"][:, 0],
-                               atol=1e-5)
-    np.testing.assert_allclose(a2["ssm_conv"][:, 2], whole["ssm_conv"][:, 0],
-                               atol=1e-6)
 
 
 @pytest.mark.parametrize("name", ["tiny", "tiny-gqa", "ssm"])
@@ -473,7 +403,7 @@ def test_the_one_pass_step_serves_token_for_token(monkeypatch):
         return results
 
     plain = serve("xla", 3)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     one_pass = serve("one_pass", 2)
     assert one_pass == plain
     for q in _requests(9):
@@ -481,45 +411,6 @@ def test_the_one_pass_step_serves_token_for_token(monkeypatch):
         assert _is_greedy(cfg, engine.params, q.input_ids, one_pass[q.rid])
     alone = engine.serving(**SERVE_KW).run([_requests(9)[7]])
     assert list(alone[0].output_ids) == one_pass["r7"]
-
-
-REFUSALS = {
-    "prefix sharing": ("prefix sharing", lambda e: e.serving(
-        prefix_cache=True, **SERVE_KW)),
-    "tiering": ("KV-page tiering", lambda e: e.serving(
-        host_tier_pages=4, **SERVE_KW)),
-    "extract and inject": ("KV-page tiering", lambda e: MeshExecutor(
-        e.model, e.params, 13, 8, 3, prefix_cache=False, host_tier=True)),
-    "speculative": ("speculative decoding", lambda e: e.serving(
-        speculative=object(), **SERVE_KW)),
-    "int8 pool": ("int8 pool", lambda e: e.serving(
-        kv_dtype="int8", **SERVE_KW)),
-    "int8 cache": ("int8 pool", lambda e: e.model.init_paged_cache(
-        4, 8, kv_dtype="int8")),
-    "copy-on-write": ("copy-on-write", lambda e: MeshExecutor(
-        e.model, e.params, 13, 8, 3, prefix_cache=True)),
-    "adapters": ("adapter", lambda e: T.forward_paged(
-        e.model.config, e.params, jnp.zeros((1, 1), jnp.int32),
-        e.model.init_paged_cache(4, 8), jnp.ones((1, 3), jnp.int32),
-        jnp.zeros((1,), jnp.int32), jnp.ones((1, 1), bool),
-        adapters={"scale": jnp.ones((1,)), "factors": {}})),
-    "adapter registry": ("multi-tenant adapters", lambda e: MeshExecutor(
-        e.model, e.params, 13, 8, 3, prefix_cache=False, adapters=object())),
-    "contiguous cache": ("contiguous cache", lambda e: e.generate(
-        np.arange(4, dtype=np.int32)[None], max_new_tokens=2)),
-    "training": ("training", lambda e: T.forward(
-        e.model.config, e.params, jnp.zeros((1, 4), jnp.int32),
-        deterministic=False)),
-}
-
-
-@pytest.mark.parametrize("what", list(REFUSALS))
-def test_mechanisms_that_know_pages_alone_refuse_by_name(engine, what):
-    named, call = REFUSALS[what]
-    with pytest.raises(NotImplementedError,
-                       match=r"state-space layers \(a state a slot\)") as e:
-        call(engine)
-    assert named in str(e.value)
 
 
 def test_tensor_sharded_serving_refuses():
@@ -531,18 +422,3 @@ def test_tensor_sharded_serving_refuses():
             lambda: init_params(cfg, jax.random.PRNGKey(0))), 13, 8, 3,
             mesh=initialize_serving_mesh(tp=2), prefix_cache=False)
 
-
-def test_what_the_block_is_not_built_from_is_refused():
-    # expert layers behind a mixer are built since PR 47; a count a layer
-    # (the pyramid) and leading dense layers are still not
-    with pytest.raises(NotImplementedError, match="per-layer expert counts"):
-        T._check_ssm(tiny(num_experts=(4, 4)))
-    with pytest.raises(NotImplementedError, match="dense_layers"):
-        init_params(tiny(num_experts=4, dense_layers=1, moe_drop_tokens=False),
-                    jax.random.PRNGKey(0))
-    assert "router" in init_params(tiny(num_experts=4),
-                                   jax.random.PRNGKey(0))["layers"]
-    with pytest.raises(NotImplementedError, match="RMSNorm"):
-        init_params(tiny(norm="layernorm"), jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match="whole groups"):
-        init_params(tiny(ssm_groups=3), jax.random.PRNGKey(0))

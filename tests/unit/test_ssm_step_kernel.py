@@ -11,6 +11,9 @@ import pytest
 from deepspeed_tpu.inference.execution import MeshExecutor
 from deepspeed_tpu.models import CausalLM, get_config, init_params
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.models.mixers import MIXERS
+from deepspeed_tpu.models.mixers import common as MX
+from deepspeed_tpu.models.mixers import ssm as SSM
 from deepspeed_tpu.ops.pallas.ssm_step import head_block, ssm_step
 
 SLOTS, P, N = 3, 8, 128
@@ -60,7 +63,7 @@ def test_kernel_is_ssm_step_on_the_rows_and_touches_no_other(case):
     leaf, x, Bm, Cm, dt, A = _inputs(cfg, 3, masked)
     row0 = layer * SLOTS
     before = leaf[row0:row0 + SLOTS]
-    y_ref, s_ref = T._ssm_step(
+    y_ref, s_ref = SSM._ssm_step(
         cfg, x, Bm, Cm, dt, A,
         jnp.where(fresh[:, None, None, None], 0.0, before))
 
@@ -113,9 +116,9 @@ def test_a_shape_the_tile_plan_refuses_keeps_ssm_step(shape, monkeypatch):
                  jnp.ones((2, H)), jnp.zeros((2, H, Pd)),
                  jnp.zeros((2, G, Nd)), jnp.zeros((2, G, Nd)),
                  interpret=True)
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
-    assert T.ssm_step_path(cfg) == "xla"
-    assert T.ssm_step_path(_cfg()) == "one_pass"
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
+    assert SSM.ssm_step_path(cfg) == "xla"
+    assert SSM.ssm_step_path(_cfg()) == "one_pass"
     ex = MeshExecutor(CausalLM(cfg), init_params(cfg, jax.random.PRNGKey(0)),
                       13, 8, 3, prefix_cache=False)
     assert ex.mesh_info()["ssm_step"] == "xla" and ex.state_passes == 3
@@ -134,10 +137,10 @@ RULE = {
 def test_the_rule_reads_what_the_trace_can_observe(case, monkeypatch):
     kw, interpret, want = RULE[case]
     if interpret is not None:
-        monkeypatch.setattr(T, "_pallas_interpret", lambda: interpret)
-    assert T.ssm_step_path(_cfg(), **kw) == want
+        monkeypatch.setattr(MX, "_pallas_interpret", lambda: interpret)
+    assert SSM.ssm_step_path(_cfg(), **kw) == want
     # a model with no state a slot has no step, wherever it runs
-    assert T.ssm_step_path(get_config("tiny")) is None
+    assert SSM.ssm_step_path(get_config("tiny")) is None
 
 
 def test_a_sharded_mesh_keeps_ssm_step(monkeypatch):
@@ -146,13 +149,13 @@ def test_a_sharded_mesh_keeps_ssm_step(monkeypatch):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", None)
-    assert T._pallas_interpret() is False
-    assert T.ssm_step_path(_cfg()) == "one_pass"
+    assert MX._pallas_interpret() is False
+    assert SSM.ssm_step_path(_cfg()) == "one_pass"
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH",
                         mesh_mod.build_mesh(mesh_mod.MeshLayout(dp=2),
                                             jax.devices()[:2]))
-    assert T._pallas_interpret() is None
-    assert T.ssm_step_path(_cfg()) == "xla"
+    assert MX._pallas_interpret() is None
+    assert SSM.ssm_step_path(_cfg()) == "xla"
 
 
 def test_a_decode_tick_through_forward_paged_is_the_xla_ticks(monkeypatch):
@@ -181,11 +184,11 @@ def test_a_decode_tick_through_forward_paged_is_the_xla_ticks(monkeypatch):
         return outs, cache
 
     want, cache_x = ticks()
-    monkeypatch.setattr(T, "_pallas_interpret", lambda: True)
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     got, cache_k = ticks()
     for a, b in zip(got, want):
         np.testing.assert_allclose(a[:2], b[:2], rtol=2e-4, atol=2e-4)
-    for leaf in T.SSM_POOL_KEYS:
+    for leaf in MIXERS["ssm"].pool_keys:
         np.testing.assert_allclose(np.asarray(cache_k[leaf]),
                                    np.asarray(cache_x[leaf]), rtol=1e-5,
                                    atol=1e-5)

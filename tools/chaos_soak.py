@@ -978,10 +978,15 @@ def run_pod_soak(seed: int, total_steps: int = 12, ckpt_every: int = 2,
     # sealing exactly this one, so the previous boundary wins the cut
     skip_from = ((kill_step // replica_every_k) * replica_every_k
                  if replica_every_k > 0 else 0)
-    # commit timeout 2s: peers respond in ~10ms, so 200x margin, and the
-    # torn-commit rounds (which always burn the full timeout) stay cheap
-    # enough for the tier-1 seeds that import this harness
-    LEASE_S, MISS, COMMIT_TIMEOUT = 1.0, 2, 2.0
+    # commit timeout 5s: peers respond in ~10ms on an idle machine, and
+    # the torn-commit rounds (which always burn the full timeout) stay
+    # cheap enough for the tier-1 seeds that import this harness.  It is a
+    # WALL-clock wait (the store clock stands still inside it), so it is the
+    # one margin the lockstep below cannot replace: at 2s a tier-1 run under
+    # six loaded workers named a LIVE peer missing beside the victim (PR 55's
+    # check: "dead: ['host1', 'host3']"), which turns a buddy's adoption
+    # into a fallback whenever that peer is the victim's buddy
+    LEASE_S, MISS, COMMIT_TIMEOUT = 1.0, 2, 5.0
     if scenario is not None:
         # scenario kills must be detected at the next pod-commit barrier:
         # its timeout names EVERY missing host at once.  Lease expiry
@@ -1026,6 +1031,12 @@ def run_pod_soak(seed: int, total_steps: int = 12, ckpt_every: int = 2,
     torn_tags: list = []
     resumes: list = []           # per-round (adopted_step, resumed_step)
     recovery = {"fail_t": None, "wall_s": None}
+    # lockstep in the wall clock's place (``settle``): the peers whose
+    # threads run, the whole scans each has made, the newest replica
+    # boundary the coordinator has announced
+    alive: set = set()
+    scans: dict = {}
+    announced = [0]
     adoptions0 = replica_adoptions_total()
     fallbacks0 = replica_fallbacks_total()
 
@@ -1035,6 +1046,7 @@ def run_pod_soak(seed: int, total_steps: int = 12, ckpt_every: int = 2,
         (replica scenarios) publish this host's shard slab at every
         boundary the coordinator announces sealed."""
         pstore = store_for(host)
+        alive.add(host)
         dead_flag: list = []
         # grace disabled: detection in the sim is lease EXPIRY on the fake
         # clock, never "host absent" races during real-time round setup
@@ -1108,8 +1120,10 @@ def run_pod_soak(seed: int, total_steps: int = 12, ckpt_every: int = 2,
                         if tag.startswith("global_step") else -1
                     write_host_manifest(tag_dir, host, gen, step,
                                         files=files)
+                scans[host] = scans.get(host, 0) + 1
                 time.sleep(0.005)
         finally:
+            alive.discard(host)
             wd.stop()
 
     def attempt(rnd):
@@ -1145,15 +1159,57 @@ def run_pod_soak(seed: int, total_steps: int = 12, ckpt_every: int = 2,
                 store, "host0", rnd.generation, members,
                 snapshot_fn=engine.replica_snapshot,
                 replica_every_k=replica_every_k,
-                on_sealed=lambda s, g=rnd.generation:
-                    announce_replica_round(store, g, s))
+                on_sealed=lambda s, g=rnd.generation: (
+                    announce_replica_round(store, g, s),
+                    announced.__setitem__(0, s)))
             adopt_kw = dict(adopt_prev_hosts=rnd.prev_hosts,
                             adopt_dead=rnd.dead)
         agent = PodElasticAgent(engine, ckpt_dir, ctx, watchdog=wd0,
                                 replicator=replicator,
                                 ckpt_every=ckpt_every, **adopt_kw)
 
+        first_step: list = []
+
+        def settle(i, timeout_s=60.0):
+            """Before step ``i`` runs, every peer has seen what the steps
+            before it published, however the machine schedules the
+            threads: the replica boundary due by now is announced (the
+            replicator publishes on a thread of its own), the
+            coordinator's lease carries its step (beaten here, not left to
+            the heartbeat thread's cadence), and every peer whose thread
+            runs has made two whole scans since (the first may have read
+            the lease before the beat).  A victim due at step ``i`` has
+            then died before step ``i`` runs and a live peer has sealed
+            the boundary and served the commit it was shown: the kill
+            schedule is the seed's, in steps.  A fixed sleep a step stood
+            here; under six loaded tier-1 workers the coordinator ran four
+            steps before the victim's thread read its lease once, the
+            victim sealed the boundary past its kill step and the round
+            adopted step 8 where the schedule said 6 (PR 55's check)."""
+            def wait(ready, what):
+                deadline = time.monotonic() + timeout_s
+                while not ready():
+                    assert time.monotonic() < deadline, (
+                        f"pod soak seed={seed}: step {i} waited "
+                        f"{timeout_s:.0f}s for {what}")
+                    time.sleep(0.002)
+
+            if not first_step:
+                first_step.append(i)
+            k = replica_every_k
+            due = i // k * k if k > 0 else 0
+            if due > first_step[0]:     # sealed by this round, not the last
+                wait(lambda: announced[0] >= due,
+                     f"the replica boundary {due} to be announced")
+            wd0.beat_once()
+            base = dict(scans)
+            wait(lambda: all(scans.get(h, 0) >= base.get(h, 0) + 2
+                             for h in tuple(alive)),
+                 "two scans of every peer (peers: "
+                 f"{sorted(alive)}, scans: {scans})")
+
         def step_fn(eng, i):
+            settle(i)
             if recovery["fail_t"] is not None and recovery["wall_s"] is None:
                 recovery["wall_s"] = time.monotonic() - recovery["fail_t"]
             loss = float(eng.train_batch(batch=random_batch(16, 16, seed=i)))
@@ -1164,7 +1220,6 @@ def run_pod_soak(seed: int, total_steps: int = 12, ckpt_every: int = 2,
                 continuity["checked"] += 1
             loss_log[i] = loss
             clock_box[0] += 1.0   # one store-clock tick per step
-            time.sleep(0.03)      # give peer scans real time to observe
 
         try:
             rendezvous(store, "host0", rnd.generation, members,

@@ -94,6 +94,7 @@ def main():
     a = ap.parse_args()
     from deepspeed_tpu.models import get_config
     from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
     from deepspeed_tpu.ops.pallas import paged_read as PR
 
     dev = jax.devices()[0]
@@ -135,7 +136,7 @@ def main():
 
         def attend(interpret):
             def f(q, k, v, read):
-                T._pallas_interpret = lambda: interpret
+                MX._pallas_interpret = lambda: interpret
                 return T._attention_paged(
                     cfg, q, {"k": logical(k), "v": logical(v)}, read, order)
             return f
@@ -179,7 +180,7 @@ def main():
             continue
         for n in a.pairs.split(","):
             report(f"kernel/{n}", kernel(int(n)))
-        T._pallas_interpret = lambda: a.interpret
+        MX._pallas_interpret = lambda: a.interpret
         say(case=case, impl="rule", block_bytes=block, path=T.kv_read_path(
             {n: jax.eval_shape(logical, a) for n, a in (("k", k), ("v", v))},
             order, jax.ShapeDtypeStruct((slots, hq), q.dtype)))
