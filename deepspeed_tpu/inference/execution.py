@@ -413,7 +413,8 @@ class MeshExecutor:
         layout.kv_write_leaves = tuple(
             sum(path == by for path in self.kv_write.values())
             for by in ("row", "page"))
-        layout.kv_read_pages = self.kv_read.get("k") == "pages"
+        layout.kv_read_pages = "pages" in (self.kv_read.get("k"),
+                                           self.kv_read.get("latent"))
         # one token's rows in every paged leaf, over every layer and pass
         layout.kv_token_bytes = sum(
             int(a.nbytes) // (a.shape[1] * self.page_size)

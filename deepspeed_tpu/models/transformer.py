@@ -2671,10 +2671,17 @@ def kv_read_paths(cfg: TransformerConfig, cache: Dict[str, Any],
     planes of a quantised pool), for a tick of ``slots`` slots whose queries
     are of ``dtype`` (``None``: the model's own).  The same function of the
     same leaves and orders that :func:`_attention_paged` asks when the tick
-    is traced; a
-    window layer's read carries its window's lower bound and its sink, and a
-    latent leaf is read by :func:`_attention_latent_paged`, which does not
-    ask: both keep the gather."""
+    is traced (a latent leaf, alone in its cache: :func:`_attention_latent_paged`);
+    a window layer's read carries its window's lower bound and its sink and
+    keeps the gather."""
+    if is_latent(cfg):
+        a = cache["latent"]
+        return {"latent": kv_read_path(
+            {"latent": jax.ShapeDtypeStruct(
+                (a.shape[0] * a.shape[1],) + tuple(a.shape[2:]), a.dtype)},
+            pool_order, jax.ShapeDtypeStruct(
+                (slots, cfg.num_heads), jnp.dtype(dtype or cfg.dtype)),
+            values=cfg.kv_lora_rank)}
     head_major = _head_major_leaves(cfg)
     suffix = _KIND_SUFFIX["window"]
     kinds: Dict[bool, Dict[str, str]] = {}      # window? -> {"k": its key}
@@ -3118,8 +3125,13 @@ def _attention_paged(cfg, q, pools, read, pool_order=None, sink=None):
     return out.astype(q.dtype).reshape(B, S, Hq, vd)
 
 
+# How the v5e stores the unstacked latent leaf ``[L, P, page, r + rd]``: the
+# page rows minor-most (``r + rd`` = 576 is no whole number of 128 lanes)
+LATENT_PAGE_ROWS_MINOR = (0, 1, 3, 2)
+
+
 def kv_read_path(pools, pool_order, query, tokens: int = 1,
-                 plain: bool = True) -> str:
+                 plain: bool = True, values: Optional[int] = None) -> str:
     """How a block of ``tokens`` a slot reads the live pages of the pool
     leaves ``pools`` (``{"k", "v"[, "k_scale", "v_scale"]}``: arrays ``[N,
     page, Hkv, hd]`` or their shapes and dtypes) that the device stores in
@@ -3142,10 +3154,37 @@ def kv_read_path(pools, pool_order, query, tokens: int = 1,
     on the v5e), a sharded mesh, any other backend.  One plan
     (:func:`_paged_read_plan`), one mask and one blockwise softmax either
     way.  Read at trace time from what the code can observe; the serving
-    executor reports it (``mesh_info()["kv_read"]``)."""
-    from ..ops.pallas.paged_read import (MIN_BLOCK_BYTES, RESIDENT_BYTES,
-                                         page_block, resident_bytes)
+    executor reports it (``mesh_info()["kv_read"]``).
 
+    The one leaf of a latent cache (``pools`` = ``{"latent"}``: ``[N, page,
+    r + rd]``, the first ``values`` = ``r`` columns of a row its values)
+    answers by the same observables: ``"pages"``
+    (``paged_read.latent_read``: one block a pair, fetched once for both
+    products) for one token a slot, bfloat16 queries and leaf, the leaf
+    OBSERVED stored page-rows-minor (:data:`LATENT_PAGE_ROWS_MINOR`: the
+    kernel's view ``[N, r + rd, page]`` then moves nothing, where over a
+    row-major leaf the compiler would copy the whole pool in front of every
+    read), a block the tile plan takes (``latent_block``) of at least
+    ``MIN_BLOCK_BYTES``, the state within ``RESIDENT_BYTES``, where a kernel
+    may run; ``"gather"`` (:func:`_attention_latent_paged`'s loop) for a
+    prompt or a verify block, any other dtype or stored order, a sharded
+    mesh, every other backend."""
+    from ..ops.pallas.paged_read import (MIN_BLOCK_BYTES, RESIDENT_BYTES,
+                                         latent_block, page_block,
+                                         resident_bytes)
+
+    if set(pools) == {"latent"}:
+        leaf = pools["latent"]
+        n, page, width = leaf.shape
+        block = (tokens == 1 and plain and values is not None
+                 and _common._pallas_interpret() is not None
+                 and leaf.dtype == query.dtype
+                 and _leaf_order(pool_order, "latent")
+                 == LATENT_PAGE_ROWS_MINOR
+                 and resident_bytes(*query.shape, width, values)
+                 <= RESIDENT_BYTES
+                 and latent_block((n, width, page), values, leaf.dtype))
+        return "pages" if block and block >= MIN_BLOCK_BYTES else "gather"
     if (tokens != 1 or not plain or set(pools) != {"k", "v"}
             or _common._pallas_interpret() is None
             or not pools["k"].dtype == pools["v"].dtype == query.dtype
@@ -3259,7 +3298,7 @@ def _merge_pages(pool, rows, write):
     return pool.at[pages.reshape(-1)].set(new.reshape(-1, *new.shape[2:]))
 
 
-def _attention_latent_paged(cfg, q, wkv_b, pool, read):
+def _attention_latent_paged(cfg, q, wkv_b, pool, read, pool_order=None):
     """The absorbed path: ``q [B,S,Hq,hd]`` against the call's live pages of
     the latent leaf ``pool [N, page, r + rd]``, ``read`` =
     :func:`_paged_read_plan`'s list of (slot, page) pairs moved to this
@@ -3287,7 +3326,15 @@ def _attention_latent_paged(cfg, q, wkv_b, pool, read):
     (:func:`_pool_views`' rule, for a leaf with no head axis) and the pool
     stays where the layer scan carries it; on a backend that stores it
     row-major it is the same arithmetic, and the CPU tests run the code
-    the chip times."""
+    the chip times.
+
+    Where :func:`kv_read_path` says ``"pages"`` (one token a slot over a
+    bfloat16 leaf that ``pool_order``, the caller's observation, says is
+    stored that way, on a TPU) the same list, mask and softmax run in
+    ``ops/pallas/paged_read.py``'s ``latent_read`` over the same view: each
+    live page fetched once for both products, a pair folded straight into
+    its slot's float32 state on chip, no copy of a step's pages, no partial
+    softmax a pair and no one-hot; the loop below is every other read."""
     steps, slot, pages, limit = read
     B, S, Hq, hd = q.shape
     r, rd = cfg.kv_lora_rank, cfg.rotary_dim
@@ -3299,9 +3346,28 @@ def _attention_latent_paged(cfg, q, wkv_b, pool, read):
             [jnp.einsum("bshn,rhn->bshr", q[..., :hd - rd], w_uk),
              q[..., hd - rd:]], axis=-1)                    # [B,S,Hq,r+rd]
     view = jnp.transpose(pool, (0, 2, 1))             # [N, r+rd, page]
+    scale = _sm_scale(cfg, hd)
+
+    def absorb_out(acc, l):
+        # a slot that was not read has l == 0: its output is 0, not NaN
+        u = (acc / jnp.where(l > 0, l, 1.0)[..., None]).astype(q.dtype)
+        with jax.named_scope("absorb_out"):
+            return jnp.einsum("bshr,rhv->bshv", u, w_uv)
+
+    if kv_read_path({"latent": pool}, pool_order,
+                    jax.ShapeDtypeStruct((B, Hq), qa.dtype), S,
+                    values=r) == "pages":
+        from ..ops.pallas.paged_read import latent_read
+
+        flat = slot.reshape(-1)
+        with jax.named_scope("kv_read"):
+            acc, l = latent_read(
+                qa[:, 0], view, jnp.sum(flat < B, dtype=jnp.int32), flat,
+                pages.reshape(-1), limit.reshape(-1), values=r, scale=scale,
+                interpret=_common._pallas_interpret())
+        return absorb_out(acc[:, None], l[:, None])
     row = jnp.arange(ps, dtype=jnp.int32)
     slots = jnp.arange(B, dtype=jnp.int32)
-    scale = _sm_scale(cfg, hd)
 
     def step(i, carry):
         m, l, acc = carry                                # [B,S,Hq] x2, +[r]
@@ -3335,13 +3401,11 @@ def _attention_latent_paged(cfg, q, wkv_b, pool, read):
     _, l, acc = jax.lax.fori_loop(
         0, steps, step,
         (m0, jnp.zeros_like(m0), jnp.zeros((B, S, Hq, r), jnp.float32)))
-    # a slot that was not read has l == 0: its output is 0, not NaN
-    u = (acc / jnp.where(l > 0, l, 1.0)[..., None]).astype(q.dtype)
-    with jax.named_scope("absorb_out"):
-        return jnp.einsum("bshr,rhv->bshv", u, w_uv)
+    return absorb_out(acc, l)
 
 
-def _attend_latent_paged(cfg, pools, write, read, within=None):
+def _attend_latent_paged(cfg, pools, write, read, pool_order=None,
+                         within=None):
     """:func:`_block`'s ``attend`` of a latent-attention layer against the
     paged pool, whose one leaf ``latent [N, page, r + rd]`` holds a token's
     normed latent and its shared rotated key row.  The block's rows are
@@ -3352,7 +3416,9 @@ def _attend_latent_paged(cfg, pools, write, read, within=None):
     :func:`_attend_paged`'s) over the expanded keys and values, reading
     nothing back (:func:`_attention_causal_block`, the expanded path); one
     token a slot reads the live pairs of ``read`` through the absorbed path
-    (:func:`_attention_latent_paged`)."""
+    (:func:`_attention_latent_paged`), by pages or by the gather as
+    :func:`kv_read_path` answers for the leaf stored in ``pool_order`` (the
+    caller's observation, as :func:`_attend_paged`'s)."""
     def attend(q, latent, wkv_b):
         with jax.named_scope("kv_write"):
             new = {"latent": _merge_pages(pools["latent"], latent, write)}
@@ -3364,7 +3430,7 @@ def _attend_latent_paged(cfg, pools, write, read, within=None):
                                                reach), new
             with jax.named_scope("attn_latent"):
                 return _attention_latent_paged(
-                    cfg, q, wkv_b, new["latent"], read), new
+                    cfg, q, wkv_b, new["latent"], read, pool_order), new
     return attend
 
 
@@ -4141,7 +4207,8 @@ def _paged_layers(cfg, layers, x, pools, first: int, n: int, num_pages: int,
         rplan = (None if read is None else
                  (read[0], read[1], read[2] + pool_page, read[3]))
         kv = {k: v for k, v in pools.items() if k not in STATE_POOL_KEYS}
-        attend = (_attend_latent_paged(cfg, kv, wplan, rplan, within)
+        attend = (_attend_latent_paged(cfg, kv, wplan, rplan, pool_order,
+                                       within)
                   if is_latent(cfg) else
                   _attend_paged(cfg, kv, wplan, rplan, pool_order))
         layer = first_page // num_pages

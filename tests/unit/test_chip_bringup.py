@@ -552,13 +552,22 @@ def test_row_write_compiles_for_every_row_its_tile_plan_takes(
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
-def _latent_tick(chip):
+_LATENT_TICKS = {}          # compiled once a (stored order, backend's answer)
+
+
+def _latent_tick(chip, pool_order=None):
     """Kanana-2's decode tick at the benchmark's widths and geometry (depth
     3: the dense layer and two expert layers, 16 experts held, 32 slots of 64
     pages of 128 rows of 576), the latent leaf donated, compiled for
-    ``chip``: ``(compiled, depth, slots, maxp, page, width)``."""
+    ``chip`` with the leaf's stored order as the caller observed it
+    (``pool_order``): ``(compiled, depth, slots, maxp, page, width)``."""
     from deepspeed_tpu.models import get_config, init_params
+    from deepspeed_tpu.models.mixers import common as MX
     from deepspeed_tpu.models.transformer import forward_paged
+
+    key = (pool_order, MX._pallas_interpret())
+    if key in _LATENT_TICKS:
+        return _LATENT_TICKS[key]
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
@@ -576,13 +585,14 @@ def _latent_tick(chip):
     def tick(params, cache, tokens, table, start, mask):
         logits, cache, counts = forward_paged(
             cfg, params, tokens, cache, table, start, mask,
-            expert_counts=True)
+            expert_counts=True, pool_order=pool_order)
         return jnp.argmax(logits[:, -1], -1), counts, cache
 
     compiled = jax.jit(tick, donate_argnums=(1,)).lower(
         params, cache, S((slots, 1), jnp.int32), S((slots, maxp), jnp.int32),
         S((slots,), jnp.int32), S((slots, 1), jnp.bool_)).compile()
-    return compiled, depth, slots, maxp, page, width
+    _LATENT_TICKS[key] = compiled, depth, slots, maxp, page, width
+    return _LATENT_TICKS[key]
 
 
 def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
@@ -631,25 +641,63 @@ def test_latent_paged_decode_keeps_its_leaf_in_place_on_v5e(one_v5e_chip):
 
 def test_latent_tick_is_the_same_program_under_the_tpus_rule(one_v5e_chip,
                                                              monkeypatch):
-    """AOT: the same tick with the K/V write chosen as on a TPU (the test
+    """AOT: the same tick with its paths chosen as on a TPU (the test
     answers for the backend, as the row write's own test does).  The latent
-    leaf has no head axis, so it keeps the page merge: the program holds no
-    row-write kernel and is, instruction for instruction, the one compiled
-    where no kernel may run (the plan's kept row has no reader there and the
-    compiler drops it), so its temporaries and its leaf-sized ops are the
-    page merge's."""
+    leaf has no head axis, so it keeps the page merge: no row-write kernel.
+    Handed the order the executor observes on the v5e (page rows
+    minor-most) the rule reads by pages: Mosaic takes ``latent_read`` at the
+    benchmark's widths, once in the dense layer's scan and once in the
+    expert layers', and the program gathers no page of latent rows for its
+    read any more (what is left is the merge's: a slot's one page); the leaf
+    is still stored as the read's view wants it, nothing but the in-place
+    scatters is its size, and the temporaries are megabytes.  Observed
+    row-major (no order handed in: the caller that has not looked) the rule
+    keeps the gather, and the program is, instruction for instruction, the
+    one compiled where no kernel may run."""
+    import re
+
+    import numpy as np
+
     from deepspeed_tpu.models import transformer as T
     from deepspeed_tpu.models.mixers import common as MX
 
     assert MX._pallas_interpret() is None        # the CPU's answer
     merge = _latent_tick(one_v5e_chip)[0]
     monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
-    on_tpu = _latent_tick(one_v5e_chip)[0]
-    assert "kv_row_write" not in on_tpu.as_text()
-    assert (_without_source_locations(on_tpu.as_text())
+    unseen = _latent_tick(one_v5e_chip)[0]
+    assert (_without_source_locations(unseen.as_text())
             == _without_source_locations(merge.as_text()))
-    assert (on_tpu.memory_analysis().temp_size_in_bytes
-            == merge.memory_analysis().temp_size_in_bytes)
+    on_tpu, depth, slots, maxp, page, width = _latent_tick(
+        one_v5e_chip, T.LATENT_PAGE_ROWS_MINOR)
+    text = on_tpu.as_text()
+    assert "kv_row_write" not in text
+    # (the expert layers' ragged-dot is the compiler's own Mosaic call)
+    calls = [ln for ln in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in ln
+             and "ragged-dot" not in ln]
+    assert len(calls) == 2
+    assert all("/attn_latent/kv_read/" in ln and "/latent_read/pallas_call"
+               in ln for ln in calls)
+    layout = on_tpu.input_formats[0][1]["latent"].layout
+    assert tuple(layout.major_to_minor) == T.LATENT_PAGE_ROWS_MINOR
+    assert on_tpu.memory_analysis().temp_size_in_bytes < 16e6
+    leaf = depth * (1 + slots * maxp) * page * width
+    gathers = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = bf16\[([\d,]+)\]", line)
+        if not m:
+            continue
+        dims = [int(d) for d in m.group(1).split(",")]
+        if sorted(dims[-2:]) != sorted([page, width]):
+            continue                     # not pages of latent rows
+        n = int(np.prod(dims))
+        if " gather(" in line:
+            gathers.add(n)
+        if n >= leaf:
+            assert re.search(r" (parameter|get-tuple-element|bitcast|while)"
+                             r"\(| scatter\(|/scatter\"", line), line[:240]
+    # the merge's gather of each slot's one page, no step of 64 pairs
+    assert gathers == {slots * page * width}
 
 
 @pytest.mark.parametrize(

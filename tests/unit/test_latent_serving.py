@@ -163,7 +163,8 @@ def test_engine_serves_the_latent_pool_token_for_token(engine, monkeypatch,
 def test_latent_rows_read_are_the_devices_trip_count(page):
     """``paged_read_rows`` (the ``gathered_rows`` span attr) is the trip
     count of the pair list the program computes on the device and hands the
-    absorbed read, and every live row is on the list once."""
+    absorbed read, under both paths, and every live row is on the list
+    once."""
     B, maxp = 4, 6
     table = jnp.arange(1, 1 + B * maxp, dtype=jnp.int32).reshape(B, maxp)
     rng = np.random.default_rng(page)
@@ -175,6 +176,10 @@ def test_latent_rows_read_are_the_devices_trip_count(page):
             jnp.asarray(active)[:, None], page)
         rows = T.paged_read_rows((lengths + 1) * active, page, maxp, B)
         assert int(steps) * T.paged_read_pairs(B, maxp) * page == rows
+        # ... in whole steps where the read gathers; the kernel's grid is as
+        # long as the live pairs (``kv_read_path``'s ``"pages"``)
+        assert int((np.asarray(slot) < B).sum()) * page == T.paged_read_rows(
+            (lengths + 1) * active, page, maxp, B, whole_steps=False) <= rows
         live = int(((np.asarray(limit)[..., 0].ravel()[:, None]
                      >= np.arange(page)[None]).sum()))
         assert live == int(((lengths + 1) * active).sum()) <= rows

@@ -4,7 +4,9 @@ gather-then-attend loop under the same plan, in interpret mode asked for by
 name, and the rule that chooses between them (``kv_read_path``): the kernel
 for one token a slot over bfloat16 K and V leaves of whole-lane heads stored
 row-major or head-major, where a program may hold a kernel at all; the gather
-elsewhere."""
+elsewhere.  A latent leaf's read (``latent_read`` against
+``_attention_latent_paged``'s loop) by the same cases."""
+import functools
 import logging
 import re
 
@@ -21,6 +23,7 @@ from deepspeed_tpu.models.mixers import common as MX
 from deepspeed_tpu.ops.pallas import paged_read as PR
 
 HEAD_MAJOR = (0, 1, 3, 2, 4)
+LATENT = T.LATENT_PAGE_ROWS_MINOR       # as the v5e stores a latent leaf
 SLOTS, MAXP = 5, 4
 TABLE = jnp.arange(1, 1 + SLOTS * MAXP, dtype=jnp.int32).reshape(SLOTS, MAXP)
 PAGES = 1 + SLOTS * MAXP            # a layer's, the trash page counted in
@@ -34,6 +37,7 @@ def every_block(monkeypatch):
     # the kernel is traced once a shape: a test that answers for
     # ``pairs_a_step`` must not be handed another test's trace
     PR.paged_read.clear_cache()
+    PR.latent_read.clear_cache()
 
 
 def _queries(slots=8, heads=16, dtype=jnp.bfloat16):
@@ -132,6 +136,90 @@ def test_no_live_pair_at_all(every_block, monkeypatch):
     assert np.all(got == 0) and np.all(want == 0)
 
 
+def _kanana(**over):
+    """Tiny widths with a latent row the tile plan takes: 128 value columns
+    and a 16-wide rotated key row, two layers (one dense, one of experts)."""
+    return get_config("kanana-2-30b-a3b", **{**dict(
+        num_layers=2, hidden_size=64, intermediate_size=96, vocab_size=256,
+        max_seq_len=1024, num_heads=4, head_dim=24, v_head_dim=16,
+        rotary_dim=16, kv_lora_rank=128, num_experts=16, moe_experts_held=4,
+        moe_top_k=3, moe_intermediate_size=32, dtype=jnp.bfloat16), **over})
+
+
+def _latent_both_ways(table, start, mask, monkeypatch, page=128, seed=0):
+    """The absorbed read of a toy Kanana layer over a random latent leaf by
+    the gather (the order not observed) and by the kernel (observed stored
+    page-rows-minor), under one plan: ``(got, want, steps)``."""
+    cfg = _kanana()
+    slots = table.shape[0]
+    read = T._paged_read_plan(table, start, mask, page)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pool = jax.random.normal(ks[0], (int(table.max()) + 1, page, 144),
+                             jnp.bfloat16)
+    q = jax.random.normal(ks[1], (slots, 1, 4, 24), jnp.bfloat16)
+    wkv_b = jax.random.normal(ks[2], (128, 4 * (8 + 16)), jnp.bfloat16) * .1
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
+
+    def attend(order):
+        # a fresh function a path: jax caches a trace by function
+        text = str(jax.make_jaxpr(lambda q, p: T._attention_latent_paged(
+            cfg, q, wkv_b, p, read, order))(q, pool))
+        assert ("name=latent_read" in text) == (order is not None)
+        return np.asarray(jax.jit(lambda q, p: T._attention_latent_paged(
+            cfg, q, wkv_b, p, read, order))(q, pool), np.float32)
+
+    return attend(LATENT), attend(None), int(read[0])
+
+
+@pytest.mark.parametrize("pairs", [1, 3], ids=["a-pair-a-step", "three"])
+def test_latent_kernel_reads_what_the_gather_reads(pairs, every_block,
+                                                   monkeypatch):
+    """The five slots of the K/V test over a latent leaf: one row, a limit
+    in mid-page, a page's last row, no real token (its output 0, not NaN),
+    a full table row; ten live pairs, one step of the gather's loop for the
+    kernel's ten or four (whose last step runs two pairs past the total,
+    masked whole)."""
+    monkeypatch.setattr(PR, "pairs_a_step", lambda block, step: pairs)
+    got, want, steps = _latent_both_ways(
+        TABLE, jnp.array([0, 160, 383, 40, 511], jnp.int32), MASK,
+        monkeypatch)
+    assert steps == 1
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+    assert np.all(got[3] == 0) and np.all(want[3] == 0)
+    assert np.abs(want[[0, 1, 2, 4]]).min(-1).max() > 0
+
+
+def test_latent_pairs_past_the_list_fold_nothing(every_block, monkeypatch):
+    """Every table row full: twenty live pairs, the whole list; the kernel's
+    seventh step of three reads one place past it, which the wrapper's
+    padding fills with slot 0 and a limit of 0: masked by the total, not by
+    what it holds."""
+    monkeypatch.setattr(PR, "pairs_a_step", lambda block, step: 3)
+    got, want, steps = _latent_both_ways(
+        TABLE, jnp.full((SLOTS,), 511, jnp.int32), jnp.ones((SLOTS, 1), bool),
+        monkeypatch, seed=3)
+    assert steps == 2
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+
+
+def test_latent_list_of_several_steps(every_block, monkeypatch):
+    """Long slots: the gather's loop runs three steps of four pairs, the
+    kernel's grid four steps of three."""
+    monkeypatch.setattr(PR, "pairs_a_step", lambda block, step: 3)
+    table = jnp.arange(1, 13, dtype=jnp.int32).reshape(2, 6)[::-1]
+    got, want, steps = _latent_both_ways(
+        table, jnp.array([760, 320], jnp.int32), jnp.ones((2, 1), bool),
+        monkeypatch, seed=7)
+    assert steps == 3
+    np.testing.assert_allclose(got, want, atol=2e-2, rtol=2e-2)
+
+
+def test_latent_no_live_pair_at_all(every_block, monkeypatch):
+    got, want, steps = _latent_both_ways(
+        TABLE, START, jnp.zeros((SLOTS, 1), bool), monkeypatch)
+    assert steps == 0 and np.all(got == 0) and np.all(want == 0)
+
+
 def _pools(heads=16, width=128, dtype=jnp.bfloat16, page=16, scales=False):
     leaf = jax.ShapeDtypeStruct((40, page, heads, width), dtype)
     pools = {"k": leaf, "v": leaf}
@@ -139,6 +227,10 @@ def _pools(heads=16, width=128, dtype=jnp.bfloat16, page=16, scales=False):
         plane = jax.ShapeDtypeStruct((40, page), jnp.float32)
         pools.update(k_scale=plane, v_scale=plane)
     return pools
+
+
+def _latent(width=576, page=128, dtype=jnp.bfloat16):
+    return {"latent": jax.ShapeDtypeStruct((40, page, width), dtype)}
 
 
 RULE = {
@@ -179,6 +271,33 @@ RULE = {
         dict(pools=_pools(8), pool_order=None), True, "gather"),
     "a-backend-that-is-not-a-tpu": (
         dict(pools=_pools(), pool_order=None), None, "gather"),
+    # a latent leaf [N, page, r + rd], r of its columns a row's values
+    "a-latent-leaf-stored-page-rows-minor": (
+        dict(pools=_latent(), pool_order=LATENT, values=512), True, "pages"),
+    "a-latent-tick-compiled-for-the-chip": (
+        dict(pools=_latent(), pool_order={"latent": LATENT}, values=512),
+        False, "pages"),
+    "a-latent-prompts-or-a-verify-block": (
+        dict(pools=_latent(), pool_order=LATENT, values=512, tokens=5), True,
+        "gather"),
+    "a-latent-leaf-observed-row-major": (
+        dict(pools=_latent(), pool_order=None, values=512), True, "gather"),
+    "a-float32-latent-leaf": (
+        dict(pools=_latent(dtype=jnp.float32), pool_order=LATENT, values=512,
+             query=_queries(dtype=jnp.float32)), True, "gather"),
+    "float32-queries-over-a-bfloat16-latent-leaf": (
+        dict(pools=_latent(), pool_order=LATENT, values=512,
+             query=_queries(dtype=jnp.float32)), True, "gather"),
+    "latent-values-that-are-no-whole-lanes": (
+        dict(pools=_latent(), pool_order=LATENT, values=120), True, "gather"),
+    "a-latent-page-of-half-a-lane-tile": (
+        dict(pools=_latent(page=64), pool_order=LATENT, values=512), True,
+        "gather"),
+    "more-latent-slots-than-stay-on-chip": (
+        dict(pools=_latent(), pool_order=LATENT, values=512,
+             query=_queries(512, 128)), True, "gather"),
+    "a-latent-leaf-where-no-kernel-may-run": (
+        dict(pools=_latent(), pool_order=LATENT, values=512), None, "gather"),
 }
 
 
@@ -215,10 +334,23 @@ def test_a_sharded_mesh_keeps_the_gather(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH", None)
     assert T.kv_read_path(_pools(page=128), None, _queries()) == "pages"
+    assert T.kv_read_path(_latent(), LATENT, _queries(),
+                          values=512) == "pages"
     monkeypatch.setattr(mesh_mod, "_GLOBAL_MESH",
                         mesh_mod.build_mesh(mesh_mod.MeshLayout(dp=2),
                                             jax.devices()[:2]))
     assert T.kv_read_path(_pools(page=128), None, _queries()) == "gather"
+    assert T.kv_read_path(_latent(), LATENT, _queries(),
+                          values=512) == "gather"
+
+
+@functools.lru_cache(maxsize=None)
+def _params(config):
+    """``config()``'s parameters in its dtype, made once a module: two tests
+    a model read them, and making them is seconds."""
+    cfg = config()
+    return jax.tree_util.tree_map(lambda a: a.astype(cfg.dtype),
+                                  init_params(cfg, jax.random.PRNGKey(1)))
 
 
 def _olmo(**over):
@@ -249,12 +381,12 @@ CACHES = {
         {"k": "gather", "v": "gather", "k_scale": "gather",
          "v_scale": "gather"}),
     "the-latent-leaf": (
-        get_config("kanana-2-30b-a3b", num_layers=2, hidden_size=64,
-                   intermediate_size=96, vocab_size=256, max_seq_len=512,
-                   num_heads=4, head_dim=24, v_head_dim=16, rotary_dim=8,
-                   kv_lora_rank=120, num_experts=16, moe_experts_held=4,
-                   moe_top_k=3, moe_intermediate_size=32,
-                   dtype=jnp.bfloat16), {}, None, {"latent": "gather"}),
+        _kanana(), dict(page=128), LATENT, {"latent": "pages"}),
+    "the-latent-leaf-observed-row-major": (
+        _kanana(), dict(page=128), None, {"latent": "gather"}),
+    "a-latent-leaf-of-values-that-are-no-whole-lanes": (
+        _kanana(kv_lora_rank=120, rotary_dim=8), dict(page=128), LATENT,
+        {"latent": "gather"}),
     "two-kinds-of-layer": (
         get_config("mimo-v2.5", num_layers=7, hidden_size=64,
                    intermediate_size=96, vocab_size=256, max_seq_len=512,
@@ -274,9 +406,10 @@ CACHES = {
 @pytest.mark.parametrize("case", list(CACHES))
 def test_every_leaf_of_a_cache_says_its_path(case, every_block, monkeypatch):
     cfg, cache_kw, order, want = CACHES[case]
+    cache_kw = dict(cache_kw)
     monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
     cache = jax.eval_shape(lambda: T.init_paged_cache(
-        cfg, 9, 16, dtype=jnp.bfloat16, **cache_kw))
+        cfg, 9, cache_kw.pop("page", 16), dtype=jnp.bfloat16, **cache_kw))
     assert T.kv_read_paths(cfg, cache, order) == want
     # what the executor reports is what the trace holds: one kernel a layer
     # with attention where K and V are said to be read by pages (in a layer
@@ -289,82 +422,112 @@ def test_every_leaf_of_a_cache_says_its_path(case, every_block, monkeypatch):
         jnp.arange(1, 9, dtype=jnp.int32).reshape(2, 4),
         jnp.array([3, 9], jnp.int32), jnp.ones((2, 1), bool),
         pool_order=order))(params, cache))
-    walked = T.kind_layers(cfg)["full"][1] if T.is_hybrid(cfg) else 1
+    # (a latent model's dense layer and its expert layers: a scan each)
+    walked = (T.kind_layers(cfg)["full"][1] if T.is_hybrid(cfg)
+              else len(T.layer_groups(cfg)) if T.is_grouped(cfg) else 1)
     # (the kernel is jitted: one call a site, its one body shared)
-    assert len(re.findall(r"jit\[\s*name=paged_read", traced)) == (
-        walked if want.get("k") == "pages" else 0)
+    assert len(re.findall(r"jit\[\s*name=(?:paged|latent)_read", traced)) == (
+        walked if "pages" in want.values() else 0)
     # nowhere but on a TPU: every leaf gathered
     monkeypatch.setattr(MX, "_pallas_interpret", lambda: None)
     assert set(T.kv_read_paths(cfg, cache, order).values()) == {"gather"}
 
 
+# model: its config, the page, the prompts' block, a prompt a slot, the order
+# handed to ``forward_paged``, the cache's kw, the layers' call sites of the
+# kernel (Olmo's one attention layer; Kanana's dense layer's scan and its
+# expert layer's), the paged leaves and the kernel's name
+TICKS = {
+    "olmo-hybrid": (_olmo, 16, 20, (5, 9, 3, 20), None, dict(slots=4), 1,
+                    ("k", "v"), "paged_read"),
+    "kanana": (_kanana, 128, 256, (40, 130, 3, 200), LATENT, {}, 2,
+               ("latent",), "latent_read"),
+}
+
+
+@pytest.mark.parametrize("model", list(TICKS))
 def test_a_decode_tick_through_forward_paged_is_the_gather_ticks(
-        every_block, monkeypatch):
-    """A prompt's block (it attends within itself either way) and two ticks
-    of ``forward_paged`` of a toy Olmo-Hybrid over four slots, one masked and
-    one in its second page, with the kernel in the attention layers: the
+        model, every_block, monkeypatch):
+    """A prompt a slot (its block attends within itself either way) and two
+    ticks of ``forward_paged`` of a toy model over four slots, one masked
+    and one in its second page, with the kernel in the attention layers: the
     gather tick's logits to bfloat16 rounding, and the same pool."""
-    cfg = _olmo()
-    params = jax.tree_util.tree_map(
-        lambda a: a.astype(cfg.dtype),
-        init_params(cfg, jax.random.PRNGKey(2)))
+    config, page, block, lengths, order, cache_kw, sites, leaves, name = \
+        TICKS[model]
+    cfg, params = config(), _params(config)
     table = jnp.arange(1, 9, dtype=jnp.int32).reshape(4, 2)
     mask = jnp.array([[True], [True], [False], [True]])
+    toks = jax.random.randint(jax.random.PRNGKey(3), (4, block), 0, 256)
     monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
 
+    def stepper():
+        # a fresh function a path: jax caches a trace by function
+        return jax.jit(lambda c, t, s, m, at: T.forward_paged(
+            cfg, params, t, c, table[at], s, m, pool_order=order,
+            **({"state_slot": at} if t.shape[1] > 1 and T.has_state(cfg)
+               else {})))
+
+    filled = T.init_paged_cache(cfg, 9, page, dtype=jnp.bfloat16, **cache_kw)
+    prompt = stepper()
+    for b, n in enumerate(lengths):                 # a prompt a slot
+        _, filled = prompt(filled, toks[b:b + 1], jnp.zeros((1,), jnp.int32),
+                           (jnp.arange(block) < n)[None],
+                           jnp.array([b], jnp.int32))
+
     def ticks():
-        cache = T.init_paged_cache(cfg, 9, 16, dtype=jnp.bfloat16, slots=4)
-        step = jax.jit(lambda c, t, s, m, at: T.forward_paged(
-            cfg, params, t, c, table[at], s, m,
-            **({"state_slot": at} if t.shape[1] > 1 else {})))
-        toks = jax.random.randint(jax.random.PRNGKey(3), (4, 20), 0, 256)
-        outs = []
-        for b, n in enumerate((5, 9, 3, 20)):       # a prompt a slot
-            logits, cache = step(
-                cache, toks[b:b + 1], jnp.zeros((1,), jnp.int32),
-                (jnp.arange(20) < n)[None], jnp.array([b], jnp.int32))
+        cache, step, outs = filled, stepper(), []
         for add in (0, 1):
-            start = jnp.array([5, 9, 3, 20], jnp.int32) + add
-            logits, cache = step(cache, toks[:, :1] + add, start, mask,
-                                 jnp.arange(4, dtype=jnp.int32))
+            logits, cache = step(
+                cache, toks[:, :1] + add, jnp.array(lengths, jnp.int32) + add,
+                mask, jnp.arange(4, dtype=jnp.int32))
             outs.append(np.asarray(logits, np.float32))
         traced = str(jax.make_jaxpr(lambda c: T.forward_paged(
-            cfg, params, toks[:, :1], c, table,
-            jnp.zeros((4,), jnp.int32), mask))(cache))
+            cfg, params, toks[:, :1], c, table, jnp.zeros((4,), jnp.int32),
+            mask, pool_order=order))(cache))
         return outs, cache, traced
 
     monkeypatch.setattr(PR, "MIN_BLOCK_BYTES", 1 << 30)
     want, cache_g, text_g = ticks()
-    assert "name=paged_read" not in text_g
+    assert f"name={name}" not in text_g
     monkeypatch.setattr(PR, "MIN_BLOCK_BYTES", 0)
     got, cache_k, text_k = ticks()
-    # the attention layer
-    assert len(re.findall(r"jit\[\s*name=paged_read", text_k)) == 1
+    assert len(re.findall(rf"jit\[\s*name={name}", text_k)) == sites
     for a, b in zip(got, want):
         np.testing.assert_allclose(a[[0, 1, 3]], b[[0, 1, 3]], atol=3e-2,
                                    rtol=3e-2)
         assert np.array_equal(a[[0, 1, 3]].argmax(-1), b[[0, 1, 3]].argmax(-1))
-    # the first tick's K/V rows do not depend on the read
-    for leaf in ("k", "v"):
+    # the first layer's rows do not depend on the read
+    for leaf in leaves:
         np.testing.assert_allclose(
             np.asarray(cache_k[leaf][0], np.float32),
             np.asarray(cache_g[leaf][0], np.float32), atol=0)
 
 
-def test_the_engine_says_how_it_reads(every_block, monkeypatch):
+# model: its config, the page, a slot's rows, its paged leaves, and the order
+# the test observes in the compiler's place where the kernel is to read (the
+# CPU stores every leaf row-major; a latent leaf asks to be seen as the v5e
+# stores it)
+ENGINES = {
+    "olmo-hybrid": (_olmo, 16, 64, ("k", "v"), None),
+    "kanana": (_kanana, 128, 256, ("latent",), LATENT),
+}
+
+
+@pytest.mark.parametrize("model", list(ENGINES))
+def test_the_engine_says_how_it_reads(model, every_block, monkeypatch):
     """Through ``engine.serving``: the executor's report, ``health()``, the
     ready line, ``gathered_rows`` on every ``serve.decode`` span (live pages
     where the kernel reads, whole steps where the gather does), and the
     gather engine's tokens."""
+    from deepspeed_tpu.inference import execution
     from deepspeed_tpu.observability import (Span, configure_tracer,
                                              get_tracer)
     from deepspeed_tpu.parallel.mesh import MeshLayout, initialize_mesh
     from deepspeed_tpu.utils.logging import logger
 
-    cfg = _olmo()
+    config, page, rows, leaves, seen = ENGINES[model]
     engine = deepspeed_tpu.init_inference(
-        model=CausalLM(cfg), params=init_params(cfg, jax.random.PRNGKey(1)),
-        dtype="bf16",
+        model=CausalLM(config()), params=_params(config), dtype="bf16",
         mesh=initialize_mesh(MeshLayout(), devices=jax.devices()[:1]))
     rng = np.random.default_rng(0)
     reqs = [Request(rid=f"r{i}", arrival_time=0.0, max_new_tokens=5,
@@ -384,7 +547,7 @@ def test_the_engine_says_how_it_reads(every_block, monkeypatch):
         said = Lines()
         logger.addHandler(said)
         try:
-            sv = engine.serving(b_slots=3, page_size=16, max_model_len=64)
+            sv = engine.serving(b_slots=3, page_size=page, max_model_len=rows)
         finally:
             logger.removeHandler(said)
         ready = [ln for ln in said.lines if "serving engine ready" in ln]
@@ -401,23 +564,27 @@ def test_the_engine_says_how_it_reads(every_block, monkeypatch):
         assert attrs and sv.page_accounting()["balanced"]
         return sv, out, attrs, ready[-1]
 
+    def says(sv, ready, path):
+        report = {leaf: path for leaf in leaves}
+        assert sv._exec.mesh_info()["kv_read"] == report
+        assert sv.health()["kv_read"] == report
+        assert "kv_read=" + ",".join(f"{leaf}:{path}"
+                                     for leaf in leaves) in ready
+
     sv, want, attrs, ready = run()
-    gather = {"k": "gather", "v": "gather"}
-    assert sv._exec.mesh_info()["kv_read"] == gather
-    assert sv.health()["kv_read"] == gather
-    assert "kv_read=k:gather,v:gather" in ready
-    # 3 slots x 2 pairs a step x 16 rows: whole steps
-    assert {a["gathered_rows"] % 96 for a in attrs} == {0}
+    says(sv, ready, "gather")
+    # 3 slots x 2 pairs a step x a page's rows: whole steps
+    step = 3 * 2 * page
+    assert {a["gathered_rows"] % step for a in attrs} == {0}
     monkeypatch.setattr(MX, "_pallas_interpret", lambda: True)
+    if seen is not None:
+        monkeypatch.setattr(execution, "paged_pool_order", lambda leaf: seen)
     sv, got, attrs, ready = run()
-    pages = {"k": "pages", "v": "pages"}
-    assert sv._exec.mesh_info()["kv_read"] == pages
-    assert sv.health()["kv_read"] == pages
-    assert "kv_read=k:pages,v:pages" in ready
-    assert any(a["gathered_rows"] % 96 for a in attrs)
-    assert all(a["gathered_rows"] % 16 == 0
+    says(sv, ready, "pages")
+    assert any(a["gathered_rows"] % step for a in attrs)
+    assert all(a["gathered_rows"] % page == 0
                and a["live_rows"] <= a["gathered_rows"] < a["live_rows"]
-               + 16 * 3 for a in attrs)
+               + page * 3 for a in attrs)
     assert got == want
 
 

@@ -16,7 +16,14 @@ hands them over: slots, heads, the page and how the device stores the leaf,
 and slots' lengths as the cell's traffic makes them.  ``kernel/<n>`` takes
 ``n`` pairs a grid step; ``rule`` is what :func:`kv_read_path` chooses for
 the shape.  The table is what ``paged_read.MIN_BLOCK_BYTES`` is set from
-(PERF.md section 5, PR 52)."""
+(PERF.md section 5, PR 52).
+
+The ``kanana`` cases are a latent leaf's (PR 57): one row ``[c ; k_pe]`` a
+token and no head axis, stored page-rows-minor; ``gather`` is
+``_attention_latent_paged``'s loop and ``kernel/<n>`` the same function
+where the rule says ``"pages"`` (``paged_read.latent_read``), both with the
+absorbed products around the read (32 rows against ``wkv_b``: under 1% of
+either); the roof counts the live pages' latent bytes once."""
 import argparse
 import json
 import os
@@ -32,10 +39,18 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 PEAK_BYTES = 819e9                              # one v5e (PERF.md section 2)
 HEAD_MAJOR = (0, 1, 3, 2, 4)
+LATENT = (0, 1, 3, 2)           # transformer.LATENT_PAGE_ROWS_MINOR
 
 # name: (slots, Hq, Hkv, head width, page, pages a table row, the stored
-# order of the unstacked leaf, (live slots, shortest, longest) rows held)
+# order of the unstacked leaf, (live slots, shortest, longest) rows held);
+# a latent leaf's: its value columns (r) for Hkv, its row (r + rd) for the
+# head width
 CASES = {
+    # kanana-2-30b-a3b-ep8-d24.longctx-backlog: the first fill (20 prompts
+    # in) and the middle of the window (every slot live, 2k-7k rows behind it)
+    "kanana_fill": (32, 32, 512, 576, 128, 64, LATENT, (20, 2048, 6144)),
+    "kanana_window": (32, 32, 512, 576, 128, 64, LATENT, (32, 2300, 7000)),
+    "tiny_latent": (3, 4, 128, 144, 128, 3, LATENT, (2, 100, 380)),
     # olmo-hybrid-7b-d16.thinkrollout-backlog: the first fill (prompts and a
     # little more) and the middle of the window
     "olmo_fill": (32, 30, 30, 128, 128, 16, HEAD_MAJOR, (32, 400, 800)),
@@ -55,11 +70,21 @@ def operands(case, seed=0):
     rng = np.random.default_rng(seed)
     n = 1 + slots * maxp
     key = jax.random.PRNGKey(seed)
-    shape = ((n, hkv, page, hd) if order == HEAD_MAJOR
-             else (n, page, hkv, hd))
-    k, v = (jax.random.normal(jax.random.fold_in(key, i), shape, jnp.bfloat16)
-            for i in (1, 2))
-    q = jax.random.normal(key, (slots, 1, hq, hd), jnp.bfloat16)
+    if order == LATENT:
+        # the leaf as the device stores it; K's place holds it, V's wkv_b
+        # [r, Hq * (nope + vd)]; queries of nope + rd = 128 + (hd - r)
+        k = jax.random.normal(jax.random.fold_in(key, 1), (n, hd, page),
+                              jnp.bfloat16)
+        v = jax.random.normal(jax.random.fold_in(key, 2),
+                              (hkv, hq * 256), jnp.bfloat16) * hkv ** -0.5
+        q = jax.random.normal(key, (slots, 1, hq, 128 + hd - hkv),
+                              jnp.bfloat16)
+    else:
+        shape = ((n, hkv, page, hd) if order == HEAD_MAJOR
+                 else (n, page, hkv, hd))
+        k, v = (jax.random.normal(jax.random.fold_in(key, i), shape,
+                                  jnp.bfloat16) for i in (1, 2))
+        q = jax.random.normal(key, (slots, 1, hq, hd), jnp.bfloat16)
     table = jnp.asarray(rng.permutation(n - 1)[:slots * maxp].reshape(
         slots, maxp) + 1, jnp.int32)
     start = np.zeros(slots, np.int32)
@@ -86,6 +111,8 @@ def main():
     ap.add_argument("--cases", default=",".join(
         c for c in CASES if not c.startswith("tiny")))
     ap.add_argument("--pairs", default="1,2,4,8")
+    ap.add_argument("--latent-pairs", default="1,3,6,8",
+                    help="pairs a grid step, the latent cases'")
     ap.add_argument("--reps", type=int, default=8,
                     help="reads chained in one program")
     ap.add_argument("--interpret", action="store_true",
@@ -110,15 +137,21 @@ def main():
 
     for case in a.cases.split(","):
         slots, hq, hkv, hd, page, maxp, order, _ = CASES[case]
-        cfg = get_config("olmoe-1b-7b", num_layers=1, num_heads=hq,
-                         num_kv_heads=hkv, head_dim=hd, hidden_size=hq * hd,
-                         dtype=jnp.bfloat16)
+        latent = order == LATENT
+        cfg = (get_config("kanana-2-30b-a3b", num_layers=1, num_heads=hq,
+                          kv_lora_rank=hkv, rotary_dim=hd - hkv,
+                          head_dim=128 + hd - hkv, v_head_dim=128,
+                          dtype=jnp.bfloat16) if latent else
+               get_config("olmoe-1b-7b", num_layers=1, num_heads=hq,
+                          num_kv_heads=hkv, head_dim=hd, hidden_size=hq * hd,
+                          dtype=jnp.bfloat16))
         q, k, v, table, start, mask = operands(case)
         read = jax.jit(lambda t, s, m: T._paged_read_plan(t, s, m, page))(
             table, start, mask)
         pairs_live = int(jnp.sum(read[1] < slots))
-        block = hkv * page * hd * 2
-        roof_ms = pairs_live * 2 * block / PEAK_BYTES * 1e3
+        # K's and V's block of a pair; a latent pair's one block is both
+        block = (hd if latent else hkv * hd) * page * 2
+        roof_ms = pairs_live * (1 if latent else 2) * block / PEAK_BYTES * 1e3
 
         def logical(a):          # as forward_paged hands a head-major leaf
             return jnp.transpose(a, (0, 2, 1, 3)) if order else a
@@ -130,7 +163,10 @@ def main():
             def f(q, k, v, read):
                 out = read_once(q, k, v, read)
                 for _ in range(a.reps - 1):
-                    out = read_once(q + out * 1e-3, k, v, read)
+                    # (a latent read's output is not as wide as its queries)
+                    out = read_once(q + (out if out.shape == q.shape
+                                         else out[..., :1]) * 1e-3,
+                                    k, v, read)
                 return out
             return jax.jit(f)
 
@@ -154,6 +190,20 @@ def main():
                         ).astype(q.dtype)[:, None]
             return f
 
+        def latent_attend(interpret, n=None):
+            """``_attention_latent_paged`` where no kernel may run (the
+            gather), or where one may, ``n`` pairs a step."""
+            def f(q, c, wkv_b, read):
+                MX._pallas_interpret = lambda: interpret
+                if n is not None:
+                    # whatever the block's bytes: this table sets the bound
+                    PR.pairs_a_step, PR.MIN_BLOCK_BYTES = (
+                        lambda block, step: n), 0
+                    PR.latent_read.clear_cache()    # a trace a shape
+                return T._attention_latent_paged(
+                    cfg, q, wkv_b, jnp.transpose(c, (0, 2, 1)), read, order)
+            return f
+
         want = None
 
         def report(impl, fn):
@@ -173,6 +223,24 @@ def main():
                 roof_share=round(roof_ms / ms, 4),
                 max_err=float(np.abs(got - want).max()))
 
+        if latent:
+            report("gather", latent_attend(None))
+            if PR.latent_block(k.shape, hkv, k.dtype) is None:
+                say(case=case, impl="kernel",
+                    error="no tile plan for the leaf")
+                continue
+            rule = PR.pairs_a_step, PR.MIN_BLOCK_BYTES
+            for n in a.latent_pairs.split(","):
+                report(f"kernel/{n}", latent_attend(a.interpret, int(n)))
+            PR.pairs_a_step, PR.MIN_BLOCK_BYTES = rule
+            say(case=case, impl="rule", block_bytes=block,
+                pairs_a_step=PR.pairs_a_step(block, PR.LATENT_STEP_BYTES),
+                path=T.kv_read_path(
+                    {"latent": jax.ShapeDtypeStruct((k.shape[0], page, hd),
+                                                    k.dtype)},
+                    order, jax.ShapeDtypeStruct((slots, hq), q.dtype),
+                    values=hkv))
+            continue
         report("gather", attend(None))
         if PR.page_block(k.shape, v.shape, k.dtype,
                          "ktd" if order else "tkd") is None:
