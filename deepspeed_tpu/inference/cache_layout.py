@@ -134,7 +134,10 @@ class CacheLayout:
         into which the tick stores a token's row where it lies, and those it
         merges a slot's whole page into (static a program).  Two
         kinds of layer: K/V head rows read and live a kind (a window layer
-        reads the ring pages under its window).
+        reads the ring pages under its window), ``kv_slots_live`` the live
+        slots and ``kv_slots_past_window`` those of them that hold more rows
+        than the window (their rings have wrapped: a window layer reads fewer
+        rows of them than a full one).
         A state a slot: the slots whose state the tick read and wrote, the
         bytes of one reading over the layers that have one
         (``state_layers``), its step's passes, and the live token rows over
@@ -152,7 +155,9 @@ class CacheLayout:
             attrs.update(self._kv_row_attrs(
                 rows, int(lengths.sum()),
                 window_read_rows(lengths, self.page_size, W, slots),
-                int(np.minimum(lengths, W).sum())))
+                int(np.minimum(lengths, W).sum())),
+                kv_slots_live=len(lengths),
+                kv_slots_past_window=int((lengths > W).sum()))
         if self.stateful:
             # ``state_bytes`` over the layers with a mixer, ``kv_live_rows``
             # (token rows x layers) over those with attention

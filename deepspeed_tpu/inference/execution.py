@@ -914,7 +914,8 @@ class MeshExecutor:
         leaves over every layer and pass.  ``cache_kind``
         (``models.transformer.cache_kind``) and how deep its leaves are:
         ``kv_layers`` layers with K/V pages, ``state_layers`` with a state row
-        a slot."""
+        a slot, ``ring_pages`` pages a slot in a window layer's ring (0: no
+        window layers)."""
         mesh = self.mesh
         return {"mesh_devices": 1 if mesh is None else int(mesh.size),
                 "mesh_axes": {} if mesh is None else {
@@ -930,7 +931,8 @@ class MeshExecutor:
                 "kv_bytes_per_token": self.layout.kv_token_bytes,
                 "cache_kind": self.layout.kind,
                 "kv_layers": self.layout.kv_layers,
-                "state_layers": self.layout.state_layers}
+                "state_layers": self.layout.state_layers,
+                "ring_pages": self.layout.ring_pages}
 
     def expert_matmul(self) -> Dict[str, str]:
         """How each compiled program of a model with dropless expert layers

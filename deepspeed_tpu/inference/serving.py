@@ -781,6 +781,8 @@ class ServingEngine:
             f"leaf a layer, {info['weight_leaves_relaid']} leaf(s) "
             f"({info['weight_bytes_relaid'] / 1e6:.1f} MB) re-laid out"
             + f" cache={info['cache_kind']} kv_layers={info['kv_layers']}"
+            + (f" ring_pages={info['ring_pages']}"
+               if info["ring_pages"] else "")
             + "".join(f" state_layers={info['state_layers']} "
                       f"{step}={info[step]}"
                       for step in (m.step_key for m in MIXERS.values())

@@ -115,6 +115,14 @@ class TransformerConfig:
     window_kv_heads: Optional[int] = None     # None => num_kv_heads
     window_rope_theta: Optional[float] = None  # None => rope_theta
     window_attn_sink: bool = False
+    # the position rule of the window layers where it is not the model's
+    # (Trinity, ``afmoe``: ``position="none"`` and ``window_position="rope"``,
+    # window layers rotate q and k and full layers carry no position at all)
+    window_position: Optional[str] = None     # None => position
+    # a gate on attention's output (Trinity's ``gate_proj``): one more leaf a
+    # layer, ``wg [d, Hq * vd]``, made from the normed input beside q, k and
+    # v: ``(attn * sigmoid(h wg)) wo``
+    attn_output_gate: bool = False
     # values narrower (or wider) than keys, and scaled after the projection
     v_head_dim: Optional[int] = None          # None => head_dim
     attn_value_scale: float = 1.0
@@ -344,6 +352,8 @@ class TransformerConfig:
             attn += sum(qk_norm_widths(self))
         if self.window_attn_sink:
             attn += nh
+        if self.attn_output_gate:
+            attn += d * vd * nh
         if not sublayers(self)[0]:      # a mixer in attention's place
             attn = 0
         attn += sum(m.param_count(self) for m in mixers_of(self))
@@ -551,6 +561,29 @@ CONFIGS: Dict[str, TransformerConfig] = {
         num_experts=32, moe_top_k=4, moe_score_func="sigmoid",
         moe_select_bias=True, moe_norm_topk_prob=True, moe_norm_topk_eps=1e-6,
         moe_drop_tokens=False, tie_embeddings=True, remat=False),
+    # arcee-ai/Trinity-Large-Preview config.json (``afmoe``, 400B-A13B): 60
+    # layers, three of a 4,096-wide sliding window (rotary, theta 1e4) then
+    # one of full attention WITHOUT any position, fifteen times; 48 heads
+    # over 8 KV heads of 128, QK-norm by head, a sigmoid gate on attention's
+    # output; four RMSNorms a layer (eps 1e-5); layers 0-5 a dense SwiGLU of
+    # 12,288, the rest 256 experts of 3,072 beside one shared, sigmoid
+    # scores, 4 a token chosen on score + bias, gates renormalised (sum +
+    # 1e-20) and scaled by 2.448; the embedding x sqrt(3,072)
+    # (``mup_enabled``); untied head over 200,192 ids
+    "trinity-large-preview": TransformerConfig(
+        vocab_size=200192, hidden_size=3072, intermediate_size=12288,
+        moe_intermediate_size=3072, num_layers=60, num_heads=48,
+        num_kv_heads=8, head_dim=128, max_seq_len=262144, norm_eps=1e-5,
+        rope_theta=1e4, position="none", window_position="rope",
+        qk_norm="head", attn_output_gate=True, sandwich_norm=True,
+        embed_multiplier=55.42562584220407,
+        layer_pattern=tuple("full" if i % 4 == 3 else "window"
+                            for i in range(60)),
+        window_size=4096, dense_layers=6,
+        num_experts=256, moe_top_k=4, moe_score_func="sigmoid",
+        moe_select_bias=True, moe_norm_topk_prob=True,
+        moe_norm_topk_eps=1e-20, moe_routed_scale=2.448,
+        moe_shared_experts=1, moe_drop_tokens=False, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -601,7 +634,7 @@ def has_moe(cfg: TransformerConfig) -> bool:
 
 # the attention projections: what a layer with the mixer in attention's
 # place (:func:`sublayers`) does not have
-_ATTN_LEAVES = ("wq", "wk", "wv", "wo")
+_ATTN_LEAVES = ("wq", "wk", "wv", "wo", "wg")
 
 # The per-expert leaves of an MoE layer, ``[L, E, ...]`` in the layer stack:
 # the matmul weights and (gelu experts) their per-expert biases.
@@ -725,6 +758,11 @@ def layer_plan(cfg: TransformerConfig):
     the group, kind, dense)`` each, ``group`` = ``<kind>_<dense|moe>``
     (every layer ``full`` without a ``layer_pattern``)."""
     pattern = cfg.layer_pattern or ("full",) * cfg.num_layers
+    if cfg.window_position not in (None, "rope", "none"):
+        # a table of learned positions or ALiBi's slopes is the whole
+        # model's (the embedding, the read's mask), not a kind of layer's
+        raise ValueError(f"window_position={cfg.window_position!r}: "
+                         "None | 'rope' | 'none'")
     if len(pattern) < cfg.num_layers:
         raise ValueError(
             f"layer_pattern has {len(pattern)} entries for "
@@ -768,6 +806,8 @@ def layer_groups(cfg: TransformerConfig):
                           and cfg.window_kv_heads else cfg.num_kv_heads),
             rope_theta=(cfg.window_rope_theta if window
                         and cfg.window_rope_theta else cfg.rope_theta),
+            position=(cfg.window_position if window and cfg.window_position
+                      else cfg.position),
             window_attn_sink=window and cfg.window_attn_sink,
             moe_intermediate_size=None if dense else cfg.moe_intermediate_size,
             num_experts=1 if dense else cfg.num_experts,
@@ -975,9 +1015,11 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         # tokens at these weights (std^2 * d), so that it takes a real
         # share of a row's probability: a checkpoint learns it
         layers["attn_sink"] = dense(keys[16], (L, nh), std * std * d)
+    if cfg.attn_output_gate:
+        layers["wg"] = dense(jax.random.fold_in(rng, 19), (L, d, nh * vd))
     if not has_attn:
         for name in _ATTN_LEAVES:
-            del layers[name]
+            layers.pop(name, None)
     for m in mixers_of(cfg):
         layers.update(m.init(cfg, rng, dense))
     if not cfg.shared_layernorm:   # GPT-J shares the attention LN
@@ -1229,9 +1271,11 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         layers.update(q_norm_scale=P(None, by), k_norm_scale=P(None, by))
     if cfg.window_attn_sink:
         layers["attn_sink"] = P(None, "model")
+    if cfg.attn_output_gate:
+        layers["wg"] = col
     if not has_attn:
         for name in _ATTN_LEAVES:
-            del layers[name]
+            layers.pop(name, None)
     for m in mixers_of(cfg):
         layers.update(m.specs(cfg))
     if not cfg.shared_layernorm:
@@ -1942,11 +1986,29 @@ def _latent_expand(cfg: TransformerConfig, latent, wkv_b):
     return k, jnp.einsum("bsr,rhv->bshv", c, w_uv)
 
 
-def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None):
-    """Attention output ``[B,S,Hq,hd]`` through the output projection."""
+def _attn_gate(cfg: TransformerConfig, lp: Dict[str, Any], h, proj=None):
+    """Post-norm activations ``h [B,S,d]`` -> the gate on attention's output
+    ``sigmoid(h wg) [B,S,Hq * vd]`` (``attn_output_gate``: made from the
+    input q, k and v are made from, applied by :func:`_attn_out` in front of
+    ``wo``); None for a model without one."""
+    if not cfg.attn_output_gate:
+        return None
+    with jax.named_scope("attn_gate"):
+        g = h @ lp["wg"]
+        if proj is not None:
+            g = proj(g, "wg", h)
+        return jax.nn.sigmoid(g.astype(jnp.float32)).astype(g.dtype)
+
+
+def _attn_out(cfg: TransformerConfig, lp: Dict[str, Any], attn, proj=None,
+              gate=None):
+    """Attention output ``[B,S,Hq,hd]`` through the output projection,
+    multiplied by ``gate`` (:func:`_attn_gate`) first where there is one."""
     B, S = attn.shape[:2]
     with jax.named_scope("attn_out"):
         attn2d = attn.reshape(B, S, -1)
+        if gate is not None:
+            attn2d = attn2d * gate
         out = attn2d @ lp["wo"]
         if proj is not None:
             out = proj(out, "wo", attn2d)
@@ -1997,7 +2059,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
                                    x += mlp(LN'(x))
       one mixer a layer (Granite   x += r ssm(LN(x))  or  x += r attn(LN(x));
       4.0-H, ``residual_multiplier``)                 x += r mlp(LN'(x))
-      sandwich (Ouro)              x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))
+      sandwich (Ouro, Trinity)     x += N2(attn(N1(x)));  x += N4(mlp(N3(x)))
+      gated (``attn_output_gate``) attn(n) = (softmax(q k) v * sigmoid(n Wg)) Wo
       norm after (Olmo-Hybrid,     x += N1(mix(x));  x += N2(mlp(x)), mix the
       ``norm_after``)              delta mixer or attention by the layer's kind
 
@@ -2044,8 +2107,9 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
         # (:func:`_attention`; the flash kernel names its own output inside
         # its vjp, in the layout its backward reads), so a remat policy can
         # keep it
+        gate = _attn_gate(cfg, lp, h, proj)
         attn, state = attend(q, k, v)
-        attn = _attn_out(cfg, lp, attn, proj)
+        attn = _attn_out(cfg, lp, attn, proj, gate)
         if cfg.sandwich_norm:
             attn = _norm(cfg, attn, lp["attn_post_norm_scale"])
         attn, rng = _dropout(cfg, attn, rng, deterministic)
@@ -3532,19 +3596,24 @@ WINDOW_BLOCK_CHUNKS = 16
 CAUSAL_BLOCK_CHUNK = 512
 
 
-def causal_walk_steps(block: int, tokens: Optional[int] = None) -> int:
+def causal_walk_steps(block: int, tokens: Optional[int] = None,
+                      window: Optional[int] = None) -> int:
     """Chunk steps a full or latent layer runs for a block of ``block``
     tokens that starts its slot and holds ``tokens`` real ones from its
     start (all of them if ``None``): chunk ``i`` of the ``r`` chunks that
     real tokens reach walks ``i + 1`` chunks of keys and a chunk past them
-    none, ``r (r + 1) / 2``; a short block is one masked product.  The
+    none, ``r (r + 1) / 2``; a short block is one masked product.  Under a
+    ``window`` (a window layer's walk) chunk ``i`` starts at the chunk that
+    holds its first query's oldest key, ``(i C - window + 1) // C``.  The
     host's copy of :func:`_attention_causal_block`'s trip counts (the
     ``walk_steps`` span attrs of a prompt)."""
     C = CAUSAL_BLOCK_CHUNK
     if block % C or block < 2 * C:
         return 1
     r = -(-min(block if tokens is None else tokens, block) // C)
-    return r * (r + 1) // 2
+    if window is None:
+        return r * (r + 1) // 2
+    return sum(i + 1 - max(i * C - window + 1, 0) // C for i in range(r))
 
 
 def block_read_rows(block: int, window: Optional[int] = None,
@@ -3556,21 +3625,22 @@ def block_read_rows(block: int, window: Optional[int] = None,
     (:func:`causal_walk_steps`), through a window layer each chunk of
     ``window`` queries two chunks of keys, of a long block the groups of
     ``WINDOW_BLOCK_CHUNKS`` chunks that hold a real token
-    (:func:`_attention_window_block`); a short block all of itself, once.
-    The host's copy of those functions' shapes (the ``kv_rows_*`` span
-    attrs of a prompt)."""
+    (:func:`_attention_window_block`), and where the window is longer than
+    a chunk of the causal walk that walk's chunks inside the window; a
+    short block all of itself, once.  The host's copy of those functions'
+    shapes (the ``kv_rows_*`` span attrs of a prompt)."""
     tokens = block if tokens is None else min(tokens, block)
-    if window is not None:
+    C = CAUSAL_BLOCK_CHUNK
+    if window is not None and window <= C:
         if block % window or block < 2 * window:
             return block
         group = WINDOW_BLOCK_CHUNKS * window
         if block > group and block % group == 0:
             block = -(-tokens // group) * group
         return 2 * block
-    C = CAUSAL_BLOCK_CHUNK
     if block % C or block < 2 * C:
         return block
-    return C * causal_walk_steps(block, tokens)
+    return C * causal_walk_steps(block, tokens, window)
 
 
 def _block_reach(seq_mask):
@@ -3585,10 +3655,15 @@ def _block_reach(seq_mask):
                              0))
 
 
-def _attention_causal_block(cfg, q, k, v, positions, reach=None):
+def _attention_causal_block(cfg, q, k, v, positions, reach=None, window=None,
+                            sink=None):
     """A block of tokens ``[B,S,...]`` that starts its slot, through a full
-    layer: plain causal attention over the block's own keys, values of
-    their own width, nothing read from the pool.  Where ``S`` is whole
+    layer (or, with ``window``, a window layer whose window is longer than a
+    chunk: the walk then starts at the chunk that holds the oldest key the
+    chunk's first query sees, and a key ``window`` or more positions back
+    is masked; ``sink``: the layer's learned logits, which join each row's
+    softmax at the end of its walk): plain causal attention over the block's
+    own keys, values of their own width, nothing read from the pool.  Where ``S`` is whole
     chunks of ``CAUSAL_BLOCK_CHUNK``, a chunk of queries walks the chunks of
     keys at or before it with a running maximum, sum and accumulator of its
     own size (float32), the diagonal chunk masked: the work is the causal
@@ -3608,11 +3683,14 @@ def _attention_causal_block(cfg, q, k, v, positions, reach=None):
     Hkv, vd, C = k.shape[2], v.shape[-1], CAUSAL_BLOCK_CHUNK
     if S % C or S < 2 * C:
         return _attention(cfg, q, k, v, positions, "xla",
-                          custom_positions=True)
+                          custom_positions=True, window=window, sink=sink)
     n, G = S // C, Hq // Hkv
     qc = jnp.moveaxis(q.reshape(B, n, C, Hkv, G, hd), 1, 0)
     diagonal = (jnp.arange(C, dtype=jnp.int32)[None, :]
                 <= jnp.arange(C, dtype=jnp.int32)[:, None])       # [Cq, Ck]
+    if window is not None:      # how far behind its query a key of the
+        back = (jnp.arange(C, dtype=jnp.int32)[:, None]   # same chunk lies
+                - jnp.arange(C, dtype=jnp.int32)[None, :])
 
     def chunk(args):
         i, qi = args                                  # qi [B,C,Hkv,G,hd]
@@ -3622,7 +3700,13 @@ def _attention_causal_block(cfg, q, k, v, positions, reach=None):
             kj = jax.lax.dynamic_slice_in_dim(k, j * C, C, axis=1)
             vj = jax.lax.dynamic_slice_in_dim(v, j * C, C, axis=1)
             s = jnp.einsum("bckgd,bjkd->bkgcj", qi, kj).astype(jnp.float32)
-            s = jnp.where(diagonal | (j < i), s * _sm_scale(cfg, hd), -1e30)
+            seen = diagonal | (j < i)
+            if window is not None:
+                # a row none of whose keys of the walk's first chunk is
+                # inside the window weighs them exp(0) under m = -1e30; the
+                # next chunk's real maximum scales that to exactly 0
+                seen = seen & ((i - j) * C + back < window)
+            s = jnp.where(seen, s * _sm_scale(cfg, hd), -1e30)
             m_new = jnp.maximum(m, s.max(-1))
             p = jnp.exp(s - m_new[..., None])
             alpha = jnp.exp(m - m_new)
@@ -3631,10 +3715,20 @@ def _attention_causal_block(cfg, q, k, v, positions, reach=None):
                     acc * alpha[..., None] + pv.astype(jnp.float32))
 
         steps = i + 1 if reach is None else jnp.where(i * C < reach, i + 1, 0)
+        first = (0 if window is None
+                 else jnp.maximum(i * C - window + 1, 0) // C)
         m0 = jnp.full((B, Hkv, G, C), -1e30, jnp.float32)
-        _, l, acc = jax.lax.fori_loop(0, steps, step, (
+        m, l, acc = jax.lax.fori_loop(first, steps, step, (
             m0, jnp.zeros_like(m0), jnp.zeros((B, Hkv, G, C, vd),
                                               jnp.float32)))
+        if sink is not None:
+            # the sink's term joins the sum under the larger of the two
+            # maxima, as at the end of :func:`_attention_paged`'s walk
+            b = sink.astype(jnp.float32).reshape(1, Hkv, G, 1)
+            m_new = jnp.maximum(m, b)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + jnp.exp(b - m_new)
+            acc = acc * alpha[..., None]
         # a chunk that ran no step has l == 0: its output is 0, not NaN
         return (acc / jnp.where(l > 0, l, 1.0)[..., None]).astype(q.dtype)
 
@@ -3653,9 +3747,17 @@ def _attention_window_block(cfg, q, k, v, positions, window: int, sink=None,
     shorter or ragged block takes the masked product.  A long block takes
     ``WINDOW_BLOCK_CHUNKS`` chunks at a time, and of those groups the ones
     that real tokens ``reach`` (:func:`_block_reach`; ``None``: all): a
-    group of a bucket's padding alone is not computed and comes out 0."""
+    group of a bucket's padding alone is not computed and comes out 0.
+
+    A window longer than a chunk of the causal walk (``CAUSAL_BLOCK_CHUNK``;
+    Trinity's 4,096, where a chunk of ``window`` queries against two of keys
+    is 0.8 GB of float32 scores a KV head) takes that walk, bounded below by
+    the window (:func:`_attention_causal_block`)."""
     B, S, Hq, hd = q.shape
     Hkv, C = k.shape[2], window
+    if window > CAUSAL_BLOCK_CHUNK:
+        return _attention_causal_block(cfg, q, k, v, positions, reach, window,
+                                       sink)
     if S % C or S < 2 * C:
         return _attention(cfg, q, k, v, positions, "xla",
                           custom_positions=True, window=window, sink=sink)
@@ -3872,6 +3974,12 @@ def _head_at(cfg, params, x, logits_at):
     return _head(cfg, params, x)
 
 
+# Bytes of weights and cache in a call past which a prompt's block of a
+# model of window and full layers pins x after each layer
+# (:func:`_forward_paged_hybrid`): three quarters of a v5e's 16 GB
+PIN_RESIDENT_BYTES = 12e9
+
+
 def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
                           seq_mask, expert_counts, pool_order,
                           logits_at=None, state_slot=None):
@@ -3957,9 +4065,16 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     # very end and keeps them all until then: 0.54 GB a layer of a
     # 16,384-token block, 5.3 GB over ten layers beside 12.5 GB of weights
     # and cache.  x is pinned after each layer so that the next reads the
-    # sum and the branches die.  (A model of window and full layers keeps
-    # the program it had: its seven layers fit.)
-    pin = logits_at is not None and slots > 0
+    # sum and the branches die.  A model of window and full layers alone is
+    # pinned where the weights and the cache the call holds leave the kept
+    # branches no room (``PIN_RESIDENT_BYTES``: Trinity's 12.8 GB, where an
+    # 8,192-token block's temporaries are 2.2 GB unpinned and 0.7 GB pinned);
+    # under that it keeps the program it had (MiMo's 9.8 GB: its seven
+    # layers fit).
+    resident = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves((params, cache)))
+    pin = logits_at is not None and (slots > 0
+                                     or resident > PIN_RESIDENT_BYTES)
     for group, index, kind, _ in layer_plan(cfg):
         g = groups[group][0]
         # ``v`` is the group's stack or, as the serving executor holds it, a

@@ -31,7 +31,8 @@ import pytest  # noqa: E402
 
 # the benchmark tests' own set-asides, which their conftest.py may not take
 pytest_plugins = ("tests.benchmark.pinned_sets", "tests.benchmark.pinned_tail",
-                  "tests.benchmark.pinned_thirteenth")
+                  "tests.benchmark.pinned_thirteenth",
+                  "tests.benchmark.pinned_fourteenth")
 
 
 def pytest_collection_modifyitems(config, items):

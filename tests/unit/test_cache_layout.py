@@ -151,7 +151,10 @@ def test_decode_attrs_are_the_hosts_row_counts(kind, lengths):
             "kv_page_write_leaves"}
     if kind == "window":
         want |= {"kv_rows_full", "kv_live_rows_full", "kv_rows_window",
-                 "kv_live_rows_window"}
+                 "kv_live_rows_window", "kv_slots_live",
+                 "kv_slots_past_window"}
+        assert (a["kv_slots_live"], a["kv_slots_past_window"]) == (
+            len(lengths), sum(n > 16 for n in lengths))
         assert a["kv_rows_full"] == rows * 4
         assert a["kv_live_rows_full"] == sum(lengths) * 4
         assert a["kv_rows_window"] == 20 * T.window_read_rows(

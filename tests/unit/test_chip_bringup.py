@@ -1328,3 +1328,82 @@ def test_conv_or_attention_layers_fit_the_chip_at_the_cells_size(
     # no copy of the tails' leaf: it is updated where it lies
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (
         "bf16[11,128,4096]" in ln or "bf16[1408,4096]" in ln)]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_8192"])
+def test_gated_window_layers_fit_the_chip_at_the_cells_size(
+        one_v5e_chip, monkeypatch, program):
+    """AOT: the two largest programs of ``trinity-large-ep16-d8.mixedctx-
+    backlog`` at the cell's own sizes (eight layers at the published widths
+    with 16 held experts of each of the seven expert layers, 24 slots of 80
+    pages in the full layers' pool and a ring of 33 in the window layers'),
+    the weights held a leaf a layer and the cache donated.  The tick: 8.29
+    GB of weights and 4.5 GB of cache in place; BOTH pools are read by the
+    gather: the ring through the window's lower bound, and the full pool (8
+    x 128, bfloat16, row-major) because 8 heads are half a tile of 16
+    sublanes, which the page kernel's block does not take (``page_block``);
+    its 96 pairs of (slot, expert) are under a row an expert,
+    ``ragged_dot``'s side of the rule.  The
+    8,192-token prefill walks a window layer's keys in chunks of 512 inside
+    the window: no array of the program is a chunk of 4,096 queries against
+    8,192 keys."""
+    import json
+
+    from benchmark.lib import system
+    from deepspeed_tpu.models import CausalLM, init_params
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "trinity-large-ep16-d8.json")) as f:
+        cfg = system.transformer_config(json.load(f), False)
+    slots, maxp, ring = 24, 80, 33
+    assert T.window_ring_pages(cfg.window_size, 128) == ring
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: T.per_layer_leaves(cfg, init_params(
+                cfg, jax.random.PRNGKey(0)))[0]))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * maxp, 128, dtype=jnp.bfloat16,
+                window_pages=1 + slots * ring, slots=slots)))
+    assert cache["k"].shape == cache["v"].shape == (2, 1921, 128, 8, 128)
+    assert cache["k_window"].shape == (6, 793, 128, 8, 128)
+    b, s = (slots, 1) if program == "decode" else (1, 8192)
+
+    def run(params, cache, tokens, tables, start, mask, at):
+        kw = {} if program == "decode" else {"logits_at": at}
+        logits, cache, counts = T.forward_paged(
+            cfg, params, tokens, cache, tables, start, mask,
+            expert_counts=True, **kw)
+        return jnp.argmax(logits[:, -1], -1), cache, counts
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, S((b, s), jnp.int32),
+        (S((b, maxp), jnp.int32), S((b, ring), jnp.int32)),
+        S((b,), jnp.int32), S((b, s), jnp.bool_), S((b,), jnp.int32)
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert 12.7e9 < mem.argument_size_in_bytes < 12.9e9
+    assert mem.alias_size_in_bytes > 4.4e9          # both pools in place
+    text = compiled.as_text()
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 0.6e9
+        assert T.expert_matmul_path(cfg, slots, 1) == "ragged_dot"
+        assert set(T.kv_read_paths(cfg, cache, None, slots).values()) == {
+            "gather"}
+        assert T.kv_write_paths(cfg, cache, None) == {
+            k: "row" for k in ("k", "v", "k_window", "v_window")}
+    else:
+        # x pinned after each layer (12.8 GB of weights and cache are over
+        # ``PIN_RESIDENT_BYTES``): 0.74 GB of temporaries, 2.2 GB unpinned
+        assert mem.temp_size_in_bytes < 1.0e9
+        # a chunk of the window's own length against two of keys, one KV
+        # head's six query heads: what the chunks of ``window`` would hold
+        assert "f32[1,1,6,4096,8192]" not in text
+        assert "4096,8192]" not in text

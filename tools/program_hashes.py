@@ -36,6 +36,14 @@ KINDS = {
         window_kv_heads=4, head_dim=24, v_head_dim=16, rotary_dim=8,
         window_size=16, num_experts=16, moe_experts_held=4, moe_top_k=3,
         **F32)),
+    # Trinity's form: a gate on attention, rotary by kind, four norms, a
+    # shared expert, a window longer than a page
+    "gated_window": ("trinity-large-preview", dict(
+        num_layers=8, layer_pattern=("window", "window", "full", "window") * 2,
+        dense_layers=1, hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_heads=8, num_kv_heads=2, head_dim=16,
+        window_size=16, num_experts=16, moe_experts_held=4, moe_top_k=3,
+        **F32)),
     "latent": ("kanana-2-30b-a3b", dict(
         num_layers=4, hidden_size=64, intermediate_size=96,
         moe_intermediate_size=32, num_heads=4, head_dim=24, v_head_dim=16,
