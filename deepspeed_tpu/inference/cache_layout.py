@@ -19,6 +19,7 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
+from ..models.mixers.ssm import ssm_scan_path
 from ..models.transformer import (block_read_rows, cache_depth, cache_kind,
                                   cache_layers, causal_walk_steps, is_hybrid,
                                   kind_layers, paged_read_rows,
@@ -195,7 +196,9 @@ class CacheLayout:
         the kind's prompt runs a scan (not a convolution's tail alone), the
         scan's chunks (of the kind's own length: ``ssm_chunk``, or
         ``linear_chunk`` for delta layers) that hold a real token beside the
-        bucket's."""
+        bucket's, and how the bucket's program runs that scan where the kind
+        has a kernel for it (``ssm_scan``: ``"kernel"`` / ``"xla"``,
+        ``models.mixers.ssm.ssm_scan_path``)."""
         attrs: Dict[str, Any] = {"gathered_rows": (
             0 if self.block_attends_itself else paged_read_rows(
                 [shared + tokens], self.page_size, self.pages_per_slot, 1)),
@@ -207,6 +210,9 @@ class CacheLayout:
                 attrs.update(
                     scan_chunks=chunks,
                     scan_chunks_bucket=ssm_scan_chunks(self.cfg, bucket))
+            scan = ssm_scan_path(self.cfg, bucket)
+            if scan is not None:        # state-space layers
+                attrs.update(ssm_scan=scan)
             attrs.update(state_reset=int(shared == 0))
         if self.block_attends_itself:
             attrs.update(walk_steps=causal_walk_steps(bucket, tokens),

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.mixers import MIXERS, state_step_paths
+from ..models.mixers import MIXERS, state_scan_paths, state_step_paths
 from ..models.transformer import (PAGED_POOL_KEYS, STATE_POOL_KEYS,
                                   cow_copy_pool, expert_counts_shape,
                                   is_hybrid, paged_pool_cache,
@@ -286,6 +286,9 @@ class MeshExecutor:
         self.state_steps = state_step_paths(cfg)
         self.state_passes = max((MIXERS[kind].passes[step] for kind, step
                                  in self.state_steps.items()), default=0)
+        # and the scan a prompt's program holds, for the kinds whose scan
+        # has a kernel (chosen where the bucket is traced)
+        self.state_scans = state_scan_paths(cfg)
         pool_kw = {"dtype": dtype, "kv_dtype": kv_dtype, **layout.pool_kw}
         specs = model.paged_cache_specs(kv_dtype=kv_dtype)
         # canonical pool tuple (models.transformer.PAGED_POOL_KEYS order):
@@ -901,7 +904,10 @@ class MeshExecutor:
         ``delta_step``, ``conv_step`` (each row's ``step_key`` of
         ``models.mixers.MIXERS``): the step the decode tick holds for that
         kind of mixer (the row's ``step_path``: ``"one_pass"`` or the plain
-        step's name), ``None`` for a model with no such layer.
+        step's name), ``None`` for a model with no such layer.  ``ssm_scan``:
+        the scan a prompt's program holds for its state-space layers
+        (``models.mixers.ssm.ssm_scan_path``: ``"kernel"``,
+        ``ops/pallas/ssm_scan.py``, or ``"xla"``), ``None`` likewise.
         ``kv_write``: how the decode tick
         lays a token's rows into each paged leaf (``"row"`` / ``"page"``:
         ``models.transformer.kv_write_path``), ``kv_read`` how it reads each
@@ -924,6 +930,7 @@ class MeshExecutor:
                 **self.weight_placement,
                 **{m.step_key: self.state_steps.get(m.kind)
                    for m in MIXERS.values()},
+                **self.state_scans,
                 "kv_write": dict(self.kv_write),
                 "kv_read": dict(self.kv_read),
                 "expert_matmul": self.expert_matmul(),

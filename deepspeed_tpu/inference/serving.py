@@ -787,6 +787,9 @@ class ServingEngine:
                       f"{step}={info[step]}"
                       for step in (m.step_key for m in MIXERS.values())
                       if info[step])
+            + "".join(f" {scan}={info[scan]}"
+                      for scan in (m.scan_key for m in MIXERS.values())
+                      if scan and info[scan])
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items())
             + " kv_read=" + ",".join(

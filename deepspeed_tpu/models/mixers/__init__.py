@@ -16,7 +16,7 @@ from . import conv, delta, ssm
 from .common import _slot_rows, _slot_rows_in_place
 
 __all__ = ["MIXERS", "Mixer", "check", "mixers_of", "paged",
-           "state_step_paths"]
+           "state_scan_paths", "state_step_paths"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,6 +59,13 @@ class Mixer:
     unpack: Optional[Callable] = None
     # the step over the state leaf where it lies (``step_path``'s "one_pass")
     one_pass: Optional[Callable] = None
+    # a kind whose prompt's scan has a kernel: ``mesh_info()``'s key for the
+    # scan a prefill program holds, the rule that names it (scan_path(cfg,
+    # tokens, the state leaf's dtype)), and the block's update where the rule
+    # says "kernel": the mixer's ``step`` over a state taken from the leaf
+    scan_key: Optional[str] = None
+    scan_path: Optional[Callable[..., Optional[str]]] = None
+    scan: Optional[Callable] = None
 
 
 _NO_PAGE = (" that no page holds, so a page copied, parked, rescaled or "
@@ -107,7 +114,9 @@ MIXERS: Dict[str, Mixer] = {m.kind: m for m in (
           refusals=ssm.refusals, param_count=ssm.param_count,
           init=ssm.init, specs=ssm.specs, leaves=ssm.leaves,
           mixer=ssm._ssm_mixer, rows=_slot_rows,
-          one_pass=ssm._ssm_step_one_pass),
+          one_pass=ssm._ssm_step_one_pass,
+          scan_key="ssm_scan", scan_path=ssm.ssm_scan_path,
+          scan=ssm._ssm_scan_kernel),
 )}
 
 
@@ -157,6 +166,14 @@ def state_step_paths(cfg) -> Dict[str, str]:
     return {m.kind: m.step_path(cfg) for m in mixers_of(cfg)}
 
 
+def state_scan_paths(cfg, tokens: int = 2) -> Dict[str, Optional[str]]:
+    """``{scan_key: the scan a prompt of cfg holds}`` for every row whose
+    scan has a kernel (the row's ``scan_path`` over a block of ``tokens``;
+    ``None`` for a model without the kind); ``mesh_info()`` reports them."""
+    return {m.scan_key: m.scan_path(cfg, tokens)
+            for m in MIXERS.values() if m.scan_key}
+
+
 def paged(m: Mixer, cfg, pools: Dict[str, Any], row0, state_slot, start,
           seq_mask):
     """``_block``'s ``ssm`` for a layer of ``m``'s kind against its cache
@@ -168,13 +185,17 @@ def paged(m: Mixer, cfg, pools: Dict[str, Any], row0, state_slot, start,
 
     The state of a decode tick on a TPU is never taken: where
     ``m.step_path`` says ``"one_pass"`` the step is ``m.one_pass`` over the
-    state leaf itself, one read and one write of each row."""
+    state leaf itself, one read and one write of each row.  A longer block
+    whose ``m.scan_path`` says ``"kernel"`` runs its scan as ``m.scan`` over
+    the rows taken."""
     B, S = seq_mask.shape
     fresh = (start == 0) & seq_mask.any(axis=1)
     take, put = m.rows(row0, state_slot, B)
     state_key, tail_key = (None, *m.pool_keys)[-2:]    # (tail,): no state
     one_pass = state_key and m.step_path(
         cfg, S, state_slot, pools[state_key].dtype) == "one_pass"
+    scan = functools.partial(m.scan, cfg) if m.scan_path and m.scan_path(
+        cfg, S, pools[state_key].dtype) == "kernel" else None
 
     def mixer(lp, h):
         tail = jnp.where(fresh[:, None, None], 0, take(
@@ -193,7 +214,8 @@ def paged(m: Mixer, cfg, pools: Dict[str, Any], row0, state_slot, start,
             if m.unpack is not None:
                 state = m.unpack(cfg, state)
             state = jnp.where(fresh[:, None, None, None], 0.0, state)
-            out, (state, tail) = m.mixer(cfg, lp, h, seq_mask, (state, tail))
+            out, (state, tail) = m.mixer(cfg, lp, h, seq_mask, (state, tail),
+                                         scan)
             if m.pack is not None:
                 state = m.pack(cfg, state)
             kept[state_key] = put(pools[state_key], state)

@@ -61,7 +61,14 @@ def _ssm_scan(cfg, x, Bm, Cm, dt, A, state):
     nothing, so padding behind the real tokens (the bucket's, or up to a
     whole chunk) changes no number.  Decays, cumulative sums and the carried
     state are float32; the four products take the compute dtype's operands
-    and accumulate in float32."""
+    and accumulate in float32.
+
+    Who runs it is :func:`ssm_scan_path`'s rule: the training forward and
+    any plain ``forward`` (it has a derivative, a ``pallas_call`` has none),
+    and a serving program off a TPU or at a shape the kernel's tile plan
+    refuses; a serving program on a TPU runs :func:`_ssm_scan_kernel`, the
+    same arithmetic with a chunk's temporaries held on chip.  It is that
+    kernel's yardstick in the tests."""
     B, S, H, P = x.shape
     G, N = Bm.shape[2:]
     Hg, Q = H // G, cfg.ssm_chunk
@@ -100,6 +107,40 @@ def _ssm_scan(cfg, x, Bm, Cm, dt, A, state):
                     jnp.moveaxis(s_in, 0, 1).astype(cd))
                  * jnp.exp(cum)[..., None])
     return (y.reshape(B, S + pad, H, P)[:, :S], state.reshape(B, H, P, N))
+
+
+def ssm_scan_path(cfg, tokens: int, dtype=jnp.float32) -> Optional[str]:
+    """Which scan a paged program of ``tokens`` a row holds for ``cfg``'s
+    state-space layers: ``"kernel"`` (``ops/pallas/ssm_scan.py``: a chunk's
+    decays, its four products and the carried state in on-chip memory, ``x``
+    read and ``y`` written once where the mixer holds them) for a block of
+    more than one token over a float32 state, on a TPU, at a shape the
+    kernel's tile plan takes; ``"xla"`` (:func:`_ssm_scan`) for any other
+    block; ``None`` for one token a row (:func:`ssm_step_path`) and a model
+    with no such layer.  Read at trace time from what the code can observe;
+    the serving executor reports it (``mesh_info()["ssm_scan"]``, the
+    ``ssm_scan`` attr of a ``serve.prefill`` span).  The training forward
+    and ``forward`` never ask: they keep :func:`_ssm_scan`."""
+    from ...ops.pallas.ssm_scan import scan_block
+
+    if not cfg.ssm_heads or tokens <= 1:
+        return None
+    if (dtype == jnp.float32 and common._pallas_interpret() is not None
+            and scan_block(cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_head_dim,
+                           cfg.ssm_state, cfg.ssm_chunk) is not None):
+        return "kernel"
+    return "xla"
+
+
+def _ssm_scan_kernel(cfg, x, Bm, Cm, dt, A, state):
+    """:func:`_ssm_scan` as the one kernel of ``ops/pallas/ssm_scan.py``,
+    the same formula term for term; the ``step`` of a block where
+    :func:`ssm_scan_path` says ``"kernel"``."""
+    from ...ops.pallas.ssm_scan import ssm_scan
+
+    with jax.named_scope("ssm_scan"):
+        return ssm_scan(x, Bm, Cm, dt, A, state, chunk=cfg.ssm_chunk,
+                        interpret=common._pallas_interpret())
 
 
 def _ssm_step(cfg, x, Bm, Cm, dt, A, state):
@@ -187,10 +228,14 @@ def _ssm_start(cfg, rows: int, dtype):
 
 
 # Positions of one prompt the mixer takes at a time: the in-projection's
-# output is 16,768 wide and the chunked scan keeps ``[chunk, chunk, heads]``
+# output is 16,768 wide and :func:`_ssm_scan` keeps ``[chunk, chunk, heads]``
 # float32 a chunk (the decays between every two positions), together 3 GB
 # over a 16,384-token block of 128 heads in chunks of 256 and 0.4 GB over
-# 2,048 of them
+# 2,048 of them.  A serving program on a TPU runs the scan as
+# ``ops/pallas/ssm_scan.py`` (:func:`ssm_scan_path`), which keeps the decays
+# on chip: a piece is then the in-projection's alone, a kernel call a piece
+# with the state carried between them.  The training forward, ``forward``
+# and the tests run :func:`_ssm_scan`
 SSM_BLOCK_TOKENS = 2048
 
 
@@ -201,10 +246,11 @@ def _ssm_mixer(cfg, lp: Dict[str, Any], h, seq_mask=None,
     the device over the pieces, the state and the convolution's tail carried
     from piece to piece as they are from call to call, so that the
     temporaries are a piece's and not the prompt's.  The same numbers either
-    way: real tokens lead the block, so they lead every piece."""
+    way: real tokens lead the block, so they lead every piece.  ``step``
+    (a block's: :func:`_ssm_scan_kernel`) is every piece's."""
     B, S, _ = h.shape
     n = SSM_BLOCK_TOKENS
-    if S <= n or S % n or step is not None:
+    if S <= n or S % n:
         return _ssm_mixer_block(cfg, lp, h, seq_mask, kept, step)
     if seq_mask is None:
         seq_mask = jnp.ones((B, S), bool)
@@ -215,7 +261,7 @@ def _ssm_mixer(cfg, lp: Dict[str, Any], h, seq_mask=None,
         return jnp.moveaxis(a.reshape(B, S // n, n, *a.shape[2:]), 1, 0)
 
     def piece(kept, xs):
-        out, kept = _ssm_mixer_block(cfg, lp, xs[0], xs[1], kept)
+        out, kept = _ssm_mixer_block(cfg, lp, xs[0], xs[1], kept, step)
         return kept, out
 
     kept, out = jax.lax.scan(piece, kept, (pieces(h), pieces(seq_mask)))
@@ -233,8 +279,9 @@ def _ssm_mixer_block(cfg, lp: Dict[str, Any], h,
     Returns ``(out [B,S,d], (state, tail) after the block's real tokens)``.
     ``step(x, Bm, Cm, dt, A, state) -> (y, state)`` stands in for the state
     update where the caller holds the state in another form (a decode
-    tick's pool leaf: :func:`~deepspeed_tpu.models.mixers.paged`); ``state``
-    is then whatever it takes and returns."""
+    tick's pool leaf: :func:`~deepspeed_tpu.models.mixers.paged`; ``state``
+    is then whatever it takes and returns) or runs it another way (a
+    prompt's block in a serving program: :func:`_ssm_scan_kernel`)."""
     B, S, _ = h.shape
     H, P, N, G = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
                   cfg.ssm_groups)
@@ -258,9 +305,17 @@ def _ssm_mixer_block(cfg, lp: Dict[str, Any], h,
     A = -jnp.exp(lp["ssm_A_log"].astype(jnp.float32))
     y, state = (step or functools.partial(
         _ssm_step if S == 1 else _ssm_scan, cfg))(x, Bm, Cm, dt, A, state)
-    y = y + lp["ssm_D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    D = lp["ssm_D"].astype(jnp.float32)
+    if step is not None and S > 1:
+        # a block's kernel reads x and writes y as ``[B,S,d_ssm]``; the skip
+        # stays in that order (a float32 ``[.., H, 64]`` is laid out
+        # position-minor on the TPU: a copy of y and of x in, one out)
+        y = y.reshape(B, S, d_ssm) + jnp.repeat(D, P) * xbc[
+            ..., :d_ssm].astype(jnp.float32)
+    else:
+        y = (y + D[:, None] * x.astype(jnp.float32)).reshape(B, S, d_ssm)
     with jax.named_scope("ssm_out"):
-        out = _ssm_gate_norm(cfg, lp, y.reshape(B, S, d_ssm), z) @ lp["ssm_out"]
+        out = _ssm_gate_norm(cfg, lp, y, z) @ lp["ssm_out"]
     return out, (state, tail)
 
 

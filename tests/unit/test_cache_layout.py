@@ -208,5 +208,7 @@ def test_prefill_attrs_are_the_hosts_trip_counts(monkeypatch, kind, bucket,
         assert a["scan_chunks"] == T.ssm_scan_chunks(lay.cfg, bucket, tokens)
         assert a["scan_chunks_bucket"] == bucket // 8
         assert a["state_reset"] == int(shared == 0)
+        # and how the bucket's program runs the scan: no kernel off a TPU
+        assert a.pop("ssm_scan") == "xla"
     assert set(a) == want
     assert all(isinstance(v, (int, np.integer)) for v in a.values())

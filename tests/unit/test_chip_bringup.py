@@ -1097,16 +1097,19 @@ def test_mamba_or_attention_layers_fit_the_chip_at_the_cells_size(
     32 slots of 104 pages), the weights held a leaf a layer and the cache
     donated.  The tick: one one-pass kernel a mamba layer over the
     ``ssm_state`` leaf where it lies, megabytes of temporaries.  The
-    16,384-token prefill: the mixer a piece of 2,048 tokens at a time, the
-    expert layer too, x pinned after each layer, 1.5 GB of temporaries
+    16,384-token prefill: the mixer a piece of 2,048 tokens at a time (its
+    scan one kernel a piece since PR 59), the expert layer too, x pinned
+    after each layer, 1.5 GB of temporaries
     (5.3 GB, and over the chip, where the compiler reads the head's one row
     out of every layer's branch outputs at the end; PERF.md, PR 47)."""
     import json
+    import re
 
     from benchmark.lib import system
     from deepspeed_tpu.models import CausalLM, init_params
     from deepspeed_tpu.models import transformer as T
     from deepspeed_tpu.models.mixers import common as MX
+    from deepspeed_tpu.models.mixers import ssm as SSM
 
     def S(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
@@ -1171,6 +1174,50 @@ def test_mamba_or_attention_layers_fit_the_chip_at_the_cells_size(
         # [2048, 10, 4096], no select over [20480, 4096]
         _holds_no_pass_over_dead_rows(text, 2048, cfg.moe_top_k,
                                       cfg.hidden_size)
+        # the nine mamba layers' scan is one kernel each, a call a piece of
+        # 2,048 tokens under the pieces' loop (ops/pallas/ssm_scan.py): no
+        # float32 of the decays' size ([.., 256, 256, .., 128]) and none of
+        # y or x dt between [position, head, dim] and another order
+        assert SSM.ssm_scan_path(cfg, s) == "kernel"
+        assert sum("ssm_scan" in ln for ln in kernels) == 9
+        assert not re.search(r"f32\[[\d,]*256,256,[\d,]*128\]", text)
+        assert not re.search(
+            r"f32\[1,(2048,8192|2048,128,64|8,256,1,128,64)\]\{[^}]*\}"
+            r" (copy|reshape|transpose)\(", text)
+
+
+SCANS_AT_PR_59 = {
+    # heads, head width, state, groups, chunk, tokens: a state-space layer
+    # of a prefill program of the benchmark's two cells
+    "granite-4.0-h-small-a-piece-of-2048": (128, 64, 128, 1, 256, 2048),
+    "falcon-h1-34b-the-512-bucket": (32, 128, 256, 2, 128, 512),
+}
+
+
+@pytest.mark.parametrize("shape", list(SCANS_AT_PR_59))
+def test_a_prompts_scan_compiles_at_both_published_shapes(one_v5e_chip,
+                                                          shape):
+    """AOT: ``ops/pallas/ssm_scan.py`` alone at the widths its tile plan
+    must take (64-wide heads two a lane tile in one group; 128-wide heads in
+    two groups), bfloat16 operands and a float32 state: Mosaic takes the
+    blocks, and beside the kernel the program holds megabytes (the
+    cumulative sum of ``dt A`` a block of heads at a time), nothing of the
+    decays' size."""
+    from deepspeed_tpu.ops.pallas.ssm_scan import scan_block, ssm_scan
+
+    H, P, N, G, Q, tokens = SCANS_AT_PR_59[shape]
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    assert scan_block(H, G, P, N, Q) == 8
+    compiled = jax.jit(lambda *a: ssm_scan(*a, chunk=Q, interpret=False)).lower(
+        S((1, tokens, H, P), jnp.bfloat16), S((1, tokens, G, N), jnp.bfloat16),
+        S((1, tokens, G, N), jnp.bfloat16), S((1, tokens, H), jnp.float32),
+        S((H,), jnp.float32), S((1, H, P, N), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * tokens * H * 4
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_1024"])
