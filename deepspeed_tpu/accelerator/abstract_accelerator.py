@@ -107,6 +107,15 @@ class DeepSpeedAccelerator(abc.ABC):
         assert self._communication_backend_name is not None
         return self._communication_backend_name
 
+    def collective_overlap_options(self) -> Dict[str, str]:
+        """Compiler options that make this accelerator's compiler run a
+        step's collectives behind the compute beside them.  The engine
+        attaches them to a step's compile only where the ZeRO plan has
+        collectives to hide (``DeepSpeedEngine.step_compile_options``).
+        Empty unless the accelerator's compiler knows such options, so no
+        other backend's compile ever sees a key it would refuse."""
+        return {}
+
     # --- profiler ranges (reference abstract_accelerator.py:169-174 nvtx) ---
     def range_push(self, name: str):
         import jax

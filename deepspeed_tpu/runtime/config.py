@@ -84,8 +84,15 @@ class ZeroConfig(DeepSpeedConfigModel):
     offload_param: Optional[DeepSpeedZeroOffloadParamConfig] = None
     offload_optimizer: Optional[DeepSpeedZeroOffloadOptimizerConfig] = None
 
-    # stage-3 knobs (kept for API parity; prefetch/persistence map to XLA
-    # scheduling hints and the "small params stay replicated" threshold)
+    # stage-3 knobs.  The three prefetch / live-window sizes are kept for
+    # API parity and steer nothing: the overlap of a layer's collectives
+    # with the compute beside them is asked of the compiler by the engine,
+    # one option set a plan that gathers parameters
+    # (DeepSpeedEngine.step_compile_options; runtime/zero/planner.py), since
+    # the scheduler alone left a sixth of the four-chip step waiting on the
+    # wire (PERF.md section 6, PR 60).  The persistence threshold is live:
+    # parameters under it stay replicated in stage 3, and their gradients'
+    # small all-reduce runs inside the backward's layer loop.
     sub_group_size: int = Field(1_000_000_000, ge=0)
     stage3_max_live_parameters: int = Field(1_000_000_000, ge=0)
     stage3_max_reuse_distance: int = Field(1_000_000_000, ge=0)

@@ -98,7 +98,7 @@ def make_grad_accumulator(grad_of_batch, gas: int, accum_dtype=None):
     return run
 
 
-def _xla_options() -> Optional[Dict[str, str]]:
+def _xla_options() -> Dict[str, str]:
     """Extra XLA compiler options for the train/eval step jits.
 
     ``DS_TPU_XLA_OPTIONS="k=v,k2=v2"`` — escape hatch for per-job compiler
@@ -106,21 +106,19 @@ def _xla_options() -> Optional[Dict[str, str]]:
     reference exposes the same class of knob via op-builder build flags.
     """
     raw = os.environ.get("DS_TPU_XLA_OPTIONS", "").strip()
-    if not raw:
-        return None
     opts = {}
     for item in raw.split(","):
         if "=" in item:
             k, v = item.split("=", 1)
             opts[k.strip()] = v.strip()
-    return opts or None
+    return opts
 
 
-def _jit_step(fn, **kw):
-    """jax.jit wrapper applying the DS_TPU_XLA_OPTIONS passthrough."""
-    opts = _xla_options()
-    if opts:
-        kw["compiler_options"] = opts
+def _jit_step(fn, options: Optional[Dict[str, str]] = None, **kw):
+    """``jax.jit`` of a train / grad / eval step under the compiler options
+    the engine resolved for its steps (``engine.step_compile_options``)."""
+    if options:
+        kw["compiler_options"] = options
     return jax.jit(fn, **kw)
 
 
@@ -452,7 +450,28 @@ class DeepSpeedEngine:
             f"engine ready: params={self.param_count:,} zero_stage={self.zero_stage} "
             f"dtype={self.compute_dtype.__name__} mesh={dict(mesh.shape)} "
             f"batch={self.train_batch_size} (micro={self.micro_batch_size} gas={self.gas} "
-            f"dp={self.dp_world})", ranks=[0])
+            f"dp={self.dp_world}) step_compile_options="
+            f"{self.step_compile_options or 'none'}", ranks=[0])
+
+    @property
+    def step_compile_options(self) -> Dict[str, str]:
+        """The compiler options the train / grad / eval steps are compiled
+        with (read-only).  A rule of the plan and the accelerator, not a
+        switch: where the ZeRO plan moves parameters between devices as the
+        step runs (:attr:`ZeroShardingPlan.gathers_params`: stage 3 over
+        ZeRO axes of more than one device) the accelerator's
+        ``collective_overlap_options()``, which ask its compiler to run a
+        layer's collectives behind the layers beside it (empty on every
+        accelerator but the TPU); nothing anywhere else, so a stage <= 2
+        step, a one-device step and a CPU step compile as they always did.
+        A key of ``DS_TPU_XLA_OPTIONS`` wins over the rule's."""
+        opts: Dict[str, str] = {}
+        if self.plan is not None and self.plan.gathers_params:
+            from ..accelerator import get_accelerator
+
+            opts.update(get_accelerator().collective_overlap_options())
+        opts.update(_xla_options())
+        return opts
 
     def _init_device_state(self, init_fn, params, param_specs, mesh, hpz):
         """Build the device-resident TrainState: sharded init, ZeRO planning,
@@ -919,7 +938,7 @@ class DeepSpeedEngine:
                 grads = jax.tree_util.tree_map(lambda g: g * factor, grads)
             return grads, jnp.mean(losses), gnorm, new_rng
 
-        return _jit_step(grad_step)
+        return _jit_step(grad_step, self.step_compile_options)
 
     def _train_batch_nvme(self, global_batch):
         """device grads -> host NVMe Adam -> bf16 params back to device."""
@@ -1013,7 +1032,8 @@ class DeepSpeedEngine:
                            "step_applied": jnp.bool_(True)}
                 return new_state, metrics
 
-            return _jit_step(train_step, donate_argnums=(0,))
+            return _jit_step(train_step, self.step_compile_options,
+                             donate_argnums=(0,))
 
         if compression is not None:
             from .comm.compressed import make_compressed_grad_fn
@@ -1045,9 +1065,11 @@ class DeepSpeedEngine:
                 return new_state, metrics
 
             if self._train_out_shardings is not None:
-                return _jit_step(train_step, donate_argnums=(0,),
+                return _jit_step(train_step, self.step_compile_options,
+                                 donate_argnums=(0,),
                                  out_shardings=self._train_out_shardings)
-            return _jit_step(train_step, donate_argnums=(0,))
+            return _jit_step(train_step, self.step_compile_options,
+                             donate_argnums=(0,))
 
         accumulate = make_grad_accumulator(grad_of_batch, gas,
                                            self.config.data_types.jnp_dtype())
@@ -1131,9 +1153,11 @@ class DeepSpeedEngine:
             return new_state, metrics
 
         if self._train_out_shardings is not None:
-            return _jit_step(train_step, donate_argnums=(0,),
+            return _jit_step(train_step, self.step_compile_options,
+                             donate_argnums=(0,),
                              out_shardings=self._train_out_shardings)
-        return _jit_step(train_step, donate_argnums=(0,))
+        return _jit_step(train_step, self.step_compile_options,
+                         donate_argnums=(0,))
 
     def _select_train_step(self, global_batch,
                            budget_bytes: Optional[int] = None) -> None:
@@ -1248,7 +1272,7 @@ class DeepSpeedEngine:
             loss, aux = out if isinstance(out, tuple) else (out, {})
             return loss, aux
 
-        return _jit_step(eval_step)
+        return _jit_step(eval_step, self.step_compile_options)
 
     # ------------------------------------------------------------------
     # Public API (reference engine.forward/backward/step + train_batch)

@@ -22,10 +22,20 @@ replicated in stage 3 — exactly the reference's persistent-param optimization
 
 The prefetch-window knobs (`stage3_max_live_parameters`,
 `stage3_prefetch_bucket_size`, `stage3_max_reuse_distance`) are accepted for
-schema parity and validated, but NOT translated further: XLA's latency-hiding
-scheduler owns all-gather placement and double-buffering under jit, and it
-makes those decisions from the compiled program's live ranges — the quantities
-the reference's Python-side coordinator approximated with these knobs.
+schema parity and validated, but NOT translated further.  The assumption this
+design started from, that XLA's latency-hiding scheduler would place and
+double-buffer the plan's collectives by itself, the chip refuted (PERF.md
+section 6, PR 60): the layers are one ``lax.scan`` body and the scheduler
+hides nothing across its iterations, and the v5e's partitioner turns most of a
+layer's products into windowed einsums (a ring of collective-permutes around
+the activations) whose waits, one synchronous weight all-gather and the small
+replicated parameters' gradient all-reduce stood exposed for a sixth of the
+four-chip step.  What asks for the overlap now is the engine, on the step's
+own compile: where a plan :attr:`ZeroShardingPlan.gathers_params`, the
+accelerator's ``collective_overlap_options()`` go to the compiler with the
+train / grad / eval steps (``DeepSpeedEngine.step_compile_options``).  The
+knobs above still steer nothing: the option set is one rule of the plan and
+the accelerator, measured on the chip, not a window a user sizes.
 """
 from __future__ import annotations
 
@@ -49,6 +59,16 @@ class ZeroShardingPlan:
     grad_specs: Any       # gradient shardings (stage>=2 sharded)
     opt_specs: Any        # optimizer state per-param shardings (== master_specs)
     stage: int
+    # devices a compute parameter's ZeRO axes span (1: nothing to gather)
+    param_zero_size: int = 1
+
+    @property
+    def gathers_params(self) -> bool:
+        """Whether a step under this plan moves parameters between devices
+        as it runs: stage 3 over ZeRO axes of more than one device.  What
+        the engine asks before it hands the step's compile the accelerator's
+        collective-overlap options."""
+        return self.stage >= 3 and self.param_zero_size > 1
 
 
 def _spec_axes_in_dim(entry) -> Tuple[str, ...]:
@@ -176,7 +196,8 @@ def plan_sharding(param_shapes: Any, stage: int, mesh: Mesh, tp_specs: Optional[
     else:
         grads = tp_specs
     return ZeroShardingPlan(param_specs=params, master_specs=master, grad_specs=grads,
-                            opt_specs=master, stage=stage)
+                            opt_specs=master, stage=stage,
+                            param_zero_size=axis_size(mesh, list(param_zero_axes)))
 
 
 def named_shardings(mesh: Mesh, specs: Any) -> Any:

@@ -230,15 +230,20 @@ def test_paged_decode_reads_a_bounded_pool_in_place_on_v5e(
 
 
 @pytest.fixture(scope="module")
-def one_v5e_chip():
+def v5e_2x2():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+        return topologies.get_topology_desc("v5e:2x2", "tpu")
     except Exception as e:  # noqa: BLE001 — no libtpu / no such topology
         pytest.skip(f"TPU topology unavailable for AOT compile: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _placed_as_the_executor_places(cfg, chip):
@@ -1086,6 +1091,39 @@ def test_pythia_cell_resolves_the_chip_tables_rung_on_v5e(one_v5e_chip,
     calls = {r: t.count('custom_call_target="tpu_custom_call"')
              for r, t in texts.items()}
     assert calls[PYTHIA_CELL_RUNG] == 3 and calls["nothing_saveable"] == 4
+
+
+def test_collective_overlap_options_are_the_installed_compilers(v5e_2x2):
+    """AOT, four described chips: the option set the engine hands a ZeRO-3
+    step's compile (``TPU_Accelerator.collective_overlap_options``) is one
+    the installed libtpu takes through ``compiler_options`` (it refuses a
+    key it does not know, so a renamed option fails here and not on the
+    chip), on a product whose weight is sharded over its contraction as the
+    planner shards it and whose gradient lands sharded."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.accelerator.tpu_accelerator import TPU_Accelerator
+
+    mesh = Mesh(np.array(v5e_2x2.devices), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    x = jax.ShapeDtypeStruct((4, 256, 512), jnp.bfloat16, sharding=rows)
+    w = jax.ShapeDtypeStruct((512, 512), jnp.bfloat16, sharding=rows)
+
+    def grad(x, w):
+        def loss(w):
+            y = jax.lax.with_sharding_constraint(
+                jnp.einsum("bsd,df->bsf", x, w), rows)
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.lax.with_sharding_constraint(jax.grad(loss)(w), rows)
+
+    options = TPU_Accelerator().collective_overlap_options()
+    assert options
+    jax.jit(grad, compiler_options=options).lower(x, w).compile()
+    with pytest.raises(Exception, match="No such compile option"):
+        jax.jit(grad, compiler_options={**options, "xla_tpu_no_such": "1"}
+                ).lower(x, w).compile()
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_16384"])
