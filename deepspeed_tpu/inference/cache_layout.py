@@ -19,10 +19,12 @@ from typing import Any, Dict, Tuple
 
 import numpy as np
 
+from ..models.mixers import MIXERS, mixers_of
 from ..models.mixers.ssm import ssm_scan_path
 from ..models.transformer import (block_read_rows, cache_depth, cache_kind,
                                   cache_layers, causal_walk_steps, is_hybrid,
-                                  kind_layers, paged_read_rows,
+                                  kind_layers, layers_by_kind,
+                                  paged_read_rows,
                                   ssm_scan_chunks, window_read_rows,
                                   window_ring_pages)
 
@@ -73,6 +75,18 @@ class CacheLayout:
         # block), or each layer one of the two (a layer_pattern)
         self.stateful = self.kind == "state"
         self.kv_layers, self.state_layers = cache_layers(cfg)
+        # the layers run by kind ("mlp": a layer that is its MLP or expert
+        # layer alone and owns no cache leaf), and the leaves a kind's own
+        self.layers_by_kind = layers_by_kind(cfg)
+        self.leaves_by_kind = {
+            kind: (MIXERS[kind].pool_keys if kind in MIXERS
+                   else () if kind == "mlp"
+                   else ("latent",) if self.kind == "latent"
+                   else tuple(n + ("_window" if kind == "window" else "")
+                              for n in ("k", "v")))
+            for kind in self.layers_by_kind}
+        if self.stateful and not is_hybrid(cfg):    # beside attention
+            self.leaves_by_kind["full"] += mixers_of(cfg)[0].pool_keys
         # a pool of pages each: ``(pages, page 0 its trash page; those a
         # slot's row of its table names)``.  The slots' table first, then
         # what is taken a whole row a slot and never shared
@@ -141,8 +155,9 @@ class CacheLayout:
         rows of them than a full one).
         A state a slot: the slots whose state the tick read and wrote, the
         bytes of one reading over the layers that have one
-        (``state_layers``), its step's passes, and the live token rows over
-        the layers that have K/V (``kv_layers``)."""
+        (``state_layers``), its step's passes, the live token rows over
+        the layers that have K/V (``kv_layers``), and the layers run by kind
+        (``layers_by_kind``: ``"ssm:5,mlp:5,full:1"``)."""
         lengths = np.asarray(lengths, np.int64)
         rows = paged_read_rows(lengths, self.page_size, self.pages_per_slot,
                                slots, whole_steps=not self.kv_read_pages)
@@ -167,7 +182,10 @@ class CacheLayout:
                          state_passes=self.state_passes,
                          state_layers=self.state_layers,
                          kv_layers=self.kv_layers,
-                         kv_live_rows=int(lengths.sum()) * self.kv_layers)
+                         kv_live_rows=int(lengths.sum()) * self.kv_layers,
+                         layers_by_kind=",".join(
+                             f"{k}:{n}"
+                             for k, n in self.layers_by_kind.items()))
         return attrs
 
     def tick_attrs(self, pools, page_wait: bool) -> Dict[str, int]:

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 from jax.experimental.layout import Format, Layout
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..models.mixers import MIXERS, state_scan_paths, state_step_paths
+from ..models.mixers import (MIXERS, mixers_of, state_scan_paths,
+                             state_step_paths)
 from ..models.transformer import (PAGED_POOL_KEYS, STATE_POOL_KEYS,
                                   cow_copy_pool, expert_counts_shape,
                                   is_hybrid, paged_pool_cache,
@@ -921,7 +922,11 @@ class MeshExecutor:
         (``models.transformer.cache_kind``) and how deep its leaves are:
         ``kv_layers`` layers with K/V pages, ``state_layers`` with a state row
         a slot, ``ring_pages`` pages a slot in a window layer's ring (0: no
-        window layers)."""
+        window layers).  ``layers_by_kind``: the layers run of each kind
+        ("mlp": a layer that is its MLP or expert layer alone) and
+        ``cache_leaves_by_kind`` the cache leaves a kind's layers own (none
+        for "mlp").  ``state_programs``: the step or scan each program
+        compiled so far holds for its mixer (:meth:`state_programs`)."""
         mesh = self.mesh
         return {"mesh_devices": 1 if mesh is None else int(mesh.size),
                 "mesh_axes": {} if mesh is None else {
@@ -939,7 +944,28 @@ class MeshExecutor:
                 "cache_kind": self.layout.kind,
                 "kv_layers": self.layout.kv_layers,
                 "state_layers": self.layout.state_layers,
-                "ring_pages": self.layout.ring_pages}
+                "ring_pages": self.layout.ring_pages,
+                "layers_by_kind": dict(self.layout.layers_by_kind),
+                "cache_leaves_by_kind": {
+                    k: list(v)
+                    for k, v in self.layout.leaves_by_kind.items()},
+                "state_programs": self.state_programs()}
+
+    def state_programs(self) -> Dict[str, str]:
+        """How each compiled program of a model with a state a slot advances
+        it, by the program's name on its ``serve.launch`` span: ``decode``
+        the tick's step (each row's ``step_path`` of
+        ``models.mixers.MIXERS``), ``prefill_<bucket>`` the bucket's scan
+        where the kind has a kernel for one (``scan_path``); ``{}`` for any
+        other model."""
+        cfg = self.model.config
+        out = {}
+        for m in mixers_of(cfg):
+            out["decode"] = self.state_steps[m.kind]
+            if m.scan_path:
+                out.update({f"prefill_{s}": m.scan_path(cfg, s)
+                            for s in sorted(self._prefill_progs)})
+        return out
 
     def expert_matmul(self) -> Dict[str, str]:
         """How each compiled program of a model with dropless expert layers

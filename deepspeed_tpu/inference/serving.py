@@ -790,6 +790,10 @@ class ServingEngine:
             + "".join(f" {scan}={info[scan]}"
                       for scan in (m.scan_key for m in MIXERS.values())
                       if scan and info[scan])
+            + (" layers=" + ",".join(
+                f"{kind}:{n}({'+'.join(info['cache_leaves_by_kind'][kind]) or '-'})"
+                for kind, n in info["layers_by_kind"].items())
+               if len(info["layers_by_kind"]) > 1 else "")
             + " kv_write=" + ",".join(
                 f"{leaf}:{path}" for leaf, path in info["kv_write"].items())
             + " kv_read=" + ",".join(
@@ -1970,10 +1974,16 @@ class ServingEngine:
         the call was given; and the grouped products the program holds, by
         the way they went (``moe_kernel_products`` / ``moe_ragged_products``:
         static a program), with the rows they sorted and the rows the way in
-        moved (``moe_sorted_rows`` / ``moe_moved_rows``)."""
-        pairs = live_tokens * self.model.config.moe_top_k * counts.shape[0]
+        moved (``moe_sorted_rows`` / ``moe_moved_rows``).  Experts in a
+        latent (``moe_latent_size``): ``moe_latent_rows``, the token rows
+        that went through each of the two projections, a row a real token
+        an expert layer."""
+        cfg = self.model.config
+        pairs = live_tokens * cfg.moe_top_k * counts.shape[0]
         rows, touched = int(counts.sum()), int((counts > 0).sum())
-        sp.set(moe_live_rows=pairs, moe_rows=rows,
+        latent = ({"moe_latent_rows": live_tokens * counts.shape[0]}
+                  if cfg.moe_latent_size else {})
+        sp.set(moe_live_rows=pairs, moe_rows=rows, **latent,
                moe_experts_touched=touched,
                moe_max_load=int(counts.max()),
                # the (token, expert) pairs the routers chose, those whose

@@ -133,10 +133,14 @@ def check(cfg) -> None:
     run = (cfg.layer_pattern or ())[:cfg.num_layers]
     for m in mixers_of(cfg):
         own = m.refusals(cfg)
-        if cfg.norm != "rmsnorm" or cfg.activation != "swiglu":
+        if cfg.norm != "rmsnorm" or cfg.activation not in ("swiglu",
+                                                           "relu2"):
             raise NotImplementedError(
-                f"{m.words} take RMSNorm and a gated MLP")
-        other = sorted(set(run) - {m.kind, "full"})
+                f"{m.words} take RMSNorm and a gated MLP (swiglu) or a "
+                "squared-ReLU one (relu2)")
+        # of layers that are one sublayer the MLP's are a kind too
+        other = sorted(set(run) - {m.kind, "full"}
+                       - ({"mlp"} if cfg.one_sublayer else set()))
         for on, what in (
                 *((True, o.words) for o in mixers_of(cfg) if o is not m),
                 *own,

@@ -57,7 +57,8 @@ class TransformerConfig:
     head_dim: Optional[int] = None            # None => hidden // heads
     max_seq_len: int = 2048
     norm: str = "rmsnorm"                     # rmsnorm | layernorm
-    activation: str = "swiglu"    # swiglu | gelu | gelu_exact | relu | quick_gelu
+    # swiglu | gelu | gelu_exact | relu | quick_gelu | relu2 (relu(x)^2)
+    activation: str = "swiglu"
     position: str = "rope"            # rope | learned | alibi | none (NoPE)
     rope_theta: float = 10000.0
     # partial rotary (GPT-J/NeoX): apply rope to the first rotary_dim dims
@@ -112,6 +113,15 @@ class TransformerConfig:
     # K/V leaves then cover the attention layers and the state leaves the
     # "ssm" ones.
     layer_pattern: Optional[tuple] = None
+    # Layers of ONE sublayer (Nemotron-H, ``nemotron_h``): every entry of
+    # ``layer_pattern`` is then ``x + f(N(x))``, one norm and one add: "ssm"
+    # the mixer alone and "full" attention alone (no MLP leaves and no second
+    # norm behind either), and a further kind, "mlp", the MLP or expert
+    # layer alone (no attention, no mixer, no cache leaf).  ``sublayer`` is
+    # how :func:`layer_groups` says which of the two such a group's uniform
+    # config is ("mix" | "mlp"), never a model's own setting.
+    one_sublayer: bool = False
+    sublayer: Optional[str] = None
     window_kv_heads: Optional[int] = None     # None => num_kv_heads
     window_rope_theta: Optional[float] = None  # None => rope_theta
     window_attn_sink: bool = False
@@ -276,6 +286,13 @@ class TransformerConfig:
     # routed experts' gates are multiplied by ``moe_routed_scale``
     moe_shared_experts: int = 0
     moe_routed_scale: float = 1.0
+    # routed experts that work in a latent narrower than the model
+    # (Nemotron-H's ``moe_latent_size``): two more leaves a layer,
+    # ``moe_latent_in [d, latent]`` and ``moe_latent_out [latent, d]``, the
+    # expert stacks ``[E, latent, f]`` / ``[E, f, latent]``; the router and
+    # the shared experts stay on the model's width: ``(sum_e gate_e
+    # expert_e(n W_in)) W_out + shared(n)``.  None: experts on the width
+    moe_latent_size: Optional[int] = None
     # residual MoE (PR-MoE, reference moe/layer.py use_residual): each MoE
     # layer also runs a dense MLP; outputs mix via a learned 2-way coefficient
     moe_use_residual: bool = False
@@ -334,7 +351,7 @@ class TransformerConfig:
         if is_grouped(self):
             # the parts outside the layers once, each group's layers beside
             outside = dataclasses.replace(
-                self, layer_pattern=None, dense_layers=0,
+                self, layer_pattern=None, dense_layers=0, one_sublayer=False,
                 num_layers=0).param_count
             return outside + sum(
                 g.param_count - outside for g, _ in layer_groups(self).values())
@@ -354,12 +371,14 @@ class TransformerConfig:
             attn += nh
         if self.attn_output_gate:
             attn += d * vd * nh
-        if not sublayers(self)[0]:      # a mixer in attention's place
+        has_attn, _, has_mlp = sublayers(self)
+        if not has_attn:                # a mixer in attention's place
             attn = 0
         attn += sum(m.param_count(self) for m in mixers_of(self))
         if self.moe_intermediate_size and self.num_experts != 1:
             f = self.moe_intermediate_size
-        mlp = 3 * d * f if self.activation == "swiglu" else 2 * d * f
+        products = 3 if self.activation == "swiglu" else 2
+        mlp = products * d * f
         if self.mlp_bias:
             mlp += (2 * f if self.activation == "swiglu" else f) + d
         experts = (tuple(self.num_experts)
@@ -369,16 +388,23 @@ class TransformerConfig:
         for E in experts:
             m = mlp
             if E > 1:
-                # the experts held here + the router at its full width
-                m = mlp * (self.moe_experts_held or E) + d * E
+                # the experts held here + the router at its full width;
+                # experts in a latent: their two products on its width, and
+                # the projections into it and back
+                dl = self.moe_latent_size or d
+                m = (products * dl * f * (self.moe_experts_held or E) + d * E
+                     + (2 * d * dl if self.moe_latent_size else 0))
                 if self.moe_select_bias:
                     m += E
                 m += mlp * self.moe_shared_experts
                 if self.moe_use_residual:
                     m += mlp + 2 * d  # dense residual branch + coefficient
             total_mlp += m
-        n_norms = ((1 if self.shared_layernorm else 2)
-                   + (2 if self.sandwich_norm else 0))
+        if not has_mlp:
+            total_mlp = 0
+        n_norms = 1 if self.sublayer else (
+            (1 if self.shared_layernorm else 2)
+            + (2 if self.sandwich_norm else 0))
         norms = n_norms * d * (2 if self.norm == "layernorm" else 1)
         embed = v * d * (1 if self.tie_embeddings else 2)
         if self.lm_head_bias and not self.tie_embeddings:
@@ -394,6 +420,12 @@ class TransformerConfig:
         return (L * (attn + norms) + total_mlp + embed + pos + extra
                 + final_norm)
 
+
+# Nemotron-3-Super's ``hybrid_override_pattern``: M a Mamba-2 mixer, E an
+# expert layer, * attention, each a layer of its own
+NEMOTRON_3_SUPER_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEM*EMEMEMEME")
 
 # -- named configs (sizes from the public model cards) --
 CONFIGS: Dict[str, TransformerConfig] = {
@@ -584,6 +616,30 @@ CONFIGS: Dict[str, TransformerConfig] = {
         moe_select_bias=True, moe_norm_topk_prob=True,
         moe_norm_topk_eps=1e-20, moe_routed_scale=2.448,
         moe_shared_experts=1, moe_drop_tokens=False, remat=False),
+    # nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16 config.json
+    # (``nemotron_h``, 120B-A12B): 88 layers by ``hybrid_override_pattern``,
+    # each ONE sublayer behind one RMSNorm (eps 1e-5): 40 Mamba-2 mixers (128
+    # heads of 64, state 128, 8 groups, 4 taps with a bias, chunk 128), 8 of
+    # attention (32 heads over 2 KV heads of 128, no position of any kind)
+    # and 40 expert layers: 512 experts of 2,688 in a latent of 1,024
+    # (squared ReLU, ungated), sigmoid scores, 22 a token chosen on score +
+    # bias, gates renormalised (sum + 1e-20) and scaled by 5, beside one
+    # shared expert of 5,376 on the full width; untied head over 131,072
+    # ids.  The multi-token-prediction module is left out
+    "nemotron-3-super-120b-a12b": TransformerConfig(
+        vocab_size=131072, hidden_size=4096, intermediate_size=2688,
+        moe_intermediate_size=2688, moe_latent_size=1024, num_layers=88,
+        num_heads=32, num_kv_heads=2, head_dim=128, max_seq_len=262144,
+        norm_eps=1e-5, position="none", activation="relu2",
+        one_sublayer=True,
+        layer_pattern=tuple({"M": "ssm", "E": "mlp", "*": "full"}[c]
+                            for c in NEMOTRON_3_SUPER_PATTERN),
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssm_groups=8,
+        ssm_conv=4, ssm_chunk=128,
+        num_experts=512, moe_top_k=22, moe_score_func="sigmoid",
+        moe_select_bias=True, moe_norm_topk_prob=True,
+        moe_norm_topk_eps=1e-20, moe_routed_scale=5.0,
+        moe_shared_experts=2, moe_drop_tokens=False, remat=False),
     # tiny variants for tests / dryruns
     "tiny": TransformerConfig(
         vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -635,6 +691,11 @@ def has_moe(cfg: TransformerConfig) -> bool:
 # the attention projections: what a layer with the mixer in attention's
 # place (:func:`sublayers`) does not have
 _ATTN_LEAVES = ("wq", "wk", "wv", "wo", "wg")
+# what a layer that is its MLP alone does not have beside them, and what a
+# layer that is its mixer or attention alone does not have (``sublayer``)
+_MIX_NORM_LEAVES = ("attn_norm_scale", "attn_norm_bias")
+_MLP_LEAVES = ("mlp_norm_scale", "mlp_norm_bias", "w_gate", "w_up", "w_in",
+               "w_down", "b_gate", "b_up", "b_in", "b_down")
 
 # The per-expert leaves of an MoE layer, ``[L, E, ...]`` in the layer stack:
 # the matmul weights and (gelu experts) their per-expert biases.
@@ -648,8 +709,11 @@ def expert_counts_shape(cfg) -> Optional[Tuple[int, int]]:
     model (dense, capacity buffers, a per-layer pyramid)."""
     experts = getattr(cfg, "num_experts", 1)
     if isinstance(experts, int) and experts > 1 and not cfg.moe_drop_tokens:
-        return (cfg.num_layers - cfg.dense_layers,
-                cfg.moe_experts_held or experts)
+        # of layers that are one sublayer, the "mlp" ones alone
+        layers = (sum(not dense for *_, kind, dense in layer_plan(cfg)
+                      if kind == "mlp") if cfg.one_sublayer
+                  else cfg.num_layers - cfg.dense_layers)
+        return layers, cfg.moe_experts_held or experts
     return None
 
 
@@ -663,7 +727,8 @@ def is_grouped(cfg: TransformerConfig) -> bool:
     """Layers that are not one uniform stack (two kinds of attention, or
     leading dense layers before expert layers): ``params["layers"]`` is
     ``{group: stack}`` (:func:`layer_groups`)."""
-    return cfg.layer_pattern is not None or cfg.dense_layers > 0
+    return (cfg.layer_pattern is not None or cfg.dense_layers > 0
+            or cfg.one_sublayer)
 
 
 def is_latent(cfg: TransformerConfig) -> bool:
@@ -701,16 +766,22 @@ def qk_norm_widths(cfg: TransformerConfig) -> Tuple[int, int]:
     return cfg.num_heads * hd, cfg.kv_heads * hd
 
 
-def sublayers(cfg: TransformerConfig) -> Tuple[bool, bool]:
-    """``(attention, mixer)``: which of the two a layer of the uniform stack
-    ``cfg`` has before its MLP.  The one rule :func:`_block`, the parameters
-    and the cache's leaves follow: a mixer where the stack turns one of
-    :data:`~.mixers.MIXERS` on, attention unless that mixer stands in its
-    place (any but one that may stand ``beside`` it, and that one too in a
-    pattern's group of its own, ``ssm_alone``; both: Falcon-H1's parallel
-    block)."""
+def sublayers(cfg: TransformerConfig) -> Tuple[bool, bool, bool]:
+    """``(attention, mixer, mlp)``: which of the three a layer of the
+    uniform stack ``cfg`` has, the first two before the third.  The one rule
+    :func:`_block`, the parameters and the cache's leaves follow: a mixer
+    where the stack turns one of :data:`~.mixers.MIXERS` on, attention
+    unless that mixer stands in its place (any but one that may stand
+    ``beside`` it, and that one too in a pattern's group of its own,
+    ``ssm_alone``; both: Falcon-H1's parallel block), and the MLP or expert
+    layer behind them; a layer that is ONE sublayer (``sublayer``) its
+    mixer or attention and no MLP ("mix"), or the MLP and nothing before it
+    ("mlp")."""
+    if cfg.sublayer == "mlp":
+        return False, False, True
     has = mixers_of(cfg)
-    return not (cfg.ssm_alone or any(not m.beside for m in has)), bool(has)
+    return (not (cfg.ssm_alone or any(not m.beside for m in has)), bool(has),
+            cfg.sublayer != "mix")
 
 
 def cache_layers(cfg: TransformerConfig) -> Tuple[int, int]:
@@ -720,8 +791,8 @@ def cache_layers(cfg: TransformerConfig) -> Tuple[int, int]:
     groups = (layer_groups(cfg).values() if is_grouped(cfg)
               else [(cfg, cfg.num_layers)])
     has = [(sublayers(g), n) for g, n in groups]
-    return (sum(n for (attn, _), n in has if attn),
-            sum(n for (_, mixer), n in has if mixer))
+    return (sum(n for (attn, _, _), n in has if attn),
+            sum(n for (_, mixer, _), n in has if mixer))
 
 
 def cache_depth(cfg: TransformerConfig) -> int:
@@ -756,7 +827,9 @@ def pool_leaf_head_major(kv_heads: int, width: int) -> bool:
 def layer_plan(cfg: TransformerConfig):
     """A grouped model's layers in the published order: ``(group, index in
     the group, kind, dense)`` each, ``group`` = ``<kind>_<dense|moe>``
-    (every layer ``full`` without a ``layer_pattern``)."""
+    (every layer ``full`` without a ``layer_pattern``); of layers that are
+    one sublayer (``one_sublayer``) a mixer's or attention's group is
+    ``<kind>_only``: it has no MLP of either sort."""
     pattern = cfg.layer_pattern or ("full",) * cfg.num_layers
     if cfg.window_position not in (None, "rope", "none"):
         # a table of learned positions or ALiBi's slopes is the whole
@@ -767,18 +840,31 @@ def layer_plan(cfg: TransformerConfig):
         raise ValueError(
             f"layer_pattern has {len(pattern)} entries for "
             f"{cfg.num_layers} layers")
+    if cfg.one_sublayer:
+        if cfg.layer_pattern is None:
+            raise ValueError("one_sublayer says what each entry of a "
+                             "layer_pattern is: it takes one")
+        for flag in ("sandwich_norm", "norm_after", "post_layernorm",
+                     "parallel_residual", "shared_layernorm"):
+            if getattr(cfg, flag):
+                raise NotImplementedError(
+                    "layers of one sublayer (one_sublayer) are one norm and "
+                    f"one add: they do not take {flag}")
+    kinds = ("full", "window", *MIXERS, *(("mlp",) if cfg.one_sublayer
+                                          else ()))
     plan, seen = [], {}
     # a model cut in depth runs the first layers of the published pattern
     for i, kind in enumerate(pattern[:cfg.num_layers]):
-        if kind not in ("full", "window", *MIXERS):
+        if kind not in kinds:
             raise ValueError(f"layer_pattern[{i}] = {kind!r}: "
-                             + " | ".join(("full", "window", *MIXERS)))
+                             + " | ".join(kinds))
         if kind in MIXERS and MIXERS[kind] not in mixers_of(cfg):
             raise ValueError(
                 f"layer_pattern[{i}] = {kind!r} in a model with no "
                 f"{MIXERS[kind].words}")
-        dense = i < cfg.dense_layers or not has_moe(cfg)
-        group = f"{kind}_{'dense' if dense else 'moe'}"
+        alone = cfg.one_sublayer and kind != "mlp"
+        dense = alone or i < cfg.dense_layers or not has_moe(cfg)
+        group = f"{kind}_{'only' if alone else 'dense' if dense else 'moe'}"
         plan.append((group, seen.get(group, 0), kind, dense))
         seen[group] = seen.get(group, 0) + 1
     return plan
@@ -790,13 +876,17 @@ def layer_groups(cfg: TransformerConfig):
     :func:`init_params`, :func:`param_specs` and :func:`_block` take as they
     take any model's, with the kind's KV heads, theta, sink and MLP, and
     under a pattern the kind's one mixer (:func:`sublayers`): a kind of
-    :data:`~.mixers.MIXERS` its mixer alone, any other attention alone."""
+    :data:`~.mixers.MIXERS` its mixer alone, any other attention alone; of
+    layers that are one sublayer (``one_sublayer``) that and no MLP, or
+    (kind "mlp") the MLP alone."""
     groups: Dict[str, Any] = {}
     for group, index, kind, dense in layer_plan(cfg):
         window = kind == "window"
         groups[group] = (dataclasses.replace(
             cfg, layer_pattern=None, dense_layers=0, num_layers=index + 1,
-            ssm_alone=kind == "ssm",
+            ssm_alone=kind == "ssm", one_sublayer=False,
+            sublayer=(("mlp" if kind == "mlp" else "mix")
+                      if cfg.one_sublayer else None),
             # a kind's mixer in its own layers alone; without a pattern the
             # one that stands beside attention in every layer
             **{m.field: (getattr(cfg, m.field) if kind == m.kind
@@ -887,6 +977,16 @@ def kind_layers(cfg: TransformerConfig):
     return kinds
 
 
+def layers_by_kind(cfg: TransformerConfig) -> Dict[str, int]:
+    """``{kind: layers}`` of the layers run, in order of first appearance:
+    a pattern's kinds (of layers that are one sublayer "mlp" is one), else
+    every layer ``full``."""
+    kinds: Dict[str, int] = {}
+    for *_, kind, _ in layer_plan(cfg):
+        kinds[kind] = kinds.get(kind, 0) + 1
+    return kinds
+
+
 def _check_latent(cfg: TransformerConfig) -> None:
     """What a latent-attention layer is built from, and what it leaves
     out."""
@@ -905,8 +1005,27 @@ def _check_latent(cfg: TransformerConfig) -> None:
         if on:
             raise NotImplementedError(
                 f"latent attention (kv_lora_rank) does not take {what}")
-    if cfg.moe_shared_experts and cfg.activation != "swiglu":
-        raise NotImplementedError("shared experts are gated (swiglu) MLPs")
+
+
+def _own_sublayer_alone(cfg: TransformerConfig, layers: Dict[str, Any]):
+    """A layer that is one sublayer (``sublayer``) keeps one norm and its
+    own leaves: the others go from a stack's leaves or specs, in place."""
+    for name in {"mix": _MLP_LEAVES, "mlp": _MIX_NORM_LEAVES}.get(
+            cfg.sublayer, ()):
+        layers.pop(name, None)
+
+
+def _check_experts(cfg: TransformerConfig) -> None:
+    """What an expert layer's shared experts and latent are built from."""
+    if cfg.moe_shared_experts and cfg.activation not in ("swiglu", "relu2"):
+        raise NotImplementedError(
+            "shared experts are gated (swiglu) or squared-ReLU (relu2) "
+            f"MLPs, not activation={cfg.activation!r}")
+    if cfg.moe_latent_size and (cfg.moe_drop_tokens or cfg.mlp_bias
+                                or cfg.moe_use_residual):
+        raise NotImplementedError(
+            "experts in a latent (moe_latent_size) are the dropless path's "
+            "(moe_drop_tokens=False), without mlp_bias or moe_use_residual")
 
 
 def _check_qk_norm(cfg: TransformerConfig) -> None:
@@ -1049,7 +1168,10 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         f = cfg.moe_intermediate_size
     held = cfg.moe_experts_held or E        # the expert stacks' own count
     mlp_shape = (lambda *s: (L, held) + s) if E > 1 else (lambda *s: (L,) + s)
+    # the width the routed experts read and write: the model's, or a latent
+    de = cfg.moe_latent_size if E > 1 and cfg.moe_latent_size else d
     if E > 1:
+        _check_experts(cfg)
         # per-expert biases supported on the gelu/relu path (Megatron-DS MoE
         # experts are biased Linears); swiglu experts stay bias-free
         assert not (cfg.mlp_bias and cfg.activation == "swiglu"), \
@@ -1076,18 +1198,26 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
             layers["router_bias"] = (quantiles[order].reshape(L, E)
                                      * (0.04 * std * math.sqrt(d)))
     if cfg.activation == "swiglu":
-        layers["w_gate"] = dense(keys[4], mlp_shape(d, f))
-        layers["w_up"] = dense(keys[5], mlp_shape(d, f))
-        layers["w_down"] = dense(keys[6], mlp_shape(f, d), std / math.sqrt(2 * L))
+        layers["w_gate"] = dense(keys[4], mlp_shape(de, f))
+        layers["w_up"] = dense(keys[5], mlp_shape(de, f))
+        layers["w_down"] = dense(keys[6], mlp_shape(f, de), std / math.sqrt(2 * L))
     else:
-        layers["w_in"] = dense(keys[4], mlp_shape(d, f))
-        layers["w_down"] = dense(keys[6], mlp_shape(f, d), std / math.sqrt(2 * L))
+        layers["w_in"] = dense(keys[4], mlp_shape(de, f))
+        layers["w_down"] = dense(keys[6], mlp_shape(f, de), std / math.sqrt(2 * L))
+    if de != d:
+        lk = jax.random.split(jax.random.fold_in(rng, 20), 2)
+        layers["moe_latent_in"] = dense(lk[0], (L, d, de))
+        layers["moe_latent_out"] = dense(lk[1], (L, de, d))
     if E > 1 and cfg.moe_shared_experts:
-        # the shared experts as one gated MLP of their widths together
+        # the shared experts as one MLP of their widths together, gated
+        # where the experts are
         fs = cfg.moe_shared_experts * f
         sk = jax.random.split(jax.random.fold_in(rng, 18), 3)
-        layers["shared_w_gate"] = dense(sk[0], (L, d, fs))
-        layers["shared_w_up"] = dense(sk[1], (L, d, fs))
+        if cfg.activation == "swiglu":
+            layers["shared_w_gate"] = dense(sk[0], (L, d, fs))
+            layers["shared_w_up"] = dense(sk[1], (L, d, fs))
+        else:
+            layers["shared_w_in"] = dense(sk[1], (L, d, fs))
         layers["shared_w_down"] = dense(sk[2], (L, fs, d),
                                         std / math.sqrt(2 * L))
     if E > 1 and cfg.moe_use_residual:
@@ -1115,6 +1245,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Dict[str, Any]:
         else:
             layers["b_in"] = jnp.zeros(mlp_shape(f))
         layers["b_down"] = jnp.zeros(mlp_shape(d))
+    _own_sublayer_alone(cfg, layers)
 
     params: Dict[str, Any] = {
         # a block with no norm on its input (norm_after) reads the embedding
@@ -1300,8 +1431,16 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
         layers.update(w_gate=mcol, w_up=mcol, w_down=mrow)
     else:
         layers.update(w_in=mcol, w_down=mrow)
+    if cfg.num_experts > 1 and cfg.moe_latent_size:
+        # whole on every chip, as the router that stands beside them
+        layers.update(moe_latent_in=P(None, None, None),
+                      moe_latent_out=P(None, None, None))
     if cfg.num_experts > 1 and cfg.moe_shared_experts:
-        layers.update(shared_w_gate=col, shared_w_up=col, shared_w_down=row)
+        if cfg.activation == "swiglu":
+            layers.update(shared_w_gate=col, shared_w_up=col)
+        else:
+            layers["shared_w_in"] = col
+        layers["shared_w_down"] = row
     if cfg.num_experts > 1 and cfg.moe_use_residual:
         if cfg.activation == "swiglu":
             layers.update(res_w_gate=col, res_w_up=col, res_w_down=row)
@@ -1320,6 +1459,7 @@ def param_specs(cfg: TransformerConfig) -> Dict[str, Any]:
             layers["b_in"] = P(None, "model")
         layers["b_down"] = (P(None, "expert", None) if cfg.num_experts > 1
                             and cfg.activation != "swiglu" else P(None, None))
+    _own_sublayer_alone(cfg, layers)
 
     if cfg.pipeline_stages > 1:
         # stage dim rides the 'pipe' axis; each shard holds its stage's layers
@@ -1752,6 +1892,8 @@ def _dense_mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, prefix=""):
             m = m + lp["b_in"]
         if cfg.activation == "relu":
             m = jax.nn.relu(m)
+        elif cfg.activation == "relu2":        # Nemotron-H: relu(x)^2
+            m = jnp.square(jax.nn.relu(m))
         elif cfg.activation == "gelu_exact":   # HF 'gelu' (erf)
             m = jax.nn.gelu(m, approximate=False)
         elif cfg.activation == "quick_gelu":   # CLIP: x * sigmoid(1.702 x)
@@ -1791,7 +1933,7 @@ def expert_matmul_path(cfg: TransformerConfig, B: int, S: int
     if expert_counts_shape(cfg) is None:
         return None
     return path(B * S // _moe_chunks(cfg, B, S), cfg.moe_top_k,
-                cfg.num_experts, cfg.hidden_size,
+                cfg.num_experts, cfg.moe_latent_size or cfg.hidden_size,
                 cfg.moe_intermediate_size or cfg.intermediate_size,
                 cfg.dtype, _common._pallas_interpret())
 
@@ -1862,13 +2004,26 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
                                   if cfg.moe_experts_held else None),
                             routed_scale=cfg.moe_routed_scale)
 
+            latent = "moe_latent_in" in lp
+
             def experts(hc, mask):
-                return moe_ffn(
-                    hc, lp["router"], lp, moe, activation=cfg.activation,
+                u = hc
+                if latent:
+                    # the experts read and write the latent's rows; the
+                    # router reads the model's width
+                    with jax.named_scope("moe_latent_in"):
+                        u = hc @ lp["moe_latent_in"]
+                m, aux, counts = moe_ffn(
+                    u, lp["router"], lp, moe, activation=cfg.activation,
                     deterministic=deterministic, rng=rng, token_mask=mask,
                     expert_offset=expert_offset,
                     select_bias=lp.get("router_bias"),
-                    pallas_interpret=_common._pallas_interpret())
+                    pallas_interpret=_common._pallas_interpret(),
+                    router_input=hc if latent else None)
+                if latent:
+                    with jax.named_scope("moe_latent_out"):
+                        m = m @ lp["moe_latent_out"]
+                return m, aux, counts
 
             B, S, D = h.shape
             n = _moe_chunks(cfg, B, S)
@@ -1885,7 +2040,7 @@ def _mlp(cfg: TransformerConfig, lp: Dict[str, Any], h, rng, deterministic,
                 m, aux, counts = m.reshape(B, S, D), aux.mean(), counts.sum(0)
             else:
                 m, aux, counts = experts(h, token_mask)
-            if "shared_w_gate" in lp:
+            if "shared_w_down" in lp:
                 # the shared experts: every token's, beside the routed sum
                 # (under a held share of the routed experts every chip
                 # computes them alike)
@@ -2063,6 +2218,8 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
       gated (``attn_output_gate``) attn(n) = (softmax(q k) v * sigmoid(n Wg)) Wo
       norm after (Olmo-Hybrid,     x += N1(mix(x));  x += N2(mlp(x)), mix the
       ``norm_after``)              delta mixer or attention by the layer's kind
+      one sublayer (Nemotron-H,    x += mix(N(x))  or  x += mlp(N(x)): one
+      ``sublayer``)                norm and one add, by the layer's kind
 
     What attention reads, and where K/V go, is the caller's:
     ``attend(q, k, v) -> (out [B,S,Hq,hd], state)`` is handed the layer's
@@ -2076,11 +2233,16 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     and keeps (:func:`_ssm_mixer`); ``state`` is then ``(attend's, the
     mixer's)``.  Which of the two a layer has is :func:`sublayers`' rule: a
     layer with no attention takes no ``attend`` (None), and ``state`` is
-    ``(None, the mixer's)``.
+    ``(None, the mixer's)``; a layer that is its MLP alone takes neither and
+    keeps nothing (``state`` None), one that is its mixer or attention
+    alone ends at that add.
 
     Returns ``(x, moe_aux_loss, expert_counts, state)``."""
     post = cfg.post_layernorm
-    has_attn, has_mixer = sublayers(cfg)
+    has_attn, has_mixer, has_mlp = sublayers(cfg)
+    if not (has_attn or has_mixer):
+        return (*_block_mlp(cfg, lp, x, x, rng, deterministic, token_mask,
+                            expert_offset), None)
     h = x if post or cfg.norm_after else _norm(
         cfg, x, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
     h = _maybe_act_quant(cfg, _off_stream(cfg, h))
@@ -2123,6 +2285,20 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     if cfg.norm_after:
         attn = _norm(cfg, attn, lp["attn_norm_scale"])
     res = x + _scaled(attn, cfg.residual_multiplier)
+    if not has_mlp:
+        return res, jnp.float32(0.0), None, state
+    x, aux, counts = _block_mlp(cfg, lp, x, res, rng, deterministic,
+                                token_mask, expert_offset, h)
+    return x, aux, counts, state
+
+
+def _block_mlp(cfg: TransformerConfig, lp: Dict[str, Any], x, res, rng,
+               deterministic, token_mask, expert_offset, h=None):
+    """:func:`_block`'s second half, the MLP or expert layer on ``res`` (the
+    layer's input ``x`` with its first sublayer added, or ``x`` itself in a
+    layer that is its MLP alone; ``h``: the first sublayer's normed input,
+    which a GPT-J block's MLP reads): ``(x, moe_aux_loss, expert_counts)``."""
+    post = cfg.post_layernorm
     if post:
         res = _norm(cfg, res, lp["attn_norm_scale"], lp.get("attn_norm_bias"))
         h2 = _maybe_act_quant(cfg, res)
@@ -2145,7 +2321,7 @@ def _block(cfg: TransformerConfig, lp: Dict[str, Any], x, positions, rng,
     x = res + _scaled(m, cfg.residual_multiplier)
     if post:
         x = _norm(cfg, x, lp["mlp_norm_scale"], lp.get("mlp_norm_bias"))
-    return x, aux, counts, state
+    return x, aux, counts
 
 
 def _attend_full(cfg: TransformerConfig, positions, attn_impl: str = "xla",
@@ -3999,7 +4175,9 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
     of a kind of :data:`~.mixers.MIXERS` has no pages: its slot-indexed
     leaves, ``[layers of the kind * slots, ...]`` stacked, are read and
     written where they lie (:func:`~.mixers.paged`; ``state_slot`` as
-    :func:`forward_paged`'s)."""
+    :func:`forward_paged`'s).  A layer that is its MLP or expert layer alone
+    (``one_sublayer``'s "mlp") owns no leaf: it is handed neither an
+    ``attend`` nor a mixer and nothing of it is kept."""
     full_table, ring_table = (page_table if isinstance(page_table,
                                                        (tuple, list))
                               else (page_table, None))
@@ -4082,13 +4260,15 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
         # cut out of a stack here
         lp = {k: v[index] for k, v in params["layers"][group].items()
               if k not in experts[group]}
-        layer = seen[kind]
-        seen[kind] += 1
         attend = mixer = None
+        # a layer that is its MLP alone has no cache leaf to read or keep
+        layer = seen.get(kind)
+        if layer is not None:
+            seen[kind] += 1
         if kind in stateful:
             mixer = mixers.paged(MIXERS[kind], g, pools[kind],
                                  layer * slots, state_slot, start, seq_mask)
-        else:
+        elif layer is not None:
             first_page = layer * n_pages[kind]
             write, read = plans[kind]
             if read is not None:
@@ -4104,7 +4284,8 @@ def _forward_paged_hybrid(cfg, params, tokens, cache, page_table, start,
             expert_offset=(jnp.int32(index * (g.moe_experts_held
                                               or g.num_experts))
                            if experts[group] else None), ssm=mixer)
-        pools[kind] = kept[1] if kind in stateful else kept
+        if layer is not None:
+            pools[kind] = kept[1] if kind in stateful else kept
         x = constrain_spec(x, P(BATCH_AXES, None, None))
         if pin:
             x = jax.lax.optimization_barrier(x)

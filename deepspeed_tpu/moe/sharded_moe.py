@@ -186,6 +186,14 @@ def expert_matmul_path(tokens: int, top_k: int, num_experts: int,
     return "ragged_dot"
 
 
+def _ungated(activation: str, h):
+    """An expert's activation where it is not a gated one: the squared ReLU
+    (``"relu2"``, Nemotron-H's), else the GELU every other name has meant
+    here."""
+    return (jnp.square(jax.nn.relu(h)) if activation == "relu2"
+            else jax.nn.gelu(h))
+
+
 def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
                    expert_params: Dict[str, Any], cfg: MoEConfig,
                    activation: str = "swiglu", deterministic: bool = True,
@@ -193,7 +201,8 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
                    token_mask: Optional[jnp.ndarray] = None,
                    expert_offset: Optional[jnp.ndarray] = None,
                    select_bias: Optional[jnp.ndarray] = None,
-                   pallas_interpret: Optional[bool] = None
+                   pallas_interpret: Optional[bool] = None,
+                   router_input: Optional[jnp.ndarray] = None
                    ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """True no-token-dropping MoE via ``lax.ragged_dot`` — the TPU-native
     answer to the reference's dynamic-capacity exchange (sharded_moe.py:253
@@ -233,6 +242,11 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     result is this share's part of the layer's sum, the gates those of the
     whole choice.
 
+    ``router_input [B, S, d]`` (optional): what the router reads where it
+    is not what the experts read (experts that work in a latent: ``x`` is
+    the tokens' latent rows, ``D`` the latent's width, and the router keeps
+    the model's).
+
     ``pallas_interpret``: ``None`` (the default) where the program traced
     now may hold no Pallas kernel, and the three products are
     ``lax.ragged_dot``; else the kernels' ``interpret`` flag (``False`` on a
@@ -259,7 +273,8 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
     E, k = cfg.num_experts, cfg.top_k
     T = B * S
     with jax.named_scope("moe_router"):
-        logits = _router_logits(x, router_w, cfg, deterministic, rng)
+        logits = _router_logits(x if router_input is None else router_input,
+                                router_w, cfg, deterministic, rng)
         if cfg.score_func == "sigmoid":
             gates = jax.nn.sigmoid(logits.reshape(T, E))
         elif cfg.score_func == "softmax":
@@ -355,7 +370,7 @@ def moe_ffn_nodrop(x: jnp.ndarray, router_w: jnp.ndarray,
             h = product(xs, w("w_in"), group_sizes)
             if "b_in" in expert_params:  # per-expert bias (Megatron-DS experts)
                 h = h + w("b_in")[row_expert]
-            h = jax.nn.gelu(h)
+            h = _ungated(activation, h)
         out = product(h, w("w_down"), group_sizes)               # [T*k, D]
         if "b_down" in expert_params and activation != "swiglu":
             out = out + w("b_down")[row_expert]
@@ -408,7 +423,8 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
             token_mask: Optional[jnp.ndarray] = None,
             expert_offset: Optional[jnp.ndarray] = None,
             select_bias: Optional[jnp.ndarray] = None,
-            pallas_interpret: Optional[bool] = None):
+            pallas_interpret: Optional[bool] = None,
+            router_input: Optional[jnp.ndarray] = None):
     """x [B, S, D] -> (out [B, S, D], aux_loss, counts): ``counts`` are the
     rows each expert computed, ``[E]`` int32, on the dropless path, and
     ``None`` on the capacity path, whose buffers have one static size.
@@ -416,8 +432,9 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
     Groups = batch rows; capacity is per group.  expert_params leaves are
     [E, D, F] / [E, F, D], sharded P('expert', None, 'model') by the model's
     param_specs.  ``token_mask`` and ``expert_offset`` are the dropless
-    path's, as is ``pallas_interpret`` (see :func:`moe_ffn_nodrop`); a
-    capacity buffer holds a masked token like any other.
+    path's, as are ``pallas_interpret`` and ``router_input`` (see
+    :func:`moe_ffn_nodrop`); a capacity buffer holds a masked token like
+    any other.
     """
     if not cfg.drop_tokens:
         _warn_nodrop_on_expert_mesh()
@@ -427,13 +444,16 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
                               token_mask=token_mask,
                               expert_offset=expert_offset,
                               select_bias=select_bias,
-                              pallas_interpret=pallas_interpret)
+                              pallas_interpret=pallas_interpret,
+                              router_input=router_input)
     assert expert_offset is None, "expert stacks are the dropless path's"
     if (cfg.score_func != "softmax" or cfg.held is not None
-            or select_bias is not None or cfg.routed_scale != 1.0):
+            or select_bias is not None or cfg.routed_scale != 1.0
+            or router_input is not None):
         raise NotImplementedError(
-            "sigmoid scores, a selection bias, a scale on the gates and a "
-            "held share of the experts are the dropless path's (drop_tokens=False); the "
+            "sigmoid scores, a selection bias, a scale on the gates, a "
+            "held share of the experts and experts in a latent are the "
+            "dropless path's (drop_tokens=False); the "
             "capacity buffers route by softmax over experts that are all "
             "here")
     B, S, D = x.shape
@@ -462,7 +482,7 @@ def moe_ffn(x: jnp.ndarray, router_w: jnp.ndarray, expert_params: Dict[str, Any]
                            expert_params["w_in"].astype(x.dtype))
             if "b_in" in expert_params:   # per-expert bias [E, F]
                 h = h + expert_params["b_in"].astype(x.dtype)[None, :, None, :]
-            h = jax.nn.gelu(h)
+            h = _ungated(activation, h)
         expert_out = jnp.einsum("gecf,efd->gecd", h,
                                 expert_params["w_down"].astype(x.dtype))
         if "b_down" in expert_params and activation != "swiglu":

@@ -32,7 +32,8 @@ import pytest  # noqa: E402
 # the benchmark tests' own set-asides, which their conftest.py may not take
 pytest_plugins = ("tests.benchmark.pinned_sets", "tests.benchmark.pinned_tail",
                   "tests.benchmark.pinned_thirteenth",
-                  "tests.benchmark.pinned_fourteenth")
+                  "tests.benchmark.pinned_fourteenth",
+                  "tests.benchmark.pinned_fifteenth")
 
 
 def pytest_collection_modifyitems(config, items):

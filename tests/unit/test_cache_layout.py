@@ -163,7 +163,8 @@ def test_decode_attrs_are_the_hosts_row_counts(kind, lengths):
             min(n, 16) for n in lengths)
     if kind == "state":
         want |= {"state_slots", "state_bytes", "state_passes",
-                 "state_layers", "kv_layers", "kv_live_rows"}
+                 "state_layers", "kv_layers", "kv_live_rows",
+                 "layers_by_kind"}
         assert (a["state_slots"], a["state_bytes"], a["state_passes"]) == (
             len(lengths), 1000 * len(lengths), 3)
         # a parallel block: every layer has both; rows x layers with K/V

@@ -1492,3 +1492,81 @@ def test_gated_window_layers_fit_the_chip_at_the_cells_size(
         # head's six query heads: what the chunks of ``window`` would hold
         assert "f32[1,1,6,4096,8192]" not in text
         assert "4096,8192]" not in text
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_4096"])
+def test_one_sublayer_layers_fit_the_chip_at_the_cells_size(
+        one_v5e_chip, monkeypatch, program):
+    """AOT: the two programs of ``nemotron-3-super-ep4-d11.subagent-backlog``
+    that decide whether its 12.96 GB of weights and cache leave room, at the
+    cell's own sizes (eleven one-sublayer layers at the published widths,
+    128 of 512 experts in the 1,024-wide latent, 128 slots of 56 pages), the
+    weights held a leaf a layer and the cache donated.  The tick: one
+    one-pass kernel an M layer over the ``ssm_state`` leaf where it lies (a
+    block of 8 heads inside one of the 8 groups of 16), the E layers' two
+    products ``lax.ragged_dot`` (5.5 rows an expert), megabytes of
+    temporaries.  The longest prompt's bucket: the M layers' scan one kernel
+    each, the E layers' two products the grouped kernel a chunk of 2,048
+    tokens (88 rows an expert) over latent rows, half a GB of
+    temporaries."""
+    import json
+
+    from benchmark.lib import system
+    from deepspeed_tpu.models import CausalLM, init_params
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.mixers import common as MX
+    from deepspeed_tpu.models.mixers import ssm as SSM
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e_chip)
+
+    monkeypatch.setattr(MX, "_pallas_interpret", lambda: False)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "nemotron-3-super-ep4-d11.json")) as f:
+        cfg = system.transformer_config(json.load(f), False)
+    slots, maxp = 128, 56
+    params = jax.tree_util.tree_map(
+        lambda a: S(a.shape, jnp.bfloat16), jax.eval_shape(
+            lambda: T.per_layer_leaves(cfg, init_params(
+                cfg, jax.random.PRNGKey(0)))[0]))
+    cache = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), jax.eval_shape(
+            lambda: CausalLM(cfg).init_paged_cache(
+                1 + slots * maxp, 128, dtype=jnp.bfloat16, slots=slots)))
+    # one layer of K/V pages (2 KV heads: head-major), five of state rows,
+    # nothing for the five expert layers
+    assert cache["k"].shape == (1, 7169, 2, 128, 128)
+    assert cache["ssm_state"].shape[:2] == (5, slots)
+    b, s = (slots, 1) if program == "decode" else (1, 4096)
+
+    def run(params, cache, tokens, table, start, mask, slot, at):
+        kw = {} if program == "decode" else {"state_slot": slot,
+                                             "logits_at": at}
+        logits, cache, counts = T.forward_paged(
+            cfg, params, tokens, cache, table, start, mask,
+            expert_counts=True, **kw)
+        return jnp.argmax(logits[:, -1], -1), cache, counts
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        params, cache, S((b, s), jnp.int32), S((b, maxp), jnp.int32),
+        S((b,), jnp.int32), S((b, s), jnp.bool_), S((b,), jnp.int32),
+        S((b,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9
+    assert mem.alias_size_in_bytes > 3.6e9          # the cache in place
+    kernels = [ln for ln in compiled.as_text().splitlines()
+               if "custom_call_target=\"tpu_custom_call\"" in ln]
+    if program == "decode":
+        assert mem.temp_size_in_bytes < 0.1e9
+        assert SSM.ssm_step_path(cfg) == "one_pass"
+        assert sum("ssm_step" in ln for ln in kernels) == 5
+        assert sum("ragged" in ln for ln in kernels) >= 10
+        assert not any("grouped_matmul" in ln for ln in kernels)
+        assert T.expert_matmul_path(cfg, b, s) == "ragged_dot"
+    else:
+        assert mem.temp_size_in_bytes < 0.7e9
+        assert SSM.ssm_scan_path(cfg, s) == "kernel"
+        assert sum("ssm_scan" in ln for ln in kernels) == 5
+        assert sum("grouped_matmul" in ln for ln in kernels) == 10
+        assert not any("ragged" in ln for ln in kernels)
+        assert T.expert_matmul_path(cfg, b, s) == "kernel"

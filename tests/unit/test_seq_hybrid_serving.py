@@ -56,11 +56,12 @@ def test_the_pattern_takes_a_third_kind_and_groups_by_it(tiny):
     groups = T.layer_groups(cfg)
     assert list(groups) == ["ssm_moe", "full_moe"]
     assert [n for _, n in groups.values()] == [9, 1]
-    # one rule: (attention, mixer) of each group, and of a parallel block
-    assert T.sublayers(groups["ssm_moe"][0]) == (False, True)
-    assert T.sublayers(groups["full_moe"][0]) == (True, False)
-    assert T.sublayers(get_config("falcon-h1-34b")) == (True, True)
-    assert T.sublayers(get_config("tiny")) == (True, False)
+    # one rule: (attention, mixer, mlp) of each group, and of a parallel
+    # block
+    assert T.sublayers(groups["ssm_moe"][0]) == (False, True, True)
+    assert T.sublayers(groups["full_moe"][0]) == (True, False, True)
+    assert T.sublayers(get_config("falcon-h1-34b")) == (True, True, True)
+    assert T.sublayers(get_config("tiny")) == (True, False, True)
     # the parameters follow it: no attention leaf in a mamba layer, no mixer
     # leaf in an attention layer, an expert layer behind both
     ssm, full = params["layers"]["ssm_moe"], params["layers"]["full_moe"]

@@ -71,6 +71,14 @@ KINDS = {
     "looped": ("ouro-2.6b", dict(
         num_layers=3, hidden_size=64, intermediate_size=96, num_heads=4,
         num_kv_heads=4, head_dim=16, **F32)),
+    # Nemotron-H's form: every layer ONE sublayer (a mixer, attention or an
+    # expert layer alone), the experts in a latent, squared ReLU
+    "latent_moe": ("nemotron-3-super-120b-a12b", dict(
+        num_layers=11, hidden_size=64, intermediate_size=24,
+        moe_intermediate_size=24, moe_latent_size=32, num_heads=4,
+        num_kv_heads=2, head_dim=16, ssm_heads=4, ssm_head_dim=8,
+        ssm_state=16, ssm_groups=2, ssm_chunk=8, num_experts=16,
+        moe_experts_held=4, moe_top_k=3, **F32)),
 }
 # the kinds whose layers run a mixer: their training forward is hashed too
 TRAINED = ("state", "ssm_moe", "delta", "conv")
